@@ -27,7 +27,6 @@ _log = logging.getLogger(__name__)
 
 def _pin_jax_env() -> None:
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
-    os.environ.setdefault("JAX_PLATFORM_NAME", "cpu")
     os.environ.setdefault("TF_CPP_MIN_LOG_LEVEL", "3")
     if "--xla_force_host_platform_device_count" not in os.environ.get(
             "XLA_FLAGS", ""):
@@ -43,16 +42,14 @@ def _init_jax() -> bool:
         _log.debug("jax import failed", exc_info=True)
         print(f"hlo_oracle: SKIP — jax unavailable ({exc})")
         return False
-    try:
-        jax.config.update("jax_platforms", "cpu")
-        jax.config.update(
-            "jax_compilation_cache_dir",
-            os.environ.get("KSERVE_TPU_COMPILE_CACHE",
-                           "/tmp/kserve-tpu-compile-cache"))
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
-    except Exception:  # older jax without these knobs: just slower
-        _log.debug("compile-cache config knobs unavailable", exc_info=True)
+    # the budgets are CPU-lowered by definition, whatever the host offers
+    jax.config.update("jax_platforms", "cpu")
+    jax.config.update(
+        "jax_compilation_cache_dir",
+        os.environ.get("KSERVE_TPU_COMPILE_CACHE",
+                       "/tmp/kserve-tpu-compile-cache"))
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
     return True
 
 

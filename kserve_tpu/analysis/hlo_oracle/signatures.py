@@ -90,16 +90,16 @@ def build_program_set(tp: int = 1, spec_k: Optional[int] = None,
         dtype=cfg.dtype,
     )
     if cfg.kv_quant == "int8":
-        pages = shd.shard_kv_pages(
-            init_kv_pages(dataclasses.replace(cache_cfg, dtype="int8")),
-            mesh)
+        pages = init_kv_pages(
+            dataclasses.replace(cache_cfg, dtype="int8"),
+            shd.kv_pages_sharding(mesh))
         scale_sharding = shd.named_canonical(
             mesh,
             jax.sharding.PartitionSpec(None, None, shd.MODEL_AXIS, None))
         scales = init_kv_scales(cache_cfg, scale_sharding)
         kv_pages = list(zip(pages, scales))
     else:
-        kv_pages = shd.shard_kv_pages(init_kv_pages(cache_cfg), mesh)
+        kv_pages = init_kv_pages(cache_cfg, shd.kv_pages_sharding(mesh))
     defs = program_defs(mc, cfg, mesh, spec_k=spec_k)
     return ProgramSet(mc=mc, cfg=cfg, mesh=mesh, params=params,
                       kv_pages=kv_pages, defs=defs, spec_k=spec_k)

@@ -109,6 +109,10 @@ def _jsonable(value: Any) -> Any:
     return str(value)
 
 
+def _mesh_devices(mesh) -> list:
+    return list(mesh.devices.flat) if mesh is not None else jax.devices()
+
+
 def aot_cache_key(model_config, engine_config, mesh) -> str:
     """Content digest of everything that determines the compiled
     artifact.  Model config is digested WHOLE (any architectural field
@@ -121,7 +125,7 @@ def aot_cache_key(model_config, engine_config, mesh) -> str:
 
     import jaxlib
 
-    devices = list(mesh.devices.flat) if mesh is not None else jax.devices()
+    devices = _mesh_devices(mesh)
     payload = {
         "format": AOT_CACHE_FORMAT,
         "model": _jsonable(_dc.asdict(model_config)),
@@ -186,18 +190,28 @@ def _discard_tmp(tmp_name: Optional[str]) -> None:
         pass
 
 
-def _reset_jax_compilation_cache() -> None:
-    """Drop jax's in-memory compilation-cache state so the enable-flag is
-    re-consulted on the next compile (is_cache_used latches its verdict
-    once per process; without the reset a disable toggle is ignored after
-    any cached compile has happened).  Private-API guarded: on a jax that
-    moved it, the AOT cache degrades to verified stores (see store())."""
-    try:
-        from jax._src import compilation_cache as _cc
+def _compile_fresh(lowered):
+    """Compile `lowered` with a genuine backend compile.  An executable
+    that jax's own persistent compilation cache returned on a HIT is itself
+    deserialized, and serialize(deserialized) is lossy on the CPU backend
+    (jax 0.9.0: the reloaded entry dies at its first dispatch with
+    "Function <fusion> not found"), so the artifact this module persists
+    must never come from that cache.  With jax's cache unconfigured (the
+    serving default) this is a plain compile; with it configured (the test
+    suite's conftest) the cache is switched off around the compile — and
+    reset, because jax latches its is-the-cache-used verdict per process."""
+    if not jax.config.jax_compilation_cache_dir:
+        return lowered.compile()
+    from jax.experimental.compilation_cache import compilation_cache as cc
 
-        _cc.reset_cache()
-    except Exception as exc:  # noqa: BLE001 — best-effort; store() verifies
-        logger.debug("jax compilation-cache reset unavailable: %s", exc)
+    prev = jax.config.jax_enable_compilation_cache
+    try:
+        jax.config.update("jax_enable_compilation_cache", False)
+        cc.reset_cache()
+        return lowered.compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", prev)
+        cc.reset_cache()
 
 
 @dataclass
@@ -235,6 +249,11 @@ class AOTExecutableCache:
         self.digest = aot_cache_key(model_config, engine_config, mesh)
         self.root = os.path.join(cache_dir, self.digest)
         self.label = label
+        # deserialization binds an executable to the devices it runs on;
+        # left to its default that is EVERY device of the backend, and a
+        # one-device engine on an eight-device host then fails its first
+        # dispatch ("expected 8 shards")
+        self._devices = _mesh_devices(mesh)
         self.stats = AOTCacheStats()
         os.makedirs(self.root, exist_ok=True)
         self._write_meta(model_config, engine_config)
@@ -332,7 +351,8 @@ class AOTExecutableCache:
                     f"{entry.get('jax')} vs {AOT_CACHE_FORMAT}/{jax.__version__}"
                 )
             compiled = _se.deserialize_and_load(
-                entry["payload"], entry["in_tree"], entry["out_tree"])
+                entry["payload"], entry["in_tree"], entry["out_tree"],
+                execution_devices=self._devices)
         except Exception as exc:  # noqa: BLE001 — any deserialization
             # failure (truncated write, pickle drift, backend skew) must
             # degrade to a compile, not a crashed replica start
@@ -359,12 +379,13 @@ class AOTExecutableCache:
             from jax.experimental import serialize_executable as _se
 
             payload, in_tree, out_tree = _se.serialize(compiled)
-            # round-trip verification BEFORE persisting: CPU executable
-            # serialization is lossy for executables that were themselves
-            # deserialized (jax-cache hits), and a silently-poisoned entry
-            # would force a compile on every future restart while looking
-            # cached.  A payload that cannot load back is never written.
-            _se.deserialize_and_load(payload, in_tree, out_tree)
+            # round-trip verification BEFORE persisting: a payload that
+            # cannot load back would force a compile on every future
+            # restart while looking cached, so it is never written.  (It
+            # cannot catch a payload that loads but fails to run — the
+            # jax-cache-hit case _compile_fresh exists to prevent.)
+            _se.deserialize_and_load(
+                payload, in_tree, out_tree, execution_devices=self._devices)
             entry = {
                 "format": AOT_CACHE_FORMAT,
                 "jax": jax.__version__,
@@ -464,38 +485,7 @@ class AOTProgram:
         t0 = time.perf_counter()
         lowered = self._jit.lower(*args)
         t1 = time.perf_counter()
-        # CPU-only: this xla's thunk-runtime executable serialization is
-        # not self-contained for large programs — deserialization dies
-        # with "Symbols not found: [<fusion kernels>]" (JIT-resolved
-        # symbols are not embedded in the payload; reproduced under the
-        # test suite's 8-virtual-device platform).  The legacy runtime
-        # plus single-module codegen serializes whole.  Scoped to
-        # AOT-cached builds; TPU executables serialize self-contained.
-        options = (
-            {
-                "xla_cpu_use_thunk_runtime": False,
-                "xla_cpu_parallel_codegen_split_count": 1,
-            }
-            if jax.default_backend() == "cpu" else None
-        )
-        # bypass jax's own persistent compilation cache for THIS compile:
-        # an executable returned from a cache HIT is itself deserialized,
-        # and serialize(deserialized) is LOSSY on CPU (the payload drops
-        # the JIT-resolved symbols -> "Symbols not found" on the next
-        # start), so the artifact we persist must come from a genuine
-        # backend compile.  Toggling the flag alone is not enough: once
-        # jax's cache object is initialized, reads keep happening — so
-        # reset the latch too (it re-initializes on the next ordinary jit
-        # compile).  The two caches are redundant here anyway — ours is
-        # the one keyed for replica reuse.
-        prev = jax.config.jax_enable_compilation_cache
-        try:
-            jax.config.update("jax_enable_compilation_cache", False)
-            _reset_jax_compilation_cache()
-            compiled = lowered.compile(options)
-        finally:
-            jax.config.update("jax_enable_compilation_cache", prev)
-            _reset_jax_compilation_cache()
+        compiled = _compile_fresh(lowered)
         t2 = time.perf_counter()
         stats.trace_s += t1 - t0
         stats.compile_s += t2 - t1

@@ -118,10 +118,7 @@ class _CompileCounting:
 
     def __call__(self, *args, **kwargs):
         out = self._fn(*args, **kwargs)
-        try:
-            n = self._fn._cache_size()
-        except AttributeError:  # older jax: counting degrades to a no-op
-            return out
+        n = self._fn._cache_size()
         if n > self._seen:
             XLA_COMPILES.labels(program=self._name).inc(n - self._seen)
             self._seen = n
@@ -265,12 +262,10 @@ def program_defs(model_config, engine_config, mesh, spec_k=None) -> dict:
 
         from jax.sharding import PartitionSpec as _P
 
-        from ..parallel.sharding import shard_map
-
         from ..parallel.ring_attention import ring_attention
 
         qkv_spec = _P(None, shd.SEQ_AXIS, shd.MODEL_AXIS, None)
-        ring_fn = shard_map(
+        ring_fn = jax.shard_map(
             _partial(
                 ring_attention,
                 axis_name=shd.SEQ_AXIS,
@@ -563,7 +558,8 @@ def program_defs(model_config, engine_config, mesh, spec_k=None) -> dict:
         Kp = k_drafts + 1
         kernel_possible = cfg.use_pallas or (
             cfg.use_pallas is None
-            and _should_use_ragged_pallas(mc.head_dim, jax.default_backend())
+            and _should_use_ragged_pallas(
+                mc.head_dim, jax.default_backend(), _quantized)
         )
         align = RAGGED_BQ if kernel_possible else 1
         sp = dense_stride_for(Kp, align)  # padded slice stride
@@ -738,18 +734,10 @@ def program_defs(model_config, engine_config, mesh, spec_k=None) -> dict:
         # keep the staged legacy programs (use_ragged forces off there)
         defs["mixed"] = (_make_mixed(), (8,))
         if spec_k is not None:
-            # kv_pages (3) is the device-resident carry the engine threads
-            # dispatch to dispatch.  The draft table (8) is deliberately
-            # NOT donated: on jaxlib 0.4.36's CPU runtime, donating a
-            # buffer that the program updates in place via scatter inside
-            # a scan corrupts the heap (nondeterministic segfault/abort at
-            # later allocation sites — reproduced at 50-100% per
-            # tests/test_spec_decode.py run, in-bounds indices included,
-            # while kv_pages-only donation is clean under the same loop).
-            # The copy this buys back is one
-            # [B, V] int32 per dispatch; re-donate after a jaxlib upgrade
-            # proves clean under the same stress loop.
-            defs["mixed_decode"] = (_make_mixed_decode(int(spec_k)), (3,))
+            # kv_pages (3) and the draft table (8) are the device-resident
+            # carries the engine threads dispatch to dispatch — both
+            # donated, both updated in place
+            defs["mixed_decode"] = (_make_mixed_decode(int(spec_k)), (3, 8))
     return defs
 
 

@@ -111,6 +111,32 @@ class DataParallelEngine:
     def draining(self) -> bool:
         return any(eng.draining for eng in self.replicas)
 
+    @property
+    def on_loop_crash(self):
+        return self.replicas[0].on_loop_crash
+
+    @on_loop_crash.setter
+    def on_loop_crash(self, hook) -> None:
+        """Any replica's run loop dying is fatal to the pod (same reasoning
+        as `wedged`): its slice of traffic would otherwise hang."""
+        for eng in self.replicas:
+            eng.on_loop_crash = hook
+
+    def scheduler_state(self, max_digests: int = 512) -> dict:
+        """The pod's load in the single-engine shape the REST state handler
+        and the EPP read (summed queues and pages), with every replica's
+        own snapshot — its devices included — under "replicas"."""
+        states = [eng.scheduler_state(max_digests) for eng in self.replicas]
+        return {
+            "queue_depth": sum(s["queue_depth"] for s in states),
+            "inflight": sum(s["inflight"] for s in states),
+            "free_pages": sum(s["free_pages"] for s in states),
+            "page_size": self.config.page_size,
+            "running": self.running,
+            "wedged": self.wedged,
+            "replicas": states,
+        }
+
     async def drain(self, deadline=None, clock=None,
                     poll_s: float = 0.01) -> list:
         """Drain every dp group concurrently against the shared budget;

@@ -43,18 +43,21 @@ class KVCacheConfig:
         return 2 * self.n_kv_heads * self.page_size * self.head_dim * itemsize
 
 
+def device_filler(sharding, shape, dtype, value):
+    """A function creating one `shape` array of `value` ON `sharding` (None
+    = the default device): the fill runs under jit with out_shardings, so a
+    cache sized for the whole mesh is never staged on one device and then
+    moved.  One compiled program however often it is called."""
+    return jax.jit(
+        lambda: jnp.full(shape, value, dtype), out_shardings=sharding)
+
+
 def init_kv_pages(config: KVCacheConfig, sharding=None) -> List[jnp.ndarray]:
     """[n_layers] list of page-major K/V pages:
     [num_pages, 2, n_kv_heads, page_size, head_dim]."""
     shape = (config.num_pages, 2, config.n_kv_heads, config.page_size, config.head_dim)
-    dtype = jnp.dtype(config.dtype)
-    pages = []
-    for _ in range(config.n_layers):
-        arr = jnp.zeros(shape, dtype=dtype)
-        if sharding is not None:
-            arr = jax.device_put(arr, sharding)
-        pages.append(arr)
-    return pages
+    make = device_filler(sharding, shape, jnp.dtype(config.dtype), 0)
+    return [make() for _ in range(config.n_layers)]
 
 
 class PageAllocator:
@@ -105,29 +108,6 @@ def pages_needed(n_tokens: int, page_size: int) -> int:
     return (n_tokens + page_size - 1) // page_size
 
 
-def write_prompt_kv(
-    kv_pages: jnp.ndarray,  # [num_pages, 2, n_kv, ps, d]
-    k: jnp.ndarray,  # [T, n_kv, d]
-    v: jnp.ndarray,  # [T, n_kv, d]
-    page_ids: jnp.ndarray,  # [max_pages_this_seq] int32 (padded with 0)
-    n_tokens: jnp.ndarray,  # scalar int32: valid token count
-    page_size: int,
-) -> jnp.ndarray:
-    """Scatter a prefilled prompt's K/V into its pages.  Writes the full
-    padded T; positions >= n_tokens land on the null page (page 0)."""
-    T = k.shape[0]
-    t = jnp.arange(T, dtype=jnp.int32)
-    valid = t < n_tokens
-    page_of_t = jnp.where(valid, page_ids[t // page_size], 0)
-    slot_of_t = t % page_size
-    kv = jnp.stack([k, v]).astype(kv_pages.dtype)  # [2, T, n_kv, d]
-    # non-adjacent advanced indices (dims 0,3) put the broadcast dim first:
-    # the updated slice has shape [T, 2, n_kv, d]
-    return kv_pages.at[page_of_t, :, :, slot_of_t, :].set(
-        kv.transpose(1, 0, 2, 3), mode="drop", unique_indices=False
-    )
-
-
 def write_prompt_kv_batch(
     kv_pages: jnp.ndarray,  # [num_pages, 2, n_kv, ps, d]
     k: jnp.ndarray,  # [B, T, n_kv, d]
@@ -172,25 +152,40 @@ def write_chunk_kv_batch(
 def _scatter_kv(kv_pages, k, v, pages_flat, slot_flat):
     """Scatter K/V rows (k/v: [N, ..., n_kv, d] flattened to [Nf, n_kv, d])
     into a plain or quantized ((int8 pages, scales)) cache at the given
-    flat (page, slot) indices; updated slice shape [Nf, 2, n_kv, d]."""
+    flat (page, slot) indices.
+
+    A ROW scatter: every [d] row is addressed by all four leading dims
+    (page, k/v, head, slot), so the update window is the minor dim alone.
+    Indexing (page, slot) with a [2, n_kv, d] window instead makes XLA's
+    TPU layout assignment move the slot dim out of the tiled minor pair —
+    the cache then no longer has the row-major layout the Pallas kernels'
+    page DMAs require, and every layer of every step copies the WHOLE
+    cache into the scatter's layout and back (at 4096 pages x 28 layers
+    that is 12 GiB of temporaries: the `mixed` program did not fit a
+    16 GB chip).  The head index is an iota, which GSPMD partitions along
+    the model-sharded head dim with no collective."""
     lead = int(np.prod(k.shape[:-2])) if k.ndim > 3 else k.shape[0]
     kf = k.reshape(lead, k.shape[-2], k.shape[-1])
     vf = v.reshape(lead, v.shape[-2], v.shape[-1])
+    page_ix = pages_flat[:, None, None]
+    kv_ix = jnp.arange(2, dtype=jnp.int32)[None, :, None]
+    head_ix = jnp.arange(kf.shape[1], dtype=jnp.int32)[None, None, :]
+    slot_ix = slot_flat[:, None, None]
     if isinstance(kv_pages, tuple):
         pages, scales = kv_pages
         qk, sk = quantize_rows(kf)  # [Nf, n_kv, d] int8, [Nf, n_kv]
         qv, sv = quantize_rows(vf)
         values = jnp.stack([qk, qv], axis=1)  # [Nf, 2, n_kv, d]
         svals = jnp.stack([sk, sv], axis=1)  # [Nf, 2, n_kv]
-        pages = pages.at[pages_flat, :, :, slot_flat, :].set(
+        pages = pages.at[page_ix, kv_ix, head_ix, slot_ix, :].set(
             values, mode="drop", unique_indices=False
         )
-        scales = scales.at[pages_flat, :, :, slot_flat].set(
+        scales = scales.at[page_ix, kv_ix, head_ix, slot_ix].set(
             svals, mode="drop", unique_indices=False
         )
         return pages, scales
     values = jnp.stack([kf, vf], axis=1).astype(kv_pages.dtype)
-    return kv_pages.at[pages_flat, :, :, slot_flat, :].set(
+    return kv_pages.at[page_ix, kv_ix, head_ix, slot_ix, :].set(
         values, mode="drop", unique_indices=False
     )
 
@@ -244,13 +239,8 @@ def append_token_kv(
 
 def init_kv_scales(config: KVCacheConfig, sharding=None) -> List[jnp.ndarray]:
     shape = (config.num_pages, 2, config.n_kv_heads, config.page_size)
-    out = []
-    for _ in range(config.n_layers):
-        arr = jnp.ones(shape, jnp.float32)
-        if sharding is not None:
-            arr = jax.device_put(arr, sharding)
-        out.append(arr)
-    return out
+    make = device_filler(sharding, shape, jnp.float32, 1)
+    return [make() for _ in range(config.n_layers)]
 
 
 def quantize_rows(x: jnp.ndarray) -> tuple:

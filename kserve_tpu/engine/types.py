@@ -37,10 +37,10 @@ class EngineConfig:
     max_batch_size: int = 8
     page_size: int = 16
     num_pages: int = 2048
-    # wedge detection (VERDICT round-2 weak #6): a device fetch exceeding
-    # this deadline marks the engine wedged — /v2/health/live goes red so
-    # the pod restarts instead of hanging forever.  Must exceed the worst
-    # first-call compile (~40s on chip); 300s is 3x slack over that.
+    # wedge detection: a device fetch exceeding this deadline marks the
+    # engine wedged — /v2/health/live goes red so the pod restarts instead
+    # of hanging forever.  A first dispatch compiles BEFORE the fetch is
+    # issued, so this bounds execution only, with wide slack.
     step_deadline_s: float = 300.0
     max_pages_per_seq: int = 128
     max_prefill_len: int = 1024
@@ -109,8 +109,8 @@ class EngineConfig:
     # False forces the gather.
     use_pallas: Optional[bool] = None
     # decode steps executed on-device per host round-trip (lax.scan inner
-    # loop).  >1 amortizes host<->device latency — essential when the chip
-    # sits behind a network tunnel; streaming granularity becomes K tokens.
+    # loop).  >1 amortizes the host<->device round-trip; streaming
+    # granularity becomes K tokens.
     steps_per_sync: int = 8
     # waiting requests prefilled together in one compiled call (padded to the
     # largest length bucket among them; batch padded to pow2)
@@ -233,8 +233,8 @@ def spec_decode_k_from_env() -> Optional[int]:
 
 
 class EngineWedgedError(RuntimeError):
-    """A device fetch exceeded step_deadline_s: the device tunnel is
-    assumed wedged; liveness fails until the pod restarts."""
+    """A device fetch exceeded step_deadline_s: the device is assumed
+    wedged; liveness fails until the pod restarts."""
 
 
 class _DeadlineFetcher:

@@ -1,6 +1,6 @@
 """Typed exceptions shared by the data plane and protocol layers.
 
-Parity: reference python/kserve/kserve/errors.py (exception taxonomy and the
+Parity: reference python/kserve/kserve/errors.py (exception hierarchy and the
 HTTP status codes each maps to); re-implemented for an aiohttp-based stack.
 """
 

@@ -111,6 +111,12 @@ class ModelServer:
         self._grpc_server: Optional[GRPCServer] = None
         self._engine_tasks: List[asyncio.Task] = []
         self._grpc_task: Optional[asyncio.Task] = None
+        # set (with the cause in _fatal) when an engine fails to start or
+        # its run loop dies: the blocking entrypoint then shuts down and
+        # raises, so the process exits non-zero instead of idling
+        # live-but-never-ready
+        self._stop_event = asyncio.Event()
+        self._fatal: Optional[BaseException] = None
         # replica lifecycle (kserve_tpu/lifecycle — docs/lifecycle.md):
         # STARTING -> READY after start_async; SIGTERM / POST /admin/drain
         # -> DRAINING (readiness red, admission 503, in-flight gets the
@@ -155,7 +161,7 @@ class ModelServer:
         for model in engine_models:
             task = asyncio.create_task(_start_engine(model))
             task.add_done_callback(
-                lambda _t, m=model: self._wire_stall_hook(m))
+                lambda t, m=model: self._engine_started(t, m))
             self._engine_tasks.append(task)
         self._rest_server = RESTServer(
             self.dataplane,
@@ -176,6 +182,29 @@ class ModelServer:
             )
             self._grpc_task = asyncio.create_task(self._grpc_server.start(self.max_threads))
         self.lifecycle.mark_ready()
+
+    def _engine_started(self, task: asyncio.Task, model) -> None:
+        """Done-callback of a model's engine start: a failed start is fatal
+        (a replica that cannot run its programs must not stay up), a good
+        one wires the engine's stall and loop-crash hooks."""
+        if task.cancelled():
+            return
+        exc = task.exception()
+        if exc is not None:
+            self._on_fatal(exc)
+            return
+        self._wire_stall_hook(model)
+        engine = getattr(model, "engine", None)
+        if hasattr(engine, "on_loop_crash"):
+            engine.on_loop_crash = self._on_fatal
+
+    def _on_fatal(self, exc: BaseException) -> None:
+        if self._fatal is None:
+            self._fatal = exc
+            logger.error(
+                "fatal engine failure (%s: %s): shutting the server down",
+                type(exc).__name__, exc)
+        self._stop_event.set()
 
     def _wire_stall_hook(self, model) -> None:
         """Gray-failure watchdog wiring (docs/resilience.md): a confirmed
@@ -291,20 +320,23 @@ class ModelServer:
 
         async def serve():
             await self.start_async(models)
-            stop_event = asyncio.Event()
             loop = asyncio.get_event_loop()
-            handler = self._make_signal_handler(stop_event)
+            handler = self._make_signal_handler(self._stop_event)
             for sig in (signal.SIGINT, signal.SIGTERM):
                 try:
                     loop.add_signal_handler(sig, handler)
                 except NotImplementedError:  # pragma: no cover (non-unix)
                     pass
-            await stop_event.wait()
-            await self.drain_async()
+            await self._stop_event.wait()
+            if self._fatal is None:
+                await self.drain_async()
             logger.info("Stopping servers (grace period %ss)", self.grace_period)
             await self.stop_async()
 
         asyncio.run(serve())
+        if self._fatal is not None:
+            raise RuntimeError(
+                "engine failed; the server has shut down") from self._fatal
 
     def _child_main(self, models: List[BaseModel]) -> None:
         # one gRPC listener is enough; REST shares the port via SO_REUSEPORT

@@ -308,13 +308,21 @@ class LlamaConfig:
 
 
 def init_params(config: LlamaConfig, rng: jax.Array, scale: float = 0.02,
-                weight_quant: str = "none") -> Params:
+                weight_quant: str = "none", shardings=None) -> Params:
     """Random-initialized parameter pytree (bench/tests; real serving loads
     checkpoints via load_hf_weights).
 
     weight_quant="int8" emits quantized leaves DIRECTLY (random int8 +
     constant scales matching `scale`'s distribution) — an 8B random init
-    must never stage the bf16 tree on a 16-GB chip just to quantize it."""
+    must never stage the bf16 tree on a 16-GB chip just to quantize it.
+
+    Every layer (and the embedding/head group) is generated under jit, and
+    `shardings` — a pytree of jax shardings matching the result
+    (parallel/sharding.init_params_on_mesh) — becomes that jit's
+    out_shardings: leaves are created ON their devices, so no device ever
+    holds more than its own shard plus one layer's f32 temporaries.  A
+    model that only fits sharded (Llama-3-8B bf16 at tp=4) starts; None
+    places everything on the default device."""
     dtype = jnp.dtype(config.dtype)
     h, hd = config.hidden_size, config.head_dim
     nq, nkv = config.n_heads, config.n_kv_heads
@@ -333,11 +341,10 @@ def init_params(config: LlamaConfig, rng: jax.Array, scale: float = 0.02,
     if quant and config.n_experts > 0:
         raise NotImplementedError("weight_quant over MoE experts")
     dense = (lambda key, shape: dense_q(key, shape)) if quant else dense_f32
+    norm_init = jnp.zeros if config.norm_plus_one else jnp.ones
 
-    layers = []
-    for i in range(config.n_layers):
-        k = jax.random.split(keys[i], 8)
-        norm_init = jnp.zeros if config.norm_plus_one else jnp.ones
+    def make_layer(window: int, key):
+        k = jax.random.split(key, 8)
         layer = {
             "attn_norm": norm_init((h,), dtype),
             "wq": dense(k[0], (h, nq * hd)),
@@ -368,23 +375,36 @@ def init_params(config: LlamaConfig, rng: jax.Array, scale: float = 0.02,
             layer["post_attn_norm"] = jnp.zeros((h,), dtype)
             layer["post_mlp_norm"] = jnp.zeros((h,), dtype)
         if config.sliding_window > 0:
-            layer["attn_window"] = jnp.asarray(
-                config.layer_window(i), jnp.int32)
-        layers.append(layer)
-    params: Params = {
-        # tied quantized embeddings carry per-ROW scales (they serve as the
-        # transposed lm_head); untied embeddings stay bf16 (gather-only)
-        "embed": (
-            dense_q(keys[-2], (config.vocab_size, h), channel_axis=0)
-            if quant and config.tie_word_embeddings
-            else dense_f32(keys[-2], (config.vocab_size, h))
-        ),
-        "final_norm": (jnp.zeros if config.norm_plus_one else jnp.ones)(
-            (h,), dtype),
-        "layers": layers,
-    }
-    if not config.tie_word_embeddings:
-        params["lm_head"] = dense(keys[-1], (h, config.vocab_size))
+            layer["attn_window"] = jnp.asarray(window, jnp.int32)
+        return layer
+
+    def make_top(embed_key, head_key):
+        top: Params = {
+            # tied quantized embeddings carry per-ROW scales (they serve as
+            # the transposed lm_head); untied embeddings stay bf16
+            # (gather-only)
+            "embed": (
+                dense_q(embed_key, (config.vocab_size, h), channel_axis=0)
+                if quant and config.tie_word_embeddings
+                else dense_f32(embed_key, (config.vocab_size, h))
+            ),
+            "final_norm": norm_init((h,), dtype),
+        }
+        if not config.tie_word_embeddings:
+            top["lm_head"] = dense(head_key, (h, config.vocab_size))
+        return top
+
+    # all layers share shapes and shardings: one compiled program per
+    # distinct (static) window value, reused across the layers
+    layer_fn = jax.jit(
+        make_layer, static_argnums=0,
+        out_shardings=None if shardings is None else shardings["layers"][0])
+    layers = [layer_fn(config.layer_window(i), keys[i])
+              for i in range(config.n_layers)]
+    top_sharding = None if shardings is None else {
+        k: v for k, v in shardings.items() if k != "layers"}
+    params = jax.jit(make_top, out_shardings=top_sharding)(keys[-2], keys[-1])
+    params["layers"] = layers
     return params
 
 

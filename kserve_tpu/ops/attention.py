@@ -199,15 +199,12 @@ def paged_attention_xla(
     return out[:, 0].astype(q.dtype)
 
 
-# Auto-dispatch threshold, in page-table width (pages).  Measured e2e on one
-# v5e chip (B=48, bench_1b, page_size=16, 2026-07-29):
-#   width 16 (256-tok ctx):  gather 1671 tok/s  vs kernel 1146  -> gather
-#   width 40 (640-tok ctx):  gather  847 tok/s  vs kernel  809  -> gather
-#   width 72 (1152-tok ctx): gather  603 tok/s  vs kernel  636  -> kernel
-# The gather path writes a [B, width*ps, nkv, d] copy of the live KV before
-# attention; the kernel streams pages once.  The copy's extra traffic grows
-# with width, the kernel's serial per-sequence grid cost does not — they
-# cross between 40 and 72 pages.
+# Auto-dispatch threshold, in page-table width (pages): the gather path
+# writes a [B, width*ps, nkv, d] copy of the live KV before attention, the
+# kernel streams pages once, so the copy's extra traffic grows with width
+# while the kernel's serial per-block grid cost does not.  Where the two
+# cross on this installation is not measured (PERF.md); 64 is carried from
+# the legacy decode programs and re-deriving it is a perf_opt with a cell.
 PALLAS_MIN_PAGES = 64
 
 
@@ -267,7 +264,7 @@ def make_sharded_paged_attention(
     """
     from jax.sharding import PartitionSpec as P
 
-    from ..parallel.sharding import MODEL_AXIS, shard_map
+    from ..parallel.sharding import MODEL_AXIS
 
     if interpret and (windowed or scale is not None):
         # the interpret path exists to test the KERNEL's math on CPU, and
@@ -294,7 +291,7 @@ def make_sharded_paged_attention(
             logit_softcap=logit_softcap, use_pallas=use_pallas,
             scale=scale, window=window if windowed else None)
 
-    return shard_map(
+    return jax.shard_map(
         inner,
         mesh=mesh,
         in_specs=(q_spec, kv_spec, P(None, None), P(None), P()),
@@ -373,12 +370,16 @@ def ragged_paged_attention_xla(
     return out.astype(q.dtype)
 
 
-def _should_use_ragged_pallas(d: int, backend: str) -> bool:
-    """Auto-dispatch predicate for the ragged kernel: lane-aligned heads on
-    a TPU backend.  Unlike the decode kernel there is no gather-vs-kernel
-    width crossover — the ragged gather reference materializes [T, L, ...]
-    per token and is strictly a correctness/CPU path."""
-    return d % 128 == 0 and backend == "tpu"
+def _should_use_ragged_pallas(d: int, backend: str,
+                              quantized: bool = False) -> bool:
+    """Auto-dispatch predicate for the ragged kernel: lane-aligned heads and
+    unquantized pages on a TPU backend.  Unlike the decode kernel there is
+    no gather-vs-kernel width crossover — the ragged gather reference
+    materializes [T, L, ...] per token and is strictly a correctness/CPU
+    path.  int8 pages stay on the gather: Mosaic refuses the kernel's
+    per-page scale DMA (a [2, nkv, ps] f32 slice whose minor dim is below
+    the 128-lane tiling — docs/kernels.md)."""
+    return d % 128 == 0 and not quantized and backend == "tpu"
 
 
 def dense_stride_for(width: int, align: int) -> int:
@@ -424,14 +425,20 @@ def ragged_paged_attention(
 ) -> jnp.ndarray:
     """Dispatch the ragged contract between the fused Pallas kernel and the
     XLA gather reference.  The ragged kernel (unlike the decode kernel)
-    supports int8 KV pages, sliding windows and scale overrides natively,
-    so the dispatch is purely head-alignment + backend; use_pallas=True
-    forces the kernel (raising on unsupported head_dim), False forces the
-    reference."""
+    masks sliding windows and applies scale overrides natively, so the
+    dispatch is head-alignment + page dtype + backend; use_pallas=True
+    forces the kernel (raising on an unsupported head_dim or int8 pages),
+    False forces the reference."""
     d = q.shape[-1]
+    quantized = isinstance(kv_pages, tuple)
     if use_pallas is None:
-        use_pallas = _should_use_ragged_pallas(d, jax.default_backend())
+        use_pallas = _should_use_ragged_pallas(
+            d, jax.default_backend(), quantized)
     if use_pallas:
+        if quantized:
+            raise ValueError(
+                "the ragged pallas kernel does not compile over the int8 "
+                "KV cache (docs/kernels.md)")
         from .pallas_paged_attention import ragged_paged_attention_pallas
 
         return ragged_paged_attention_pallas(
@@ -466,7 +473,7 @@ def make_sharded_ragged_attention(
     q_len [B], kv_start [B], window [] int32) -> [T,nq,d]."""
     from jax.sharding import PartitionSpec as P
 
-    from ..parallel.sharding import MODEL_AXIS, shard_map
+    from ..parallel.sharding import MODEL_AXIS
 
     q_spec = P(None, MODEL_AXIS, None)
     kv_spec = P(None, None, MODEL_AXIS, None, None)
@@ -486,7 +493,7 @@ def make_sharded_ragged_attention(
             logit_softcap=logit_softcap, use_pallas=use_pallas,
             scale=scale, window=window, dense_stride=dense_stride)
 
-    return shard_map(
+    return jax.shard_map(
         inner,
         mesh=mesh,
         in_specs=(q_spec, kv_spec, P(None, None), P(None), P(None),
@@ -494,6 +501,42 @@ def make_sharded_ragged_attention(
         out_specs=q_spec,
         check_vma=False,
     )
+
+
+def describe_attention_dispatch(model_config, engine_config,
+                                backend: str) -> dict:
+    """Which implementation each program's attention is built with, from
+    the SAME predicates the dispatch functions above consult at trace time
+    — the engine logs this at start and serves it on
+    /v1/internal/scheduler/state, so a TPU replica that quietly serves
+    from an `*_xla` path (a correctness/CPU path) is visible.
+
+    `mixed` is the ragged attention of the unified program; `decode` is
+    the single-token decode attention (the mixed program's scan tail AND
+    the legacy decode programs a logprobs/penalty lane falls back to),
+    whose kernel is additionally width-gated per compiled shape."""
+    mc, cfg = model_config, engine_config
+    quantized = cfg.kv_quant == "int8"
+    if cfg.use_pallas is None:
+        ragged = _should_use_ragged_pallas(mc.head_dim, backend, quantized)
+        decode = (
+            mc.sliding_window <= 0 and mc.attn_scale is None
+            and _should_use_pallas(
+                mc.head_dim, quantized, PALLAS_MIN_PAGES,
+                cfg.max_batch_size, backend, cfg.page_size))
+    else:
+        ragged = decode = bool(cfg.use_pallas)
+    return {
+        "backend": backend,
+        "mixed": "pallas_ragged" if ragged else "xla_ragged_gather",
+        "decode": "pallas_decode" if decode else "xla_gather",
+        # the decode kernel only replaces the gather from this page-table
+        # width up (auto-dispatch); below it every shape takes the gather
+        "decode_pallas_min_pages": (
+            PALLAS_MIN_PAGES if decode and cfg.use_pallas is None else None),
+        # tp/sp>1: both run per shard inside shard_map over the model axis
+        "shard_map": cfg.tp > 1 or cfg.sp > 1,
+    }
 
 
 def paged_attention(

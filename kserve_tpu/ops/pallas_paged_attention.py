@@ -33,6 +33,7 @@ paths in interpret mode; bench exercises the compiled kernels on hardware).
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -42,14 +43,7 @@ from jax.experimental.pallas import tpu as pltpu
 NBUF = 4  # VMEM ring depth (iterations in flight); NBUF-1 ahead
 MAX_SB = 8  # sequences per grid step (VMEM budget: NBUF*SB pages resident)
 
-# jax>=0.5 renamed pltpu.TPUMemorySpace -> MemorySpace (and the HBM member
-# replaced ANY as the name for "stay in device memory, no VMEM block").
-# The 0.4.x fallback keeps interpret-mode tests runnable on CI images that
-# pin the older jax.
-if hasattr(pltpu, "MemorySpace"):
-    _HBM = pltpu.MemorySpace.HBM
-else:  # jax 0.4.x
-    _HBM = pltpu.TPUMemorySpace.ANY
+_HBM = pltpu.MemorySpace.HBM  # stay in device memory, no VMEM block
 
 
 def _pick_sb(B: int) -> int:
@@ -60,6 +54,21 @@ def _pick_sb(B: int) -> int:
         if B % sb == 0:
             return sb
     return 1
+
+
+def _heads_dot(a, b, contract_b: int):
+    """`a` [..., M, K] against `b` batched over EVERY leading dim: scores
+    (contract_b=2: b is [..., N, K]) or pv (contract_b=1: b is [..., K, N]).
+    Mosaic's tpu.matmul takes at most ONE batch dim, so the leading dims
+    ([SB, nkv] / [L, nkv]) fold into one for the dot and unfold after —
+    leading-dim reshapes leave the tiled minor dims untouched."""
+    lead = a.shape[:-2]
+    out = jax.lax.dot_general(
+        a.reshape((-1,) + a.shape[-2:]), b.reshape((-1,) + b.shape[-2:]),
+        dimension_numbers=(((2,), (contract_b,)), ((0,), (0,))),
+        preferred_element_type=jnp.float32,
+    )
+    return out.reshape(lead + out.shape[-2:])
 
 
 # ---- DMA-ring scaffolding shared by both kernel variants ----
@@ -114,11 +123,15 @@ def _ring_wait_and_refill(start_iter, kv_hbm_ref, kv_bufs, sems, sb, i,
     return slot
 
 
-def _block_lens(seq_lens_ref, g, sb):
-    """Per-row valid lengths [SB, 1, 1, 1] for masking."""
-    return jnp.stack(
-        [seq_lens_ref[g * sb + s] for s in range(sb)]
-    ).reshape(sb, 1, 1, 1)
+def _per_row(ref, base, n):
+    """SMEM scalars ref[base .. base+n) as an int32 [n, 1, 1, 1] vector for
+    masking.  Built by select-on-iota: Mosaic has no layout for the
+    [n] -> [n, 1, 1, 1] shape cast a stack+reshape would need."""
+    row = jax.lax.broadcasted_iota(jnp.int32, (n, 1, 1, 1), 0)
+    out = jnp.zeros((n, 1, 1, 1), jnp.int32)
+    for r in range(n):
+        out = jnp.where(row == r, ref[base + r], out)
+    return out
 
 
 def _pallas_call(kernel, B, sb, nq, lane, kv_arr):
@@ -175,7 +188,7 @@ def _decode_kernel(
 
     # q per kv-head group: [SB, nkv, group, d] f32
     q = q_ref[...].astype(jnp.float32).reshape(sb, num_kv_heads, group, head_dim)
-    lens = _block_lens(seq_lens_ref, g, sb)
+    lens = _per_row(seq_lens_ref, g * sb, sb)
 
     def body(i, carry):
         m, l, acc = carry
@@ -184,11 +197,7 @@ def _decode_kernel(
 
         k = kv_bufs[slot, :, 0].astype(jnp.float32)  # [SB, nkv, ps, d]
         v = kv_bufs[slot, :, 1].astype(jnp.float32)
-        s_ = jax.lax.dot_general(
-            q, k,
-            dimension_numbers=(((3,), (3,)), ((0, 1), (0, 1))),
-            preferred_element_type=jnp.float32,
-        ) * scale  # [SB, nkv, group, ps]
+        s_ = _heads_dot(q, k, 2) * scale  # [SB, nkv, group, ps]
         if logit_softcap > 0.0:
             s_ = jnp.tanh(s_ / logit_softcap) * logit_softcap
         token_pos = i * page_size + jax.lax.broadcasted_iota(
@@ -199,11 +208,7 @@ def _decode_kernel(
         alpha = jnp.exp(m - m_new)
         p = jnp.exp(s_ - m_new)
         l_new = l * alpha + p.sum(axis=-1, keepdims=True)
-        pv = jax.lax.dot_general(
-            p, v,
-            dimension_numbers=(((3,), (2,)), ((0, 1), (0, 1))),
-            preferred_element_type=jnp.float32,
-        )  # [SB, nkv, group, d]
+        pv = _heads_dot(p, v, 1)  # [SB, nkv, group, d]
         acc_new = acc * alpha + pv
         return m_new, l_new, acc_new
 
@@ -262,7 +267,7 @@ def _packed_decode_kernel(
     q2 = q_ref[...].astype(jnp.float32).reshape(
         sb, num_kv_heads, group, 128
     )
-    lens = _block_lens(seq_lens_ref, g, sb)
+    lens = _per_row(seq_lens_ref, g * sb, sb)
     lane = jax.lax.broadcasted_iota(jnp.int32, (1, 1, 1, 128), 3)
     mask_lo = (lane < 64).astype(jnp.float32)
     mask_hi = (lane >= 64).astype(jnp.float32)
@@ -277,11 +282,7 @@ def _packed_decode_kernel(
         row = jax.lax.broadcasted_iota(jnp.int32, (1, 1, 1, rows), 3)
 
         def scores(kmask, parity):
-            s_ = jax.lax.dot_general(
-                q2, k * kmask,
-                dimension_numbers=(((3,), (3,)), ((0, 1), (0, 1))),
-                preferred_element_type=jnp.float32,
-            ) * scale  # [SB, nkv, group, ps/2]
+            s_ = _heads_dot(q2, k * kmask, 2) * scale  # [SB, nkv, group, ps/2]
             if logit_softcap > 0.0:
                 s_ = jnp.tanh(s_ / logit_softcap) * logit_softcap
             pos = i * page_size + 2 * row + parity
@@ -304,13 +305,9 @@ def _packed_decode_kernel(
             + p_even.sum(axis=-1, keepdims=True)
             + p_odd.sum(axis=-1, keepdims=True)
         )
-        dims = (((3,), (2,)), ((0, 1), (0, 1)))
-        pv = jax.lax.dot_general(
-            p_even, v * mask_lo, dimension_numbers=dims,
-            preferred_element_type=jnp.float32,
-        ) + jax.lax.dot_general(
-            p_odd, v * mask_hi, dimension_numbers=dims,
-            preferred_element_type=jnp.float32,
+        pv = (
+            _heads_dot(p_even, v * mask_lo, 1)
+            + _heads_dot(p_odd, v * mask_hi, 1)
         )  # [SB, nkv, group, 128] — halves carry their parity's pv
         return m_new, l_new, acc * alpha + pv
 
@@ -514,11 +511,7 @@ def _ragged_kernel(
         if quantized:
             k = k * s_bufs[slot, 0].astype(jnp.float32)[..., None]
             v = v * s_bufs[slot, 1].astype(jnp.float32)[..., None]
-        s_ = jax.lax.dot_general(
-            q, k,
-            dimension_numbers=(((2,), (2,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32,
-        ) * scale  # [nkv, BQ*group, ps]
+        s_ = _heads_dot(q, k, 2) * scale  # [nkv, BQ*group, ps]
         if logit_softcap > 0.0:
             s_ = jnp.tanh(s_ / logit_softcap) * logit_softcap
         kpos = i * page_size + jax.lax.broadcasted_iota(
@@ -530,11 +523,7 @@ def _ragged_kernel(
         alpha = jnp.exp(m - m_new)
         p = jnp.exp(s_ - m_new)
         l_new = l * alpha + p.sum(axis=-1, keepdims=True)
-        pv = jax.lax.dot_general(
-            p, v,
-            dimension_numbers=(((2,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32,
-        )  # [nkv, BQ*group, d]
+        pv = _heads_dot(p, v, 1)  # [nkv, BQ*group, d]
         return m_new, l_new, acc * alpha + pv
 
     m0 = jnp.full((num_kv_heads, rows, 1), -1e30, jnp.float32)
@@ -592,18 +581,19 @@ def _dense_ragged_kernel(
     group = nq // num_kv_heads
     rows = sp * group
 
-    kv0 = jnp.stack(
-        [kv_start_ref[g * lanes + l] for l in range(lanes)]
-    ).reshape(lanes, 1, 1, 1)
-    qn = jnp.stack(
-        [q_len_ref[g * lanes + l] for l in range(lanes)]
-    ).reshape(lanes, 1, 1, 1)
+    kv0 = _per_row(kv_start_ref, g * lanes, lanes)
+    qn = _per_row(q_len_ref, g * lanes, lanes)
     # keys each lane needs; a lane with no valid query rows (inactive, or
     # capacity-starved mid-dispatch with a large kv_start) must not drive
     # the page loop — all its rows are masked, so streaming its history
-    # would be pure wasted DMA
-    kv_hi = jnp.where(qn > 0, kv0 + qn, 0)
-    max_hi = kv_hi.max()
+    # would be pure wasted DMA.  Scalar max over SMEM reads (like
+    # _block_pages): the loop bound must be a scalar, not a vector reduce.
+    max_hi = jnp.int32(0)
+    for l in range(lanes):
+        qn_l = q_len_ref[g * lanes + l]
+        max_hi = jnp.maximum(
+            max_hi,
+            jnp.where(qn_l > 0, kv_start_ref[g * lanes + l] + qn_l, 0))
     num_pages = (max_hi + page_size - 1) // page_size
 
     def start_iter(i, slot):
@@ -661,11 +651,7 @@ def _dense_ragged_kernel(
         if quantized:
             k = k * s_bufs[slot, :, 0].astype(jnp.float32)[..., None]
             v = v * s_bufs[slot, :, 1].astype(jnp.float32)[..., None]
-        s_ = jax.lax.dot_general(
-            q, k,
-            dimension_numbers=(((3,), (3,)), ((0, 1), (0, 1))),
-            preferred_element_type=jnp.float32,
-        ) * scale  # [L, nkv, rows, ps]
+        s_ = _heads_dot(q, k, 2) * scale  # [L, nkv, rows, ps]
         if logit_softcap > 0.0:
             s_ = jnp.tanh(s_ / logit_softcap) * logit_softcap
         kpos = i * page_size + jax.lax.broadcasted_iota(
@@ -677,11 +663,7 @@ def _dense_ragged_kernel(
         alpha = jnp.exp(m - m_new)
         p = jnp.exp(s_ - m_new)
         l_new = l_ * alpha + p.sum(axis=-1, keepdims=True)
-        pv = jax.lax.dot_general(
-            p, v,
-            dimension_numbers=(((3,), (2,)), ((0, 1), (0, 1))),
-            preferred_element_type=jnp.float32,
-        )  # [L, nkv, rows, d]
+        pv = _heads_dot(p, v, 1)  # [L, nkv, rows, d]
         return m_new, l_new, acc * alpha + pv
 
     m0 = jnp.full((lanes, num_kv_heads, rows, 1), -1e30, jnp.float32)

@@ -186,8 +186,6 @@ def pipeline_blocks(
     """Stage-sharded transformer stack WITH paged-KV state: the serving
     engine's pipeline-parallel execution path (engine pp>1).  Returns
     ([B, ...] outputs replicated over pipe, updated stacked pages)."""
-    from .sharding import shard_map
-
     B = x.shape[0]
     if B % n_microbatches != 0:
         raise ValueError(
@@ -202,7 +200,7 @@ def pipeline_blocks(
     # pages may be one stacked array OR an (int8 pages, scales) tuple
     # (kv_quant): spec the pytree leaf-wise
     pages_spec = jax.tree.map(lambda _: P(axis_name), stacked_pages)
-    fn = shard_map(
+    fn = jax.shard_map(
         partial(_pipeline_local_stateful, block_fn=block_fn,
                 axis_name=axis_name, S=S),
         mesh=mesh,
@@ -245,8 +243,6 @@ def pipeline_forward(
     The batch is split into `n_microbatches` along dim 0 (must divide B);
     output is the full [B, ...] result, replicated over the pipe axis.
     """
-    from .sharding import shard_map
-
     B = x.shape[0]
     if B % n_microbatches != 0:
         raise ValueError(f"batch {B} not divisible by {n_microbatches} microbatches")
@@ -259,7 +255,7 @@ def pipeline_forward(
     microbatches = x.reshape((n_microbatches, mb) + x.shape[1:])
 
     stage_spec = jax.tree.map(lambda _: P(PIPE_AXIS), stacked_params)
-    fn = shard_map(
+    fn = jax.shard_map(
         partial(_pipeline_local, layer_fn=layer_fn, axis_name=axis_name,
                 S=mesh.shape[axis_name]),
         mesh=mesh,

@@ -38,13 +38,8 @@ def ring_attention(
     B, C, nq, d = q.shape
     nkv = k.shape[2]
     group = nq // nkv
-    # lax.axis_size is newer-jax; on 0.4.x psum of the literal 1 constant-
-    # folds to the static axis size (it must be static: `perm` below is a
-    # host-side list comprehension)
-    if hasattr(lax, "axis_size"):
-        ring = lax.axis_size(axis_name)
-    else:
-        ring = int(lax.psum(1, axis_name))
+    # static (`perm` below is a host-side list comprehension)
+    ring = lax.axis_size(axis_name)
     my = lax.axis_index(axis_name)
     scale = 1.0 / jnp.sqrt(jnp.asarray(d, jnp.float32))
 

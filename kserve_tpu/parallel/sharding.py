@@ -16,36 +16,14 @@ needed inside a slice.
 
 from __future__ import annotations
 
-from typing import Any, Dict, List, Optional
+from functools import partial
+from typing import Any, Dict, Optional
 
 import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..models.llama import LlamaConfig
-
-# version-portable shard_map: promoted to `jax.shard_map` in newer jax,
-# only under jax.experimental in the pinned image (0.4.x).  Every in-repo
-# user imports it from HERE (ops/attention.py, engine/compiled.py,
-# parallel/pipeline.py, the parallel-ops tests) so the compat shim lives
-# in exactly one place — `from jax import shard_map` at module scope was
-# tier-1's standing collection error (test_parallel_ops.py).  On 0.4.x
-# the adapter also translates the renamed kwargs: check_vma -> check_rep,
-# and axis_names (manual axes) -> auto (its complement).
-try:
-    from jax import shard_map  # noqa: F401  (jax >= 0.6)
-except ImportError:  # jax 0.4.x
-    from jax.experimental.shard_map import shard_map as _legacy_shard_map
-
-    def shard_map(f, *, mesh, in_specs, out_specs,
-                  check_vma=None, axis_names=None):
-        kw = {}
-        if check_vma is not None:
-            kw["check_rep"] = check_vma
-        if axis_names is not None:
-            kw["auto"] = frozenset(mesh.axis_names) - frozenset(axis_names)
-        return _legacy_shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
 
 DATA_AXIS = "data"
 SEQ_AXIS = "seq"
@@ -223,9 +201,24 @@ def shard_params(params, config: LlamaConfig, mesh: Mesh):
     )
 
 
-def shard_kv_pages(kv_pages: List, mesh: Mesh) -> List:
-    sharding = named_canonical(mesh, kv_pages_pspec())
-    return [jax.device_put(p, sharding) for p in kv_pages]
+def init_params_on_mesh(config: LlamaConfig, rng: jax.Array, mesh: Mesh,
+                        weight_quant: str = "none"):
+    """Random-initialized params created SHARDED over `mesh` (never staged
+    whole on one device and then moved — models/llama.init_params)."""
+    from ..models import llama
+
+    init = partial(llama.init_params, config, weight_quant=weight_quant)
+    specs = expand_quant_specs(jax.eval_shape(init, rng), param_pspecs(config))
+    shardings = jax.tree.map(
+        lambda spec: NamedSharding(mesh, spec), specs,
+        is_leaf=lambda x: isinstance(x, P))
+    return init(rng, shardings=shardings)
+
+
+def kv_pages_sharding(mesh: Mesh) -> NamedSharding:
+    """The cache's canonical sharding (engine/kvcache.init_kv_pages creates
+    the pages directly on it)."""
+    return named_canonical(mesh, kv_pages_pspec())
 
 
 def named(mesh: Mesh, spec: P) -> NamedSharding:

@@ -97,7 +97,7 @@ _WORKER_SCRIPT = r"""
 import sys
 sys.path.insert(0, {repo!r})
 import os
-os.environ.setdefault("JAX_PLATFORM_NAME", "cpu")
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
 from kserve_tpu.model import Model
 from kserve_tpu.model_server import ModelServer
 

@@ -50,9 +50,7 @@ _WORKER = r"""
 import os, sys
 sys.path.insert(0, {repo!r})
 os.environ["JAX_PLATFORMS"] = "cpu"
-os.environ["JAX_PLATFORM_NAME"] = "cpu"
 import jax
-jax.config.update("jax_platforms", "cpu")
 from kserve_tpu.utils.distributed import maybe_initialize_distributed
 assert maybe_initialize_distributed() is True
 assert jax.process_count() == 2, jax.process_count()

@@ -9,7 +9,6 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from kserve_tpu.models.moe import MoEConfig, init_moe_params, moe_mlp, moe_param_pspecs
-from kserve_tpu.parallel.sharding import shard_map
 from kserve_tpu.ops.attention import causal_prefill_attention
 from kserve_tpu.parallel.ring_attention import ring_attention
 
@@ -27,7 +26,7 @@ class TestRingAttention:
 
         mesh = Mesh(np.asarray(jax.devices()[:ring]), ("seq",))
         seq_sharded = P(None, "seq", None, None)
-        fn = shard_map(
+        fn = jax.shard_map(
             lambda q, k, v, vl: ring_attention(q, k, v, vl, "seq"),
             mesh=mesh,
             in_specs=(seq_sharded, seq_sharded, seq_sharded, P(None)),

@@ -252,7 +252,6 @@ def _write_tiny_checkpoint(model_dir):
 def _boot(cmd, model_dir, port, extra=()):  # -> subprocess.Popen
     env = dict(os.environ)
     env.update(
-        JAX_PLATFORM_NAME="cpu",
         JAX_PLATFORMS="cpu",
         XLA_FLAGS="--xla_force_host_platform_device_count=8",
         PYTHONPATH=REPO,

@@ -1,0 +1,204 @@
+"""Pallas kernels against the TPU toolchain, without a chip.
+
+Two levels, both run on the CPU-only test host:
+
+- LOWERING (`lowering_platforms=("tpu",)`): runs the Pallas -> Mosaic MLIR
+  lowering and its verifier.  Catches what the lowering refuses (a
+  `tpu.matmul` with two batch dims took three of the four kernels down on
+  jax 0.9.0).  PASSING IT IS NOT PROOF OF COMPILATION: Mosaic proper runs
+  later, inside the XLA TPU compile.
+- COMPILE (libtpu's compile-only v5e topology, when the installation has
+  it): runs that XLA TPU compile, Mosaic included.  Catches unsupported
+  shape casts, misaligned DMA slices and layout conflicts with the
+  surrounding XLA program.  Passing it is not proof of correct numerics or
+  of fitting a real chip's run-time memory — `chip_smoke.py` checks the
+  kernels against their XLA references on the device.
+
+Shapes are the published head shapes of the presets the server offers.
+"""
+
+import functools
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+from kserve_tpu.engine import kvcache
+from kserve_tpu.ops import attention as att
+from kserve_tpu.ops import pallas_paged_attention as pk
+
+# the suite's persistent compile cache stores these TPU executables too, and
+# a compile-only client cannot load them back: jax warns and recompiles
+pytestmark = pytest.mark.filterwarnings(
+    "ignore:Error reading persistent compilation cache entry")
+
+#: (n_q_heads, n_kv_heads, head_dim) a kernel sees on ONE device
+HEAD_SHAPES = {
+    "qwen3-0.6b": (16, 8, 128),
+    "llama3-8b": (32, 8, 128),
+    "llama3-8b/tp4": (8, 2, 128),  # one tp=4 shard
+    "llama3.2-1b": (32, 8, 64),  # packed decode kernel only (d % 128 != 0)
+    "gemma2-2b": (8, 4, 256),
+}
+PAGE_SIZES = (8, 16)
+B, W, NUM_PAGES, T = 8, 64, 128, 64
+
+
+@functools.lru_cache(maxsize=None)
+def _tpu_sharding():
+    """A one-device sharding on libtpu's compile-only v5e topology, or None
+    where the installation cannot describe one."""
+    try:
+        from jax.experimental import topologies
+
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except (RuntimeError, ImportError, ValueError):
+        # no libtpu, or one that cannot describe a topology without a chip
+        return None
+    return NamedSharding(Mesh(np.array(topo.devices[:1]), ("x",)), P())
+
+
+def _abstract(shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=_tpu_sharding())
+
+
+def _check(fn, *args, compiles=True):
+    """Lower `fn` for TPU; then, where a compile-only topology exists,
+    compile it — or assert Mosaic still refuses it (`compiles=False`)."""
+    jax.jit(fn).trace(*args).lower(lowering_platforms=("tpu",))
+    if _tpu_sharding() is None:
+        return
+    lowered = jax.jit(fn).lower(*args)
+    if compiles:
+        lowered.compile()
+    else:
+        with pytest.raises(Exception):  # noqa: B017 — a MosaicError
+            lowered.compile()
+
+
+def _cache(nkv, ps, d, dtype=jnp.bfloat16):
+    return _abstract((NUM_PAGES, 2, nkv, ps, d), dtype)
+
+
+def _i32(*shape):
+    return _abstract(shape, jnp.int32)
+
+
+@pytest.mark.parametrize("ps", PAGE_SIZES)
+@pytest.mark.parametrize("model", sorted(HEAD_SHAPES))
+class TestKernelsLowerAndCompileForTpu:
+    def test_decode_kernel(self, model, ps):
+        """_decode_kernel, and _packed_decode_kernel at head_dim 64."""
+        nq, nkv, d = HEAD_SHAPES[model]
+        _check(pk.paged_attention_pallas,
+               _abstract((B, nq, d), jnp.bfloat16), _cache(nkv, ps, d),
+               _i32(B, W), _i32(B))
+
+    def test_ragged_kernel(self, model, ps):
+        """_ragged_kernel, full attention and windowed."""
+        nq, nkv, d = HEAD_SHAPES[model]
+        if d % 128:
+            pytest.skip("ragged kernel needs head_dim % 128 == 0")
+        args = (_abstract((T, nq, d), jnp.bfloat16), _cache(nkv, ps, d),
+                _i32(B, W), _i32(B), _i32(B), _i32(B))
+        _check(pk.ragged_paged_attention_pallas, *args)
+        _check(
+            lambda *a: pk.ragged_paged_attention_pallas(
+                *a[:-1], window=a[-1], logit_softcap=50.0, scale=0.0625),
+            *args, _i32())
+
+    @pytest.mark.parametrize("stride", [1, 2, 4])
+    def test_dense_ragged_kernel(self, model, ps, stride):
+        """_dense_ragged_kernel at every dense stride below RAGGED_BQ."""
+        nq, nkv, d = HEAD_SHAPES[model]
+        if d % 128:
+            pytest.skip("ragged kernel needs head_dim % 128 == 0")
+        _check(
+            functools.partial(
+                pk.ragged_paged_attention_pallas, dense_stride=stride),
+            _abstract((B * stride, nq, d), jnp.bfloat16),
+            _cache(nkv, ps, d), _i32(B, W), _i32(B), _i32(B), _i32(B))
+
+    def test_int8_pages_lower_but_do_not_compile(self, model, ps):
+        """The int8 variant LOWERS — and is the example of why that proves
+        nothing: Mosaic refuses the per-page scale DMA (a [2, nkv, ps] f32
+        slice whose minor dim is below the 128-lane tiling), which is why
+        auto-dispatch keeps int8 caches off the kernel.  When this starts
+        compiling, reopen `_should_use_ragged_pallas`."""
+        nq, nkv, d = HEAD_SHAPES[model]
+        if d % 128:
+            pytest.skip("ragged kernel needs head_dim % 128 == 0")
+        _check(
+            lambda q, pages, scales, *rest: pk.ragged_paged_attention_pallas(
+                q, (pages, scales), *rest),
+            _abstract((T, nq, d), jnp.bfloat16),
+            _cache(nkv, ps, d, jnp.int8),
+            _abstract((NUM_PAGES, 2, nkv, ps), jnp.float32),
+            _i32(B, W), _i32(B), _i32(B), _i32(B),
+            compiles=False)
+        assert not att._should_use_ragged_pallas(d, "tpu", quantized=True)
+
+
+class TestCacheKeepsKernelLayout:
+    def test_kv_write_then_kernel_copies_no_cache(self):
+        """The KV write and the kernel's page DMAs must agree on the
+        cache's layout.  A scatter indexed by (page, slot) with a
+        [2, nkv, d] window made XLA re-lay the WHOLE cache out around
+        every layer's write (12 GiB of temporaries for `mixed` at
+        4096 pages x 28 layers — it did not fit the chip); the row scatter
+        in kvcache._scatter_kv leaves the layout alone."""
+        if _tpu_sharding() is None:
+            pytest.skip("no compile-only TPU topology in this installation")
+        nq, nkv, d = HEAD_SHAPES["qwen3-0.6b"]
+        ps = 16
+        # deployment-sized (abstract: nothing is allocated) — XLA only
+        # re-laid the cache out once it was large
+        cache = _abstract((4096, 2, nkv, ps, d), jnp.bfloat16)
+
+        def write_then_attend(pages, q, k, v, table, seq, pos, start, n):
+            pages = kvcache.write_ragged_kv(
+                pages, k, v, table, seq, pos, ps)
+            return pages, pk.ragged_paged_attention_pallas(
+                q, pages, table, start, n, start)
+
+        compiled = jax.jit(write_then_attend, donate_argnums=(0,)).lower(
+            cache, _abstract((T, nq, d), jnp.bfloat16),
+            _abstract((T, nkv, d), jnp.bfloat16),
+            _abstract((T, nkv, d), jnp.bfloat16),
+            _i32(B, W), _i32(T), _i32(T), _i32(B), _i32(B)).compile()
+        cache_bytes = int(np.prod(cache.shape)) * 2
+        assert compiled.memory_analysis().temp_size_in_bytes < cache_bytes // 4
+
+
+class TestDispatchReport:
+    def _report(self, model_config, backend="tpu", **cfg):
+        from kserve_tpu.engine.types import EngineConfig
+
+        return att.describe_attention_dispatch(
+            model_config, EngineConfig(**cfg), backend)
+
+    def test_reports_what_dispatch_selects(self):
+        from kserve_tpu.models.llama import LlamaConfig
+
+        qwen = LlamaConfig.qwen3_0_6b()
+        on_tpu = self._report(qwen)
+        assert on_tpu["mixed"] == "pallas_ragged"
+        assert on_tpu["decode"] == "pallas_decode"
+        assert on_tpu["decode_pallas_min_pages"] == att.PALLAS_MIN_PAGES
+        on_cpu = self._report(qwen, backend="cpu")
+        assert (on_cpu["mixed"], on_cpu["decode"]) == (
+            "xla_ragged_gather", "xla_gather")
+        # head_dim 64: the ragged kernel is out, the packed decode kernel in
+        d64 = self._report(LlamaConfig.llama3_1b())
+        assert (d64["mixed"], d64["decode"]) == (
+            "xla_ragged_gather", "pallas_decode")
+        # int8 pages, windows and scale overrides keep decode on the gather
+        assert self._report(qwen, kv_quant="int8")["mixed"] == (
+            "xla_ragged_gather")
+        gemma = self._report(LlamaConfig.gemma2_2b())
+        assert (gemma["mixed"], gemma["decode"]) == (
+            "pallas_ragged", "xla_gather")
+        assert self._report(qwen, tp=4)["shard_map"]

@@ -19,7 +19,7 @@ from test_engine import make_engine
 
 class _BlockingChunk:
     """A fake device result whose host fetch never completes (what a
-    wedged device tunnel looks like from np.asarray)."""
+    wedged device looks like from np.asarray)."""
 
     def __array__(self, dtype=None, copy=None):
         # this sleep IS the simulated wedge (a host fetch that never
