@@ -1,7 +1,7 @@
 #!/usr/bin/env python3
 """One run of one cell of BENCHMARK.json.
 
-    python3 benchmark/run.py --workload <name> --seed <n> --seconds <s> --trace <0|1>
+    python3 benchmark/run.py --workload <name> --seed <n> --seconds <s> --trace <0|1|2>
 
 A parent that never imports JAX starts the generative server as a child on
 the cell's chips (seeded random weights, a model directory that holds only
@@ -10,8 +10,10 @@ is ready, sends the correctness probes, warms the cell's program shapes,
 drives the cell's traffic over /openai/v1/completions with SSE — ramp,
 window of `--seconds`, cool-down — repeats the probes, stops the server and
 prints one JSON line: `correct`, `attempted`, `failed`, `metrics`, `device`
-(and `breakdown` with `--trace 1`).  `--trace 0` reports the cell's
-end-to-end metrics, `--trace 1` its per-layer metrics.
+(and `breakdown` with `--trace 1` or `2`).  `--trace 0` reports the cell's
+end-to-end metrics, `--trace 1` its per-layer metrics, `--trace 2` both: it
+is a `--trace 0` run until the generator has stopped, followed by a short
+traced stretch of the same traffic (`traced_phase`).
 
 It measures only on a TPU: with no accelerator, or fewer chips than the
 cell asks for, the server child fails at start-up and this exits non-zero
@@ -46,13 +48,19 @@ from kbench.server import (  # noqa: E402
 CLIENT_TIMEOUT_S = 300.0
 IDLE_TIMEOUT_S = 400.0
 TRACE_SECONDS = 4.0
-#: the capture starts as the window closes, on the same traffic carried on:
-#: the python tracer slows the host while it runs (a dispatch took 760 ms
-#: against 430), and `stop_trace` then blocks the server's loop for some
-#: 17 s.  Inside the window that left its last requests without a first
-#: token (failed, so not correct); after it, only the cool-down waits.
+#: `--trace 1`: the capture starts as the window closes, on the same traffic
+#: carried on, so that no number of the window is taken under the profiler.
+#: Before PR 26 the program's capture ran the python tracer, which slows the
+#: host (a dispatch took 760 ms against 430), and its `stop_trace` blocked
+#: the server's loop; inside the window that left the last requests without
+#: a first token (failed, so not correct).  Since PR 26 the program stops a
+#: capture in a worker thread, with the python tracer off unless asked; a
+#: process's FIRST `stop_trace` still takes 13-88 s (my chip runs, PR 26).
 #: The traffic goes on this long past the capture's start.
 TRACE_TAIL_S = TRACE_SECONDS + 1.0
+#: `--trace 2`: the traced stretch's traffic is scheduled this far and
+#: called off as soon as the capture is written
+TRACED_MAX_S = 200.0
 MAX_OPEN_LATE_S = 2.0
 MAX_RAMPS = 3
 
@@ -141,6 +149,104 @@ def make_window_hooks(server: Server, t_open: float, seconds: float,
                     out["profile"] = (r.status, await r.json())
 
     return hooks
+
+
+def traced_phase(server: Server, plan: Plan, seed: int, profile_dir: str) -> dict:
+    """`--trace 2`, once a `--trace 0` run's numbers are taken and its
+    generator has stopped: the cell's traffic again (another seed: nothing
+    of the window's prompts is in the prefix cache), from the ramp's primer
+    on.  During the ramp the profiler is started and stopped once into a
+    directory that is thrown away (a process's first stop costs 12-74 s,
+    which so falls into no number); at the ramp's end TRACE_SECONDS are
+    captured (`{"seconds": N}`: the server stops the capture itself), and
+    the traffic is called off as the capture ends.  Writing the trace then
+    takes the server 37-79 s more, in a thread of its own: `wait_capture`
+    waits that out after the probes.  Returns what the capture cost so
+    far: the first start and stop, how long the server took to answer
+    /admin/telemetry under the capture, the engine's dispatch period
+    under it."""
+    import aiohttp
+
+    mix = plan.mix
+    ramp = float(mix.get("ramp_s", 0.0))
+    out = {}
+    t_open = time.perf_counter() + ramp + 0.25
+
+    async def hooks(abort):
+        async def post(body):
+            async with session.post(
+                    server.base_url + "/admin/profile", json=body) as r:
+                if r.status not in (200, 202):
+                    raise ServerFailure(f"/admin/profile {body} -> HTTP "
+                                        f"{r.status}: {await r.text()}")
+                return await r.json()
+
+        async def telemetry():
+            t = time.perf_counter()
+            snap = json.loads(
+                await _fetch(session, server.base_url + "/admin/telemetry"))
+            return time.perf_counter() - t, snap["models"].get(MODEL_NAME, {})
+
+        try:
+            async with aiohttp.ClientSession() as session:
+                t0 = time.perf_counter()
+                await post({"action": "start", "dir": profile_dir + ".first"})
+                await post({"action": "stop"})
+                shutil.rmtree(profile_dir + ".first", ignore_errors=True)
+                out["first_start_stop_s"] = time.perf_counter() - t0
+                await asyncio.sleep(max(0.0, t_open - time.perf_counter()))
+                out["ramp_s"] = time.perf_counter() - t0
+                answered_s, at_start = await telemetry()
+                await post({"seconds": TRACE_SECONDS, "dir": profile_dir})
+                await asyncio.sleep(TRACE_SECONDS)
+                while_stopping_s, at_stop = await telemetry()
+                out["capture_ended_at"] = time.monotonic()
+                out["telemetry_answered_s"] = max(answered_s, while_stopping_s)
+                ring = at_stop.get("dispatches") or {
+                    "columns": ["launched_at"], "rows": []}
+                at = ring["columns"].index("launched_at")
+                launches = [row[at] for row in ring["rows"]
+                            if row[at] >= (at_start.get("now") or 0.0)]
+                out["period_under_capture_ms"] = (
+                    1e3 * (launches[-1] - launches[0]) / (len(launches) - 1)
+                    if len(launches) > 1 else None)
+        finally:
+            abort.set()
+
+    if plan.open_loop:
+        requests = schedule.open_loop_schedule(
+            mix, plan.rate, TRACED_MAX_S, seed, plan.vocab, plan.scale)
+        asyncio.run(loadgen.run_open_loop(
+            server.base_url, MODEL_NAME, requests, mix["sampling"], t_open,
+            float(mix.get("drain_s", 30.0)), CLIENT_TIMEOUT_S, hooks))
+    else:
+        per_client = schedule.closed_loop_schedule(
+            mix, plan.clients, seed, plan.vocab, plan.scale)
+        head = schedule.ramp_head(
+            mix, -ramp, random.Random(seed + 1), plan.vocab, plan.scale)
+        asyncio.run(loadgen.run_closed_loop(
+            server.base_url, MODEL_NAME, per_client, mix["sampling"], t_open,
+            TRACED_MAX_S, CLIENT_TIMEOUT_S, hooks, plan.vocab, head))
+    if "capture_ended_at" not in out:
+        raise ServerFailure(f"the capture was not taken: {out}")
+    return out
+
+
+def wait_capture(server: Server, cost: dict, timeout_s: float = 200.0) -> None:
+    """Until the traced stretch's capture is written; adds to `cost` how
+    long that took from the capture's end (`stop_s`) and the longest the
+    server took to answer meanwhile."""
+    ended_at = cost.pop("capture_ended_at")
+    while time.monotonic() - ended_at < timeout_s:
+        t = time.monotonic()
+        active = server.get_json("/admin/telemetry")["profiler"]["active"]
+        cost["telemetry_answered_s"] = max(
+            cost["telemetry_answered_s"], time.monotonic() - t)
+        if not active:
+            cost["stop_s"] = time.monotonic() - ended_at
+            return
+        time.sleep(0.5)
+    raise ServerFailure("the profiler capture did not end")
 
 
 def drive(server: Server, plan: Plan, seed: int, seconds: float, trace: bool,
@@ -362,7 +468,7 @@ def measure(args, plan: Plan, platform: str) -> int:
             shapes = compiles(parse_metrics(server.get("/metrics")))
             timings["shapes_before_ramp"] = shapes
             side = drive(server, plan, args.seed, args.seconds,
-                         bool(args.trace), profile_dir,
+                         args.trace == 1, profile_dir,
                          shapes_before_ramp=shapes)
             if "disturbed" not in side:
                 break
@@ -374,10 +480,15 @@ def measure(args, plan: Plan, platform: str) -> int:
                 f"{MAX_RAMPS} ramps in a row met a program shape nothing "
                 "had warmed: no window was measured")
         log(f"window closed; set-up was {side['t_open_launch_s']:.1f} s")
-        if args.trace:
+        if args.trace == 1:
             wait_profiler(server)
+        elif args.trace == 2:
+            timings["traced_phase"] = traced_phase(
+                server, plan, args.seed + 1, profile_dir)
         timings["idle_wait_s"] = wait_idle(server)
         served_after = correctness.run_probes(server, prompts)
+        if args.trace == 2:
+            wait_capture(server, timings["traced_phase"])
         state = server.state()
         device = device_report(state)
         code = server.stop()
@@ -386,6 +497,8 @@ def measure(args, plan: Plan, platform: str) -> int:
         if args.trace:
             trace = reduce_trace(
                 profile_dir, os.path.join(profile_dir, "reduced.json"))
+            if args.trace == 2:  # reduced: the trace itself is not kept
+                shutil.rmtree(profile_dir, ignore_errors=True)
         run = build_run(plan, side, args.seconds, state, trace, timings,
                         startup_metrics, device)
         ok, reasons, detail = judge(
@@ -417,6 +530,8 @@ def measure(args, plan: Plan, platform: str) -> int:
         return 0 if ok else 1
     if args.trace:
         metrics = per_layer_metrics(plan, run)
+        if args.trace == 2:
+            metrics = {**end_to_end_metrics(plan, run), **metrics}
         if trace:
             device["busy_s"] = trace["busy_s"]
             device["window_s"] = trace["window_s"]
@@ -540,7 +655,7 @@ def main(argv=None) -> int:
     ap.add_argument("--workload", required=True)
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--seconds", type=float, required=True)
-    ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
+    ap.add_argument("--trace", type=int, choices=(0, 1, 2), default=0)
     ap.add_argument("--mode", choices=("measure", "rehearse", "sweep"),
                     default="measure")
     ap.add_argument("--rates", type=lambda s: [float(x) for x in s.split(",")],

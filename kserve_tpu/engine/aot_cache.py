@@ -40,6 +40,7 @@ bound instead of compile-bound.
 
 from __future__ import annotations
 
+import functools
 import json
 import os
 import pickle
@@ -53,7 +54,7 @@ import jax
 import numpy as np
 
 from ..logging import logger
-from ..metrics import AOT_CACHE_EVENTS, XLA_COMPILES
+from ..metrics import AOT_CACHE_EVENTS, XLA_COMPILE_SECONDS, XLA_COMPILES
 
 # bump when the on-disk entry layout changes; old entries become
 # structurally invalid (logged + recompiled) instead of misread
@@ -113,6 +114,32 @@ def _mesh_devices(mesh) -> list:
     return list(mesh.devices.flat) if mesh is not None else jax.devices()
 
 
+#: where the device programs are written: a change to any of these files
+#: may change the compiled artifact, so their bytes are part of the key
+_PROGRAM_SOURCES = ("models", "ops", "parallel", "engine/compiled.py",
+                    "engine/sampling.py", "engine/kvcache.py")
+
+
+@functools.lru_cache(maxsize=1)
+def program_source_digest() -> str:
+    """Digest of the source files that define the device programs.  An
+    executable is loaded without tracing, so nothing else would notice
+    that the program it was compiled from has since been edited: after an
+    upgrade a warm cache directory would go on serving the old program."""
+    package = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    h = sha256()
+    for entry in _PROGRAM_SOURCES:
+        path = os.path.join(package, entry)
+        files = [path] if os.path.isfile(path) else sorted(
+            os.path.join(d, f) for d, _, fs in os.walk(path)
+            for f in fs if f.endswith(".py"))
+        for name in files:
+            h.update(os.path.relpath(name, package).encode())
+            with open(name, "rb") as f:
+                h.update(f.read())
+    return h.hexdigest()[:16]
+
+
 def aot_cache_key(model_config, engine_config, mesh) -> str:
     """Content digest of everything that determines the compiled
     artifact.  Model config is digested WHOLE (any architectural field
@@ -120,7 +147,8 @@ def aot_cache_key(model_config, engine_config, mesh) -> str:
     ``AOT_KEY_ENGINE_FIELDS`` list; the mesh contributes axis names,
     shape, and the concrete device assignment (serialized executables
     bake device ids, so dp groups on disjoint device sets must not share
-    entries); jax/jaxlib versions guard serialization-format skew."""
+    entries); jax/jaxlib versions guard serialization-format skew; the
+    programs' own source guards an edited program."""
     import dataclasses as _dc
 
     import jaxlib
@@ -144,6 +172,7 @@ def aot_cache_key(model_config, engine_config, mesh) -> str:
         "jax": jax.__version__,
         "jaxlib": jaxlib.__version__,
         "backend": jax.default_backend(),
+        "source": program_source_digest(),
     }
     blob = json.dumps(payload, sort_keys=True).encode()
     return sha256(blob).hexdigest()[:32]
@@ -466,6 +495,12 @@ class AOTProgram:
     def name(self) -> str:
         return self._name
 
+    @property
+    def compiles(self) -> int:
+        """Compiles so far (of the whole cache: it only ever rises); the
+        engine reads it around a launch to mark the dispatch that compiled."""
+        return self._cache.stats.compiles
+
     def preload(self) -> int:
         """Deserialize every on-disk entry for this program into memory
         (replica start: first request pays zero trace/compile/load).
@@ -491,6 +526,7 @@ class AOTProgram:
         stats.compile_s += t2 - t1
         stats.compiles += 1
         XLA_COMPILES.labels(program=self._name).inc()
+        XLA_COMPILE_SECONDS.labels(program=self._name).inc(t2 - t0)
         self._observe(lowered, compiled, sig_hash)
         return compiled
 

@@ -14,13 +14,14 @@ from __future__ import annotations
 
 import hashlib
 import logging
+import time
 from dataclasses import dataclass
 from typing import Callable, Dict, List, Optional
 
 import jax
 import jax.numpy as jnp
 
-from ..metrics import XLA_COMPILES
+from ..metrics import XLA_COMPILE_SECONDS, XLA_COMPILES
 from ..models import llama
 from ..parallel import sharding as shd
 from .sampling import apply_penalties, compute_logprobs, sample_tokens
@@ -107,7 +108,10 @@ class _CompileCounting:
     signature via record_compile_fingerprint, so the retrace-budget test
     can diff the spellings of compile N and N+1.  The signature is built
     from avals (which survive donation) AFTER the dispatch — cost is one
-    tree-flatten per compile event, nothing per steady-state call."""
+    tree-flatten per compile event, and one clock read per steady-state
+    call: a call that missed is timed whole (trace, compile, first run)
+    into engine_xla_compile_seconds_total, which is how long it kept the
+    engine's loop from serving anything, /metrics included."""
 
     __slots__ = ("_name", "_fn", "_seen")
 
@@ -116,10 +120,19 @@ class _CompileCounting:
         self._fn = fn
         self._seen = 0
 
+    @property
+    def compiles(self) -> int:
+        """Cache misses so far; the engine reads it around a launch to
+        mark the dispatch that compiled."""
+        return self._seen
+
     def __call__(self, *args, **kwargs):
+        t0 = time.perf_counter()
         out = self._fn(*args, **kwargs)
         n = self._fn._cache_size()
         if n > self._seen:
+            XLA_COMPILE_SECONDS.labels(program=self._name).inc(
+                time.perf_counter() - t0)
             XLA_COMPILES.labels(program=self._name).inc(n - self._seen)
             self._seen = n
             try:

@@ -38,6 +38,9 @@ import numpy as np
 from ..logging import logger
 from ..metrics import (
     ENGINE_BATCH_OCCUPANCY,
+    ENGINE_DISPATCH_PHASE_SECONDS,
+    ENGINE_DISPATCHES,
+    ENGINE_FIRST_TOKEN_DISPATCHES,
     ENGINE_KV_DISK_BYTES,
     ENGINE_KV_OFFLOAD_BYTES,
     ENGINE_KV_PAGES_FREE,
@@ -64,7 +67,14 @@ from ..metrics import (
 from ..lifecycle.checkpoint import GenerationCheckpoint, GenerationPreempted
 from ..lifecycle.state import ReplicaDrainingError
 from ..models import llama
-from ..observability import RequestTimeline, TimelineRecorder, emit_timeline_spans
+from ..observability import (
+    DISPATCH_COLUMNS,
+    PHASES,
+    DispatchPhases,
+    RequestTimeline,
+    TimelineRecorder,
+    emit_timeline_spans,
+)
 from ..parallel import sharding as shd
 from ..resilience import (
     MONOTONIC,
@@ -105,6 +115,13 @@ def _device_row(device) -> dict:
         "bytes_in_use": stats.get("bytes_in_use"),
         "peak_bytes_in_use": stats.get("peak_bytes_in_use"),
     }
+
+
+#: where a dispatch row (observability.DISPATCH_COLUMNS) holds what the
+#: counters are fed from: the program, and the six phases with `wait_lag`
+_PROGRAM_COLUMN = DISPATCH_COLUMNS.index("program")
+_PHASE_COLUMNS = slice(DISPATCH_COLUMNS.index(PHASES[0]),
+                       DISPATCH_COLUMNS.index("wait_lag") + 1)
 
 
 class LLMEngine:
@@ -174,6 +191,17 @@ class LLMEngine:
         # bounded ring of finished timelines + rolling percentile windows
         # behind GET /admin/telemetry
         self.telemetry = TimelineRecorder()
+        # the loop's phases per dispatch (docs/observability.md): host spans
+        # on the profiler's clock while a capture runs, the
+        # engine_dispatch_phase_seconds_total counters, one ring row each
+        self._phases = DispatchPhases(
+            self._clock, annotate=jax.profiler.TraceAnnotation)
+        self._phase_seconds = [
+            ENGINE_DISPATCH_PHASE_SECONDS.labels(
+                model_name=metrics_label, phase=phase)
+            for phase in (*PHASES, "wait_lag")]
+        # when the fetch worker last had a result on the host
+        self._fetch_ready_at: Optional[float] = None
         # checkpoints carry this as model_name; resume_generation rejects a
         # mismatch.  Distinct from the metrics label so DP sub-engines
         # (engine-dp0, engine-dp1, ...) share one weights identity and a
@@ -1260,7 +1288,7 @@ class LLMEngine:
     def telemetry_snapshot(self) -> dict:
         """Rolling latency percentiles + recent request timelines (the
         GET /admin/telemetry payload; observability/introspection.py)."""
-        snap = self.telemetry.snapshot()
+        snap = self.telemetry.snapshot(now=self._clock.now())
         snap["queue_depth"] = self.queue_depth
         snap["prefix_cache_hits"] = self.prefix_cache_hits
         snap["preemptions"] = self.preemption_count
@@ -1383,9 +1411,17 @@ class LLMEngine:
         wd = self._watchdog
         if wd is not None:
             wd.fetch_started()
+
+        def fetch():
+            out = np.asarray(x)
+            # stamped by the worker: the loop's resumption less this is
+            # how long a finished result waited for the event loop
+            self._fetch_ready_at = self._clock.now()
+            return out
+
         try:
             return await self._fetcher.fetch_async(
-                lambda: np.asarray(x), self.config.step_deadline_s)
+                fetch, self.config.step_deadline_s)
         except TimeoutError:
             raise self._fetch_timeout() from None
         finally:
@@ -1930,6 +1966,7 @@ class LLMEngine:
         try:
             while not self._stopped:
                 did_work = False
+                self._phases.mark("admit")
                 # deadline enforcement: a queued request whose budget died
                 # is failed upfront — seating it would burn prefill+decode
                 # on an answer nobody is waiting for
@@ -1966,11 +2003,14 @@ class LLMEngine:
                     # or a routed dispatch)
                     self._note_progress()
                 if not did_work:
+                    self._phases.pause()  # idle time is in no phase
                     self._wake.clear()
                     await self._wake.wait()
                 else:
                     # yield to the event loop so streams flush between steps
+                    self._phases.mark("yield")
                     await asyncio.sleep(0)
+                    self._commit_dispatch()
         except Exception as e:  # noqa: BLE001 — engine death must surface
             logger.exception("engine loop crashed")
             self._loop_error = e
@@ -1997,6 +2037,20 @@ class LLMEngine:
             self._admitting = []
             if self.on_loop_crash is not None:
                 self.on_loop_crash(e)
+        finally:
+            self._phases.close()
+
+    def _commit_dispatch(self) -> None:
+        """Close the loop iteration's phases; if it dispatched, the row
+        goes to the telemetry ring and its seconds to the counters."""
+        row = self._phases.commit()
+        if row is None:
+            return
+        self.telemetry.record_dispatch(row)
+        ENGINE_DISPATCHES.labels(
+            model_name=self._mlabel, program=row[_PROGRAM_COLUMN]).inc()
+        for counter, seconds in zip(self._phase_seconds, row[_PHASE_COLUMNS]):
+            counter.inc(seconds)
 
     def _drop_expired_waiting(self) -> None:
         """Fail queued requests whose propagated deadline expired before a
@@ -2095,7 +2149,8 @@ class LLMEngine:
             pages = list(hits) + self.allocator.allocate(need - len(hits))
             self._waiting.pop(0)
             if req.timeline is not None:
-                req.timeline.mark_admitted(self._clock.now())
+                req.timeline.mark_admitted(
+                    self._clock.now(), self._phases.serial)
             self._count_prefix_hits(pkeys, hits)
             admitted.append((free.pop(0), req, pages, len(hits), seq))
         if not admitted:
@@ -2351,7 +2406,8 @@ class LLMEngine:
         self._waiting.remove(req)
         self._set_queue_gauge()
         if req.timeline is not None:
-            req.timeline.mark_admitted(self._clock.now())
+            req.timeline.mark_admitted(
+                self._clock.now(), self._phases.serial)
         self._count_prefix_hits(keys or [], cached)
         # the slot enters "prefilling" state immediately and the run loop
         # advances ONE chunk per iteration — in-flight decode streams keep
@@ -2507,7 +2563,8 @@ class LLMEngine:
         slot.deadline = req.deadline
         slot.timeline = req.timeline
         if req.timeline is not None:
-            req.timeline.mark_admitted(self._clock.now())
+            req.timeline.mark_admitted(
+                self._clock.now(), self._phases.serial)
 
     def _admit_injected(self, req: "_QueuedRequest") -> bool:
         """Admit a request whose KV already exists on host: either P/D
@@ -2548,7 +2605,8 @@ class LLMEngine:
         self._waiting.remove(req)
         self._set_queue_gauge()
         if req.timeline is not None:
-            req.timeline.mark_admitted(self._clock.now())
+            req.timeline.mark_admitted(
+                self._clock.now(), self._phases.serial)
             req.timeline.mark_prefill_start(self._clock.now())
         entry = (idx, req, pages, 0, None)
         self._admitting.append(entry)
@@ -2986,7 +3044,11 @@ class LLMEngine:
     def _dispatch_chunk(self, meta: dict, tokens_dev=None):
         """Launch one decode chunk (async); tokens_dev chains the previous
         chunk's device-resident last tokens, skipping a host round-trip."""
-        meta["_dispatched_at"] = self._clock.now()
+        meta["_dispatched_at"] = self._phases.mark("launch")
+        n_active = int(np.count_nonzero(meta["active"]))
+        self._phases.launched(
+            "decode", n_active, meta["page_table"].shape[1], 0, n_active,
+            chained=tokens_dev is not None)
         rng = jax.random.fold_in(self._base_rng, self._next_step())
         tokens = tokens_dev if tokens_dev is not None else jnp.asarray(meta["tokens"])
         args = (
@@ -3025,12 +3087,14 @@ class LLMEngine:
         drain evicting a slot during the await is observed (request_id
         None) rather than raced."""
         steps = self.config.steps_per_sync
+        self._phases.mark("wait")
         if isinstance(chunk, tuple):  # logprobs variant: (tokens, lp, tv, ti)
             chunk_np = await self._fetch_async(chunk[0])  # [steps, B]
             lp_np = tuple([await self._fetch_async(a) for a in chunk[1:]])
         else:
             chunk_np = await self._fetch_async(chunk)  # [steps, B]
             lp_np = None
+        self._phases.resumed(self._fetch_ready_at)
         step_s = self._clock.now() - meta["_dispatched_at"]
         ENGINE_STEP_DURATION.labels(model_name=self._mlabel).observe(step_s)
         self.telemetry.record_step(step_s)
@@ -3065,12 +3129,15 @@ class LLMEngine:
     async def _decode_once(self):
         """Decode with a depth-2 dispatch pipeline: chunk N+1 launches
         (chained on N's device tokens) before N's tokens are fetched, so the
-        host round-trip hides behind device compute."""
+        host round-trip hides behind device compute.  Each routed chunk
+        commits its own dispatch row; phases are recorded as they occur."""
+        self._phases.mark("plan")
         meta = self._prepare_chunk(prev=None)
         if meta is None:
             return
         chunk = self._dispatch_chunk(meta)
         while True:
+            self._phases.mark("plan")
             meta2 = None
             chunk2 = None
             # chain when admission couldn't run anyway (no waiting work, or
@@ -3109,7 +3176,9 @@ class LLMEngine:
                 self._pipeline_busy = True
             finished_any = await self._route_chunk(meta, chunk)
             # flush streams while the chained chunk runs on device
+            self._phases.mark("yield")
             await asyncio.sleep(0)
+            self._commit_dispatch()
             if chunk2 is None:
                 break
             meta, chunk = meta2, chunk2
@@ -3151,6 +3220,8 @@ class LLMEngine:
         dispatch seat and keep decoding in the same program (the scan
         tail), so a short request can prefill AND decode its whole budget
         in a single dispatch."""
+        phases = self._phases
+        phases.mark("plan")
         if self._needs_legacy_step():
             did = self._advance_prefills()
             active = self._active_decode_slots()
@@ -3187,8 +3258,9 @@ class LLMEngine:
                 await self._step_dense(meta)
                 return True
         plan = self._plan_ragged(meta, prefilling)
-        dispatched_at = self._clock.now()
+        dispatched_at = phases.mark("launch")
         rng = jax.random.fold_in(self._base_rng, self._next_step())
+        compiles = getattr(self._mixed_fn, "compiles", 0)
         out, self.kv_pages = self._mixed_fn(
             self.params,
             jnp.asarray(plan["q_tokens"]),
@@ -3210,7 +3282,13 @@ class LLMEngine:
             rng,
             jnp.asarray(plan["adapters"]),
         )
+        phases.launched(
+            "mixed", len(plan["q_tokens"]), plan["page_table"].shape[1],
+            plan["prefill_tokens"], plan["decode_tokens"],
+            compiled=getattr(self._mixed_fn, "compiles", 0) != compiles)
+        phases.mark("wait")
         chunk_np = await self._fetch_async(out)
+        phases.resumed(self._fetch_ready_at)
         self._route_mixed(plan, chunk_np, dispatched_at)
         return True
 
@@ -3500,7 +3578,11 @@ class LLMEngine:
         dispatch's device (token, pos, counters) carry so the chained
         program starts exactly where the in-flight one ends — no host
         round-trip between them."""
-        plan["_dispatched_at"] = self._clock.now()
+        plan["_dispatched_at"] = self._phases.mark("launch")
+        n_tokens = int(np.count_nonzero(plan["live"])) * ((self._spec_k or 0) + 1)
+        self._phases.launched(
+            "mixed_decode", n_tokens, plan["page_table"].shape[1], 0,
+            n_tokens, chained=chain is not None)
         rng = jax.random.fold_in(self._base_rng, self._next_step())
         if chain is not None:
             tok, pos, cnt = chain["carry"]
@@ -3537,8 +3619,10 @@ class LLMEngine:
         ACCEPTED, routed tokens ever reach slot.generated, so checkpoints
         (drain/preempt/hedge) can never carry an unverified draft tail.
         Returns (any lane finished, any token routed)."""
+        self._phases.mark("wait")
         toks_np = await self._fetch_async(chunk["toks"])  # [rounds, B, K+1]
         n_np = await self._fetch_async(chunk["n"])  # [rounds, B]
+        self._phases.resumed(self._fetch_ready_at)
         step_s = self._clock.now() - plan["_dispatched_at"]
         ENGINE_STEP_DURATION.labels(model_name=self._mlabel).observe(step_s)
         self.telemetry.record_step(step_s)
@@ -3616,10 +3700,12 @@ class LLMEngine:
         restored on the mixed path: dispatch N+1 launches — chained on
         N's device (token, pos, counters) carry — before N's tokens are
         fetched, so draft+verify of step N+1 overlaps routing of step N
-        and the host round-trip hides behind device compute."""
+        and the host round-trip hides behind device compute.  Each routed
+        dispatch commits its own row; phases are recorded as they occur."""
         plan = self._plan_dense(meta)
         chunk = self._dispatch_dense(plan)
         while True:
+            self._phases.mark("plan")
             plan2 = None
             chunk2 = None
             admission_blocked = (
@@ -3646,7 +3732,9 @@ class LLMEngine:
                 self._pipeline_busy = True
             finished_any, routed_any = await self._route_dense(plan, chunk)
             # flush streams while the chained dispatch runs on device
+            self._phases.mark("yield")
             await asyncio.sleep(0)
+            self._commit_dispatch()
             if chunk2 is None:
                 break
             plan, chunk = plan2, chunk2
@@ -3668,8 +3756,14 @@ class LLMEngine:
               logprob: Optional[float] = None,
               top_logprobs: Optional[List[tuple]] = None):
         """Stream one token; apply stop conditions."""
-        if slot.timeline is not None:
-            slot.timeline.mark_token(self._clock.now())
+        tl = slot.timeline
+        if tl is not None:
+            first = tl.first_token_at is None
+            tl.mark_token(self._clock.now(), self._phases.serial)
+            if first and tl.dispatches_to_first_token is not None:
+                ENGINE_FIRST_TOKEN_DISPATCHES.labels(
+                    model_name=self._mlabel).observe(
+                        tl.dispatches_to_first_token)
         n_gen = len(slot.generated)
         params = slot.params
         finish_reason = None

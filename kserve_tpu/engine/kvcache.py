@@ -108,6 +108,7 @@ def pages_needed(n_tokens: int, page_size: int) -> int:
     return (n_tokens + page_size - 1) // page_size
 
 
+@jax.named_scope("kv_write")
 def write_prompt_kv_batch(
     kv_pages: jnp.ndarray,  # [num_pages, 2, n_kv, ps, d]
     k: jnp.ndarray,  # [B, T, n_kv, d]
@@ -127,6 +128,7 @@ def write_prompt_kv_batch(
     return _scatter_kv(kv_pages, k, v, pages_flat, slot_of)
 
 
+@jax.named_scope("kv_write")
 def write_chunk_kv_batch(
     kv_pages,  # [num_pages, 2, nkv, ps, d] or (int8 pages, scales)
     k: jnp.ndarray,  # [B, C, n_kv, d] — chunk keys
@@ -190,6 +192,7 @@ def _scatter_kv(kv_pages, k, v, pages_flat, slot_flat):
     )
 
 
+@jax.named_scope("kv_write")
 def write_ragged_kv(
     kv_pages,  # [num_pages, 2, n_kv, ps, d] or (int8 pages, scales)
     k: jnp.ndarray,  # [T, n_kv, d] — packed ragged slice keys
@@ -212,6 +215,7 @@ def write_ragged_kv(
     return _scatter_kv(kv_pages, k[:, None], v[:, None], page, slot)
 
 
+@jax.named_scope("kv_write")
 def append_token_kv(
     kv_pages: jnp.ndarray,  # [num_pages, 2, n_kv, ps, d]
     k: jnp.ndarray,  # [B, n_kv, d]

@@ -100,6 +100,7 @@ class SamplingState:
         )
 
 
+@jax.named_scope("sampler")
 def sample_tokens(
     logits: jnp.ndarray,  # [B, V] f32
     state: SamplingState,

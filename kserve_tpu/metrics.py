@@ -8,7 +8,7 @@ tokens/sec autoscaling has a native signal.
 
 from __future__ import annotations
 
-from prometheus_client import Counter, Gauge, Histogram
+from prometheus_client import Counter, Gauge, Histogram, Summary
 
 PRE_HIST_TIME = Histogram(
     "request_preprocess_seconds", "pre-process request latency", ["model_name"]
@@ -272,6 +272,34 @@ XLA_COMPILES = Counter(
     "XLA compilations observed (jit cache misses incl. retraces), by "
     "compiled engine program",
     ["program"],
+)
+XLA_COMPILE_SECONDS = Counter(
+    "engine_xla_compile_seconds_total",
+    "wall seconds of the calls that missed the jit cache (trace + compile "
+    "+ the first run), by compiled engine program: what a compile cost the "
+    "loop it blocked",
+    ["program"],
+)
+# `phase` is the closed set observability/timeline.PHASES plus `wait_lag`;
+# the six tile one iteration of the engine's loop, so their sum over
+# engine_dispatches_total is the dispatch period
+ENGINE_DISPATCH_PHASE_SECONDS = Counter(
+    "engine_dispatch_phase_seconds_total",
+    "seconds of the engine's loop by phase of a dispatch: admit | plan | "
+    "launch | wait | route | yield, and wait_lag (the part of wait in "
+    "which the result was on the host and the loop had not resumed)",
+    ["model_name", "phase"],
+)
+ENGINE_DISPATCHES = Counter(
+    "engine_dispatches_total",
+    "device dispatches committed by the engine's loop, by program",
+    ["model_name", "program"],
+)
+ENGINE_FIRST_TOKEN_DISPATCHES = Summary(
+    "engine_first_token_dispatches",
+    "dispatches from a request's admission to its first token, both "
+    "included, observed once per request at the first token",
+    ["model_name"],
 )
 # `role` is a closed enum (decoding/prefilling/free): batch composition per
 # engine step without per-request labels

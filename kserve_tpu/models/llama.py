@@ -433,6 +433,7 @@ def _qkv(layer: Params, x: jnp.ndarray, config: LlamaConfig, onehot=None):
     return q, k, v
 
 
+@jax.named_scope("mlp")
 def _mlp(layer: Params, x: jnp.ndarray, config: LlamaConfig, onehot=None) -> jnp.ndarray:
     if config.n_experts > 0:
         from .moe import MoEConfig, moe_mlp
@@ -456,6 +457,7 @@ def _mlp(layer: Params, x: jnp.ndarray, config: LlamaConfig, onehot=None) -> jnp
     )
 
 
+@jax.named_scope("lm_head")
 def _logits(params: Params, x: jnp.ndarray, config: LlamaConfig) -> jnp.ndarray:
     x = _norm(x, params["final_norm"], config)
     head = params.get("lm_head")
@@ -475,6 +477,7 @@ def _norm(x: jnp.ndarray, weight: jnp.ndarray, config: LlamaConfig) -> jnp.ndarr
     return rms_norm(x, weight, config.rms_norm_eps)
 
 
+@jax.named_scope("embedding")
 def _embed(params: Params, tokens: jnp.ndarray, config: LlamaConfig) -> jnp.ndarray:
     x = embed_lookup(params["embed"], tokens, jnp.dtype(config.dtype))
     if config.embed_scale:
@@ -531,23 +534,24 @@ def transformer_block(
     B, T = x.shape[0], x.shape[1]
     residual = x
     h = _norm(x, layer["attn_norm"], config)
-    q, k, v = _qkv(layer, h, config, onehot)
-    q = apply_rope(q, positions, config.rope_theta, config.rope_scaling)
-    k = apply_rope(k, positions, config.rope_theta, config.rope_scaling)
-    if attention_fn is None:
-        attn = causal_prefill_attention(
-            q, k, v, valid_len, config.attn_logit_softcap,
-            scale=config.attn_scale, window=layer.get("attn_window"),
+    with jax.named_scope("attention"):
+        q, k, v = _qkv(layer, h, config, onehot)
+        q = apply_rope(q, positions, config.rope_theta, config.rope_scaling)
+        k = apply_rope(k, positions, config.rope_theta, config.rope_scaling)
+        if attention_fn is None:
+            attn = causal_prefill_attention(
+                q, k, v, valid_len, config.attn_logit_softcap,
+                scale=config.attn_scale, window=layer.get("attn_window"),
+            )
+        else:
+            # pluggable path (SP ring attention); engines exclude it for
+            # windowed/scaled configs at init
+            attn = attention_fn(q, k, v, valid_len, config.attn_logit_softcap)
+        attn_flat = attn.reshape(B, T, -1)
+        attn = _maybe_add(
+            dense(attn_flat, layer["wo"]),
+            lora_delta(layer.get("lora"), "wo", attn_flat, onehot),
         )
-    else:
-        # pluggable path (SP ring attention); engines exclude it for
-        # windowed/scaled configs at init
-        attn = attention_fn(q, k, v, valid_len, config.attn_logit_softcap)
-    attn_flat = attn.reshape(B, T, -1)
-    attn = _maybe_add(
-        dense(attn_flat, layer["wo"]),
-        lora_delta(layer.get("lora"), "wo", attn_flat, onehot),
-    )
     if config.sandwich_norms:
         attn = _norm(attn, layer["post_attn_norm"], config)
     x = residual + attn
@@ -614,19 +618,20 @@ def chunk_transformer_block(
     positions = chunk_start[:, None] + jnp.arange(C, dtype=jnp.int32)[None, :]
     residual = x
     h = _norm(x, layer["attn_norm"], config)
-    q, k, v = _qkv(layer, h, config, onehot)
-    q = apply_rope(q, positions, config.rope_theta, config.rope_scaling)
-    k = apply_rope(k, positions, config.rope_theta, config.rope_scaling)
-    attn = chunked_prefill_attention(
-        q, k, v, pages, page_ids, chunk_start, valid_len,
-        config.attn_logit_softcap,
-        scale=config.attn_scale, window=layer.get("attn_window"),
-    )
-    attn_flat = attn.reshape(B, C, -1)
-    attn = _maybe_add(
-        dense(attn_flat, layer["wo"]),
-        lora_delta(layer.get("lora"), "wo", attn_flat, onehot),
-    )
+    with jax.named_scope("attention"):
+        q, k, v = _qkv(layer, h, config, onehot)
+        q = apply_rope(q, positions, config.rope_theta, config.rope_scaling)
+        k = apply_rope(k, positions, config.rope_theta, config.rope_scaling)
+        attn = chunked_prefill_attention(
+            q, k, v, pages, page_ids, chunk_start, valid_len,
+            config.attn_logit_softcap,
+            scale=config.attn_scale, window=layer.get("attn_window"),
+        )
+        attn_flat = attn.reshape(B, C, -1)
+        attn = _maybe_add(
+            dense(attn_flat, layer["wo"]),
+            lora_delta(layer.get("lora"), "wo", attn_flat, onehot),
+        )
     if config.sandwich_norms:
         attn = _norm(attn, layer["post_attn_norm"], config)
     x = residual + attn
@@ -697,33 +702,34 @@ def decode_step(
     for layer, pages in zip(params["layers"], kv_pages):
         residual = x
         h = _norm(x, layer["attn_norm"], config)
-        q, k, v = _qkv(layer, h, config, onehot)
-        q = apply_rope(q, positions, config.rope_theta, config.rope_scaling)
-        k = apply_rope(k, positions, config.rope_theta, config.rope_scaling)
-        pages = append_token_kv(
-            pages, k[:, 0], v[:, 0], page_table, pos, active, page_size
-        )
-        window = layer.get("attn_window")
-        if attention_fn is not None:
-            attn = attention_fn(q[:, 0], pages, page_table, seq_lens,
-                                window if window is not None
-                                else jnp.asarray(0, jnp.int32))
-        else:
-            attn = paged_attention(
-                q[:, 0],
-                pages,
-                page_table,
-                seq_lens,
-                logit_softcap=config.attn_logit_softcap,
-                use_pallas=use_pallas,
-                scale=config.attn_scale,
-                window=window,
+        with jax.named_scope("attention"):
+            q, k, v = _qkv(layer, h, config, onehot)
+            q = apply_rope(q, positions, config.rope_theta, config.rope_scaling)
+            k = apply_rope(k, positions, config.rope_theta, config.rope_scaling)
+            pages = append_token_kv(
+                pages, k[:, 0], v[:, 0], page_table, pos, active, page_size
             )
-        attn_flat = attn.reshape(B, 1, -1)
-        attn = _maybe_add(
-            dense(attn_flat, layer["wo"]),
-            lora_delta(layer.get("lora"), "wo", attn_flat, onehot),
-        )
+            window = layer.get("attn_window")
+            if attention_fn is not None:
+                attn = attention_fn(q[:, 0], pages, page_table, seq_lens,
+                                    window if window is not None
+                                    else jnp.asarray(0, jnp.int32))
+            else:
+                attn = paged_attention(
+                    q[:, 0],
+                    pages,
+                    page_table,
+                    seq_lens,
+                    logit_softcap=config.attn_logit_softcap,
+                    use_pallas=use_pallas,
+                    scale=config.attn_scale,
+                    window=window,
+                )
+            attn_flat = attn.reshape(B, 1, -1)
+            attn = _maybe_add(
+                dense(attn_flat, layer["wo"]),
+                lora_delta(layer.get("lora"), "wo", attn_flat, onehot),
+            )
         if config.sandwich_norms:
             attn = _norm(attn, layer["post_attn_norm"], config)
         x = residual + attn
@@ -785,32 +791,33 @@ def forward_ragged(
     for layer, pages in zip(params["layers"], kv_pages):
         residual = x
         h = _norm(x, layer["attn_norm"], config)
-        q, k, v = _qkv(layer, h, config, onehot)
-        q = apply_rope(q, positions, config.rope_theta, config.rope_scaling)
-        k = apply_rope(k, positions, config.rope_theta, config.rope_scaling)
-        pages = write_ragged_kv(
-            pages, k[:, 0], v[:, 0], page_table, token_seq, token_pos,
-            page_size,
-        )
-        window = layer.get("attn_window")
-        if attention_fn is not None:
-            attn = attention_fn(
-                q[:, 0], pages, page_table, q_start, q_len, kv_start,
-                window if window is not None else jnp.asarray(0, jnp.int32))
-        else:
-            attn = ragged_paged_attention(
-                q[:, 0], pages, page_table, q_start, q_len, kv_start,
-                logit_softcap=config.attn_logit_softcap,
-                use_pallas=use_pallas,
-                scale=config.attn_scale,
-                window=window,
-                dense_stride=dense_stride,
+        with jax.named_scope("attention"):
+            q, k, v = _qkv(layer, h, config, onehot)
+            q = apply_rope(q, positions, config.rope_theta, config.rope_scaling)
+            k = apply_rope(k, positions, config.rope_theta, config.rope_scaling)
+            pages = write_ragged_kv(
+                pages, k[:, 0], v[:, 0], page_table, token_seq, token_pos,
+                page_size,
             )
-        attn_flat = attn.reshape(T, 1, -1)
-        attn = _maybe_add(
-            dense(attn_flat, layer["wo"]),
-            lora_delta(layer.get("lora"), "wo", attn_flat, onehot),
-        )
+            window = layer.get("attn_window")
+            if attention_fn is not None:
+                attn = attention_fn(
+                    q[:, 0], pages, page_table, q_start, q_len, kv_start,
+                    window if window is not None else jnp.asarray(0, jnp.int32))
+            else:
+                attn = ragged_paged_attention(
+                    q[:, 0], pages, page_table, q_start, q_len, kv_start,
+                    logit_softcap=config.attn_logit_softcap,
+                    use_pallas=use_pallas,
+                    scale=config.attn_scale,
+                    window=window,
+                    dense_stride=dense_stride,
+                )
+            attn_flat = attn.reshape(T, 1, -1)
+            attn = _maybe_add(
+                dense(attn_flat, layer["wo"]),
+                lora_delta(layer.get("lora"), "wo", attn_flat, onehot),
+            )
         if config.sandwich_norms:
             attn = _norm(attn, layer["post_attn_norm"], config)
         x = residual + attn
@@ -872,22 +879,23 @@ def _pp_decode_block(config: LlamaConfig, page_size: int):
         positions = pos[:, None]
         residual = x
         h = _norm(x, layer["attn_norm"], config)
-        q, k, v = _qkv(layer, h, config, onehot)
-        q = apply_rope(q, positions, config.rope_theta, config.rope_scaling)
-        k = apply_rope(k, positions, config.rope_theta, config.rope_scaling)
-        pages_l = append_token_kv(
-            pages_l, k[:, 0], v[:, 0], page_table, pos, live, page_size)
-        seq_lens = jnp.where(live, pos + 1, 0)
-        attn = paged_attention(
-            q[:, 0], pages_l, page_table, seq_lens,
-            logit_softcap=config.attn_logit_softcap, use_pallas=False,
-            scale=config.attn_scale, window=layer.get("attn_window"),
-        )
-        attn_flat = attn.reshape(B, 1, -1)
-        attn_out = _maybe_add(
-            dense(attn_flat, layer["wo"]),
-            lora_delta(layer.get("lora"), "wo", attn_flat, onehot),
-        )
+        with jax.named_scope("attention"):
+            q, k, v = _qkv(layer, h, config, onehot)
+            q = apply_rope(q, positions, config.rope_theta, config.rope_scaling)
+            k = apply_rope(k, positions, config.rope_theta, config.rope_scaling)
+            pages_l = append_token_kv(
+                pages_l, k[:, 0], v[:, 0], page_table, pos, live, page_size)
+            seq_lens = jnp.where(live, pos + 1, 0)
+            attn = paged_attention(
+                q[:, 0], pages_l, page_table, seq_lens,
+                logit_softcap=config.attn_logit_softcap, use_pallas=False,
+                scale=config.attn_scale, window=layer.get("attn_window"),
+            )
+            attn_flat = attn.reshape(B, 1, -1)
+            attn_out = _maybe_add(
+                dense(attn_flat, layer["wo"]),
+                lora_delta(layer.get("lora"), "wo", attn_flat, onehot),
+            )
         if config.sandwich_norms:
             attn_out = _norm(attn_out, layer["post_attn_norm"], config)
         x = residual + attn_out

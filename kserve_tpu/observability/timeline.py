@@ -11,6 +11,13 @@ rolling sample windows (TTFT, ITL, queue wait, e2e, decode-step and
 prefill-chunk durations) that back `GET /admin/telemetry` — engine step
 introspection without a Prometheus scrape in the loop.
 
+`DispatchPhases` stamps the engine's loop the same way: every dispatch gets
+a serial number and six consecutive phases (admit, plan, launch, wait,
+route, yield) that tile one iteration of the loop, from the same clock.
+One stamp feeds three outputs: a `TraceAnnotation` on the profiler's clock
+while a capture runs, the `engine_dispatch_phase_seconds_total` counters,
+and one row per dispatch in the recorder's bounded ring.
+
 Derived metrics follow the serving-benchmark vocabulary of the vLLM/TGI
 comparative study (PAPERS.md, arXiv:2511.17593): TTFT is first token
 minus *received* (queue wait included — the client experiences it), ITL
@@ -27,6 +34,16 @@ from typing import Any, Dict, List, Optional
 # count/sum so means stay exact)
 MAX_EVENTS = 64
 MAX_ITL_SAMPLES = 4096
+# one row per dispatch; a 51 s benchmark window holds 80-135 of them
+MAX_DISPATCHES = 512
+
+#: the phases that tile one iteration of the engine's loop, in order
+PHASES = ("admit", "plan", "launch", "wait", "route", "yield")
+#: the columns of a dispatch row: flat, so a snapshot of the whole ring
+#: serialises in about a millisecond
+DISPATCH_COLUMNS = (
+    "serial", "launched_at", "program", "tokens", "width", "prefill_tokens",
+    "decode_tokens", *PHASES, "wait_lag", "compiled", "chained")
 
 
 class RequestTimeline:
@@ -39,7 +56,7 @@ class RequestTimeline:
         "prefill_start", "prefill_end", "first_token_at", "finished_at",
         "finish_reason", "n_prompt_tokens", "n_generated", "itls",
         "itl_overflow_n", "itl_overflow_sum", "events", "_last_token_at",
-        "recorded",
+        "recorded", "admit_dispatch", "first_token_dispatch",
     )
 
     def __init__(self, request_id: str, model_name: str = "",
@@ -68,6 +85,11 @@ class RequestTimeline:
         # recorder/metrics — makes terminal recording idempotent across
         # overlapping teardown paths (finish vs cancel vs stop)
         self.recorded = False
+        # serials of the dispatch that admitted the request and of the one
+        # that gave its first token: TTFT = queue wait + (their difference
+        # + 1) dispatch periods
+        self.admit_dispatch: Optional[int] = None
+        self.first_token_dispatch: Optional[int] = None
 
     # ---- stamps (first-write-wins where re-admission can re-stamp) ----
 
@@ -75,11 +97,12 @@ class RequestTimeline:
         if self.received is None:
             self.received = t
 
-    def mark_admitted(self, t: float) -> None:
+    def mark_admitted(self, t: float, dispatch: Optional[int] = None) -> None:
         # queue wait is measured to the FIRST admission; a preemption
         # re-seat must not shrink it retroactively
         if self.admitted is None:
             self.admitted = t
+            self.admit_dispatch = dispatch
 
     def mark_prefill_start(self, t: float) -> None:
         if self.prefill_start is None:
@@ -88,11 +111,12 @@ class RequestTimeline:
     def mark_prefill_end(self, t: float) -> None:
         self.prefill_end = t
 
-    def mark_token(self, t: float) -> None:
+    def mark_token(self, t: float, dispatch: Optional[int] = None) -> None:
         """One emitted token: the first sets TTFT, later ones append ITL."""
         self.n_generated += 1
         if self.first_token_at is None:
             self.first_token_at = t
+            self.first_token_dispatch = dispatch
         elif self._last_token_at is not None:
             gap = t - self._last_token_at
             if len(self.itls) < MAX_ITL_SAMPLES:
@@ -141,6 +165,13 @@ class RequestTimeline:
         return self._delta(self.received, self.finished_at)
 
     @property
+    def dispatches_to_first_token(self) -> Optional[int]:
+        """Dispatches from admission to the first token, both included."""
+        if self.admit_dispatch is None or self.first_token_dispatch is None:
+            return None
+        return self.first_token_dispatch - self.admit_dispatch + 1
+
+    @property
     def mean_itl_s(self) -> Optional[float]:
         n = len(self.itls) + self.itl_overflow_n
         if n == 0:
@@ -165,6 +196,8 @@ class RequestTimeline:
             "prefill_s": self.prefill_s,
             "e2e_s": self.e2e_s,
             "mean_itl_s": self.mean_itl_s,
+            "admit_dispatch": self.admit_dispatch,
+            "first_token_dispatch": self.first_token_dispatch,
             "events": self.events[:max_events],
         }
         if self.trace is not None:
@@ -206,6 +239,7 @@ class TimelineRecorder:
         self._e2e: deque = deque(maxlen=max_samples)
         self._step: deque = deque(maxlen=max_samples)
         self._prefill_chunk: deque = deque(maxlen=max_samples)
+        self.dispatches: deque = deque(maxlen=MAX_DISPATCHES)
         self.finished_count = 0
         self.preempted_count = 0
         self.aborted_count = 0
@@ -253,7 +287,12 @@ class TimelineRecorder:
     def record_prefill_chunk(self, seconds: float) -> None:
         self._prefill_chunk.append(seconds)
 
-    def snapshot(self, max_recent: int = 32) -> Dict[str, Any]:
+    def record_dispatch(self, row: list) -> None:
+        """One committed dispatch, as a flat row of DISPATCH_COLUMNS."""
+        self.dispatches.append(row)
+
+    def snapshot(self, max_recent: int = 32,
+                 now: Optional[float] = None) -> Dict[str, Any]:
         # [-0:] would slice the WHOLE ring, the opposite of "none"
         recent = list(self.timelines)[-max_recent:] if max_recent > 0 else []
         return {
@@ -270,4 +309,98 @@ class TimelineRecorder:
             "decode_step_s": percentiles(self._step),
             "prefill_chunk_s": percentiles(self._prefill_chunk),
             "recent": [tl.to_dict() for tl in reversed(recent)],
+            # the engine clock's reading at the snapshot, so a reader can
+            # cut the ring to "the last N seconds" on the ring's own clock
+            "now": now,
+            "dispatches": {"columns": list(DISPATCH_COLUMNS),
+                           "rows": list(self.dispatches)},
         }
+
+
+class DispatchPhases:
+    """Stamps the phases of the engine's loop.  `mark(phase)` closes the
+    phase under way at the clock's reading and opens the next; `launched`
+    notes what was dispatched; `commit` closes the iteration and returns
+    the row of DISPATCH_COLUMNS of the oldest dispatch not yet committed
+    (the engine appends it to the recorder's ring and feeds its counters
+    from it).  An iteration that dispatched nothing returns None and is
+    dropped, so idle time is in no phase.  Where the dense path chains a
+    dispatch on the one in flight, two launches are open at once: phases
+    go to the row committed next, as they occur.
+
+    `annotate` is `jax.profiler.TraceAnnotation` in the engine: each phase
+    is then a host span `engine.<phase>` on the profiler's clock while a
+    capture runs, and one flag test when none does."""
+
+    def __init__(self, clock, annotate=None):
+        self._clock = clock
+        self._annotate = annotate
+        self.serial = 1  # of the oldest dispatch not yet committed
+        self._span = None
+        self._launches: deque = deque()
+        self._reset(None)
+
+    def _reset(self, now: Optional[float]) -> None:
+        self._phase: Optional[str] = None
+        self._since = now
+        self._seconds = dict.fromkeys(PHASES, 0.0)
+        self._wait_lag = 0.0
+
+    def mark(self, phase: str) -> float:
+        now = self._clock.now()
+        if self._phase is not None:
+            self._seconds[self._phase] += now - self._since
+        self._phase, self._since = phase, now
+        if self._annotate is not None:
+            self.close()
+            self._span = self._annotate("engine." + phase,
+                                        dispatch=self.serial)
+            self._span.__enter__()
+        return now
+
+    def close(self) -> None:
+        """End the host span under way (the loop is leaving)."""
+        if self._span is not None:
+            self._span.__exit__(None, None, None)
+            self._span = None
+
+    def launched(self, program: str, tokens: int, width: int,
+                 prefill_tokens: int, decode_tokens: int,
+                 compiled: bool = False, chained: bool = False) -> None:
+        """What the `launch` phase under way dispatched."""
+        self._launches.append([
+            self._since, program, tokens, width, prefill_tokens,
+            decode_tokens, int(compiled), int(chained)])
+
+    def resumed(self, ready_at: Optional[float]) -> None:
+        """The loop took a fetched result up `now - ready_at` after the
+        fetch worker had it on the host: opens `route`."""
+        now = self.mark("route")
+        if ready_at is not None:
+            self._wait_lag += max(0.0, now - ready_at)
+
+    def commit(self) -> Optional[list]:
+        """Close the iteration.  The next one's `admit` opens at the same
+        reading, so that consecutive iterations leave no time between them
+        in no phase; a loop that goes idle calls `pause` instead."""
+        now = self._clock.now()
+        if self._phase is not None:
+            self._seconds[self._phase] += now - self._since
+        self.close()
+        seconds = self._seconds
+        wait_lag = min(self._wait_lag, seconds["wait"])
+        self._reset(now)
+        self._phase = "admit"
+        if not self._launches:
+            return None
+        launched_at, *what, compiled, chained = self._launches.popleft()
+        row = [self.serial, launched_at, *what,
+               *(seconds[p] for p in PHASES), wait_lag, compiled, chained]
+        self.serial += 1
+        return row
+
+    def pause(self) -> None:
+        """The loop waits for work: what follows is in no phase until the
+        next `mark`."""
+        self.close()
+        self._reset(None)

@@ -344,6 +344,7 @@ def _paged_attention_pallas_packed(
     packed_out = _pallas_call(kernel, B, sb, nq, 128, kv_packed)(
         out_shape=jax.ShapeDtypeStruct((B, nq, 128), jnp.float32),
         interpret=interpret,
+        name="paged_attention_decode_packed",
     )(page_table, seq_lens, q2, kv_packed)
     # fold the parity halves (plain XLA; f32 before the final cast)
     out = packed_out.reshape(B, nq, 2, 64).sum(axis=2)
@@ -388,6 +389,7 @@ def paged_attention_pallas(
     return _pallas_call(kernel, B, sb, nq, d, kv_pages)(
         out_shape=jax.ShapeDtypeStruct((B, nq, d), q.dtype),
         interpret=interpret,
+        name="paged_attention_decode",
     )(page_table, seq_lens, q, kv_pages)
 
 
@@ -727,6 +729,7 @@ def _dense_ragged_call(q, pages, scales, page_table, q_len, kv_start, win,
         ),
         out_shape=jax.ShapeDtypeStruct((T, nq, d), q.dtype),
         interpret=interpret,
+        name="ragged_paged_attention_dense",
     )(page_table, kv_start, q_len, win, *operands)
 
 
@@ -822,5 +825,6 @@ def ragged_paged_attention_pallas(
         ),
         out_shape=jax.ShapeDtypeStruct((T, nq, d), q.dtype),
         interpret=interpret,
+        name="ragged_paged_attention",
     )(block_seq, block_qoff, page_table, kv_start, q_len, win,
       *operands)

@@ -29,7 +29,9 @@ def one_line(text, limit=200):
 
 def test_top_level_keys_and_sizes():
     assert set(MANIFEST) == {"command", "paths", "run_seconds", "configs",
-                             "workloads", "end_to_end", "per_layer"}
+                             "workloads", "end_to_end", "per_layer",
+                             "trace_in_run"}
+    assert MANIFEST["trace_in_run"] is True  # the harness takes --trace 2
     assert os.path.getsize(os.path.join(ROOT, "BENCHMARK.json")) <= 64 * 1024
     assert 1 <= MANIFEST["run_seconds"] <= 51
     assert 1 <= len(MANIFEST["paths"]) <= 16

@@ -25,7 +25,10 @@ from kserve_tpu.metrics import (
 )
 from kserve_tpu.models.llama import LlamaConfig
 from kserve_tpu.observability import (
+    DISPATCH_COLUMNS,
+    PHASES,
     PROFILER_KEY,
+    DispatchPhases,
     ProfilerSession,
     RequestTimeline,
     TimelineRecorder,
@@ -531,6 +534,62 @@ class TestIntrospectionEndpoints:
             await app[PROFILER_KEY].wait()
 
     @async_test
+    async def test_admin_profile_start_stop_and_409(self, tmp_path, monkeypatch):
+        """start / stop by action; 409 for a second start, for a stop with
+        nothing to stop and for a second stop; while a slow `stop_trace`
+        runs (in a worker thread) the server goes on answering and the
+        capture counts as active."""
+        import threading
+
+        import jax.profiler
+
+        started, release = [], threading.Event()
+        monkeypatch.setattr(
+            jax.profiler, "start_trace",
+            lambda target, profiler_options=None: started.append(
+                (target, profiler_options.python_tracer_level)))
+        monkeypatch.setattr(
+            jax.profiler, "stop_trace", lambda: release.wait(30.0))
+        server = self._server(profiler=ProfilerSession())
+        app = server.create_application()
+        async with TestClient(TestServer(app)) as client:
+            res = await client.post("/admin/profile", json={"action": "stop"})
+            assert res.status == 409
+            res = await client.post(
+                "/admin/profile", json={"action": "start", "dir": str(tmp_path)})
+            assert res.status == 202
+            info = await res.json()
+            assert info["seconds"] is None and info["python"] is False
+            assert started == [(info["dir"], 0)]  # the python tracer is off
+            res = await client.post("/admin/profile", json={"action": "start"})
+            assert res.status == 409
+            stopping = asyncio.ensure_future(
+                client.post("/admin/profile", json={"action": "stop"}))
+            for _ in range(200):  # until stop_trace is under way
+                if app[PROFILER_KEY]._stopping is not None:
+                    break
+                await asyncio.sleep(0.005)
+            assert not stopping.done()
+            t0 = asyncio.get_running_loop().time()
+            tele = await (await client.get("/admin/telemetry")).json()
+            assert asyncio.get_running_loop().time() - t0 < 1.0
+            assert tele["profiler"]["active"] is True
+            res = await client.post("/admin/profile", json={"action": "stop"})
+            assert res.status == 409  # it is stopping already
+            release.set()
+            res = await stopping
+            assert res.status == 200 and "stop_s" in await res.json()
+            tele = await (await client.get("/admin/telemetry")).json()
+            assert tele["profiler"]["active"] is False
+            res = await client.post(
+                "/admin/profile", json={"action": "start", "python": True})
+            assert res.status == 202 and started[-1][1] == 1
+            assert (await client.post(
+                "/admin/profile", json={"action": "stop"})).status == 200
+            res = await client.post("/admin/profile", json={"action": "pause"})
+            assert res.status == 400
+
+    @async_test
     async def test_admin_profile_rejects_bad_seconds(self):
         server = self._server(profiler=ProfilerSession(clock=_GateClock()))
         async with TestClient(TestServer(server.create_application())) as client:
@@ -538,6 +597,193 @@ class TestIntrospectionEndpoints:
             assert res.status == 400
             res = await client.post("/admin/profile", json={"seconds": "zzz"})
             assert res.status == 400
+
+
+# ------------------------------------------------------- dispatch phases
+
+
+class _TickClock(Clock):
+    """Every reading is one second later than the last: each stamp of the
+    engine's loop is told apart, and sums come out as whole numbers."""
+
+    def __init__(self):
+        self._now = 0.0
+
+    def now(self) -> float:
+        self._now += 1.0
+        return self._now
+
+
+def _row(row):
+    return dict(zip(DISPATCH_COLUMNS, row))
+
+
+class TestDispatchPhases:
+    def test_phase_sums_wait_lag_and_dropped_iterations(self):
+        clock = FakeClock()
+        phases = DispatchPhases(clock)
+        script = (("admit", 1.0), ("plan", 2.0), ("launch", 3.0), ("wait", 10.0))
+        for phase, seconds in script:
+            phases.mark(phase)
+            if phase == "launch":
+                phases.launched("mixed", 32, 8, 24, 8, compiled=True)
+            clock.advance(seconds)
+        phases.resumed(clock.now() - 2.5)  # on the host 2.5 s before the loop
+        clock.advance(0.5)
+        phases.mark("yield")
+        clock.advance(4.0)
+        assert phases.serial == 1
+        row = _row(phases.commit())
+        assert row == {
+            "serial": 1, "launched_at": 3.0, "program": "mixed", "tokens": 32,
+            "width": 8, "prefill_tokens": 24, "decode_tokens": 8,
+            "admit": 1.0, "plan": 2.0, "launch": 3.0, "wait": 10.0,
+            "route": 0.5, "yield": 4.0, "wait_lag": 2.5, "compiled": 1,
+            "chained": 0}
+        assert sum(row[p] for p in PHASES) == clock.now()
+        assert phases.serial == 2
+        # an iteration that launched nothing is dropped, and so is idle time
+        phases.mark("admit")
+        clock.advance(7.0)
+        assert phases.commit() is None
+        phases.pause()
+        clock.advance(100.0)
+        phases.mark("admit")
+        clock.advance(1.0)
+        phases.mark("launch")
+        phases.launched("mixed", 16, 8, 0, 2)
+        row = _row(phases.commit())
+        assert (row["serial"], row["admit"], row["launch"]) == (2, 1.0, 0.0)
+
+    def test_chained_launches_commit_oldest_first(self):
+        clock = FakeClock()
+        phases = DispatchPhases(clock)
+        phases.mark("launch")
+        phases.launched("mixed_decode", 4, 8, 0, 4)
+        clock.advance(1.0)
+        phases.mark("launch")
+        phases.launched("mixed_decode", 4, 8, 0, 4, chained=True)
+        first, second = _row(phases.commit()), _row(phases.commit())
+        assert (first["serial"], first["chained"], first["launched_at"]) == (1, 0, 0.0)
+        assert (second["serial"], second["chained"], second["launched_at"]) == (2, 1, 1.0)
+        assert phases.commit() is None
+
+    def test_annotations_open_and_close_once_per_phase(self):
+        opened, closed = [], []
+
+        class Span:
+            def __init__(self, name, **kwargs):
+                opened.append((name, kwargs))
+
+            def __enter__(self):
+                return self
+
+            def __exit__(self, *exc):
+                closed.append(1)
+
+        phases = DispatchPhases(FakeClock(), annotate=Span)
+        for phase in PHASES:
+            phases.mark(phase)
+        phases.commit()
+        assert opened == [("engine." + p, {"dispatch": 1}) for p in PHASES]
+        assert len(closed) == len(PHASES)
+
+    def test_ring_is_bounded(self):
+        rec = TimelineRecorder()
+        for i in range(600):
+            rec.record_dispatch([i])
+        snap = rec.snapshot(now=12.5)
+        assert len(snap["dispatches"]["rows"]) == 512
+        assert snap["dispatches"]["rows"][-1] == [599]
+        assert snap["dispatches"]["columns"] == list(DISPATCH_COLUMNS)
+        assert snap["now"] == 12.5
+
+    @async_test
+    async def test_engine_phases_tile_the_launch_to_launch_interval(self):
+        """Under a clock that ticks at every reading, what lies between
+        two consecutive launches is, to the tick, the first dispatch's
+        launch, wait, route and yield and the second's admit and plan; the
+        counters hold the ring's sums; a request notes the dispatch that
+        admitted it and the one that gave its first token."""
+        label = "obs-phases"
+        engine = make_engine(clock=_TickClock(), metrics_label=label)
+        await engine.start()
+        params = SamplingParams(max_tokens=20, temperature=0.0, ignore_eos=True)
+        await asyncio.gather(
+            collect(engine.generate(list(range(1, 40)), params)),
+            collect(engine.generate([4, 5, 6], params)))
+        snap = engine.telemetry_snapshot()
+        await engine.stop()
+        rows = [_row(r) for r in snap["dispatches"]["rows"]]
+        assert len(rows) >= 4
+        assert [r["serial"] for r in rows] == list(range(1, len(rows) + 1))
+        assert {r["program"] for r in rows} == {"mixed"}
+        for a, b in zip(rows, rows[1:]):
+            between = (a["launch"] + a["wait"] + a["route"] + a["yield"]
+                       + b["admit"] + b["plan"])
+            assert b["launched_at"] - a["launched_at"] == between
+        assert all(0.0 <= r["wait_lag"] <= r["wait"] for r in rows)
+        assert rows[0]["prefill_tokens"] > 0 and rows[-1]["decode_tokens"] > 0
+        assert snap["now"] > rows[-1]["launched_at"]
+
+        def counter(name, **labels):
+            return REGISTRY.get_sample_value(
+                name, {"model_name": label, **labels}) or 0.0
+
+        assert counter("engine_dispatches_total", program="mixed") == len(rows)
+        for phase in (*PHASES, "wait_lag"):
+            assert counter("engine_dispatch_phase_seconds_total",
+                           phase=phase) == sum(r[phase] for r in rows)
+        # both are admitted before the first dispatch, whose 32-token budget
+        # the long prompt (39 tokens) fills alone: its second chunk and the
+        # short prompt ride the second, which gives both their first token
+        spans = [(t["admit_dispatch"], t["first_token_dispatch"])
+                 for t in snap["recent"]]
+        assert spans == [(1, 2), (1, 2)]
+        assert counter("engine_first_token_dispatches_count") == 2
+        assert counter("engine_first_token_dispatches_sum") == 4
+
+    def test_compile_seconds_counted_on_a_forced_retrace(self):
+        import jax
+        import jax.numpy as jnp
+
+        from kserve_tpu.engine.compiled import _CompileCounting
+
+        def seconds():
+            return REGISTRY.get_sample_value(
+                "engine_xla_compile_seconds_total",
+                {"program": "obs-retrace"}) or 0.0
+
+        fn = _CompileCounting("obs-retrace", jax.jit(lambda x: x * 2 + 1))
+        fn(jnp.ones((4,)))
+        first = seconds()
+        assert fn.compiles == 1 and first > 0.0
+        fn(jnp.ones((4,)))  # same shape: a cache hit costs nothing
+        assert fn.compiles == 1 and seconds() == first
+        fn(jnp.ones((8,)))  # another shape: a retrace, timed again
+        assert fn.compiles == 2 and seconds() > first
+
+    @async_test
+    async def test_cpu_capture_holds_the_engines_phases(self, tmp_path):
+        """A capture of a tiny engine on the CPU: the host plane holds the
+        engine's own spans, on the profiler's clock."""
+        from jax.profiler import ProfileData
+
+        engine = make_engine(metrics_label="obs-capture")
+        await engine.start()
+        params = SamplingParams(max_tokens=8, temperature=0.0, ignore_eos=True)
+        await collect(engine.generate([4, 5, 6], params))  # compile first
+        session = ProfilerSession(default_dir=str(tmp_path))
+        info = await session.start()
+        assert info["python"] is False and session.active
+        await collect(engine.generate([7, 8, 9], params))
+        stopped = await session.stop()
+        await engine.stop()
+        assert not session.active and stopped["stop_s"] >= 0.0
+        (path,) = list(tmp_path.rglob("*.xplane.pb"))
+        names = {e.name for plane in ProfileData.from_file(str(path)).planes
+                 for line in plane.lines for e in line.events}
+        assert {"engine." + p for p in PHASES} <= names
 
 
 # ------------------------------------------------------- trace propagation
