@@ -102,11 +102,12 @@ class EngineConfig:
     # sp (raises at init).
     pp: int = 1
     pp_microbatches: int = 0  # 0 = auto (pp when it divides the batch)
-    # None = auto (ops/attention.py): the fused Pallas kernel for
-    # long-context decode (page-table width >= PALLAS_MIN_PAGES, head_dim %
-    # 128 == 0), the XLA gather for short context — each where it measures
-    # faster.  True forces the kernel (raises on unsupported head_dim);
-    # False forces the gather.
+    # None = auto (ops/attention.py): decode attention takes the fused
+    # Pallas kernel wherever it measured faster on the chip — per compiled
+    # shape, from the size of one page (pallas_min_pages; docs/kernels.md
+    # "Kernel against gather") — and the XLA gather elsewhere and for what
+    # the kernel cannot do.  True forces the kernel (raises on unsupported
+    # head_dim); False forces the gather.
     use_pallas: Optional[bool] = None
     # decode steps executed on-device per host round-trip (lax.scan inner
     # loop).  >1 amortizes the host<->device round-trip; streaming

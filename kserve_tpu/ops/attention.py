@@ -199,17 +199,48 @@ def paged_attention_xla(
     return out[:, 0].astype(q.dtype)
 
 
-# Auto-dispatch threshold, in page-table width (pages): the gather path
-# writes a [B, width*ps, nkv, d] copy of the live KV before attention, the
-# kernel streams pages once, so the copy's extra traffic grows with width
-# while the kernel's serial per-block grid cost does not.  Where the two
-# cross on this installation is not measured (PERF.md); 64 is carried from
-# the legacy decode programs and re-deriving it is a perf_opt with a cell.
-PALLAS_MIN_PAGES = 64
+def pallas_min_pages(d: int, kv_heads: int, page_size: int,
+                     batch: int) -> Optional[int]:
+    """The narrowest page table (in pages) from which the decode kernel
+    beats the gather for one compiled shape: 0 = at every width, None = at
+    no width.  Set from per-call times measured on a v5e at widths 8-128
+    (the table in docs/kernels.md "Kernel against gather", re-measured by
+    scripts/decode_attention_crossover.py), not carried.
+
+    Head size 128: the size of one page DMA decides.  The kernel spends
+    ~0.45 us per (8-lane block, page) iteration whatever the page holds
+    and streams at ~630 GB/s once a page covers that; the gather moves the
+    same pages three times but in a few large operations.  K+V pages of
+    32 KB and more (4+ KV heads x 16 tokens): the kernel wins at every
+    width, 1.2-1.6x at 8 pages, 6-8x from 40 up, at 8, 16 and 48 lanes.
+    16 KB (2 KV heads, a tp=4 shard of 8): the gather is 1-10 % ahead up
+    to 56 pages, the kernel 1.2-1.9x from 64.  8 KB (1 KV head): the
+    gather is 2-3x ahead at every width.
+
+    Head size 64 (the packed kernel): its [.., ps, 64] -> [.., ps/2, 128]
+    view of the cache is NOT free on the chip, XLA re-lays one layer's
+    whole cache out on every call (0.13 ms at 2300 pages of 8 KV heads,
+    0.9 ms at 9200), so where it wins depends on the cache's size, which
+    a width cannot express.  64 is where it has stopped losing at every
+    cache size measured, 128 for pages of 2 KV heads; 8 lanes never pay
+    the copy back.  Removing the copy, not these numbers, is the repair.
+
+    `kv_heads` is what ONE device holds (the local shard under shard_map),
+    so a model's answer changes with its tp, from the shape alone."""
+    if d == 64:
+        if batch < 16:
+            return None
+        return 64 if kv_heads >= 4 else 128
+    page_bytes = 2 * kv_heads * page_size * d * 2  # K and V, bf16
+    if page_bytes >= 32 * 1024:
+        return 0
+    if page_bytes >= 16 * 1024:
+        return 64
+    return None
 
 
 def _should_use_pallas(d: int, quantized: bool, table_width: int, batch: int,
-                       backend: str, page_size) -> bool:
+                       backend: str, page_size, kv_heads: int) -> bool:
     """The use_pallas=None auto-dispatch predicate (factored out so tests
     assert the production decision, not a re-inlined copy)."""
     from .pallas_paged_attention import _pick_sb
@@ -220,17 +251,19 @@ def _should_use_pallas(d: int, quantized: bool, table_width: int, batch: int,
         # even page_size; auto must fall back to the gather, not raise
         or (d == 64 and page_size is not None and page_size % 2 == 0)
     )
-    return (
+    if not (
         supported_head
         and not quantized  # kernel reads bf16 pages only (today)
-        and table_width >= PALLAS_MIN_PAGES
         # a batch with no divisor <= MAX_SB would run the serialized
         # sb=1 kernel shape, which loses to the gather
         and _pick_sb(batch) > 1
         # Mosaic only lowers on TPU; CPU smoke runs of a real model at
         # long context must take the gather, not fail to compile
         and backend == "tpu"
-    )
+    ):
+        return False
+    min_pages = pallas_min_pages(d, kv_heads, page_size, batch)
+    return min_pages is not None and table_width >= min_pages
 
 
 def make_sharded_paged_attention(
@@ -513,27 +546,33 @@ def describe_attention_dispatch(model_config, engine_config,
 
     `mixed` is the ragged attention of the unified program; `decode` is
     the single-token decode attention (the mixed program's scan tail AND
-    the legacy decode programs a logprobs/penalty lane falls back to),
-    whose kernel is additionally width-gated per compiled shape."""
+    the legacy decode programs a logprobs/penalty lane falls back to).
+    Its kernel is gated per compiled shape by `pallas_min_pages`:
+    `decode_pallas_min_pages` is the width it starts at, None where it
+    runs at every width (or, with `decode: xla_gather`, at none)."""
     mc, cfg = model_config, engine_config
     quantized = cfg.kv_quant == "int8"
+    min_pages = None
     if cfg.use_pallas is None:
         ragged = _should_use_ragged_pallas(mc.head_dim, backend, quantized)
+        kv_heads = mc.n_kv_heads // cfg.tp  # one device's
+        # the widest table this replica compiles: is the kernel built at all
         decode = (
             mc.sliding_window <= 0 and mc.attn_scale is None
             and _should_use_pallas(
-                mc.head_dim, quantized, PALLAS_MIN_PAGES,
-                cfg.max_batch_size, backend, cfg.page_size))
+                mc.head_dim, quantized, cfg.max_pages_per_seq,
+                cfg.max_batch_size, backend, cfg.page_size, kv_heads))
+        if decode:
+            min_pages = pallas_min_pages(
+                mc.head_dim, kv_heads, cfg.page_size,
+                cfg.max_batch_size) or None
     else:
         ragged = decode = bool(cfg.use_pallas)
     return {
         "backend": backend,
         "mixed": "pallas_ragged" if ragged else "xla_ragged_gather",
         "decode": "pallas_decode" if decode else "xla_gather",
-        # the decode kernel only replaces the gather from this page-table
-        # width up (auto-dispatch); below it every shape takes the gather
-        "decode_pallas_min_pages": (
-            PALLAS_MIN_PAGES if decode and cfg.use_pallas is None else None),
+        "decode_pallas_min_pages": min_pages,
         # tp/sp>1: both run per shard inside shard_map over the model axis
         "shard_map": cfg.tp > 1 or cfg.sp > 1,
     }
@@ -551,11 +590,14 @@ def paged_attention(
 ) -> jnp.ndarray:
     """Dispatch between the fused Pallas kernel and the XLA gather path.
 
-    use_pallas=None (default) auto-selects: the kernel for long-context
-    batches (page-table width >= PALLAS_MIN_PAGES and a supported head_dim),
-    the gather otherwise — each path where it measures faster (table above).
-    True forces the kernel (raising on unsupported head_dim rather than
-    silently benchmarking the gather); False forces the gather."""
+    use_pallas=None (default) auto-selects per compiled shape: the kernel
+    wherever it measured faster on the chip (`pallas_min_pages`: for pages
+    of 4+ KV heads x 128 at every table width), the gather for what the
+    kernel cannot do (CPU, int8 pages, sliding windows, scale overrides,
+    other head sizes, a batch with no divisor up to MAX_SB) and for the
+    small-page shapes where it loses.  True forces the kernel (raising on
+    an unsupported head_dim rather than silently benchmarking the gather);
+    False forces the gather."""
     d = q.shape[-1]
     quantized = isinstance(kv_pages, tuple)
     if window is not None:
@@ -573,10 +615,10 @@ def paged_attention(
         # sliding window): auto-dispatch falls back rather than raising
         use_pallas = False
     if use_pallas is None:
-        page_size = None if quantized else int(kv_pages.shape[3])
+        pages = kv_pages[0] if quantized else kv_pages
         use_pallas = _should_use_pallas(
             d, quantized, int(page_table.shape[1]), int(q.shape[0]),
-            jax.default_backend(), page_size,
+            jax.default_backend(), int(pages.shape[3]), int(pages.shape[2]),
         )
     if use_pallas:
         if quantized:

@@ -244,8 +244,8 @@ def _packed_decode_kernel(
     The natural [ps, 64] layout would pad the lane dim to 128 (half of
     VMEM wasted) and Mosaic rejects both trailing-dim DMA slices and the
     in-kernel shape-cast that would unpack a packed row.  Instead the
-    CALLER bit-casts the cache to [.., ps/2, 128] (contiguous memory, free
-    view) and everything inside stays 128-lane aligned:
+    CALLER reshapes the cache to [.., ps/2, 128] (a copy of the array on
+    the chip, not a free view) and everything inside stays 128-lane aligned:
     - q arrives duplicated: q2 = [q | q], so one dot against a half-masked
       K row contracts exactly one token's 64 dims
     - scores for even/odd tokens are two dots against lane-masked K; each
@@ -329,8 +329,11 @@ def _paged_attention_pallas_packed(
         raise ValueError(f"packed kernel requires even page_size, got {ps}")
     sb = _pick_sb(B)
     scale = float(1.0 / (d ** 0.5))
-    # contiguous-memory view: [.., ps, 64] -> [.., ps/2, 128] (two tokens
-    # per lane row); XLA lowers this to a bitcast, not a copy
+    # [.., ps, 64] -> [.., ps/2, 128] (two tokens per lane row): the same
+    # bytes in row-major order, but NOT a bitcast under the TPU's tiled
+    # layout — XLA copies the whole array on every call (measured:
+    # docs/kernels.md "Kernel against gather"), which is why auto-dispatch
+    # gates this kernel harder than the 128-lane one
     kv_packed = kv_pages.reshape(num_pages_total, 2, nkv, ps // 2, 128)
     q2 = jnp.concatenate([q, q], axis=-1)  # [B, nq, 128]
     kernel = functools.partial(

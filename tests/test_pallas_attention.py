@@ -56,6 +56,29 @@ class TestPallasPagedAttention:
         assert_paths_match(*make_case(B=B, seed=2, d=d))
 
     @pytest.mark.parametrize("d", [64, 128])
+    @pytest.mark.parametrize("width", [8, 40, 64])
+    def test_matches_xla_at_cell_widths(self, width, d):
+        """The table widths the benchmark's cells compile (decode-sat 8 to
+        40, chat 64 and up), 16-token pages, two grid blocks: ragged
+        lengths, one lane of length 1, one full lane and one EMPTY lane.
+        An empty lane's output is never read (the paths average different
+        masked positions there); it must be finite on both."""
+        B, ps = 16, 16
+        q, kv, pt, lens = make_case(
+            B=B, nq=8, nkv=2, d=d, ps=ps, num_pages=B * width + 1,
+            max_pages=width, seed=width)
+        lens = np.array(lens)
+        lens[3], lens[5], lens[B - 1] = 1, 0, width * ps
+        live = lens > 0
+        lens = jnp.asarray(lens)
+        ref = np.asarray(paged_attention_xla(q, kv, pt, lens))
+        got = np.asarray(
+            paged_attention_pallas(q, kv, pt, lens, interpret=True))
+        np.testing.assert_allclose(got[live], ref[live], rtol=2e-5, atol=2e-5)
+        assert np.isfinite(got).all() and np.isfinite(ref).all()
+        assert np.abs(ref[live]).max() > 1e-3
+
+    @pytest.mark.parametrize("d", [64, 128])
     def test_gqa_groups(self, d):
         assert_paths_match(*make_case(nq=16, nkv=2, d=d))
 
@@ -87,35 +110,40 @@ class TestPallasPagedAttention:
         with pytest.raises(ValueError, match="even page_size"):
             paged_attention_pallas(q, kv, pt, lens, interpret=True)
 
-    def test_auto_dispatch_predicate(self):
-        """The production predicate (attention._should_use_pallas) must
-        auto-select the kernel for llama3_1b-class d=64 at long context on
-        TPU — and fall back on every disqualifier."""
-        from kserve_tpu.ops.attention import PALLAS_MIN_PAGES, _should_use_pallas
+    @pytest.mark.parametrize("width", [8, 16, 32, 40, 64, 128])
+    def test_auto_dispatch_predicate(self, width):
+        """The production predicate (attention._should_use_pallas) at the
+        page-table widths the benchmark's cells compile: Qwen3-4B's pages
+        (8 KV heads x 128, 64 KB) take the kernel at EVERY one of them —
+        the gate measured on the chip, docs/kernels.md "Kernel against
+        gather" — and fall back on every disqualifier."""
+        from kserve_tpu.ops.attention import _should_use_pallas
 
-        W = PALLAS_MIN_PAGES
-        ok = dict(d=64, quantized=False, table_width=W, batch=48,
-                  backend="tpu", page_size=16)
+        ok = dict(d=128, quantized=False, table_width=width, batch=48,
+                  backend="tpu", page_size=16, kv_heads=8)
         assert _should_use_pallas(**ok)
-        assert _should_use_pallas(**{**ok, "d": 128})
         assert _should_use_pallas(**{**ok, "d": 256})
+        assert _should_use_pallas(**{**ok, "batch": 8})
+        assert _should_use_pallas(**{**ok, "kv_heads": 4})  # 32 KB pages
         # disqualifiers, one at a time
         assert not _should_use_pallas(**{**ok, "d": 96})
-        assert not _should_use_pallas(**{**ok, "page_size": 7})  # odd ps @ d=64
-        assert _should_use_pallas(**{**ok, "d": 128, "page_size": 7})  # main kernel: ps free
+        assert not _should_use_pallas(**{**ok, "d": 64, "page_size": 7})  # odd ps @ d=64
         assert not _should_use_pallas(**{**ok, "quantized": True})
-        assert not _should_use_pallas(**{**ok, "table_width": W - 1})
         assert not _should_use_pallas(**{**ok, "batch": 13})  # prime > MAX_SB
         assert not _should_use_pallas(**{**ok, "backend": "cpu"})
+        # small pages: the measured crossover, not a carried constant
+        assert _should_use_pallas(**{**ok, "kv_heads": 2}) == (width >= 64)
+        assert _should_use_pallas(**{**ok, "page_size": 7}) == (width >= 64)
+        assert not _should_use_pallas(**{**ok, "kv_heads": 1})
 
     def test_scale_override_auto_falls_back(self):
         """A non-default scale (query_pre_attn_scalar without a sliding
         window) must auto-dispatch to the gather, not raise at trace time;
         an explicit use_pallas=True stays loud."""
-        from kserve_tpu.ops.attention import PALLAS_MIN_PAGES, paged_attention
+        from kserve_tpu.ops.attention import paged_attention
 
-        q, kv, pt, lens = make_case(B=8, d=64, max_pages=PALLAS_MIN_PAGES,
-                                    num_pages=PALLAS_MIN_PAGES * 8 + 1)
+        q, kv, pt, lens = make_case(B=8, d=64, max_pages=64,
+                                    num_pages=64 * 8 + 1)
         ref = paged_attention_xla(q, kv, pt, lens, scale=0.5)
         got = paged_attention(q, kv, pt, lens, scale=0.5)  # auto
         np.testing.assert_allclose(np.asarray(got), np.asarray(ref),

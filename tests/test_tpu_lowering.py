@@ -187,18 +187,146 @@ class TestDispatchReport:
         on_tpu = self._report(qwen)
         assert on_tpu["mixed"] == "pallas_ragged"
         assert on_tpu["decode"] == "pallas_decode"
-        assert on_tpu["decode_pallas_min_pages"] == att.PALLAS_MIN_PAGES
+        # 8 KV heads x 128 x 16 tokens = 64 KB pages: the kernel at every
+        # table width (measured on the chip, docs/kernels.md)
+        assert on_tpu["decode_pallas_min_pages"] is None
         on_cpu = self._report(qwen, backend="cpu")
         assert (on_cpu["mixed"], on_cpu["decode"]) == (
             "xla_ragged_gather", "xla_gather")
-        # head_dim 64: the ragged kernel is out, the packed decode kernel in
-        d64 = self._report(LlamaConfig.llama3_1b())
-        assert (d64["mixed"], d64["decode"]) == (
-            "xla_ragged_gather", "pallas_decode")
+        # head_dim 64: the ragged kernel is out; the packed decode kernel
+        # is in from 64 pages for 16+ lanes (8 never pay its cache copy back)
+        d64 = self._report(LlamaConfig.llama3_1b(), max_batch_size=48)
+        assert (d64["mixed"], d64["decode"], d64["decode_pallas_min_pages"]
+                ) == ("xla_ragged_gather", "pallas_decode", 64)
+        assert self._report(LlamaConfig.llama3_1b())["decode"] == "xla_gather"
         # int8 pages, windows and scale overrides keep decode on the gather
         assert self._report(qwen, kv_quant="int8")["mixed"] == (
             "xla_ragged_gather")
         gemma = self._report(LlamaConfig.gemma2_2b())
         assert (gemma["mixed"], gemma["decode"]) == (
             "pallas_ragged", "xla_gather")
-        assert self._report(qwen, tp=4)["shard_map"]
+        # the gate reads ONE device's pages: 2 local KV heads (16 KB) keep
+        # the measured crossover, 1 (8 KB) keeps the gather at every width
+        tp4 = self._report(qwen, tp=4)
+        assert tp4["shard_map"]
+        assert (tp4["decode"], tp4["decode_pallas_min_pages"]) == (
+            "pallas_decode", 64)
+        tp8 = self._report(qwen, tp=8)
+        assert (tp8["decode"], tp8["decode_pallas_min_pages"]) == (
+            "xla_gather", None)
+        # a forced path reports no gate
+        assert self._report(qwen, use_pallas=True)[
+            "decode_pallas_min_pages"] is None
+
+    @pytest.mark.parametrize("kv_heads, page_size, batch, expected", [
+        (8, 16, 48, 0), (8, 16, 8, 0), (4, 16, 48, 0), (8, 8, 48, 0),
+        (2, 16, 48, 64), (4, 8, 48, 64), (1, 16, 48, None),
+    ])
+    def test_gate_follows_the_page_dma_size(self, kv_heads, page_size, batch,
+                                            expected):
+        """`pallas_min_pages` for the 128-lane kernel is a function of the
+        bytes of one K+V page (the rows of docs/kernels.md's table)."""
+        assert att.pallas_min_pages(128, kv_heads, page_size, batch) == expected
+
+
+def _crossover_rows():
+    import json
+    import pathlib
+
+    path = pathlib.Path(__file__).parent.parent / (
+        "docs/data/decode_attention_crossover.v5e.json")
+    rows = {}
+    for row in json.loads(path.read_text())["rows"]:
+        rows.setdefault(row["family"], []).append(row)
+    return rows
+
+
+CROSSOVER = _crossover_rows()
+
+
+@pytest.mark.parametrize("family", sorted(CROSSOVER))
+class TestGateEqualsTheMeasuredTable:
+    """The auto-dispatch gate against the per-call times measured on the
+    v5e (docs/kernels.md "Kernel against gather"): a path WINS a row when
+    it is more than 3 % ahead under both length distributions."""
+
+    @staticmethod
+    def _verdict(row):
+        ratios = [row[f"{k}.gather_us"] / row[f"{k}.kernel_us"]
+                  for k in ("aged", "full")]
+        auto = att._should_use_pallas(
+            row["d"], False, row["width"], row["lanes"], "tpu", 16,
+            row["nkv"])
+        return auto, min(ratios) > 1.03, max(ratios) < 0.97
+
+    def test_never_takes_the_measured_loser(self, family):
+        for row in CROSSOVER[family]:
+            auto, kernel_wins, gather_wins = self._verdict(row)
+            assert not (auto and gather_wins), row
+            # the two paths' bf16 outputs, compared on the chip
+            assert max(row["aged.max_abs_diff"],
+                       row["full.max_abs_diff"]) < 2e-2, row
+            if row["d"] == 128 and row["aged.gather_spread"] < 0.05:
+                # the main kernel's wins are all taken (the packed
+                # kernel's depend on the cache's size: docs/kernels.md)
+                assert auto or not kernel_wins, row
+
+
+#: the widths `qwen3-4b.decode-sat` compiles (max_model_len 640 = 40 pages)
+DECODE_SAT_WIDTHS = (8, 16, 32, 40)
+
+
+class TestMixedProgramTakesTheDecodeKernel:
+    @pytest.mark.parametrize("width", DECODE_SAT_WIDTHS)
+    def test_mixed_lowers_with_decode_kernel_and_no_gather(
+            self, width, monkeypatch):
+        """The `mixed` program at the decode-sat cell's shape (48 lanes,
+        T = 512, Qwen3-4B's 32/8 x 128 heads, 16-token pages), lowered for
+        TPU: its scan tail calls the decode kernel and nothing gathers a
+        [48, W, .., 16, 128] copy of the lanes' pages (such a gather in
+        every layer of every step was 40 % of the cell's device time,
+        PERF.md section 6).  Two layers and a narrow MLP: the attention
+        shapes are the cell's, the rest only has to lower."""
+        import dataclasses
+        import re
+
+        from kserve_tpu.engine.compiled import program_defs
+        from kserve_tpu.engine.sampling import SamplingState
+        from kserve_tpu.engine.types import EngineConfig
+        from kserve_tpu.models import llama
+        from kserve_tpu.parallel import sharding as shd
+
+        # code that asks the backend takes its TPU branch, as on the chip
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        lanes, tokens, ps = 48, 512, 16
+        mc = dataclasses.replace(
+            llama.LlamaConfig.qwen3_0_6b(), n_layers=2, n_heads=32,
+            hidden_size=256, intermediate_size=512, vocab_size=1024,
+            dtype="bfloat16")
+        cfg = EngineConfig(
+            max_batch_size=lanes, page_size=ps, num_pages=2300,
+            max_pages_per_seq=40, max_prefill_len=tokens,
+            prefill_buckets=(128, tokens), dtype="bfloat16")
+        fn, donate = program_defs(mc, cfg, shd.create_mesh())["mixed"]
+
+        def i32(*shape):
+            return jax.ShapeDtypeStruct(shape, jnp.int32)
+
+        cache = jax.ShapeDtypeStruct(
+            (cfg.num_pages, 2, mc.n_kv_heads, ps, mc.head_dim), jnp.bfloat16)
+        text = jax.jit(fn, donate_argnums=donate).trace(
+            jax.eval_shape(
+                lambda: llama.init_params(mc, jax.random.PRNGKey(1))),
+            i32(tokens), i32(tokens), i32(tokens),  # q_tokens, seq, pos
+            i32(lanes), i32(lanes), i32(lanes), i32(lanes),
+            [cache] * mc.n_layers, i32(lanes, width),
+            jax.ShapeDtypeStruct((lanes,), jnp.bool_),  # joins
+            i32(lanes), i32(lanes), i32(lanes), i32(lanes), i32(lanes),
+            jax.eval_shape(lambda: SamplingState.defaults(lanes)),
+            jax.ShapeDtypeStruct((2,), jnp.uint32), i32(lanes),
+        ).lower(lowering_platforms=("tpu",)).as_text()
+        kernels = set(re.findall(r'kernel_name = "([a-z_]+)"', text))
+        assert {"paged_attention_decode", "ragged_paged_attention"} <= kernels
+        gathered = re.findall(
+            rf"tensor<{lanes}x{width}x[0-9x]*{ps}x128xbf16>", text)
+        assert not gathered, sorted(set(gathered))
