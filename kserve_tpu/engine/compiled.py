@@ -210,6 +210,12 @@ def program_defs(model_config, engine_config, mesh, spec_k=None) -> dict:
         Pinning the output spec makes call 2's signature identical to
         call 1's: every program compiles exactly once per shape bucket
         (pinned by tests/test_retrace_budget.py)."""
+        if mc.is_hybrid:
+            # a state pytree (kvcache.StateLayout), tp=1: every leaf pinned
+            # to the replicated spelling its init carries
+            rep = shd.named(mesh, _P())
+            return jax.tree.map(
+                lambda a: jax.lax.with_sharding_constraint(a, rep), kv_pages)
         if cfg.pp > 1:
             # no constraint under pp: the staged shard_map is manual over
             # `pipe`, and adding a GSPMD constraint to its output makes
@@ -470,6 +476,16 @@ def program_defs(model_config, engine_config, mesh, spec_k=None) -> dict:
             )
         return logits, _kv_pin(kv_pages)
 
+    # the alignment the engine packs slices at (engine._ragged_align): a
+    # block of that many tokens holds one lane, which a hybrid model's
+    # packed scan and window attention build on
+    from ..ops.attention import _should_use_ragged_pallas
+    from ..ops.pallas_paged_attention import RAGGED_BQ
+
+    ragged_block = RAGGED_BQ if cfg.use_pallas or (
+        cfg.use_pallas is None and _should_use_ragged_pallas(
+            mc.cache_head_dim, jax.default_backend(), _quantized)) else 1
+
     def _make_mixed():
         """THE unified ragged program (docs/kernels.md): one dispatch
         serves an arbitrary mix of prompt chunks and decode lanes.
@@ -502,6 +518,7 @@ def program_defs(model_config, engine_config, mesh, spec_k=None) -> dict:
                 adapter_ids=adapter_ids,
                 attention_fn=ragged_attention_fn,
                 use_pallas=cfg.use_pallas,
+                ragged_block=ragged_block,
             )
             sampled0 = sample_tokens(logits, state, rngs[0], counters)
             tokens0 = jnp.where(scan_tok0 >= 0, scan_tok0, sampled0)
@@ -562,19 +579,11 @@ def program_defs(model_config, engine_config, mesh, spec_k=None) -> dict:
         counts, pinned kv_pages, updated draft_table, and the final
         (token, pos, counters) device carry the engine feeds a chained
         dispatch without a host round-trip)."""
-        from ..ops.attention import (
-            _should_use_ragged_pallas,
-            dense_stride_for,
-        )
-        from ..ops.pallas_paged_attention import RAGGED_BQ
+        from ..ops.attention import dense_stride_for
 
         Kp = k_drafts + 1
-        kernel_possible = cfg.use_pallas or (
-            cfg.use_pallas is None
-            and _should_use_ragged_pallas(
-                mc.head_dim, jax.default_backend(), _quantized)
-        )
-        align = RAGGED_BQ if kernel_possible else 1
+        kernel_possible = ragged_block > 1
+        align = ragged_block
         sp = dense_stride_for(Kp, align)  # padded slice stride
         dense_stride = sp if (kernel_possible and sp < RAGGED_BQ) else None
         dense_attention_fn = None

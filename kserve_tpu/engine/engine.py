@@ -45,6 +45,9 @@ from ..metrics import (
     ENGINE_KV_OFFLOAD_BYTES,
     ENGINE_KV_PAGES_FREE,
     ENGINE_PREEMPTIONS,
+    ENGINE_STATE_BYTES,
+    ENGINE_STATE_RESETS,
+    ENGINE_STATE_SLOTS_IN_USE,
     ENGINE_PREFILL_CHUNK_DURATION,
     ENGINE_QUEUE_DEPTH,
     ENGINE_STEP_BATCH_COMPOSITION,
@@ -87,6 +90,7 @@ from ..resilience import (
 from .kvcache import (
     KVCacheConfig,
     PageAllocator,
+    StateLayout,
     device_filler,
     init_kv_pages,
     init_kv_scales,
@@ -122,6 +126,53 @@ def _device_row(device) -> dict:
 _PROGRAM_COLUMN = DISPATCH_COLUMNS.index("program")
 _PHASE_COLUMNS = slice(DISPATCH_COLUMNS.index(PHASES[0]),
                        DISPATCH_COLUMNS.index("wait_lag") + 1)
+
+
+def resolve_hybrid_serving(model_config, engine_config,
+                           role: str = "both") -> None:
+    """THE place that says what a model with recurrent or ring state
+    (models/hybrid.py) cannot do yet.  A lane's pages are no longer its
+    whole state, and that state cannot be rewound, shared or shipped, so:
+    what was asked for explicitly is refused here, at start-up, by name;
+    what was left at its default is resolved to off, with a log line.
+    Request-time features that run the legacy programs are refused at
+    submit (`LLMEngine._check_hybrid_request`)."""
+    if not model_config.is_hybrid:
+        return
+    cfg = engine_config
+    refused = []
+    if cfg.pp > 1:
+        refused.append("pp>1 (staged layers assume one kind of layer)")
+    if cfg.sp > 1:
+        refused.append("sp>1 (ring-attention prefill)")
+    if cfg.kv_quant != "none":
+        refused.append(f"kv_quant={cfg.kv_quant}")
+    if cfg.weight_quant != "none":
+        refused.append(f"weight_quant={cfg.weight_quant}")
+    if cfg.spec_decode_k is not None:
+        refused.append("spec_decode_k (a rejected draft rewinds kv_len; "
+                       "recurrent state has no rewind)")
+    if cfg.kv_offload != "none" or cfg.kv_persist_dir:
+        refused.append("kv_offload / kv_persist_dir (tier offload and the "
+                       "persistent prefix store move pages only)")
+    if cfg.prefix_cache:
+        refused.append("prefix_cache (a prefix's pages do not hold the "
+                       "recurrent state at its boundary)")
+    if cfg.use_ragged is False:
+        refused.append("use_ragged=False (the legacy programs)")
+    if role != "both":
+        refused.append(f"role={role} (the P/D wire ships pages only)")
+    if refused:
+        raise NotImplementedError(
+            "not supported yet for a model with mamba / window / shared-cache "
+            "layers: " + "; ".join(refused))
+    if cfg.prefix_cache is None:
+        cfg.prefix_cache = False
+        logger.info(
+            "hybrid model: prefix cache adoption resolved to OFF (snapshots "
+            "of recurrent state are not implemented); speculative decoding, "
+            "tier offload, the P/D wire of pages, logprobs and penalties "
+            "lanes are refused by name")
 
 
 class LLMEngine:
@@ -208,6 +259,9 @@ class LLMEngine:
         # checkpoint from any of them resumes on any other
         self._ckpt_label = checkpoint_label or metrics_label
         shd.validate_tp(model_config, engine_config.tp)
+        resolve_hybrid_serving(model_config, engine_config)
+        if model_config.is_hybrid and (lora_adapters or lora_stacked):
+            raise NotImplementedError("LoRA adapters over a hybrid model")
         if engine_config.sp > 1 and (
                 model_config.sliding_window > 0
                 or model_config.query_pre_attn_scalar is not None):
@@ -352,10 +406,16 @@ class LLMEngine:
         # through the LoRA stacks landing on device is the weights phase
         self.startup_phases["weights"] = time.perf_counter() - _weights_t0
 
+        # what a lane owns, per layer kind (engine/kvcache.StateLayout): the
+        # pool's pages belong to the layers that write paged K/V
+        layout = StateLayout.of(
+            model_config, engine_config.page_size, engine_config.num_pages,
+            engine_config.max_batch_size, engine_config.dtype)
+        self.state_layout = layout
         cache_cfg = KVCacheConfig(
-            n_layers=model_config.n_layers,
-            n_kv_heads=model_config.n_kv_heads,
-            head_dim=model_config.head_dim,
+            n_layers=len(layout.paged_layers),
+            n_kv_heads=layout.kv_heads,
+            head_dim=layout.head_dim,
             page_size=engine_config.page_size,
             num_pages=engine_config.num_pages,
             max_pages_per_seq=engine_config.max_pages_per_seq,
@@ -370,7 +430,15 @@ class LLMEngine:
             model_config.n_layers, cache_cfg.num_pages, 2,
             cache_cfg.n_kv_heads, cache_cfg.page_size, cache_cfg.head_dim,
         )
-        if engine_config.kv_quant == "int8":
+        if model_config.is_hybrid:
+            self.kv_pages = layout.init_state(
+                shd.named_canonical(self.mesh, jax.sharding.PartitionSpec()))
+            logger.info(
+                "per-lane state: shared K/V %d B a token over %d pages of %d "
+                "tokens; a lane holds window K/V %d B, ssm %d B, conv %d B",
+                layout.token_bytes(), layout.num_pages, layout.page_size,
+                *(layout.lane_bytes()[k] for k in ("window_kv", "ssm", "conv")))
+        elif engine_config.kv_quant == "int8":
             if engine_config.use_pallas:
                 # fail at init, not inside the jitted decode trace where the
                 # error would kill the engine loop for all traffic
@@ -535,7 +603,7 @@ class LLMEngine:
         kernel_possible = engine_config.use_pallas or (
             engine_config.use_pallas is None
             and _should_use_ragged_pallas(
-                model_config.head_dim, jax.default_backend(),
+                model_config.cache_head_dim, jax.default_backend(),
                 engine_config.kv_quant == "int8")
         )
         self._ragged_align = RAGGED_BQ if kernel_possible else 1
@@ -643,6 +711,12 @@ class LLMEngine:
                 "ragged mixed program unavailable in this program set; "
                 "falling back to the legacy dispatch paths")
             self._use_mixed = False
+        if model_config.is_hybrid and not self._use_mixed:
+            raise NotImplementedError(
+                "a hybrid model runs the mixed program only (the legacy "
+                "programs assume one kind of layer): max_batch_size x the "
+                f"{self._ragged_align}-token slice alignment must fit the "
+                "largest prefill bucket")
         # what this replica was BUILT with — dispatch regime and, per
         # program family, the attention implementation — logged at start
         # and served on /v1/internal/scheduler/state, so a TPU replica
@@ -654,6 +728,7 @@ class LLMEngine:
             "attention": describe_attention_dispatch(
                 model_config, engine_config, jax.default_backend()),
         }
+        self._set_state_gauges()
 
     # ---------------- compiled programs ----------------
 
@@ -898,6 +973,8 @@ class LLMEngine:
                 1 for s in self._slots if s.request_id is not None),
             "free_pages": self.allocator.free_pages,
             "page_size": self.config.page_size,
+            # what the seated lanes hold, per kind of state
+            "state": self._state_occupancy(),
             "running": self.running,
             "wedged": self._wedged,
             "prefix_digests": digests,
@@ -1292,6 +1369,7 @@ class LLMEngine:
         snap["queue_depth"] = self.queue_depth
         snap["prefix_cache_hits"] = self.prefix_cache_hits
         snap["preemptions"] = self.preemption_count
+        snap["state"] = self._state_occupancy()
         return snap
 
     def _record_terminal(self, tl: Optional[RequestTimeline],
@@ -1445,6 +1523,7 @@ class LLMEngine:
             raise ValueError(
                 f"prompt+max_tokens exceeds max_model_len {self.config.max_model_len}"
             )
+        self._check_hybrid_request(params)
         self._check_accepting()
         deadline = self._admission_deadline()
         queue: asyncio.Queue = asyncio.Queue()
@@ -1456,6 +1535,23 @@ class LLMEngine:
             timeline=self._new_timeline(rid, len(prompt_ids)),
         )
         return self._submit_and_stream(req)
+
+    def _check_hybrid_request(self, params: Optional[SamplingParams],
+                              kv_wire: bool = False) -> None:
+        """The request-time half of resolve_hybrid_serving: logprobs and
+        penalties lanes run the legacy programs, and the P/D wire ships
+        pages, which are not a hybrid model's whole state."""
+        if not self.model_config.is_hybrid:
+            return
+        if kv_wire:
+            raise ValueError(
+                "the P/D wire of KV pages is not supported for a model with "
+                "recurrent state")
+        if params is not None and (
+                params.has_penalties or params.logprobs is not None):
+            raise ValueError(
+                "logprobs and sampling penalties run the legacy programs, "
+                "which a model with recurrent state does not have")
 
     def _new_timeline(self, rid: str, n_prompt: int) -> RequestTimeline:
         """Stamp `received` NOW (the sync part of submit) and capture the
@@ -1528,6 +1624,7 @@ class LLMEngine:
             raise NotImplementedError(
                 "KV injection over a quantized cache is not supported yet"
             )
+        self._check_hybrid_request(params, kv_wire=True)
         # validation runs HERE (sync), not at first __anext__: a shape
         # mismatch inside _run_loop would kill the engine for all traffic,
         # not just this request (version-skewed prefill peer)
@@ -1594,6 +1691,7 @@ class LLMEngine:
         Parity: the KV-connector role of the reference's disaggregated
         serving (workload_kvcache.go, llm_inference_service_types.go:105-110)
         with the transfer payload produced TPU-side in one gather."""
+        self._check_hybrid_request(params, kv_wire=True)
         if self.config.kv_quant != "none":
             raise NotImplementedError(
                 "detached prefill (P/D transfer) over a quantized KV cache "
@@ -2320,6 +2418,30 @@ class LLMEngine:
             self.allocator.free_pages
         )
         self._set_composition_gauge(len(active))
+        self._set_state_gauges()
+
+    def _state_occupancy(self) -> dict:
+        """Per-kind occupancy (kvcache.StateLayout): lanes seated, pages of
+        the pool held, and the bytes both come to, beside what one token
+        and one lane cost."""
+        layout = self.state_layout
+        seated = sum(1 for s in self._slots if s.request_id is not None)
+        held = layout.num_pages - 1 - self.allocator.free_pages
+        return {
+            "slots_in_use": seated,
+            "slots": layout.lanes,
+            "pages_in_use": held,
+            "bytes_in_use": layout.bytes_in_use(seated, held),
+            "bytes_per_token": {"shared_kv": layout.token_bytes()},
+            "bytes_per_lane": layout.lane_bytes(),
+        }
+
+    def _set_state_gauges(self) -> None:
+        occupancy = self._state_occupancy()
+        ENGINE_STATE_SLOTS_IN_USE.labels(model_name=self._mlabel).set(
+            occupancy["slots_in_use"])
+        for kind, n in occupancy["bytes_in_use"].items():
+            ENGINE_STATE_BYTES.labels(model_name=self._mlabel, kind=kind).set(n)
 
     def _admit_mixed(self) -> bool:
         """Admission under the unified ragged program: requests with
@@ -2423,6 +2545,10 @@ class LLMEngine:
             "done": len(cached) * self.config.page_size,
             "logits": None,
         }
+        if not cached:
+            # the lane's first slice starts at position 0: the program
+            # starts its ring and recurrent slot from zero
+            ENGINE_STATE_RESETS.labels(model_name=self._mlabel).inc()
         return True
 
     def _advance_prefills(self) -> bool:

@@ -16,6 +16,7 @@ XLA-native design.
 
 from __future__ import annotations
 
+import math
 from dataclasses import dataclass, field
 from typing import List, Optional
 
@@ -58,6 +59,118 @@ def init_kv_pages(config: KVCacheConfig, sharding=None) -> List[jnp.ndarray]:
     shape = (config.num_pages, 2, config.n_kv_heads, config.page_size, config.head_dim)
     make = device_filler(sharding, shape, jnp.dtype(config.dtype), 0)
     return [make() for _ in range(config.n_layers)]
+
+
+@dataclass(frozen=True)
+class StateLayout:
+    """What a lane owns, per layer kind: the one place that answers it.
+
+    Derived from the model's per-layer table (models/llama.LayerSpec rows),
+    three kinds of per-lane state live side by side:
+
+    - `shared_kv`: pages of the pool, grown on demand through the
+      PageAllocator and the page table, for every layer that writes
+      `paged_kv` (all of a Llama's layers; one layer of a decoder whose
+      other attention layers read it);
+    - `window_kv`: for every layer that writes `window_kv` a RING of the
+      last `window` tokens per lane, at fixed pages of its own array
+      (lane b holds ring pages 1 + b * ring_width ..; page 0 is the null
+      page), so it never grows;
+    - `ssm` and `conv`: for every layer that writes `recurrent` one slot
+      per lane: the scan's float32 state and the convolution's tail.
+
+    Ring and slots belong to the LANE (the engine's slot index): nothing is
+    allocated at admission, and a program starts a lane from zero state
+    whenever the lane's slice begins at position 0."""
+
+    paged_layers: tuple
+    window_layers: tuple
+    recurrent_layers: tuple
+    kv_heads: int  # as stored (models/llama.LlamaConfig.cache_kv_heads)
+    head_dim: int
+    page_size: int
+    num_pages: int
+    lanes: int
+    window: int
+    d_inner: int
+    d_state: int
+    d_conv: int
+    dtype: str = "bfloat16"
+
+    @classmethod
+    def of(cls, model_config, page_size: int, num_pages: int, lanes: int,
+           dtype: str = "bfloat16") -> "StateLayout":
+        table = model_config.layer_table()
+
+        def rows(writes):
+            return tuple(i for i, r in enumerate(table) if r.writes == writes)
+
+        return cls(
+            paged_layers=rows("paged_kv"), window_layers=rows("window_kv"),
+            recurrent_layers=rows("recurrent"),
+            kv_heads=model_config.cache_kv_heads,
+            head_dim=model_config.cache_head_dim,
+            page_size=page_size, num_pages=num_pages, lanes=lanes,
+            window=model_config.sliding_window if rows("window_kv") else 0,
+            d_inner=model_config.mamba_d_inner,
+            d_state=model_config.mamba_d_state,
+            d_conv=model_config.mamba_d_conv, dtype=dtype)
+
+    @property
+    def _itemsize(self) -> int:
+        return jnp.dtype(self.dtype).itemsize
+
+    @property
+    def ring_page_size(self) -> int:
+        return math.gcd(self.window, self.page_size) if self.window else 0
+
+    @property
+    def ring_width(self) -> int:
+        """Ring pages per lane and window layer."""
+        return self.window // self.ring_page_size if self.window else 0
+
+    def token_bytes(self) -> int:
+        """Bytes of shared K/V one token of context holds."""
+        return (len(self.paged_layers) * 2 * self.kv_heads * self.head_dim
+                * self._itemsize)
+
+    def lane_bytes(self) -> dict:
+        """Bytes one lane holds whatever its context's length, by kind."""
+        n = len(self.recurrent_layers)
+        return {
+            "window_kv": len(self.window_layers) * self.window * 2
+            * self.kv_heads * self.head_dim * self._itemsize,
+            "ssm": n * self.d_inner * self.d_state * 4,
+            "conv": n * max(self.d_conv - 1, 0) * self.d_inner * self._itemsize,
+        }
+
+    def bytes_in_use(self, lanes_seated: int, pages_held: int) -> dict:
+        """engine_state_bytes{kind}: what the seated lanes hold now."""
+        out = {k: v * lanes_seated for k, v in self.lane_bytes().items()}
+        out["shared_kv"] = pages_held * self.page_size * self.token_bytes()
+        return out
+
+    def init_state(self, sharding=None) -> dict:
+        """The device arrays, zeroed: {"paged": per paged layer the pool's
+        pages, "window": per window layer the rings, "ssm" / "conv": per
+        recurrent layer the slots}."""
+        dtype = jnp.dtype(self.dtype)
+        page = (2, self.kv_heads, self.page_size, self.head_dim)
+        ring = (2, self.kv_heads, self.ring_page_size, self.head_dim)
+
+        def fill(shape, dt, n):
+            make = device_filler(sharding, shape, dt, 0)
+            return [make() for _ in range(n)]
+
+        n = len(self.recurrent_layers)
+        return {
+            "paged": fill((self.num_pages,) + page, dtype, len(self.paged_layers)),
+            "window": fill((1 + self.lanes * self.ring_width,) + ring, dtype,
+                           len(self.window_layers)),
+            "ssm": fill((self.lanes, self.d_inner, self.d_state), jnp.float32, n),
+            "conv": fill((self.lanes, max(self.d_conv - 1, 0), self.d_inner),
+                         dtype, n),
+        }
 
 
 class PageAllocator:

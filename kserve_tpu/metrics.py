@@ -39,6 +39,23 @@ ENGINE_QUEUE_DEPTH = Gauge(
 ENGINE_KV_PAGES_FREE = Gauge(
     "engine_kv_pages_free", "free KV cache pages", ["model_name"]
 )
+# Per-lane state by kind (engine/kvcache.StateLayout): what the seated lanes
+# hold now.  `kind`: shared_kv (pages of the pool), window_kv (window
+# layers' rings), ssm and conv (recurrent layers' slots).
+ENGINE_STATE_BYTES = Gauge(
+    "engine_state_bytes", "bytes of per-lane state in use, by kind",
+    ["model_name", "kind"],
+)
+ENGINE_STATE_SLOTS_IN_USE = Gauge(
+    "engine_state_slots_in_use",
+    "lanes seated: each holds its ring and recurrent-state slot",
+    ["model_name"],
+)
+ENGINE_STATE_RESETS = Counter(
+    "engine_state_resets_total",
+    "lanes started from zero state (a request admitted at position 0)",
+    ["model_name"],
+)
 ENGINE_WEDGED = Gauge(
     "engine_wedged", "1 once a device fetch blew the step deadline "
     "(liveness fails; pod restart expected)", ["model_name"]

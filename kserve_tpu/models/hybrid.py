@@ -1,0 +1,580 @@
+"""Decoders whose layers are not all the same layer, run from the per-layer
+table of `LlamaConfig.layer_table()` (models/llama.LayerSpec).
+
+First family: `model_type: phi4flash`, the SambaY decoder-hybrid-decoder
+(arXiv:2507.06607) with differential attention (arXiv:2410.05258) and no
+positional encoding.  Every layer is `h += Mixer(LN_a(h)); h +=
+MLP(LN_b(h))` (LayerNorm with bias, SwiGLU MLP); the mixer is one of
+
+- `mamba`: a Mamba-1 mixer; writes `recurrent` state (ops/ssm.py);
+- `window_attention`: attention over the last `sliding_window` tokens;
+  writes `window_kv`, a per-lane ring;
+- `attention`: full causal attention; writes `paged_kv`, pages of the pool;
+- `cross_attention`: queries only, over the K/V of the layer it `reads`;
+- `gmu`: a gated memory unit over the scan output `m` of the Mamba layer
+  it `reads`; holds no state.
+
+State travels as the pytree of engine/kvcache.StateLayout: {"paged",
+"window", "ssm", "conv"}, one array per writing layer.  The two entry
+points mirror models/llama's: `forward_ragged` (the mixed program's packed
+buffer) and `decode_step` (one token per lane), called through
+`llama.forward_ragged` / `llama.decode_step`.
+
+Differential attention with the kernels the repo has: a pair's two K heads
+lie side by side in one cache row of 2 x head_dim (and its two V heads
+likewise), which is a reshape of the projection's output; query head 2j is
+padded to `[q, 0]` and 2j+1 to `[0, q]`, so plain grouped-query attention
+over rows of twice the width gives `softmax(q_1 k_1^T) [v_1, v_2]` and
+`softmax(q_2 k_2^T) [v_1, v_2]`: the pair's two terms, exactly.  The cache
+holds the same bytes per token, at the width the decode kernel streams
+without a re-layout.
+
+Layers behind the last layer that writes state only feed the logits, so the
+packed forward runs them (and the last writer's own attention output) on
+the rows that are sampled, one per lane: exact, and it makes every read of
+the shared cache a one-query-per-lane read.
+"""
+
+from __future__ import annotations
+
+import math
+import os
+import re
+from typing import Any, Dict, Optional
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from ..engine.kvcache import append_token_kv, write_ragged_kv
+from ..ops import ssm
+from ..ops.attention import paged_attention_scaled, ring_window_attention_ragged
+from ..ops.norms import layer_norm, rms_norm
+from . import llama
+from .quant import dense, tied_head_matmul
+
+Params = Dict[str, Any]
+
+
+# ---------------- parameters ----------------
+
+
+def layer_param_shapes(config, spec) -> Dict[str, tuple]:
+    """{name: (shape, init)} of one layer, from its row of the table.
+    `init`: "normal" (N(0, scale)), "ones", "bias" (N(0, scale)),
+    "lambda" (N(0, 0.1), arXiv:2410.05258), "A_log", "dt_bias", "D" (the
+    Mamba-1 defaults); float32 for the last three."""
+    h, f = config.hidden_size, config.intermediate_size
+    nq, nkv, hd = config.n_heads, config.n_kv_heads, config.head_dim
+    di, n, k, r = (config.mamba_d_inner, config.mamba_d_state,
+                   config.mamba_d_conv, config.mamba_dt_rank)
+    shapes = {
+        "attn_norm": ((h,), "ones"), "attn_norm_b": ((h,), "bias"),
+        "mlp_norm": ((h,), "ones"), "mlp_norm_b": ((h,), "bias"),
+        "w_gate": ((h, f), "normal"), "w_up": ((h, f), "normal"),
+        "w_down": ((f, h), "normal"),
+    }
+    if spec.kind in ("attention", "window_attention", "cross_attention"):
+        shapes.update({
+            "wq": ((h, nq * hd), "normal"), "wo": ((nq * hd, h), "normal"),
+            "lambda_q1": ((hd,), "lambda"), "lambda_k1": ((hd,), "lambda"),
+            "lambda_q2": ((hd,), "lambda"), "lambda_k2": ((hd,), "lambda"),
+            "subln": ((2 * hd,), "ones"),
+        })
+        if config.attention_bias:
+            shapes["bq"] = ((nq * hd,), "bias")
+        if config.attention_out_bias:
+            shapes["bo"] = ((h,), "bias")
+        if spec.kind != "cross_attention":
+            shapes.update({"wk": ((h, nkv * hd), "normal"),
+                           "wv": ((h, nkv * hd), "normal")})
+            if config.attention_bias:
+                shapes.update({"bk": ((nkv * hd,), "bias"),
+                               "bv": ((nkv * hd,), "bias")})
+    elif spec.kind == "mamba":
+        shapes.update({
+            "in_proj": ((h, 2 * di), "normal"),
+            "conv_w": ((k, di), "normal"), "conv_b": ((di,), "bias"),
+            "x_proj": ((di, r + 2 * n), "normal"),
+            "dt_proj": ((r, di), "normal"), "dt_bias": ((di,), "dt_bias"),
+            "A_log": ((di, n), "A_log"), "D": ((di,), "D"),
+            "out_proj": ((di, h), "normal"),
+        })
+    elif spec.kind == "gmu":
+        shapes.update({"gmu_in": ((h, di), "normal"),
+                       "gmu_out": ((di, h), "normal")})
+    return shapes
+
+
+_F32_INITS = ("A_log", "dt_bias", "D")
+
+
+def init_params(config, rng, scale: float = 0.02, weight_quant: str = "none",
+                shardings=None) -> Params:
+    """Seeded random parameters, each layer made under jit ON its sharding
+    (models/llama.init_params).  Biases and the lambda vectors are random
+    too, so that a comparison with the reference exercises them."""
+    if weight_quant != "none":
+        raise NotImplementedError("weight_quant over a hybrid model")
+    dtype = jnp.dtype(config.dtype)
+    table = config.layer_table()
+    keys = jax.random.split(rng, config.n_layers + 1)
+
+    def make(shape, init, key):
+        if init == "ones":
+            return jnp.ones(shape, dtype)
+        if init == "lambda":
+            return (jax.random.normal(key, shape, jnp.float32) * 0.1).astype(dtype)
+        if init == "A_log":
+            return jnp.log(jnp.broadcast_to(
+                jnp.arange(1, shape[1] + 1, dtype=jnp.float32), shape))
+        if init == "D":
+            return jnp.ones(shape, jnp.float32)
+        if init == "dt_bias":
+            # softplus(dt_bias) log-uniform in [1e-3, 1e-1]
+            dt = jnp.exp(jax.random.uniform(key, shape, jnp.float32)
+                         * (math.log(0.1) - math.log(0.001)) + math.log(0.001))
+            return dt + jnp.log(-jnp.expm1(-dt))
+        return (jax.random.normal(key, shape, jnp.float32) * scale).astype(dtype)
+
+    def make_layer(spec, key):
+        shapes = layer_param_shapes(config, spec)
+        ks = jax.random.split(key, len(shapes))
+        return {name: make(shape, init, k)
+                for (name, (shape, init)), k in zip(sorted(shapes.items()), ks)}
+
+    def make_top(key):
+        k = jax.random.split(key, 2)
+        h = config.hidden_size
+        return {"embed": make((config.vocab_size, h), "normal", k[0]),
+                "final_norm": jnp.ones((h,), dtype),
+                "final_norm_b": make((h,), "bias", k[1])}
+
+    layer_fn = jax.jit(make_layer, static_argnums=0)
+    layers = []
+    for i, spec in enumerate(table):
+        fn = layer_fn if shardings is None else jax.jit(
+            make_layer, static_argnums=0, out_shardings=shardings["layers"][i])
+        layers.append(fn(spec, keys[i]))
+    top_sharding = None if shardings is None else {
+        k: v for k, v in shardings.items() if k != "layers"}
+    params = jax.jit(make_top, out_shardings=top_sharding)(keys[-1])
+    params["layers"] = layers
+    return params
+
+
+# ---------------- pieces of a layer ----------------
+
+
+def _ln(x, layer, name, config):
+    return layer_norm(x, layer[name], layer[name + "_b"], config.rms_norm_eps)
+
+
+def _close(layer, x, mixed, config):
+    """Residual around the mixer's output, then the MLP's."""
+    x = x + mixed
+    return x + llama._mlp(layer, _ln(x, layer, "mlp_norm", config), config)
+
+
+def _lambda_init(layer_index: int) -> float:
+    return 0.8 - 0.6 * math.exp(-0.3 * layer_index)
+
+
+def _queries(layer, u, config):
+    """[N, h] -> queries over rows of twice the head size: head 2j is
+    [q, 0], head 2j+1 is [0, q]."""
+    q = dense(u, layer["wq"])
+    if config.attention_bias:
+        q = q + layer["bq"]
+    # [N, pairs, 2, 1, d] x eye(2)[2, 2, 1]: the pair's first head lands in
+    # the row's first half, its second in the second
+    q = q.reshape(u.shape[0], config.n_heads // 2, 2, 1, config.head_dim)
+    return (q * jnp.eye(2, dtype=q.dtype)[:, :, None]).reshape(
+        u.shape[0], config.n_heads, config.cache_head_dim)
+
+
+def _keys_values(layer, u, config):
+    """[N, h] -> K, V [N, pairs, 2 x head_dim]: a pair's heads side by side."""
+    k, v = dense(u, layer["wk"]), dense(u, layer["wv"])
+    if config.attention_bias:
+        k, v = k + layer["bk"], v + layer["bv"]
+    shape = (u.shape[0], config.cache_kv_heads, config.cache_head_dim)
+    return k.reshape(shape), v.reshape(shape)
+
+
+def _differential_out(layer, attn, config, layer_index: int):
+    """attn [N, heads, 2 x head_dim], heads (2j, 2j+1) the pair's two terms
+    -> the layer's output [N, h]."""
+    N = attn.shape[0]
+    f32 = jnp.float32
+    init = _lambda_init(layer_index)
+    lam = (jnp.exp(jnp.sum(layer["lambda_q1"].astype(f32)
+                           * layer["lambda_k1"].astype(f32)))
+           - jnp.exp(jnp.sum(layer["lambda_q2"].astype(f32)
+                             * layer["lambda_k2"].astype(f32))) + init)
+    pair = attn.astype(f32).reshape(
+        N, config.n_heads // 2, 2, config.cache_head_dim)
+    diff = pair[:, :, 0] - lam * pair[:, :, 1]
+    out = rms_norm(diff, layer["subln"], config.rms_norm_eps) * (1.0 - init)
+    out = dense(out.reshape(N, -1).astype(attn.dtype), layer["wo"])
+    if config.attention_out_bias:
+        out = out + layer["bo"]
+    return out
+
+
+def _scale(config) -> float:
+    return float(config.head_dim) ** -0.5
+
+
+def _mamba_project(layer, u, config):
+    xz = dense(u, layer["in_proj"])
+    return xz[:, :config.mamba_d_inner], xz[:, config.mamba_d_inner:]
+
+
+def _mamba_scan_inputs(layer, conv_out, config):
+    """The convolution's output (float32, before its activation) -> what
+    the scan takes: x, dt [N, Di] float32, A [Di, N], B, C [N, N_state]."""
+    f32 = jnp.float32
+    x = jax.nn.silu(conv_out)
+    r, n = config.mamba_dt_rank, config.mamba_d_state
+    dbc = dense(x.astype(layer["x_proj"].dtype), layer["x_proj"])
+    dt = jax.nn.softplus(
+        dense(dbc[:, :r], layer["dt_proj"]).astype(f32) + layer["dt_bias"])
+    A = -jnp.exp(layer["A_log"].astype(f32))
+    return (x, dt, A, dbc[:, r:r + n].astype(f32), dbc[:, r + n:].astype(f32))
+
+
+def _mamba_out(layer, y, z):
+    """The scan's output y [N, Di] float32 -> (the mixer's output, m: what
+    a gated memory unit reuses, y before the gate)."""
+    gated = y * jax.nn.silu(z.astype(jnp.float32))
+    return dense(gated.astype(z.dtype), layer["out_proj"]), y.astype(z.dtype)
+
+
+def _gmu(layer, u, m):
+    with jax.named_scope("gmu"):
+        gate = jax.nn.silu(dense(u, layer["gmu_in"]).astype(jnp.float32))
+        return dense((m.astype(jnp.float32) * gate).astype(u.dtype),
+                     layer["gmu_out"])
+
+
+def _logits(params, x, config):
+    with jax.named_scope("lm_head"):
+        x = layer_norm(x, params["final_norm"], params["final_norm_b"],
+                       config.rms_norm_eps)
+        return tied_head_matmul(x, params["embed"]).astype(jnp.float32)
+
+
+def _slots(table) -> Dict[int, int]:
+    """layer index -> index into its kind's list of state arrays."""
+    count: Dict[str, int] = {}
+    out = {}
+    for i, spec in enumerate(table):
+        if spec.writes != "none":
+            out[i] = count.get(spec.writes, 0)
+            count[spec.writes] = out[i] + 1
+    return out
+
+
+def _ring_table(state, lanes: int) -> jnp.ndarray:
+    ring = state["window"][0]
+    width = (ring.shape[0] - 1) // lanes
+    return (1 + jnp.arange(lanes, dtype=jnp.int32)[:, None] * width
+            + jnp.arange(width, dtype=jnp.int32)[None, :])
+
+
+# ---------------- one token per lane ----------------
+
+
+def _rows_layer(layer, spec, i, x, pos, live, state, slots, page_table,
+                page_size, ring_table, handed, config, use_pallas,
+                write: bool = True):
+    """One layer over one row per lane (x [B, h] at positions pos [B]).
+    `write` False: the row's own K/V are already in the cache (the packed
+    forward wrote them for every token)."""
+    u = _ln(x, layer, "attn_norm", config)
+    seq_lens = jnp.where(live, pos + 1, 0)
+    if spec.kind == "mamba":
+        with jax.named_scope("ssm"):
+            j = slots[i]
+            xin, z = _mamba_project(layer, u, config)
+            conv_out, tail = ssm.causal_conv_step(
+                xin, state["conv"][j], layer["conv_w"], layer["conv_b"])
+            xs, dt, A, Bm, Cm = _mamba_scan_inputs(layer, conv_out, config)
+            y, s = ssm.selective_scan_step(
+                xs, dt, A, Bm, Cm, layer["D"], state["ssm"][j], live)
+            state["ssm"][j] = s
+            state["conv"][j] = jnp.where(
+                live[:, None, None], tail, state["conv"][j])
+            mixed, handed[i] = _mamba_out(layer, y, z)
+    elif spec.kind == "gmu":
+        mixed = _gmu(layer, u, handed[spec.reads])
+    elif spec.kind == "window_attention":
+        with jax.named_scope("window_attention"):
+            j = slots[i]
+            ring = state["window"][j]
+            R = ring_table.shape[1] * ring.shape[3]
+            k, v = _keys_values(layer, u, config)
+            ring = append_token_kv(
+                ring, k, v, ring_table, pos % R, live, ring.shape[3])
+            state["window"][j] = ring
+            attn = paged_attention_scaled(
+                _queries(layer, u, config), ring, ring_table,
+                jnp.minimum(seq_lens, R), _scale(config),
+                "window_attention_decode", use_pallas)
+            mixed = _differential_out(layer, attn, config, i)
+    else:  # attention over the shared cache: its own layer's, or another's
+        with jax.named_scope("shared_kv_attention"):
+            j = slots[spec.reads]
+            if spec.kind == "attention" and write:
+                k, v = _keys_values(layer, u, config)
+                state["paged"][j] = append_token_kv(
+                    state["paged"][j], k, v, page_table, pos, live, page_size)
+            attn = paged_attention_scaled(
+                _queries(layer, u, config), state["paged"][j], page_table,
+                seq_lens, _scale(config), "shared_kv_attention_decode",
+                use_pallas)
+            mixed = _differential_out(layer, attn, config, i)
+    return _close(layer, x, mixed, config)
+
+
+def _copy_state(state) -> dict:
+    return {kind: list(arrays) for kind, arrays in state.items()}
+
+
+def decode_step(params, config, tokens, pos, state, page_table, active,
+                page_size: int, use_pallas: Optional[bool] = None):
+    """One token per lane through every layer; returns ([B, vocab] logits,
+    the new state).  A lane that is not `active` writes to the null page
+    and keeps its recurrent state."""
+    table = config.layer_table()
+    slots = _slots(table)
+    state = _copy_state(state)
+    ring_table = _ring_table(state, tokens.shape[0])
+    with jax.named_scope("embedding"):
+        x = params["embed"][tokens].astype(jnp.dtype(config.dtype))
+    handed: Dict[int, Any] = {}
+    for i, (layer, spec) in enumerate(zip(params["layers"], table)):
+        x = _rows_layer(layer, spec, i, x, pos, active, state, slots,
+                        page_table, page_size, ring_table, handed, config,
+                        use_pallas)
+    return _logits(params, x, config), state
+
+
+# ---------------- the packed buffer ----------------
+
+
+def forward_ragged(params, config, tokens, token_seq, token_pos, q_start,
+                   q_len, kv_start, state, page_table, page_size: int,
+                   last_idx, use_pallas: Optional[bool] = None,
+                   block: int = 1):
+    """The mixed program's forward over the packed [T] buffer
+    (models/llama.forward_ragged's contract): every lane's slice starts
+    from the lane's stored state, or from zero where it starts at position
+    0; returns ([B, vocab] logits at each lane's last token, new state)."""
+    table = config.layer_table()
+    slots = _slots(table)
+    state = _copy_state(state)
+    B = q_start.shape[0]
+    ring_table = _ring_table(state, B)
+    lane = jnp.maximum(token_seq, 0)
+    token_off = token_pos - kv_start[lane]
+    fresh = kv_start == 0
+    has_slice = q_len > 0
+    last_writer = max(i for i, spec in enumerate(table) if spec.writes != "none")
+    with jax.named_scope("embedding"):
+        x = params["embed"][tokens].astype(jnp.dtype(config.dtype))
+    handed: Dict[int, Any] = {}
+    pos_rows = token_pos[last_idx]
+    for i, (layer, spec) in enumerate(zip(params["layers"], table)):
+        if i > last_writer:
+            x = _rows_layer(layer, spec, i, x, pos_rows, has_slice, state,
+                            slots, page_table, page_size, ring_table, handed,
+                            config, use_pallas)
+            continue
+        u = _ln(x, layer, "attn_norm", config)
+        if spec.kind == "mamba":
+            with jax.named_scope("ssm"):
+                j = slots[i]
+                xin, z = _mamba_project(layer, u, config)
+                conv_out, tail = ssm.causal_conv_ragged(
+                    xin, state["conv"][j], layer["conv_w"], layer["conv_b"],
+                    token_seq, token_off, q_start, q_len, fresh)
+                xs, dt, A, Bm, Cm = _mamba_scan_inputs(layer, conv_out, config)
+                y, s = ssm.selective_scan_ragged(
+                    xs, dt, A, Bm, Cm, layer["D"], state["ssm"][j], token_seq,
+                    q_start, q_len, last_idx, fresh, block)
+                state["ssm"][j] = s
+                state["conv"][j] = jnp.where(
+                    has_slice[:, None, None], tail, state["conv"][j])
+                mixed, handed[i] = _mamba_out(layer, y, z)
+        elif spec.kind == "window_attention":
+            with jax.named_scope("window_attention"):
+                j = slots[i]
+                ring = state["window"][j]
+                ps = ring.shape[3]
+                R = ring_table.shape[1] * ps
+                k, v = _keys_values(layer, u, config)
+                attn = ring_window_attention_ragged(
+                    _queries(layer, u, config), k, v, ring, ring_table,
+                    token_seq, token_pos, kv_start, _scale(config), block)
+                # of a slice longer than the ring only the newest R tokens
+                # are kept (the others would collide with them)
+                kept = token_pos >= (kv_start + q_len)[lane] - R
+                state["window"][j] = write_ragged_kv(
+                    ring, k, v, ring_table,
+                    jnp.where(kept, token_seq, -1), token_pos % R, ps)
+                mixed = _differential_out(layer, attn, config, i)
+        elif spec.kind == "attention" and i == last_writer:
+            # K/V of every token go to the pool; the attention's own output
+            # feeds only layers behind it, so it is taken at the sampled rows
+            with jax.named_scope("shared_kv_attention"):
+                k, v = _keys_values(layer, u, config)
+                j = slots[i]
+                state["paged"][j] = write_ragged_kv(
+                    state["paged"][j], k, v, page_table, token_seq, token_pos,
+                    page_size)
+            x = x[last_idx]
+            handed = {key: m[last_idx] for key, m in handed.items()}
+            x = _rows_layer(layer, spec, i, x, pos_rows, has_slice, state,
+                            slots, page_table, page_size, ring_table, handed,
+                            config, use_pallas, write=False)
+            continue
+        else:
+            raise NotImplementedError(
+                f"layer {i} ({spec.kind}) in the packed forward before the "
+                "last layer that writes state")
+        x = _close(layer, x, mixed, config)
+        if i == last_writer:
+            x = x[last_idx]
+            handed = {key: m[last_idx] for key, m in handed.items()}
+    return _logits(params, x, config), state
+
+
+# ---------------- checkpoints ----------------
+
+#: checkpoint tensor (under `model.layers.<i>.`) -> (our name, transposed).
+#: Names as the builder knows them from the published modeling file, no
+#: network here: listed under `assumed` in the benchmark's configuration.
+_HF_COMMON = {
+    "input_layernorm.weight": ("attn_norm", False),
+    "input_layernorm.bias": ("attn_norm_b", False),
+    "post_attention_layernorm.weight": ("mlp_norm", False),
+    "post_attention_layernorm.bias": ("mlp_norm_b", False),
+    "mlp.fc2.weight": ("w_down", True),
+}
+_HF_BY_KIND = {
+    "attention": {
+        "attn.out_proj.weight": ("wo", True), "attn.out_proj.bias": ("bo", False),
+        "attn.lambda_q1": ("lambda_q1", False), "attn.lambda_k1": ("lambda_k1", False),
+        "attn.lambda_q2": ("lambda_q2", False), "attn.lambda_k2": ("lambda_k2", False),
+        "attn.subln.weight": ("subln", False),
+    },
+    "mamba": {
+        "attn.in_proj.weight": ("in_proj", True),
+        "attn.conv1d.bias": ("conv_b", False),
+        "attn.x_proj.weight": ("x_proj", True),
+        "attn.dt_proj.weight": ("dt_proj", True),
+        "attn.dt_proj.bias": ("dt_bias", False),
+        "attn.A_log": ("A_log", False), "attn.D": ("D", False),
+        "attn.out_proj.weight": ("out_proj", True),
+    },
+    "gmu": {
+        "attn.in_proj.weight": ("gmu_in", True),
+        "attn.out_proj.weight": ("gmu_out", True),
+    },
+}
+_HF_BY_KIND["window_attention"] = _HF_BY_KIND["attention"]
+_HF_BY_KIND["cross_attention"] = _HF_BY_KIND["attention"]
+
+
+def hf_layer_tensors(config, spec, layer: Dict[str, np.ndarray]) -> Dict[str, np.ndarray]:
+    """The inverse of the loader for one layer: our arrays (numpy) -> the
+    checkpoint's names under `model.layers.<i>.`; tests write a synthetic
+    checkpoint with it."""
+    out = {"mlp.fc1.weight": np.concatenate(
+        [layer["w_gate"].T, layer["w_up"].T], axis=0)}
+    for hf, (ours, transposed) in {**_HF_COMMON, **_HF_BY_KIND[spec.kind]}.items():
+        if ours in layer:
+            out[hf] = layer[ours].T if transposed else layer[ours]
+    if spec.kind == "mamba":
+        out["attn.conv1d.weight"] = layer["conv_w"].T[:, None, :]
+    if "wq" in layer:
+        parts = [("wq", "bq")] + (
+            [("wk", "bk"), ("wv", "bv")] if "wk" in layer else [])
+        out["attn.Wqkv.weight"] = np.concatenate(
+            [layer[w].T for w, _ in parts], axis=0)
+        if config.attention_bias:
+            out["attn.Wqkv.bias"] = np.concatenate([layer[b] for _, b in parts])
+    return out
+
+
+def load_hf_weights_streamed(model_dir: str, config, weight_quant: str = "none",
+                             stats: Optional[dict] = None) -> Params:
+    """models/llama.load_hf_weights_streamed for a table of several kinds:
+    one tensor at a time, routed by the layer's row."""
+    from safetensors import safe_open
+
+    if weight_quant != "none":
+        raise NotImplementedError("weight_quant over a hybrid model")
+    dtype = jnp.dtype(config.dtype)
+    table = config.layer_table()
+    files = sorted(os.path.join(model_dir, f) for f in os.listdir(model_dir)
+                   if f.endswith(".safetensors"))
+    if not files:
+        raise FileNotFoundError(f"no .safetensors files under {model_dir}")
+    acct = {"peak_host_bytes": 0, "read_bytes": 0, "n_tensors": 0}
+    params: Params = {"layers": [dict() for _ in table]}
+    layer_re = re.compile(r"^model\.layers\.(\d+)\.(.+)$")
+    top = {"model.embed_tokens.weight": "embed",
+           "model.final_layernorm.weight": "final_norm",
+           "model.final_layernorm.bias": "final_norm_b"}
+
+    def put(layer, name, arr, transposed=False):
+        want = jnp.float32 if name in _F32_INITS else dtype
+        layer[name] = jnp.asarray(arr.T if transposed else arr).astype(want)
+
+    def place(name: str, arr: np.ndarray) -> None:
+        if name in top:
+            put(params, top[name], arr)
+            return
+        m = layer_re.match(name)
+        if m is None or int(m.group(1)) >= len(table):
+            return
+        i, suffix = int(m.group(1)), m.group(2)
+        layer, spec = params["layers"][i], table[i]
+        nq = config.n_heads * config.head_dim
+        nkv = config.n_kv_heads * config.head_dim
+        if suffix == "mlp.fc1.weight":  # [gate; up] x h
+            f = config.intermediate_size
+            put(layer, "w_gate", arr[:f], True)
+            put(layer, "w_up", arr[f:], True)
+        elif suffix == "attn.conv1d.weight":  # [Di, 1, K]
+            put(layer, "conv_w", arr[:, 0, :], True)
+        elif suffix in ("attn.Wqkv.weight", "attn.Wqkv.bias"):
+            w = suffix.endswith("weight")
+            names = ("wq", "wk", "wv") if w else ("bq", "bk", "bv")
+            put(layer, names[0], arr[:nq], w)
+            if spec.kind != "cross_attention":
+                put(layer, names[1], arr[nq:nq + nkv], w)
+                put(layer, names[2], arr[nq + nkv:], w)
+        else:
+            hit = {**_HF_COMMON, **_HF_BY_KIND[spec.kind]}.get(suffix)
+            if hit is not None:
+                put(layer, hit[0], arr, hit[1])
+
+    for path in files:
+        with safe_open(path, framework="numpy") as f:
+            for name in f.keys():
+                arr = f.get_tensor(name)
+                acct["read_bytes"] += arr.nbytes
+                acct["n_tensors"] += 1
+                acct["peak_host_bytes"] = max(acct["peak_host_bytes"], arr.nbytes)
+                place(name, arr)
+    for i, (layer, spec) in enumerate(zip(params["layers"], table)):
+        missing = sorted(set(layer_param_shapes(config, spec)) - set(layer))
+        if missing:
+            raise ValueError(f"checkpoint lacks {missing} of layer {i} ({spec.kind})")
+    if stats is not None:
+        stats.update(acct)
+    return params

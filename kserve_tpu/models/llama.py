@@ -65,6 +65,61 @@ def _map_hidden_act(act) -> str:
     raise ValueError(f"unsupported hidden_act {act!r}")
 
 
+#: what a layer's mixer can be, and what state it writes
+MIXER_KINDS = ("attention", "window_attention", "cross_attention", "mamba", "gmu")
+_WRITES = {"attention": "paged_kv", "window_attention": "window_kv",
+           "cross_attention": "none", "mamba": "recurrent", "gmu": "none"}
+
+#: model_type values LlamaConfig's family knobs describe
+_LLAMA_MODEL_TYPES = (None, "llama", "mistral", "mixtral", "qwen2", "qwen3",
+                      "gemma2")
+#: config.json keys of mixers those knobs cannot express
+_FOREIGN_MIXER_KEYS = (
+    "mb_per_layer", "ssm_cfg", "state_size", "conv_kernel", "mamba_d_state",
+    "kv_lora_rank", "q_lora_rank", "layers_block_type", "attn_layer_indices",
+    "full_attention_interval", "linear_num_value_heads", "n_routed_experts",
+    "num_experts", "moe_intermediate_size")
+
+
+@dataclass(frozen=True)
+class LayerSpec:
+    """One row of a model's per-layer table: the layer's mixer, the kind of
+    per-lane state it writes (`none`, `paged_kv`: pages of the pool,
+    `window_kv`: a ring bounded by the window, `recurrent`: a slot of
+    convolution tail and scan state), and the layer whose state or hand-on
+    it reads (itself where it reads its own).  The forward
+    (models/hybrid.py), the cache manager (engine/kvcache.StateLayout),
+    parallel/sharding.param_pspecs, the weight initialiser and the
+    checkpoint loader all derive from these rows."""
+
+    kind: str
+    writes: str
+    reads: int
+
+
+def phi4flash_mixer_kinds(n_layers: int, mb_per_layer: int) -> Tuple[str, ...]:
+    """The SambaY decoder-hybrid-decoder (arXiv:2507.06607): a self-decoder
+    of Mamba / window-attention pairs, one more Mamba layer whose scan
+    output the cross-decoder's gated memory units reuse, one full-attention
+    layer whose K/V its cross-attention layers reuse."""
+    if n_layers % 4 or mb_per_layer != 2:
+        raise ValueError(
+            "phi4flash: num_hidden_layers must be a multiple of 4 and "
+            f"mb_per_layer 2, got {n_layers} and {mb_per_layer}")
+    half = n_layers // 2
+    kinds = []
+    for i in range(n_layers):
+        if i < half:
+            kinds.append("mamba" if i % 2 == 0 else "window_attention")
+        elif i == half:
+            kinds.append("mamba")
+        elif i == half + 1:
+            kinds.append("attention")
+        else:
+            kinds.append("gmu" if i % 2 == 0 else "cross_attention")
+    return tuple(kinds)
+
+
 @dataclass
 class LlamaConfig:
     vocab_size: int = 32000
@@ -101,12 +156,63 @@ class LlamaConfig:
     n_experts: int = 0
     n_experts_per_tok: int = 2
     dtype: str = "bfloat16"
+    # ---- hybrid families (models/hybrid.py); None / defaults = Llama ----
+    # mixer kind per layer (MIXER_KINDS); None = every layer "attention"
+    mixer_kinds: Optional[Tuple[str, ...]] = None
+    norm_type: str = "rmsnorm"  # "layernorm": weight and bias, eps = rms_norm_eps
+    use_rope: bool = True
+    attention_out_bias: bool = False
+    # differential attention (arXiv:2410.05258): heads pair up (2j, 2j+1)
+    diff_attention: bool = False
+    # Mamba-1 sizes
+    mamba_d_inner: int = 0
+    mamba_d_state: int = 0
+    mamba_d_conv: int = 0
+    mamba_dt_rank: int = 0
 
     def __post_init__(self):
         if self.head_dim is None:
             self.head_dim = self.hidden_size // self.n_heads
         if self.layer_types is not None:
             self.layer_types = tuple(self.layer_types)
+        if self.mixer_kinds is not None:
+            self.mixer_kinds = tuple(self.mixer_kinds)
+            unknown = sorted(set(self.mixer_kinds) - set(MIXER_KINDS))
+            if unknown or len(self.mixer_kinds) != self.n_layers:
+                raise ValueError(
+                    f"mixer_kinds: {self.n_layers} entries of {MIXER_KINDS} "
+                    f"expected, got {len(self.mixer_kinds)} with {unknown}")
+
+    @property
+    def is_hybrid(self) -> bool:
+        return self.mixer_kinds is not None
+
+    def layer_table(self) -> Tuple[LayerSpec, ...]:
+        """The per-layer table.  A Llama-family model is n_layers equal
+        rows: attention over its own paged K/V."""
+        kinds = self.mixer_kinds or ("attention",) * self.n_layers
+        table = []
+        for i, kind in enumerate(kinds):
+            reads = i
+            if kind in ("cross_attention", "gmu"):
+                wanted = "attention" if kind == "cross_attention" else "mamba"
+                reads = max((j for j in range(i) if kinds[j] == wanted),
+                            default=-1)
+                if reads < 0:
+                    raise ValueError(
+                        f"layer {i} ({kind}) has no {wanted} layer before it")
+            table.append(LayerSpec(kind, _WRITES[kind], reads))
+        return tuple(table)
+
+    @property
+    def cache_kv_heads(self) -> int:
+        """K/V heads as the cache stores them: a differential pair's two
+        heads lie side by side in one row of twice the width."""
+        return self.n_kv_heads // 2 if self.diff_attention else self.n_kv_heads
+
+    @property
+    def cache_head_dim(self) -> int:
+        return 2 * self.head_dim if self.diff_attention else self.head_dim
 
     def layer_window(self, i: int) -> int:
         """Sliding-window width for layer i (0 = full attention)."""
@@ -242,6 +348,16 @@ class LlamaConfig:
                 cfg = json.load(f)
         else:
             cfg = dict(path_or_dict)
+        model_type = cfg.get("model_type")
+        if model_type == "phi4flash":
+            return _phi4flash_config(cfg)
+        if model_type not in _LLAMA_MODEL_TYPES:
+            foreign = [k for k in _FOREIGN_MIXER_KEYS if k in cfg]
+            if foreign:
+                raise ValueError(
+                    f"model_type {model_type!r} is not supported: its keys "
+                    f"{foreign} describe mixers the per-layer table "
+                    f"({', '.join(MIXER_KINDS)}) cannot express")
         rope_scaling = cfg.get("rope_scaling")
         if rope_scaling is not None:
             # Validate eagerly: Llama-3.1/3.2 checkpoints rely on rope_type
@@ -307,6 +423,44 @@ class LlamaConfig:
         )
 
 
+def _phi4flash_config(cfg: dict) -> LlamaConfig:
+    """config.json of `model_type: phi4flash`.  What the published file
+    does not give is read from keys a deployment adds and otherwise set by
+    the family's convention (benchmark/configs/phi4-mini-flash.json lists
+    each under `assumed`)."""
+    h = cfg["hidden_size"]
+    d_inner = cfg.get("mamba_d_inner", 2 * h)
+    if cfg.get("diff_attention_pairing", "adjacent") != "adjacent":
+        raise ValueError(
+            "phi4flash: only diff_attention_pairing 'adjacent' (heads 2j, "
+            f"2j+1) is implemented, got {cfg['diff_attention_pairing']!r}")
+    return LlamaConfig(
+        vocab_size=cfg["vocab_size"],
+        hidden_size=h,
+        intermediate_size=cfg["intermediate_size"],
+        n_layers=cfg["num_hidden_layers"],
+        n_heads=cfg["num_attention_heads"],
+        n_kv_heads=cfg.get("num_key_value_heads", cfg["num_attention_heads"]),
+        head_dim=cfg.get("head_dim"),
+        rms_norm_eps=cfg.get("layer_norm_eps", 1e-5),
+        max_position_embeddings=cfg.get("max_position_embeddings", 4096),
+        tie_word_embeddings=cfg.get("tie_word_embeddings", True),
+        attention_bias=cfg.get("attention_bias", True),
+        attention_out_bias=cfg.get("attention_out_bias", True),
+        hidden_act=_map_hidden_act(cfg.get("hidden_act")),
+        sliding_window=cfg["sliding_window"],
+        mixer_kinds=phi4flash_mixer_kinds(
+            cfg["num_hidden_layers"], cfg.get("mb_per_layer", 2)),
+        norm_type="layernorm",
+        use_rope=False,
+        diff_attention=True,
+        mamba_d_inner=d_inner,
+        mamba_d_state=cfg.get("mamba_d_state", 16),
+        mamba_d_conv=cfg.get("mamba_d_conv", 4),
+        mamba_dt_rank=cfg.get("mamba_dt_rank", -(-h // 16)),
+    )
+
+
 def init_params(config: LlamaConfig, rng: jax.Array, scale: float = 0.02,
                 weight_quant: str = "none", shardings=None) -> Params:
     """Random-initialized parameter pytree (bench/tests; real serving loads
@@ -323,6 +477,10 @@ def init_params(config: LlamaConfig, rng: jax.Array, scale: float = 0.02,
     holds more than its own shard plus one layer's f32 temporaries.  A
     model that only fits sharded (Llama-3-8B bf16 at tp=4) starts; None
     places everything on the default device."""
+    if config.is_hybrid:
+        from . import hybrid
+
+        return hybrid.init_params(config, rng, scale, weight_quant, shardings)
     dtype = jnp.dtype(config.dtype)
     h, hd = config.hidden_size, config.head_dim
     nq, nkv = config.n_heads, config.n_kv_heads
@@ -693,6 +851,14 @@ def decode_step(
     # e.g. ops.attention.make_sharded_paged_attention for tp>1
 ) -> Tuple[jnp.ndarray, List[jnp.ndarray]]:
     """One decode token per sequence; returns ([B, vocab] logits, new pages)."""
+    if config.is_hybrid:
+        # a table of several mixer kinds: `kv_pages` is the state pytree of
+        # engine/kvcache.StateLayout, stepped by models/hybrid.py
+        from . import hybrid
+
+        return hybrid.decode_step(
+            params, config, tokens, pos, kv_pages, page_table, active,
+            page_size, use_pallas=use_pallas)
     B = tokens.shape[0]
     onehot = _adapter_onehot(params, adapter_ids, B)
     x = _embed(params, tokens, config)[:, None, :]  # [B,1,h]
@@ -765,6 +931,7 @@ def forward_ragged(
     # a K+1-token slice needs its own next-token distribution
     dense_stride: Optional[int] = None,  # static dense-packing stride for
     # the Pallas kernel (lanes share blocks; None = solo-block invariant)
+    ragged_block: int = 1,  # static: the alignment slices are packed at
 ) -> Tuple[jnp.ndarray, List[jnp.ndarray]]:
     """The unified mixed-batch forward (docs/kernels.md): every lane
     contributes an arbitrary-length query slice — a whole prompt, a prompt
@@ -778,6 +945,13 @@ def forward_ragged(
     axis = packed tokens), which keeps every per-batch mechanism — LoRA
     one-hot selection, biases, qk-norm — per-TOKEN, so lanes with
     different adapters coexist in one mixed dispatch."""
+    if config.is_hybrid:
+        from . import hybrid
+
+        return hybrid.forward_ragged(
+            params, config, tokens, token_seq, token_pos, q_start, q_len,
+            kv_start, kv_pages, page_table, page_size, last_idx,
+            use_pallas=use_pallas, block=ragged_block)
     T = tokens.shape[0]
     valid = token_seq >= 0
     seq_ix = jnp.maximum(token_seq, 0)
@@ -1068,6 +1242,11 @@ def load_hf_weights_streamed(model_dir: str, config: LlamaConfig,
     stack into one [E, in, out] tensor), then free."""
     from safetensors import safe_open
 
+    if config.is_hybrid:
+        from . import hybrid
+
+        return hybrid.load_hf_weights_streamed(
+            model_dir, config, weight_quant, stats)
     if weight_quant == "int8" and config.n_experts > 0:
         raise NotImplementedError("weight_quant over MoE experts")
     dtype = jnp.dtype(config.dtype)

@@ -199,8 +199,13 @@ def paged_attention_xla(
     return out[:, 0].astype(q.dtype)
 
 
-def pallas_min_pages(d: int, kv_heads: int, page_size: int,
-                     batch: int) -> Optional[int]:
+#: the packed head-64 kernel copies one layer's whole cache on every call:
+#: beyond this many bytes of cache the copy alone outlasts the gather
+PACKED_KERNEL_MAX_CACHE_BYTES = 256 << 20
+
+
+def pallas_min_pages(d: int, kv_heads: int, page_size: int, batch: int,
+                     cache_pages: Optional[int] = None) -> Optional[int]:
     """The narrowest page table (in pages) from which the decode kernel
     beats the gather for one compiled shape: 0 = at every width, None = at
     no width.  Set from per-call times measured on a v5e at widths 8-128
@@ -223,12 +228,22 @@ def pallas_min_pages(d: int, kv_heads: int, page_size: int,
     0.9 ms at 9200), so where it wins depends on the cache's size, which
     a width cannot express.  64 is where it has stopped losing at every
     cache size measured, 128 for pages of 2 KV heads; 8 lanes never pay
-    the copy back.  Removing the copy, not these numbers, is the repair.
+    the copy back.  Where the caller knows the cache's size (`cache_pages`)
+    and one layer's cache passes PACKED_KERNEL_MAX_CACHE_BYTES, never: at
+    30000 pages of 20 KV heads (2.46 GB) the copy is 7.8 ms a call and the
+    kernel loses at every width to 64 (0.04-0.71x; PR 29's rows), at 9200
+    pages of 8 (301 MB) it loses at 40 pages.  Removing the copy, not
+    these numbers, is the repair: models/hybrid.py stores head-64 pairs
+    side by side in rows of 128 and takes the main kernel.
 
     `kv_heads` is what ONE device holds (the local shard under shard_map),
     so a model's answer changes with its tp, from the shape alone."""
     if d == 64:
         if batch < 16:
+            return None
+        if cache_pages is not None and (
+                cache_pages * 2 * kv_heads * page_size * d * 2
+                > PACKED_KERNEL_MAX_CACHE_BYTES):
             return None
         return 64 if kv_heads >= 4 else 128
     page_bytes = 2 * kv_heads * page_size * d * 2  # K and V, bf16
@@ -240,7 +255,8 @@ def pallas_min_pages(d: int, kv_heads: int, page_size: int,
 
 
 def _should_use_pallas(d: int, quantized: bool, table_width: int, batch: int,
-                       backend: str, page_size, kv_heads: int) -> bool:
+                       backend: str, page_size, kv_heads: int,
+                       cache_pages: Optional[int] = None) -> bool:
     """The use_pallas=None auto-dispatch predicate (factored out so tests
     assert the production decision, not a re-inlined copy)."""
     from .pallas_paged_attention import _pick_sb
@@ -262,7 +278,7 @@ def _should_use_pallas(d: int, quantized: bool, table_width: int, batch: int,
         and backend == "tpu"
     ):
         return False
-    min_pages = pallas_min_pages(d, kv_heads, page_size, batch)
+    min_pages = pallas_min_pages(d, kv_heads, page_size, batch, cache_pages)
     return min_pages is not None and table_width >= min_pages
 
 
@@ -553,6 +569,28 @@ def describe_attention_dispatch(model_config, engine_config,
     mc, cfg = model_config, engine_config
     quantized = cfg.kv_quant == "int8"
     min_pages = None
+    if mc.is_hybrid:
+        # models/hybrid.py: every read of a cache is one query per lane (the
+        # decode kernel or its gather, gated as below at the cache's row
+        # width); a window layer's packed slice is the XLA ring attention
+        if cfg.use_pallas is None:
+            decode = _should_use_pallas(
+                mc.cache_head_dim, False, cfg.max_pages_per_seq,
+                cfg.max_batch_size, backend, cfg.page_size, mc.cache_kv_heads)
+            if decode:
+                min_pages = pallas_min_pages(
+                    mc.cache_head_dim, mc.cache_kv_heads, cfg.page_size,
+                    cfg.max_batch_size) or None
+        else:
+            decode = bool(cfg.use_pallas)
+        return {
+            "backend": backend,
+            "mixed": "xla_ring_window+" + (
+                "pallas_decode" if decode else "xla_gather"),
+            "decode": "pallas_decode" if decode else "xla_gather",
+            "decode_pallas_min_pages": min_pages,
+            "shard_map": False,
+        }
     if cfg.use_pallas is None:
         ragged = _should_use_ragged_pallas(mc.head_dim, backend, quantized)
         kv_heads = mc.n_kv_heads // cfg.tp  # one device's
@@ -561,11 +599,12 @@ def describe_attention_dispatch(model_config, engine_config,
             mc.sliding_window <= 0 and mc.attn_scale is None
             and _should_use_pallas(
                 mc.head_dim, quantized, cfg.max_pages_per_seq,
-                cfg.max_batch_size, backend, cfg.page_size, kv_heads))
+                cfg.max_batch_size, backend, cfg.page_size, kv_heads,
+                cfg.num_pages))
         if decode:
             min_pages = pallas_min_pages(
                 mc.head_dim, kv_heads, cfg.page_size,
-                cfg.max_batch_size) or None
+                cfg.max_batch_size, cfg.num_pages) or None
     else:
         ragged = decode = bool(cfg.use_pallas)
     return {
@@ -619,6 +658,7 @@ def paged_attention(
         use_pallas = _should_use_pallas(
             d, quantized, int(page_table.shape[1]), int(q.shape[0]),
             jax.default_backend(), int(pages.shape[3]), int(pages.shape[2]),
+            int(pages.shape[0]),
         )
     if use_pallas:
         if quantized:
@@ -639,3 +679,97 @@ def paged_attention(
         q, kv_pages, page_table, seq_lens, logit_softcap,
         scale=scale, window=window,
     )
+
+
+# ---------------- hybrid families (models/hybrid.py) ----------------
+#
+# A model whose attention layers read two kinds of cache: pages of a shared
+# full-attention cache, and a per-lane RING holding the last `window`
+# tokens of a window layer.  The model has no positional encoding, so the
+# order of the keys inside a ring does not matter to a softmax: one decode
+# token attends to its lane's ring as to a short paged sequence, with the
+# decode kernel as it is.
+
+
+def paged_attention_scaled(
+    q: jnp.ndarray,  # [B, nq, d]
+    kv_pages: jnp.ndarray,  # [num_pages, 2, nkv, ps, d]
+    page_table: jnp.ndarray,  # [B, W]
+    seq_lens: jnp.ndarray,  # [B]
+    scale: float,
+    name: str,  # the Pallas call's name: tells layer kinds apart in a trace
+    use_pallas: Optional[bool] = None,
+) -> jnp.ndarray:
+    """`paged_attention` with the score scale stated and the kernel's call
+    named by the caller.  The same gate picks kernel or gather."""
+    if use_pallas is None:
+        use_pallas = _should_use_pallas(
+            int(q.shape[-1]), False, int(page_table.shape[1]), int(q.shape[0]),
+            jax.default_backend(), int(kv_pages.shape[3]), int(kv_pages.shape[2]))
+    if use_pallas:
+        from .pallas_paged_attention import paged_attention_pallas
+
+        return paged_attention_pallas(
+            q, kv_pages, page_table, seq_lens, scale=scale, name=name)
+    return paged_attention_xla(q, kv_pages, page_table, seq_lens, scale=scale)
+
+
+def ring_window_attention_ragged(
+    q: jnp.ndarray,  # [T, nq, d] packed queries
+    k_new: jnp.ndarray,  # [T, nkv, d] the buffer's own keys (not yet in the ring)
+    v_new: jnp.ndarray,  # [T, nkv, d]
+    ring_pages: jnp.ndarray,  # [pages, 2, nkv, ps, d] as it was BEFORE this buffer
+    ring_table: jnp.ndarray,  # [B, Wr] each lane's ring pages; Wr * ps = window
+    token_seq: jnp.ndarray,  # [T] lane per token (-1 = padding)
+    token_pos: jnp.ndarray,  # [T] absolute positions
+    kv_start: jnp.ndarray,  # [B] tokens the lane had before its slice
+    scale: float,
+    block: int,  # packing alignment: a block of tokens holds one lane
+) -> jnp.ndarray:
+    """Window attention for the mixed program's packed buffer, in XLA.
+
+    A query at position i sees keys j with i - window < j <= i: those of its
+    own slice, taken from the buffer, and those the lane's ring held before
+    the slice.  Ring slot s holds the newest position below kv_start that is
+    congruent to s modulo the window; positions the slice will overwrite
+    are exactly those that have left the window of the query that would
+    need them last, so the ring is read before it is written.  The ring is
+    gathered once per BLOCK of queries (one lane), not per token."""
+    T, nq, d = q.shape
+    nkv = k_new.shape[1]
+    group = nq // nkv
+    Wr, ps = ring_table.shape[1], ring_pages.shape[3]
+    R = Wr * ps
+    nb = T // block
+    f32 = jnp.float32
+    blk_lane = jnp.maximum(token_seq[::block], 0)  # [nb]
+    ring = ring_pages[ring_table[blk_lane]]  # [nb, Wr, 2, nkv, ps, d]
+    start = kv_start[blk_lane][:, None]  # [nb, 1]
+    slot = jnp.arange(R, dtype=jnp.int32)[None, :]
+    slot_pos = start - 1 - ((start - 1 - slot) % R)  # [nb, R]; < 0: empty
+    q_pos = token_pos.reshape(nb, block)
+    ring_mask = (slot_pos[:, None, :] >= 0) & (
+        slot_pos[:, None, :] > q_pos[:, :, None] - R)  # [nb, block, R]
+    same = (token_seq[:, None] == token_seq[None, :]) & (token_seq[:, None] >= 0)
+    buf_mask = same & (token_pos[None, :] <= token_pos[:, None]) & (
+        token_pos[None, :] > token_pos[:, None] - R)  # [T, T]
+    # operands stay in the cache's dtype, products accumulate in float32
+    # (on the chip a float32 operand would be rounded to bfloat16 anyway)
+    qg = q.reshape(nb, block, nkv, group, d)
+    s_ring = jnp.einsum("nbkgd,nwkpd->nkgbwp", qg, ring[:, :, 0],
+                        preferred_element_type=f32).reshape(
+                            nb, nkv, group, block, R) * scale
+    s_buf = jnp.einsum("nbkgd,skd->nkgbs", qg, k_new.astype(q.dtype),
+                       preferred_element_type=f32) * scale
+    s_ring = jnp.where(ring_mask[:, None, None], s_ring, -1e30)
+    s_buf = jnp.where(
+        buf_mask.reshape(nb, block, T)[:, None, None], s_buf, -1e30)
+    weights = jax.nn.softmax(
+        jnp.concatenate([s_ring, s_buf], axis=-1), axis=-1).astype(v_new.dtype)
+    out = jnp.einsum(
+        "nkgbwp,nwkpd->nbkgd",
+        weights[..., :R].reshape(nb, nkv, group, block, Wr, ps), ring[:, :, 1],
+        preferred_element_type=f32)
+    out = out + jnp.einsum("nkgbs,skd->nbkgd", weights[..., R:], v_new,
+                           preferred_element_type=f32)
+    return out.reshape(T, nq, d).astype(q.dtype)

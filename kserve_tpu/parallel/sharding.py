@@ -52,6 +52,13 @@ def create_mesh(
 
 
 def validate_tp(config: LlamaConfig, tp: int) -> None:
+    if config.is_hybrid:
+        if tp > 1:
+            raise NotImplementedError(
+                "tp>1 over a hybrid model (mamba / window / shared-cache "
+                "layers): its parameters and per-lane state have no sharding "
+                "rules yet; run it with tp=1")
+        return
     if config.n_heads % tp != 0:
         raise ValueError(f"n_heads={config.n_heads} not divisible by tp={tp}")
     if config.n_kv_heads % tp != 0:
@@ -71,6 +78,16 @@ def validate_tp(config: LlamaConfig, tp: int) -> None:
 
 def param_pspecs(config: LlamaConfig) -> Dict[str, Any]:
     """PartitionSpec pytree matching models.llama param pytree."""
+    if config.is_hybrid:
+        # tp=1 only (validate_tp): every leaf of every row replicated
+        from ..models import hybrid
+
+        return {
+            "embed": P(), "final_norm": P(), "final_norm_b": P(),
+            "layers": [
+                {name: P() for name in hybrid.layer_param_shapes(config, spec)}
+                for spec in config.layer_table()],
+        }
     layer = {
         "attn_norm": P(),
         "wq": P(None, MODEL_AXIS),  # column parallel (heads)

@@ -59,6 +59,11 @@ FAMILIES = {
     "llama3.2-1b/b32": (32, 32, 8, 64),
     "llama3.2-1b/tp2": (48, 16, 4, 64),
     "llama3.2-1b/tp4": (48, 8, 2, 64),
+    # Phi-4-mini-flash: 20 K/V heads of 64 stored as 10 rows of 128 (a
+    # differential pair's heads side by side), and the same bytes as the
+    # published heads would lie, for the packed kernel
+    "phi4-mini-flash": (48, 40, 10, 128),
+    "phi4-mini-flash/heads-of-64": (48, 40, 20, 64),
 }
 N_LO, N_HI = 72, 216
 
@@ -111,7 +116,8 @@ def measure(family: str, width: int, budget_s: float,
         .reshape(lanes, width), jnp.int32)
     row = {"family": family, "lanes": lanes, "nq": nq, "nkv": nkv, "d": d,
            "width": width, "cache_pages": num_pages, "auto": _should_use_pallas(
-               d, False, width, lanes, jax.default_backend(), PAGE, nkv)}
+               d, False, width, lanes, jax.default_backend(), PAGE, nkv,
+               num_pages)}
     fns = {"kernel": _looped(True, num_pages),
            "gather": _looped(False, num_pages)}
     for kind in ("aged", "full"):

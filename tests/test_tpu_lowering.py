@@ -256,7 +256,7 @@ class TestGateEqualsTheMeasuredTable:
                   for k in ("aged", "full")]
         auto = att._should_use_pallas(
             row["d"], False, row["width"], row["lanes"], "tpu", 16,
-            row["nkv"])
+            row["nkv"], row["cache_pages"])
         return auto, min(ratios) > 1.03, max(ratios) < 0.97
 
     def test_never_takes_the_measured_loser(self, family):
@@ -330,3 +330,43 @@ class TestMixedProgramTakesTheDecodeKernel:
         gathered = re.findall(
             rf"tensor<{lanes}x{width}x[0-9x]*{ps}x128xbf16>", text)
         assert not gathered, sorted(set(gathered))
+
+
+class TestHybridDecodeKernelCalls:
+    """models/hybrid.py reads both of its caches with the decode kernel, at
+    rows of a differential pair's two heads side by side (10 rows of 128
+    for Phi-4-mini-flash's 20 K/V heads of 64), with the score scale of the
+    64-wide heads stated and the call named by the layer's kind: the names
+    are what a trace tells window and shared-cache attention apart by."""
+
+    @pytest.mark.parametrize("width, pages, name", [
+        (32, 1 + 48 * 32, "window_attention_decode"),  # a lane's ring
+        (8, 50000, "shared_kv_attention_decode"),
+        (64, 50000, "shared_kv_attention_decode"),
+    ])
+    def test_kernel_compiles_at_the_published_widths(self, width, pages, name):
+        fn = functools.partial(
+            pk.paged_attention_pallas, scale=64 ** -0.5, name=name)
+        args = (_abstract((48, 40, 128), jnp.bfloat16),
+                _abstract((pages, 2, 10, 16, 128), jnp.bfloat16),
+                _i32(48, width), _i32(48))
+        _check(fn, *args)
+        text = jax.jit(fn).trace(*args).lower(
+            lowering_platforms=("tpu",)).as_text()
+        assert name in text and "paged_attention_decode" not in text.replace(
+            name, "")
+
+    def test_gate_takes_the_kernel_at_every_width_of_the_cell(self):
+        """80 KB pages (10 rows x 16 tokens x 128, K and V): the kernel at
+        every width, the ring's 32 pages included."""
+        assert att.pallas_min_pages(128, 10, 16, 48) == 0
+        for width in (8, 16, 32, 64):
+            assert att._should_use_pallas(128, False, width, 48, "tpu", 16, 10)
+
+    def test_the_packed_head_64_kernel_takes_no_scale(self):
+        with pytest.raises(ValueError, match="no scale override"):
+            pk.paged_attention_pallas(
+                jnp.zeros((8, 4, 64), jnp.bfloat16),
+                jnp.zeros((8, 2, 2, 16, 64), jnp.bfloat16),
+                jnp.zeros((8, 4), jnp.int32), jnp.zeros((8,), jnp.int32),
+                scale=0.1)
