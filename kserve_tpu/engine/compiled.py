@@ -24,7 +24,12 @@ import jax.numpy as jnp
 from ..metrics import XLA_COMPILE_SECONDS, XLA_COMPILES
 from ..models import llama
 from ..parallel import sharding as shd
-from .sampling import apply_penalties, compute_logprobs, sample_tokens
+from .sampling import (
+    apply_penalties,
+    compute_logprobs,
+    sample_tokens,
+    sampler_truncates,
+)
 
 _log = logging.getLogger(__name__)
 
@@ -372,6 +377,7 @@ def program_defs(model_config, engine_config, mesh, spec_k=None) -> dict:
                capacity, counters, state, rng, adapter_ids, *penalty_args):
             steps = cfg.steps_per_sync
             B = tokens.shape[0]
+            truncates = sampler_truncates(state)  # once, not once a step
 
             def body(carry, step_rng):
                 if with_penalties:
@@ -400,7 +406,7 @@ def program_defs(model_config, engine_config, mesh, spec_k=None) -> dict:
                         state.presence_penalty,
                         penalty_args[0],
                     )
-                nxt = sample_tokens(logits, state, step_rng, counters)
+                nxt = sample_tokens(logits, state, step_rng, counters, truncates)
                 nxt = jnp.where(live, nxt, tokens)
                 if with_logprobs:
                     lp, tv, ti = compute_logprobs(logits, nxt, cfg.max_logprobs)
@@ -511,6 +517,7 @@ def program_defs(model_config, engine_config, mesh, spec_k=None) -> dict:
                adapter_ids):
             steps = cfg.steps_per_sync
             rngs = jax.random.split(rng, steps)
+            truncates = sampler_truncates(state)  # once, not once a step
             logits, kv_pages = llama.forward_ragged(
                 params, mc, q_tokens, token_seq, token_pos,
                 q_start, q_len, kv_start, kv_pages, page_table,
@@ -520,7 +527,7 @@ def program_defs(model_config, engine_config, mesh, spec_k=None) -> dict:
                 use_pallas=cfg.use_pallas,
                 ragged_block=ragged_block,
             )
-            sampled0 = sample_tokens(logits, state, rngs[0], counters)
+            sampled0 = sample_tokens(logits, state, rngs[0], counters, truncates)
             tokens0 = jnp.where(scan_tok0 >= 0, scan_tok0, sampled0)
             counters0 = counters + step0_emits
 
@@ -533,7 +540,7 @@ def program_defs(model_config, engine_config, mesh, spec_k=None) -> dict:
                     adapter_ids=adapter_ids,
                     attention_fn=decode_attention_fn,
                 )
-                nxt = sample_tokens(logits, state, step_rng, counters)
+                nxt = sample_tokens(logits, state, step_rng, counters, truncates)
                 nxt = jnp.where(live, nxt, tokens)
                 return (
                     nxt,
@@ -618,6 +625,7 @@ def program_defs(model_config, engine_config, mesh, spec_k=None) -> dict:
             # K+1 slice rows, so every verify position samples with the
             # lane's own temperature/top-k/top-p/seed
             row_state = jax.tree.map(lambda a: jnp.repeat(a, Kp), state)
+            truncates = sampler_truncates(state)  # once, not once a round
             rngs = jax.random.split(rng, rounds)
             lane_ix = jnp.arange(B)
 
@@ -660,7 +668,7 @@ def program_defs(model_config, engine_config, mesh, spec_k=None) -> dict:
                     cnt[:, None] + jnp.arange(Kp, dtype=cnt.dtype)[None, :]
                 ).reshape(-1)
                 sampled = sample_tokens(
-                    logits, row_state, step_rng, row_counters
+                    logits, row_state, step_rng, row_counters, truncates
                 ).reshape(B, Kp)
                 if k_drafts > 0:
                     match = (slice_toks[:, 1:] == sampled[:, :-1])

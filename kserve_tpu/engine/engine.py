@@ -50,6 +50,7 @@ from ..metrics import (
     ENGINE_STATE_SLOTS_IN_USE,
     ENGINE_PREFILL_CHUNK_DURATION,
     ENGINE_QUEUE_DEPTH,
+    ENGINE_SAMPLER_DISPATCHES,
     ENGINE_STEP_BATCH_COMPOSITION,
     ENGINE_STEP_DURATION,
     ENGINE_WEDGED,
@@ -96,7 +97,7 @@ from .kvcache import (
     init_kv_scales,
     pages_needed,
 )
-from .sampling import SamplingParams, SamplingState
+from .sampling import SAMPLER_PATHS, SamplingParams, SamplingState
 from .tokenizer import BaseTokenizer, IncrementalDetokenizer
 
 
@@ -251,6 +252,12 @@ class LLMEngine:
             ENGINE_DISPATCH_PHASE_SECONDS.labels(
                 model_name=metrics_label, phase=phase)
             for phase in (*PHASES, "wait_lag")]
+        # engine_sampler_dispatches_total by the path a dispatch's batch
+        # takes through the sampler (sampling.SAMPLER_PATHS)
+        self._sampler_dispatches = {
+            path: ENGINE_SAMPLER_DISPATCHES.labels(
+                model_name=metrics_label, sampler_path=path)
+            for path in SAMPLER_PATHS}
         # when the fetch worker last had a result on the host
         self._fetch_ready_at: Optional[float] = None
         # checkpoints carry this as model_name; resume_generation rejects a
@@ -3038,6 +3045,7 @@ class LLMEngine:
             and slot.params.logprobs is not None
             for i, slot in enumerate(self._slots)
         )
+        state, sampler_path = SamplingState.planned(params_list)
         return {
             "tokens": tokens,
             "pos": pos,
@@ -3046,7 +3054,8 @@ class LLMEngine:
             "page_table": page_table,
             "counters": counters,
             "adapters": adapters,
-            "state": SamplingState.from_params(params_list),
+            "state": state,
+            "sampler_path": sampler_path,
             "penalized": penalized,
             "want_logprobs": want_logprobs,
         }
@@ -3175,6 +3184,7 @@ class LLMEngine:
         self._phases.launched(
             "decode", n_active, meta["page_table"].shape[1], 0, n_active,
             chained=tokens_dev is not None)
+        self._sampler_dispatches[meta["sampler_path"]].inc()
         rng = jax.random.fold_in(self._base_rng, self._next_step())
         tokens = tokens_dev if tokens_dev is not None else jnp.asarray(meta["tokens"])
         args = (
@@ -3412,6 +3422,7 @@ class LLMEngine:
             "mixed", len(plan["q_tokens"]), plan["page_table"].shape[1],
             plan["prefill_tokens"], plan["decode_tokens"],
             compiled=getattr(self._mixed_fn, "compiles", 0) != compiles)
+        self._sampler_dispatches[plan["sampler_path"]].inc()
         phases.mark("wait")
         chunk_np = await self._fetch_async(out)
         phases.resumed(self._fetch_ready_at)
@@ -3538,6 +3549,7 @@ class LLMEngine:
         for i, slot in enumerate(self._slots):
             if slot.request_id is not None and slot.pages:
                 page_table[i, : len(slot.pages)] = slot.pages
+        state, sampler_path = SamplingState.planned(params_list)
         return {
             "q_tokens": np.asarray(tok_list, np.int32),
             "token_seq": np.asarray(seq_list, np.int32),
@@ -3554,7 +3566,8 @@ class LLMEngine:
             "capacity": capacity,
             "counters": counters,
             "adapters": adapters,
-            "state": SamplingState.from_params(params_list),
+            "state": state,
+            "sampler_path": sampler_path,
             "consume": consume,
             "chunks": chunks,
             "prefill_tokens": n_prefill_tokens,
@@ -3644,6 +3657,7 @@ class LLMEngine:
             "adapters": meta["adapters"],
             "page_table": meta["page_table"],
             "state": meta["state"],
+            "sampler_path": meta["sampler_path"],
         }
 
     def _plan_dense_chained(self, prev: dict) -> Optional[dict]:
@@ -3697,6 +3711,7 @@ class LLMEngine:
             "adapters": prev["adapters"],
             "page_table": page_table,
             "state": prev["state"],
+            "sampler_path": prev["sampler_path"],
         }
 
     def _dispatch_dense(self, plan: dict, chain: Optional[dict] = None):
@@ -3709,6 +3724,7 @@ class LLMEngine:
         self._phases.launched(
             "mixed_decode", n_tokens, plan["page_table"].shape[1], 0,
             n_tokens, chained=chain is not None)
+        self._sampler_dispatches[plan["sampler_path"]].inc()
         rng = jax.random.fold_in(self._base_rng, self._next_step())
         if chain is not None:
             tok, pos, cnt = chain["carry"]

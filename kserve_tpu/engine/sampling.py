@@ -10,7 +10,7 @@ Role parity: vLLM's Sampler (the reference delegates sampling to vLLM).
 from __future__ import annotations
 
 from dataclasses import dataclass
-from typing import List, Optional
+from typing import List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -66,25 +66,32 @@ class SamplingState:
     presence_penalty: jnp.ndarray  # [B] f32 (0.0 => off)
 
     @staticmethod
-    def from_params(params_list: List[SamplingParams]) -> "SamplingState":
-        return SamplingState(
-            temperature=jnp.asarray([p.temperature for p in params_list], jnp.float32),
-            top_p=jnp.asarray([p.top_p for p in params_list], jnp.float32),
-            top_k=jnp.asarray([p.top_k for p in params_list], jnp.int32),
-            min_p=jnp.asarray([p.min_p for p in params_list], jnp.float32),
-            seed=jnp.asarray(
-                [p.seed if p.seed is not None else -1 for p in params_list], jnp.int32
-            ),
-            repetition_penalty=jnp.asarray(
-                [p.repetition_penalty for p in params_list], jnp.float32
-            ),
-            frequency_penalty=jnp.asarray(
-                [p.frequency_penalty for p in params_list], jnp.float32
-            ),
-            presence_penalty=jnp.asarray(
-                [p.presence_penalty for p in params_list], jnp.float32
-            ),
+    def planned(params_list: List[SamplingParams]) -> Tuple["SamplingState", str]:
+        """The rows' state for the device and, from the same float32 /
+        int32 columns, the path `sample_tokens` will take for them
+        (SAMPLER_PATHS: engine_sampler_dispatches_total's label)."""
+        def column(name, dtype):
+            return np.asarray([getattr(p, name) for p in params_list], dtype)
+
+        cols = dict(
+            temperature=column("temperature", np.float32),
+            top_p=column("top_p", np.float32),
+            top_k=column("top_k", np.int32),
+            min_p=column("min_p", np.float32),
+            seed=np.asarray(
+                [p.seed if p.seed is not None else -1 for p in params_list],
+                np.int32),
+            repetition_penalty=column("repetition_penalty", np.float32),
+            frequency_penalty=column("frequency_penalty", np.float32),
+            presence_penalty=column("presence_penalty", np.float32),
         )
+        path = SAMPLER_PATHS[int(_truncates(
+            cols["temperature"], cols["top_k"], cols["top_p"], cols["min_p"]))]
+        return SamplingState(**{k: jnp.asarray(v) for k, v in cols.items()}), path
+
+    @staticmethod
+    def from_params(params_list: List[SamplingParams]) -> "SamplingState":
+        return SamplingState.planned(params_list)[0]
 
     @staticmethod
     def defaults(batch: int) -> "SamplingState":
@@ -100,23 +107,30 @@ class SamplingState:
         )
 
 
-@jax.named_scope("sampler")
-def sample_tokens(
-    logits: jnp.ndarray,  # [B, V] f32
-    state: SamplingState,
-    rng: jax.Array,
-    counters: Optional[jnp.ndarray] = None,  # [B] i32: tokens generated so far
-) -> jnp.ndarray:
-    """Returns [B] sampled token ids.  temperature==0 rows are greedy.
-    Rows with state.seed >= 0 draw from their own PRNG stream
-    (PRNGKey(seed) folded with the row's token counter) so a client-supplied
-    seed reproduces output regardless of batching."""
-    B, V = logits.shape
-    greedy = jnp.argmax(logits, axis=-1)
+#: the work a batch asks of the sampler: `truncate` where a sampled row
+#: carries top-k, top-p or min-p (three full-vocabulary sorts a step),
+#: `plain` where none does (no sort); indexed by `_truncates`
+SAMPLER_PATHS = ("plain", "truncate")
 
-    temp = jnp.maximum(state.temperature, 1e-6)[:, None]
-    scaled = logits / temp
 
+def _truncates(temperature, top_k, top_p, min_p):
+    """Whether any sampled row asks for top-k, top-p or min-p.  A greedy row
+    never does, whatever else it carries: its sampled value is discarded.
+    Written for numpy and jax arrays alike, so the host's label and the
+    device's branch are one predicate."""
+    sampled = ~(temperature <= 0.0)
+    return (sampled & ((top_k > 0) | (top_p < 1.0) | (min_p > 0.0))).any()
+
+
+def sampler_truncates(state: SamplingState) -> jnp.ndarray:
+    """`_truncates` of the batch, on the device.  A program that samples in
+    a loop computes it once, outside the loop."""
+    return _truncates(state.temperature, state.top_k, state.top_p, state.min_p)
+
+
+def _truncated(scaled: jnp.ndarray, state: SamplingState) -> jnp.ndarray:
+    """`scaled` with what top-k, top-p and min-p drop set to -inf."""
+    V = scaled.shape[-1]
     # top-k: mask logits below the k-th largest (k==0 disables)
     sorted_logits = jnp.sort(scaled, axis=-1)[:, ::-1]  # desc
     k = jnp.clip(state.top_k, 0, V)
@@ -147,7 +161,35 @@ def sample_tokens(
         probs < state.min_p[:, None] * max_prob,
         jnp.zeros_like(scaled, bool),
     )
-    scaled = jnp.where(minp_mask, -jnp.inf, scaled)
+    return jnp.where(minp_mask, -jnp.inf, scaled)
+
+
+@jax.named_scope("sampler")
+def sample_tokens(
+    logits: jnp.ndarray,  # [B, V] f32
+    state: SamplingState,
+    rng: jax.Array,
+    counters: Optional[jnp.ndarray] = None,  # [B] i32: tokens generated so far
+    truncates: Optional[jnp.ndarray] = None,  # sampler_truncates(state)
+) -> jnp.ndarray:
+    """Returns [B] sampled token ids.  temperature==0 rows are greedy.
+    Rows with state.seed >= 0 draw from their own PRNG stream
+    (PRNGKey(seed) folded with the row's token counter) so a client-supplied
+    seed reproduces output regardless of batching.
+
+    Only a batch in which a sampled row carries top-k, top-p or min-p runs
+    the truncation and its sorts (SAMPLER_PATHS).  The tokens do not depend
+    on that: with every mask off, truncation leaves the scaled logits as
+    they are."""
+    B = logits.shape[0]
+    greedy = jnp.argmax(logits, axis=-1)
+
+    temp = jnp.maximum(state.temperature, 1e-6)[:, None]
+    scaled = logits / temp
+    if truncates is None:
+        truncates = sampler_truncates(state)
+    scaled = jax.lax.cond(
+        truncates, lambda: _truncated(scaled, state), lambda: scaled)
 
     if counters is None:
         counters = jnp.zeros((B,), jnp.int32)

@@ -312,6 +312,17 @@ ENGINE_DISPATCHES = Counter(
     "device dispatches committed by the engine's loop, by program",
     ["model_name", "program"],
 )
+# `sampler_path` is the closed set engine/sampling.SAMPLER_PATHS: what the
+# dispatch's batch asked of the sampler, decided on the device by the same
+# predicate (docs/observability.md "Sampler paths"); not named `path`,
+# which the cardinality gate keeps for URL paths
+ENGINE_SAMPLER_DISPATCHES = Counter(
+    "engine_sampler_dispatches_total",
+    "device dispatches by the path their batch takes through the sampler: "
+    "truncate (a sampled row carries top-k, top-p or min-p: three "
+    "full-vocabulary sorts a step) | plain (none does: no sort)",
+    ["model_name", "sampler_path"],
+)
 ENGINE_FIRST_TOKEN_DISPATCHES = Summary(
     "engine_first_token_dispatches",
     "dispatches from a request's admission to its first token, both "

@@ -1,0 +1,343 @@
+"""engine/sampling.sample_tokens, held to a frozen copy of the body it had
+when every batch ran the truncation (three sorts, two softmaxes, a
+cumulative sum): whichever path a batch takes through the sampler, its
+tokens are that body's, token for token.
+"""
+
+import asyncio
+import dataclasses
+import inspect
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from prometheus_client import REGISTRY
+
+from kserve_tpu.engine.sampling import (
+    SAMPLER_PATHS,
+    SamplingParams,
+    SamplingState,
+    sample_tokens,
+    sampler_truncates,
+)
+
+V = 257  # odd and small: ties in a sort and clipping at V - 1 both occur
+
+
+def frozen_sample_tokens(logits, state, rng, counters=None):
+    """The sampler as it stood before it chose a path (PR 29's
+    engine/sampling.py:115-160), kept here unedited as the reference."""
+    B, V = logits.shape
+    greedy = jnp.argmax(logits, axis=-1)
+
+    temp = jnp.maximum(state.temperature, 1e-6)[:, None]
+    scaled = logits / temp
+
+    # top-k: mask logits below the k-th largest (k==0 disables)
+    sorted_logits = jnp.sort(scaled, axis=-1)[:, ::-1]  # desc
+    k = jnp.clip(state.top_k, 0, V)
+    kth_idx = jnp.clip(k - 1, 0, V - 1)
+    kth_val = jnp.take_along_axis(sorted_logits, kth_idx[:, None], axis=1)
+    topk_mask = jnp.where(
+        (state.top_k > 0)[:, None], scaled < kth_val, jnp.zeros_like(scaled, bool)
+    )
+    scaled = jnp.where(topk_mask, -jnp.inf, scaled)
+
+    # top-p (nucleus): keep smallest prefix of sorted probs with cumsum >= p
+    probs_sorted = jax.nn.softmax(jnp.sort(scaled, axis=-1)[:, ::-1], axis=-1)
+    cumprobs = jnp.cumsum(probs_sorted, axis=-1)
+    cutoff_count = jnp.sum(cumprobs - probs_sorted < state.top_p[:, None], axis=-1)
+    cutoff_idx = jnp.clip(cutoff_count - 1, 0, V - 1)
+    sorted_again = jnp.sort(scaled, axis=-1)[:, ::-1]
+    cutoff_val = jnp.take_along_axis(sorted_again, cutoff_idx[:, None], axis=1)
+    topp_mask = jnp.where(
+        (state.top_p < 1.0)[:, None], scaled < cutoff_val, jnp.zeros_like(scaled, bool)
+    )
+    scaled = jnp.where(topp_mask, -jnp.inf, scaled)
+
+    # min-p: drop tokens with prob < min_p * max_prob
+    probs = jax.nn.softmax(scaled, axis=-1)
+    max_prob = probs.max(axis=-1, keepdims=True)
+    minp_mask = jnp.where(
+        (state.min_p > 0.0)[:, None],
+        probs < state.min_p[:, None] * max_prob,
+        jnp.zeros_like(scaled, bool),
+    )
+    scaled = jnp.where(minp_mask, -jnp.inf, scaled)
+
+    if counters is None:
+        counters = jnp.zeros((B,), jnp.int32)
+    batch_keys = jax.random.split(rng, B)
+    seeded_keys = jax.vmap(
+        lambda s, c: jax.random.fold_in(jax.random.PRNGKey(s), c)
+    )(jnp.maximum(state.seed, 0), counters)
+    keys = jnp.where((state.seed >= 0)[:, None], seeded_keys, batch_keys)
+    sampled = jax.vmap(lambda k, row: jax.random.categorical(k, row))(keys, scaled)
+    return jnp.where(state.temperature <= 0.0, greedy, sampled).astype(jnp.int32)
+
+
+def P(**kw) -> SamplingParams:
+    return SamplingParams(**kw)
+
+
+GREEDY = P(temperature=0.0)
+#: what the planners seat in a lane no request holds
+EMPTY_LANE = SamplingParams()
+#: name -> (rows, the path the batch must take)
+MIXES = {
+    "all_greedy": ([GREEDY] * 6, "plain"),
+    "greedy_carrying_top_p": (
+        [GREEDY, P(temperature=0.0, top_p=0.5, top_k=3, min_p=0.2)] * 3,
+        "plain"),
+    "empty_lanes_only": ([EMPTY_LANE] * 6, "plain"),
+    "greedy_and_temperature": (
+        [GREEDY, P(temperature=0.7), P(), GREEDY, P(temperature=1.3), GREEDY],
+        "plain"),
+    "seeded_and_unseeded": (
+        [P(seed=7), P(temperature=0.8), P(temperature=0.8, seed=7),
+         P(seed=2**31 - 1), P(seed=0), GREEDY],
+        "plain"),
+    "temperature_beside_empty_lanes": (
+        [P(temperature=0.9, seed=3), EMPTY_LANE, EMPTY_LANE, P(), EMPTY_LANE,
+         EMPTY_LANE],
+        "plain"),
+    "greedy_top_p_beside_a_draw": (
+        [P(temperature=0.0, top_p=0.3), P(temperature=0.6), GREEDY, P(seed=5),
+         GREEDY, GREEDY],
+        "plain"),
+    "top_k": (
+        [P(top_k=1), P(top_k=5, temperature=0.7), P(top_k=V + 9), P(top_k=40),
+         GREEDY, P()],
+        "truncate"),
+    "top_p": (
+        [P(top_p=0.9, temperature=0.7, seed=11), P(top_p=0.05), P(top_p=0.5),
+         P(), GREEDY, P(top_p=0.999)],
+        "truncate"),
+    "min_p": (
+        [P(min_p=0.05), P(min_p=0.5, temperature=1.5), P(min_p=1.0), P(),
+         GREEDY, P(seed=4)],
+        "truncate"),
+    "one_truncating_row_among_empty_lanes": (
+        [EMPTY_LANE, EMPTY_LANE, P(top_p=0.9, temperature=0.7), EMPTY_LANE,
+         EMPTY_LANE, EMPTY_LANE],
+        "truncate"),
+    "everything_at_once": (
+        [GREEDY, P(temperature=0.0, top_p=0.2), P(temperature=0.7),
+         P(seed=9), P(top_k=7, top_p=0.8, min_p=0.1, temperature=0.6, seed=13),
+         EMPTY_LANE, P(top_k=3), P(top_p=0.6, seed=1), P(min_p=0.3),
+         P(temperature=2.0, top_k=50, top_p=0.95)],
+        "truncate"),
+}
+MIX_NAMES = sorted(MIXES)
+
+
+def _inputs(rows: int, seed: int = 0):
+    rng = np.random.RandomState(seed)
+    logits = jnp.asarray(rng.randn(rows, V).astype(np.float32) * 3.0)
+    counters = jnp.asarray(rng.randint(0, 500, rows), jnp.int32)
+    return logits, counters, jax.random.PRNGKey(seed + 17)
+
+
+def _scanned(fn):
+    """`fn` as the programs call it: inside a `lax.scan` over per-step keys,
+    the counters advancing, the sampling state fixed outside the loop."""
+    def run(logits, state, rng, counters):
+        def body(carry, step_rng):
+            logits, counters = carry
+            out = fn(logits, state, step_rng, counters)
+            # the next step's logits depend on what was sampled
+            logits = logits.at[jnp.arange(logits.shape[0]), out].add(-1.5)
+            return (logits, counters + 1), out
+        _, outs = jax.lax.scan(
+            body, (logits, counters), jax.random.split(rng, 4))
+        return outs
+    return run
+
+
+def _scanned_with_path(logits, state, rng, counters):
+    """As engine/compiled.py does: the path decided once, outside the scan."""
+    truncates = sampler_truncates(state)
+    return _scanned(
+        lambda lg, st, r, c: sample_tokens(lg, st, r, c, truncates)
+    )(logits, state, rng, counters)
+
+
+@pytest.mark.parametrize("how", ["jit", "scan", "scan_path_hoisted"])
+@pytest.mark.parametrize("with_counters", [True, False], ids=["counters", "no_counters"])
+@pytest.mark.parametrize("mix", MIX_NAMES)
+def test_tokens_are_the_frozen_body_s(mix, with_counters, how):
+    rows, _ = MIXES[mix]
+    state = SamplingState.from_params(rows)
+    logits, counters, rng = _inputs(len(rows), seed=MIX_NAMES.index(mix))
+    if how == "jit":
+        c = counters if with_counters else None
+        new = jax.jit(sample_tokens)(logits, state, rng, c)
+        old = jax.jit(frozen_sample_tokens)(logits, state, rng, c)
+    else:
+        if not with_counters:
+            counters = jnp.zeros_like(counters)
+        new_fn = _scanned_with_path if how == "scan_path_hoisted" else _scanned(sample_tokens)
+        new = jax.jit(new_fn)(logits, state, rng, counters)
+        old = jax.jit(_scanned(frozen_sample_tokens))(logits, state, rng, counters)
+    np.testing.assert_array_equal(np.asarray(new), np.asarray(old))
+    assert new.dtype == jnp.int32
+
+
+@pytest.mark.parametrize("mix", MIX_NAMES)
+def test_host_label_is_the_device_s_branch(mix):
+    """engine_sampler_dispatches_total's label, computed at plan time from
+    the params, names the branch the program takes for the same rows."""
+    rows, expected = MIXES[mix]
+    state, label = SamplingState.planned(rows)
+    on_device = int(jax.jit(sampler_truncates)(state))
+    assert SAMPLER_PATHS[on_device] == label == expected
+
+
+def test_host_label_compares_what_the_device_holds():
+    """A top_p that only float64 tells from 1 is 1.0 on the device: the
+    host's predicate reads float32 too."""
+    state, label = SamplingState.planned([P(top_p=1.0 - 1e-12)])
+    assert label == "plain" and not bool(sampler_truncates(state))
+    assert SamplingState.planned([P(temperature=-1.0, top_k=4)])[1] == "plain"
+
+
+def _count_sorts(jaxpr) -> int:
+    """`sort` primitives in a jaxpr, those of the jaxprs it calls included."""
+    n = 0
+    for eqn in jaxpr.eqns:
+        n += eqn.primitive.name == "sort"
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            n += _count_sorts(sub)
+    return n
+
+
+@pytest.mark.parametrize("path, sorts", [("plain", 0), ("truncate", 3)])
+def test_only_the_truncating_branch_sorts(path, sorts):
+    """The traced sampler holds one conditional of two branches, and every
+    sort lies in the truncating one."""
+    rows = MIXES["everything_at_once"][0]
+    logits, counters, rng = _inputs(len(rows))
+    jaxpr = jax.make_jaxpr(sample_tokens)(
+        logits, SamplingState.from_params(rows), rng, counters).jaxpr
+    conds = [e for e in jaxpr.eqns if e.primitive.name == "cond"]
+    assert len(conds) == 1 and len(conds[0].params["branches"]) == 2
+    assert _count_sorts(jaxpr) == 3  # all of them inside the conditional
+    branch = conds[0].params["branches"][SAMPLER_PATHS.index(path)]
+    assert _count_sorts(branch.jaxpr) == sorts
+
+
+def _engine(label, **overrides):
+    from kserve_tpu.engine.engine import EngineConfig, LLMEngine
+    from kserve_tpu.engine.tokenizer import ByteTokenizer
+    from kserve_tpu.models.llama import LlamaConfig
+
+    model_config = LlamaConfig.tiny(dtype="float32")
+    cfg = dict(
+        max_batch_size=4, page_size=8, num_pages=64, max_pages_per_seq=8,
+        max_prefill_len=32, prefill_buckets=(16, 32), dtype="float32",
+        use_pallas=False, steps_per_sync=4)
+    cfg.update(overrides)
+    return LLMEngine(model_config, EngineConfig(**cfg),
+                     ByteTokenizer(model_config.vocab_size),
+                     metrics_label=label)
+
+
+async def _drain(stream):
+    return [out.token_id async for out in stream]
+
+
+def _sampler_dispatches(label):
+    return {path: REGISTRY.get_sample_value(
+        "engine_sampler_dispatches_total",
+        {"model_name": label, "sampler_path": path}) or 0.0 for path in SAMPLER_PATHS}
+
+
+def _dispatches(label):
+    return sum(REGISTRY.get_sample_value(
+        "engine_dispatches_total", {"model_name": label, "program": program})
+        or 0.0 for program in ("mixed", "mixed_decode", "decode"))
+
+
+@pytest.fixture(scope="module")
+def planned():
+    """The rows each planner built its SamplingState from while three
+    requests (fewer than the four lanes) ran through the unified program,
+    the legacy programs and a detached prefill: planner -> list of rows."""
+    seen = {}
+    real = SamplingState.planned
+
+    def spy(params_list):
+        caller = next(f.function for f in inspect.stack()[1:]
+                      if f.function != "from_params")
+        seen.setdefault(caller, []).append(list(params_list))
+        return real(params_list)
+
+    request = P(max_tokens=9, temperature=0.8, top_p=0.9, seed=1, ignore_eos=True)
+    prompts = ([1, 2, 3], [4, 5, 6, 7, 8], list(range(9, 30)))
+
+    async def main():
+        for label, overrides in (("sampling-mixed", {}),
+                                 ("sampling-legacy", {"use_ragged": False})):
+            engine = _engine(label, **overrides)
+            await engine.start()
+            try:
+                await asyncio.gather(
+                    *[_drain(engine.generate(p, request)) for p in prompts])
+                await asyncio.gather(
+                    *[engine.prefill_detached(p, request) for p in prompts])
+            finally:
+                await engine.stop()
+
+    SamplingState.planned = staticmethod(spy)
+    try:
+        asyncio.run(main())
+    finally:
+        SamplingState.planned = staticmethod(real)
+    return request, seen
+
+
+@pytest.mark.parametrize("planner", [
+    "_prefill_detached_batch", "_admit_batch", "_prepare_chunk", "_plan_ragged"])
+def test_a_planner_s_empty_lanes_ask_for_no_sort(planned, planner):
+    """An unseated lane's token is discarded: what a planner seats there
+    must not put its batch on the truncating path."""
+    request, seen = planned
+    batches = seen[planner]
+    assert any(EMPTY_LANE in rows for rows in batches)
+    for rows in batches:
+        assert len(rows) == 4  # three requests, padded to the lanes
+        assert all(p is request or p == EMPTY_LANE for p in rows)
+    assert SamplingState.planned([EMPTY_LANE] * 4)[1] == "plain"
+
+
+@pytest.mark.parametrize("regime", ["mixed", "mixed_decode", "legacy"])
+@pytest.mark.parametrize("request_params, path", [
+    (P(temperature=0.0), "plain"),
+    (P(temperature=0.0, top_p=0.5), "plain"),
+    (P(temperature=0.7, seed=3), "plain"),
+    (P(temperature=0.7, top_p=0.9, seed=3), "truncate"),
+], ids=["greedy", "greedy_top_p", "temperature", "top_p"])
+def test_counter_takes_every_dispatch_on_the_batch_s_path(
+        regime, request_params, path):
+    """engine_sampler_dispatches_total beside engine_dispatches_total: one
+    request among empty lanes puts every dispatch on its own path."""
+    label = (f"sampling-{regime}-{request_params.temperature}"
+             f"-{request_params.top_p}")
+    overrides = {"mixed": {}, "mixed_decode": {"spec_decode_k": 2},
+                 "legacy": {"use_ragged": False}}[regime]
+    params = dataclasses.replace(
+        request_params, max_tokens=14, ignore_eos=True)
+
+    async def main():
+        engine = _engine(label, **overrides)
+        await engine.start()
+        try:
+            return await _drain(engine.generate([5, 6, 7, 8, 9], params))
+        finally:
+            await engine.stop()
+
+    assert len(asyncio.run(main())) == 14
+    counts = _sampler_dispatches(label)
+    assert counts[path] == _dispatches(label) > 0
+    assert sum(counts.values()) == counts[path]

@@ -276,60 +276,190 @@ class TestGateEqualsTheMeasuredTable:
 DECODE_SAT_WIDTHS = (8, 16, 32, 40)
 
 
+def _lower_mixed(mc, cfg, cache, width, monkeypatch):
+    """The `mixed` program of `mc` under `cfg`, lowered for TPU from
+    abstract arguments (`cache`: the program's K/V argument)."""
+    from kserve_tpu.engine.compiled import program_defs
+    from kserve_tpu.engine.sampling import SamplingState
+    from kserve_tpu.models import llama
+    from kserve_tpu.parallel import sharding as shd
+
+    # code that asks the backend takes its TPU branch, as on the chip
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    lanes, tokens = cfg.max_batch_size, cfg.prefill_buckets[-1]
+    fn, donate = program_defs(mc, cfg, shd.create_mesh())["mixed"]
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32)
+
+    return jax.jit(fn, donate_argnums=donate).trace(
+        jax.eval_shape(
+            lambda: llama.init_params(mc, jax.random.PRNGKey(1))),
+        i32(tokens), i32(tokens), i32(tokens),  # q_tokens, seq, pos
+        i32(lanes), i32(lanes), i32(lanes), i32(lanes),
+        cache, i32(lanes, width),
+        jax.ShapeDtypeStruct((lanes,), jnp.bool_),  # joins
+        i32(lanes), i32(lanes), i32(lanes), i32(lanes), i32(lanes),
+        jax.eval_shape(lambda: SamplingState.defaults(lanes)),
+        jax.ShapeDtypeStruct((2,), jnp.uint32), i32(lanes),
+    ).lower(lowering_platforms=("tpu",))
+
+
+def _decode_sat_mixed(width, monkeypatch):
+    """`mixed` at the decode-sat cell's shape (48 lanes, T = 512, Qwen3-4B's
+    32/8 x 128 heads, 16-token pages).  Two layers and a narrow MLP: the
+    attention shapes are the cell's, the rest only has to lower."""
+    import dataclasses
+
+    from kserve_tpu.engine.types import EngineConfig
+    from kserve_tpu.models import llama
+
+    lanes, tokens, ps = 48, 512, 16
+    mc = dataclasses.replace(
+        llama.LlamaConfig.qwen3_0_6b(), n_layers=2, n_heads=32,
+        hidden_size=256, intermediate_size=512, vocab_size=1024,
+        dtype="bfloat16")
+    cfg = EngineConfig(
+        max_batch_size=lanes, page_size=ps, num_pages=2300,
+        max_pages_per_seq=40, max_prefill_len=tokens,
+        prefill_buckets=(128, tokens), dtype="bfloat16")
+    cache = jax.ShapeDtypeStruct(
+        (cfg.num_pages, 2, mc.n_kv_heads, ps, mc.head_dim), jnp.bfloat16)
+    return _lower_mixed(mc, cfg, [cache] * mc.n_layers, width, monkeypatch)
+
+
 class TestMixedProgramTakesTheDecodeKernel:
     @pytest.mark.parametrize("width", DECODE_SAT_WIDTHS)
     def test_mixed_lowers_with_decode_kernel_and_no_gather(
             self, width, monkeypatch):
-        """The `mixed` program at the decode-sat cell's shape (48 lanes,
-        T = 512, Qwen3-4B's 32/8 x 128 heads, 16-token pages), lowered for
-        TPU: its scan tail calls the decode kernel and nothing gathers a
+        """Its scan tail calls the decode kernel and nothing gathers a
         [48, W, .., 16, 128] copy of the lanes' pages (such a gather in
         every layer of every step was 40 % of the cell's device time,
-        PERF.md section 6).  Two layers and a narrow MLP: the attention
-        shapes are the cell's, the rest only has to lower."""
-        import dataclasses
+        PERF.md section 6)."""
         import re
 
-        from kserve_tpu.engine.compiled import program_defs
-        from kserve_tpu.engine.sampling import SamplingState
-        from kserve_tpu.engine.types import EngineConfig
-        from kserve_tpu.models import llama
-        from kserve_tpu.parallel import sharding as shd
-
-        # code that asks the backend takes its TPU branch, as on the chip
-        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-        lanes, tokens, ps = 48, 512, 16
-        mc = dataclasses.replace(
-            llama.LlamaConfig.qwen3_0_6b(), n_layers=2, n_heads=32,
-            hidden_size=256, intermediate_size=512, vocab_size=1024,
-            dtype="bfloat16")
-        cfg = EngineConfig(
-            max_batch_size=lanes, page_size=ps, num_pages=2300,
-            max_pages_per_seq=40, max_prefill_len=tokens,
-            prefill_buckets=(128, tokens), dtype="bfloat16")
-        fn, donate = program_defs(mc, cfg, shd.create_mesh())["mixed"]
-
-        def i32(*shape):
-            return jax.ShapeDtypeStruct(shape, jnp.int32)
-
-        cache = jax.ShapeDtypeStruct(
-            (cfg.num_pages, 2, mc.n_kv_heads, ps, mc.head_dim), jnp.bfloat16)
-        text = jax.jit(fn, donate_argnums=donate).trace(
-            jax.eval_shape(
-                lambda: llama.init_params(mc, jax.random.PRNGKey(1))),
-            i32(tokens), i32(tokens), i32(tokens),  # q_tokens, seq, pos
-            i32(lanes), i32(lanes), i32(lanes), i32(lanes),
-            [cache] * mc.n_layers, i32(lanes, width),
-            jax.ShapeDtypeStruct((lanes,), jnp.bool_),  # joins
-            i32(lanes), i32(lanes), i32(lanes), i32(lanes), i32(lanes),
-            jax.eval_shape(lambda: SamplingState.defaults(lanes)),
-            jax.ShapeDtypeStruct((2,), jnp.uint32), i32(lanes),
-        ).lower(lowering_platforms=("tpu",)).as_text()
+        text = _decode_sat_mixed(width, monkeypatch).as_text()
         kernels = set(re.findall(r'kernel_name = "([a-z_]+)"', text))
         assert {"paged_attention_decode", "ragged_paged_attention"} <= kernels
         gathered = re.findall(
-            rf"tensor<{lanes}x{width}x[0-9x]*{ps}x128xbf16>", text)
+            rf"tensor<48x{width}x[0-9x]*16x128xbf16>", text)
         assert not gathered, sorted(set(gathered))
+
+
+def _sorts_met_without_a_branch(hlo: str):
+    """(sorts in all, computations that sort and are reached from the entry
+    without passing a conditional's branch), from HLO text."""
+    import re
+
+    bodies, entry, name = {}, None, None
+    for line in hlo.splitlines():
+        head = re.match(r"(ENTRY\s+)?%?([\w.\-]+)\s.*\{\s*$", line)
+        if head and not line.startswith(" "):
+            name = head.group(2)
+            bodies[name] = []
+            entry = name if head.group(1) else entry
+        elif name is not None:
+            bodies[name].append(line)
+    sorts = {n for n, body in bodies.items()
+             if any(re.search(r"\ssort\(", ln) for ln in body)}
+    reached, todo = set(), [entry]
+    while todo:
+        comp = todo.pop()
+        if comp in reached:
+            continue
+        reached.add(comp)
+        for ln in bodies[comp]:
+            # every callee but a conditional's branches
+            ln = re.sub(r"(branch_computations=\{[^}]*\}|"
+                        r"(true|false)_computation=%?[\w.\-]+)", "", ln)
+            todo += re.findall(
+                r"(?:to_apply|body|condition|calls)=%?([\w.\-]+)", ln)
+    n_sorts = sum(len(re.findall(r"\ssort\(", ln))
+                  for body in bodies.values() for ln in body)
+    return n_sorts, sorted(sorts & reached)
+
+
+class TestSamplerSortsOnlyInsideAConditional:
+    """engine/sampling.sample_tokens sorts in one branch of a conditional
+    on the batch's own sampling state: a batch in which no sampled row
+    truncates runs no sort (the sorts were 47-53 % of every cell's device
+    time, greedy cells included: PERF.md section 6)."""
+
+    def _hybrid_mixed(self, monkeypatch):
+        import dataclasses
+
+        from kserve_tpu.engine.types import EngineConfig
+        from kserve_tpu.models import llama
+        from test_hybrid_model import CFG
+
+        mc = dataclasses.replace(
+            llama.LlamaConfig.from_hf_config(CFG), dtype="float32")
+        cfg = EngineConfig(
+            max_batch_size=4, page_size=4, num_pages=64, max_pages_per_seq=16,
+            max_prefill_len=16, prefill_buckets=(16,), dtype="float32")
+        layout = kvcache.StateLayout.of(mc, 4, cfg.num_pages, 4, "float32")
+        return _lower_mixed(
+            mc, cfg, jax.eval_shape(layout.init_state), 16, monkeypatch)
+
+    @pytest.mark.parametrize("family", ["llama", "hybrid"])
+    def test_every_sort_of_mixed_lies_in_a_branch(self, family, monkeypatch):
+        lowered = (_decode_sat_mixed(40, monkeypatch) if family == "llama"
+                   else self._hybrid_mixed(monkeypatch))
+        hlo = lowered.compiler_ir(dialect="hlo").as_hlo_text()
+        n_sorts, unconditional = _sorts_met_without_a_branch(hlo)
+        assert n_sorts >= 1 and not unconditional
+        # step 0's sampler and the scan tail's: one conditional each
+        assert hlo.count(" conditional(") == 2
+
+    @pytest.mark.parametrize("outside", [True, False])
+    def test_the_check_sees_a_sort_outside_a_branch(self, outside):
+        def fn(x):
+            inside = jax.lax.cond(x[0] > 0, jnp.sort, lambda y: y, x)
+            return inside + jnp.sort(x) if outside else inside
+
+        hlo = jax.jit(fn).lower(jnp.zeros((8,))).compiler_ir(
+            dialect="hlo").as_hlo_text()
+        n_sorts, unconditional = _sorts_met_without_a_branch(hlo)
+        assert n_sorts >= 1 and bool(unconditional) == outside
+
+    def test_the_tpu_compiler_keeps_the_conditional(self):
+        """The sampler at Qwen3-4B's 48 x 151936 logits inside a scan, as
+        `mixed` calls it, through the XLA TPU compile: the optimised
+        program still holds ONE conditional of two branches and sorts in
+        none but the truncating one (a conditional flattened to both sides
+        and a select would run the sorts for every batch)."""
+        import re
+
+        from kserve_tpu.engine.sampling import (
+            SamplingState, sample_tokens, sampler_truncates)
+
+        if _tpu_sharding() is None:
+            pytest.skip("no compile-only TPU topology in this installation")
+        lanes, vocab = 48, 151936
+        state = jax.tree.map(
+            lambda a: _abstract(a.shape, a.dtype),
+            jax.eval_shape(lambda: SamplingState.defaults(lanes)))
+
+        def steps(logits, state, rng, counters):
+            truncates = sampler_truncates(state)
+
+            def body(carry, step_rng):
+                logits, counters = carry
+                out = sample_tokens(
+                    logits, state, step_rng, counters, truncates)
+                logits = logits.at[jnp.arange(lanes), out].add(-1.0)
+                return (logits, counters + 1), out
+
+            return jax.lax.scan(
+                body, (logits, counters), jax.random.split(rng, 8))[1]
+
+        hlo = jax.jit(steps).lower(
+            _abstract((lanes, vocab), jnp.float32), state,
+            _abstract((2,), jnp.uint32), _i32(lanes)).compile().as_text()
+        n_sorts, unconditional = _sorts_met_without_a_branch(hlo)
+        assert n_sorts >= 1 and not unconditional
+        branches = re.findall(r"branch_computations=\{([^}]*)\}", hlo)
+        assert len(branches) == 1 and branches[0].count(",") == 1
 
 
 class TestHybridDecodeKernelCalls:
