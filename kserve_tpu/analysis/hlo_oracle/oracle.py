@@ -49,7 +49,7 @@ def program_keys(variant: str, name: str, ps) -> List[Tuple[str, Optional[int]]]
     is keyed by its K."""
     if name in _BUCKETED:
         return [(f"{variant}/{name}/b{b}", b)
-                for b in ps.cfg.prefill_buckets]
+                for b in ps.shapes.token_buckets]
     if name == "mixed_decode":
         return [(f"{variant}/{name}/k{ps.spec_k or 0}", None)]
     return [(f"{variant}/{name}", None)]
@@ -107,7 +107,8 @@ def _variant_programs(variant: str, ps_kwargs: dict, names, only):
         pass
 
     shim = _KeyShim()
-    shim.cfg = cfg
+    shim.shapes = signatures.DispatchShapes.of(
+        signatures.tiny_model_config(), cfg, jax.default_backend())
     shim.spec_k = spec_k
     if names is None:
         names = _default_program_names(cfg, spec_k)

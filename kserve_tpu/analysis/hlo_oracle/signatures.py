@@ -23,6 +23,7 @@ import jax.numpy as jnp
 from ...engine.compiled import program_defs
 from ...engine.kvcache import KVCacheConfig, init_kv_pages, init_kv_scales
 from ...engine.sampling import SamplingState
+from ...engine.shapes import DispatchShapes
 from ...engine.types import EngineConfig
 from ...models import llama
 from ...parallel import sharding as shd
@@ -31,8 +32,8 @@ from ...parallel import sharding as shd
 #: batch to a power of two; 4 is the tiny config's max_batch_size)
 PREFILL_ROWS = 4
 
-#: pages per inject dispatch before page_bucket padding (a mid-size
-#: P/D / tier-store payload)
+#: pages per inject dispatch before padding to a width bucket (a
+#: mid-size P/D / tier-store payload)
 INJECT_PAGES = 4
 
 
@@ -68,6 +69,12 @@ class ProgramSet:
     kv_pages: list
     defs: dict  # name -> (python fn, donate_argnums)
     spec_k: Optional[int] = None
+
+    @property
+    def shapes(self) -> DispatchShapes:
+        """The dispatch shapes the engine would plan with under this
+        config: what the canonical arguments below are sized from."""
+        return DispatchShapes.of(self.mc, self.cfg, jax.default_backend())
 
 
 def build_program_set(tp: int = 1, spec_k: Optional[int] = None,
@@ -120,8 +127,10 @@ def args_for(ps: ProgramSet, name: str,
     B = cfg.max_batch_size
     V = mc.vocab_size
     Bp = PREFILL_ROWS
-    bucket = bucket or cfg.prefill_buckets[-1]
-    width = cfg.page_bucket(cfg.max_pages_per_seq)
+    shapes = ps.shapes
+    # the largest pair a mixed dispatch takes (_plan_ragged)
+    T, width = shapes.pairs()[-1]
+    bucket = bucket or shapes.token_budget
     rng = jax.random.PRNGKey(0)
     steps = cfg.steps_per_sync
 
@@ -178,7 +187,7 @@ def args_for(ps: ProgramSet, name: str,
             args = args + (jnp.zeros((B, V), bool), i32(B, V))
         return args, {"batch": B, "tokens": B * steps, "steps": steps}
     if name == "inject":
-        nb = cfg.page_bucket(INJECT_PAGES)
+        nb = shapes.width(INJECT_PAGES)
         args = (
             ps.kv_pages,
             jnp.zeros(_kv_payload_shapes(ps, nb), jnp.dtype(cfg.dtype)),
@@ -186,7 +195,7 @@ def args_for(ps: ProgramSet, name: str,
         )
         return args, {"pages": nb, "steps": 1}
     if name == "inject_q":
-        nb = cfg.page_bucket(INJECT_PAGES)
+        nb = shapes.width(INJECT_PAGES)
         args = (
             ps.kv_pages,
             jnp.zeros(_kv_payload_shapes(ps, nb), jnp.int8),
@@ -195,9 +204,6 @@ def args_for(ps: ProgramSet, name: str,
         )
         return args, {"pages": nb, "steps": 1}
     if name == "mixed":
-        # _plan_ragged: packed buffer sized to the largest prefill
-        # bucket (align=1 on the XLA reference path)
-        T = cfg.prefill_buckets[-1]
         args = (
             ps.params,
             i32(T),              # q_tokens

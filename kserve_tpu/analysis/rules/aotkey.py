@@ -13,7 +13,9 @@ the worst kind of heisenbug.  This rule pins the two in lockstep: every
 ``<engine-config>.field`` read (attribute or ``getattr``) inside a
 compiled-program builder — a function named ``build_compiled`` or
 ``program_defs`` (the extracted definition table both dispatch modes and
-the hlo_oracle build from) — must appear in ``AOT_KEY_ENGINE_FIELDS``.
+the hlo_oracle build from), or ``DispatchShapes.of`` (engine/shapes.py:
+the slice alignment the programs are traced with is derived there) — must
+appear in ``AOT_KEY_ENGINE_FIELDS``.
 
 The allowlist is resolved from the linted source itself when it defines
 ``AOT_KEY_ENGINE_FIELDS`` (test fixtures), else from the sibling
@@ -38,6 +40,11 @@ _CONFIG_PARAM_NAMES = {"engine_config", "cfg"}
 #: is the extracted definition table (engine/compiled.py) — moving reads
 #: there must NOT escape the audit.
 _BUILDER_NAMES = {"build_compiled", "program_defs"}
+
+#: (class, method) pairs audited the same way: program_defs hands the
+#: config whole to DispatchShapes.of, whose reads would otherwise leave
+#: the audit's sight
+_BUILDER_METHODS = {("DispatchShapes", "of")}
 
 _LIST_NAME = "AOT_KEY_ENGINE_FIELDS"
 
@@ -105,6 +112,12 @@ class AOTCacheKeyDrift(Rule):
             node for node in ast.walk(ctx.tree)
             if isinstance(node, ast.FunctionDef)
             and node.name in _BUILDER_NAMES
+        ] + [
+            fn for cls in ast.walk(ctx.tree)
+            if isinstance(cls, ast.ClassDef)
+            for fn in cls.body
+            if isinstance(fn, ast.FunctionDef)
+            and (cls.name, fn.name) in _BUILDER_METHODS
         ]
         if not builders:
             return

@@ -30,6 +30,7 @@ from .sampling import (
     sample_tokens,
     sampler_truncates,
 )
+from .shapes import DispatchShapes
 
 _log = logging.getLogger(__name__)
 
@@ -482,15 +483,10 @@ def program_defs(model_config, engine_config, mesh, spec_k=None) -> dict:
             )
         return logits, _kv_pin(kv_pages)
 
-    # the alignment the engine packs slices at (engine._ragged_align): a
-    # block of that many tokens holds one lane, which a hybrid model's
-    # packed scan and window attention build on
-    from ..ops.attention import _should_use_ragged_pallas
-    from ..ops.pallas_paged_attention import RAGGED_BQ
-
-    ragged_block = RAGGED_BQ if cfg.use_pallas or (
-        cfg.use_pallas is None and _should_use_ragged_pallas(
-            mc.cache_head_dim, jax.default_backend(), _quantized)) else 1
+    # the alignment the engine packs slices at: a block of that many
+    # tokens holds one lane, which a hybrid model's packed scan and window
+    # attention build on
+    ragged_block = DispatchShapes.of(mc, cfg, jax.default_backend()).align
 
     def _make_mixed():
         """THE unified ragged program (docs/kernels.md): one dispatch
@@ -589,10 +585,9 @@ def program_defs(model_config, engine_config, mesh, spec_k=None) -> dict:
         from ..ops.attention import dense_stride_for
 
         Kp = k_drafts + 1
-        kernel_possible = ragged_block > 1
-        align = ragged_block
-        sp = dense_stride_for(Kp, align)  # padded slice stride
-        dense_stride = sp if (kernel_possible and sp < RAGGED_BQ) else None
+        sp = dense_stride_for(Kp, ragged_block)  # padded slice stride
+        # lanes share the kernel's blocks only below its alignment
+        dense_stride = sp if sp < ragged_block else None
         dense_attention_fn = None
         if cfg.tp > 1 or cfg.sp > 1:
             from ..ops.attention import make_sharded_ragged_attention

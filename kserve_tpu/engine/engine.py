@@ -98,6 +98,7 @@ from .kvcache import (
     pages_needed,
 )
 from .sampling import SAMPLER_PATHS, SamplingParams, SamplingState
+from .shapes import DispatchShapes
 from .tokenizer import BaseTokenizer, IncrementalDetokenizer
 
 
@@ -275,13 +276,10 @@ class LLMEngine:
             raise NotImplementedError(
                 "sp>1 (ring-attention prefill) does not support sliding "
                 "windows or attention-scale overrides yet")
-        if engine_config.sp > 1:
-            bad = [b for b in engine_config.prefill_buckets if b % engine_config.sp]
-            if bad:
-                raise ValueError(
-                    f"prefill buckets {bad} not divisible by sp={engine_config.sp} "
-                    "(ring-attention prefill shards the prompt dim over seq)"
-                )
+        # which (T, W) program a dispatch runs in: engine/shapes.py decides,
+        # every planner below asks it
+        self._shapes = DispatchShapes.of(
+            model_config, engine_config, jax.default_backend())
         if engine_config.pp > 1:
             # supported composition today: pp x tp (x dp via disjoint
             # replica meshes).  Everything else raises loudly here rather
@@ -600,29 +598,13 @@ class LLMEngine:
         # preemption choice (and therefore its whole report) would hinge on
         # a tie-break
         self._admission_seq = 0.0
-        # packed-slice alignment: the Pallas ragged kernel walks BQ-token
-        # blocks that each belong to ONE sequence, so slices must start at
-        # BQ multiples wherever the kernel can be selected; the XLA
-        # reference packs densely
-        from ..ops.attention import _should_use_ragged_pallas
-        from ..ops.pallas_paged_attention import RAGGED_BQ
-
-        kernel_possible = engine_config.use_pallas or (
-            engine_config.use_pallas is None
-            and _should_use_ragged_pallas(
-                model_config.cache_head_dim, jax.default_backend(),
-                engine_config.kv_quant == "int8")
-        )
-        self._ragged_align = RAGGED_BQ if kernel_possible else 1
         # unified ragged program (docs/kernels.md): resolve the use_ragged
-        # knob against what the topology supports.  A pure-decode mixed
-        # step packs max_batch_size aligned single-token slices, so the
-        # largest prefill bucket must cover the batch.
+        # knob against what the topology supports
+        align = self._shapes.align
         mixed_ok = (
             engine_config.pp == 1
             and engine_config.sp == 1
-            and engine_config.max_batch_size * self._ragged_align
-            <= engine_config.prefill_buckets[-1]
+            and self._shapes.fits_pure_decode
         )
         if engine_config.use_ragged and not mixed_ok:
             raise NotImplementedError(
@@ -650,15 +632,13 @@ class LLMEngine:
                     "pp>1 or sp>1")
             from ..ops.attention import dense_stride_for
 
-            stride = dense_stride_for(spec_k + 1, self._ragged_align)
-            if (self._ragged_align > 1
-                    and (engine_config.max_batch_size * stride)
-                    % self._ragged_align):
+            stride = dense_stride_for(spec_k + 1, align)
+            if align > 1 and (engine_config.max_batch_size * stride) % align:
                 raise ValueError(
                     "spec_decode_k on the Pallas kernel path needs "
                     "max_batch_size * padded-slice stride "
                     f"({engine_config.max_batch_size}*{stride}) to be a "
-                    f"multiple of the {self._ragged_align}-token block")
+                    f"multiple of the {align}-token block")
             # the [B, V] draft table shards lane rows over the model axis
             # (sharding.draft_table_pspec) — an indivisible batch would
             # only surface as a JAX sharding error at the first dense
@@ -674,7 +654,7 @@ class LLMEngine:
         # worst-case per-lane advance of one dispatch: every round accepts
         # all K drafts plus the bonus token.  Page growth and the
         # predictable-finish chain gate both plan against it.
-        self._max_step_advance = engine_config.steps_per_sync * (
+        self._max_step_advance = self._shapes.steps * (
             (spec_k or 0) + 1 if spec_k is not None else 1)
         # hard per-lane kv ceiling: a dense round needs a full (K+1)-token
         # write window, so a lane within K tokens of this cap can NEVER
@@ -722,7 +702,7 @@ class LLMEngine:
             raise NotImplementedError(
                 "a hybrid model runs the mixed program only (the legacy "
                 "programs assume one kind of layer): max_batch_size x the "
-                f"{self._ragged_align}-token slice alignment must fit the "
+                f"{align}-token slice alignment must fit the "
                 "largest prefill bucket")
         # what this replica was BUILT with — dispatch regime and, per
         # program family, the attention implementation — logged at start
@@ -734,6 +714,7 @@ class LLMEngine:
             "regime": "mixed" if self._use_mixed else "legacy",
             "attention": describe_attention_dispatch(
                 model_config, engine_config, jax.default_backend()),
+            "shapes": self._shapes.published(),
         }
         self._set_state_gauges()
 
@@ -849,10 +830,10 @@ class LLMEngine:
         hand-building abstract signatures means warmup can never drift
         from what the scheduler actually dispatches."""
         params = SamplingParams(
-            max_tokens=min(4, max(1, self.config.steps_per_sync)),
+            max_tokens=min(4, max(1, self._shapes.steps)),
             temperature=0.0, ignore_eos=True,
         )
-        for bucket in self.config.prefill_buckets:
+        for bucket in self._shapes.token_buckets:
             n = min(bucket, self.config.max_model_len - params.max_tokens)
             if n <= 0:
                 continue
@@ -1296,7 +1277,7 @@ class LLMEngine:
             pages = self.allocator.allocate(len(entries))
             try:
                 n = len(entries)
-                bucket = self.config.page_bucket(n)
+                bucket = self._shapes.width(n)
                 ids = np.zeros((bucket,), np.int32)
                 ids[:n] = pages
 
@@ -1764,7 +1745,7 @@ class LLMEngine:
             )
         if not runnable:
             return
-        bucket = self._bucket_for(max(len(r[0]) for r in runnable))
+        bucket = self._shapes.bucket(max(len(r[0]) for r in runnable))
         Bp = 1
         while Bp < len(runnable):
             Bp *= 2
@@ -2182,12 +2163,6 @@ class LLMEngine:
                 return i
         return None
 
-    def _bucket_for(self, n: int) -> int:
-        for b in self.config.prefill_buckets:
-            if n <= b:
-                return b
-        return self.config.prefill_buckets[-1]
-
     def _admit_batch(self) -> bool:
         """Prefill up to `prefill_batch` waiting requests in ONE compiled
         call (padded to the widest TAIL bucket among them); False when no
@@ -2199,7 +2174,7 @@ class LLMEngine:
         ring-attention prefill instead (no cache; whole prompt per row)."""
         use_fused = self.config.sp > 1
         ps = self.config.page_size
-        chunk_cap = self.config.prefill_buckets[-1]
+        chunk_cap = self._shapes.token_budget
         admitted: List[tuple] = []  # (slot_index, request, pages, n_cached, seq)
         # aliased (not assigned after the loop) so the run-loop crash
         # handler sees every popped-but-unseated request even when a later
@@ -2261,7 +2236,7 @@ class LLMEngine:
         if not admitted:
             return False
 
-        bucket = self._bucket_for(
+        bucket = self._shapes.bucket(
             max(len(seq) - c * ps for _, _, _, c, seq in admitted)
         )
         # pad the batch dim to pow2 so the compile cache stays small
@@ -2276,7 +2251,7 @@ class LLMEngine:
         valid = np.zeros((Bp,), np.int32)
         width = (
             self.config.max_pages_per_seq if use_fused_call
-            else self.config.page_bucket(
+            else self._shapes.width(
                 max(len(pages) for _, _, pages, _, _ in admitted)
             )
         )
@@ -2520,7 +2495,7 @@ class LLMEngine:
         # must not demand more pages than the legacy batched path did
         headroom = (
             total - len(cached) * self.config.page_size
-            > self.config.prefill_buckets[-1]
+            > self._shapes.token_budget
         )
         if not self._prefix_cache.ensure_allocatable(
             self._admission_pages(req, fresh_needed, headroom=headroom)
@@ -2562,7 +2537,7 @@ class LLMEngine:
         """One chunk of progress for every prefilling slot; completes slots
         whose prompt is fully prefilled (sampling the first token)."""
         progressed = False
-        chunk_cap = self.config.prefill_buckets[-1]
+        chunk_cap = self._shapes.token_budget
         for idx, slot in enumerate(self._slots):
             pf = slot.prefilling
             if slot.request_id is None or pf is None:
@@ -2571,14 +2546,14 @@ class LLMEngine:
             total = len(seq)
             if done < total:
                 n = min(chunk_cap, total - done)
-                bucket = self._bucket_for(n)
+                bucket = self._shapes.bucket(n)
                 tokens = np.zeros((1, bucket), np.int32)
                 tokens[0, :n] = seq[done : done + n]
                 page_ids = np.zeros((self.config.max_pages_per_seq,), np.int32)
                 page_ids[: len(slot.pages)] = slot.pages
                 # table width must cover this chunk's writes (the history
                 # gather reads the same table, masked by history length)
-                width = self.config.page_bucket(
+                width = self._shapes.width(
                     pages_needed(done + n, self.config.page_size)
                 )
                 chunk_t0 = self._clock.now()
@@ -2676,7 +2651,7 @@ class LLMEngine:
         out (KV ping-pong for resumes, aborted prefills for long prompts)."""
         if req.resume is None and not headroom:
             return need
-        extra = pages_needed(2 * self.config.steps_per_sync, self.config.page_size)
+        extra = pages_needed(2 * self._shapes.steps, self.config.page_size)
         return min(need + extra, self.config.num_pages - 1)
 
     def _seat_resumed(self, slot: _Slot, req: "_QueuedRequest", pages: List[int]) -> None:
@@ -2745,7 +2720,7 @@ class LLMEngine:
         self._admitting.append(entry)
         P = kv.shape[1]
         # pad the page dim to the standard width buckets (small compile cache)
-        bucket = self.config.page_bucket(P)
+        bucket = self._shapes.width(P)
         ids = np.zeros((bucket,), np.int32)
         ids[:P] = pages[:P]
 
@@ -2972,12 +2947,27 @@ class LLMEngine:
             self.allocator.free(self._deferred_free)
             self._deferred_free = []
 
+    def _page_table_of(self, lanes) -> np.ndarray:
+        """The [B, W] page table of one dispatch: W from the most pages a
+        seated lane of `lanes` ([B] bool) owns, those lanes' rows filled
+        from their slots, the rest zero (the null page).  WHICH lanes
+        count is the caller's: the mixed step counts every seated lane,
+        the decode chunk and the chained dense dispatch only the lanes
+        they run."""
+        rows = [(i, slot.pages) for i, slot in enumerate(self._slots)
+                if lanes[i] and slot.request_id is not None]
+        width = self._shapes.width(max([len(p) for _, p in rows] or [1]))
+        page_table = np.zeros((self.config.max_batch_size, width), np.int32)
+        for i, pages in rows:
+            page_table[i, : len(pages)] = pages
+        return page_table
+
     def _prepare_chunk(self, prev: Optional[dict]) -> Optional[dict]:
         """Build host-side inputs for a decode chunk.  `prev` chains the
         chunk after an in-flight one: positions advance speculatively by
         min(steps, prev capacity) without reading prev's tokens."""
         B = self.config.max_batch_size
-        steps = self.config.steps_per_sync
+        steps = self._shapes.steps
         if prev is None:
             # page growth + preemption happen only between pipelines (the KV
             # extraction in _preempt needs no chunk in flight)
@@ -2986,8 +2976,9 @@ class LLMEngine:
         pos = np.zeros((B,), np.int32)
         active = np.zeros((B,), bool)
         capacity = np.zeros((B,), np.int32)
+        counters = np.zeros((B,), np.int32)
+        adapters = np.full((B,), -1, np.int32)
         params_list = [SamplingParams() for _ in range(B)]
-        max_owned = 1
         for i, slot in enumerate(self._slots):
             if slot.request_id is None or slot.prefilling is not None:
                 continue
@@ -3013,23 +3004,15 @@ class LLMEngine:
             pos[i] = base
             active[i] = True
             capacity[i] = len(slot.pages) * self.config.page_size
+            # tokens generated when this chunk starts (for seeded lanes)
+            counters[i] = base - slot.prompt_len + 1
+            adapters[i] = slot.adapter_id
             params_list[i] = slot.params
-            max_owned = max(max_owned, len(slot.pages))
         if not active.any():
             return None
-        # bucketed page-table width: attention gathers only ~longest-seq pages
-        width = self.config.page_bucket(max_owned)
-        page_table = np.zeros((B, width), np.int32)
-        for i, slot in enumerate(self._slots):
-            if slot.request_id is not None and active[i]:
-                page_table[i, : len(slot.pages)] = slot.pages
-        counters = np.zeros((B,), np.int32)
-        adapters = np.full((B,), -1, np.int32)
-        for i, slot in enumerate(self._slots):
-            if slot.request_id is not None and active[i]:
-                # tokens generated when this chunk starts (for seeded lanes)
-                counters[i] = int(pos[i]) - slot.prompt_len + 1
-                adapters[i] = slot.adapter_id
+        # as wide as the longest ACTIVE lane: a seated lane that sits this
+        # chunk out is not read
+        page_table = self._page_table_of(active)
         # penalized chunks use device-resident [B, V] count/prompt arrays,
         # rebuilt from the host-side slot lists only when batch composition
         # changed; such chunks are never pipeline-chained so the counts are
@@ -3222,7 +3205,7 @@ class LLMEngine:
         state is only mutated in the sync stretch after the fetches, so a
         drain evicting a slot during the await is observed (request_id
         None) rather than raced."""
-        steps = self.config.steps_per_sync
+        steps = self._shapes.steps
         self._phases.mark("wait")
         if isinstance(chunk, tuple):  # logprobs variant: (tokens, lp, tv, ti)
             chunk_np = await self._fetch_async(chunk[0])  # [steps, B]
@@ -3289,7 +3272,7 @@ class LLMEngine:
             predictable_finish = any(
                 s.request_id is not None
                 and meta["active"][i]
-                and len(s.generated) + self.config.steps_per_sync
+                and len(s.generated) + self._shapes.steps
                 >= s.params.max_tokens
                 for i, s in enumerate(self._slots)
             )
@@ -3432,18 +3415,15 @@ class LLMEngine:
     def _plan_ragged(self, meta: Optional[dict], prefilling) -> dict:
         """Pack this step's ragged token buffer (host side, numpy): decode
         lanes first (one token each), then each prefilling slot's next
-        chunk, within one largest-prefill-bucket token budget.  Slices
-        start at self._ragged_align multiples (the Pallas kernel's
+        chunk, within one largest-token-bucket budget.  Slices start at
+        multiples of the shapes' alignment (the Pallas kernel's
         one-sequence-per-block invariant; 1 on the XLA reference path).
         Returns the packed arrays plus per-lane routing windows."""
         B = self.config.max_batch_size
         ps = self.config.page_size
-        steps = self.config.steps_per_sync
-        align = self._ragged_align
-        budget = self.config.prefill_buckets[-1]
-
-        def aligned(n: int) -> int:
-            return -(-n // align) * align
+        steps = self._shapes.steps
+        aligned = self._shapes.aligned
+        budget = self._shapes.token_budget
 
         q_start = np.zeros((B,), np.int32)
         q_len = np.zeros((B,), np.int32)
@@ -3536,19 +3516,12 @@ class LLMEngine:
             chunks.append((i, n, final))
             n_prefill_tokens += n
 
-        T = -(-self._bucket_for(max(offset, 1)) // align) * align
-        pad = T - offset
+        pad = self._shapes.tokens(max(offset, 1)) - offset
         tok_list.extend([0] * pad)
         seq_list.extend([-1] * pad)
         pos_list.extend([0] * pad)
-        width = self.config.page_bucket(max(
-            [len(s.pages) for s in self._slots if s.request_id is not None]
-            or [1]
-        ))
-        page_table = np.zeros((B, width), np.int32)
-        for i, slot in enumerate(self._slots):
-            if slot.request_id is not None and slot.pages:
-                page_table[i, : len(slot.pages)] = slot.pages
+        # as wide as the longest SEATED lane, in this dispatch or not
+        page_table = self._page_table_of(np.ones((B,), bool))
         state, sampler_path = SamplingState.planned(params_list)
         return {
             "q_tokens": np.asarray(tok_list, np.int32),
@@ -3672,7 +3645,6 @@ class LLMEngine:
         kp = (self._spec_k or 0) + 1
         live = prev["live"]
         capacity = np.zeros((B,), np.int32)
-        max_owned = 1
         any_live = False
         for i, slot in enumerate(self._slots):
             if slot.request_id is None or not live[i]:
@@ -3693,15 +3665,10 @@ class LLMEngine:
             if grow > 0:
                 self._ensure_pages_at(slot, slot.pos, grow)
             capacity[i] = len(slot.pages) * self.config.page_size
-            max_owned = max(max_owned, len(slot.pages))
             any_live = True
         if not any_live:
             return None
-        width = self.config.page_bucket(max_owned)
-        page_table = np.zeros((B, width), np.int32)
-        for i, slot in enumerate(self._slots):
-            if slot.request_id is not None and live[i]:
-                page_table[i, : len(slot.pages)] = slot.pages
+        page_table = self._page_table_of(live)
         return {
             "tokens": prev["tokens"],  # unused (device carry chains)
             "pos": prev["pos"],

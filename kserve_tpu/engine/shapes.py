@@ -1,0 +1,132 @@
+"""The shape of a dispatch: which (T, W) program a step runs in.
+
+The engine compiles one `mixed` program per pair (T, the length of the
+packed token buffer; W, the width of the page table).  Which pair a step
+takes is a policy — the token buckets, the width ladder, the alignment
+slices are packed at, the steps a dispatch runs — and this module is the
+one place that knows it: the engine's planners, the program table
+(`compiled.program_defs`), AOT warm-up and the HLO oracle's canonical
+signatures all ask a `DispatchShapes`, and `/v1/internal/scheduler/state`
+publishes it (`dispatch.shapes`), so a client that warms shapes before a
+measurement reads the policy instead of copying it.
+
+A change of the policy (coarser buckets, rounding to loaded pairs, a step
+count from queue depth) is a change to this file.
+"""
+
+from __future__ import annotations
+
+from dataclasses import dataclass
+from typing import Dict, List, Tuple
+
+#: the first rung of the width ladder, in pages
+MIN_WIDTH = 8
+
+
+def width_ladder(max_pages: int) -> Tuple[int, ...]:
+    """Page-table widths: doubling from MIN_WIDTH, ending at the most
+    pages a sequence may own — attention gathers only about as many pages
+    as the longest lane of a dispatch holds."""
+    rungs = []
+    b = MIN_WIDTH
+    while b < max_pages:
+        rungs.append(b)
+        b *= 2
+    return tuple(rungs) + (max_pages,)
+
+
+def rung(ladder: Tuple[int, ...], n: int) -> int:
+    """The first rung that holds n; the last for anything larger."""
+    for b in ladder:
+        if n <= b:
+            return b
+    return ladder[-1]
+
+
+@dataclass(frozen=True)
+class DispatchShapes:
+    #: tokens a slice of the packed buffer is aligned to: the Pallas
+    #: ragged kernel walks blocks of RAGGED_BQ tokens that each belong to
+    #: ONE sequence, so wherever it can be selected slices start at its
+    #: multiples; the XLA reference packs densely (1)
+    align: int
+    token_buckets: Tuple[int, ...]
+    width_buckets: Tuple[int, ...]
+    #: decode steps one dispatch runs per lane
+    steps: int
+    lanes: int
+
+    @classmethod
+    def of(cls, model_config, engine_config, backend: str) -> "DispatchShapes":
+        """The policy under one resolved configuration.  `backend` is an
+        argument because the programs are also built with no engine
+        (`compiled.program_defs`, the HLO oracle)."""
+        from ..ops.attention import _should_use_ragged_pallas
+        from ..ops.pallas_paged_attention import RAGGED_BQ
+
+        buckets = tuple(engine_config.prefill_buckets)
+        if engine_config.sp > 1:
+            bad = [b for b in buckets if b % engine_config.sp]
+            if bad:
+                raise ValueError(
+                    f"prefill buckets {bad} not divisible by sp={engine_config.sp} "
+                    "(ring-attention prefill shards the prompt dim over seq)"
+                )
+        kernel_possible = engine_config.use_pallas or (
+            engine_config.use_pallas is None
+            and _should_use_ragged_pallas(
+                model_config.cache_head_dim, backend,
+                engine_config.kv_quant == "int8")
+        )
+        return cls(
+            align=RAGGED_BQ if kernel_possible else 1,
+            token_buckets=buckets,
+            width_buckets=width_ladder(engine_config.max_pages_per_seq),
+            steps=engine_config.steps_per_sync,
+            lanes=engine_config.max_batch_size,
+        )
+
+    @property
+    def token_budget(self) -> int:
+        """The most tokens one dispatch packs: the cap of a prompt chunk
+        and the budget a mixed step shares between its lanes."""
+        return self.token_buckets[-1]
+
+    @property
+    def fits_pure_decode(self) -> bool:
+        """A pure-decode mixed step packs one aligned single-token slice
+        per lane, so the largest bucket must cover the batch."""
+        return self.lanes * self.align <= self.token_budget
+
+    def aligned(self, n: int) -> int:
+        return -(-n // self.align) * self.align
+
+    def bucket(self, n: int) -> int:
+        """The token bucket of n tokens: the row length the legacy prefill
+        programs pad to."""
+        return rung(self.token_buckets, n)
+
+    def tokens(self, n: int) -> int:
+        """T of a mixed dispatch whose packed slices end at offset n: their
+        bucket, in whole slices."""
+        return self.aligned(self.bucket(n))
+
+    def width(self, pages: int) -> int:
+        """W of a dispatch whose longest lane owns `pages` pages."""
+        return rung(self.width_buckets, pages)
+
+    def pairs(self) -> List[Tuple[int, int]]:
+        """Every (T, W) a mixed dispatch can take, smallest first."""
+        ts = sorted({self.tokens(b) for b in self.token_buckets})
+        return [(t, w) for t in ts for w in self.width_buckets]
+
+    def published(self) -> Dict[str, object]:
+        """The policy as served under `dispatch.shapes`, in the key names
+        of the benchmark's own copy (`deployment.engine_policy`)."""
+        return {
+            "lane_tokens": self.align,
+            "tokens_per_dispatch": self.steps,
+            "token_buckets": list(self.token_buckets),
+            "width_buckets": list(self.width_buckets),
+            "min_width": self.width_buckets[0],
+        }

@@ -12,6 +12,8 @@ import asyncio
 from dataclasses import dataclass
 from typing import List, Optional, Tuple
 
+from .shapes import rung, width_ladder
+
 
 class _LoopNotify:
     """threading.Event-shaped completion signal for fetch_async: the worker
@@ -51,7 +53,7 @@ class EngineConfig:
     # over it; decode state is replicated across it)
     sp: int = 1
     dtype: str = "bfloat16"
-    # tiered KV offload (kv_tiers.py; parity: KVCacheOffloadingSpec,
+    # tiered KV offload (kvstore/tiers.py; parity: KVCacheOffloadingSpec,
     # llm_inference_service_types.go:188-260): "none" re-prefills preempted
     # sequences on resume; "host" spills their KV pages to a host-RAM tier
     # (within kv_offload_gib) fronted over an optional disk tier
@@ -200,12 +202,9 @@ class EngineConfig:
         return self.max_pages_per_seq * self.page_size
 
     def page_bucket(self, n_pages: int) -> int:
-        """Page-table width bucket (pow2) so decode attention only gathers
-        as many pages as the longest active sequence actually owns."""
-        b = 8
-        while b < n_pages:
-            b *= 2
-        return min(b, self.max_pages_per_seq)
+        """Page-table width bucket: DispatchShapes.width (engine/shapes.py)
+        for callers that hold only the config."""
+        return rung(width_ladder(self.max_pages_per_seq), n_pages)
 
 
 def spec_decode_k_from_env() -> Optional[int]:

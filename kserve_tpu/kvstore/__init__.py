@@ -3,7 +3,7 @@
 One store unifies the two host-side KV paths that used to live apart:
 
 - the **spill path** (preempted sequences park their device KV in host
-  RAM / disk and re-inject on resume — previously engine/kv_tiers.py),
+  RAM / disk and re-inject on resume — formerly an engine module),
 - the **prefix path** (evicted prefix-cache pages demote into the same
   tiers instead of being dropped, keyed by the blake2b digest chains of
   scheduler/prefix.py, plus a content-addressed persistent layer whose

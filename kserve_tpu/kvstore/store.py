@@ -6,8 +6,7 @@ whenever host/disk offload OR the persistent prefix layer is
 configured).  Two key namespaces share the host/disk tiers:
 
 - **spill entries** (request-id keys, consume-on-get): a preempted
-  sequence's whole KV, re-injected on resume — the engine/kv_tiers.py
-  contract, unchanged;
+  sequence's whole KV, re-injected on resume (kvstore/tiers.py);
 - **prefix entries** (``px-<digest hex>`` keys, non-consuming): single
   prefix-cache pages demoted out of HBM instead of dropped, readable
   any number of times (the same page can be paged back in after every

@@ -1,6 +1,6 @@
 """Host-RAM / disk KV tiers with lru / arc eviction between them.
 
-This is the spill engine that used to live at engine/kv_tiers.py, folded
+This is the spill engine, folded
 into the hierarchical store (docs/kv_hierarchy.md) and made
 **clock-injectable**: every entry stamp comes from a resilience.Clock so
 spill traffic inside the fleet simulator stays a pure function of

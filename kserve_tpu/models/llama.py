@@ -275,24 +275,6 @@ class LlamaConfig:
         )
 
     @staticmethod
-    def bench_1b() -> "LlamaConfig":
-        """1B-class flagship with MXU-native head_dim=128 (the Pallas paged
-        attention kernel requires 128-aligned heads; llama3_1b's d=64 takes
-        the XLA fallback path until the packed-row kernel variant lands)."""
-        return LlamaConfig(
-            vocab_size=128256,
-            hidden_size=2048,
-            intermediate_size=8192,
-            n_layers=16,
-            n_heads=16,
-            n_kv_heads=8,
-            head_dim=128,
-            rope_theta=500000.0,
-            max_position_embeddings=8192,
-            tie_word_embeddings=True,
-        )
-
-    @staticmethod
     def qwen3_0_6b() -> "LlamaConfig":
         """Qwen3-0.6B shape (qk-norm family; MXU-native head_dim=128)."""
         return LlamaConfig(

@@ -103,8 +103,8 @@ class StubCosts:
     # step-0 compute PER DECODE LANE (each single-token lane burns a
     # whole block), which the dense mixed_decode packing avoids.  0 (the
     # default) disables the charge — every pre-dense scenario's virtual
-    # timeline stays byte-identical; bench --mode spec sets 8 (RAGGED_BQ)
-    # to price the K=0 dense-packing win in sim terms.
+    # timeline stays byte-identical; 8 (RAGGED_BQ)
+    # prices the K=0 dense-packing win in sim terms.
     ragged_align_tokens: int = 0
 
     @classmethod

@@ -852,7 +852,7 @@ class TestKVTierStaleSweep:
 
         import numpy as np
 
-        from kserve_tpu.engine.kv_tiers import KVTierStore, TierConfig
+        from kserve_tpu.kvstore.tiers import KVTierStore, TierConfig
 
         base = str(tmp_path)
         stale = os.path.join(base, "kv-999999-deadbeef")  # pid surely dead

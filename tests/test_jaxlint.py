@@ -702,12 +702,34 @@ def test_aotkey_suppressed():
     assert "aot-cache-key-drift" not in rules_of(src)
 
 
-def test_aotkey_real_tree_digest_covers_build_compiled():
-    """The production pair stays in lockstep: engine/compiled.py lints
+@pytest.mark.parametrize("module", ["compiled.py", "shapes.py"])
+def test_aotkey_real_tree_digest_covers_build_compiled(module):
+    """The production files stay in lockstep: engine/compiled.py, and
+    engine/shapes.py whose DispatchShapes.of it hands the config to, lint
     clean under the rule against engine/aot_cache.py's field list."""
-    compiled_py = os.path.join(PKG_DIR, "engine", "compiled.py")
-    findings = lint_paths([compiled_py], select=["aot-cache-key-drift"])
+    path = os.path.join(PKG_DIR, "engine", module)
+    findings = lint_paths([path], select=["aot-cache-key-drift"])
     assert findings == []
+
+
+def test_aotkey_audits_dispatch_shapes_of():
+    """program_defs passes the config whole to DispatchShapes.of: a read
+    there is a read during compiled-program construction."""
+    src = """
+        AOT_KEY_ENGINE_FIELDS = ("use_pallas",)
+
+        class DispatchShapes:
+            @classmethod
+            def of(cls, model_config, engine_config, backend):
+                ok = engine_config.use_pallas
+                return cls(engine_config.queue_policy)
+
+            def width(self, cfg):
+                return cfg.anything
+    """
+    findings = [f for f in lint_source(textwrap.dedent(src), path="fixture.py")
+                if f.rule == "aot-cache-key-drift"]
+    assert len(findings) == 1 and "queue_policy" in findings[0].message
 
 
 # ------------------------------------------- pagein-host-sync

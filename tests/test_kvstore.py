@@ -74,16 +74,6 @@ class TestTierClockInjection:
         assert store.get("px-aa") is not None
         assert not store.contains("px-aa")
 
-    def test_compat_shim_still_importable(self):
-        """engine/kv_tiers.py remains a working import path."""
-        from kserve_tpu.engine.kv_tiers import (
-            KVTierStore as ShimStore,
-            TierConfig as ShimConfig,
-        )
-
-        assert ShimStore is KVTierStore
-        assert ShimConfig is TierConfig
-
 
 class TestPersistentPrefixStore:
     def test_round_trip_and_index_across_instances(self, tmp_path):

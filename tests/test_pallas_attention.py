@@ -1,5 +1,5 @@
 """Pallas paged-attention kernel vs XLA reference (interpret mode on CPU;
-the compiled path runs on hardware via bench.py / the engine).
+the compiled path runs on hardware via chip_smoke.py / the engine).
 
 B=8 with MAX_SB=8 exercises the sequence-block kernel shape (whole block in
 one grid step); B=6 exercises sb<max and the multi-grid-step path; B=5

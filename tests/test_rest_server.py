@@ -1,10 +1,7 @@
 """In-process REST protocol tests (aiohttp test client against the real app),
 mirroring the reference's test_server.py/test_dataplane.py strategy."""
 
-import asyncio
 import json
-import os
-import time
 
 import numpy as np
 import pytest
@@ -247,68 +244,6 @@ class TestV2:
             res = await client.get("/metrics")
             text = await res.text()
             assert "request_predict_seconds" in text
-
-
-class TestLoadBench:
-    """scripts/loadbench.py drives a live server and reports percentiles
-    (the in-repo analogue of the reference's vegeta benchmark runs)."""
-
-    @async_test
-    async def test_loadbench_against_live_server(self, tmp_path):
-        import subprocess
-        import sys
-        import socket
-
-        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-        s = socket.socket(); s.bind(("127.0.0.1", 0))
-        port = s.getsockname()[1]; s.close()
-        serve = tmp_path / "serve.py"
-        serve.write_text(f"""
-import sys
-sys.path.insert(0, {repo!r})
-from kserve_tpu.model import Model
-from kserve_tpu.model_server import ModelServer
-
-class Echo(Model):
-    def load(self):
-        self.ready = True
-        return True
-    async def predict(self, payload, headers=None, response_headers=None):
-        return {{"predictions": payload.get("instances", [])}}
-
-m = Echo("echo"); m.load()
-ModelServer(http_port={port}, enable_grpc=False).start([m])
-""")
-        proc = subprocess.Popen([sys.executable, str(serve)],
-                                stdout=subprocess.DEVNULL, stderr=subprocess.DEVNULL)
-        try:
-            import httpx
-            deadline = time.time() + 30
-            while time.time() < deadline:
-                # deliberately drives a live subprocess server with sync
-                # httpx from the async test body; refusal during boot is
-                # the retry condition
-                try:
-                    if httpx.get(f"http://127.0.0.1:{port}/", timeout=1).status_code == 200:  # jaxlint: disable=blocking-async
-                        break
-                except Exception:  # jaxlint: disable=swallowed-exception
-                    await asyncio.sleep(0.2)
-            # the loadbench CLI is the thing under test; blocking the
-            # test's loop while it runs is the point
-            out = subprocess.run(  # jaxlint: disable=blocking-async
-                [sys.executable, os.path.join(repo, "scripts", "loadbench.py"),
-                 "--url", f"http://127.0.0.1:{port}/v1/models/echo:predict",
-                 "--body", '{"instances": [[1, 2]]}',
-                 "--concurrency", "2", "--duration", "1.5", "--warmup", "0.5"],
-                capture_output=True, text=True, timeout=60,
-            )
-            result = json.loads(out.stdout.strip().splitlines()[-1])
-            assert result["requests"] > 10
-            assert result["errors"] == 0
-            assert result["p50_ms"] > 0 and result["p99_ms"] >= result["p50_ms"]
-        finally:
-            proc.terminate()
-            proc.wait(timeout=10)
 
 
 class TestPeerPageServer:

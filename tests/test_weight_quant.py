@@ -80,7 +80,7 @@ class TestQuantMath:
         assert bf16 > 15.5e9  # bf16 8B does NOT fit 16-GB HBM with KV
         assert int8 < 9.5e9  # int8 leaves >6 GB for KV cache
         # tied 1B: the embed (= lm_head) quantizes too
-        cfg1 = llama.LlamaConfig.bench_1b()
+        cfg1 = llama.LlamaConfig.llama3_1b()
         assert param_bytes(cfg1, "int8") < 0.62 * param_bytes(cfg1, "none")
 
     def test_moe_rejected(self):
