@@ -515,6 +515,14 @@ class AOTProgram:
                 n += 1
         return n
 
+    def loaded_shapes(self, *argnums: int) -> List[Tuple[Tuple[int, ...], ...]]:
+        """Per executable held in memory, the shapes of the positional
+        arguments `argnums`: which shape buckets this program is loaded in
+        (the engine reads its `mixed` pairs here after `preload`)."""
+        return [
+            tuple(tuple(exe.in_avals[0][i].shape) for i in argnums)
+            for exe in self._mem.values()]
+
     def _compile(self, args: Tuple, sig_hash: str = ""):
         stats = self._cache.stats
         t0 = time.perf_counter()

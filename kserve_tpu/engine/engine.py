@@ -50,6 +50,7 @@ from ..metrics import (
     ENGINE_STATE_SLOTS_IN_USE,
     ENGINE_PREFILL_CHUNK_DURATION,
     ENGINE_QUEUE_DEPTH,
+    ENGINE_DISPATCH_SHAPE,
     ENGINE_SAMPLER_DISPATCHES,
     ENGINE_STEP_BATCH_COMPOSITION,
     ENGINE_STEP_DURATION,
@@ -98,7 +99,7 @@ from .kvcache import (
     pages_needed,
 )
 from .sampling import SAMPLER_PATHS, SamplingParams, SamplingState
-from .shapes import DispatchShapes
+from .shapes import FITS, DispatchShapes, LoadedPairs
 from .tokenizer import BaseTokenizer, IncrementalDetokenizer
 
 
@@ -259,6 +260,11 @@ class LLMEngine:
             path: ENGINE_SAMPLER_DISPATCHES.labels(
                 model_name=metrics_label, sampler_path=path)
             for path in SAMPLER_PATHS}
+        # engine_dispatch_shape_total by how a mixed dispatch's (T, W) pair
+        # was found among the loaded ones (shapes.FITS)
+        self._dispatch_fits = {
+            fit: ENGINE_DISPATCH_SHAPE.labels(model_name=metrics_label, fit=fit)
+            for fit in FITS}
         # when the fetch worker last had a result on the host
         self._fetch_ready_at: Optional[float] = None
         # checkpoints carry this as model_name; resume_generation rejects a
@@ -797,6 +803,15 @@ class LLMEngine:
         # the unified ragged program; absent on program sets that predate
         # it (or pp>1 builds), which forces the legacy dispatch paths
         self._mixed_fn = getattr(p, "mixed", None)
+        # the (T, W) pairs `mixed` is loaded in, which its planner fits a
+        # dispatch to (shapes.LoadedPairs): what the AOT cache preloaded
+        # above, then every pair a launch runs in.  Arguments 1 and 9 of
+        # `mixed` are the packed tokens [T] and the page table [B, W]
+        # (_step_mixed's call)
+        preloaded = getattr(self._mixed_fn, "loaded_shapes", None)
+        self._loaded = LoadedPairs(
+            (tokens[0], table[1])
+            for tokens, table in (preloaded(1, 9) if preloaded else ()))
         # dense/speculative decode-only program (docs/kernels.md); present
         # only when spec_decode_k is configured (stubs included)
         self._mixed_decode_fn = getattr(p, "mixed_decode", None)
@@ -823,12 +838,16 @@ class LLMEngine:
 
     async def _aot_warmup(self):
         """Drive one tiny generation per prefill bucket through the REAL
-        serving loop before the replica turns ready, so every
-        steady-state program signature is compiled (cold start — and
+        serving loop before the replica turns ready, so the program
+        signatures those generations reach are compiled (cold start — and
         persisted to the AOT cache) or deserialized (warm start) ahead
         of the first real request.  Driving generate() instead of
         hand-building abstract signatures means warmup can never drift
-        from what the scheduler actually dispatches."""
+        from what the scheduler actually dispatches.
+
+        The `mixed` pairs loaded here are where a settled engine runs a
+        dispatch whose own pair is not loaded: padded into the smallest of
+        them that holds it (shapes.LoadedPairs)."""
         params = SamplingParams(
             max_tokens=min(4, max(1, self._shapes.steps)),
             temperature=0.0, ignore_eos=True,
@@ -970,7 +989,9 @@ class LLMEngine:
             # previously internal to telemetry, surfaced here so the EPP —
             # and the autoscaler behind it — sees SLO pressure per replica
             "telemetry": self.telemetry.signal_windows(),
-            "dispatch": self.dispatch_report,
+            "dispatch": {**self.dispatch_report, "shapes": {
+                **self.dispatch_report["shapes"],
+                "loaded": self._loaded.published()}},
             # where this replica's params and cache live, and how much of
             # each device they hold (None where the backend keeps no stats)
             "devices": [_device_row(d) for d in self.mesh.devices.flat],
@@ -2954,9 +2975,18 @@ class LLMEngine:
         count is the caller's: the mixed step counts every seated lane,
         the decode chunk and the chained dense dispatch only the lanes
         they run."""
-        rows = [(i, slot.pages) for i, slot in enumerate(self._slots)
+        rows = self._page_rows(lanes)
+        return self._page_table(rows, self._width_of(rows))
+
+    def _page_rows(self, lanes) -> list:
+        return [(i, slot.pages) for i, slot in enumerate(self._slots)
                 if lanes[i] and slot.request_id is not None]
-        width = self._shapes.width(max([len(p) for _, p in rows] or [1]))
+
+    def _width_of(self, rows) -> int:
+        """W that `rows` need: the rung of the most pages one of them owns."""
+        return self._shapes.width(max([len(p) for _, p in rows] or [1]))
+
+    def _page_table(self, rows, width: int) -> np.ndarray:
         page_table = np.zeros((self.config.max_batch_size, width), np.int32)
         for i, pages in rows:
             page_table[i, : len(pages)] = pages
@@ -3401,10 +3431,13 @@ class LLMEngine:
             rng,
             jnp.asarray(plan["adapters"]),
         )
+        ran = (len(plan["q_tokens"]), plan["page_table"].shape[1])
         phases.launched(
-            "mixed", len(plan["q_tokens"]), plan["page_table"].shape[1],
-            plan["prefill_tokens"], plan["decode_tokens"],
-            compiled=getattr(self._mixed_fn, "compiles", 0) != compiles)
+            "mixed", *ran, plan["prefill_tokens"], plan["decode_tokens"],
+            compiled=getattr(self._mixed_fn, "compiles", 0) != compiles,
+            need=plan["need"])
+        self._loaded.ran(ran)
+        self._dispatch_fits[plan["fit"]].inc()
         self._sampler_dispatches[plan["sampler_path"]].inc()
         phases.mark("wait")
         chunk_np = await self._fetch_async(out)
@@ -3516,12 +3549,18 @@ class LLMEngine:
             chunks.append((i, n, final))
             n_prefill_tokens += n
 
-        pad = self._shapes.tokens(max(offset, 1)) - offset
+        # the pair this dispatch needs: the packed slices' bucket, and a
+        # table as wide as the longest SEATED lane, in this dispatch or not;
+        # it runs in the smallest pair the program is loaded in that holds
+        # it (shapes.LoadedPairs.fit), compiling only where none does
+        rows = self._page_rows(np.ones((B,), bool))
+        need = (self._shapes.tokens(max(offset, 1)), self._width_of(rows))
+        (tokens, width), fit = self._loaded.fit(*need)
+        pad = tokens - offset
         tok_list.extend([0] * pad)
         seq_list.extend([-1] * pad)
         pos_list.extend([0] * pad)
-        # as wide as the longest SEATED lane, in this dispatch or not
-        page_table = self._page_table_of(np.ones((B,), bool))
+        page_table = self._page_table(rows, width)
         state, sampler_path = SamplingState.planned(params_list)
         return {
             "q_tokens": np.asarray(tok_list, np.int32),
@@ -3541,6 +3580,8 @@ class LLMEngine:
             "adapters": adapters,
             "state": state,
             "sampler_path": sampler_path,
+            "need": need,
+            "fit": fit,
             "consume": consume,
             "chunks": chunks,
             "prefill_tokens": n_prefill_tokens,

@@ -108,7 +108,7 @@ class SamplingState:
 
 
 #: the work a batch asks of the sampler: `truncate` where a sampled row
-#: carries top-k, top-p or min-p (three full-vocabulary sorts a step),
+#: carries top-k, top-p or min-p (one full-vocabulary sort a step),
 #: `plain` where none does (no sort); indexed by `_truncates`
 SAMPLER_PATHS = ("plain", "truncate")
 
@@ -129,25 +129,30 @@ def sampler_truncates(state: SamplingState) -> jnp.ndarray:
 
 
 def _truncated(scaled: jnp.ndarray, state: SamplingState) -> jnp.ndarray:
-    """`scaled` with what top-k, top-p and min-p drop set to -inf."""
+    """`scaled` with what top-k, top-p and min-p drop set to -inf.  Every
+    cutoff is read from ONE descending sort of the rows."""
     V = scaled.shape[-1]
     # top-k: mask logits below the k-th largest (k==0 disables)
     sorted_logits = jnp.sort(scaled, axis=-1)[:, ::-1]  # desc
     k = jnp.clip(state.top_k, 0, V)
     kth_idx = jnp.clip(k - 1, 0, V - 1)
     kth_val = jnp.take_along_axis(sorted_logits, kth_idx[:, None], axis=1)
+    top_k_on = (state.top_k > 0)[:, None]
     topk_mask = jnp.where(
-        (state.top_k > 0)[:, None], scaled < kth_val, jnp.zeros_like(scaled, bool)
+        top_k_on, scaled < kth_val, jnp.zeros_like(scaled, bool)
     )
     scaled = jnp.where(topk_mask, -jnp.inf, scaled)
+    # the rows sorted again after that mask, without sorting again: masking
+    # everything below a value commutes with sorting, ties included
+    sorted_logits = jnp.where(
+        top_k_on & (sorted_logits < kth_val), -jnp.inf, sorted_logits)
 
     # top-p (nucleus): keep smallest prefix of sorted probs with cumsum >= p
-    probs_sorted = jax.nn.softmax(jnp.sort(scaled, axis=-1)[:, ::-1], axis=-1)
+    probs_sorted = jax.nn.softmax(sorted_logits, axis=-1)
     cumprobs = jnp.cumsum(probs_sorted, axis=-1)
     cutoff_count = jnp.sum(cumprobs - probs_sorted < state.top_p[:, None], axis=-1)
     cutoff_idx = jnp.clip(cutoff_count - 1, 0, V - 1)
-    sorted_again = jnp.sort(scaled, axis=-1)[:, ::-1]
-    cutoff_val = jnp.take_along_axis(sorted_again, cutoff_idx[:, None], axis=1)
+    cutoff_val = jnp.take_along_axis(sorted_logits, cutoff_idx[:, None], axis=1)
     topp_mask = jnp.where(
         (state.top_p < 1.0)[:, None], scaled < cutoff_val, jnp.zeros_like(scaled, bool)
     )
@@ -178,7 +183,7 @@ def sample_tokens(
     seed reproduces output regardless of batching.
 
     Only a batch in which a sampled row carries top-k, top-p or min-p runs
-    the truncation and its sorts (SAMPLER_PATHS).  The tokens do not depend
+    the truncation and its sort (SAMPLER_PATHS).  The tokens do not depend
     on that: with every mask off, truncation leaves the scaled logits as
     they are."""
     B = logits.shape[0]

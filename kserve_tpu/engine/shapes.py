@@ -10,14 +10,20 @@ signatures all ask a `DispatchShapes`, and `/v1/internal/scheduler/state`
 publishes it (`dispatch.shapes`), so a client that warms shapes before a
 measurement reads the policy instead of copying it.
 
-A change of the policy (coarser buckets, rounding to loaded pairs, a step
-count from queue depth) is a change to this file.
+The pair a mixed dispatch NEEDS comes from the policy alone
+(`DispatchShapes.tokens`, `.width`); the pair it RUNS in also depends on
+which executables the engine holds (`LoadedPairs.fit`): a dispatch compiles
+only when no loaded pair holds it, since a compile stalls every request in
+flight and a longer buffer or a wider table only pads.
+
+A change of the policy (coarser buckets, a step count from queue depth) is
+a change to this file.
 """
 
 from __future__ import annotations
 
 from dataclasses import dataclass
-from typing import Dict, List, Tuple
+from typing import Dict, Iterable, List, Set, Tuple
 
 #: the first rung of the width ladder, in pages
 MIN_WIDTH = 8
@@ -41,6 +47,65 @@ def rung(ladder: Tuple[int, ...], n: int) -> int:
         if n <= b:
             return b
     return ladder[-1]
+
+
+#: how a mixed dispatch's pair was found (`LoadedPairs.fit`):
+#: engine_dispatch_shape_total's label
+FITS = ("exact", "padded", "compiled")
+
+
+#: mixed dispatches in a row that ran in an already loaded pair after which
+#: an engine counts as settled (`LoadedPairs`)
+SETTLED_AFTER = 16
+
+
+class LoadedPairs:
+    """The (T, W) pairs an engine's `mixed` program is loaded in: every pair
+    a launch has run in and every pair the AOT cache preloaded at start-up
+    (so a warm start plans as the cold run that filled the cache did).
+
+    An engine that loaded a new pair within its last SETTLED_AFTER
+    dispatches is still being warmed, by its first traffic or by a client
+    that drives shapes on purpose, and compiles the pair a dispatch needs,
+    as it always did.  Once settled it stalls its streams for a compile
+    only where no loaded pair holds the dispatch."""
+
+    def __init__(self, pairs: Iterable[Tuple[int, int]] = ()):
+        self._pairs: Set[Tuple[int, int]] = set(pairs)
+        self._since_new = 0  # dispatches since one ran in a pair new here
+
+    def ran(self, pair: Tuple[int, int]) -> None:
+        """A launch ran in `pair`."""
+        if pair in self._pairs:
+            self._since_new += 1
+        else:
+            self._pairs.add(pair)
+            self._since_new = 0
+
+    @property
+    def settled(self) -> bool:
+        return self._since_new >= SETTLED_AFTER
+
+    def fit(self, tokens: int, width: int) -> Tuple[Tuple[int, int], str]:
+        """The pair a mixed dispatch that needs (tokens, width) runs in, and
+        which of FITS that is: the needed pair where it is loaded; else, in
+        a settled engine, the loaded pair of least T, then least W, that
+        holds it (the buffer pads with rows of no sequence, the table with
+        null pages); else the needed pair itself, which compiles and joins
+        the set."""
+        need = (tokens, width)
+        if need in self._pairs:
+            return need, "exact"
+        if self.settled:
+            holding = [p for p in self._pairs
+                       if p[0] >= tokens and p[1] >= width]
+            if holding:
+                return min(holding), "padded"
+        return need, "compiled"
+
+    def published(self) -> List[List[int]]:
+        """As served under `dispatch.shapes.loaded`, smallest first."""
+        return [list(p) for p in sorted(self._pairs)]
 
 
 @dataclass(frozen=True)

@@ -319,9 +319,19 @@ ENGINE_DISPATCHES = Counter(
 ENGINE_SAMPLER_DISPATCHES = Counter(
     "engine_sampler_dispatches_total",
     "device dispatches by the path their batch takes through the sampler: "
-    "truncate (a sampled row carries top-k, top-p or min-p: three "
-    "full-vocabulary sorts a step) | plain (none does: no sort)",
+    "truncate (a sampled row carries top-k, top-p or min-p: one "
+    "full-vocabulary sort a step) | plain (none does: no sort)",
     ["model_name", "sampler_path"],
+)
+# `fit` is the closed set engine/shapes.FITS: how the (T, W) pair a `mixed`
+# dispatch ran in was found among the pairs the program is loaded in
+ENGINE_DISPATCH_SHAPE = Counter(
+    "engine_dispatch_shape_total",
+    "mixed dispatches by how their (T, W) pair was found: exact (the "
+    "needed pair was loaded) | padded (it was not: the smallest loaded "
+    "pair that holds it) | compiled (no loaded pair holds it: the needed "
+    "pair, new to this engine)",
+    ["model_name", "fit"],
 )
 ENGINE_FIRST_TOKEN_DISPATCHES = Summary(
     "engine_first_token_dispatches",

@@ -27,7 +27,7 @@ is the gap between consecutive emitted tokens.
 from __future__ import annotations
 
 from collections import deque
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, List, Optional, Tuple
 
 # bounded per-timeline storage: events and ITL samples never grow past
 # these caps even for max_model_len generations (overflow keeps aggregate
@@ -41,9 +41,12 @@ MAX_DISPATCHES = 512
 PHASES = ("admit", "plan", "launch", "wait", "route", "yield")
 #: the columns of a dispatch row: flat, so a snapshot of the whole ring
 #: serialises in about a millisecond
+#: (`tokens`, `width` are the (T, W) pair the dispatch ran in, `need_*` the
+#: pair it needed: they differ where it ran padded in a loaded pair)
 DISPATCH_COLUMNS = (
-    "serial", "launched_at", "program", "tokens", "width", "prefill_tokens",
-    "decode_tokens", *PHASES, "wait_lag", "compiled", "chained")
+    "serial", "launched_at", "program", "tokens", "width", "need_tokens",
+    "need_width", "prefill_tokens", "decode_tokens", *PHASES, "wait_lag",
+    "compiled", "chained")
 
 
 class RequestTimeline:
@@ -366,11 +369,14 @@ class DispatchPhases:
 
     def launched(self, program: str, tokens: int, width: int,
                  prefill_tokens: int, decode_tokens: int,
-                 compiled: bool = False, chained: bool = False) -> None:
-        """What the `launch` phase under way dispatched."""
+                 compiled: bool = False, chained: bool = False,
+                 need: Optional[Tuple[int, int]] = None) -> None:
+        """What the `launch` phase under way dispatched.  `need` is the
+        (T, W) pair the dispatch needed, where its planner may run it in
+        another (the mixed step: engine/shapes.LoadedPairs)."""
         self._launches.append([
-            self._since, program, tokens, width, prefill_tokens,
-            decode_tokens, int(compiled), int(chained)])
+            self._since, program, tokens, width, *(need or (tokens, width)),
+            prefill_tokens, decode_tokens, int(compiled), int(chained)])
 
     def resumed(self, ready_at: Optional[float]) -> None:
         """The loop took a fetched result up `now - ready_at` after the

@@ -636,7 +636,8 @@ class TestDispatchPhases:
         row = _row(phases.commit())
         assert row == {
             "serial": 1, "launched_at": 3.0, "program": "mixed", "tokens": 32,
-            "width": 8, "prefill_tokens": 24, "decode_tokens": 8,
+            "width": 8, "need_tokens": 32, "need_width": 8,
+            "prefill_tokens": 24, "decode_tokens": 8,
             "admit": 1.0, "plan": 2.0, "launch": 3.0, "wait": 10.0,
             "route": 0.5, "yield": 4.0, "wait_lag": 2.5, "compiled": 1,
             "chained": 0}
@@ -654,6 +655,18 @@ class TestDispatchPhases:
         phases.launched("mixed", 16, 8, 0, 2)
         row = _row(phases.commit())
         assert (row["serial"], row["admit"], row["launch"]) == (2, 1.0, 0.0)
+
+    @pytest.mark.parametrize("need, row_need", [
+        (None, (64, 16)), ((64, 16), (64, 16)), ((16, 8), (16, 8))])
+    def test_a_row_holds_the_pair_needed_beside_the_pair_run_in(
+            self, need, row_need):
+        phases = DispatchPhases(FakeClock())
+        phases.mark("launch")
+        phases.launched("mixed", 64, 16, 0, 2, need=need)
+        row = _row(phases.commit())
+        assert (row["tokens"], row["width"]) == (64, 16)
+        assert (row["need_tokens"], row["need_width"]) == row_need
+        assert (row["prefill_tokens"], row["decode_tokens"]) == (0, 2)
 
     def test_chained_launches_commit_oldest_first(self):
         clock = FakeClock()

@@ -1,7 +1,8 @@
 """engine/sampling.sample_tokens, held to a frozen copy of the body it had
 when every batch ran the truncation (three sorts, two softmaxes, a
-cumulative sum): whichever path a batch takes through the sampler, its
-tokens are that body's, token for token.
+cumulative sum): whichever path a batch takes through the sampler, and
+however few sorts the truncation now reads its cutoffs from, its tokens
+are that body's, token for token.
 """
 
 import asyncio
@@ -77,6 +78,39 @@ def frozen_sample_tokens(logits, state, rng, counters=None):
     return jnp.where(state.temperature <= 0.0, greedy, sampled).astype(jnp.int32)
 
 
+def _frozen_truncated(scaled, state):
+    """The truncation's lines of the body above (top-k to min-p), copied a
+    second time so that the array they leave can be compared, not only the
+    token drawn from it."""
+    V = scaled.shape[-1]
+    sorted_logits = jnp.sort(scaled, axis=-1)[:, ::-1]  # desc
+    k = jnp.clip(state.top_k, 0, V)
+    kth_idx = jnp.clip(k - 1, 0, V - 1)
+    kth_val = jnp.take_along_axis(sorted_logits, kth_idx[:, None], axis=1)
+    topk_mask = jnp.where(
+        (state.top_k > 0)[:, None], scaled < kth_val, jnp.zeros_like(scaled, bool)
+    )
+    scaled = jnp.where(topk_mask, -jnp.inf, scaled)
+    probs_sorted = jax.nn.softmax(jnp.sort(scaled, axis=-1)[:, ::-1], axis=-1)
+    cumprobs = jnp.cumsum(probs_sorted, axis=-1)
+    cutoff_count = jnp.sum(cumprobs - probs_sorted < state.top_p[:, None], axis=-1)
+    cutoff_idx = jnp.clip(cutoff_count - 1, 0, V - 1)
+    sorted_again = jnp.sort(scaled, axis=-1)[:, ::-1]
+    cutoff_val = jnp.take_along_axis(sorted_again, cutoff_idx[:, None], axis=1)
+    topp_mask = jnp.where(
+        (state.top_p < 1.0)[:, None], scaled < cutoff_val, jnp.zeros_like(scaled, bool)
+    )
+    scaled = jnp.where(topp_mask, -jnp.inf, scaled)
+    probs = jax.nn.softmax(scaled, axis=-1)
+    max_prob = probs.max(axis=-1, keepdims=True)
+    minp_mask = jnp.where(
+        (state.min_p > 0.0)[:, None],
+        probs < state.min_p[:, None] * max_prob,
+        jnp.zeros_like(scaled, bool),
+    )
+    return jnp.where(minp_mask, -jnp.inf, scaled)
+
+
 def P(**kw) -> SamplingParams:
     return SamplingParams(**kw)
 
@@ -128,8 +162,34 @@ MIXES = {
          EMPTY_LANE, P(top_k=3), P(top_p=0.6, seed=1), P(min_p=0.3),
          P(temperature=2.0, top_k=50, top_p=0.95)],
         "truncate"),
+    "top_k_and_top_p_on_one_row": (
+        [P(top_k=5, top_p=0.5), P(top_k=40, top_p=0.9, temperature=0.7, seed=2),
+         P(top_k=2, top_p=0.999), P(top_k=200, top_p=0.05, seed=8),
+         P(top_k=1, top_p=0.01), P(top_k=3, top_p=0.7, min_p=0.2)],
+        "truncate"),
+    "top_k_at_and_past_the_vocabulary": (
+        [P(top_k=V), P(top_k=V + 1, top_p=0.8), P(top_k=V - 1),
+         P(top_k=2**31 - 1, temperature=0.7, seed=6), P(top_k=V, min_p=0.1),
+         GREEDY],
+        "truncate"),
 }
 MIX_NAMES = sorted(MIXES)
+
+#: logits that tie where a cutoff falls, and rows with nothing to keep
+LOGITS = {
+    # every value one of five: the k-th largest is tied many times over
+    "ties": lambda rng, rows: rng.randint(-2, 3, (rows, V)).astype(np.float32),
+    # one value in every column: any k, any nucleus keeps the whole row
+    "flat": lambda rng, rows: np.zeros((rows, V), np.float32),
+    # a penalised row can hold -inf alone (every other row is as drawn)
+    "a_row_of_minus_inf": lambda rng, rows: np.where(
+        np.arange(rows)[:, None] % 3 == 1, -np.inf,
+        rng.randn(rows, V) * 3.0).astype(np.float32),
+    # and -inf beside finite values, fewer of those than k
+    "mostly_minus_inf": lambda rng, rows: np.where(
+        rng.rand(rows, V) < 0.99, -np.inf, rng.randn(rows, V)).astype(np.float32),
+}
+TRUNCATING = [name for name in MIX_NAMES if MIXES[name][1] == "truncate"]
 
 
 def _inputs(rows: int, seed: int = 0):
@@ -184,6 +244,41 @@ def test_tokens_are_the_frozen_body_s(mix, with_counters, how):
     assert new.dtype == jnp.int32
 
 
+@pytest.mark.parametrize("how", ["jit", "scan"])
+@pytest.mark.parametrize("logits_kind", sorted(LOGITS))
+@pytest.mark.parametrize("mix", TRUNCATING)
+def test_cutoffs_at_ties_and_empty_rows_are_the_frozen_body_s(
+        mix, logits_kind, how):
+    """Where the one sort could differ from the three: values tied at the
+    k-th place or at the nucleus's edge, and rows the masks leave empty."""
+    rows, _ = MIXES[mix]
+    state = SamplingState.from_params(rows)
+    _, counters, rng = _inputs(len(rows), seed=3)
+    logits = jnp.asarray(
+        LOGITS[logits_kind](np.random.RandomState(5), len(rows)))
+    wrap = _scanned if how == "scan" else (lambda fn: fn)
+    new = jax.jit(wrap(sample_tokens))(logits, state, rng, counters)
+    old = jax.jit(wrap(frozen_sample_tokens))(logits, state, rng, counters)
+    np.testing.assert_array_equal(np.asarray(new), np.asarray(old))
+
+
+@pytest.mark.parametrize("logits_kind", sorted(LOGITS))
+@pytest.mark.parametrize("mix", TRUNCATING)
+def test_truncated_logits_are_the_frozen_body_s_bit_for_bit(mix, logits_kind):
+    """Not only the tokens: the array the draw reads is the one the three
+    sorts left (what is -inf, and every kept value)."""
+    from kserve_tpu.engine.sampling import _truncated
+
+    rows, _ = MIXES[mix]
+    state = SamplingState.from_params(rows)
+    logits = jnp.asarray(
+        LOGITS[logits_kind](np.random.RandomState(9), len(rows)))
+    scaled = logits / jnp.maximum(state.temperature, 1e-6)[:, None]
+    new = jax.jit(_truncated)(scaled, state)
+    old = jax.jit(_frozen_truncated)(scaled, state)
+    np.testing.assert_array_equal(np.asarray(new), np.asarray(old))
+
+
 @pytest.mark.parametrize("mix", MIX_NAMES)
 def test_host_label_is_the_device_s_branch(mix):
     """engine_sampler_dispatches_total's label, computed at plan time from
@@ -212,17 +307,17 @@ def _count_sorts(jaxpr) -> int:
     return n
 
 
-@pytest.mark.parametrize("path, sorts", [("plain", 0), ("truncate", 3)])
+@pytest.mark.parametrize("path, sorts", [("plain", 0), ("truncate", 1)])
 def test_only_the_truncating_branch_sorts(path, sorts):
-    """The traced sampler holds one conditional of two branches, and every
-    sort lies in the truncating one."""
+    """The traced sampler holds one conditional of two branches, and its
+    one sort lies in the truncating one."""
     rows = MIXES["everything_at_once"][0]
     logits, counters, rng = _inputs(len(rows))
     jaxpr = jax.make_jaxpr(sample_tokens)(
         logits, SamplingState.from_params(rows), rng, counters).jaxpr
     conds = [e for e in jaxpr.eqns if e.primitive.name == "cond"]
     assert len(conds) == 1 and len(conds[0].params["branches"]) == 2
-    assert _count_sorts(jaxpr) == 3  # all of them inside the conditional
+    assert _count_sorts(jaxpr) == 1  # inside the conditional
     branch = conds[0].params["branches"][SAMPLER_PATHS.index(path)]
     assert _count_sorts(branch.jaxpr) == sorts
 
