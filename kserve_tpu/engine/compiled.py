@@ -24,6 +24,7 @@ import jax.numpy as jnp
 from ..metrics import XLA_COMPILE_SECONDS, XLA_COMPILES
 from ..models import llama
 from ..parallel import sharding as shd
+from .kvcache import pages_of_passes
 from .sampling import (
     apply_penalties,
     compute_logprobs,
@@ -438,6 +439,17 @@ def program_defs(model_config, engine_config, mesh, spec_k=None) -> dict:
 
         return fn
 
+    def _rows_at(layer, i, data, ids):
+        """Layer i's array with the wire rows `data` [cache_rows, P, ...]
+        set at pages `ids`: a looped model's layer holds a row a pass, pass
+        u's pages at + u * pool, its wire rows at u * n_layers + i."""
+        if not mc.is_looped:
+            return layer.at[ids].set(data[i].astype(layer.dtype))
+        of_pass = pages_of_passes(
+            ids, mc.n_passes, layer.shape[0] // mc.n_passes)
+        rows = data.reshape((mc.n_passes, -1) + data.shape[1:])[:, i]
+        return layer.at[of_pass].set(rows.astype(layer.dtype))
+
     def _inject(kv_pages, kv_data, ids):
         """Scatter transferred KV pages (P/D transfer or tier-store
         resume) into the cache.  Padded ids point at the null page (page
@@ -448,7 +460,7 @@ def program_defs(model_config, engine_config, mesh, spec_k=None) -> dict:
             return _kv_pin(
                 kv_pages.at[:, ids].set(kv_data.astype(kv_pages.dtype)))
         return _kv_pin([
-            layer.at[ids].set(kv_data[i].astype(layer.dtype))
+            _rows_at(layer, i, kv_data, ids)
             for i, layer in enumerate(kv_pages)
         ])
 
@@ -460,8 +472,7 @@ def program_defs(model_config, engine_config, mesh, spec_k=None) -> dict:
             return _kv_pin((pages.at[:, ids].set(q.astype(pages.dtype)),
                             scales.at[:, ids].set(s.astype(scales.dtype))))
         return _kv_pin([
-            (pages.at[ids].set(q[i].astype(pages.dtype)),
-             scales.at[ids].set(s[i].astype(scales.dtype)))
+            (_rows_at(pages, i, q, ids), _rows_at(scales, i, s, ids))
             for i, (pages, scales) in enumerate(kv_pages)
         ])
 

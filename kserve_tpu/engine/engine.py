@@ -43,7 +43,11 @@ from ..metrics import (
     ENGINE_FIRST_TOKEN_DISPATCHES,
     ENGINE_KV_DISK_BYTES,
     ENGINE_KV_OFFLOAD_BYTES,
+    ENGINE_KV_CONTEXT_TOKENS,
     ENGINE_KV_PAGES_FREE,
+    ENGINE_KV_PAGES_TOTAL,
+    ENGINE_KV_TOKEN_BYTES,
+    ENGINE_LAYER_PASSES,
     ENGINE_PREEMPTIONS,
     ENGINE_STATE_BYTES,
     ENGINE_STATE_RESETS,
@@ -97,6 +101,7 @@ from .kvcache import (
     init_kv_pages,
     init_kv_scales,
     pages_needed,
+    pages_of_passes,
 )
 from .sampling import SAMPLER_PATHS, SamplingParams, SamplingState
 from .shapes import FITS, DispatchShapes, LoadedPairs
@@ -131,6 +136,28 @@ _PHASE_COLUMNS = slice(DISPATCH_COLUMNS.index(PHASES[0]),
                        DISPATCH_COLUMNS.index("wait_lag") + 1)
 
 
+def _refuse_looped(model_config, engine_config) -> None:
+    """What a model whose stack runs several times a token cannot do yet,
+    by name (ROADMAP.md Queue R names the mechanisms)."""
+    if not model_config.is_looped:
+        return
+    refused = []
+    if model_config.early_exit_threshold < 1.0:
+        refused.append(
+            f"early_exit_threshold={model_config.early_exit_threshold} < 1 "
+            "(per-token early exit: the lanes of one dispatch would run "
+            "different numbers of passes)")
+    if engine_config.pp > 1:
+        refused.append("pp>1 (a stage boundary inside the loop over passes)")
+    if engine_config.sp > 1:
+        refused.append("sp>1 (ring-attention prefill under the loop over "
+                       "passes is untested)")
+    if refused:
+        raise NotImplementedError(
+            f"not supported yet for a looped model ({model_config.n_passes} "
+            "passes over shared weights): " + "; ".join(refused))
+
+
 def resolve_hybrid_serving(model_config, engine_config,
                            role: str = "both") -> None:
     """THE place that says what a model with recurrent or ring state
@@ -139,7 +166,12 @@ def resolve_hybrid_serving(model_config, engine_config,
     what was asked for explicitly is refused here, at start-up, by name;
     what was left at its default is resolved to off, with a log line.
     Request-time features that run the legacy programs are refused at
-    submit (`LLMEngine._check_hybrid_request`)."""
+    submit (`LLMEngine._check_hybrid_request`).
+
+    It also says what a LOOPED model (LlamaConfig.n_passes > 1) cannot do
+    yet: every program the engine can pick for it runs all the passes
+    (models/llama._run_passes is under each forward), or is refused here."""
+    _refuse_looped(model_config, engine_config)
     if not model_config.is_hybrid:
         return
     cfg = engine_config
@@ -265,6 +297,12 @@ class LLMEngine:
         self._dispatch_fits = {
             fit: ENGINE_DISPATCH_SHAPE.labels(model_name=metrics_label, fit=fit)
             for fit in FITS}
+        # engine_layer_passes_total / engine_kv_context_tokens_total: what
+        # a launch's forward steps run (_count_forward)
+        self._layer_passes = ENGINE_LAYER_PASSES.labels(
+            model_name=metrics_label)
+        self._kv_context_tokens = ENGINE_KV_CONTEXT_TOKENS.labels(
+            model_name=metrics_label)
         # when the fetch worker last had a result on the host
         self._fetch_ready_at: Optional[float] = None
         # checkpoints carry this as model_name; resume_generation rejects a
@@ -431,14 +469,15 @@ class LLMEngine:
             num_pages=engine_config.num_pages,
             max_pages_per_seq=engine_config.max_pages_per_seq,
             dtype=engine_config.dtype,
+            n_passes=model_config.n_passes,
         )
         self.cache_config = cache_cfg
         if engine_config.kv_quant not in ("none", "int8"):
             raise ValueError(
                 f"unknown kv_quant {engine_config.kv_quant!r}; supported: none, int8"
             )
-        stacked_shape = (
-            model_config.n_layers, cache_cfg.num_pages, 2,
+        stacked_shape = (  # pp > 1 only, which a looped model refuses
+            cache_cfg.cache_rows, cache_cfg.num_pages, 2,
             cache_cfg.n_kv_heads, cache_cfg.page_size, cache_cfg.head_dim,
         )
         if model_config.is_hybrid:
@@ -723,6 +762,11 @@ class LLMEngine:
             "shapes": self._shapes.published(),
         }
         self._set_state_gauges()
+        # what the pool is made of does not change while the engine lives
+        ENGINE_KV_PAGES_TOTAL.labels(model_name=metrics_label).set(
+            layout.num_pages - 1)
+        ENGINE_KV_TOKEN_BYTES.labels(model_name=metrics_label).set(
+            layout.token_bytes())
 
     # ---------------- compiled programs ----------------
 
@@ -982,6 +1026,8 @@ class LLMEngine:
             "page_size": self.config.page_size,
             # what the seated lanes hold, per kind of state
             "state": self._state_occupancy(),
+            # the pool: pages held / in all, and what a token holds of it
+            "cache": self._cache_report(),
             "running": self.running,
             "wedged": self._wedged,
             "prefix_digests": digests,
@@ -1080,13 +1126,26 @@ class LLMEngine:
             return {"kv_q": pages[:, ids], "kv_s": scales[:, ids]}
         if self.config.kv_quant == "int8":
             return {
-                "kv_q": jnp.stack([layer[0][ids] for layer in self.kv_pages]),
-                "kv_s": jnp.stack([layer[1][ids] for layer in self.kv_pages]),
+                "kv_q": self._gather_rows([q for q, _ in self.kv_pages], ids),
+                "kv_s": self._gather_rows([s for _, s in self.kv_pages], ids),
             }
         if self.config.pp > 1:
             # stacked cache: one gather covers every stage's layers
             return {"kv": self.kv_pages[:, ids]}
-        return {"kv": jnp.stack([layer[ids] for layer in self.kv_pages])}
+        return {"kv": self._gather_rows(self.kv_pages, ids)}
+
+    def _gather_rows(self, arrays: list, ids) -> jnp.ndarray:
+        """Pages `ids` of the flat cache in the wire's layout
+        [cache_rows, P, ...]: row u * n_layers + l is (pass u, layer l),
+        which layer l's array holds at page id + u * num_pages
+        (engine/kvcache.py; compiled._inject is the way back)."""
+        cc = self.cache_config
+        if cc.n_passes == 1:
+            return jnp.stack([layer[ids] for layer in arrays])
+        of_pass = pages_of_passes(ids, cc.n_passes, cc.num_pages)
+        by_layer = jnp.stack([layer[of_pass] for layer in arrays])
+        return jnp.swapaxes(by_layer, 0, 1).reshape(
+            (cc.cache_rows,) + by_layer.shape[2:])
 
     def _demote_prefix_pages(self, evicted: List[tuple]) -> None:
         """PrefixCache eviction seam: gather the evicted pages' KV (one
@@ -1640,7 +1699,7 @@ class LLMEngine:
         kv_data = np.asarray(kv_data)
         cc = self.cache_config
         expect = (
-            cc.n_layers, pages_needed(len(prompt_ids), cc.page_size), 2,
+            cc.cache_rows, pages_needed(len(prompt_ids), cc.page_size), 2,
             cc.n_kv_heads, cc.page_size, cc.head_dim,
         )
         if tuple(kv_data.shape) != expect:
@@ -1785,6 +1844,7 @@ class LLMEngine:
         state = SamplingState.from_params(params_list)
         rng = jax.random.fold_in(self._base_rng, self._next_step())
         try:
+            self._count_forward(1)
             first, self.kv_pages = self._prefill_fn(
                 self.params,
                 jnp.asarray(tokens),
@@ -1808,9 +1868,7 @@ class LLMEngine:
                     # pp/tp topologies
                     kv = self._fetch(self.kv_pages[:, ids])
                 else:
-                    kv = self._fetch(
-                        jnp.stack([layer[ids] for layer in self.kv_pages])
-                    )
+                    kv = self._fetch(self._gather_rows(self.kv_pages, ids))
                 if not fut.done():
                     fut.set_result((int(first_np[j]), kv))
         finally:
@@ -2303,6 +2361,7 @@ class LLMEngine:
         )
         lp_tuple = None
         prefill_t0 = self._clock.now()
+        self._count_forward(1)  # one legacy prefill forward, either program
         if use_fused_call:
             prefill_fn = self._prefill_lp_fn if want_lp else self._prefill_fn
             out = prefill_fn(
@@ -2438,6 +2497,38 @@ class LLMEngine:
             "bytes_per_token": {"shared_kv": layout.token_bytes()},
             "bytes_per_lane": layout.lane_bytes(),
         }
+
+    def _cache_report(self) -> dict:
+        """The pool as /v1/internal/scheduler/state publishes it (`cache`):
+        pages held (not free: the lanes' and the prefix cache's, which are
+        given up under pressure) and in all, the rows a token holds (passes
+        x layers) and their bytes."""
+        layout = self.state_layout
+        total = layout.num_pages - 1  # page 0 is the null page
+        return {
+            "pages_held": total - self.allocator.free_pages,
+            "pages_cached": len(self._prefix_cache),
+            "pages_total": total,
+            "page_size": layout.page_size,
+            "passes": layout.n_passes,
+            "cache_rows": layout.cache_rows,
+            "token_bytes": layout.token_bytes(),
+        }
+
+    def _count_forward(self, steps: int, pos=None, live=None, capacity=None,
+                       decode_steps: int = 0) -> None:
+        """Count what a launch runs: `steps` forward steps (each all the
+        model's passes) and, over its `decode_steps` decode steps, the
+        cached tokens the live lanes attend to.  Lane b, live at position
+        pos[b], attends to pos[b] + s + 1 tokens at decode step s while it
+        stays under its page capacity: the device's own rule
+        (compiled._make_decode / _make_mixed), evaluated on the host."""
+        self._layer_passes.inc(steps * self.model_config.n_passes)
+        if decode_steps and pos is not None:
+            pos = np.asarray(pos, np.int64)
+            n = np.where(np.asarray(live),
+                         np.clip(np.asarray(capacity) - pos, 0, decode_steps), 0)
+            self._kv_context_tokens.inc(int(np.sum(n * pos + n * (n + 1) // 2)))
 
     def _set_state_gauges(self) -> None:
         occupancy = self._state_occupancy()
@@ -2581,6 +2672,7 @@ class LLMEngine:
                 tl = pf["req"].timeline
                 if tl is not None:
                     tl.mark_prefill_start(chunk_t0)
+                self._count_forward(1)
                 pf["logits"], self.kv_pages = self._prefill_chunk_fn(
                     self.params,
                     jnp.asarray(tokens),
@@ -2901,9 +2993,7 @@ class LLMEngine:
         pos = slot.pos  # KV on device covers positions 0..pos-1
         P = pages_needed(pos, self.config.page_size)
         kv_key = None
-        nbytes = (
-            P * self.model_config.n_layers * self.cache_config.bytes_per_page()
-        )
+        nbytes = P * self.cache_config.page_bytes()
         # spill into the tier store when it can fit; otherwise chunked
         # re-prefill recomputes the KV on resume.  Quantized caches spill
         # both tensors (int8 pages + scales) as one payload.  Mid-drain the
@@ -3198,6 +3288,9 @@ class LLMEngine:
             "decode", n_active, meta["page_table"].shape[1], 0, n_active,
             chained=tokens_dev is not None)
         self._sampler_dispatches[meta["sampler_path"]].inc()
+        self._count_forward(
+            self._shapes.steps, meta["pos"], meta["active"], meta["capacity"],
+            decode_steps=self._shapes.steps)
         rng = jax.random.fold_in(self._base_rng, self._next_step())
         tokens = tokens_dev if tokens_dev is not None else jnp.asarray(meta["tokens"])
         args = (
@@ -3439,6 +3532,10 @@ class LLMEngine:
         self._loaded.ran(ran)
         self._dispatch_fits[plan["fit"]].inc()
         self._sampler_dispatches[plan["sampler_path"]].inc()
+        # the packed step, then steps - 1 decode steps over the joining lanes
+        self._count_forward(
+            self._shapes.steps, plan["scan_pos0"], plan["joins"],
+            plan["capacity"], decode_steps=self._shapes.steps - 1)
         phases.mark("wait")
         chunk_np = await self._fetch_async(out)
         phases.resumed(self._fetch_ready_at)
@@ -3733,6 +3830,7 @@ class LLMEngine:
             "mixed_decode", n_tokens, plan["page_table"].shape[1], 0,
             n_tokens, chained=chain is not None)
         self._sampler_dispatches[plan["sampler_path"]].inc()
+        self._count_forward(self._shapes.steps)  # rounds of the packed step
         rng = jax.random.fold_in(self._base_rng, self._next_step())
         if chain is not None:
             tok, pos, cnt = chain["carry"]

@@ -9,6 +9,13 @@ time.  Sequences own pages through a page table [B_slots,
 max_pages_per_seq]; page 0 is reserved as the null page so padded table
 entries are always valid gathers.
 
+A looped model (models/llama.LlamaConfig.n_passes > 1) keeps K/V rows of
+its own for every (pass, layer): `cache_rows` = passes x layers.  A layer's
+array then holds `n_passes * num_pages` pages, pass u's at [u * num_pages,
+(u + 1) * num_pages), and the forward reads and writes pass u through the
+page table + u * num_pages (models/llama._run_passes): one page id of the
+allocator, the prefix cache or the wire stands for that page in every row.
+
 Role parity: replaces vLLM's block allocator + CUDA paged attention cache
 (the reference delegates this entirely to vLLM; see SURVEY.md §2.3) with an
 XLA-native design.
@@ -34,14 +41,27 @@ class KVCacheConfig:
     num_pages: int = 1024
     max_pages_per_seq: int = 128
     dtype: str = "bfloat16"
+    n_passes: int = 1  # a looped model's passes over its layers
 
     @property
     def max_seq_len(self) -> int:
         return self.max_pages_per_seq * self.page_size
 
+    @property
+    def cache_rows(self) -> int:
+        """K/V rows a token holds: one per (pass, layer).  THE count every
+        size of a page, a pool, a transfer or a spill multiplies by."""
+        return self.n_passes * self.n_layers
+
     def bytes_per_page(self) -> int:
+        """One page of ONE row."""
         itemsize = 2 if self.dtype in ("bfloat16", "float16") else 4
         return 2 * self.n_kv_heads * self.page_size * self.head_dim * itemsize
+
+    def page_bytes(self) -> int:
+        """One page of the pool, all its rows: what a transfer, a spill or
+        a prefix page moves."""
+        return self.cache_rows * self.bytes_per_page()
 
 
 def device_filler(sharding, shape, dtype, value):
@@ -55,10 +75,17 @@ def device_filler(sharding, shape, dtype, value):
 
 def init_kv_pages(config: KVCacheConfig, sharding=None) -> List[jnp.ndarray]:
     """[n_layers] list of page-major K/V pages:
-    [num_pages, 2, n_kv_heads, page_size, head_dim]."""
-    shape = (config.num_pages, 2, config.n_kv_heads, config.page_size, config.head_dim)
+    [n_passes * num_pages, 2, n_kv_heads, page_size, head_dim]."""
+    shape = (config.n_passes * config.num_pages, 2, config.n_kv_heads,
+             config.page_size, config.head_dim)
     make = device_filler(sharding, shape, jnp.dtype(config.dtype), 0)
     return [make() for _ in range(config.n_layers)]
+
+
+def pages_of_passes(ids: jnp.ndarray, n_passes: int, pool: int) -> jnp.ndarray:
+    """[n_passes, P]: where a looped model's layer array holds the pool's
+    pages `ids` [P] for every pass (pass u's pages lie at + u * pool)."""
+    return ids[None, :] + pool * jnp.arange(n_passes, dtype=ids.dtype)[:, None]
 
 
 @dataclass(frozen=True)
@@ -96,6 +123,7 @@ class StateLayout:
     d_state: int
     d_conv: int
     dtype: str = "bfloat16"
+    n_passes: int = 1  # a looped model: every paged layer has a row a pass
 
     @classmethod
     def of(cls, model_config, page_size: int, num_pages: int, lanes: int,
@@ -114,7 +142,8 @@ class StateLayout:
             window=model_config.sliding_window if rows("window_kv") else 0,
             d_inner=model_config.mamba_d_inner,
             d_state=model_config.mamba_d_state,
-            d_conv=model_config.mamba_d_conv, dtype=dtype)
+            d_conv=model_config.mamba_d_conv, dtype=dtype,
+            n_passes=model_config.n_passes)
 
     @property
     def _itemsize(self) -> int:
@@ -129,9 +158,14 @@ class StateLayout:
         """Ring pages per lane and window layer."""
         return self.window // self.ring_page_size if self.window else 0
 
+    @property
+    def cache_rows(self) -> int:
+        """K/V rows of the pool a token holds: one per (pass, paged layer)."""
+        return self.n_passes * len(self.paged_layers)
+
     def token_bytes(self) -> int:
-        """Bytes of shared K/V one token of context holds."""
-        return (len(self.paged_layers) * 2 * self.kv_heads * self.head_dim
+        """Bytes of shared K/V one token of context holds, all its rows."""
+        return (self.cache_rows * 2 * self.kv_heads * self.head_dim
                 * self._itemsize)
 
     def lane_bytes(self) -> dict:
@@ -355,7 +389,8 @@ def append_token_kv(
 # quantized layer cache travels as the tuple (pages_int8, scales).
 
 def init_kv_scales(config: KVCacheConfig, sharding=None) -> List[jnp.ndarray]:
-    shape = (config.num_pages, 2, config.n_kv_heads, config.page_size)
+    shape = (config.n_passes * config.num_pages, 2, config.n_kv_heads,
+             config.page_size)
     make = device_filler(sharding, shape, jnp.float32, 1)
     return [make() for _ in range(config.n_layers)]
 

@@ -39,6 +39,20 @@ ENGINE_QUEUE_DEPTH = Gauge(
 ENGINE_KV_PAGES_FREE = Gauge(
     "engine_kv_pages_free", "free KV cache pages", ["model_name"]
 )
+# The pool and what a token holds of it (engine/kvcache.py): a looped model
+# keeps a K/V row for every (pass, layer), so one token's bytes, and with
+# them the tokens a pool seats, follow passes x layers.
+ENGINE_KV_PAGES_TOTAL = Gauge(
+    "engine_kv_pages_total",
+    "pages of the pool a sequence can be given (the null page left out)",
+    ["model_name"],
+)
+ENGINE_KV_TOKEN_BYTES = Gauge(
+    "engine_kv_token_bytes",
+    "bytes of paged K/V one token of context holds, all its cache rows "
+    "(passes x layers)",
+    ["model_name"],
+)
 # Per-lane state by kind (engine/kvcache.StateLayout): what the seated lanes
 # hold now.  `kind`: shared_kv (pages of the pool), window_kv (window
 # layers' rings), ssm and conv (recurrent layers' slots).
@@ -332,6 +346,22 @@ ENGINE_DISPATCH_SHAPE = Counter(
     "pair that holds it) | compiled (no loaded pair holds it: the needed "
     "pair, new to this engine)",
     ["model_name", "fit"],
+)
+# What the forward did, counted at launch from the dispatch's plan
+# (docs/observability.md "Passes and context").  A forward step is one run
+# of the model over a dispatch's tokens: the packed step and each decode
+# step of a dispatch.
+ENGINE_LAYER_PASSES = Counter(
+    "engine_layer_passes_total",
+    "passes of the layer stack run: forward steps x the model's passes "
+    "(1 a step for a model that is not looped)",
+    ["model_name"],
+)
+ENGINE_KV_CONTEXT_TOKENS = Counter(
+    "engine_kv_context_tokens_total",
+    "sum over a dispatch's decode steps of the cached tokens its live "
+    "lanes attend to: the work of decode attention, in tokens a cache row",
+    ["model_name"],
 )
 ENGINE_FIRST_TOKEN_DISPATCHES = Summary(
     "engine_first_token_dispatches",

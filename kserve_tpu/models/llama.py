@@ -72,13 +72,16 @@ _WRITES = {"attention": "paged_kv", "window_attention": "window_kv",
 
 #: model_type values LlamaConfig's family knobs describe
 _LLAMA_MODEL_TYPES = (None, "llama", "mistral", "mixtral", "qwen2", "qwen3",
-                      "gemma2")
+                      "gemma2", "ouro")
 #: config.json keys of mixers those knobs cannot express
 _FOREIGN_MIXER_KEYS = (
     "mb_per_layer", "ssm_cfg", "state_size", "conv_kernel", "mamba_d_state",
     "kv_lora_rank", "q_lora_rank", "layers_block_type", "attn_layer_indices",
     "full_attention_interval", "linear_num_value_heads", "n_routed_experts",
-    "num_experts", "moe_intermediate_size")
+    "num_experts", "moe_intermediate_size",
+    # a stack run several times a token: served as one pass it would give
+    # wrong logits and no error
+    "total_ut_steps")
 
 
 @dataclass(frozen=True)
@@ -169,10 +172,24 @@ class LlamaConfig:
     mamba_d_state: int = 0
     mamba_d_conv: int = 0
     mamba_dt_rank: int = 0
+    # ---- looped models (arXiv:2510.25741): the stack runs n_passes times
+    # a token over ONE set of weights, the final norm closes every pass and
+    # every (pass, layer) keeps K/V rows of its own ----
+    n_passes: int = 1
+    # the exit gate's threshold: at >= 1 the exit distribution reaches 1
+    # only at the last pass, so every token runs every pass and the gate
+    # (params "exit_gate_w" / "exit_gate_b") is loaded but not evaluated
+    early_exit_threshold: float = 1.0
 
     def __post_init__(self):
         if self.head_dim is None:
             self.head_dim = self.hidden_size // self.n_heads
+        if self.n_passes < 1:
+            raise ValueError(f"n_passes must be >= 1, got {self.n_passes}")
+        if self.n_passes > 1 and self.mixer_kinds is not None:
+            raise NotImplementedError(
+                "n_passes > 1 over a hybrid per-layer table: per-lane state "
+                "of the other mixer kinds has no rows per pass")
         if self.layer_types is not None:
             self.layer_types = tuple(self.layer_types)
         if self.mixer_kinds is not None:
@@ -203,6 +220,10 @@ class LlamaConfig:
                         f"layer {i} ({kind}) has no {wanted} layer before it")
             table.append(LayerSpec(kind, _WRITES[kind], reads))
         return tuple(table)
+
+    @property
+    def is_looped(self) -> bool:
+        return self.n_passes > 1
 
     @property
     def cache_kv_heads(self) -> int:
@@ -334,7 +355,8 @@ class LlamaConfig:
         if model_type == "phi4flash":
             return _phi4flash_config(cfg)
         if model_type not in _LLAMA_MODEL_TYPES:
-            foreign = [k for k in _FOREIGN_MIXER_KEYS if k in cfg]
+            foreign = [k for k in _FOREIGN_MIXER_KEYS if k in cfg
+                       and not (k == "total_ut_steps" and int(cfg[k]) <= 1)]
             if foreign:
                 raise ValueError(
                     f"model_type {model_type!r} is not supported: its keys "
@@ -380,7 +402,8 @@ class LlamaConfig:
                 cfg.get("hidden_act", cfg.get("hidden_activation"))),
             norm_plus_one=cfg.get("model_type") == "gemma2",
             embed_scale=cfg.get("model_type") == "gemma2",
-            sandwich_norms=cfg.get("model_type") == "gemma2",
+            # Ouro's layer is the same four-norm layer, with plain RMSNorm
+            sandwich_norms=cfg.get("model_type") in ("gemma2", "ouro"),
             attn_logit_softcap=cfg.get("attn_logit_softcapping") or 0.0,
             logit_softcap=cfg.get("final_logit_softcapping") or 0.0,
             query_pre_attn_scalar=cfg.get("query_pre_attn_scalar"),
@@ -402,6 +425,11 @@ class LlamaConfig:
             # MixtralForCausalLM fields
             n_experts=cfg.get("num_local_experts", 0),
             n_experts_per_tok=cfg.get("num_experts_per_tok", 2),
+            # Ouro (model_type "ouro"): the stack runs total_ut_steps times
+            n_passes=(int(cfg.get("total_ut_steps", 1))
+                      if model_type == "ouro" else 1),
+            early_exit_threshold=(float(cfg.get("early_exit_threshold", 1.0))
+                                  if model_type == "ouro" else 1.0),
         )
 
 
@@ -482,6 +510,17 @@ def init_params(config: LlamaConfig, rng: jax.Array, scale: float = 0.02,
         raise NotImplementedError("weight_quant over MoE experts")
     dense = (lambda key, shape: dense_q(key, shape)) if quant else dense_f32
     norm_init = jnp.zeros if config.norm_plus_one else jnp.ones
+    # A looped model's branches start at 1 / sqrt(2 L) (the residual
+    # scaling of GPT-2's and DeepNet's inits: the 2 L branches of a pass
+    # add the variance the stream entered it with).  At gain 1 a pass of
+    # random layers multiplies a rounding error ~2.5 times, so four passes
+    # in bf16 leave the float32 reference by as much as int8 weights do
+    # (correlation of logits 0.955; 0.9999 after one pass: PERF.md section
+    # 6, PR 35) and no tolerance tells a precision from a fault.
+    post_norm_init = norm_init
+    if config.is_looped:
+        def post_norm_init(shape, dtype):
+            return jnp.full(shape, (2 * config.n_layers) ** -0.5, dtype)
 
     def make_layer(window: int, key):
         k = jax.random.split(key, 8)
@@ -511,9 +550,9 @@ def init_params(config: LlamaConfig, rng: jax.Array, scale: float = 0.02,
             layer["q_norm"] = jnp.ones((hd,), dtype)
             layer["k_norm"] = jnp.ones((hd,), dtype)
         if config.sandwich_norms:
-            # Gemma norm weights init to ZERO ((1+w) multiplies by 1)
-            layer["post_attn_norm"] = jnp.zeros((h,), dtype)
-            layer["post_mlp_norm"] = jnp.zeros((h,), dtype)
+            # Gemma's init to ZERO ((1+w) multiplies by 1), plain ones to 1
+            layer["post_attn_norm"] = post_norm_init((h,), dtype)
+            layer["post_mlp_norm"] = post_norm_init((h,), dtype)
         if config.sliding_window > 0:
             layer["attn_window"] = jnp.asarray(window, jnp.int32)
         return layer
@@ -532,6 +571,12 @@ def init_params(config: LlamaConfig, rng: jax.Array, scale: float = 0.02,
         }
         if not config.tie_word_embeddings:
             top["lm_head"] = dense(head_key, (h, config.vocab_size))
+        if config.is_looped:
+            # the exit gate, Linear(h -> 1): present so that a checkpoint
+            # loads; not evaluated at early_exit_threshold >= 1
+            top["exit_gate_w"] = dense_f32(
+                jax.random.fold_in(head_key, 1), (h, 1))
+            top["exit_gate_b"] = jnp.zeros((1,), dtype)
         return top
 
     # all layers share shapes and shardings: one compiled program per
@@ -599,7 +644,8 @@ def _mlp(layer: Params, x: jnp.ndarray, config: LlamaConfig, onehot=None) -> jnp
 
 @jax.named_scope("lm_head")
 def _logits(params: Params, x: jnp.ndarray, config: LlamaConfig) -> jnp.ndarray:
-    x = _norm(x, params["final_norm"], config)
+    if not config.is_looped:  # a looped model's passes each end in it
+        x = _norm(x, params["final_norm"], config)
     head = params.get("lm_head")
     if head is None:
         logits = tied_head_matmul(x, params["embed"]).astype(jnp.float32)
@@ -655,6 +701,49 @@ def _adapter_onehot(params: Params, adapter_ids, batch: int):
                 adapter_ids = jnp.full((batch,), -1, jnp.int32)
             return jax.nn.one_hot(adapter_ids, n_a, dtype=jnp.float32)
     return None
+
+
+def _run_passes(params: Params, config: LlamaConfig, x, kv_pages, table,
+                stack):
+    """Run the layer stack `config.n_passes` times over one set of weights.
+
+    `stack(x, kv_pages, table) -> (x, kv_pages)` is one pass over the layers
+    with `table` as the page table (or page ids) its K/V writes and reads go
+    through.  One pass is that call and nothing else: the program a
+    one-pass model lowers to does not change.
+
+    A looped model runs a `lax.fori_loop` over the passes IN the program
+    (one traced stack, not n_passes of them).  The cache of every layer
+    holds n_passes x the pool's pages: pass u owns pages
+    [u * pool, (u + 1) * pool), so the K/V row of (pass u, layer l) is
+    layer l's array read through `table + u * pool` (the kernels address
+    pages through the table: nothing is sliced or copied), written in
+    place by the loop's carry.  A padded table entry (page 0) becomes pass
+    u's own null page, which no sequence is ever given.  The final norm
+    closes EVERY pass, the last included, so `_logits` applies none to a
+    looped model.  The body is the same for every pass and reads nothing
+    of the loop's index (the pass's table is carried): a body that chose
+    by the index (`where(u > 0, norm(x), x)` at its top) computed other
+    logits on the TPU than on the CPU and than the same passes unrolled
+    (PERF.md section 6, PR 35).  The exit gate is not evaluated: the
+    engine refuses early_exit_threshold < 1
+    (engine.resolve_hybrid_serving)."""
+    if not config.is_looped:
+        return stack(x, kv_pages, table)
+    first = kv_pages[0]
+    pool = (first[0] if isinstance(first, tuple) else first).shape[0] \
+        // config.n_passes
+
+    def one_pass(_, carry):
+        x, kv_pages, table = carry
+        with jax.named_scope("loop_pass"):
+            x, kv_pages = stack(x, kv_pages, table)
+            return (_norm(x, params["final_norm"], config), kv_pages,
+                    table + pool)
+
+    x, kv_pages, _ = jax.lax.fori_loop(
+        0, config.n_passes, one_pass, (x, kv_pages, table))
+    return x, kv_pages
 
 
 def transformer_block(
@@ -724,15 +813,21 @@ def prefill(
     onehot = _adapter_onehot(params, adapter_ids, B)
     positions = jnp.arange(T, dtype=jnp.int32)[None, :].repeat(B, axis=0)
     x = _embed(params, tokens, config)
-    new_pages = []
-    for layer, pages in zip(params["layers"], kv_pages):
-        x, k, v = transformer_block(
-            layer, x, positions, valid_len, config,
-            onehot=onehot, attention_fn=attention_fn,
-        )
-        # scatter the whole batch's K/V into its pages in one op
-        pages = write_prompt_kv_batch(pages, k, v, page_ids, valid_len, page_size)
-        new_pages.append(pages)
+
+    def stack(x, kv_pages, page_ids):
+        new_pages = []
+        for layer, pages in zip(params["layers"], kv_pages):
+            x, k, v = transformer_block(
+                layer, x, positions, valid_len, config,
+                onehot=onehot, attention_fn=attention_fn,
+            )
+            # scatter the whole batch's K/V into its pages in one op
+            pages = write_prompt_kv_batch(
+                pages, k, v, page_ids, valid_len, page_size)
+            new_pages.append(pages)
+        return x, new_pages
+
+    x, new_pages = _run_passes(params, config, x, kv_pages, page_ids, stack)
     last = jnp.maximum(valid_len - 1, 0)
     x_last = x[jnp.arange(B), last]  # [B, h]
     return _logits(params, x_last[:, None], config)[:, 0], new_pages
@@ -806,13 +901,18 @@ def prefill_chunk(
     B, C = tokens.shape
     onehot = _adapter_onehot(params, adapter_ids, B)
     x = _embed(params, tokens, config)
-    new_pages = []
-    for layer, pages in zip(params["layers"], kv_pages):
-        x, pages = chunk_transformer_block(
-            layer, pages, x, chunk_start, valid_len, page_ids, page_size,
-            config, onehot=onehot,
-        )
-        new_pages.append(pages)
+
+    def stack(x, kv_pages, page_ids):
+        new_pages = []
+        for layer, pages in zip(params["layers"], kv_pages):
+            x, pages = chunk_transformer_block(
+                layer, pages, x, chunk_start, valid_len, page_ids, page_size,
+                config, onehot=onehot,
+            )
+            new_pages.append(pages)
+        return x, new_pages
+
+    x, new_pages = _run_passes(params, config, x, kv_pages, page_ids, stack)
     last = jnp.maximum(valid_len - 1, 0)
     x_last = x[jnp.arange(B), last]  # [B, h]
     return _logits(params, x_last[:, None], config)[:, 0], new_pages
@@ -846,48 +946,54 @@ def decode_step(
     x = _embed(params, tokens, config)[:, None, :]  # [B,1,h]
     positions = pos[:, None]
     seq_lens = jnp.where(active, pos + 1, 0)
-    new_pages = []
-    for layer, pages in zip(params["layers"], kv_pages):
-        residual = x
-        h = _norm(x, layer["attn_norm"], config)
-        with jax.named_scope("attention"):
-            q, k, v = _qkv(layer, h, config, onehot)
-            q = apply_rope(q, positions, config.rope_theta, config.rope_scaling)
-            k = apply_rope(k, positions, config.rope_theta, config.rope_scaling)
-            pages = append_token_kv(
-                pages, k[:, 0], v[:, 0], page_table, pos, active, page_size
-            )
-            window = layer.get("attn_window")
-            if attention_fn is not None:
-                attn = attention_fn(q[:, 0], pages, page_table, seq_lens,
-                                    window if window is not None
-                                    else jnp.asarray(0, jnp.int32))
-            else:
-                attn = paged_attention(
-                    q[:, 0],
-                    pages,
-                    page_table,
-                    seq_lens,
-                    logit_softcap=config.attn_logit_softcap,
-                    use_pallas=use_pallas,
-                    scale=config.attn_scale,
-                    window=window,
+
+    def stack(x, kv_pages, page_table):
+        new_pages = []
+        for layer, pages in zip(params["layers"], kv_pages):
+            residual = x
+            h = _norm(x, layer["attn_norm"], config)
+            with jax.named_scope("attention"):
+                q, k, v = _qkv(layer, h, config, onehot)
+                q = apply_rope(q, positions, config.rope_theta, config.rope_scaling)
+                k = apply_rope(k, positions, config.rope_theta, config.rope_scaling)
+                pages = append_token_kv(
+                    pages, k[:, 0], v[:, 0], page_table, pos, active, page_size
                 )
-            attn_flat = attn.reshape(B, 1, -1)
-            attn = _maybe_add(
-                dense(attn_flat, layer["wo"]),
-                lora_delta(layer.get("lora"), "wo", attn_flat, onehot),
-            )
-        if config.sandwich_norms:
-            attn = _norm(attn, layer["post_attn_norm"], config)
-        x = residual + attn
-        residual = x
-        h = _norm(x, layer["mlp_norm"], config)
-        out = _mlp(layer, h, config, onehot)
-        if config.sandwich_norms:
-            out = _norm(out, layer["post_mlp_norm"], config)
-        x = residual + out
-        new_pages.append(pages)
+                window = layer.get("attn_window")
+                if attention_fn is not None:
+                    attn = attention_fn(q[:, 0], pages, page_table, seq_lens,
+                                        window if window is not None
+                                        else jnp.asarray(0, jnp.int32))
+                else:
+                    attn = paged_attention(
+                        q[:, 0],
+                        pages,
+                        page_table,
+                        seq_lens,
+                        logit_softcap=config.attn_logit_softcap,
+                        use_pallas=use_pallas,
+                        scale=config.attn_scale,
+                        window=window,
+                    )
+                attn_flat = attn.reshape(B, 1, -1)
+                attn = _maybe_add(
+                    dense(attn_flat, layer["wo"]),
+                    lora_delta(layer.get("lora"), "wo", attn_flat, onehot),
+                )
+            if config.sandwich_norms:
+                attn = _norm(attn, layer["post_attn_norm"], config)
+            x = residual + attn
+            residual = x
+            h = _norm(x, layer["mlp_norm"], config)
+            out = _mlp(layer, h, config, onehot)
+            if config.sandwich_norms:
+                out = _norm(out, layer["post_mlp_norm"], config)
+            x = residual + out
+            new_pages.append(pages)
+        return x, new_pages
+
+    x, new_pages = _run_passes(
+        params, config, x, kv_pages, page_table, stack)
     return _logits(params, x, config)[:, 0], new_pages
 
 
@@ -943,47 +1049,53 @@ def forward_ragged(
     onehot = _adapter_onehot(params, token_adapters, T)
     x = _embed(params, tokens, config)[:, None, :]  # [T, 1, h]
     positions = token_pos[:, None]
-    new_pages = []
-    for layer, pages in zip(params["layers"], kv_pages):
-        residual = x
-        h = _norm(x, layer["attn_norm"], config)
-        with jax.named_scope("attention"):
-            q, k, v = _qkv(layer, h, config, onehot)
-            q = apply_rope(q, positions, config.rope_theta, config.rope_scaling)
-            k = apply_rope(k, positions, config.rope_theta, config.rope_scaling)
-            pages = write_ragged_kv(
-                pages, k[:, 0], v[:, 0], page_table, token_seq, token_pos,
-                page_size,
-            )
-            window = layer.get("attn_window")
-            if attention_fn is not None:
-                attn = attention_fn(
-                    q[:, 0], pages, page_table, q_start, q_len, kv_start,
-                    window if window is not None else jnp.asarray(0, jnp.int32))
-            else:
-                attn = ragged_paged_attention(
-                    q[:, 0], pages, page_table, q_start, q_len, kv_start,
-                    logit_softcap=config.attn_logit_softcap,
-                    use_pallas=use_pallas,
-                    scale=config.attn_scale,
-                    window=window,
-                    dense_stride=dense_stride,
+
+    def stack(x, kv_pages, page_table):
+        new_pages = []
+        for layer, pages in zip(params["layers"], kv_pages):
+            residual = x
+            h = _norm(x, layer["attn_norm"], config)
+            with jax.named_scope("attention"):
+                q, k, v = _qkv(layer, h, config, onehot)
+                q = apply_rope(q, positions, config.rope_theta, config.rope_scaling)
+                k = apply_rope(k, positions, config.rope_theta, config.rope_scaling)
+                pages = write_ragged_kv(
+                    pages, k[:, 0], v[:, 0], page_table, token_seq, token_pos,
+                    page_size,
                 )
-            attn_flat = attn.reshape(T, 1, -1)
-            attn = _maybe_add(
-                dense(attn_flat, layer["wo"]),
-                lora_delta(layer.get("lora"), "wo", attn_flat, onehot),
-            )
-        if config.sandwich_norms:
-            attn = _norm(attn, layer["post_attn_norm"], config)
-        x = residual + attn
-        residual = x
-        h = _norm(x, layer["mlp_norm"], config)
-        out = _mlp(layer, h, config, onehot)
-        if config.sandwich_norms:
-            out = _norm(out, layer["post_mlp_norm"], config)
-        x = residual + out
-        new_pages.append(pages)
+                window = layer.get("attn_window")
+                if attention_fn is not None:
+                    attn = attention_fn(
+                        q[:, 0], pages, page_table, q_start, q_len, kv_start,
+                        window if window is not None else jnp.asarray(0, jnp.int32))
+                else:
+                    attn = ragged_paged_attention(
+                        q[:, 0], pages, page_table, q_start, q_len, kv_start,
+                        logit_softcap=config.attn_logit_softcap,
+                        use_pallas=use_pallas,
+                        scale=config.attn_scale,
+                        window=window,
+                        dense_stride=dense_stride,
+                    )
+                attn_flat = attn.reshape(T, 1, -1)
+                attn = _maybe_add(
+                    dense(attn_flat, layer["wo"]),
+                    lora_delta(layer.get("lora"), "wo", attn_flat, onehot),
+                )
+            if config.sandwich_norms:
+                attn = _norm(attn, layer["post_attn_norm"], config)
+            x = residual + attn
+            residual = x
+            h = _norm(x, layer["mlp_norm"], config)
+            out = _mlp(layer, h, config, onehot)
+            if config.sandwich_norms:
+                out = _norm(out, layer["post_mlp_norm"], config)
+            x = residual + out
+            new_pages.append(pages)
+        return x, new_pages
+
+    x, new_pages = _run_passes(
+        params, config, x, kv_pages, page_table, stack)
     if logits_at is not None:
         x_sel = x[logits_at, 0]  # [N, h]
         return _logits(params, x_sel[:, None], config)[:, 0], new_pages
@@ -1194,12 +1306,21 @@ _HF_LAYER_MAP = {
     # loader remaps below when the config is sandwich
     "pre_feedforward_layernorm.weight": "pre_ffn_norm_hf",
     "post_feedforward_layernorm.weight": "post_mlp_norm",
+    # Ouro's sandwich norms (assumed names, benchmark/configs/ouro-2.6b.json):
+    # input_layernorm_2 follows the mixer, post_attention_layernorm_2 the
+    # feed-forward; post_attention_layernorm stays the pre-ffn norm
+    "input_layernorm_2.weight": "post_attn_norm",
+    "post_attention_layernorm_2.weight": "post_mlp_norm",
     "mlp.gate_proj.weight": "w_gate",
     "mlp.up_proj.weight": "w_up",
     "mlp.down_proj.weight": "w_down",
 }
 
 _TRANSPOSED = {"wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down"}
+
+#: a looped model's exit gate, Linear(hidden -> 1) with bias
+_HF_EXIT_GATE = {"model.early_exit_gate.weight": "exit_gate_w",
+                 "model.early_exit_gate.bias": "exit_gate_b"}
 
 
 def load_hf_weights_streamed(model_dir: str, config: LlamaConfig,
@@ -1287,6 +1408,9 @@ def load_hf_weights_streamed(model_dir: str, config: LlamaConfig,
                 params["lm_head"] = (
                     to_jnp_q(arr, True) if quant else to_jnp(arr, True))
             return False
+        if name in _HF_EXIT_GATE and config.is_looped:
+            params[_HF_EXIT_GATE[name]] = to_jnp(arr, arr.ndim == 2)
+            return False
         m = layer_re.match(name)
         if m is None:
             return False  # rotary inv_freq etc.: derived, never loaded
@@ -1338,12 +1462,14 @@ def load_hf_weights_streamed(model_dir: str, config: LlamaConfig,
         raise ValueError(
             f"checkpoint is missing MoE experts for (layer, proj): {missing[:4]}")
     for i, layer in enumerate(params["layers"]):
-        if config.sandwich_norms:
+        if config.sandwich_norms and "pre_ffn_norm_hf" in layer:
+            # Gemma-2's names (Ouro's map straight to ours)
             layer["post_attn_norm"] = layer.pop("mlp_norm")
             layer["mlp_norm"] = layer.pop("pre_ffn_norm_hf")
-        else:
+        elif not config.sandwich_norms:
             layer.pop("pre_ffn_norm_hf", None)
             layer.pop("post_mlp_norm", None)
+            layer.pop("post_attn_norm", None)
         if config.sliding_window > 0:
             layer["attn_window"] = jnp.asarray(
                 config.layer_window(i), jnp.int32)
@@ -1407,6 +1533,9 @@ def load_hf_weights(model_dir: str, config: LlamaConfig,
             to_jnp_q(tensors["lm_head.weight"], True) if quant
             else to_jnp(tensors["lm_head.weight"], True)
         )
+    if config.is_looped:
+        for hf_name, ours in _HF_EXIT_GATE.items():
+            params[ours] = to_jnp(tensors[hf_name], tensors[hf_name].ndim == 2)
     for i in range(config.n_layers):
         prefix = f"model.layers.{i}."
         layer: Params = {}
@@ -1417,15 +1546,17 @@ def load_hf_weights(model_dir: str, config: LlamaConfig,
                     layer[ours] = to_jnp_q(tensors[key], True)
                 else:
                     layer[ours] = to_jnp(tensors[key], ours in _TRANSPOSED)
-        if config.sandwich_norms:
+        if config.sandwich_norms and "pre_ffn_norm_hf" in layer:
             # Gemma-2 norm remap: HF post_attention_layernorm is the
             # POST-attn norm (our "post_attn_norm"); pre_feedforward is
-            # the pre-ffn norm (our "mlp_norm" slot)
+            # the pre-ffn norm (our "mlp_norm" slot).  Ouro's names map
+            # straight to ours.
             layer["post_attn_norm"] = layer.pop("mlp_norm")
             layer["mlp_norm"] = layer.pop("pre_ffn_norm_hf")
-        else:
+        elif not config.sandwich_norms:
             layer.pop("pre_ffn_norm_hf", None)
             layer.pop("post_mlp_norm", None)
+            layer.pop("post_attn_norm", None)
         if config.sliding_window > 0:
             layer["attn_window"] = jnp.asarray(
                 config.layer_window(i), jnp.int32)

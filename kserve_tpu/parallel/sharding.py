@@ -122,6 +122,9 @@ def param_pspecs(config: LlamaConfig) -> Dict[str, Any]:
     }
     if not config.tie_word_embeddings:
         specs["lm_head"] = P(None, MODEL_AXIS)  # logits vocab-sharded -> gather
+    if config.is_looped:
+        # the exit gate, Linear(h -> 1): tiny, replicated
+        specs.update({"exit_gate_w": P(), "exit_gate_b": P()})
     return specs
 
 

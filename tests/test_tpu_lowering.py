@@ -501,3 +501,59 @@ class TestHybridDecodeKernelCalls:
                 jnp.zeros((8, 2, 2, 16, 64), jnp.bfloat16),
                 jnp.zeros((8, 4), jnp.int32), jnp.zeros((8,), jnp.int32),
                 scale=0.1)
+
+
+class TestLoopedMixedProgram:
+    """A looped model's `mixed` program at the `ouro-2.6b.eval-sat` cell's
+    shape (12 lanes, 16/16 heads x 128, 300 pages of 16 tokens a pass, 24-page
+    tables; two layers and a narrow MLP: the loop and the attention shapes
+    are the cell's, the rest only has to lower): the passes are loops IN the
+    program (the packed step's, the decode steps', and the decode steps'
+    own), every layer's kernels are traced once and not once a pass, and the
+    gate keeps the kernel at every width of the cell."""
+
+    def _lowered(self, monkeypatch, passes):
+        import dataclasses
+
+        from kserve_tpu.engine.types import EngineConfig
+        from kserve_tpu.models import llama
+
+        lanes, ps, pages = 12, 16, 300
+        mc = dataclasses.replace(
+            llama.LlamaConfig.from_hf_config({
+                "model_type": "ouro", "vocab_size": 1024, "hidden_size": 256,
+                "intermediate_size": 512, "num_hidden_layers": 2,
+                "num_attention_heads": 16, "num_key_value_heads": 16,
+                "head_dim": 128, "rms_norm_eps": 1e-6, "rope_theta": 1000000,
+                "total_ut_steps": passes, "early_exit_threshold": 1}),
+            dtype="bfloat16")
+        cfg = EngineConfig(
+            max_batch_size=lanes, page_size=ps, num_pages=pages,
+            max_pages_per_seq=24, max_prefill_len=256,
+            prefill_buckets=(128, 256), dtype="bfloat16")
+        cache = jax.ShapeDtypeStruct(
+            (passes * pages, 2, mc.n_kv_heads, ps, mc.head_dim), jnp.bfloat16)
+        return _lower_mixed(
+            mc, cfg, [cache] * mc.n_layers, 24, monkeypatch).as_text()
+
+    def test_the_passes_are_loops_in_the_program(self, monkeypatch):
+        import re
+
+        looped = self._lowered(monkeypatch, 4)
+        one = self._lowered(monkeypatch, 1)
+        kernels = re.findall(r'kernel_name = "([a-z_]+)"', looped)
+        # 2 layers: traced once under each loop, whatever the passes
+        assert sorted(kernels) == sorted(
+            re.findall(r'kernel_name = "([a-z_]+)"', one)) == [
+                "paged_attention_decode"] * 2 + ["ragged_paged_attention"] * 2
+        assert looped.count("stablehlo.while") == 3  # passes, steps, passes
+        assert one.count("stablehlo.while") == 1  # the decode steps alone
+        # the cache arrays enter and leave whole: a pass is an offset into
+        # the page table, never a slice of the cache
+        assert not re.findall(r"tensor<300x2x16x16x128xbf16>", looped)
+
+    def test_gate_takes_the_kernel_at_every_width_of_the_cell(self):
+        """128 KB pages (16 rows x 16 tokens x 128, K and V) at 12 lanes."""
+        assert att.pallas_min_pages(128, 16, 16, 12) == 0
+        for width in (8, 16, 24):
+            assert att._should_use_pallas(128, False, width, 12, "tpu", 16, 16)
