@@ -29,6 +29,7 @@ from __future__ import annotations
 import asyncio
 import dataclasses
 import time
+from collections import deque
 from typing import Any, AsyncIterator, Dict, List, Optional, Tuple
 
 import jax
@@ -40,6 +41,8 @@ from ..metrics import (
     ENGINE_BATCH_OCCUPANCY,
     ENGINE_DISPATCH_PHASE_SECONDS,
     ENGINE_DISPATCHES,
+    ENGINE_DISPATCH_DELIVERIES,
+    ENGINE_DISPATCH_DELIVER_SECONDS,
     ENGINE_FIRST_TOKEN_DISPATCHES,
     ENGINE_KV_DISK_BYTES,
     ENGINE_KV_OFFLOAD_BYTES,
@@ -77,6 +80,7 @@ from ..lifecycle.checkpoint import GenerationCheckpoint, GenerationPreempted
 from ..lifecycle.state import ReplicaDrainingError
 from ..models import llama
 from ..observability import (
+    DELIVERIES,
     DISPATCH_COLUMNS,
     PHASES,
     DispatchPhases,
@@ -113,6 +117,7 @@ from .types import (  # noqa: F401 — re-exported: the public engine surface
     EngineWedgedError,
     GenerationOutput,
     _DeadlineFetcher,
+    _Delivery,
     _QueuedRequest,
     _Slot,
 )
@@ -130,10 +135,14 @@ def _device_row(device) -> dict:
 
 
 #: where a dispatch row (observability.DISPATCH_COLUMNS) holds what the
-#: counters are fed from: the program, and the six phases with `wait_lag`
+#: counters are fed from: the program, the six phases with `wait_lag`, and
+#: the seconds and tokens of what the iteration handed to its streams
 _PROGRAM_COLUMN = DISPATCH_COLUMNS.index("program")
 _PHASE_COLUMNS = slice(DISPATCH_COLUMNS.index(PHASES[0]),
                        DISPATCH_COLUMNS.index("wait_lag") + 1)
+_DELIVER_COLUMN = DISPATCH_COLUMNS.index("deliver")
+_DELIVERED_COLUMNS = slice(_DELIVER_COLUMN + 1,
+                           _DELIVER_COLUMN + 1 + len(DELIVERIES))
 
 
 def _refuse_looped(model_config, engine_config) -> None:
@@ -286,6 +295,17 @@ class LLMEngine:
             ENGINE_DISPATCH_PHASE_SECONDS.labels(
                 model_name=metrics_label, phase=phase)
             for phase in (*PHASES, "wait_lag")]
+        # deferred delivery (the `mixed` path): what a routed dispatch's
+        # tokens owe their streams, in the order they were produced, until
+        # the next dispatch is launched; never left standing across an
+        # await of the loop
+        self._undelivered: "deque[_Delivery]" = deque()
+        self._deliveries = [
+            ENGINE_DISPATCH_DELIVERIES.labels(
+                model_name=metrics_label, when=when)
+            for when in DELIVERIES]
+        self._deliver_seconds = ENGINE_DISPATCH_DELIVER_SECONDS.labels(
+            model_name=metrics_label)
         # engine_sampler_dispatches_total by the path a dispatch's batch
         # takes through the sampler (sampling.SAMPLER_PATHS)
         self._sampler_dispatches = {
@@ -1547,12 +1567,15 @@ class LLMEngine:
         except TimeoutError:
             raise self._fetch_timeout() from None
 
-    async def _fetch_async(self, x) -> np.ndarray:
+    async def _fetch_async(self, x, meanwhile=None) -> np.ndarray:
         """_fetch for the decode hot loop: AWAITS the device->host fetch so
         the event loop keeps serving (probes, /admin/drain, the drain
         budget loop, admission rejects) while the chunk computes — a
         blocking wait here starves every other coroutine for the full step
-        duration.  Same fault seam and wedge mapping as _fetch."""
+        duration.  Same fault seam and wedge mapping as _fetch.
+        `meanwhile` runs on this thread once the fetch is with its worker,
+        before the first await: the result is stamped when the device has
+        it, however long `meanwhile` takes."""
         self._fetch_fault_check()
         wd = self._watchdog
         if wd is not None:
@@ -1567,7 +1590,7 @@ class LLMEngine:
 
         try:
             return await self._fetcher.fetch_async(
-                fetch, self.config.step_deadline_s)
+                fetch, self.config.step_deadline_s, meanwhile)
         except TimeoutError:
             raise self._fetch_timeout() from None
         finally:
@@ -1946,7 +1969,9 @@ class LLMEngine:
 
     def _evict_slot(self, slot: _Slot, exc: Exception) -> None:
         """Deliver exc to the slot's stream and release its resources
-        (deferred-free-safe: legal while a chained chunk is in flight)."""
+        (deferred-free-safe: legal while a chained chunk is in flight).
+        Whatever the stream is still owed goes out first."""
+        self._deliver()
         slot.queue.put_nowait(exc)
         if slot.timeline is not None:
             if isinstance(exc, GenerationPreempted):
@@ -2172,14 +2197,29 @@ class LLMEngine:
                     self._wake.clear()
                     await self._wake.wait()
                 else:
-                    # yield to the event loop so streams flush between steps
                     self._phases.mark("yield")
-                    await asyncio.sleep(0)
+                    if not self._undelivered:
+                        # yield to the event loop so streams flush between
+                        # steps; where tokens are still owed, the next
+                        # launch comes first and the streams flush while
+                        # the loop awaits its fetch
+                        await asyncio.sleep(0)
                     self._commit_dispatch()
+            self._deliver()
         except Exception as e:  # noqa: BLE001 — engine death must surface
             logger.exception("engine loop crashed")
             self._loop_error = e
             self._pipeline_busy = False  # frees must not defer post-mortem
+            # a request that finished in the advance has no slot left to
+            # be found below: its last chunk goes out before any error (a
+            # record that raises is logged and the rest still go out: each
+            # call drops what it met)
+            while self._undelivered:
+                try:
+                    self._deliver()
+                except Exception:  # noqa: BLE001 — the loop is already dead
+                    logger.exception(
+                        "a token could not be handed to its stream")
             for slot in self._slots:
                 if slot.request_id is not None:
                     slot.queue.put_nowait(e)
@@ -2216,6 +2256,9 @@ class LLMEngine:
             model_name=self._mlabel, program=row[_PROGRAM_COLUMN]).inc()
         for counter, seconds in zip(self._phase_seconds, row[_PHASE_COLUMNS]):
             counter.inc(seconds)
+        self._deliver_seconds.inc(row[_DELIVER_COLUMN])
+        for counter, tokens in zip(self._deliveries, row[_DELIVERED_COLUMNS]):
+            counter.inc(tokens)
 
     def _drop_expired_waiting(self) -> None:
         """Fail queued requests whose propagated deadline expired before a
@@ -2425,7 +2468,7 @@ class LLMEngine:
             if req.adapter_id < 0:
                 self._prefix_cache.register(req.prompt_ids, pages)
             self._mark_penalty_dirty(idx)
-            self._emit(slot, first_token, *self._lp_for(req.params, lp_np, j))
+            self._advance(slot, first_token, *self._lp_for(req.params, lp_np, j))
         self._admitting = []
         return True
 
@@ -2708,7 +2751,8 @@ class LLMEngine:
 
     def _complete_prefilling(self, idx: int, slot: _Slot, req,
                              first_token: Optional[int],
-                             lp: tuple = (None, None)) -> None:
+                             lp: tuple = (None, None),
+                             defer: bool = False) -> None:
         """A prefilling slot's prompt is fully in the cache: seat it and
         (fresh path) emit its first token.  The single completion path
         shared by the legacy chunk loop (_finish_prefilling, which samples
@@ -2728,7 +2772,7 @@ class LLMEngine:
             len(req.prompt_ids))
         self._seat_fresh(slot, req, pages, first_token)
         self._mark_penalty_dirty(idx)
-        self._emit(slot, first_token, *lp)
+        self._advance(slot, first_token, *lp, defer=defer)
 
     def _finish_prefilling(self, idx: int, slot: _Slot, pf: dict) -> None:
         req = pf["req"]
@@ -2865,7 +2909,7 @@ class LLMEngine:
         self._admitting.remove(entry)
         PROMPT_TOKENS.labels(model_name=self._mlabel).inc(len(req.prompt_ids))
         self._mark_penalty_dirty(idx)
-        self._emit(slot, req.first_token)
+        self._advance(slot, req.first_token)
         return True
 
     def _ensure_pages_at(self, slot: _Slot, base: int, extra: int) -> bool:
@@ -3354,7 +3398,7 @@ class LLMEngine:
                 token = int(chunk_np[s, i])
                 slot.pos += 1
                 slot.generated.append(token)
-                self._emit(slot, token, *self._lp_for(slot.params, lp_np, i, s))
+                self._advance(slot, token, *self._lp_for(slot.params, lp_np, i, s))
                 routed += 1
             if slot.request_id is None:
                 finished_any = True
@@ -3465,6 +3509,7 @@ class LLMEngine:
         phases = self._phases
         phases.mark("plan")
         if self._needs_legacy_step():
+            self._deliver()  # the legacy paths hand over in place
             did = self._advance_prefills()
             active = self._active_decode_slots()
             self._set_occupancy_gauges(active)
@@ -3479,6 +3524,7 @@ class LLMEngine:
         ]
         self._set_occupancy_gauges(self._active_decode_slots())
         if meta is None and not prefilling:
+            self._deliver()  # nothing to launch
             return False
         if self._dense_ok and not prefilling and meta is not None:
             # pure-decode step with the dense/speculative program
@@ -3497,6 +3543,7 @@ class LLMEngine:
                 or s.pos + kp <= self._dense_lane_cap
                 for i, s in enumerate(self._slots)
             ):
+                self._deliver()  # the dense path hands over in place
                 await self._step_dense(meta)
                 return True
         plan = self._plan_ragged(meta, prefilling)
@@ -3537,7 +3584,12 @@ class LLMEngine:
             self._shapes.steps, plan["scan_pos0"], plan["joins"],
             plan["capacity"], decode_steps=self._shapes.steps - 1)
         phases.mark("wait")
-        chunk_np = await self._fetch_async(out)
+        # the fetch is handed to its worker first, so that the result is
+        # stamped when the device has it and a delivery that outlasts the
+        # device shows as wait_lag; then the previous dispatch's tokens go
+        # to their streams while this one runs, and the streams' writes
+        # happen in the turns of the event loop that the await leaves
+        chunk_np = await self._fetch_async(out, self._deliver_overlapped)
         phases.resumed(self._fetch_ready_at)
         self._route_mixed(plan, chunk_np, dispatched_at)
         return True
@@ -3688,11 +3740,15 @@ class LLMEngine:
     def _route_mixed(self, plan: dict, chunk_np: np.ndarray,
                      dispatched_at: float) -> None:
         """Consume one mixed dispatch's [steps, B] tokens: advance chunk
-        cursors, seat lanes whose prompt completed (emitting their first
-        token), then stream each joining lane's scan window.  Slots
-        evicted while the dispatch was in flight (drain) are observed as
-        empty and their speculative tokens discarded — same contract as
-        the legacy _route_chunk."""
+        cursors, seat lanes whose prompt completed (with their first
+        token), then each joining lane's scan window.  Slots evicted
+        while the dispatch was in flight (drain) are observed as empty and
+        their speculative tokens discarded — same contract as the legacy
+        _route_chunk.  This is the ADVANCE: it changes the engine's state
+        from the ids alone and leaves what the tokens owe their streams on
+        _undelivered, for _step_mixed to hand over once the next dispatch
+        is launched; a lane with stop strings is handed its tokens here,
+        and so is every lane when nothing is left to launch."""
         now = self._clock.now()
         step_s = now - dispatched_at
         ENGINE_STEP_DURATION.labels(model_name=self._mlabel).observe(step_s)
@@ -3734,7 +3790,8 @@ class LLMEngine:
                 pf["registered"] = covered // self.config.page_size
             if not final:
                 continue
-            self._complete_prefilling(i, slot, req, int(chunk_np[0, i]))
+            self._complete_prefilling(
+                i, slot, req, int(chunk_np[0, i]), defer=True)
         routed = 0
         for i in sorted(plan["consume"]):
             first_row, n_rows = plan["consume"][i]
@@ -3745,11 +3802,15 @@ class LLMEngine:
                 token = int(chunk_np[s, i])
                 slot.pos += 1
                 slot.generated.append(token)
-                self._emit(slot, token)
+                self._advance(slot, token, defer=True)
                 routed += 1
         GENERATED_TOKENS.labels(model_name=self._mlabel).inc(routed)
         if routed or plan["chunks"]:
             self._note_progress()
+        if not self._has_live_work():
+            # the last lanes finished and nothing waits: no launch will
+            # follow for the delivery to hide behind
+            self._deliver()
 
     # ---------------- dense / speculative decode stepping ----------------
 
@@ -3899,7 +3960,7 @@ class LLMEngine:
                     token = int(toks_np[r, i, j])
                     slot.pos += 1
                     slot.generated.append(token)
-                    self._emit(slot, token)
+                    self._advance(slot, token)
                     routed += 1
                     emitted += 1
                     if slot.request_id is None:
@@ -4000,73 +4061,115 @@ class LLMEngine:
         self._pipeline_busy = False
         self._flush_deferred_frees()
 
-    def _emit(self, slot: _Slot, token: int,
-              logprob: Optional[float] = None,
-              top_logprobs: Optional[List[tuple]] = None):
-        """Stream one token; apply stop conditions."""
-        tl = slot.timeline
-        if tl is not None:
-            first = tl.first_token_at is None
-            tl.mark_token(self._clock.now(), self._phases.serial)
-            if first and tl.dispatches_to_first_token is not None:
-                ENGINE_FIRST_TOKEN_DISPATCHES.labels(
-                    model_name=self._mlabel).observe(
-                        tl.dispatches_to_first_token)
-        n_gen = len(slot.generated)
+    def _advance(self, slot: _Slot, token: int,
+                 logprob: Optional[float] = None,
+                 top_logprobs: Optional[List[tuple]] = None,
+                 defer: bool = False) -> None:
+        """One token: apply stop conditions; stream it.  This is the
+        state's half: every finish the ids decide (EOS under min_tokens /
+        ignore_eos, max_tokens) with its page frees and the lane's reset.
+        The stream's half (_hand_over) runs in place (the legacy and dense
+        paths, which keep their own chain), or with `defer` is left on
+        _undelivered until the next dispatch is launched (_deliver).  A
+        lane with stop strings is never deferred: its text decides whether
+        it goes on."""
         params = slot.params
-        finish_reason = None
+        n_gen = len(slot.generated)
         is_eos = (
             token == self.tokenizer.eos_token_id
             and not params.ignore_eos
             and n_gen > params.min_tokens
         )
-        delta = "" if is_eos else slot.detok.push(token)
-        text = slot.detok.text
         if is_eos:
             finish_reason = "stop"
         elif n_gen >= params.max_tokens:
             finish_reason = "length"
         else:
-            for stop in slot.stop_texts:
-                if stop and stop in text:
-                    cut = text.index(stop)
-                    delta = delta[: max(0, len(delta) - (len(text) - cut))]
-                    finish_reason = "stop"
-                    break
-        out = GenerationOutput(
-            token_id=token,
-            text_delta=delta,
-            finished=finish_reason is not None,
-            finish_reason=finish_reason,
-            num_generated=n_gen,
-            num_prompt_tokens=slot.prompt_len,
-            cumulative_text=text,
-            logprob=logprob,
-            top_logprobs=top_logprobs,
-        )
-        slot.queue.put_nowait(out)
+            finish_reason = None
+        owed = _Delivery(slot, token, finish_reason, is_eos,
+                         self._phases.serial, logprob, top_logprobs)
+        if defer and not slot.stop_texts:
+            self._undelivered.append(owed)
+        else:
+            finish_reason = self._hand_over(owed)
+            self._phases.delivered("inline", 1)
         if finish_reason is not None:
-            self._record_terminal(slot.timeline, finish_reason)
             self._free_pages(slot.pages)
             slot.reset()
             self._mark_penalty_dirty(self._slots.index(slot))
             self._wake.set()
 
     def _finish(self, slot: _Slot, reason: str):
-        out = GenerationOutput(
-            token_id=-1,
-            text_delta="",
-            finished=True,
-            finish_reason=reason,
-            num_generated=len(slot.generated),
-            num_prompt_tokens=slot.prompt_len,
-            cumulative_text=slot.detok.text,
-        )
-        slot.queue.put_nowait(out)
-        self._record_terminal(slot.timeline, reason)
+        """Close a lane's stream without a token.  The closing chunk goes
+        behind whatever is still owed (the lane's own tokens may be)."""
+        owed = _Delivery(slot, -1, reason, False, self._phases.serial)
+        if self._undelivered:
+            self._undelivered.append(owed)
+        else:
+            self._hand_over(owed)
+            self._phases.delivered("inline", 1)
         self._free_pages(slot.pages)
         slot.reset()
         self._mark_penalty_dirty(self._slots.index(slot))
+
+    def _hand_over(self, owed: _Delivery) -> Optional[str]:
+        """The stream's half of one token: the timeline's stamp (this
+        reading of the clock, the PRODUCING dispatch's serial), the text,
+        the stop strings, the output on the request's queue.  Returns the
+        finish reason, which only here can come from a stop string."""
+        tl = owed.timeline
+        closing = owed.token < 0  # _finish: no token, the last chunk
+        if tl is not None and not closing:
+            first = tl.first_token_at is None
+            tl.mark_token(self._clock.now(), owed.serial)
+            if first and tl.dispatches_to_first_token is not None:
+                ENGINE_FIRST_TOKEN_DISPATCHES.labels(
+                    model_name=self._mlabel).observe(
+                        tl.dispatches_to_first_token)
+        detok = owed.detok
+        delta = "" if closing or owed.is_eos else detok.push(owed.token)
+        text = detok.text
+        finish_reason = owed.finish_reason
+        if finish_reason is None:
+            for stop in owed.stops:
+                if stop and stop in text:
+                    cut = text.index(stop)
+                    delta = delta[: max(0, len(delta) - (len(text) - cut))]
+                    finish_reason = "stop"
+                    break
+        owed.queue.put_nowait(GenerationOutput(
+            token_id=owed.token,
+            text_delta=delta,
+            finished=finish_reason is not None,
+            finish_reason=finish_reason,
+            num_generated=owed.n_generated,
+            num_prompt_tokens=owed.n_prompt,
+            cumulative_text=text,
+            logprob=owed.logprob,
+            top_logprobs=owed.top_logprobs,
+        ))
+        if finish_reason is not None:
+            self._record_terminal(tl, finish_reason)
+        return finish_reason
+
+    def _deliver(self, when: str = "inline") -> None:
+        """Hand over everything still owed, in the order it was produced.
+        `overlapped` from the one call that follows a launch; every other
+        caller is about to put something else on a stream, or has nothing
+        to launch.  A record is dropped before it is handed over, so that
+        one that raises is not met again by the crash handler's call."""
+        owed = self._undelivered
+        if not owed:
+            return
+        started = self._clock.now()
+        tokens = len(owed)
+        with self._phases.span("deliver"):
+            while owed:
+                self._hand_over(owed.popleft())
+        self._phases.delivered(when, tokens, self._clock.now() - started)
+
+    def _deliver_overlapped(self) -> None:
+        self._deliver("overlapped")
 
     def _next_step(self) -> int:
         self._step_counter += 1

@@ -290,18 +290,22 @@ class _DeadlineFetcher:
             raise TimeoutError(f"fetch exceeded {timeout_s}s")
         return self._unbox(box)
 
-    async def fetch_async(self, fn, timeout_s: float):
+    async def fetch_async(self, fn, timeout_s: float, meanwhile=None):
         """fetch() for the decode hot loop: the event-loop thread must not
         sit in a threading wait for device compute — that starves every
         other coroutine (readiness probes, /admin/drain, the drain budget
         loop, admission 503s) for the full duration of the step.  The
         worker signals completion back through call_soon_threadsafe so the
-        loop keeps serving while the chunk computes."""
+        loop keeps serving while the chunk computes.  `meanwhile` is host
+        work for the caller's thread once the worker has the thunk and
+        before the result is awaited (the mixed step's deferred delivery)."""
         self._check_open()
         loop = asyncio.get_running_loop()
         event = asyncio.Event()
         box: list = []
         self._q.put((fn, box, _LoopNotify(loop, event)))
+        if meanwhile is not None:
+            meanwhile()
         try:
             await asyncio.wait_for(event.wait(), timeout_s)
         except asyncio.TimeoutError:
@@ -354,6 +358,37 @@ class _Slot:
         self.request_id = None
         self.prefilling = None
         self.timeline = None
+
+
+class _Delivery:
+    """What one token owes its stream once the engine's state has taken it
+    in: detokenise, the `GenerationOutput`, the queue put, the timeline's
+    stamp.  It holds the request's own objects, taken from the slot before
+    `reset()` or a new seating changes them, and the serial of the dispatch
+    that produced the token.  `token` -1 closes a stream without a token
+    (`LLMEngine._finish`); `stops` are the lane's stop strings, which only
+    the text can decide, so a lane that has any is never deferred."""
+
+    __slots__ = (
+        "queue", "detok", "timeline", "token", "n_generated", "n_prompt",
+        "finish_reason", "is_eos", "serial", "stops", "logprob",
+        "top_logprobs",
+    )
+
+    def __init__(self, slot: _Slot, token: int, finish_reason: Optional[str],
+                 is_eos: bool, serial: int, logprob=None, top_logprobs=None):
+        self.queue = slot.queue
+        self.detok = slot.detok
+        self.timeline = slot.timeline
+        self.token = token
+        self.n_generated = len(slot.generated)
+        self.n_prompt = slot.prompt_len
+        self.finish_reason = finish_reason
+        self.is_eos = is_eos
+        self.serial = serial
+        self.stops = slot.stop_texts
+        self.logprob = logprob
+        self.top_logprobs = top_logprobs
 
 
 class _QueuedRequest:

@@ -326,6 +326,28 @@ ENGINE_DISPATCHES = Counter(
     "device dispatches committed by the engine's loop, by program",
     ["model_name", "program"],
 )
+# Handing a dispatch's tokens to their streams (detokenise, the output, the
+# queue put) is split from what the tokens change in the engine's state: on
+# the `mixed` path it runs after the NEXT dispatch is launched, inside that
+# one's `wait` (docs/observability.md "Dispatch phases").  `when` is the
+# closed set observability.timeline.DELIVERIES.
+ENGINE_DISPATCH_DELIVERIES = Counter(
+    "engine_dispatch_deliveries_total",
+    "tokens handed to their streams, by when: overlapped (after the NEXT "
+    "dispatch was launched, while it ran) | inline (in place, before the "
+    "next launch: a lane with stop strings, the legacy and dense paths, "
+    "nothing to launch next); fed from the committed dispatch rows, as the "
+    "phases are",
+    ["model_name", "when"],
+)
+ENGINE_DISPATCH_DELIVER_SECONDS = Counter(
+    "engine_dispatch_deliver_seconds_total",
+    "host seconds spent handing deferred tokens to their streams, inside "
+    "whichever phase of engine_dispatch_phase_seconds_total that was (a "
+    "token handed over in place is not timed apart from its `route`); fed "
+    "from the committed dispatch rows, as the phases are",
+    ["model_name"],
+)
 # `sampler_path` is the closed set engine/sampling.SAMPLER_PATHS: what the
 # dispatch's batch asked of the sampler, decided on the device by the same
 # predicate (docs/observability.md "Sampler paths"); not named `path`,

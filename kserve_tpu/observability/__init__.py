@@ -21,6 +21,7 @@ from .introspection import (  # noqa: F401
 )
 from .spans import emit_timeline_spans  # noqa: F401
 from .timeline import (  # noqa: F401
+    DELIVERIES,
     DISPATCH_COLUMNS,
     PHASES,
     DispatchPhases,

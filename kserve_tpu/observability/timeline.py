@@ -16,7 +16,10 @@ a serial number and six consecutive phases (admit, plan, launch, wait,
 route, yield) that tile one iteration of the loop, from the same clock.
 One stamp feeds three outputs: a `TraceAnnotation` on the profiler's clock
 while a capture runs, the `engine_dispatch_phase_seconds_total` counters,
-and one row per dispatch in the recorder's bounded ring.
+and one row per dispatch in the recorder's bounded ring.  Handing tokens to
+their streams is no phase: it runs inside one (`wait` where it follows the
+launch, `route` where it is done in place) and is noted beside them
+(`delivered`, the nested span `engine.deliver`).
 
 Derived metrics follow the serving-benchmark vocabulary of the vLLM/TGI
 comparative study (PAPERS.md, arXiv:2511.17593): TTFT is first token
@@ -27,6 +30,7 @@ is the gap between consecutive emitted tokens.
 from __future__ import annotations
 
 from collections import deque
+from contextlib import nullcontext
 from typing import Any, Dict, List, Optional, Tuple
 
 # bounded per-timeline storage: events and ITL samples never grow past
@@ -39,14 +43,21 @@ MAX_DISPATCHES = 512
 
 #: the phases that tile one iteration of the engine's loop, in order
 PHASES = ("admit", "plan", "launch", "wait", "route", "yield")
+#: when a token is handed to its stream: `overlapped` with the dispatch
+#: launched after the one that produced it, while that one runs, or `inline`,
+#: between its own dispatch's fetch and the next launch
+DELIVERIES = ("overlapped", "inline")
 #: the columns of a dispatch row: flat, so a snapshot of the whole ring
 #: serialises in about a millisecond
 #: (`tokens`, `width` are the (T, W) pair the dispatch ran in, `need_*` the
-#: pair it needed: they differ where it ran padded in a loaded pair)
+#: pair it needed: they differ where it ran padded in a loaded pair;
+#: `overlapped`, `inline` are the tokens this iteration handed to their
+#: streams, by DELIVERIES, and `deliver` the seconds it spent handing over
+#: those that had been deferred, inside whichever phase that was)
 DISPATCH_COLUMNS = (
     "serial", "launched_at", "program", "tokens", "width", "need_tokens",
     "need_width", "prefill_tokens", "decode_tokens", *PHASES, "wait_lag",
-    "compiled", "chained")
+    "compiled", "chained", "deliver", *DELIVERIES)
 
 
 class RequestTimeline:
@@ -348,6 +359,8 @@ class DispatchPhases:
         self._since = now
         self._seconds = dict.fromkeys(PHASES, 0.0)
         self._wait_lag = 0.0
+        self._deliver = 0.0
+        self._handed = dict.fromkeys(DELIVERIES, 0)
 
     def mark(self, phase: str) -> float:
         now = self._clock.now()
@@ -378,6 +391,19 @@ class DispatchPhases:
             self._since, program, tokens, width, *(need or (tokens, width)),
             prefill_tokens, decode_tokens, int(compiled), int(chained)])
 
+    def span(self, name: str):
+        """A host span `engine.<name>` nested in the phase under way, for
+        `with`; nothing while no annotation is set."""
+        if self._annotate is None:
+            return nullcontext()
+        return self._annotate("engine." + name, dispatch=self.serial)
+
+    def delivered(self, when: str, tokens: int, seconds: float = 0.0) -> None:
+        """`tokens` were handed to their streams, `when` (one of
+        DELIVERIES), in `seconds` of the phase under way."""
+        self._handed[when] += tokens
+        self._deliver += seconds
+
     def resumed(self, ready_at: Optional[float]) -> None:
         """The loop took a fetched result up `now - ready_at` after the
         fetch worker had it on the host: opens `route`."""
@@ -395,13 +421,15 @@ class DispatchPhases:
         self.close()
         seconds = self._seconds
         wait_lag = min(self._wait_lag, seconds["wait"])
+        delivery = (self._deliver, *self._handed.values())
         self._reset(now)
         self._phase = "admit"
         if not self._launches:
             return None
         launched_at, *what, compiled, chained = self._launches.popleft()
         row = [self.serial, launched_at, *what,
-               *(seconds[p] for p in PHASES), wait_lag, compiled, chained]
+               *(seconds[p] for p in PHASES), wait_lag, compiled, chained,
+               *delivery]
         self.serial += 1
         return row
 

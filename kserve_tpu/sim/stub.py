@@ -265,8 +265,10 @@ class SimFetcher:
         self.clock.advance_to(self.device.busy_until)
         return out
 
-    async def fetch_async(self, fn, timeout_s: float):
+    async def fetch_async(self, fn, timeout_s: float, meanwhile=None):
         out = fn()
+        if meanwhile is not None:
+            meanwhile()
         # a gray-wedged fetch worker: the result exists on the "device",
         # it just never gets delivered until the wedge lifts — liveness
         # stays green, the step deadline never fires (the sim fetcher

@@ -128,9 +128,13 @@ def standin_counts_dispatch_shapes(monkeypatch):
     def with_dispatch_shapes(self) -> str:
         n = self._dispatches()
         series = 'engine_dispatch_shape_total{model_name="bench",fit="%s"} %d\n'
+        # since PR 36 also the deliveries: every made-up token is handed
+        # over behind the next launch (`dispatch.deliver_overlap_share`)
+        handed = 'engine_dispatch_deliveries_total{model_name="bench",when="%s"} %d\n'
         return metrics(self) + "".join(
             series % fit for fit in (
-                ("exact", n - n // 10), ("padded", n // 10), ("compiled", 0)))
+                ("exact", n - n // 10), ("padded", n // 10), ("compiled", 0))
+        ) + "".join(handed % when for when in (("overlapped", n), ("inline", 0)))
 
     monkeypatch.setattr(standin.StandIn, "_metrics", with_dispatch_shapes)
 

@@ -871,3 +871,263 @@ class TestKVTierStaleSweep:
         assert not os.path.exists(stale), "dead-pid dir not swept"
         assert os.path.exists(live), "live-pid dir wrongly removed"
         assert os.path.exists(unrelated), "non-spill dir wrongly removed"
+
+
+# ------------------------------------------------- deferred delivery (PR 36)
+
+
+class TestDeferredDelivery:
+    """The `mixed` loop launches the next dispatch before it hands the
+    last one's tokens to their streams (PR 36).  What every stream
+    receives is what the parent commit's loop gave it, which handed every
+    token over first: tests/delivery_cases.py, recorded there."""
+
+    @pytest.mark.parametrize("case", [
+        "max_tokens_mid_dispatch", "eos_mid_dispatch", "min_tokens",
+        "max_model_len", "stop_string_beside_deferred", "reseated_lane",
+        "cancelled_between_dispatches", "preempted_between_dispatches"])
+    def test_every_stream_is_the_parents(self, case):
+        import delivery_cases
+
+        delivery_cases.NOTES.clear()
+        streams = asyncio.run(
+            delivery_cases.CASES[case](delivery_cases.llama_engine))
+        assert delivery_cases.jsonable(streams) == delivery_cases.recorded(
+            "llama", case)
+        notes = delivery_cases.NOTES
+        if case == "cancelled_between_dispatches":
+            # the cancel did fall between an advance and its delivery
+            assert notes["owed_at_each_plan"][3] > 0
+        if case == "preempted_between_dispatches":
+            assert notes["preemptions"] == 1
+
+    @async_test
+    async def test_the_next_launch_precedes_the_puts_but_for_a_stop_lane(self):
+        """Two lanes without a stop string and one with: dispatch N's
+        tokens reach the first two after dispatch N + 1 is launched and
+        the third before; the last dispatch has nothing to hide behind
+        and hands over in place; a batch of stop lanes alone never
+        defers.  `engine_dispatch_deliveries_total` counts each token by
+        when it was handed over (its row's `overlapped`, `inline`), and a
+        token's stamp carries the serial of the dispatch that PRODUCED
+        it."""
+        import delivery_cases as dc
+        from prometheus_client import REGISTRY
+        from test_observability import _TickClock
+
+        label = "engine-deferred-order"
+        engine = dc.llama_engine(clock=_TickClock(), metrics_label=label)
+        log = []
+        mixed_fn, hand_over = engine._mixed_fn, engine._hand_over
+
+        def launch(*args):
+            log.append(("launch", engine._phases.serial))
+            return mixed_fn(*args)
+
+        def put(owed):
+            log.append(("put", owed.serial, bool(owed.stops)))
+            return hand_over(owed)
+
+        engine._mixed_fn, engine._hand_over = launch, put
+
+        def counted(when):
+            return REGISTRY.get_sample_value(
+                "engine_dispatch_deliveries_total",
+                {"model_name": label, "when": when}) or 0.0
+
+        await engine.start()
+        try:
+            # 8-token prompts: two pages from the start, so that every
+            # lane takes 4 tokens from each of three dispatches
+            await dc.together(
+                engine, a=(dc.PROMPT_A, dc.greedy(12)),
+                b=(dc.PROMPT_A[::-1], dc.greedy(12, stop=["never"])),
+                c=(dc.PROMPT_A, dc.sampled(12, seed=4)))
+            launches = {n: i for i, (what, n, *_) in enumerate(log)
+                        if what == "launch"}
+            assert sorted(launches) == [1, 2, 3]
+            puts = [(i, n, stops) for i, (what, n, *stops) in enumerate(log)
+                    if what == "put"]
+            assert len(puts) == 36
+            for i, n, (stops,) in puts:
+                if n == 3:  # nothing is launched behind the last
+                    assert i > launches[3]
+                elif stops:
+                    assert launches[n] < i < launches[n + 1]
+                else:
+                    assert i > launches[n + 1]
+            await asyncio.sleep(0.05)  # the loop commits behind its yield
+            snap = engine.telemetry_snapshot()
+            assert [t["first_token_dispatch"] for t in snap["recent"]] == [1] * 3
+            rows = [dict(zip(snap["dispatches"]["columns"], r))
+                    for r in snap["dispatches"]["rows"]]
+            # a row says when ITS iteration's tokens were handed over:
+            # the stop lane's 4 in place; 8 behind the launch and 4 in
+            # place; 8 behind the launch and the last dispatch's 12 in place
+            assert [(r["overlapped"], r["inline"]) for r in rows] == [
+                (0, 4), (8, 4), (8, 12)]
+            assert (counted("overlapped"), counted("inline")) == (16, 20)
+            await dc.stream(engine, dc.PROMPT_A, dc.greedy(8, stop=["never"]))
+            await asyncio.sleep(0.05)
+            assert (counted("overlapped"), counted("inline")) == (16, 28)
+            assert not engine._undelivered
+        finally:
+            await engine.stop()
+
+
+def _tokens_then(rows):
+    """A stream as collected by `_drain_stream`: its outputs' token ids,
+    and the exception that ended it (None: it finished)."""
+    ids = [r.token_id for r in rows if not isinstance(r, Exception)]
+    ends = [r for r in rows if isinstance(r, Exception)]
+    assert len(ends) <= 1 and (not ends or rows[-1] is ends[0])
+    return ids, (ends[0] if ends else None)
+
+
+async def _drain_stream(engine, prompt, params, into):
+    try:
+        async for out in engine.generate(prompt, params):
+            into.append(out)
+    # the exception IS the stream's ending: kept in the list and asserted on
+    except Exception as e:  # noqa: BLE001  # jaxlint: disable=swallowed-exception
+        into.append(e)
+
+
+class TestDeferredDeliveryExits:
+    """However the loop ends, no consumer is left waiting, no token is
+    lost or handed over twice, and what a request was still owed reaches
+    it before any error or checkpoint does."""
+
+    @staticmethod
+    async def _two_streams(engine, n_short=8, n_long=50):
+        import delivery_cases as dc
+
+        short, long = [], []
+        tasks = [
+            asyncio.create_task(_drain_stream(
+                engine, dc.PROMPT_A, dc.greedy(n_short), short)),
+            asyncio.create_task(_drain_stream(
+                engine, dc.PROMPT_B, dc.sampled(n_long, seed=6), long))]
+        return short, long, tasks
+
+    @pytest.mark.parametrize("seam", ["engine.fetch", "plan"])
+    @async_test
+    async def test_a_crash_between_advance_and_delivery(self, seam):
+        """The third dispatch never comes back (the fetch seam's
+        replica_crash), or is never launched (its planner raises): the
+        request that finished in the second dispatch's advance has its
+        last chunk and no error; the other has every token of two
+        dispatches, once, and then the error."""
+        import delivery_cases as dc
+
+        from kserve_tpu.resilience import FaultPlan, FaultSpec, ReplicaCrashError
+
+        engine = dc.llama_engine(max_batch_size=2)
+        await engine.start()
+        if seam == "engine.fetch":
+            engine.fault_plan = FaultPlan(
+                [FaultSpec("engine.fetch", "replica_crash", after=2, count=1)])
+            error = ReplicaCrashError
+        else:
+            plan_ragged, calls = engine._plan_ragged, []
+
+            def plan(meta, prefilling):
+                calls.append(1)
+                if len(calls) == 3:
+                    assert engine._undelivered
+                    raise RuntimeError("planner fault")
+                return plan_ragged(meta, prefilling)
+
+            engine._plan_ragged = plan
+            error = RuntimeError
+        short, long, tasks = await self._two_streams(engine)
+        await asyncio.wait_for(asyncio.gather(*tasks), 60)
+        ids, end = _tokens_then(short)
+        assert len(ids) == 8 and end is None and short[-1].finished
+        # (3 + 4: a 6-token prompt's first page ends its first dispatch)
+        ids, end = _tokens_then(long)
+        assert len(ids) == 7 and isinstance(end, error)
+        assert [o.num_generated for o in long[:-1]] == list(range(1, 8))
+        assert not engine.running and not engine._undelivered
+        await engine.stop()
+
+    @pytest.mark.parametrize("exit_", ["stop", "drain", "self_drain"])
+    @async_test
+    async def test_what_is_owed_goes_out_before_the_exit_speaks(self, exit_):
+        """`stop()`, `drain()` past its deadline and the watchdog's
+        self-drain, each called while a token is still owed (taken in by
+        the state, not yet with its stream): the stream has the token
+        before the error or the checkpoint, and the checkpoint counts
+        exactly the tokens the stream received."""
+        import delivery_cases as dc
+
+        from kserve_tpu.engine.types import _Delivery
+        from kserve_tpu.lifecycle.checkpoint import GenerationPreempted
+        from kserve_tpu.resilience import Deadline
+
+        engine = dc.llama_engine(
+            max_batch_size=2, watchdog_salvage_grace_s=0.0)
+        await engine.start()
+        short, long, tasks = await self._two_streams(engine, n_short=40)
+        while len(long) < 8 or len(short) < 8:
+            await asyncio.sleep(0.005)
+        # the loop is parked in its fetch: leave one more token of each
+        # lane taken in and still owed, as between an advance and the
+        # next launch
+        assert not engine._undelivered
+        for slot, token in zip(engine._slots, (501, 502)):
+            slot.pos += 1
+            slot.generated.append(token)
+            engine._undelivered.append(
+                _Delivery(slot, token, None, False, engine._phases.serial))
+        if exit_ == "stop":
+            await engine.stop()
+        elif exit_ == "drain":
+            await engine.drain(deadline=Deadline.after(0.0))
+        else:
+            await engine._stall_self_drain()
+        await asyncio.wait_for(asyncio.gather(*tasks), 60)
+        assert not engine._undelivered
+        for rows, owed in ((short, 501), (long, 502)):
+            ids, end = _tokens_then(rows)
+            assert ids.count(owed) == 1
+            outs = [r for r in rows if not isinstance(r, Exception)]
+            assert [o.num_generated for o in outs] == list(
+                range(1, len(outs) + 1))
+            if exit_ == "stop":
+                assert isinstance(end, RuntimeError)
+            else:
+                assert isinstance(end, GenerationPreempted)
+                assert list(end.checkpoint.generated) == ids
+                assert end.checkpoint.reason == (
+                    "drain" if exit_ == "drain" else "stall")
+        if exit_ != "stop":
+            await engine.stop()
+
+    @async_test
+    async def test_a_drain_in_flight_finds_nothing_owed(self):
+        """A draining engine goes on deferring behind its launches, and
+        owes nothing across an await of its loop: a checkpoint taken at
+        any poll counts only tokens that are with their streams."""
+        import delivery_cases as dc
+
+        from kserve_tpu.lifecycle.checkpoint import GenerationPreempted
+        from kserve_tpu.resilience import Deadline
+
+        engine = dc.llama_engine(max_batch_size=2)
+        await engine.start()
+        short, long, tasks = await self._two_streams(engine, n_short=40)
+        while len(long) < 4:
+            await asyncio.sleep(0.005)
+        # two polls of the drain's loop, so that it dispatches once more
+        checkpoints = await engine.drain(deadline=Deadline.after(0.015))
+        await asyncio.wait_for(asyncio.gather(*tasks), 60)
+        assert checkpoints
+        for rows in (short, long):
+            ids, end = _tokens_then(rows)
+            if end is None:  # a tiny model may finish inside the budget
+                assert rows[-1].finished
+                continue
+            assert isinstance(end, GenerationPreempted)
+            assert list(end.checkpoint.generated) == ids
+        await engine.stop()

@@ -205,3 +205,17 @@ def test_a_preempted_lane_is_prefilled_again_from_zero_state():
     (tight, some), _ = _run(engine_config(num_pages=24), jobs, "hybrid-tight")
     assert none == 0 and some >= 1
     assert tight == roomy
+
+
+@pytest.mark.parametrize("case", ["stop_string_beside_deferred", "reseated_lane"])
+def test_streams_do_not_depend_on_when_tokens_are_handed_over(case):
+    """The same loop as every model's: a dispatch's tokens reach their
+    streams after the next launch (PR 36), a lane with a stop string in
+    place; every stream is what the parent commit's loop gave, which
+    handed everything over first (tests/delivery_cases.py, recorded)."""
+    import delivery_cases
+
+    streams = asyncio.run(
+        delivery_cases.CASES[case](delivery_cases.hybrid_engine))
+    assert delivery_cases.jsonable(streams) == delivery_cases.recorded(
+        "hybrid", case)

@@ -1321,6 +1321,51 @@ class TestFetchLoopResponsiveness:
             backstop.cancel()
             fetcher.close()
 
+    @pytest.mark.parametrize("raises", [False, True])
+    @async_test
+    async def test_fetch_async_runs_meanwhile_beside_the_worker(self, raises):
+        """`meanwhile` (the mixed step's deferred delivery) runs on the
+        caller's thread once the worker has the thunk and before the
+        result is awaited, with no task started for it; one that raises
+        is the caller's error and leaves the worker serving."""
+        import threading
+
+        from kserve_tpu.engine.types import _DeadlineFetcher
+
+        fetcher = _DeadlineFetcher()
+        started, release = threading.Event(), threading.Event()
+        log = []
+
+        def compute():
+            started.set()
+            assert release.wait(15.0)
+            log.append("computed")
+            return 42
+
+        def meanwhile():
+            assert started.wait(15.0)  # the worker is already at it
+            assert threading.current_thread() is threading.main_thread()
+            log.append("meanwhile")
+            release.set()
+            if raises:
+                raise ValueError("a delivery failed")
+
+        try:
+            tasks = len(asyncio.all_tasks())
+            fetching = fetcher.fetch_async(compute, 20.0, meanwhile)
+            if raises:
+                with pytest.raises(ValueError):
+                    await fetching
+            else:
+                assert await fetching == 42
+            assert len(asyncio.all_tasks()) == tasks
+            assert log[0] == "meanwhile"
+            assert await fetcher.fetch_async(lambda: 7, 20.0) == 7
+            assert log == ["meanwhile", "computed"]
+        finally:
+            release.set()
+            fetcher.close()
+
     @async_test
     async def test_fetch_async_timeout_maps_to_wedge_contract(self):
         import threading

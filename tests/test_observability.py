@@ -640,7 +640,7 @@ class TestDispatchPhases:
             "prefill_tokens": 24, "decode_tokens": 8,
             "admit": 1.0, "plan": 2.0, "launch": 3.0, "wait": 10.0,
             "route": 0.5, "yield": 4.0, "wait_lag": 2.5, "compiled": 1,
-            "chained": 0}
+            "chained": 0, "deliver": 0.0, "overlapped": 0, "inline": 0}
         assert sum(row[p] for p in PHASES) == clock.now()
         assert phases.serial == 2
         # an iteration that launched nothing is dropped, and so is idle time
@@ -755,6 +755,127 @@ class TestDispatchPhases:
         assert spans == [(1, 2), (1, 2)]
         assert counter("engine_first_token_dispatches_count") == 2
         assert counter("engine_first_token_dispatches_sum") == 4
+
+    @async_test
+    async def test_delivery_behind_the_launch_lands_in_wait(self):
+        """Under the ticking clock: a dispatch's tokens are handed over
+        after the next launch, inside that iteration's `wait`, and noted
+        beside the phases (`deliver`, `overlapped`, `inline`); the six
+        phases still
+        tile the period; the last dispatch hands over in `route`; the
+        counters hold the rows' sums; a token's stamp carries the serial
+        of the dispatch that produced it, whenever it was handed over."""
+        label = "obs-deliver"
+        engine = make_engine(clock=_TickClock(), metrics_label=label,
+                             steps_per_sync=4)
+        stamps = []
+        hand_over = engine._hand_over
+
+        def spy(owed):
+            stamps.append((owed.serial, engine._phases.serial,
+                           engine._phases._phase))
+            return hand_over(owed)
+
+        engine._hand_over = spy
+        await engine.start()
+        params = SamplingParams(max_tokens=12, temperature=0.0, ignore_eos=True)
+        prompt = list(range(1, 9))
+        await asyncio.gather(collect(engine.generate(prompt, params)),
+                             collect(engine.generate(prompt[::-1], params)))
+        await engine.stop()  # the last row is committed behind the yield
+        snap = engine.telemetry_snapshot()
+        rows = [_row(r) for r in snap["dispatches"]["rows"]]
+        # a row says when ITS iteration's tokens were handed over (the
+        # last: 8 behind the launch, 8 in place), and the counters are
+        # fed from the rows
+        assert [(r["overlapped"], r["inline"]) for r in rows] == [
+            (0, 0), (8, 0), (8, 8)]
+        for a, b in zip(rows, rows[1:]):
+            between = (a["launch"] + a["wait"] + a["route"] + a["yield"]
+                       + b["admit"] + b["plan"])
+            assert b["launched_at"] - a["launched_at"] == between
+        first, second, last = rows
+        assert first["deliver"] == 0.0
+        assert 0.0 < second["deliver"] < second["wait"]
+        # nothing is handed over in the second iteration's route: it is
+        # the advance alone, and no turn of the loop follows it
+        assert second["route"] < last["route"] and second["yield"] == 1.0
+        assert last["deliver"] > second["deliver"]  # 8 behind the launch + 8 in place
+        # produced by dispatch n: handed over in iteration n + 1's wait,
+        # the last dispatch's in its own route
+        assert stamps == (
+            [(1, 2, "wait")] * 8 + [(2, 3, "wait")] * 8 + [(3, 3, "route")] * 8)
+        assert [(t["admit_dispatch"], t["first_token_dispatch"])
+                for t in snap["recent"]] == [(1, 1), (1, 1)]
+
+        def counter(name, **labels):
+            return REGISTRY.get_sample_value(
+                name, {"model_name": label, **labels}) or 0.0
+
+        assert counter("engine_dispatch_deliver_seconds_total") == sum(
+            r["deliver"] for r in rows)
+        assert counter("engine_dispatch_deliveries_total", when="overlapped") == 16
+        assert counter("engine_dispatch_deliveries_total", when="inline") == 8
+        for phase in (*PHASES, "wait_lag"):
+            assert counter("engine_dispatch_phase_seconds_total",
+                           phase=phase) == sum(r[phase] for r in rows)
+
+    @pytest.mark.parametrize("token_s, wait, wait_lag", [
+        (0.125, 1.0, 0.0),  # 4 tokens handed over in half a step
+        (0.5, 2.0, 1.0),  # in two steps: the result waits a step for the loop
+    ])
+    @async_test
+    async def test_a_delivery_that_outlasts_the_device_shows_as_wait_lag(
+            self, token_s, wait, wait_lag):
+        """The device takes 1 s a dispatch on the engine's clock and the
+        fetch is with its worker BEFORE the delivery begins: a delivery
+        shorter than the step costs the period nothing, a longer one shows
+        as `wait_lag` (the result was on the host, the loop was not)."""
+        clock = FakeClock()
+        engine = make_engine(clock=clock, metrics_label="obs-deliver-lag",
+                             steps_per_sync=4)
+
+        class StepFetcher:
+            """engine.types._DeadlineFetcher's duck: the worker has the
+            result 1 s after the fetch was handed to it."""
+
+            def fetch(self, fn, timeout_s):
+                return fn()
+
+            async def fetch_async(self, fn, timeout_s, meanwhile=None):
+                ready_at = clock.now() + 1.0
+                if meanwhile is not None:
+                    meanwhile()
+                await asyncio.sleep(0)
+                clock.advance(max(0.0, ready_at - clock.now()))
+                out = fn()
+                engine._fetch_ready_at = ready_at
+                return out
+
+            def close(self):
+                pass
+
+        engine._fetcher.close()
+        engine._fetcher = StepFetcher()
+        hand_over = engine._hand_over
+
+        def slow(owed):
+            clock.advance(token_s)
+            return hand_over(owed)
+
+        engine._hand_over = slow
+        await engine.start()
+        params = SamplingParams(max_tokens=12, temperature=0.0, ignore_eos=True)
+        await collect(engine.generate(list(range(1, 9)), params))
+        await engine.stop()  # the last row is committed behind the yield
+        snap = engine.telemetry_snapshot()
+        rows = [_row(r) for r in snap["dispatches"]["rows"]]
+        assert [(r["wait"], r["wait_lag"]) for r in rows] == [
+            (1.0, 0.0), (wait, wait_lag), (wait, wait_lag)]
+        assert [r["deliver"] for r in rows] == [
+            0.0, 4 * token_s, 8 * token_s]
+        # the last dispatch's own tokens, in place: that is `route`
+        assert [r["route"] for r in rows] == [0.0, 0.0, 4 * token_s]
 
     def test_compile_seconds_counted_on_a_forced_retrace(self):
         import jax

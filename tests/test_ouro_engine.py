@@ -255,3 +255,17 @@ def test_tensor_parallel_runs_every_pass():
     (a, b), engine = _run(engine_config(tp=2), jobs, "ouro-tp2")
     assert max(_gaps(PROMPTS[0], a)) < GAP and max(_gaps(PROMPTS[2], b)) < GAP
     assert "model" in str(engine.kv_pages[0].sharding.spec)
+
+
+@pytest.mark.parametrize("case", ["stop_string_beside_deferred", "reseated_lane"])
+def test_streams_do_not_depend_on_when_tokens_are_handed_over(case):
+    """The same loop as every model's: a dispatch's tokens reach their
+    streams after the next launch (PR 36), a lane with a stop string in
+    place; every stream is what the parent commit's loop gave, which
+    handed everything over first (tests/delivery_cases.py, recorded)."""
+    import delivery_cases
+
+    streams = asyncio.run(
+        delivery_cases.CASES[case](delivery_cases.ouro_engine))
+    assert delivery_cases.jsonable(streams) == delivery_cases.recorded(
+        "ouro", case)
