@@ -498,6 +498,7 @@ def program_defs(model_config, engine_config, mesh, spec_k=None) -> dict:
     # tokens holds one lane, which a hybrid model's packed scan and window
     # attention build on
     ragged_block = DispatchShapes.of(mc, cfg, jax.default_backend()).align
+    expert_stats = mc.has_expert_sums
 
     def _make_mixed():
         """THE unified ragged program (docs/kernels.md): one dispatch
@@ -525,6 +526,10 @@ def program_defs(model_config, engine_config, mesh, spec_k=None) -> dict:
             steps = cfg.steps_per_sync
             rngs = jax.random.split(rng, steps)
             truncates = sampler_truncates(state)  # once, not once a step
+            if expert_stats:
+                # this dispatch's sums start at zero (kvcache.StateLayout)
+                kv_pages = dict(kv_pages, stats=[
+                    jnp.zeros_like(a) for a in kv_pages["stats"]])
             logits, kv_pages = llama.forward_ragged(
                 params, mc, q_tokens, token_seq, token_pos,
                 q_start, q_len, kv_start, kv_pages, page_table,
@@ -563,6 +568,12 @@ def program_defs(model_config, engine_config, mesh, spec_k=None) -> dict:
                 kv_pages = carry[3]
             else:
                 out = sampled0[None]
+            if expert_stats:
+                # two more rows behind the tokens', the sums in column 0:
+                # fetched with the tokens, no sync of their own
+                out = jnp.concatenate([out, jnp.broadcast_to(
+                    kv_pages["stats"][0][:, None].astype(out.dtype),
+                    (2, out.shape[1]))], axis=0)
             return out, _kv_pin(kv_pages)
 
         return fn

@@ -47,6 +47,9 @@ from ..metrics import (
     ENGINE_KV_DISK_BYTES,
     ENGINE_KV_OFFLOAD_BYTES,
     ENGINE_KV_CONTEXT_TOKENS,
+    ENGINE_MOE_ASSIGNMENTS,
+    ENGINE_MOE_EXPERT_HITS,
+    ENGINE_MOE_PEAK_LOAD,
     ENGINE_KV_PAGES_FREE,
     ENGINE_KV_PAGES_TOTAL,
     ENGINE_KV_TOKEN_BYTES,
@@ -167,6 +170,43 @@ def _refuse_looped(model_config, engine_config) -> None:
             "passes over shared weights): " + "; ".join(refused))
 
 
+def _refuse_latent(model_config, engine_config, role: str) -> None:
+    """What a model of latent-attention layers and routed experts
+    (models/latent.py, models/moe.py) cannot do yet, by name (ROADMAP.md
+    Queue R names the mechanisms).  Its pages ARE a lane's whole state (a
+    latent row carries absolute positions in its roped key, as K does), so
+    the prefix cache stays as configured: on by default."""
+    cfg = engine_config
+    refused = []
+    if cfg.tp > 1:
+        refused.append("tp>1 (latent attention across chips: heads shard, "
+                       "the latent page would be replicated; a chip's share "
+                       "of the experts)")
+    if cfg.pp > 1:
+        refused.append("pp>1 (staged layers assume one kind of layer)")
+    if cfg.sp > 1:
+        refused.append("sp>1 (ring-attention prefill over K and V per head)")
+    if cfg.kv_quant != "none":
+        refused.append(f"kv_quant={cfg.kv_quant} (a latent row has no scales)")
+    if cfg.weight_quant != "none":
+        refused.append(f"weight_quant={cfg.weight_quant} (int8 over experts "
+                       "and the latent projections)")
+    if cfg.spec_decode_k is not None:
+        refused.append("spec_decode_k (the dense verify program has no "
+                       "attention over latent pages)")
+    if cfg.kv_offload != "none" or cfg.kv_persist_dir:
+        refused.append("kv_offload / kv_persist_dir (spill and page-in move "
+                       "K/V pages; the latent row is not on their wire)")
+    if cfg.use_ragged is False:
+        refused.append("use_ragged=False (the legacy programs)")
+    if role != "both":
+        refused.append(f"role={role} (the P/D wire ships K/V pages)")
+    if refused:
+        raise NotImplementedError(
+            "not supported yet for a model with latent-attention layers and "
+            "routed experts: " + "; ".join(refused))
+
+
 def resolve_hybrid_serving(model_config, engine_config,
                            role: str = "both") -> None:
     """THE place that says what a model with recurrent or ring state
@@ -182,6 +222,9 @@ def resolve_hybrid_serving(model_config, engine_config,
     (models/llama._run_passes is under each forward), or is refused here."""
     _refuse_looped(model_config, engine_config)
     if not model_config.is_hybrid:
+        return
+    if model_config.is_latent:
+        _refuse_latent(model_config, engine_config, role)
         return
     cfg = engine_config
     refused = []
@@ -323,6 +366,14 @@ class LLMEngine:
             model_name=metrics_label)
         self._kv_context_tokens = ENGINE_KV_CONTEXT_TOKENS.labels(
             model_name=metrics_label)
+        # engine_moe_*_total: pairs counted at launch, hits and peak load
+        # summed in the program (the `mixed` program's last two rows)
+        self._moe_assignments = ENGINE_MOE_ASSIGNMENTS.labels(
+            model_name=metrics_label)
+        self._moe_hits = ENGINE_MOE_EXPERT_HITS.labels(
+            model_name=metrics_label)
+        self._moe_peak = ENGINE_MOE_PEAK_LOAD.labels(model_name=metrics_label)
+        self._expert_stats = model_config.has_expert_sums
         # when the fetch worker last had a result on the host
         self._fetch_ready_at: Optional[float] = None
         # checkpoints carry this as model_name; resume_generation rejects a
@@ -504,9 +555,9 @@ class LLMEngine:
             self.kv_pages = layout.init_state(
                 shd.named_canonical(self.mesh, jax.sharding.PartitionSpec()))
             logger.info(
-                "per-lane state: shared K/V %d B a token over %d pages of %d "
+                "per-lane state: %s B a token over %d pages of %d "
                 "tokens; a lane holds window K/V %d B, ssm %d B, conv %d B",
-                layout.token_bytes(), layout.num_pages, layout.page_size,
+                layout.bytes_per_token(), layout.num_pages, layout.page_size,
                 *(layout.lane_bytes()[k] for k in ("window_kv", "ssm", "conv")))
         elif engine_config.kv_quant == "int8":
             if engine_config.use_pallas:
@@ -1637,12 +1688,13 @@ class LLMEngine:
         if kv_wire:
             raise ValueError(
                 "the P/D wire of KV pages is not supported for a model with "
-                "recurrent state")
+                "recurrent state or latent pages")
         if params is not None and (
                 params.has_penalties or params.logprobs is not None):
             raise ValueError(
                 "logprobs and sampling penalties run the legacy programs, "
-                "which a model with recurrent state does not have")
+                "which a model with recurrent state or latent pages does "
+                "not have")
 
     def _new_timeline(self, rid: str, n_prompt: int) -> RequestTimeline:
         """Stamp `received` NOW (the sync part of submit) and capture the
@@ -2537,7 +2589,7 @@ class LLMEngine:
             "slots": layout.lanes,
             "pages_in_use": held,
             "bytes_in_use": layout.bytes_in_use(seated, held),
-            "bytes_per_token": {"shared_kv": layout.token_bytes()},
+            "bytes_per_token": layout.bytes_per_token(),
             "bytes_per_lane": layout.lane_bytes(),
         }
 
@@ -2559,19 +2611,28 @@ class LLMEngine:
         }
 
     def _count_forward(self, steps: int, pos=None, live=None, capacity=None,
-                       decode_steps: int = 0) -> None:
+                       decode_steps: int = 0, packed_tokens: int = 0) -> None:
         """Count what a launch runs: `steps` forward steps (each all the
         model's passes) and, over its `decode_steps` decode steps, the
         cached tokens the live lanes attend to.  Lane b, live at position
         pos[b], attends to pos[b] + s + 1 tokens at decode step s while it
         stays under its page capacity: the device's own rule
-        (compiled._make_decode / _make_mixed), evaluated on the host."""
-        self._layer_passes.inc(steps * self.model_config.n_passes)
+        (compiled._make_decode / _make_mixed), evaluated on the host.  The
+        tokens that pass the model (`packed_tokens` in the packed step, one
+        a live lane and decode step) are each routed to `n_experts_per_tok`
+        experts in every expert layer."""
+        mc = self.model_config
+        self._layer_passes.inc(steps * mc.n_passes)
+        tokens = packed_tokens
         if decode_steps and pos is not None:
             pos = np.asarray(pos, np.int64)
             n = np.where(np.asarray(live),
                          np.clip(np.asarray(capacity) - pos, 0, decode_steps), 0)
             self._kv_context_tokens.inc(int(np.sum(n * pos + n * (n + 1) // 2)))
+            tokens += int(np.sum(n))
+        if mc.n_experts > 0 and tokens:
+            self._moe_assignments.inc(
+                tokens * mc.n_experts_per_tok * mc.n_expert_layers)
 
     def _set_state_gauges(self) -> None:
         occupancy = self._state_occupancy()
@@ -3037,7 +3098,7 @@ class LLMEngine:
         pos = slot.pos  # KV on device covers positions 0..pos-1
         P = pages_needed(pos, self.config.page_size)
         kv_key = None
-        nbytes = P * self.cache_config.page_bytes()
+        nbytes = P * self.state_layout.page_bytes()
         # spill into the tier store when it can fit; otherwise chunked
         # re-prefill recomputes the KV on resume.  Quantized caches spill
         # both tensors (int8 pages + scales) as one payload.  Mid-drain the
@@ -3582,7 +3643,8 @@ class LLMEngine:
         # the packed step, then steps - 1 decode steps over the joining lanes
         self._count_forward(
             self._shapes.steps, plan["scan_pos0"], plan["joins"],
-            plan["capacity"], decode_steps=self._shapes.steps - 1)
+            plan["capacity"], decode_steps=self._shapes.steps - 1,
+            packed_tokens=plan["prefill_tokens"] + plan["decode_tokens"])
         phases.mark("wait")
         # the fetch is handed to its worker first, so that the result is
         # stamped when the device has it and a delivery that outlasts the
@@ -3591,6 +3653,10 @@ class LLMEngine:
         # happen in the turns of the event loop that the await leaves
         chunk_np = await self._fetch_async(out, self._deliver_overlapped)
         phases.resumed(self._fetch_ready_at)
+        if self._expert_stats:
+            chunk_np, (hits, peak) = chunk_np[:-2], chunk_np[-2:, 0]
+            self._moe_hits.inc(int(hits))
+            self._moe_peak.inc(int(peak))
         self._route_mixed(plan, chunk_np, dispatched_at)
         return True
 

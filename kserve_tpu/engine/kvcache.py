@@ -104,7 +104,18 @@ class StateLayout:
       (lane b holds ring pages 1 + b * ring_width ..; page 0 is the null
       page), so it never grows;
     - `ssm` and `conv`: for every layer that writes `recurrent` one slot
-      per lane: the scan's float32 state and the convolution's tail.
+      per lane: the scan's float32 state and the convolution's tail;
+    - `latent`: for every layer that writes `latent_kv` (latent attention,
+      models/latent.py) pages of the SAME pool and page table, holding ONE
+      row a token and no K/V planes or heads: `latent_width` values (the
+      compressed K/V and the roped key all heads share), stored in
+      `latent_row` columns (padded to the TPU's 128 lanes, which is what
+      the device's tiled layout would hold anyway; the padding is counted
+      in every byte count here).
+
+    A model with expert layers also carries `stats`: two int32 sums its
+    forward adds to in the program (models/hybrid.py `_ffn`), which the
+    `mixed` program zeroes at its start and returns with its tokens.
 
     Ring and slots belong to the LANE (the engine's slot index): nothing is
     allocated at admission, and a program starts a lane from zero state
@@ -124,6 +135,9 @@ class StateLayout:
     d_conv: int
     dtype: str = "bfloat16"
     n_passes: int = 1  # a looped model: every paged layer has a row a pass
+    latent_layers: tuple = ()
+    latent_width: int = 0  # values a latent row holds
+    expert_layers: int = 0
 
     @classmethod
     def of(cls, model_config, page_size: int, num_pages: int, lanes: int,
@@ -143,7 +157,10 @@ class StateLayout:
             d_inner=model_config.mamba_d_inner,
             d_state=model_config.mamba_d_state,
             d_conv=model_config.mamba_d_conv, dtype=dtype,
-            n_passes=model_config.n_passes)
+            n_passes=model_config.n_passes,
+            latent_layers=rows("latent_kv"),
+            latent_width=model_config.latent_width,
+            expert_layers=sum(r.ffn == "experts" for r in table))
 
     @property
     def _itemsize(self) -> int:
@@ -163,10 +180,31 @@ class StateLayout:
         """K/V rows of the pool a token holds: one per (pass, paged layer)."""
         return self.n_passes * len(self.paged_layers)
 
+    @property
+    def latent_row(self) -> int:
+        """Columns a latent row is stored in: `latent_width` rounded up to
+        the TPU's 128 lanes."""
+        return -(-self.latent_width // 128) * 128 if self.latent_layers else 0
+
+    def bytes_per_token(self) -> dict:
+        """Bytes of the pool one token of context holds, by kind: K and V
+        of every (pass, paged layer); one row as stored of every latent
+        layer."""
+        out = {"shared_kv": self.cache_rows * 2 * self.kv_heads
+               * self.head_dim * self._itemsize}
+        if self.latent_layers:
+            out["latent_kv"] = (len(self.latent_layers) * self.latent_row
+                                * self._itemsize)
+        return out
+
     def token_bytes(self) -> int:
-        """Bytes of shared K/V one token of context holds, all its rows."""
-        return (self.cache_rows * 2 * self.kv_heads * self.head_dim
-                * self._itemsize)
+        """Bytes of the pool one token of context holds, all its rows."""
+        return sum(self.bytes_per_token().values())
+
+    def page_bytes(self) -> int:
+        """One page of the pool, all its rows: what a spill or a transfer
+        of one page id moves."""
+        return self.page_size * self.token_bytes()
 
     def lane_bytes(self) -> dict:
         """Bytes one lane holds whatever its context's length, by kind."""
@@ -181,7 +219,8 @@ class StateLayout:
     def bytes_in_use(self, lanes_seated: int, pages_held: int) -> dict:
         """engine_state_bytes{kind}: what the seated lanes hold now."""
         out = {k: v * lanes_seated for k, v in self.lane_bytes().items()}
-        out["shared_kv"] = pages_held * self.page_size * self.token_bytes()
+        for kind, n in self.bytes_per_token().items():
+            out[kind] = pages_held * self.page_size * n
         return out
 
     def init_state(self, sharding=None) -> dict:
@@ -197,7 +236,15 @@ class StateLayout:
             return [make() for _ in range(n)]
 
         n = len(self.recurrent_layers)
+        extra = {}
+        if self.latent_layers:
+            extra["latent"] = fill(
+                (self.num_pages, 1, 1, self.page_size, self.latent_row),
+                dtype, len(self.latent_layers))
+        if self.expert_layers:
+            extra["stats"] = fill((2,), jnp.int32, 1)
         return {
+            **extra,
             "paged": fill((self.num_pages,) + page, dtype, len(self.paged_layers)),
             "window": fill((1 + self.lanes * self.ring_width,) + ring, dtype,
                            len(self.window_layers)),
@@ -301,7 +348,8 @@ def write_chunk_kv_batch(
 def _scatter_kv(kv_pages, k, v, pages_flat, slot_flat):
     """Scatter K/V rows (k/v: [N, ..., n_kv, d] flattened to [Nf, n_kv, d])
     into a plain or quantized ((int8 pages, scales)) cache at the given
-    flat (page, slot) indices.
+    flat (page, slot) indices.  `v` None: LATENT pages [num_pages, 1, 1,
+    ps, row] (StateLayout): one plane, `k` the rows as [N, 1, row].
 
     A ROW scatter: every [d] row is addressed by all four leading dims
     (page, k/v, head, slot), so the update window is the minor dim alone.
@@ -315,6 +363,11 @@ def _scatter_kv(kv_pages, k, v, pages_flat, slot_flat):
     the model-sharded head dim with no collective."""
     lead = int(np.prod(k.shape[:-2])) if k.ndim > 3 else k.shape[0]
     kf = k.reshape(lead, k.shape[-2], k.shape[-1])
+    if v is None:
+        return kv_pages.at[pages_flat[:, None, None], 0, 0,
+                           slot_flat[:, None, None], :].set(
+            kf[:, None].astype(kv_pages.dtype), mode="drop",
+            unique_indices=False)
     vf = v.reshape(lead, v.shape[-2], v.shape[-1])
     page_ix = pages_flat[:, None, None]
     kv_ix = jnp.arange(2, dtype=jnp.int32)[None, :, None]
@@ -343,7 +396,7 @@ def _scatter_kv(kv_pages, k, v, pages_flat, slot_flat):
 def write_ragged_kv(
     kv_pages,  # [num_pages, 2, n_kv, ps, d] or (int8 pages, scales)
     k: jnp.ndarray,  # [T, n_kv, d] — packed ragged slice keys
-    v: jnp.ndarray,  # [T, n_kv, d]
+    v,  # [T, n_kv, d]; None: latent pages, k the rows [T, 1, row]
     page_table: jnp.ndarray,  # [B, max_pages_per_seq]
     token_seq: jnp.ndarray,  # [T] sequence index per packed token (-1 = pad)
     token_pos: jnp.ndarray,  # [T] absolute position per packed token
@@ -359,14 +412,15 @@ def write_ragged_kv(
     page = jnp.where(
         valid, page_table[seq_ix, token_pos // page_size], 0)
     slot = token_pos % page_size
-    return _scatter_kv(kv_pages, k[:, None], v[:, None], page, slot)
+    return _scatter_kv(
+        kv_pages, k[:, None], None if v is None else v[:, None], page, slot)
 
 
 @jax.named_scope("kv_write")
 def append_token_kv(
     kv_pages: jnp.ndarray,  # [num_pages, 2, n_kv, ps, d]
     k: jnp.ndarray,  # [B, n_kv, d]
-    v: jnp.ndarray,  # [B, n_kv, d]
+    v,  # [B, n_kv, d]; None: latent pages, k the rows [B, 1, row]
     page_table: jnp.ndarray,  # [B, max_pages_per_seq]
     pos: jnp.ndarray,  # [B] position being written
     active: jnp.ndarray,  # [B] bool — inactive slots write to null page
@@ -377,7 +431,8 @@ def append_token_kv(
     b = jnp.arange(B, dtype=jnp.int32)
     page = jnp.where(active, page_table[b, pos // page_size], 0)
     slot = pos % page_size
-    return _scatter_kv(kv_pages, k[:, None], v[:, None], page, slot)
+    return _scatter_kv(
+        kv_pages, k[:, None], None if v is None else v[:, None], page, slot)
 
 
 # ---------------- int8 KV quantization (opt-in, kv_quant="int8") ----------------

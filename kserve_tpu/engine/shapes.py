@@ -126,7 +126,7 @@ class DispatchShapes:
         """The policy under one resolved configuration.  `backend` is an
         argument because the programs are also built with no engine
         (`compiled.program_defs`, the HLO oracle)."""
-        from ..ops.attention import _should_use_ragged_pallas
+        from ..ops.attention import _should_use_ragged_pallas, latent_uses_pallas
         from ..ops.pallas_paged_attention import RAGGED_BQ
 
         buckets = tuple(engine_config.prefill_buckets)
@@ -137,12 +137,16 @@ class DispatchShapes:
                     f"prefill buckets {bad} not divisible by sp={engine_config.sp} "
                     "(ring-attention prefill shards the prompt dim over seq)"
                 )
-        kernel_possible = engine_config.use_pallas or (
-            engine_config.use_pallas is None
-            and _should_use_ragged_pallas(
-                model_config.cache_head_dim, backend,
-                engine_config.kv_quant == "int8")
-        )
+        if getattr(model_config, "is_latent", False):
+            kernel_possible = latent_uses_pallas(
+                engine_config.use_pallas, backend)
+        else:
+            kernel_possible = engine_config.use_pallas or (
+                engine_config.use_pallas is None
+                and _should_use_ragged_pallas(
+                    model_config.cache_head_dim, backend,
+                    engine_config.kv_quant == "int8")
+            )
         return cls(
             align=RAGGED_BQ if kernel_possible else 1,
             token_buckets=buckets,

@@ -49,13 +49,15 @@ ENGINE_KV_PAGES_TOTAL = Gauge(
 )
 ENGINE_KV_TOKEN_BYTES = Gauge(
     "engine_kv_token_bytes",
-    "bytes of paged K/V one token of context holds, all its cache rows "
-    "(passes x layers)",
+    "bytes of the pool one token of context holds, all its cache rows: K "
+    "and V of every (pass, layer), or a latent layer's one row as stored "
+    "(its padding to 128 lanes included)",
     ["model_name"],
 )
 # Per-lane state by kind (engine/kvcache.StateLayout): what the seated lanes
-# hold now.  `kind`: shared_kv (pages of the pool), window_kv (window
-# layers' rings), ssm and conv (recurrent layers' slots).
+# hold now.  `kind`: shared_kv (K/V pages of the pool), latent_kv (latent
+# attention's rows in pages of the pool), window_kv (window layers' rings),
+# ssm and conv (recurrent layers' slots).
 ENGINE_STATE_BYTES = Gauge(
     "engine_state_bytes", "bytes of per-lane state in use, by kind",
     ["model_name", "kind"],
@@ -383,6 +385,26 @@ ENGINE_KV_CONTEXT_TOKENS = Counter(
     "engine_kv_context_tokens_total",
     "sum over a dispatch's decode steps of the cached tokens its live "
     "lanes attend to: the work of decode attention, in tokens a cache row",
+    ["model_name"],
+)
+# Expert layers (models/moe.py).  Assignments are counted at launch from the
+# dispatch's tokens; hits and peak load are summed IN the program over its
+# forward steps and expert layers and come back with the dispatch's tokens.
+ENGINE_MOE_ASSIGNMENTS = Counter(
+    "engine_moe_assignments_total",
+    "(token, expert) pairs the routed experts multiplied: forward tokens x "
+    "experts a token x expert layers",
+    ["model_name"],
+)
+ENGINE_MOE_EXPERT_HITS = Counter(
+    "engine_moe_expert_hits_total",
+    "sum over forward steps and expert layers of the experts that got at "
+    "least one token: what was read of the experts' weights, in experts",
+    ["model_name"],
+)
+ENGINE_MOE_PEAK_LOAD = Counter(
+    "engine_moe_peak_load_total",
+    "sum over forward steps and expert layers of the fullest expert's rows",
     ["model_name"],
 )
 ENGINE_FIRST_TOKEN_DISPATCHES = Summary(

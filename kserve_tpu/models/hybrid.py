@@ -29,6 +29,14 @@ over rows of twice the width gives `softmax(q_1 k_1^T) [v_1, v_2]` and
 holds the same bytes per token, at the width the decode kernel streams
 without a re-layout.
 
+Second family: `model_type: glm4_moe_lite`: every layer `h += Mixer(
+RMSNorm(h)); h += FFN(RMSNorm(h))` with the `latent_attention` mixer
+(models/latent.py: a compressed row a token in `state["latent"]`, read in
+the absorbed form by both programs) and a feed-forward that is `dense` or
+`experts` by the row's `ffn` (models/moe.py: routed pairs only, plus a
+shared expert).  Its norms carry no bias and its head is untied: `_ln`,
+`_ffn` and `_logits` choose by `config.norm_type` and by the row.
+
 Layers behind the last layer that writes state only feed the logits, so the
 packed forward runs them (and the last writer's own attention output) on
 the rows that are sampled, one per lane: exact, and it makes every read of
@@ -48,9 +56,15 @@ import numpy as np
 
 from ..engine.kvcache import append_token_kv, write_ragged_kv
 from ..ops import ssm
-from ..ops.attention import paged_attention_scaled, ring_window_attention_ragged
+from ..ops.attention import (
+    latent_paged_attention,
+    latent_ragged_attention,
+    paged_attention_scaled,
+    ring_window_attention_ragged,
+)
 from ..ops.norms import layer_norm, rms_norm
-from . import llama
+from . import latent, llama
+from .moe import moe_config_of, moe_mlp, moe_param_shapes
 from .quant import dense, tied_head_matmul
 
 Params = Dict[str, Any]
@@ -63,18 +77,28 @@ def layer_param_shapes(config, spec) -> Dict[str, tuple]:
     """{name: (shape, init)} of one layer, from its row of the table.
     `init`: "normal" (N(0, scale)), "ones", "bias" (N(0, scale)),
     "lambda" (N(0, 0.1), arXiv:2410.05258), "A_log", "dt_bias", "D" (the
-    Mamba-1 defaults); float32 for the last three."""
+    Mamba-1 defaults), "zeros" (a router's choice-only bias); float32 for
+    those four; "routed_out" (N(0, scale x ROUTED_OUT_GAIN): a routed
+    expert's down-projection)."""
     h, f = config.hidden_size, config.intermediate_size
     nq, nkv, hd = config.n_heads, config.n_kv_heads, config.head_dim
     di, n, k, r = (config.mamba_d_inner, config.mamba_d_state,
                    config.mamba_d_conv, config.mamba_dt_rank)
-    shapes = {
-        "attn_norm": ((h,), "ones"), "attn_norm_b": ((h,), "bias"),
-        "mlp_norm": ((h,), "ones"), "mlp_norm_b": ((h,), "bias"),
-        "w_gate": ((h, f), "normal"), "w_up": ((h, f), "normal"),
-        "w_down": ((f, h), "normal"),
-    }
-    if spec.kind in ("attention", "window_attention", "cross_attention"):
+    shapes = {"attn_norm": ((h,), "ones"), "mlp_norm": ((h,), "ones")}
+    if config.norm_type == "layernorm":
+        shapes.update({"attn_norm_b": ((h,), "bias"),
+                       "mlp_norm_b": ((h,), "bias")})
+    if spec.ffn == "experts":
+        inits = {"router_bias": "zeros", "w_down": "routed_out"}
+        shapes.update({
+            name: (shape, inits.get(name, "normal"))
+            for name, shape in moe_param_shapes(moe_config_of(config)).items()})
+    else:
+        shapes.update({"w_gate": ((h, f), "normal"), "w_up": ((h, f), "normal"),
+                       "w_down": ((f, h), "normal")})
+    if spec.kind == "latent_attention":
+        shapes.update(latent.param_shapes(config))
+    elif spec.kind in ("attention", "window_attention", "cross_attention"):
         shapes.update({
             "wq": ((h, nq * hd), "normal"), "wo": ((nq * hd, h), "normal"),
             "lambda_q1": ((hd,), "lambda"), "lambda_k1": ((hd,), "lambda"),
@@ -106,7 +130,22 @@ def layer_param_shapes(config, spec) -> Dict[str, tuple]:
     return shapes
 
 
-_F32_INITS = ("A_log", "dt_bias", "D")
+_F32_INITS = ("A_log", "dt_bias", "D", "router_bias")
+
+#: Random weights only (a checkpoint's values load as they are): a routed
+#: expert's down-projection starts at a tenth of the other projections'
+#: scale.  A top-k router is discontinuous: in bf16 the program's router and
+#: a float32 reference's choose different experts at 5-20 % of (token, expert
+#: layer) decisions, whatever the scale.  With every projection at one scale
+#: a single such decision moves the stream by ~10 % and the later layers of
+#: random weights amplify it, so that bf16 left the float32 reference by as
+#: much as int8 weights did (a served token up to 1.4 under the reference's
+#: best logit at logits of deviation 0.9, against 0.01 where no decision
+#: differed: PERF.md section 6, PR 37) and no tolerance told a precision
+#: from a fault.  At a tenth a differing decision costs no more than the
+#: rounding around it, and a routed path that computed nothing would still
+#: read over the tolerance.
+ROUTED_OUT_GAIN = 0.1
 
 
 def init_params(config, rng, scale: float = 0.02, weight_quant: str = "none",
@@ -123,6 +162,11 @@ def init_params(config, rng, scale: float = 0.02, weight_quant: str = "none",
     def make(shape, init, key):
         if init == "ones":
             return jnp.ones(shape, dtype)
+        if init == "zeros":
+            return jnp.zeros(shape, jnp.float32)
+        if init == "routed_out":
+            return (jax.random.normal(key, shape, jnp.float32)
+                    * scale * ROUTED_OUT_GAIN).astype(dtype)
         if init == "lambda":
             return (jax.random.normal(key, shape, jnp.float32) * 0.1).astype(dtype)
         if init == "A_log":
@@ -146,9 +190,13 @@ def init_params(config, rng, scale: float = 0.02, weight_quant: str = "none",
     def make_top(key):
         k = jax.random.split(key, 2)
         h = config.hidden_size
-        return {"embed": make((config.vocab_size, h), "normal", k[0]),
-                "final_norm": jnp.ones((h,), dtype),
-                "final_norm_b": make((h,), "bias", k[1])}
+        top = {"embed": make((config.vocab_size, h), "normal", k[0]),
+               "final_norm": jnp.ones((h,), dtype)}
+        if config.norm_type == "layernorm":
+            top["final_norm_b"] = make((h,), "bias", k[1])
+        if not config.tie_word_embeddings:
+            top["lm_head"] = make((h, config.vocab_size), "normal", k[1])
+        return top
 
     layer_fn = jax.jit(make_layer, static_argnums=0)
     layers = []
@@ -167,13 +215,30 @@ def init_params(config, rng, scale: float = 0.02, weight_quant: str = "none",
 
 
 def _ln(x, layer, name, config):
-    return layer_norm(x, layer[name], layer[name + "_b"], config.rms_norm_eps)
+    if config.norm_type == "layernorm":
+        return layer_norm(x, layer[name], layer[name + "_b"],
+                          config.rms_norm_eps)
+    return rms_norm(x, layer[name], config.rms_norm_eps)
 
 
-def _close(layer, x, mixed, config):
-    """Residual around the mixer's output, then the MLP's."""
+def _ffn(layer, spec, x, valid, state, config):
+    """The row's feed-forward over x [N, h].  An `experts` row multiplies
+    only the pairs routed among the `valid` rows and adds two sums to
+    `state["stats"]`: experts that got at least one row, and the fullest
+    expert's rows (engine_moe_expert_hits_total / _peak_load_total)."""
+    if spec.ffn != "experts":
+        return llama._mlp(layer, x, config)
+    out, rows = moe_mlp(layer, x, moe_config_of(config), valid, with_rows=True)
+    state["stats"][0] = state["stats"][0] + jnp.stack(
+        [jnp.sum(rows > 0, dtype=jnp.int32), jnp.max(rows)])
+    return out
+
+
+def _close(layer, spec, x, mixed, valid, state, config):
+    """Residual around the mixer's output, then the feed-forward's."""
     x = x + mixed
-    return x + llama._mlp(layer, _ln(x, layer, "mlp_norm", config), config)
+    return x + _ffn(layer, spec, _ln(x, layer, "mlp_norm", config), valid,
+                    state, config)
 
 
 def _lambda_init(layer_index: int) -> float:
@@ -259,6 +324,8 @@ def _gmu(layer, u, m):
 
 
 def _logits(params, x, config):
+    if config.norm_type != "layernorm":
+        return llama._logits(params, x, config)
     with jax.named_scope("lm_head"):
         x = layer_norm(x, params["final_norm"], params["final_norm_b"],
                        config.rms_norm_eps)
@@ -276,7 +343,9 @@ def _slots(table) -> Dict[int, int]:
     return out
 
 
-def _ring_table(state, lanes: int) -> jnp.ndarray:
+def _ring_table(state, lanes: int):
+    if not state["window"]:
+        return None
     ring = state["window"][0]
     width = (ring.shape[0] - 1) // lanes
     return (1 + jnp.arange(lanes, dtype=jnp.int32)[:, None] * width
@@ -309,6 +378,20 @@ def _rows_layer(layer, spec, i, x, pos, live, state, slots, page_table,
             mixed, handed[i] = _mamba_out(layer, y, z)
     elif spec.kind == "gmu":
         mixed = _gmu(layer, u, handed[spec.reads])
+    elif spec.kind == "latent_attention":
+        with jax.named_scope("latent_attention"):
+            j = slots[i]
+            pages = state["latent"][j]
+            queries, rows = latent.project(layer, u, pos, config,
+                                           pages.shape[-1])
+            if write:
+                pages = state["latent"][j] = append_token_kv(
+                    pages, rows[:, None], None, page_table, pos, live,
+                    page_size)
+            attn = latent_paged_attention(
+                queries, pages, page_table, seq_lens, latent.scale(config),
+                config.kv_lora_rank, use_pallas)
+            mixed = latent.output(layer, attn, config)
     elif spec.kind == "window_attention":
         with jax.named_scope("window_attention"):
             j = slots[i]
@@ -335,7 +418,7 @@ def _rows_layer(layer, spec, i, x, pos, live, state, slots, page_table,
                 seq_lens, _scale(config), "shared_kv_attention_decode",
                 use_pallas)
             mixed = _differential_out(layer, attn, config, i)
-    return _close(layer, x, mixed, config)
+    return _close(layer, spec, x, mixed, live, state, config)
 
 
 def _copy_state(state) -> dict:
@@ -408,6 +491,19 @@ def forward_ragged(params, config, tokens, token_seq, token_pos, q_start,
                 state["conv"][j] = jnp.where(
                     has_slice[:, None, None], tail, state["conv"][j])
                 mixed, handed[i] = _mamba_out(layer, y, z)
+        elif spec.kind == "latent_attention":
+            with jax.named_scope("latent_attention"):
+                j = slots[i]
+                pages = state["latent"][j]
+                queries, rows = latent.project(layer, u, token_pos, config,
+                                               pages.shape[-1])
+                pages = state["latent"][j] = write_ragged_kv(
+                    pages, rows[:, None], None, page_table, token_seq,
+                    token_pos, page_size)
+                attn = latent_ragged_attention(
+                    queries, pages, page_table, q_start, q_len, kv_start,
+                    latent.scale(config), config.kv_lora_rank, use_pallas)
+                mixed = latent.output(layer, attn, config)
         elif spec.kind == "window_attention":
             with jax.named_scope("window_attention"):
                 j = slots[i]
@@ -444,7 +540,7 @@ def forward_ragged(params, config, tokens, token_seq, token_pos, q_start,
             raise NotImplementedError(
                 f"layer {i} ({spec.kind}) in the packed forward before the "
                 "last layer that writes state")
-        x = _close(layer, x, mixed, config)
+        x = _close(layer, spec, x, mixed, token_seq >= 0, state, config)
         if i == last_writer:
             x = x[last_idx]
             handed = {key: m[last_idx] for key, m in handed.items()}

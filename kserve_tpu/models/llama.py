@@ -66,9 +66,11 @@ def _map_hidden_act(act) -> str:
 
 
 #: what a layer's mixer can be, and what state it writes
-MIXER_KINDS = ("attention", "window_attention", "cross_attention", "mamba", "gmu")
+MIXER_KINDS = ("attention", "window_attention", "cross_attention", "mamba",
+               "gmu", "latent_attention")
 _WRITES = {"attention": "paged_kv", "window_attention": "window_kv",
-           "cross_attention": "none", "mamba": "recurrent", "gmu": "none"}
+           "cross_attention": "none", "mamba": "recurrent", "gmu": "none",
+           "latent_attention": "latent_kv"}
 
 #: model_type values LlamaConfig's family knobs describe
 _LLAMA_MODEL_TYPES = (None, "llama", "mistral", "mixtral", "qwen2", "qwen3",
@@ -93,11 +95,14 @@ class LayerSpec:
     it reads (itself where it reads its own).  The forward
     (models/hybrid.py), the cache manager (engine/kvcache.StateLayout),
     parallel/sharding.param_pspecs, the weight initialiser and the
-    checkpoint loader all derive from these rows."""
+    checkpoint loader all derive from these rows.  `ffn` is the layer's
+    feed-forward: `dense` (one gated MLP) or `experts` (a router over
+    routed experts, models/moe.py)."""
 
     kind: str
     writes: str
     reads: int
+    ffn: str = "dense"
 
 
 def phi4flash_mixer_kinds(n_layers: int, mb_per_layer: int) -> Tuple[str, ...]:
@@ -172,6 +177,22 @@ class LlamaConfig:
     mamba_d_state: int = 0
     mamba_d_conv: int = 0
     mamba_dt_rank: int = 0
+    # ---- latent attention (models/latent.py): queries through a rank
+    # q_lora_rank bottleneck, keys and values through ONE compressed row of
+    # kv_lora_rank values plus qk_rope_head_dim roped ones a token, which is
+    # all the cache holds; 0 = not a latent-attention model ----
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # ---- routed experts beside Mixtral's (models/moe.py) ----
+    moe_intermediate_size: int = 0  # an expert's width; 0 = intermediate_size
+    n_shared_experts: int = 0  # experts every token passes, beside the routed
+    first_k_dense: int = 0  # leading layers whose feed-forward stays dense
+    moe_router: str = "softmax"  # "sigmoid": scores sigmoid, a bias chooses
+    routed_scaling_factor: float = 1.0
+    norm_topk_prob: bool = True
     # ---- looped models (arXiv:2510.25741): the stack runs n_passes times
     # a token over ONE set of weights, the final norm closes every pass and
     # every (pass, layer) keeps K/V rows of its own ----
@@ -204,6 +225,32 @@ class LlamaConfig:
     def is_hybrid(self) -> bool:
         return self.mixer_kinds is not None
 
+    @property
+    def is_latent(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
+    def latent_width(self) -> int:
+        """Values a token leaves in a latent layer's cache: the compressed
+        row and the one roped key all heads share."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+    def ffn_kind(self, i: int) -> str:
+        """Layer i's feed-forward: `dense` or `experts`."""
+        return ("experts" if self.n_experts > 0 and i >= self.first_k_dense
+                else "dense")
+
+    @property
+    def n_expert_layers(self) -> int:
+        return sum(self.ffn_kind(i) == "experts" for i in range(self.n_layers))
+
+    @property
+    def has_expert_sums(self) -> bool:
+        """Whether the model's state carries its expert layers' sums
+        (models/hybrid._ffn adds to `state["stats"]`; the `mixed` program
+        returns them with its tokens)."""
+        return self.is_hybrid and self.n_expert_layers > 0
+
     def layer_table(self) -> Tuple[LayerSpec, ...]:
         """The per-layer table.  A Llama-family model is n_layers equal
         rows: attention over its own paged K/V."""
@@ -218,7 +265,8 @@ class LlamaConfig:
                 if reads < 0:
                     raise ValueError(
                         f"layer {i} ({kind}) has no {wanted} layer before it")
-            table.append(LayerSpec(kind, _WRITES[kind], reads))
+            table.append(
+                LayerSpec(kind, _WRITES[kind], reads, self.ffn_kind(i)))
         return tuple(table)
 
     @property
@@ -354,6 +402,8 @@ class LlamaConfig:
         model_type = cfg.get("model_type")
         if model_type == "phi4flash":
             return _phi4flash_config(cfg)
+        if model_type == "glm4_moe_lite":
+            return _glm4_moe_lite_config(cfg)
         if model_type not in _LLAMA_MODEL_TYPES:
             foreign = [k for k in _FOREIGN_MIXER_KEYS if k in cfg
                        and not (k == "total_ut_steps" and int(cfg[k]) <= 1)]
@@ -468,6 +518,65 @@ def _phi4flash_config(cfg: dict) -> LlamaConfig:
         mamba_d_state=cfg.get("mamba_d_state", 16),
         mamba_d_conv=cfg.get("mamba_d_conv", 4),
         mamba_dt_rank=cfg.get("mamba_dt_rank", -(-h // 16)),
+    )
+
+
+def _glm4_moe_lite_config(cfg: dict) -> LlamaConfig:
+    """config.json of `model_type: glm4_moe_lite`: latent attention in every
+    layer, `first_k_dense_replace` dense feed-forwards and routed experts
+    (sigmoid router, a choice-only bias, a shared expert) behind them.
+    What the published file leaves to the modeling file is listed under
+    `assumed` in benchmark/configs/glm47-flash.json.  The next-token-
+    prediction module (`num_nextn_predict_layers`) is neither built nor
+    run: the published causal-LM forward does not evaluate it."""
+    refused = []
+    if int(cfg.get("n_group", 1)) != 1 or int(cfg.get("topk_group", 1)) != 1:
+        refused.append(f"n_group={cfg.get('n_group')} / topk_group="
+                       f"{cfg.get('topk_group')} (group-limited routing)")
+    if cfg.get("topk_method", "noaux_tc") != "noaux_tc":
+        refused.append(f"topk_method={cfg['topk_method']!r}")
+    if cfg.get("rope_scaling") is not None:
+        refused.append("rope_scaling (the softmax scale's extra factor is "
+                       "not implemented)")
+    if float(cfg.get("partial_rotary_factor", 1)) != 1:
+        refused.append(f"partial_rotary_factor={cfg['partial_rotary_factor']}")
+    if cfg.get("attention_bias"):
+        refused.append("attention_bias")
+    if not cfg.get("q_lora_rank"):
+        refused.append("q_lora_rank null (queries without the bottleneck)")
+    if int(cfg.get("n_shared_experts", 1)) > 1:
+        refused.append(f"n_shared_experts={cfg['n_shared_experts']}")
+    if refused:
+        raise ValueError(
+            "glm4_moe_lite: not implemented: " + "; ".join(refused))
+    n_layers = cfg["num_hidden_layers"]
+    return LlamaConfig(
+        vocab_size=cfg["vocab_size"],
+        hidden_size=cfg["hidden_size"],
+        intermediate_size=cfg["intermediate_size"],
+        n_layers=n_layers,
+        n_heads=cfg["num_attention_heads"],
+        n_kv_heads=cfg["num_attention_heads"],
+        head_dim=cfg["qk_rope_head_dim"],
+        rope_theta=cfg.get("rope_theta", 10000.0),
+        rms_norm_eps=cfg.get("rms_norm_eps", 1e-5),
+        max_position_embeddings=cfg.get("max_position_embeddings", 4096),
+        tie_word_embeddings=cfg.get("tie_word_embeddings", False),
+        hidden_act=_map_hidden_act(cfg.get("hidden_act")),
+        mixer_kinds=("latent_attention",) * n_layers,
+        q_lora_rank=cfg["q_lora_rank"],
+        kv_lora_rank=cfg["kv_lora_rank"],
+        qk_nope_head_dim=cfg["qk_nope_head_dim"],
+        qk_rope_head_dim=cfg["qk_rope_head_dim"],
+        v_head_dim=cfg["v_head_dim"],
+        n_experts=cfg["n_routed_experts"],
+        n_experts_per_tok=cfg["num_experts_per_tok"],
+        moe_intermediate_size=cfg["moe_intermediate_size"],
+        n_shared_experts=int(cfg.get("n_shared_experts", 0)),
+        first_k_dense=int(cfg.get("first_k_dense_replace", 0)),
+        moe_router="sigmoid",
+        routed_scaling_factor=float(cfg.get("routed_scaling_factor", 1.0)),
+        norm_topk_prob=bool(cfg.get("norm_topk_prob", True)),
     )
 
 
@@ -620,16 +729,10 @@ def _qkv(layer: Params, x: jnp.ndarray, config: LlamaConfig, onehot=None):
 
 @jax.named_scope("mlp")
 def _mlp(layer: Params, x: jnp.ndarray, config: LlamaConfig, onehot=None) -> jnp.ndarray:
-    if config.n_experts > 0:
-        from .moe import MoEConfig, moe_mlp
+    if "router" in layer:  # this layer's feed-forward is routed experts
+        from .moe import moe_config_of, moe_mlp
 
-        moe_cfg = MoEConfig(
-            n_experts=config.n_experts,
-            top_k=config.n_experts_per_tok,
-            hidden_size=config.hidden_size,
-            intermediate_size=config.intermediate_size,
-        )
-        return moe_mlp(layer, x, moe_cfg)
+        return moe_mlp(layer, x, moe_config_of(config))
     lora = layer.get("lora")
     gate = _act(
         _maybe_add(dense(x, layer["w_gate"]), lora_delta(lora, "w_gate", x, onehot)),

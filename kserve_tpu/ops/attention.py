@@ -569,6 +569,17 @@ def describe_attention_dispatch(model_config, engine_config,
     mc, cfg = model_config, engine_config
     quantized = cfg.kv_quant == "int8"
     min_pages = None
+    if mc.is_latent:
+        # models/latent.py: both reads of the latent pages are the kernels
+        # on a TPU and the XLA references on a K/V view elsewhere
+        pallas = latent_uses_pallas(cfg.use_pallas, backend)
+        return {
+            "backend": backend,
+            "mixed": "pallas_latent_ragged" if pallas else "xla_ragged_gather",
+            "decode": "pallas_latent_decode" if pallas else "xla_gather",
+            "decode_pallas_min_pages": None,
+            "shard_map": False,
+        }
     if mc.is_hybrid:
         # models/hybrid.py: every read of a cache is one query per lane (the
         # decode kernel or its gather, gated as below at the cache's row
@@ -773,3 +784,74 @@ def ring_window_attention_ragged(
     out = out + jnp.einsum("nkgbs,skd->nbkgd", weights[..., R:], v_new,
                            preferred_element_type=f32)
     return out.reshape(T, nq, d).astype(q.dtype)
+
+
+# ---------------- latent pages (models/latent.py) ----------------
+#
+# A latent-attention layer's cache is one row a token (engine/kvcache.
+# StateLayout, `latent`): pages [num_pages, 1, 1, ps, row].  In the absorbed
+# form attention is ONE key/value head over those rows, the value the row's
+# first `value_dim` columns.  On the TPU the Pallas kernels read a page once
+# for both (ops/pallas_paged_attention.py, `latent_attention_decode` /
+# `latent_attention_ragged`); elsewhere the XLA references above run on a
+# K/V view of the pages (a copy: a correctness path).
+
+
+def _latent_as_kv(pages: jnp.ndarray) -> jnp.ndarray:
+    """[P, 1, 1, ps, row] -> [P, 2, 1, ps, row]: the row as key and as value."""
+    return jnp.concatenate([pages, pages], axis=1)
+
+
+def latent_uses_pallas(use_pallas: Optional[bool],
+                       backend: Optional[str] = None) -> bool:
+    """The kernels on a TPU at every shape (whatever `head_dim` says: the
+    row is padded to the lanes), the XLA references elsewhere."""
+    if use_pallas is not None:
+        return bool(use_pallas)
+    return (backend or jax.default_backend()) == "tpu"
+
+
+def latent_paged_attention(
+    q: jnp.ndarray,  # [B, nq, row] absorbed queries (zero where the row pads)
+    pages: jnp.ndarray,  # [num_pages, 1, 1, ps, row]
+    page_table: jnp.ndarray,  # [B, W]
+    seq_lens: jnp.ndarray,  # [B]
+    scale: float,
+    value_dim: int,
+    use_pallas: Optional[bool] = None,
+) -> jnp.ndarray:
+    """One query token a lane over its latent pages -> [B, nq, value_dim].
+    The kernel at every table width on a TPU: the gather it would be
+    weighed against copies [B, W ps, row] three times (docs/kernels.md)."""
+    if latent_uses_pallas(use_pallas):
+        from .pallas_paged_attention import latent_attention_decode_pallas
+
+        return latent_attention_decode_pallas(
+            q, pages, page_table, seq_lens, scale, value_dim)
+    out = paged_attention_xla(
+        q, _latent_as_kv(pages), page_table, seq_lens, scale=scale)
+    return out[..., :value_dim]
+
+
+def latent_ragged_attention(
+    q: jnp.ndarray,  # [T, nq, row]
+    pages: jnp.ndarray,  # [num_pages, 1, 1, ps, row]
+    page_table: jnp.ndarray,  # [B, W]
+    q_start: jnp.ndarray,  # [B]
+    q_len: jnp.ndarray,  # [B]
+    kv_start: jnp.ndarray,  # [B]
+    scale: float,
+    value_dim: int,
+    use_pallas: Optional[bool] = None,
+) -> jnp.ndarray:
+    """The packed step's attention over latent pages (the ragged contract:
+    the slice's rows are already written) -> [T, nq, value_dim]."""
+    if latent_uses_pallas(use_pallas):
+        from .pallas_paged_attention import latent_attention_ragged_pallas
+
+        return latent_attention_ragged_pallas(
+            q, pages, page_table, q_start, q_len, kv_start, scale, value_dim)
+    out = ragged_paged_attention_xla(
+        q, _latent_as_kv(pages), page_table, q_start, q_len, kv_start,
+        scale=scale)
+    return out[..., :value_dim]

@@ -134,10 +134,10 @@ def _per_row(ref, base, n):
     return out
 
 
-def _pallas_call(kernel, B, sb, nq, lane, kv_arr):
-    """Shared PrefetchScalarGridSpec + pallas_call builder: q/out blocks
-    are [SB, nq, lane], the cache stays in HBM, scratch is the NBUF-deep
-    VMEM ring + DMA semaphores."""
+def _pallas_call(kernel, B, sb, nq, lane, kv_arr, out_lane=None):
+    """Shared PrefetchScalarGridSpec + pallas_call builder: q blocks are
+    [SB, nq, lane] and out blocks [SB, nq, out_lane or lane], the cache
+    stays in HBM, scratch is the NBUF-deep VMEM ring + DMA semaphores."""
     return functools.partial(
         pl.pallas_call,
         kernel,
@@ -148,7 +148,8 @@ def _pallas_call(kernel, B, sb, nq, lane, kv_arr):
                 pl.BlockSpec((sb, nq, lane), lambda g, *_: (g, 0, 0)),
                 pl.BlockSpec(memory_space=_HBM),
             ],
-            out_specs=pl.BlockSpec((sb, nq, lane), lambda g, *_: (g, 0, 0)),
+            out_specs=pl.BlockSpec(
+                (sb, nq, out_lane or lane), lambda g, *_: (g, 0, 0)),
             scratch_shapes=[
                 pltpu.VMEM((NBUF, sb) + kv_arr.shape[1:], kv_arr.dtype),
                 pltpu.SemaphoreType.DMA((NBUF, sb)),
@@ -176,10 +177,15 @@ def _decode_kernel(
     head_dim: int,
     scale: float,
     logit_softcap: float,
+    value_dim: Optional[int] = None,
 ):
+    """`value_dim` set: a LATENT page [1, 1, ps, d] (one plane, one row a
+    token; engine/kvcache.StateLayout): the row is the key, its first
+    `value_dim` columns are the value, so a page is fetched once."""
     g = pl.program_id(0)
     nq = q_ref.shape[1]
     group = nq // num_kv_heads
+    vd = value_dim or head_dim
 
     num_pages = _block_pages(seq_lens_ref, g, sb, page_size)
     start_iter = _make_start_iter(
@@ -196,7 +202,10 @@ def _decode_kernel(
             start_iter, kv_hbm_ref, kv_bufs, sems, sb, i, num_pages)
 
         k = kv_bufs[slot, :, 0].astype(jnp.float32)  # [SB, nkv, ps, d]
-        v = kv_bufs[slot, :, 1].astype(jnp.float32)
+        if value_dim is None:
+            v = kv_bufs[slot, :, 1].astype(jnp.float32)
+        else:
+            v = k[..., :value_dim]
         s_ = _heads_dot(q, k, 2) * scale  # [SB, nkv, group, ps]
         if logit_softcap > 0.0:
             s_ = jnp.tanh(s_ / logit_softcap) * logit_softcap
@@ -214,10 +223,10 @@ def _decode_kernel(
 
     m0 = jnp.full((sb, num_kv_heads, group, 1), -1e30, jnp.float32)
     l0 = jnp.zeros((sb, num_kv_heads, group, 1), jnp.float32)
-    acc0 = jnp.zeros((sb, num_kv_heads, group, head_dim), jnp.float32)
+    acc0 = jnp.zeros((sb, num_kv_heads, group, vd), jnp.float32)
     m, l, acc = jax.lax.fori_loop(0, num_pages, body, (m0, l0, acc0))
     out = acc / jnp.maximum(l, 1e-30)
-    out_ref[...] = out.reshape(sb, nq, head_dim).astype(out_ref.dtype)
+    out_ref[...] = out.reshape(sb, nq, vd).astype(out_ref.dtype)
 
 
 def _packed_decode_kernel(
@@ -452,12 +461,14 @@ def _ragged_kernel(
     scale: float,
     logit_softcap: float,
     quantized: bool,
+    value_dim: Optional[int] = None,  # set: latent pages (_decode_kernel)
 ):
     if quantized:
         scales_hbm_ref, out_ref, kv_bufs, kv_sems, s_bufs, s_sems = rest
     else:
         out_ref, kv_bufs, kv_sems = rest
         scales_hbm_ref = s_bufs = s_sems = None
+    vd = value_dim or head_dim
 
     g = pl.program_id(0)
     s_raw = block_seq_ref[g]
@@ -516,7 +527,10 @@ def _ragged_kernel(
             start_iter(i + NBUF - 1, jax.lax.rem(i + NBUF - 1, NBUF))
 
         k = kv_bufs[slot, 0].astype(jnp.float32)  # [nkv, ps, d]
-        v = kv_bufs[slot, 1].astype(jnp.float32)
+        if value_dim is None:
+            v = kv_bufs[slot, 1].astype(jnp.float32)
+        else:
+            v = k[..., :value_dim]
         if quantized:
             k = k * s_bufs[slot, 0].astype(jnp.float32)[..., None]
             v = v * s_bufs[slot, 1].astype(jnp.float32)[..., None]
@@ -537,16 +551,16 @@ def _ragged_kernel(
 
     m0 = jnp.full((num_kv_heads, rows, 1), -1e30, jnp.float32)
     l0 = jnp.zeros((num_kv_heads, rows, 1), jnp.float32)
-    acc0 = jnp.zeros((num_kv_heads, rows, head_dim), jnp.float32)
+    acc0 = jnp.zeros((num_kv_heads, rows, vd), jnp.float32)
     m, l, acc = jax.lax.fori_loop(0, num_pages, body, (m0, l0, acc0))
     # rows past their slice's q_len never see a valid key: their running
     # max stays -1e30, so exp(s - m) saturates to 1 and acc collects a
     # garbage mean of V — mask them to exact zero instead
     out = jnp.where(qvalid, acc / jnp.maximum(l, 1e-30), 0.0)
     out_ref[...] = (
-        out.reshape(num_kv_heads, bq, group, head_dim)
+        out.reshape(num_kv_heads, bq, group, vd)
         .transpose(1, 0, 2, 3)
-        .reshape(bq, nq, head_dim)
+        .reshape(bq, nq, vd)
         .astype(out_ref.dtype)
     )
 
@@ -835,3 +849,83 @@ def ragged_paged_attention_pallas(
         name="ragged_paged_attention",
     )(block_seq, block_qoff, page_table, kv_start, q_len, win,
       *operands)
+
+
+# ---------------- latent pages (models/latent.py) ----------------
+#
+# A latent-attention layer keeps ONE row a token: [compressed K/V | the
+# roped key all heads share | zeros up to a multiple of 128 lanes], in pages
+# [num_pages, 1, 1, ps, row] (engine/kvcache.StateLayout).  In the absorbed
+# form every query head is a query over that row, and the row's first
+# `value_dim` columns are the value: attention with ONE key/value head whose
+# value is a slice of its key.  The two kernels above run it with
+# `value_dim` set: a page is fetched once and serves scores and values.
+
+
+def latent_attention_decode_pallas(
+    q: jnp.ndarray,  # [B, nq, row]: absorbed queries, zero where the row pads
+    pages: jnp.ndarray,  # [num_pages, 1, 1, ps, row]
+    page_table: jnp.ndarray,  # [B, W] int32
+    seq_lens: jnp.ndarray,  # [B] int32
+    scale: float,
+    value_dim: int,
+    interpret: bool = False,
+) -> jnp.ndarray:
+    """One query token a lane over its latent pages -> [B, nq, value_dim]."""
+    B, nq, d = q.shape
+    sb = _pick_sb(B)
+    kernel = functools.partial(
+        _decode_kernel, sb=sb, page_size=pages.shape[3], num_kv_heads=1,
+        head_dim=d, scale=float(scale), logit_softcap=0.0,
+        value_dim=value_dim)
+    return _pallas_call(kernel, B, sb, nq, d, pages, out_lane=value_dim)(
+        out_shape=jax.ShapeDtypeStruct((B, nq, value_dim), q.dtype),
+        interpret=interpret,
+        name="latent_attention_decode",
+    )(page_table, seq_lens, q, pages)
+
+
+def latent_attention_ragged_pallas(
+    q: jnp.ndarray,  # [T, nq, row] packed at RAGGED_BQ-aligned offsets
+    pages: jnp.ndarray,  # [num_pages, 1, 1, ps, row]
+    page_table: jnp.ndarray,  # [B, W] int32
+    q_start: jnp.ndarray,  # [B]
+    q_len: jnp.ndarray,  # [B]
+    kv_start: jnp.ndarray,  # [B]
+    scale: float,
+    value_dim: int,
+    interpret: bool = False,
+) -> jnp.ndarray:
+    """The packed step's attention over latent pages (the ragged contract
+    of `ragged_paged_attention_pallas`) -> [T, nq, value_dim]."""
+    T, nq, d = q.shape
+    if T % RAGGED_BQ != 0:
+        raise ValueError(
+            f"ragged buffer length {T} not a multiple of RAGGED_BQ={RAGGED_BQ}")
+    G = T // RAGGED_BQ
+    block_seq, block_qoff = _ragged_block_metadata(q_start, q_len, G, RAGGED_BQ)
+    kernel = functools.partial(
+        _ragged_kernel, bq=RAGGED_BQ, page_size=pages.shape[3],
+        num_kv_heads=1, head_dim=d, scale=float(scale), logit_softcap=0.0,
+        quantized=False, value_dim=value_dim)
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=6,
+            grid=(G,),
+            in_specs=[
+                pl.BlockSpec((RAGGED_BQ, nq, d), lambda g, *_: (g, 0, 0)),
+                pl.BlockSpec(memory_space=_HBM),
+            ],
+            out_specs=pl.BlockSpec(
+                (RAGGED_BQ, nq, value_dim), lambda g, *_: (g, 0, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((NBUF,) + pages.shape[1:], pages.dtype),
+                pltpu.SemaphoreType.DMA((NBUF,)),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((T, nq, value_dim), q.dtype),
+        interpret=interpret,
+        name="latent_attention_ragged",
+    )(block_seq, block_qoff, page_table, kv_start, q_len,
+      jnp.zeros((1,), jnp.int32), q, pages)

@@ -82,12 +82,17 @@ def param_pspecs(config: LlamaConfig) -> Dict[str, Any]:
         # tp=1 only (validate_tp): every leaf of every row replicated
         from ..models import hybrid
 
-        return {
-            "embed": P(), "final_norm": P(), "final_norm_b": P(),
+        specs = {
+            "embed": P(), "final_norm": P(),
             "layers": [
                 {name: P() for name in hybrid.layer_param_shapes(config, spec)}
                 for spec in config.layer_table()],
         }
+        if config.norm_type == "layernorm":
+            specs["final_norm_b"] = P()
+        if not config.tie_word_embeddings:
+            specs["lm_head"] = P()
+        return specs
     layer = {
         "attn_norm": P(),
         "wq": P(None, MODEL_AXIS),  # column parallel (heads)

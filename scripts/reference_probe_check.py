@@ -55,6 +55,10 @@ def main(argv=None) -> int:
                     help="send the probes at once: lanes share dispatches")
     ap.add_argument("--weights", choices=("bf16", "int8"), default="bf16",
                     help="int8: also read the reference with int8 weights")
+    ap.add_argument("--experts", action="store_true",
+                    help="also count, on the host CPU, the (position, expert "
+                    "layer) decisions at which the program's router and the "
+                    "reference's chose different experts")
     args = ap.parse_args(argv)
     plan = bench_run.Plan(manifest.resolve_cell(args.workload), rehearse=args.cpu)
     platform = "cpu" if args.cpu else "tpu"
@@ -109,6 +113,14 @@ def main(argv=None) -> int:
             env=env, check=True)
         with open(out_path + ".int8") as f:
             line["int8_weights"] = json.load(f)
+    if args.experts:
+        subprocess.run(
+            [sys.executable, os.path.abspath(__file__), "--experts-child",
+             os.path.join(bench_run.model_dir_of(plan, cache), "config.json"),
+             plan.cell.deployment["family"], probes_path, out_path + ".experts"],
+            env=env, check=True)
+        with open(out_path + ".experts") as f:
+            line["expert_choices"] = json.load(f)
     print(json.dumps(line))
     return 0 if result["max_gap"] <= tolerance else 1
 
@@ -132,14 +144,20 @@ def int8_child(config_path, family_name, probes_path, out_path) -> int:
     params = check.program_weights(config_path)
 
     def rounded(w):
+        # per output channel: the contraction axis is the last but one
+        # ([in, out], and [experts, in, out] of stacked experts)
         w = np.asarray(w, np.float32)
-        scale = np.maximum(np.abs(w).max(axis=0, keepdims=True) / 127.0, 1e-8)
+        scale = np.maximum(np.abs(w).max(axis=-2, keepdims=True) / 127.0, 1e-8)
         return jax.numpy.asarray(np.round(w / scale).clip(-127, 127) * scale)
 
-    linear = ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down")
-    params = dict(params, layers=[
-        {k: rounded(v) if k in linear else v for k, v in layer.items()}
-        for layer in params["layers"]])
+    linear = ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down",
+              # latent attention's projections and the shared expert
+              "wq_a", "wq_b", "wkv_a", "wkv_b",
+              "shared_gate", "shared_up", "shared_down")
+    for i, layer in enumerate(params["layers"]):
+        # in place: a layer's bf16 tensors go as its float32 ones come
+        params["layers"][i] = {k: rounded(v) if k in linear else v
+                               for k, v in layer.items()}
     if "lm_head" in params:
         params["lm_head"] = rounded(params["lm_head"])
     gaps, matches, total = [], 0, 0
@@ -157,7 +175,75 @@ def int8_child(config_path, family_name, probes_path, out_path) -> int:
     return 0
 
 
+def experts_child(config_path, family_name, probes_path, out_path) -> int:
+    """How often a top-k router, which is discontinuous, chooses other
+    experts in the program's precision than in the reference's float32.
+    The program's own packed forward (kserve_tpu, bf16 weights and
+    activations, the XLA attention path) runs teacher-forced over each
+    probe's prompt + served tokens ON THE HOST CPU, as the reference does;
+    both routers' choices are recorded and compared as sets, per (expert
+    layer, position).  A count, not a device metric: the chip's bf16 rounds
+    like the CPU's but not bit for bit, so the chip's own count differs by
+    a few decisions."""
+    sys.path.insert(0, os.path.join(ROOT, "benchmark", "reference"))
+    import check  # noqa: E402
+    import jax.numpy as jnp
+    import numpy as np
+
+    with open(config_path) as f:
+        cfg = json.load(f)
+    with open(probes_path) as f:
+        probes = json.load(f)
+    family = check.load_family(family_name)
+    params = check.program_weights(config_path)
+    from kserve_tpu.engine.kvcache import StateLayout
+    from kserve_tpu.models import llama, moe
+
+    config = llama.LlamaConfig.from_hf_config(config_path)
+    chosen = {"program": [], "reference": []}
+
+    def recording(route, into):
+        def wrapped(*a, **k):
+            weights, selected = route(*a, **k)
+            chosen[into].append(np.sort(np.asarray(selected), axis=-1))
+            return weights, selected
+        return wrapped
+
+    moe.route = recording(moe.route, "program")
+    family.route = recording(family.route, "reference")
+    page = 64
+    out = {"decisions": 0, "differ": 0, "positions": 0,
+           "positions_with_a_difference": 0}
+    for probe in probes:
+        tokens = probe["prompt"] + probe["served"][:-1]
+        n, first = len(tokens), len(probe["prompt"]) - 1
+        width = -(-n // page)
+        layout = StateLayout.of(config, page, width + 1, 1, config.dtype)
+        for rows in chosen.values():
+            rows.clear()
+        llama.forward_ragged(
+            params, config, jnp.asarray(tokens, jnp.int32),
+            jnp.zeros(n, jnp.int32), jnp.arange(n, dtype=jnp.int32),
+            jnp.zeros(1, jnp.int32), jnp.full((1,), n, jnp.int32),
+            jnp.zeros(1, jnp.int32), layout.init_state(),
+            1 + jnp.arange(width, dtype=jnp.int32)[None, :], page,
+            jnp.full((1,), n - 1, jnp.int32), use_pallas=False)
+        family.forward(params, cfg, tokens)
+        differ = np.stack([
+            (a[first:] != b[first:]).any(axis=-1)
+            for a, b in zip(chosen["program"], chosen["reference"])])
+        out["decisions"] += int(differ.size)
+        out["differ"] += int(differ.sum())
+        out["positions"] += int(differ.shape[1])
+        out["positions_with_a_difference"] += int(differ.any(axis=0).sum())
+    with open(out_path, "w") as f:
+        json.dump(out, f)
+    return 0
+
+
 if __name__ == "__main__":
     if len(sys.argv) > 1 and sys.argv[1] == "--int8-child":
         sys.exit(int8_child(*sys.argv[2:6]))
+    if len(sys.argv) > 1 and sys.argv[1] == "--experts-child":
+        sys.exit(experts_child(*sys.argv[2:6]))
     sys.exit(main())

@@ -124,6 +124,13 @@ def test_alignment_follows_where_the_ragged_kernel_can_run(
     assert DispatchShapes.of(model, cfg, backend).align == align
 
 
+@pytest.mark.parametrize("backend,align", [("tpu", RAGGED_BQ), ("cpu", 1)])
+def test_latent_pages_take_the_kernel_on_a_tpu_whatever_the_head_size(
+        backend, align):
+    model = types.SimpleNamespace(cache_head_dim=64, is_latent=True)
+    assert DispatchShapes.of(model, EngineConfig(), backend).align == align
+
+
 def test_buckets_sp_cannot_split_are_refused():
     cfg = EngineConfig(sp=4, max_prefill_len=30, prefill_buckets=(16, 30))
     with pytest.raises(ValueError, match=r"prefill buckets \[30\] not "

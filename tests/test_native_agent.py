@@ -260,14 +260,15 @@ async def test_file_sink_csv_marshaller(agent_binary, tmp_path):
             )
             assert r.status_code == 200
         deadline = time.time() + 5
-        files = []
+        files, lines = [], []
         while time.time() < deadline:
+            # the file exists before the batch is written into it
             files = sorted(log_dir.glob("payloads-*.csv"))
-            if files:
+            lines = files[0].read_text().splitlines() if files else []
+            if len(lines) >= 3:
                 break
             await asyncio.sleep(0.1)
         assert files
-        lines = files[0].read_text().splitlines()
         assert lines[0] == "id,type,path,payload"
         assert len(lines) == 3  # header + request + response
         assert "request" in lines[1] and "[[5,6]]" in lines[1].replace('""', '"').replace(" ", "")

@@ -557,3 +557,66 @@ class TestLoopedMixedProgram:
         assert att.pallas_min_pages(128, 16, 16, 12) == 0
         for width in (8, 16, 24):
             assert att._should_use_pallas(128, False, width, 12, "tpu", 16, 16)
+
+
+class TestLatentPagesAndGroupedExperts:
+    """`model_type: glm4_moe_lite` (PR 37): both reads of the latent pages
+    are kernels that compile for the chip at the published widths (20 query
+    heads over a row of 576 values stored in 640 columns, the value its
+    first 512), whatever the page size; the `mixed` program of such a model
+    calls them and holds nothing of [tokens, experts, width]."""
+
+    @pytest.mark.parametrize("ps", [16, 64, 128])
+    def test_kernels_compile_at_the_published_widths(self, ps):
+        pages = _abstract((294400 // ps, 1, 1, ps, 640), jnp.bfloat16)
+        width = 3200 // ps
+        decode = functools.partial(
+            pk.latent_attention_decode_pallas, scale=1 / 16, value_dim=512)
+        _check(decode, _abstract((48, 20, 640), jnp.bfloat16), pages,
+               _i32(48, width), _i32(48))
+        ragged = functools.partial(
+            pk.latent_attention_ragged_pallas, scale=1 / 16, value_dim=512)
+        args = (_abstract((2048, 20, 640), jnp.bfloat16), pages,
+                _i32(48, width), _i32(48), _i32(48), _i32(48))
+        _check(ragged, *args)
+        text = jax.jit(ragged).trace(*args).lower(
+            lowering_platforms=("tpu",)).as_text()
+        assert "latent_attention_ragged" in text
+        assert "tensor<2048x20x512xbf16>" in text  # the value's width out
+
+    def test_mixed_calls_the_latent_kernels_and_groups_its_experts(
+            self, monkeypatch):
+        import dataclasses
+        import re
+
+        from kserve_tpu.engine.types import EngineConfig
+        from kserve_tpu.models import llama
+        from test_glm_model import CFG
+
+        mc = dataclasses.replace(
+            llama.LlamaConfig.from_hf_config(
+                dict(CFG, hidden_size=128, kv_lora_rank=96, qk_rope_head_dim=32,
+                     n_routed_experts=16, moe_intermediate_size=80)),
+            dtype="bfloat16")
+        cfg = EngineConfig(
+            max_batch_size=8, page_size=16, num_pages=64, max_pages_per_seq=16,
+            max_prefill_len=128, prefill_buckets=(128,), dtype="bfloat16")
+        layout = kvcache.StateLayout.of(mc, 16, cfg.num_pages, 8, "bfloat16")
+        assert layout.latent_row == 128
+        text = _lower_mixed(
+            mc, cfg, jax.eval_shape(layout.init_state), 16,
+            monkeypatch).as_text()
+        kernels = re.findall(r'kernel_name = "([a-z_]+)"', text)
+        # 3 layers: the packed step's kernel and the decode steps' once each
+        assert sorted(kernels) == ["latent_attention_decode"] * 3 + [
+            "latent_attention_ragged"] * 3
+        # 2 expert layers x (packed step + decode steps) x gate, up, down
+        assert len(re.findall(r"ragged_dot", text)) >= 12
+        # nothing over (tokens or pairs, experts, an expert's width)
+        assert not re.findall(r"tensor<(?:128|256|8|16)x16x80x", text)
+        # the dispatch's slices are packed at the kernel's block
+        from kserve_tpu.engine.shapes import DispatchShapes
+        assert DispatchShapes.of(mc, cfg, "tpu").align == pk.RAGGED_BQ
+        report = att.describe_attention_dispatch(mc, cfg, "tpu")
+        assert (report["mixed"], report["decode"]) == (
+            "pallas_latent_ragged", "pallas_latent_decode")
