@@ -29,6 +29,7 @@ from .sampling import (
     apply_penalties,
     compute_logprobs,
     sample_tokens,
+    sampler_top_k,
     sampler_truncates,
 )
 from .shapes import DispatchShapes
@@ -380,6 +381,7 @@ def program_defs(model_config, engine_config, mesh, spec_k=None) -> dict:
             steps = cfg.steps_per_sync
             B = tokens.shape[0]
             truncates = sampler_truncates(state)  # once, not once a step
+            top_k = sampler_top_k(state)  # likewise
 
             def body(carry, step_rng):
                 if with_penalties:
@@ -408,7 +410,8 @@ def program_defs(model_config, engine_config, mesh, spec_k=None) -> dict:
                         state.presence_penalty,
                         penalty_args[0],
                     )
-                nxt = sample_tokens(logits, state, step_rng, counters, truncates)
+                nxt = sample_tokens(
+                    logits, state, step_rng, counters, truncates, top_k)
                 nxt = jnp.where(live, nxt, tokens)
                 if with_logprobs:
                     lp, tv, ti = compute_logprobs(logits, nxt, cfg.max_logprobs)
@@ -526,6 +529,7 @@ def program_defs(model_config, engine_config, mesh, spec_k=None) -> dict:
             steps = cfg.steps_per_sync
             rngs = jax.random.split(rng, steps)
             truncates = sampler_truncates(state)  # once, not once a step
+            top_k = sampler_top_k(state)  # likewise
             if expert_stats:
                 # this dispatch's sums start at zero (kvcache.StateLayout)
                 kv_pages = dict(kv_pages, stats=[
@@ -539,7 +543,8 @@ def program_defs(model_config, engine_config, mesh, spec_k=None) -> dict:
                 use_pallas=cfg.use_pallas,
                 ragged_block=ragged_block,
             )
-            sampled0 = sample_tokens(logits, state, rngs[0], counters, truncates)
+            sampled0 = sample_tokens(
+                logits, state, rngs[0], counters, truncates, top_k)
             tokens0 = jnp.where(scan_tok0 >= 0, scan_tok0, sampled0)
             counters0 = counters + step0_emits
 
@@ -552,7 +557,8 @@ def program_defs(model_config, engine_config, mesh, spec_k=None) -> dict:
                     adapter_ids=adapter_ids,
                     attention_fn=decode_attention_fn,
                 )
-                nxt = sample_tokens(logits, state, step_rng, counters, truncates)
+                nxt = sample_tokens(
+                    logits, state, step_rng, counters, truncates, top_k)
                 nxt = jnp.where(live, nxt, tokens)
                 return (
                     nxt,
@@ -643,6 +649,7 @@ def program_defs(model_config, engine_config, mesh, spec_k=None) -> dict:
             # lane's own temperature/top-k/top-p/seed
             row_state = jax.tree.map(lambda a: jnp.repeat(a, Kp), state)
             truncates = sampler_truncates(state)  # once, not once a round
+            top_k = sampler_top_k(state)  # likewise
             rngs = jax.random.split(rng, rounds)
             lane_ix = jnp.arange(B)
 
@@ -685,7 +692,7 @@ def program_defs(model_config, engine_config, mesh, spec_k=None) -> dict:
                     cnt[:, None] + jnp.arange(Kp, dtype=cnt.dtype)[None, :]
                 ).reshape(-1)
                 sampled = sample_tokens(
-                    logits, row_state, step_rng, row_counters, truncates
+                    logits, row_state, step_rng, row_counters, truncates, top_k
                 ).reshape(B, Kp)
                 if k_drafts > 0:
                     match = (slice_toks[:, 1:] == sampled[:, :-1])

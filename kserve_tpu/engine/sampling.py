@@ -4,6 +4,12 @@ Per-sequence parameters travel as a struct-of-arrays (`SamplingParams`
 batch) so one compiled program serves any mix of greedy/temperature/top-k/
 top-p/min-p requests — no recompiles per request.
 
+top-k and top-p read their cutoffs from a threshold search over the
+floats' key (`_cutoff`), not from a sort of the vocabulary: top-k and min-p
+keep exactly what a sort would, the nucleus what the exact one of mass
+`top_p` -/+ 1e-5 bounds (the sums run in another order than a cumulative
+sum's: the acceptance rule is in tests/test_sampling.py).
+
 Role parity: vLLM's Sampler (the reference delegates sampling to vLLM).
 """
 
@@ -108,8 +114,10 @@ class SamplingState:
 
 
 #: the work a batch asks of the sampler: `truncate` where a sampled row
-#: carries top-k, top-p or min-p (one full-vocabulary sort a step),
-#: `plain` where none does (no sort); indexed by `_truncates`
+#: carries top-k, top-p or min-p (threshold searches, each 32
+#: compare-and-reduce passes over [rows, vocab] a step: one for the nucleus,
+#: one more where a sampled row sets top-k; nothing is sorted), `plain`
+#: where none does (no pass); indexed by `_truncates`
 SAMPLER_PATHS = ("plain", "truncate")
 
 
@@ -128,31 +136,78 @@ def sampler_truncates(state: SamplingState) -> jnp.ndarray:
     return _truncates(state.temperature, state.top_k, state.top_p, state.min_p)
 
 
-def _truncated(scaled: jnp.ndarray, state: SamplingState) -> jnp.ndarray:
-    """`scaled` with what top-k, top-p and min-p drop set to -inf.  Every
-    cutoff is read from ONE descending sort of the rows."""
+def sampler_top_k(state: SamplingState) -> jnp.ndarray:
+    """`_truncates` with top-p and min-p left out: whether any sampled row
+    sets top-k.  The truncation searches for the k-th values only then.
+    Computed beside `sampler_truncates`."""
+    return _truncates(state.temperature, state.top_k, 1.0, 0.0)
+
+
+#: bits of a float32; a cutoff search settles one of them a pass
+_KEY_BITS = 32
+_TOP_BIT = np.uint32(1 << 31)
+
+
+def _threshold(key: jnp.ndarray) -> jnp.ndarray:
+    """The float32 that a key stands for, [rows] -> [rows, 1].  Keys are
+    uint32 in the floats' order: the bits, with the sign flipped for a
+    positive number and every bit for a negative one.  Below -inf's and
+    above +inf's lie NaNs, which compare false with everything."""
+    bits = jax.lax.bitcast_convert_type(key ^ _TOP_BIT, jnp.int32)
+    bits = jnp.where(bits < 0, bits ^ np.int32(0x7FFFFFFF), bits)
+    return jax.lax.bitcast_convert_type(bits, jnp.float32)[:, None]
+
+
+def _cutoff(scaled, weight, target, passes=_KEY_BITS):
+    """Per row ([rows, 1]) the LARGEST float32 `t` for which the `weight` of
+    the values >= t reaches `target`: the k-th largest value for a weight of
+    1 each and a target of k, the nucleus's edge for a mass.  What is
+    reached only falls as `t` rises, so `t`'s key is built from its top bit
+    down, one fused compare-and-reduce over [rows, vocab] a bit: exact,
+    whatever the row's distribution, and always a value of the row.  NaN,
+    which masks nothing, where the row's numbers do not reach the target."""
+    def probe(_, carry):
+        key, bit = carry
+        raised = key | bit
+        reached = jnp.sum(
+            jnp.where(scaled >= _threshold(raised), weight, 0.0), axis=-1)
+        return jnp.where(reached >= target, raised, key), bit >> 1
+
+    key, _ = jax.lax.fori_loop(
+        0, passes, probe,
+        (jnp.zeros(scaled.shape[:1], jnp.uint32), jnp.uint32(_TOP_BIT)))
+    return _threshold(key)
+
+
+def _truncated(
+    scaled: jnp.ndarray,
+    state: SamplingState,
+    top_k: Optional[jnp.ndarray] = None,  # sampler_top_k(state)
+) -> jnp.ndarray:
+    """`scaled` with what top-k, top-p and min-p drop set to -inf.  Nothing
+    is sorted: each cutoff is a threshold search over the floats' key
+    (`_cutoff`), and rows mask with a float compare against it, so equal
+    values stay together."""
     V = scaled.shape[-1]
-    # top-k: mask logits below the k-th largest (k==0 disables)
-    sorted_logits = jnp.sort(scaled, axis=-1)[:, ::-1]  # desc
-    k = jnp.clip(state.top_k, 0, V)
-    kth_idx = jnp.clip(k - 1, 0, V - 1)
-    kth_val = jnp.take_along_axis(sorted_logits, kth_idx[:, None], axis=1)
-    top_k_on = (state.top_k > 0)[:, None]
+    if top_k is None:
+        top_k = sampler_top_k(state)
+    # top-k: mask logits below the k-th largest (k==0 disables); searched
+    # only where a sampled row of the batch sets it.  The count is a
+    # float32's, exact for any vocabulary under 2 ** 24
+    kth_val = _cutoff(
+        scaled, 1.0, jnp.clip(state.top_k, 1, V).astype(jnp.float32),
+        passes=jnp.where(top_k, _KEY_BITS, 0))
     topk_mask = jnp.where(
-        top_k_on, scaled < kth_val, jnp.zeros_like(scaled, bool)
+        (state.top_k > 0)[:, None], scaled < kth_val, jnp.zeros_like(scaled, bool)
     )
     scaled = jnp.where(topk_mask, -jnp.inf, scaled)
-    # the rows sorted again after that mask, without sorting again: masking
-    # everything below a value commutes with sorting, ties included
-    sorted_logits = jnp.where(
-        top_k_on & (sorted_logits < kth_val), -jnp.inf, sorted_logits)
 
-    # top-p (nucleus): keep smallest prefix of sorted probs with cumsum >= p
-    probs_sorted = jax.nn.softmax(sorted_logits, axis=-1)
-    cumprobs = jnp.cumsum(probs_sorted, axis=-1)
-    cutoff_count = jnp.sum(cumprobs - probs_sorted < state.top_p[:, None], axis=-1)
-    cutoff_idx = jnp.clip(cutoff_count - 1, 0, V - 1)
-    cutoff_val = jnp.take_along_axis(sorted_logits, cutoff_idx[:, None], axis=1)
+    # top-p (nucleus): keep the values with less than top_p of the mass
+    # STRICTLY above them, that is down to the largest threshold with top_p
+    # of the mass at or above it (top_p <= 0: the maximum, whose weight is 1)
+    weight = jnp.exp(scaled - scaled.max(axis=-1, keepdims=True))
+    cutoff_val = _cutoff(scaled, weight, jnp.maximum(
+        state.top_p * weight.sum(axis=-1), np.finfo(np.float32).tiny))
     topp_mask = jnp.where(
         (state.top_p < 1.0)[:, None], scaled < cutoff_val, jnp.zeros_like(scaled, bool)
     )
@@ -176,6 +231,7 @@ def sample_tokens(
     rng: jax.Array,
     counters: Optional[jnp.ndarray] = None,  # [B] i32: tokens generated so far
     truncates: Optional[jnp.ndarray] = None,  # sampler_truncates(state)
+    top_k: Optional[jnp.ndarray] = None,  # sampler_top_k(state)
 ) -> jnp.ndarray:
     """Returns [B] sampled token ids.  temperature==0 rows are greedy.
     Rows with state.seed >= 0 draw from their own PRNG stream
@@ -183,9 +239,9 @@ def sample_tokens(
     seed reproduces output regardless of batching.
 
     Only a batch in which a sampled row carries top-k, top-p or min-p runs
-    the truncation and its sort (SAMPLER_PATHS).  The tokens do not depend
-    on that: with every mask off, truncation leaves the scaled logits as
-    they are."""
+    the truncation and its searches (SAMPLER_PATHS).  The tokens do not
+    depend on that: with every mask off, truncation leaves the scaled
+    logits as they are."""
     B = logits.shape[0]
     greedy = jnp.argmax(logits, axis=-1)
 
@@ -194,7 +250,7 @@ def sample_tokens(
     if truncates is None:
         truncates = sampler_truncates(state)
     scaled = jax.lax.cond(
-        truncates, lambda: _truncated(scaled, state), lambda: scaled)
+        truncates, lambda: _truncated(scaled, state, top_k), lambda: scaled)
 
     if counters is None:
         counters = jnp.zeros((B,), jnp.int32)

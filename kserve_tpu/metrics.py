@@ -357,8 +357,9 @@ ENGINE_DISPATCH_DELIVER_SECONDS = Counter(
 ENGINE_SAMPLER_DISPATCHES = Counter(
     "engine_sampler_dispatches_total",
     "device dispatches by the path their batch takes through the sampler: "
-    "truncate (a sampled row carries top-k, top-p or min-p: one "
-    "full-vocabulary sort a step) | plain (none does: no sort)",
+    "truncate (a sampled row carries top-k, top-p or min-p: threshold "
+    "searches over the vocabulary each step, no sort) | plain (none does: "
+    "no pass)",
     ["model_name", "sampler_path"],
 )
 # `fit` is the closed set engine/shapes.FITS: how the (T, W) pair a `mixed`

@@ -1,8 +1,14 @@
 """engine/sampling.sample_tokens, held to a frozen copy of the body it had
 when every batch ran the truncation (three sorts, two softmaxes, a
-cumulative sum): whichever path a batch takes through the sampler, and
-however few sorts the truncation now reads its cutoffs from, its tokens
-are that body's, token for token.
+cumulative sum): whichever path a batch takes through the sampler, its
+tokens are that body's, token for token.
+
+Since PR 38 the truncation sorts nothing: it finds each cutoff by a
+threshold search over the floats' key.  top-k and min-p are still the
+frozen body's bit for bit.  The nucleus sums the mass above a threshold in
+another order than a cumulative sum over a sorted row does, so it is held
+to the ACCEPTANCE RULE below (`TAU`, `_oracle_nucleus`): off the nucleus's
+edge it is the frozen body's bit for bit too, which is every row at V = 257.
 """
 
 import asyncio
@@ -19,7 +25,9 @@ from kserve_tpu.engine.sampling import (
     SAMPLER_PATHS,
     SamplingParams,
     SamplingState,
+    _truncated,
     sample_tokens,
+    sampler_top_k,
     sampler_truncates,
 )
 
@@ -172,6 +180,10 @@ MIXES = {
          P(top_k=2**31 - 1, temperature=0.7, seed=6), P(top_k=V, min_p=0.1),
          GREEDY],
         "truncate"),
+    "top_p_at_and_below_zero": (
+        [P(top_p=0.0), P(top_p=-0.5, temperature=0.7, seed=3), P(top_p=1e-30),
+         P(top_p=0.0, top_k=4), P(top_p=0.0, min_p=0.3), GREEDY],
+        "truncate"),
 }
 MIX_NAMES = sorted(MIXES)
 
@@ -188,6 +200,10 @@ LOGITS = {
     # and -inf beside finite values, fewer of those than k
     "mostly_minus_inf": lambda rng, rows: np.where(
         rng.rand(rows, V) < 0.99, -np.inf, rng.randn(rows, V)).astype(np.float32),
+    # -0.0 beside +0.0 at the top of the row: one value to every compare
+    "signed_zeros": lambda rng, rows: np.where(
+        rng.rand(rows, V) < 0.3, np.where(rng.rand(rows, V) < 0.5, -0.0, 0.0),
+        -np.abs(rng.randn(rows, V))).astype(np.float32),
 }
 TRUNCATING = [name for name in MIX_NAMES if MIXES[name][1] == "truncate"]
 
@@ -267,8 +283,6 @@ def test_cutoffs_at_ties_and_empty_rows_are_the_frozen_body_s(
 def test_truncated_logits_are_the_frozen_body_s_bit_for_bit(mix, logits_kind):
     """Not only the tokens: the array the draw reads is the one the three
     sorts left (what is -inf, and every kept value)."""
-    from kserve_tpu.engine.sampling import _truncated
-
     rows, _ = MIXES[mix]
     state = SamplingState.from_params(rows)
     logits = jnp.asarray(
@@ -297,29 +311,220 @@ def test_host_label_compares_what_the_device_holds():
     assert SamplingState.planned([P(temperature=-1.0, top_k=4)])[1] == "plain"
 
 
-def _count_sorts(jaxpr) -> int:
-    """`sort` primitives in a jaxpr, those of the jaxprs it calls included."""
+def _count_primitives(jaxpr, names) -> int:
+    """Primitives of `names` in a jaxpr, those of the jaxprs it calls (a
+    branch, a loop's body) included."""
     n = 0
     for eqn in jaxpr.eqns:
-        n += eqn.primitive.name == "sort"
+        n += eqn.primitive.name in names
         for sub in jax.core.jaxprs_in_params(eqn.params):
-            n += _count_sorts(sub)
+            n += _count_primitives(sub, names)
     return n
 
 
-@pytest.mark.parametrize("path, sorts", [("plain", 0), ("truncate", 1)])
-def test_only_the_truncating_branch_sorts(path, sorts):
-    """The traced sampler holds one conditional of two branches, and its
-    one sort lies in the truncating one."""
+@pytest.mark.parametrize("path", SAMPLER_PATHS)
+def test_neither_branch_sorts(path):
+    """The traced sampler holds one conditional of two branches, and
+    neither sorts, sums cumulatively or selects a top k: the truncating one
+    searches (loops of compare-and-reduce)."""
+    ordering = {"sort", "cumsum", "top_k", "approx_top_k"}
     rows = MIXES["everything_at_once"][0]
     logits, counters, rng = _inputs(len(rows))
     jaxpr = jax.make_jaxpr(sample_tokens)(
         logits, SamplingState.from_params(rows), rng, counters).jaxpr
     conds = [e for e in jaxpr.eqns if e.primitive.name == "cond"]
     assert len(conds) == 1 and len(conds[0].params["branches"]) == 2
-    assert _count_sorts(jaxpr) == 1  # inside the conditional
-    branch = conds[0].params["branches"][SAMPLER_PATHS.index(path)]
-    assert _count_sorts(branch.jaxpr) == sorts
+    assert _count_primitives(jaxpr, ordering) == 0
+    assert _count_primitives(jaxpr, {"cond"}) == 1  # none nested
+    branch = conds[0].params["branches"][SAMPLER_PATHS.index(path)].jaxpr
+    # top-k's search and the nucleus's, in the truncating branch alone
+    assert _count_primitives(branch, {"while", "scan"}) == 2 * (
+        path == "truncate")
+    # the frozen body, for contrast, is what this test would catch
+    frozen = jax.make_jaxpr(frozen_sample_tokens)(
+        logits, SamplingState.from_params(rows), rng, counters).jaxpr
+    assert _count_primitives(frozen, ordering) == 4
+
+
+def test_top_k_is_searched_only_where_a_sampled_row_sets_it():
+    """`sampler_top_k` is the truncation's second predicate: without it the
+    search for the k-th values runs no pass (a greedy row's top_k is not
+    worth 32 of them: its sampled value is discarded)."""
+    rows = [P(top_k=3), P(temperature=0.0, top_k=3), P(top_p=0.9), P()]
+    state = SamplingState.from_params(rows)
+    assert bool(sampler_top_k(state))
+    assert not bool(sampler_top_k(SamplingState.from_params(rows[1:])))
+    assert bool(sampler_truncates(SamplingState.from_params(rows[1:])))
+    scaled, _, _ = _inputs(len(rows))
+    searched = np.asarray(jax.jit(_truncated)(scaled, state))
+    np.testing.assert_array_equal(
+        searched, np.asarray(jax.jit(_frozen_truncated)(scaled, state)))
+    assert (np.isfinite(searched).sum(axis=1)[:2] == 3).all()
+    skipped = np.asarray(jax.jit(_truncated)(scaled, state, jnp.bool_(False)))
+    assert (np.isfinite(skipped).sum(axis=1)[:2] == V).all()
+    np.testing.assert_array_equal(skipped[2:], searched[2:])
+
+
+# -- the acceptance rule of the nucleus (ISSUE 38, written before the code) --
+#
+# Oracle: numpy float64 on the SAME float32 row after the top-k mask:
+# p = softmax, E(v) = the mass STRICTLY above v, K(q) = {j : row[j] >= min{v :
+# E(v) < q}}: the exact nucleus at mass q, equal values kept together.
+#
+# 1. every row's kept set K is a threshold set, holds the row's maximum, and
+#    K(top_p - TAU) <= K <= K(top_p + TAU);
+# 2. where K(top_p - TAU) == K(top_p + TAU) (no value's E within TAU of
+#    top_p), K, the masked logits and the tokens are the frozen body's, bit
+#    for bit;
+# 3. top-k and min-p alone (top_p = 1) are the frozen body's bit for bit;
+# 4. a row of -inf alone, fewer finite values than k, -0.0 beside +0.0, all
+#    values equal, top_p <= 0: as the frozen body (LOGITS, MIXES above).
+
+#: probability mass.  A float32 tree sum over 2e5 non-negative terms errs by
+#: ~1e-6, the frozen body's own float32 cumulative sum by as much.
+TAU = 1e-5
+#: the vocabularies the benchmark's cells sample from
+VOCABULARIES = (151936, 200064)
+ROWS = 4
+
+
+def _oracle_nucleus(row: np.ndarray, q: float) -> np.ndarray:
+    """K(q) of one float32 row, as a mask."""
+    x = row.astype(np.float64)
+    top = x.max()
+    if not np.isfinite(top):
+        return np.ones(row.shape, bool)  # nothing to tell apart
+    p = np.exp(x - top)
+    p /= p.sum()
+    order = np.argsort(-x, kind="stable")
+    values, first = np.unique(-x[order], return_index=True)  # descending
+    above = np.concatenate([[0.0], np.cumsum(p[order])])[first]  # E(v)
+    inside = -values[above < q]
+    # q <= 0: no value has E(v) < q, and the maximum is kept with its ties
+    return x >= (inside.min() if inside.size else top)
+
+
+BIG_LOGITS = {
+    # the benchmark's: random weights give nearly flat rows, and the
+    # nucleus keeps most of the vocabulary
+    "near_flat": lambda rng, v: rng.randn(ROWS, v) * 0.7,
+    # a trained model's: a few tokens hold the mass, the tail is long
+    "peaked": lambda rng, v: rng.randn(ROWS, v) * 4.0 + 12.0 * (
+        rng.rand(ROWS, v) < 5e-5),
+    # few distinct values: every edge is a tie of thousands
+    "tied": lambda rng, v: rng.randint(-3, 4, (ROWS, v)).astype(np.float64),
+    # a penalised row: most of it -inf
+    "mostly_minus_inf": lambda rng, v: np.where(
+        rng.rand(ROWS, v) < 0.9, -np.inf, rng.randn(ROWS, v) * 2.0),
+}
+
+
+def _big(kind: str, vocab: int, seed: int = 0) -> jnp.ndarray:
+    rng = np.random.RandomState(VOCABULARIES.index(vocab) * 100 + seed)
+    return jnp.asarray(BIG_LOGITS[kind](rng, vocab).astype(np.float32))
+
+
+def _after_top_k(scaled, state):
+    """The rows the nucleus sees: the frozen body's top-k mask alone."""
+    only_top_k = dataclasses.replace(
+        state, top_p=jnp.ones_like(state.top_p),
+        min_p=jnp.zeros_like(state.min_p))
+    return np.asarray(jax.jit(_frozen_truncated)(scaled, only_top_k))
+
+
+def _hold_to_the_rule(scaled, state):
+    """Rules 1 and 2 for every row of one batch; the rows at the edge."""
+    new = np.asarray(jax.jit(_truncated)(scaled, state))
+    old = np.asarray(jax.jit(_frozen_truncated)(scaled, state))
+    before = _after_top_k(scaled, state)
+    at_the_edge = []
+    for i, top_p in enumerate(np.asarray(state.top_p, np.float64)):
+        row, kept = before[i], new[i] > -np.inf
+        if not np.isfinite(row.max()):
+            np.testing.assert_array_equal(new[i], old[i])
+            continue
+        # what is kept is kept unchanged, and what top-k dropped stays so
+        np.testing.assert_array_equal(new[i][kept], row[kept])
+        if top_p >= 1.0:
+            np.testing.assert_array_equal(new[i], old[i])
+            continue
+        assert kept[row.argmax()]
+        assert row[kept].min() > row[~kept].max(initial=-np.inf)  # a threshold
+        inner = _oracle_nucleus(row, top_p - TAU)
+        outer = _oracle_nucleus(row, top_p + TAU)
+        assert (kept | ~inner).all() and (outer | ~kept).all()
+        if (inner == outer).all():
+            np.testing.assert_array_equal(new[i], old[i])
+        else:
+            at_the_edge.append(i)
+    return at_the_edge
+
+
+@pytest.mark.parametrize("top_k", [0, 50], ids=["no_top_k", "top_k_50"])
+@pytest.mark.parametrize("top_p", [0.9, 0.95, 0.999])
+@pytest.mark.parametrize("kind", sorted(BIG_LOGITS))
+@pytest.mark.parametrize("vocab", VOCABULARIES)
+def test_nucleus_is_within_tau_of_the_exact_one(vocab, kind, top_p, top_k):
+    """Rules 1 and 2 at the benchmark's vocabularies."""
+    temperatures = (0.6, 0.7, 1.0, 1.3)
+    state = SamplingState.from_params([
+        P(top_p=top_p, top_k=top_k, temperature=t) for t in temperatures])
+    scaled = _big(kind, vocab) / state.temperature[:, None]
+    _hold_to_the_rule(scaled, state)
+
+
+#: the rows of the V = 257 cases that DO lie within TAU of the edge (rule 2
+#: does not bind them; the cases above find them the frozen body's all the
+#: same): P(top_p=0.999) of the mix "top_p", whose tail values hold ~1e-5
+#: of the mass each
+AT_THE_EDGE_257 = {("top_p", "drawn"): [5], ("top_p", "a_row_of_minus_inf"): [5]}
+
+
+@pytest.mark.parametrize("logits_kind", ["drawn"] + sorted(LOGITS))
+@pytest.mark.parametrize("mix", TRUNCATING)
+def test_rows_at_257_lie_off_the_nucleus_s_edge(mix, logits_kind):
+    """Rule 2's premise holds for the rows that the cases above compare
+    with the frozen body at V = 257, but for the two named: so those cases
+    hold the nucleus bit for bit."""
+    rows, _ = MIXES[mix]
+    state = SamplingState.from_params(rows)
+    if logits_kind == "drawn":
+        logits = _inputs(len(rows), seed=MIX_NAMES.index(mix))[0]
+    else:
+        logits = jnp.asarray(
+            LOGITS[logits_kind](np.random.RandomState(9), len(rows)))
+    scaled = logits / jnp.maximum(state.temperature, 1e-6)[:, None]
+    assert _hold_to_the_rule(scaled, state) == AT_THE_EDGE_257.get(
+        (mix, logits_kind), [])
+
+
+@pytest.mark.parametrize("params", [
+    dict(top_k=1), dict(top_k=50), dict(top_k=10**6), dict(min_p=0.05),
+    dict(top_k=50, min_p=0.3),
+], ids=lambda d: "-".join(f"{k}={v}" for k, v in d.items()))
+@pytest.mark.parametrize("kind", sorted(BIG_LOGITS))
+@pytest.mark.parametrize("vocab", VOCABULARIES)
+def test_top_k_and_min_p_are_the_frozen_body_s_at_any_size(vocab, kind, params):
+    """Rule 3: a count is exact, and min-p never searched."""
+    state = SamplingState.from_params(
+        [P(temperature=t, **params) for t in (0.6, 0.7, 1.0, 1.3)])
+    scaled = _big(kind, vocab, seed=1) / state.temperature[:, None]
+    np.testing.assert_array_equal(
+        np.asarray(jax.jit(_truncated)(scaled, state)),
+        np.asarray(jax.jit(_frozen_truncated)(scaled, state)))
+
+
+@pytest.mark.parametrize("q, kept", [
+    (0.5, [0, 1]), (0.4, [0, 1]), (0.41, [0, 1]), (0.39, [0, 1]),
+    (0.81, [0, 1, 2]), (0.79, [0, 1]), (1.0, [0, 1, 2, 3, 4]), (0.0, [0, 1]),
+    (-1.0, [0, 1]),
+])
+def test_the_oracle_keeps_ties_and_the_strict_mass_above(q, kept):
+    """The oracle itself, on a row whose masses are 0.4 0.4 0.1 0.05 0.05
+    (the two largest tied): K(q) by hand."""
+    row = np.log(np.asarray([0.4, 0.4, 0.1, 0.05, 0.05])).astype(np.float32)
+    row[1] = row[0]
+    assert np.flatnonzero(_oracle_nucleus(row, q)).tolist() == kept
 
 
 def _engine(label, **overrides):

@@ -379,11 +379,13 @@ def _sorts_met_without_a_branch(hlo: str):
     return n_sorts, sorted(sorts & reached)
 
 
-class TestSamplerSortsOnlyInsideAConditional:
-    """engine/sampling.sample_tokens sorts in one branch of a conditional
-    on the batch's own sampling state: a batch in which no sampled row
-    truncates runs no sort (the sorts were 47-53 % of every cell's device
-    time, greedy cells included: PERF.md section 6)."""
+class TestSamplerSortsNothing:
+    """engine/sampling.sample_tokens truncates in one branch of a
+    conditional on the batch's own sampling state, and since PR 38 that
+    branch finds its cutoffs by threshold searches (loops of
+    compare-and-reduce): no program sorts for the sampler any more (the
+    sorts were 47-53 % of every cell's device time before PR 31, and still
+    a third of the truncating cells' before PR 38: PERF.md section 6)."""
 
     def _hybrid_mixed(self, monkeypatch):
         import dataclasses
@@ -401,13 +403,17 @@ class TestSamplerSortsOnlyInsideAConditional:
         return _lower_mixed(
             mc, cfg, jax.eval_shape(layout.init_state), 16, monkeypatch)
 
-    @pytest.mark.parametrize("family", ["llama", "hybrid"])
-    def test_every_sort_of_mixed_lies_in_a_branch(self, family, monkeypatch):
-        lowered = (_decode_sat_mixed(40, monkeypatch) if family == "llama"
-                   else self._hybrid_mixed(monkeypatch))
+    @pytest.mark.parametrize("family", ["llama", "hybrid", "looped"])
+    def test_mixed_sorts_nothing_and_branches_once_a_sampler_site(
+            self, family, monkeypatch):
+        lowered = {
+            "llama": lambda: _decode_sat_mixed(40, monkeypatch),
+            "hybrid": lambda: self._hybrid_mixed(monkeypatch),
+            "looped": lambda: TestLoopedMixedProgram()._lowered(
+                monkeypatch, 4),
+        }[family]()
         hlo = lowered.compiler_ir(dialect="hlo").as_hlo_text()
-        n_sorts, unconditional = _sorts_met_without_a_branch(hlo)
-        assert n_sorts >= 1 and not unconditional
+        assert _sorts_met_without_a_branch(hlo) == (0, [])
         # step 0's sampler and the scan tail's: one conditional each
         assert hlo.count(" conditional(") == 2
 
@@ -422,17 +428,18 @@ class TestSamplerSortsOnlyInsideAConditional:
         n_sorts, unconditional = _sorts_met_without_a_branch(hlo)
         assert n_sorts >= 1 and bool(unconditional) == outside
 
-    def test_the_tpu_compiler_keeps_the_conditional(self):
+    def test_the_tpu_compiler_keeps_the_conditional_and_the_loops(self):
         """The sampler at Qwen3-4B's 48 x 151936 logits inside a scan, as
         `mixed` calls it, through the XLA TPU compile: the optimised
-        program still holds ONE conditional of two branches and sorts in
-        none but the truncating one (a conditional flattened to both sides
-        and a select would run the sorts for every batch), and there ONCE
-        a step: every cutoff is read from one descending sort."""
+        program still holds ONE conditional of two branches (a conditional
+        flattened to both sides and a select would run the searches for
+        every batch), sorts nowhere, and keeps the two searches as loops
+        (32 passes unrolled twice a sampler site would be paid in set-up
+        time by every (T, W) pair)."""
         import re
 
         from kserve_tpu.engine.sampling import (
-            SamplingState, sample_tokens, sampler_truncates)
+            SamplingState, sample_tokens, sampler_top_k, sampler_truncates)
 
         if _tpu_sharding() is None:
             pytest.skip("no compile-only TPU topology in this installation")
@@ -442,12 +449,12 @@ class TestSamplerSortsOnlyInsideAConditional:
             jax.eval_shape(lambda: SamplingState.defaults(lanes)))
 
         def steps(logits, state, rng, counters):
-            truncates = sampler_truncates(state)
+            truncates, top_k = sampler_truncates(state), sampler_top_k(state)
 
             def body(carry, step_rng):
                 logits, counters = carry
                 out = sample_tokens(
-                    logits, state, step_rng, counters, truncates)
+                    logits, state, step_rng, counters, truncates, top_k)
                 logits = logits.at[jnp.arange(lanes), out].add(-1.0)
                 return (logits, counters + 1), out
 
@@ -457,10 +464,11 @@ class TestSamplerSortsOnlyInsideAConditional:
         hlo = jax.jit(steps).lower(
             _abstract((lanes, vocab), jnp.float32), state,
             _abstract((2,), jnp.uint32), _i32(lanes)).compile().as_text()
-        n_sorts, unconditional = _sorts_met_without_a_branch(hlo)
-        assert n_sorts == 1 and not unconditional
+        assert _sorts_met_without_a_branch(hlo) == (0, [])
         branches = re.findall(r"branch_computations=\{([^}]*)\}", hlo)
         assert len(branches) == 1 and branches[0].count(",") == 1
+        # the steps, top-k's search and the nucleus's
+        assert len(re.findall(r"\swhile\(", hlo)) == 3
 
 
 class TestHybridDecodeKernelCalls:
@@ -533,21 +541,22 @@ class TestLoopedMixedProgram:
             prefill_buckets=(128, 256), dtype="bfloat16")
         cache = jax.ShapeDtypeStruct(
             (passes * pages, 2, mc.n_kv_heads, ps, mc.head_dim), jnp.bfloat16)
-        return _lower_mixed(
-            mc, cfg, [cache] * mc.n_layers, 24, monkeypatch).as_text()
+        return _lower_mixed(mc, cfg, [cache] * mc.n_layers, 24, monkeypatch)
 
     def test_the_passes_are_loops_in_the_program(self, monkeypatch):
         import re
 
-        looped = self._lowered(monkeypatch, 4)
-        one = self._lowered(monkeypatch, 1)
+        looped = self._lowered(monkeypatch, 4).as_text()
+        one = self._lowered(monkeypatch, 1).as_text()
         kernels = re.findall(r'kernel_name = "([a-z_]+)"', looped)
         # 2 layers: traced once under each loop, whatever the passes
         assert sorted(kernels) == sorted(
             re.findall(r'kernel_name = "([a-z_]+)"', one)) == [
                 "paged_attention_decode"] * 2 + ["ragged_paged_attention"] * 2
-        assert looped.count("stablehlo.while") == 3  # passes, steps, passes
-        assert one.count("stablehlo.while") == 1  # the decode steps alone
+        # beside the sampler's two searches at each of its two sites:
+        searches = 4
+        assert looped.count("stablehlo.while") == 3 + searches  # passes, steps, passes
+        assert one.count("stablehlo.while") == 1 + searches  # the decode steps alone
         # the cache arrays enter and leave whole: a pass is an offset into
         # the page table, never a slice of the cache
         assert not re.findall(r"tensor<300x2x16x16x128xbf16>", looped)
