@@ -55,6 +55,7 @@ import numpy as np
 
 from ..logging import logger
 from ..metrics import AOT_CACHE_EVENTS, XLA_COMPILE_SECONDS, XLA_COMPILES
+from ..observability.pauses import PROGRAM_COMPILE
 
 # bump when the on-disk entry layout changes; old entries become
 # structurally invalid (logged + recompiled) instead of misread
@@ -528,7 +529,8 @@ class AOTProgram:
         t0 = time.perf_counter()
         lowered = self._jit.lower(*args)
         t1 = time.perf_counter()
-        compiled = _compile_fresh(lowered)
+        with PROGRAM_COMPILE:  # the process's listener books it as ours
+            compiled = _compile_fresh(lowered)
         t2 = time.perf_counter()
         stats.trace_s += t1 - t0
         stats.compile_s += t2 - t1

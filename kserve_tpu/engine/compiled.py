@@ -23,6 +23,7 @@ import jax.numpy as jnp
 
 from ..metrics import XLA_COMPILE_SECONDS, XLA_COMPILES
 from ..models import llama
+from ..observability.pauses import PROGRAM_COMPILE
 from ..parallel import sharding as shd
 from .kvcache import pages_of_passes
 from .sampling import (
@@ -136,7 +137,8 @@ class _CompileCounting:
 
     def __call__(self, *args, **kwargs):
         t0 = time.perf_counter()
-        out = self._fn(*args, **kwargs)
+        with PROGRAM_COMPILE:  # a compile in here is the engine's own
+            out = self._fn(*args, **kwargs)
         n = self._fn._cache_size()
         if n > self._seen:
             XLA_COMPILE_SECONDS.labels(program=self._name).inc(

@@ -30,7 +30,7 @@ import asyncio
 import dataclasses
 import time
 from collections import deque
-from typing import Any, AsyncIterator, Dict, List, Optional, Tuple
+from typing import Any, AsyncIterator, Callable, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -39,10 +39,11 @@ import numpy as np
 from ..logging import logger
 from ..metrics import (
     ENGINE_BATCH_OCCUPANCY,
+    ENGINE_DISPATCH_PART_SECONDS,
+    ENGINE_DISPATCH_PHASE_CPU_SECONDS,
     ENGINE_DISPATCH_PHASE_SECONDS,
     ENGINE_DISPATCHES,
     ENGINE_DISPATCH_DELIVERIES,
-    ENGINE_DISPATCH_DELIVER_SECONDS,
     ENGINE_FIRST_TOKEN_DISPATCHES,
     ENGINE_KV_DISK_BYTES,
     ENGINE_KV_OFFLOAD_BYTES,
@@ -84,12 +85,15 @@ from ..lifecycle.state import ReplicaDrainingError
 from ..models import llama
 from ..observability import (
     DELIVERIES,
+    CPU_COLUMNS,
     DISPATCH_COLUMNS,
+    PARTS,
     PHASES,
     DispatchPhases,
     RequestTimeline,
     TimelineRecorder,
     emit_timeline_spans,
+    pauses,
 )
 from ..parallel import sharding as shd
 from ..resilience import (
@@ -138,14 +142,16 @@ def _device_row(device) -> dict:
 
 
 #: where a dispatch row (observability.DISPATCH_COLUMNS) holds what the
-#: counters are fed from: the program, the six phases with `wait_lag`, and
-#: the seconds and tokens of what the iteration handed to its streams
+#: counters are fed from: the program, the six phases with `wait_lag`, the
+#: tokens the iteration handed to its streams, and the parts of its phases
 _PROGRAM_COLUMN = DISPATCH_COLUMNS.index("program")
 _PHASE_COLUMNS = slice(DISPATCH_COLUMNS.index(PHASES[0]),
                        DISPATCH_COLUMNS.index("wait_lag") + 1)
-_DELIVER_COLUMN = DISPATCH_COLUMNS.index("deliver")
-_DELIVERED_COLUMNS = slice(_DELIVER_COLUMN + 1,
-                           _DELIVER_COLUMN + 1 + len(DELIVERIES))
+_DELIVERED_COLUMNS = slice(DISPATCH_COLUMNS.index(DELIVERIES[0]),
+                           DISPATCH_COLUMNS.index(DELIVERIES[-1]) + 1)
+_PART_COLUMNS = [DISPATCH_COLUMNS.index(part) for part in PARTS]
+_CPU_COLUMNS = slice(DISPATCH_COLUMNS.index(CPU_COLUMNS[0]),
+                     DISPATCH_COLUMNS.index(CPU_COLUMNS[-1]) + 1)
 
 
 def _refuse_looped(model_config, engine_config) -> None:
@@ -278,6 +284,9 @@ class LLMEngine:
         lora_adapters: Optional[Dict[str, str]] = None,
         lora_stacked=None,  # (adapter_ids, per-layer stacks) pre-loaded
         clock: Optional[Clock] = None,  # telemetry clock (FakeClock in chaos tests)
+        # CPU seconds of the calling thread, for the loop's phases; under
+        # an injected `clock` none unless a test passes its own
+        cpu_clock: Optional[Callable[[], float]] = None,
         # the fleet-simulator stub seam (kserve_tpu/sim): an object with
         # the CompiledPrograms attribute surface replaces the jitted device
         # programs, and a fetch/fetch_async/close duck of _DeadlineFetcher
@@ -325,19 +334,35 @@ class LLMEngine:
         # every lifecycle stamp goes through this injectable clock, so the
         # FakeClock chaos suite asserts exact TTFT/ITL/queue-wait values
         # (docs/observability.md); real time is the production default
+        if cpu_clock is None and clock is None:
+            cpu_clock = time.thread_time
         self._clock = clock or MONOTONIC
         # bounded ring of finished timelines + rolling percentile windows
         # behind GET /admin/telemetry
         self.telemetry = TimelineRecorder()
-        # the loop's phases per dispatch (docs/observability.md): host spans
-        # on the profiler's clock while a capture runs, the
-        # engine_dispatch_phase_seconds_total counters, one ring row each
+        # the loop's phases per dispatch and their parts
+        # (docs/observability.md): host spans on the profiler's clock while
+        # a capture runs, the engine_dispatch_{phase,part}_seconds_total
+        # and engine_dispatch_phase_cpu_seconds_total counters, one ring
+        # row each
         self._phases = DispatchPhases(
-            self._clock, annotate=jax.profiler.TraceAnnotation)
+            self._clock, annotate=jax.profiler.TraceAnnotation,
+            cpu_clock=cpu_clock)
         self._phase_seconds = [
             ENGINE_DISPATCH_PHASE_SECONDS.labels(
                 model_name=metrics_label, phase=phase)
             for phase in (*PHASES, "wait_lag")]
+        self._phase_cpu_seconds = [
+            ENGINE_DISPATCH_PHASE_CPU_SECONDS.labels(
+                model_name=metrics_label, phase=phase)
+            for phase in PHASES]
+        self._part_seconds = [
+            ENGINE_DISPATCH_PART_SECONDS.labels(
+                model_name=metrics_label, part=part)
+            for part in PARTS]
+        # a compile of something that is none of the engine's programs,
+        # after warm-up, is logged once (_paused)
+        self._warned_other_compile = False
         # deferred delivery (the `mixed` path): what a routed dispatch's
         # tokens owe their streams, in the order they were produced, until
         # the next dispatch is launched; never left standing across an
@@ -347,8 +372,6 @@ class LLMEngine:
             ENGINE_DISPATCH_DELIVERIES.labels(
                 model_name=metrics_label, when=when)
             for when in DELIVERIES]
-        self._deliver_seconds = ENGINE_DISPATCH_DELIVER_SECONDS.labels(
-            model_name=metrics_label)
         # engine_sampler_dispatches_total by the path a dispatch's batch
         # takes through the sampler (sampling.SAMPLER_PATHS)
         self._sampler_dispatches = {
@@ -935,6 +958,7 @@ class LLMEngine:
 
     async def start(self):
         if self._task is None:
+            pauses.watch(self._paused)
             self._task = asyncio.create_task(self._run_loop())
             if self._watchdog is not None:
                 self._watchdog.start()
@@ -1046,6 +1070,22 @@ class LLMEngine:
         self._fetcher.close()
         if self._kv_store is not None:
             self._kv_store.close()
+        pauses.unwatch(self._paused)
+
+    def _paused(self, pause: str, seconds: float, what: str = "") -> None:
+        """observability.pauses' watcher: the whole process stood still for
+        `seconds` (a compile of something that is none of the engine's
+        programs, `what` its name where JAX gives one; a pause of the
+        collector); noted on the row of the iteration it fell in."""
+        self._phases.paused(pause, seconds)
+        if (pause == "other_compile" and self._startup_recorded
+                and not self._warned_other_compile):
+            self._warned_other_compile = True
+            logger.warning(
+                "a compile outside the engine's programs after warm-up: %s "
+                "took %.3f s (engine_other_compile_seconds_total, the "
+                "dispatch row's other_compile; logged once)",
+                what or "<unnamed>", seconds)
 
     def _discard_resume_kv(self, req) -> None:
         """Release a queued request's spilled resume KV to the tier store
@@ -2308,7 +2348,11 @@ class LLMEngine:
             model_name=self._mlabel, program=row[_PROGRAM_COLUMN]).inc()
         for counter, seconds in zip(self._phase_seconds, row[_PHASE_COLUMNS]):
             counter.inc(seconds)
-        self._deliver_seconds.inc(row[_DELIVER_COLUMN])
+        for counter, column in zip(self._part_seconds, _PART_COLUMNS):
+            counter.inc(row[column])
+        for counter, seconds in zip(self._phase_cpu_seconds,
+                                    row[_CPU_COLUMNS]):
+            counter.inc(seconds)
         for counter, tokens in zip(self._deliveries, row[_DELIVERED_COLUMNS]):
             counter.inc(tokens)
 
@@ -3253,7 +3297,8 @@ class LLMEngine:
             and slot.params.logprobs is not None
             for i, slot in enumerate(self._slots)
         )
-        state, sampler_path = SamplingState.planned(params_list)
+        with self._phases.span("sampling"):
+            state, sampler_path = SamplingState.planned(params_list)
         return {
             "tokens": tokens,
             "pos": pos,
@@ -3387,30 +3432,35 @@ class LLMEngine:
     def _dispatch_chunk(self, meta: dict, tokens_dev=None):
         """Launch one decode chunk (async); tokens_dev chains the previous
         chunk's device-resident last tokens, skipping a host round-trip."""
-        meta["_dispatched_at"] = self._phases.mark("launch")
-        n_active = int(np.count_nonzero(meta["active"]))
-        self._phases.launched(
-            "decode", n_active, meta["page_table"].shape[1], 0, n_active,
-            chained=tokens_dev is not None)
-        self._sampler_dispatches[meta["sampler_path"]].inc()
-        self._count_forward(
-            self._shapes.steps, meta["pos"], meta["active"], meta["capacity"],
-            decode_steps=self._shapes.steps)
-        rng = jax.random.fold_in(self._base_rng, self._next_step())
-        tokens = tokens_dev if tokens_dev is not None else jnp.asarray(meta["tokens"])
-        args = (
-            self.params,
-            tokens,
-            jnp.asarray(meta["pos"]),
-            self.kv_pages,
-            jnp.asarray(meta["page_table"]),
-            jnp.asarray(meta["active"]),
-            jnp.asarray(meta["capacity"]),
-            jnp.asarray(meta["counters"]),
-            meta["state"],
-            rng,
-            jnp.asarray(meta["adapters"]),
-        )
+        phases = self._phases
+        meta["_dispatched_at"] = phases.mark("launch")
+        with phases.span("account"):
+            n_active = int(np.count_nonzero(meta["active"]))
+            phases.launched(
+                "decode", n_active, meta["page_table"].shape[1], 0, n_active,
+                chained=tokens_dev is not None)
+            self._sampler_dispatches[meta["sampler_path"]].inc()
+            self._count_forward(
+                self._shapes.steps, meta["pos"], meta["active"],
+                meta["capacity"], decode_steps=self._shapes.steps)
+        with phases.span("upload"):
+            tokens = (tokens_dev if tokens_dev is not None
+                      else jnp.asarray(meta["tokens"]))
+            pos = jnp.asarray(meta["pos"])
+            page_table = jnp.asarray(meta["page_table"])
+            active = jnp.asarray(meta["active"])
+            capacity = jnp.asarray(meta["capacity"])
+            counters = jnp.asarray(meta["counters"])
+            adapters = jnp.asarray(meta["adapters"])
+        with phases.span("call"):
+            return self._call_chunk(meta, (
+                self.params, tokens, pos, self.kv_pages, page_table, active,
+                capacity, counters, meta["state"],
+                jax.random.fold_in(self._base_rng, self._next_step()),
+                adapters))
+
+    def _call_chunk(self, meta: dict, args: tuple):
+        """The decode program's variant that `meta` asks for, called."""
         want_lp = meta.get("want_logprobs", False)
         if meta.get("penalized"):
             fn = self._decode_penalized_lp_fn if want_lp else self._decode_penalized_fn
@@ -3479,7 +3529,8 @@ class LLMEngine:
         host round-trip hides behind device compute.  Each routed chunk
         commits its own dispatch row; phases are recorded as they occur."""
         self._phases.mark("plan")
-        meta = self._prepare_chunk(prev=None)
+        with self._phases.span("prepare"):
+            meta = self._prepare_chunk(prev=None)
         if meta is None:
             return
         chunk = self._dispatch_chunk(meta)
@@ -3514,7 +3565,8 @@ class LLMEngine:
                 # every chunk, not once per arbitrarily long pipeline
                 and not (self._stopped or self._draining)
             ):
-                meta2 = self._prepare_chunk(prev=meta)
+                with self._phases.span("prepare"):
+                    meta2 = self._prepare_chunk(prev=meta)
             if meta2 is not None:
                 last_tokens = (
                     chunk[0][-1] if isinstance(chunk, tuple) else chunk[-1]
@@ -3569,7 +3621,19 @@ class LLMEngine:
         in a single dispatch."""
         phases = self._phases
         phases.mark("plan")
-        if self._needs_legacy_step():
+        # `prepare` is all of `plan` that precedes the packing, so that the
+        # parts account for the phase: the legacy gate, the decode lanes'
+        # inputs, the gauges
+        with phases.span("prepare"):
+            legacy = self._needs_legacy_step()
+            if not legacy:
+                meta = self._prepare_chunk(prev=None)
+                prefilling = [
+                    (i, s) for i, s in enumerate(self._slots)
+                    if s.request_id is not None and s.prefilling is not None
+                ]
+                self._set_occupancy_gauges(self._active_decode_slots())
+        if legacy:
             self._deliver()  # the legacy paths hand over in place
             did = self._advance_prefills()
             active = self._active_decode_slots()
@@ -3578,12 +3642,6 @@ class LLMEngine:
                 await self._decode_once()
                 did = True
             return did
-        meta = self._prepare_chunk(prev=None)
-        prefilling = [
-            (i, s) for i, s in enumerate(self._slots)
-            if s.request_id is not None and s.prefilling is not None
-        ]
-        self._set_occupancy_gauges(self._active_decode_slots())
         if meta is None and not prefilling:
             self._deliver()  # nothing to launch
             return False
@@ -3607,44 +3665,48 @@ class LLMEngine:
                 self._deliver()  # the dense path hands over in place
                 await self._step_dense(meta)
                 return True
-        plan = self._plan_ragged(meta, prefilling)
+        with phases.span("pack"):
+            plan = self._plan_ragged(meta, prefilling)
         dispatched_at = phases.mark("launch")
-        rng = jax.random.fold_in(self._base_rng, self._next_step())
-        compiles = getattr(self._mixed_fn, "compiles", 0)
-        out, self.kv_pages = self._mixed_fn(
-            self.params,
-            jnp.asarray(plan["q_tokens"]),
-            jnp.asarray(plan["token_seq"]),
-            jnp.asarray(plan["token_pos"]),
-            jnp.asarray(plan["q_start"]),
-            jnp.asarray(plan["q_len"]),
-            jnp.asarray(plan["kv_start"]),
-            jnp.asarray(plan["last_idx"]),
-            self.kv_pages,
-            jnp.asarray(plan["page_table"]),
-            jnp.asarray(plan["joins"]),
-            jnp.asarray(plan["scan_tok0"]),
-            jnp.asarray(plan["scan_pos0"]),
-            jnp.asarray(plan["step0_emits"]),
-            jnp.asarray(plan["capacity"]),
-            jnp.asarray(plan["counters"]),
-            plan["state"],
-            rng,
-            jnp.asarray(plan["adapters"]),
-        )
-        ran = (len(plan["q_tokens"]), plan["page_table"].shape[1])
-        phases.launched(
-            "mixed", *ran, plan["prefill_tokens"], plan["decode_tokens"],
-            compiled=getattr(self._mixed_fn, "compiles", 0) != compiles,
-            need=plan["need"])
-        self._loaded.ran(ran)
-        self._dispatch_fits[plan["fit"]].inc()
-        self._sampler_dispatches[plan["sampler_path"]].inc()
-        # the packed step, then steps - 1 decode steps over the joining lanes
-        self._count_forward(
-            self._shapes.steps, plan["scan_pos0"], plan["joins"],
-            plan["capacity"], decode_steps=self._shapes.steps - 1,
-            packed_tokens=plan["prefill_tokens"] + plan["decode_tokens"])
+        with phases.span("upload"):
+            q_tokens = jnp.asarray(plan["q_tokens"])
+            token_seq = jnp.asarray(plan["token_seq"])
+            token_pos = jnp.asarray(plan["token_pos"])
+            q_start = jnp.asarray(plan["q_start"])
+            q_len = jnp.asarray(plan["q_len"])
+            kv_start = jnp.asarray(plan["kv_start"])
+            last_idx = jnp.asarray(plan["last_idx"])
+            page_table = jnp.asarray(plan["page_table"])
+            joins = jnp.asarray(plan["joins"])
+            scan_tok0 = jnp.asarray(plan["scan_tok0"])
+            scan_pos0 = jnp.asarray(plan["scan_pos0"])
+            step0_emits = jnp.asarray(plan["step0_emits"])
+            capacity = jnp.asarray(plan["capacity"])
+            counters = jnp.asarray(plan["counters"])
+            adapters = jnp.asarray(plan["adapters"])
+        with phases.span("call"):
+            rng = jax.random.fold_in(self._base_rng, self._next_step())
+            compiles = getattr(self._mixed_fn, "compiles", 0)
+            out, self.kv_pages = self._mixed_fn(
+                self.params, q_tokens, token_seq, token_pos, q_start, q_len,
+                kv_start, last_idx, self.kv_pages, page_table, joins,
+                scan_tok0, scan_pos0, step0_emits, capacity, counters,
+                plan["state"], rng, adapters)
+        with phases.span("account"):
+            ran = (len(plan["q_tokens"]), plan["page_table"].shape[1])
+            phases.launched(
+                "mixed", *ran, plan["prefill_tokens"], plan["decode_tokens"],
+                compiled=getattr(self._mixed_fn, "compiles", 0) != compiles,
+                need=plan["need"])
+            self._loaded.ran(ran)
+            self._dispatch_fits[plan["fit"]].inc()
+            self._sampler_dispatches[plan["sampler_path"]].inc()
+            # the packed step, then steps - 1 decode steps over the joining
+            # lanes
+            self._count_forward(
+                self._shapes.steps, plan["scan_pos0"], plan["joins"],
+                plan["capacity"], decode_steps=self._shapes.steps - 1,
+                packed_tokens=plan["prefill_tokens"] + plan["decode_tokens"])
         phases.mark("wait")
         # the fetch is handed to its worker first, so that the result is
         # stamped when the device has it and a delivery that outlasts the
@@ -3776,7 +3838,8 @@ class LLMEngine:
         seq_list.extend([-1] * pad)
         pos_list.extend([0] * pad)
         page_table = self._page_table(rows, width)
-        state, sampler_path = SamplingState.planned(params_list)
+        with self._phases.span("sampling"):
+            state, sampler_path = SamplingState.planned(params_list)
         return {
             "q_tokens": np.asarray(tok_list, np.int32),
             "token_seq": np.asarray(seq_list, np.int32),
@@ -3850,9 +3913,10 @@ class LLMEngine:
                 tl.mark_prefill_end(now)
             if req.adapter_id < 0 and req.resume is None:
                 covered = min(pf["done"], len(req.prompt_ids))
-                self._prefix_cache.register(
-                    req.prompt_ids[:covered], slot.pages,
-                    start_page=pf.get("registered", 0))
+                with self._phases.span("register"):
+                    self._prefix_cache.register(
+                        req.prompt_ids[:covered], slot.pages,
+                        start_page=pf.get("registered", 0))
                 pf["registered"] = covered // self.config.page_size
             if not final:
                 continue
@@ -3951,38 +4015,37 @@ class LLMEngine:
         dispatch's device (token, pos, counters) carry so the chained
         program starts exactly where the in-flight one ends — no host
         round-trip between them."""
-        plan["_dispatched_at"] = self._phases.mark("launch")
-        n_tokens = int(np.count_nonzero(plan["live"])) * ((self._spec_k or 0) + 1)
-        self._phases.launched(
-            "mixed_decode", n_tokens, plan["page_table"].shape[1], 0,
-            n_tokens, chained=chain is not None)
-        self._sampler_dispatches[plan["sampler_path"]].inc()
-        self._count_forward(self._shapes.steps)  # rounds of the packed step
-        rng = jax.random.fold_in(self._base_rng, self._next_step())
-        if chain is not None:
-            tok, pos, cnt = chain["carry"]
-        else:
-            # committed to the same replicated spelling the program pins
-            # its carry outputs to: chained and unchained dispatches must
-            # share ONE jit signature (see _refresh_draft_table)
-            rep = self._replicated_sharding
-            tok = jax.device_put(jnp.asarray(plan["tokens"]), rep)
-            pos = jax.device_put(jnp.asarray(plan["pos"]), rep)
-            cnt = jax.device_put(jnp.asarray(plan["counters"]), rep)
-        out = self._mixed_decode_fn(
-            self.params,
-            tok,
-            pos,
-            self.kv_pages,
-            jnp.asarray(plan["page_table"]),
-            jnp.asarray(plan["live"]),
-            jnp.asarray(plan["capacity"]),
-            cnt,
-            self._draft_table,
-            plan["state"],
-            rng,
-            jnp.asarray(plan["adapters"]),
-        )
+        phases = self._phases
+        plan["_dispatched_at"] = phases.mark("launch")
+        with phases.span("account"):
+            n_tokens = (int(np.count_nonzero(plan["live"]))
+                        * ((self._spec_k or 0) + 1))
+            phases.launched(
+                "mixed_decode", n_tokens, plan["page_table"].shape[1], 0,
+                n_tokens, chained=chain is not None)
+            self._sampler_dispatches[plan["sampler_path"]].inc()
+            self._count_forward(self._shapes.steps)  # rounds of the packed step
+        with phases.span("upload"):
+            if chain is not None:
+                tok, pos, cnt = chain["carry"]
+            else:
+                # committed to the same replicated spelling the program pins
+                # its carry outputs to: chained and unchained dispatches must
+                # share ONE jit signature (see _refresh_draft_table)
+                rep = self._replicated_sharding
+                tok = jax.device_put(jnp.asarray(plan["tokens"]), rep)
+                pos = jax.device_put(jnp.asarray(plan["pos"]), rep)
+                cnt = jax.device_put(jnp.asarray(plan["counters"]), rep)
+            page_table = jnp.asarray(plan["page_table"])
+            live = jnp.asarray(plan["live"])
+            capacity = jnp.asarray(plan["capacity"])
+            adapters = jnp.asarray(plan["adapters"])
+        with phases.span("call"):
+            rng = jax.random.fold_in(self._base_rng, self._next_step())
+            out = self._mixed_decode_fn(
+                self.params, tok, pos, self.kv_pages, page_table, live,
+                capacity, cnt, self._draft_table, plan["state"], rng,
+                adapters)
         toks, n_emit_dev, self.kv_pages, self._draft_table, tok_o, pos_o, cnt_o = out
         return {"toks": toks, "n": n_emit_dev, "carry": (tok_o, pos_o, cnt_o)}
 
@@ -4077,7 +4140,8 @@ class LLMEngine:
         fetched, so draft+verify of step N+1 overlaps routing of step N
         and the host round-trip hides behind device compute.  Each routed
         dispatch commits its own row; phases are recorded as they occur."""
-        plan = self._plan_dense(meta)
+        with self._phases.span("pack"):
+            plan = self._plan_dense(meta)
         chunk = self._dispatch_dense(plan)
         while True:
             self._phases.mark("plan")
@@ -4101,7 +4165,8 @@ class LLMEngine:
                 and not predictable_finish
                 and not (self._stopped or self._draining)
             ):
-                plan2 = self._plan_dense_chained(plan)
+                with self._phases.span("pack"):
+                    plan2 = self._plan_dense_chained(plan)
             if plan2 is not None:
                 chunk2 = self._dispatch_dense(plan2, chain=chunk)
                 self._pipeline_busy = True
@@ -4160,10 +4225,16 @@ class LLMEngine:
             finish_reason = self._hand_over(owed)
             self._phases.delivered("inline", 1)
         if finish_reason is not None:
+            self._release(slot)
+            self._wake.set()
+
+    def _release(self, slot: _Slot) -> None:
+        """A finished lane gives its pages back and is reset: the part
+        `register` of whichever phase the finish falls in."""
+        with self._phases.span("register"):
             self._free_pages(slot.pages)
             slot.reset()
             self._mark_penalty_dirty(self._slots.index(slot))
-            self._wake.set()
 
     def _finish(self, slot: _Slot, reason: str):
         """Close a lane's stream without a token.  The closing chunk goes
@@ -4174,9 +4245,7 @@ class LLMEngine:
         else:
             self._hand_over(owed)
             self._phases.delivered("inline", 1)
-        self._free_pages(slot.pages)
-        slot.reset()
-        self._mark_penalty_dirty(self._slots.index(slot))
+        self._release(slot)
 
     def _hand_over(self, owed: _Delivery) -> Optional[str]:
         """The stream's half of one token: the timeline's stamp (this
@@ -4227,12 +4296,11 @@ class LLMEngine:
         owed = self._undelivered
         if not owed:
             return
-        started = self._clock.now()
         tokens = len(owed)
         with self._phases.span("deliver"):
             while owed:
                 self._hand_over(owed.popleft())
-        self._phases.delivered(when, tokens, self._clock.now() - started)
+        self._phases.delivered(when, tokens)
 
     def _deliver_overlapped(self) -> None:
         self._deliver("overlapped")

@@ -342,13 +342,51 @@ ENGINE_DISPATCH_DELIVERIES = Counter(
     "phases are",
     ["model_name", "when"],
 )
-ENGINE_DISPATCH_DELIVER_SECONDS = Counter(
-    "engine_dispatch_deliver_seconds_total",
-    "host seconds spent handing deferred tokens to their streams, inside "
-    "whichever phase of engine_dispatch_phase_seconds_total that was (a "
-    "token handed over in place is not timed apart from its `route`); fed "
-    "from the committed dispatch rows, as the phases are",
-    ["model_name"],
+# `part` is the closed set observability.timeline.PARTS: what the host's
+# phases are made of, each timed inside its phase by DispatchPhases.span
+# (docs/observability.md "Dispatch phases")
+ENGINE_DISPATCH_PART_SECONDS = Counter(
+    "engine_dispatch_part_seconds_total",
+    "seconds of the engine's loop by part of a phase: prepare | sampling | "
+    "pack (of plan), upload | call | account (of launch), deliver (handing "
+    "deferred tokens to their streams, inside wait or in place), register "
+    "(finishes, page frees and prefix registration, of route); a part never "
+    "counts what a part opened inside it counts; fed from the committed "
+    "dispatch rows, as the phases are",
+    ["model_name", "part"],
+)
+# CPU seconds of the loop's THREAD (time.thread_time), booked to the phase
+# each stamp closes: in `wait` they are the work the device's step hides
+# (delivery, the SSE writes, the handlers); over the phases' wall seconds
+# they say how near the host is to being the bottleneck
+ENGINE_DISPATCH_PHASE_CPU_SECONDS = Counter(
+    "engine_dispatch_phase_cpu_seconds_total",
+    "CPU seconds of the thread that runs the engine's loop, by phase of a "
+    "dispatch (the phases of engine_dispatch_phase_seconds_total); fed from "
+    "the committed dispatch rows",
+    ["model_name", "phase"],
+)
+# XLA compiles of anything in the process that is NONE of the engine's own
+# programs (a helper jitted on a new shape, an `.at[].set`,
+# jax.random.fold_in), from jax.monitoring's backend-compile event
+# (observability/pauses.py): what engine_xla_compile_seconds_total cannot
+# see, and stalls the loop as long.  The engine's own compiles are filtered
+# out (pauses.PROGRAM_COMPILE) and stay with engine_xla_compiles_total.  Of
+# the process, so no model_name.
+OTHER_COMPILE_SECONDS = Counter(
+    "engine_other_compile_seconds_total",
+    "seconds of XLA backend compiles anywhere in the process outside the "
+    "engine's own compiled programs (those are "
+    "engine_xla_compile_seconds_total); above zero after warm-up, "
+    "something compiled under traffic: the dispatch row's other_compile "
+    "says which iteration it hit, the log's warning which function",
+)
+# `generation` is "1" or "2": a collection of generation 0 is not timed
+GC_PAUSE_SECONDS = Counter(
+    "engine_gc_pause_seconds_total",
+    "seconds the Python collector held the process, by the generation "
+    "collected (1 | 2; generation 0 is not timed)",
+    ["generation"],
 )
 # `sampler_path` is the closed set engine/sampling.SAMPLER_PATHS: what the
 # dispatch's batch asked of the sampler, decided on the device by the same

@@ -21,8 +21,11 @@ from .introspection import (  # noqa: F401
 )
 from .spans import emit_timeline_spans  # noqa: F401
 from .timeline import (  # noqa: F401
+    CPU_COLUMNS,
     DELIVERIES,
     DISPATCH_COLUMNS,
+    PARTS,
+    PAUSES,
     PHASES,
     DispatchPhases,
     RequestTimeline,

@@ -16,10 +16,14 @@ a serial number and six consecutive phases (admit, plan, launch, wait,
 route, yield) that tile one iteration of the loop, from the same clock.
 One stamp feeds three outputs: a `TraceAnnotation` on the profiler's clock
 while a capture runs, the `engine_dispatch_phase_seconds_total` counters,
-and one row per dispatch in the recorder's bounded ring.  Handing tokens to
-their streams is no phase: it runs inside one (`wait` where it follows the
-launch, `route` where it is done in place) and is noted beside them
-(`delivered`, the nested span `engine.deliver`).
+and one row per dispatch in the recorder's bounded ring.  What a phase is
+made of is timed the same way, as PARTS (`span`): handing tokens to their
+streams, for one, is no phase: it runs inside one (`wait` where it follows
+the launch, `route` where it is done in place) and is the part `deliver`.
+A second clock gives the CPU seconds of the loop's thread by phase, and
+what stops the whole process (a compile of something that is none of the
+engine's programs, a pause of the collector: observability/pauses.py) is
+noted on the row of the iteration it fell in (`paused`).
 
 Derived metrics follow the serving-benchmark vocabulary of the vLLM/TGI
 comparative study (PAPERS.md, arXiv:2511.17593): TTFT is first token
@@ -30,8 +34,7 @@ is the gap between consecutive emitted tokens.
 from __future__ import annotations
 
 from collections import deque
-from contextlib import nullcontext
-from typing import Any, Dict, List, Optional, Tuple
+from typing import Any, Callable, Dict, List, Optional, Tuple
 
 # bounded per-timeline storage: events and ITL samples never grow past
 # these caps even for max_model_len generations (overflow keeps aggregate
@@ -43,6 +46,21 @@ MAX_DISPATCHES = 512
 
 #: the phases that tile one iteration of the engine's loop, in order
 PHASES = ("admit", "plan", "launch", "wait", "route", "yield")
+#: what the host's phases are made of, each timed by `DispatchPhases.span`
+#: inside the phase under way: `prepare`, `sampling`, `pack` make up `plan`
+#: (what precedes the packing: the legacy gate, growth, preemption, the
+#: lanes' arrays, the gauges; every rebuild of the sampling
+#: state; packing the ragged step), `upload`, `call`, `account` make up
+#: `launch` (the plan's arrays to the device; the jitted call up to its
+#: return; what the counting itself costs), `deliver` is the handing over
+#: of deferred tokens (inside `wait`, or in place), `register` the finishes
+#: with their page frees and the prefix cache's registration inside `route`
+PARTS = ("prepare", "sampling", "pack", "upload", "call", "account",
+         "deliver", "register")
+#: what stops the whole process and not the loop alone, noted on the row of
+#: the iteration it fell in: seconds of XLA compiles of anything but the
+#: engine's own programs, and of the collector's pauses (generations 1, 2)
+PAUSES = ("other_compile", "gc")
 #: when a token is handed to its stream: `overlapped` with the dispatch
 #: launched after the one that produced it, while that one runs, or `inline`,
 #: between its own dispatch's fetch and the next launch
@@ -53,11 +71,17 @@ DELIVERIES = ("overlapped", "inline")
 #: pair it needed: they differ where it ran padded in a loaded pair;
 #: `overlapped`, `inline` are the tokens this iteration handed to their
 #: streams, by DELIVERIES, and `deliver` the seconds it spent handing over
-#: those that had been deferred, inside whichever phase that was)
+#: those that had been deferred, inside whichever phase that was: the part
+#: of that name; the other PARTS follow, then `cpu_<phase>`, the CPU seconds
+#: of the loop's thread inside each of the iteration's PHASES, and the
+#: PAUSES)
+_PARTS_APPENDED = tuple(part for part in PARTS if part != "deliver")
+CPU_COLUMNS = tuple("cpu_" + phase for phase in PHASES)
 DISPATCH_COLUMNS = (
     "serial", "launched_at", "program", "tokens", "width", "need_tokens",
     "need_width", "prefill_tokens", "decode_tokens", *PHASES, "wait_lag",
-    "compiled", "chained", "deliver", *DELIVERIES)
+    "compiled", "chained", "deliver", *DELIVERIES, *_PARTS_APPENDED,
+    *CPU_COLUMNS, *PAUSES)
 
 
 class RequestTimeline:
@@ -331,6 +355,39 @@ class TimelineRecorder:
         }
 
 
+class _Part:
+    """One timed part of a phase, for `with` (DispatchPhases.span)."""
+
+    __slots__ = ("_phases", "_name", "_span", "_started", "_nested")
+
+    def __init__(self, phases: "DispatchPhases", name: str):
+        self._phases = phases
+        self._name = name
+        self._span = None
+        self._nested = 0.0  # seconds of the parts opened inside this one
+
+    def __enter__(self) -> "_Part":
+        phases = self._phases
+        if phases._annotate is not None:
+            self._span = phases._annotate(
+                "engine." + self._name, dispatch=phases.serial)
+            self._span.__enter__()
+        phases._open.append(self)
+        self._started = phases._clock.now()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        phases = self._phases
+        seconds = phases._clock.now() - self._started
+        phases._open.pop()
+        # never below zero, whatever the sums of nested readings round to
+        phases._parts[self._name] += max(0.0, seconds - self._nested)
+        if phases._open:
+            phases._open[-1]._nested += seconds
+        if self._span is not None:
+            self._span.__exit__(*exc)
+
+
 class DispatchPhases:
     """Stamps the phases of the engine's loop.  `mark(phase)` closes the
     phase under way at the clock's reading and opens the next; `launched`
@@ -340,33 +397,55 @@ class DispatchPhases:
     from it).  An iteration that dispatched nothing returns None and is
     dropped, so idle time is in no phase.  Where the dense path chains a
     dispatch on the one in flight, two launches are open at once: phases
-    go to the row committed next, as they occur.
+    and parts go to the row committed next, as they occur.
+
+    `span(part)` times what a phase is made of (PARTS): the seconds go to
+    the iteration's row under the part's name, less those of a part opened
+    inside it, so parts never count a second twice and never exceed their
+    phase.
 
     `annotate` is `jax.profiler.TraceAnnotation` in the engine: each phase
-    is then a host span `engine.<phase>` on the profiler's clock while a
-    capture runs, and one flag test when none does."""
+    and part is then a host span `engine.<name>` on the profiler's clock
+    while a capture runs, and one flag test when none does.  `cpu_clock`
+    is `time.thread_time` there: the CPU seconds of the loop's thread are
+    booked to the phase a `mark` closes (in `wait` they are the work the
+    device's step hides; in `launch`, wall less CPU is how long the thread
+    was blocked on the runtime); with none, they read 0."""
 
-    def __init__(self, clock, annotate=None):
+    def __init__(self, clock, annotate=None,
+                 cpu_clock: Optional[Callable[[], float]] = None):
         self._clock = clock
         self._annotate = annotate
+        self._cpu_clock = cpu_clock
         self.serial = 1  # of the oldest dispatch not yet committed
         self._span = None
         self._launches: deque = deque()
+        self._open: List[_Part] = []
         self._reset(None)
 
-    def _reset(self, now: Optional[float]) -> None:
+    def _reset(self, now: Optional[float], cpu: float = 0.0) -> None:
         self._phase: Optional[str] = None
         self._since = now
+        self._cpu_since = cpu
         self._seconds = dict.fromkeys(PHASES, 0.0)
+        self._cpu = dict.fromkeys(PHASES, 0.0)
+        self._parts = dict.fromkeys(PARTS, 0.0)
+        self._pauses = dict.fromkeys(PAUSES, 0.0)
         self._wait_lag = 0.0
-        self._deliver = 0.0
         self._handed = dict.fromkeys(DELIVERIES, 0)
 
-    def mark(self, phase: str) -> float:
+    def _close_phase(self) -> Tuple[float, float]:
+        """Book the clocks' readings to the phase under way."""
         now = self._clock.now()
+        cpu = self._cpu_clock() if self._cpu_clock is not None else 0.0
         if self._phase is not None:
             self._seconds[self._phase] += now - self._since
-        self._phase, self._since = phase, now
+            self._cpu[self._phase] += cpu - self._cpu_since
+        return now, cpu
+
+    def mark(self, phase: str) -> float:
+        now, cpu = self._close_phase()
+        self._phase, self._since, self._cpu_since = phase, now, cpu
         if self._annotate is not None:
             self.close()
             self._span = self._annotate("engine." + phase,
@@ -391,18 +470,21 @@ class DispatchPhases:
             self._since, program, tokens, width, *(need or (tokens, width)),
             prefill_tokens, decode_tokens, int(compiled), int(chained)])
 
-    def span(self, name: str):
-        """A host span `engine.<name>` nested in the phase under way, for
-        `with`; nothing while no annotation is set."""
-        if self._annotate is None:
-            return nullcontext()
-        return self._annotate("engine." + name, dispatch=self.serial)
+    def span(self, part: str) -> _Part:
+        """Times `part` (one of PARTS) of the phase under way, for `with`;
+        also a host span `engine.<part>` nested in the phase's."""
+        return _Part(self, part)
 
-    def delivered(self, when: str, tokens: int, seconds: float = 0.0) -> None:
+    def delivered(self, when: str, tokens: int) -> None:
         """`tokens` were handed to their streams, `when` (one of
-        DELIVERIES), in `seconds` of the phase under way."""
+        DELIVERIES)."""
         self._handed[when] += tokens
-        self._deliver += seconds
+
+    def paused(self, pause: str, seconds: float) -> None:
+        """The process stood still for `seconds` (`pause`: one of PAUSES);
+        outside an iteration that is nobody's row."""
+        if self._phase is not None:
+            self._pauses[pause] += seconds
 
     def resumed(self, ready_at: Optional[float]) -> None:
         """The loop took a fetched result up `now - ready_at` after the
@@ -415,21 +497,21 @@ class DispatchPhases:
         """Close the iteration.  The next one's `admit` opens at the same
         reading, so that consecutive iterations leave no time between them
         in no phase; a loop that goes idle calls `pause` instead."""
-        now = self._clock.now()
-        if self._phase is not None:
-            self._seconds[self._phase] += now - self._since
+        now, cpu = self._close_phase()
         self.close()
-        seconds = self._seconds
+        seconds, parts, cpus = self._seconds, self._parts, self._cpu
         wait_lag = min(self._wait_lag, seconds["wait"])
-        delivery = (self._deliver, *self._handed.values())
-        self._reset(now)
+        handed, pauses = self._handed, self._pauses
+        self._reset(now, cpu)
         self._phase = "admit"
         if not self._launches:
             return None
         launched_at, *what, compiled, chained = self._launches.popleft()
         row = [self.serial, launched_at, *what,
                *(seconds[p] for p in PHASES), wait_lag, compiled, chained,
-               *delivery]
+               parts["deliver"], *handed.values(),
+               *(parts[p] for p in _PARTS_APPENDED),
+               *cpus.values(), *pauses.values()]
         self.serial += 1
         return row
 

@@ -118,8 +118,9 @@ def standin_counts_dispatch_shapes(monkeypatch):
     the accepted benchmark's and so left as it is) grows the counter the
     program has since PR 33: its made-up engine pads one mixed dispatch in
     ten, so that `dispatch.padded_share`'s reader finds something to read
-    against it, like every other reader.  Kept here because a second
-    conftest.py would take this one's module name."""
+    against it, like every other reader; likewise the deliveries (PR 36)
+    and the host's parts, CPU seconds and pauses (PR 39).  Kept here because
+    a second conftest.py would take this one's module name."""
     standin = sys.modules.get("standin")
     if standin is None:  # not a test of the benchmark
         return
@@ -134,7 +135,27 @@ def standin_counts_dispatch_shapes(monkeypatch):
         return metrics(self) + "".join(
             series % fit for fit in (
                 ("exact", n - n // 10), ("padded", n // 10), ("compiled", 0))
-        ) + "".join(handed % when for when in (("overlapped", n), ("inline", 0)))
+        ) + "".join(handed % when for when in (("overlapped", n), ("inline", 0))
+        ) + host_parts(n)
+
+    def host_parts(n: int) -> str:
+        """Since PR 39 the host's side of a dispatch: the parts that make
+        up the stand-in's `plan` (2 ms) and `launch` (3 ms), one inside its
+        `route`, the delivery inside its `wait`, the CPU seconds of the
+        loop's thread (half of every phase) and the pauses of the process
+        (none)."""
+        parts = dict(prepare=0.0005, sampling=0.0005, pack=0.001,
+                     upload=0.0015, call=0.001, account=0.0005,
+                     deliver=0.010, register=0.0005)
+        lines = ['engine_dispatch_part_seconds_total{model_name="bench",'
+                 f'part="{part}"}} {n * s}' for part, s in parts.items()]
+        lines += ['engine_dispatch_phase_cpu_seconds_total{model_name="bench",'
+                  f'phase="{phase}"}} {n * s / 2}'
+                  for phase, s in standin.PHASES.items()]
+        lines += ["engine_other_compile_seconds_total 0.0"]
+        lines += [f'engine_gc_pause_seconds_total{{generation="{g}"}} 0.0'
+                  for g in (1, 2)]
+        return "\n".join(lines) + "\n"
 
     monkeypatch.setattr(standin.StandIn, "_metrics", with_dispatch_shapes)
 

@@ -25,7 +25,10 @@ from kserve_tpu.metrics import (
 )
 from kserve_tpu.models.llama import LlamaConfig
 from kserve_tpu.observability import (
+    CPU_COLUMNS,
     DISPATCH_COLUMNS,
+    PARTS,
+    PAUSES,
     PHASES,
     PROFILER_KEY,
     DispatchPhases,
@@ -34,6 +37,7 @@ from kserve_tpu.observability import (
     TimelineRecorder,
     percentiles,
 )
+from kserve_tpu.observability import pauses
 from kserve_tpu.protocol.model_repository_extension import ModelRepositoryExtension
 from kserve_tpu.protocol.openai.dataplane import OpenAIDataPlane
 from kserve_tpu.protocol.rest.server import RESTServer
@@ -44,7 +48,8 @@ from conftest import async_test
 from test_rest_server import DummyModel
 
 
-def make_engine(clock=None, metrics_label="obs-engine", **cfg_overrides):
+def make_engine(clock=None, metrics_label="obs-engine", cpu_clock=None,
+                **cfg_overrides):
     model_config = LlamaConfig.tiny(dtype="float32")
     cfg = dict(
         max_batch_size=4, page_size=8, num_pages=64, max_pages_per_seq=8,
@@ -54,7 +59,8 @@ def make_engine(clock=None, metrics_label="obs-engine", **cfg_overrides):
     cfg.update(cfg_overrides)
     tokenizer = ByteTokenizer(model_config.vocab_size)
     return LLMEngine(model_config, EngineConfig(**cfg), tokenizer,
-                     clock=clock, metrics_label=metrics_label)
+                     clock=clock, cpu_clock=cpu_clock,
+                     metrics_label=metrics_label)
 
 
 def hist(name, label, suffix):
@@ -640,7 +646,9 @@ class TestDispatchPhases:
             "prefill_tokens": 24, "decode_tokens": 8,
             "admit": 1.0, "plan": 2.0, "launch": 3.0, "wait": 10.0,
             "route": 0.5, "yield": 4.0, "wait_lag": 2.5, "compiled": 1,
-            "chained": 0, "deliver": 0.0, "overlapped": 0, "inline": 0}
+            "chained": 0, "deliver": 0.0, "overlapped": 0, "inline": 0,
+            **dict.fromkeys(PARTS, 0.0), **dict.fromkeys(CPU_COLUMNS, 0.0),
+            **dict.fromkeys(PAUSES, 0.0)}
         assert sum(row[p] for p in PHASES) == clock.now()
         assert phases.serial == 2
         # an iteration that launched nothing is dropped, and so is idle time
@@ -812,8 +820,8 @@ class TestDispatchPhases:
             return REGISTRY.get_sample_value(
                 name, {"model_name": label, **labels}) or 0.0
 
-        assert counter("engine_dispatch_deliver_seconds_total") == sum(
-            r["deliver"] for r in rows)
+        assert counter("engine_dispatch_part_seconds_total",
+                       part="deliver") == sum(r["deliver"] for r in rows)
         assert counter("engine_dispatch_deliveries_total", when="overlapped") == 16
         assert counter("engine_dispatch_deliveries_total", when="inline") == 8
         for phase in (*PHASES, "wait_lag"):
@@ -918,6 +926,402 @@ class TestDispatchPhases:
         names = {e.name for plane in ProfileData.from_file(str(path)).planes
                  for line in plane.lines for e in line.events}
         assert {"engine." + p for p in PHASES} <= names
+        assert {"engine." + p for p in PARTS} <= names  # nested in them
+
+
+# ------------------------------------------- parts, CPU seconds, pauses
+
+
+class _HalfCpu:
+    """A CPU clock for tests: the thread is busy half of every second of
+    the engine's clock (read without ticking it)."""
+
+    def __init__(self, clock):
+        self._clock = clock
+
+    def __call__(self) -> float:
+        return 0.5 * self._clock._now
+
+
+def _counter(label):
+    def counter(name, **labels):
+        return REGISTRY.get_sample_value(
+            name, {"model_name": label, **labels}) or 0.0
+    return counter
+
+
+def _process_counter(name, **labels):
+    return REGISTRY.get_sample_value(name, labels) or 0.0
+
+
+class TestDispatchParts:
+    def test_a_part_is_timed_less_the_parts_inside_it(self):
+        clock = FakeClock()
+        cpu = [0.0]
+        phases = DispatchPhases(clock, cpu_clock=lambda: cpu[0])
+
+        def spend(wall, busy):
+            clock.advance(wall)
+            cpu[0] += busy
+
+        phases.mark("plan")
+        spend(0.25, 0.25)  # between the stamp and the first part
+        with phases.span("prepare"):
+            spend(1.0, 1.0)
+            with phases.span("sampling"):
+                spend(2.0, 1.5)
+            spend(0.5, 0.5)
+        with phases.span("pack"):
+            spend(3.0, 3.0)
+            with phases.span("sampling"):
+                spend(1.0, 1.0)
+        phases.mark("launch")
+        with phases.span("upload"):
+            spend(2.0, 0.5)  # blocked on the runtime for 1.5 s
+        with phases.span("call"):
+            spend(1.0, 1.0)
+        with phases.span("account"):
+            phases.launched("mixed", 32, 8, 24, 8)
+            spend(0.5, 0.5)
+        phases.mark("wait")
+        with phases.span("deliver"):
+            spend(4.0, 4.0)
+        spend(6.0, 1.0)  # the loop's turns while the device runs
+        phases.mark("route")
+        with phases.span("register"):
+            spend(0.125, 0.125)
+        row = _row(phases.commit())
+        assert {part: row[part] for part in PARTS} == {
+            "prepare": 1.5, "sampling": 3.0, "pack": 3.0, "upload": 2.0,
+            "call": 1.0, "account": 0.5, "deliver": 4.0, "register": 0.125}
+        assert row["prepare"] + row["sampling"] + row["pack"] == row["plan"] - 0.25
+        assert row["upload"] + row["call"] + row["account"] == row["launch"]
+        assert row["deliver"] <= row["wait"] and row["register"] <= row["route"]
+        assert {column: row[column] for column in CPU_COLUMNS} == {
+            "cpu_admit": 0.0, "cpu_plan": 7.25, "cpu_launch": 2.0,
+            "cpu_wait": 5.0, "cpu_route": 0.125, "cpu_yield": 0.0}
+        assert all(row["cpu_" + p] <= row[p] for p in PHASES)
+        # the next iteration starts from nothing
+        phases.mark("launch")
+        phases.launched("mixed", 16, 8, 0, 2)
+        row = _row(phases.commit())
+        assert not any(row[column] for column in (*PARTS, *CPU_COLUMNS))
+
+    def test_without_a_cpu_clock_the_columns_read_zero(self):
+        clock = FakeClock()
+        phases = DispatchPhases(clock)
+        phases.mark("launch")
+        phases.launched("mixed", 16, 8, 0, 2)
+        clock.advance(3.0)
+        row = _row(phases.commit())
+        assert (row["launch"], row["cpu_launch"], row["cpu_wait"]) == (3.0, 0.0, 0.0)
+
+    def test_parts_are_spans_nested_in_their_phase_with_its_serial(self):
+        events = []
+
+        class Span:
+            def __init__(self, name, **kwargs):
+                self.name = (name, kwargs["dispatch"])
+
+            def __enter__(self):
+                events.append(("open", *self.name))
+                return self
+
+            def __exit__(self, *exc):
+                events.append(("close", *self.name))
+
+        phases = DispatchPhases(FakeClock(), annotate=Span)
+        phases.mark("plan")
+        with phases.span("pack"):
+            with phases.span("sampling"):
+                pass
+        phases.mark("launch")
+        with phases.span("upload"):
+            pass
+        phases.launched("mixed", 16, 8, 0, 2)
+        phases.commit()
+        assert events == [
+            ("open", "engine.plan", 1), ("open", "engine.pack", 1),
+            ("open", "engine.sampling", 1), ("close", "engine.sampling", 1),
+            ("close", "engine.pack", 1), ("close", "engine.plan", 1),
+            ("open", "engine.launch", 1), ("open", "engine.upload", 1),
+            ("close", "engine.upload", 1), ("close", "engine.launch", 1)]
+
+    def test_two_open_launches_book_parts_to_the_row_committed_next(self):
+        """The dense path: dispatch 2 is launched, chained, before dispatch
+        1 is routed, so its launch's parts are in the iteration that
+        commits row 1, as its `launch` phase is; row 2 holds what came
+        after that commit."""
+        clock = FakeClock()
+        phases = DispatchPhases(clock)
+        for chained in (False, True):
+            phases.mark("launch")
+            with phases.span("upload"):
+                clock.advance(1.0)
+            with phases.span("call"):
+                clock.advance(2.0)
+            phases.launched("mixed_decode", 4, 8, 0, 4, chained=chained)
+        first = _row(phases.commit())
+        phases.mark("route")
+        with phases.span("register"):
+            clock.advance(0.5)
+        second = _row(phases.commit())
+        assert (first["serial"], first["launch"], first["upload"],
+                first["call"], first["register"]) == (1, 6.0, 2.0, 4.0, 0.0)
+        assert (second["serial"], second["chained"], second["launch"],
+                second["upload"], second["register"]) == (2, 1, 0.0, 0.0, 0.5)
+
+    def test_a_pause_outside_an_iteration_is_nobody_s(self):
+        clock = FakeClock()
+        phases = DispatchPhases(clock)
+        phases.paused("gc", 1.0)  # the loop is idle
+        phases.mark("launch")
+        phases.paused("gc", 0.25)
+        phases.paused("other_compile", 2.0)
+        phases.launched("mixed", 16, 8, 0, 2)
+        row = _row(phases.commit())
+        assert (row["gc"], row["other_compile"]) == (0.25, 2.0)
+        phases.pause()
+        phases.paused("other_compile", 5.0)
+        phases.mark("launch")
+        phases.launched("mixed", 16, 8, 0, 2)
+        assert _row(phases.commit())["other_compile"] == 0.0
+
+    @async_test
+    async def test_engine_parts_make_up_plan_and_launch(self):
+        """A `mixed` iteration under the ticking clock: the three parts of
+        `plan` and the three of `launch` account for their phase but for
+        the stamps between them (each reading of the clock is a second),
+        no part exceeds its phase, the CPU seconds of every phase are
+        under its wall seconds, and the counters hold the rows' sums."""
+        label = "obs-parts"
+        clock = _TickClock()
+        engine = make_engine(clock=clock, metrics_label=label,
+                             cpu_clock=_HalfCpu(clock))
+        await engine.start()
+        params = SamplingParams(max_tokens=20, temperature=0.0, ignore_eos=True)
+        await asyncio.gather(
+            collect(engine.generate(list(range(1, 40)), params)),
+            collect(engine.generate([4, 5, 6], params)))
+        await engine.stop()
+        rows = [_row(r) for r in engine.telemetry_snapshot()["dispatches"]["rows"]]
+        assert len(rows) >= 4
+        for r in rows:
+            # plan | prepare | pack | launch: three stamps apart
+            assert r["plan"] - (r["prepare"] + r["sampling"] + r["pack"]) == 3.0
+            # launch | upload | call | account | wait: four
+            assert r["launch"] - (r["upload"] + r["call"] + r["account"]) == 4.0
+            assert r["deliver"] <= r["wait"] + r["route"]
+            assert r["register"] <= r["route"]
+            assert all(r["cpu_" + p] == 0.5 * r[p] for p in PHASES)
+        # a lane that is decoding has its sampling state rebuilt twice an
+        # iteration (`_prepare_chunk`, `_plan_ragged`), a first dispatch of
+        # prompts alone once
+        assert rows[0]["sampling"] == 1.0 and rows[-1]["sampling"] == 2.0
+        assert rows[-1]["register"] > 0.0  # the finishes give their pages back
+        counter = _counter(label)
+        for part in PARTS:
+            assert counter("engine_dispatch_part_seconds_total",
+                           part=part) == sum(r[part] for r in rows)
+        for phase in PHASES:
+            cpu = counter("engine_dispatch_phase_cpu_seconds_total", phase=phase)
+            assert cpu == 0.5 * sum(r[phase] for r in rows)
+            assert cpu <= counter("engine_dispatch_phase_seconds_total",
+                                  phase=phase)
+
+    @async_test
+    async def test_the_dense_path_books_parts_as_it_books_phases(self):
+        """Two dispatches in flight at once (`mixed_decode`, chained): every
+        second of a launch's parts is in the row whose `launch` holds it."""
+        engine = make_engine(clock=_TickClock(), metrics_label="obs-parts-dense",
+                             spec_decode_k=0)
+        await engine.start()
+        params = SamplingParams(max_tokens=24, temperature=0.0, ignore_eos=True)
+        await collect(engine.generate([4, 5, 6], params))
+        await engine.stop()
+        rows = [_row(r) for r in engine.telemetry_snapshot()["dispatches"]["rows"]]
+        dense = [r for r in rows if r["program"] == "mixed_decode"]
+        assert len(dense) >= 2 and any(r["chained"] for r in dense)
+        for r in rows:
+            launch = r["upload"] + r["call"] + r["account"]
+            assert (launch > 0.0) == (r["launch"] > 0.0)
+            assert launch <= r["launch"]
+            assert r["prepare"] + r["sampling"] + r["pack"] <= r["plan"]
+
+    @async_test
+    async def test_real_clocks_cpu_stays_under_wall(self):
+        engine = make_engine(metrics_label="obs-parts-real")
+        await engine.start()
+        params = SamplingParams(max_tokens=12, temperature=0.0, ignore_eos=True)
+        await collect(engine.generate([4, 5, 6], params))
+        await engine.stop()
+        rows = [_row(r) for r in engine.telemetry_snapshot()["dispatches"]["rows"]]
+        assert rows and all(sum(r[c] for c in CPU_COLUMNS) > 0.0 for r in rows)
+        # thread_time and monotonic are two clocks: a microsecond of slack
+        assert all(r["cpu_" + p] <= r[p] + 1e-4 for r in rows for p in PHASES)
+
+
+class TestProcessPauses:
+    @async_test
+    async def test_compiles_by_origin_the_row_and_the_listener_s_life(self):
+        """One of the engine's programs compiling is the engine's own and
+        no `other` compile; a helper jitted on the loop's thread in the
+        middle of an iteration is one, lands in that iteration's row and
+        nowhere else, and is logged once; the listener and the collector's
+        callback leave with the last engine."""
+        import gc
+
+        import jax
+        import jax.numpy as jnp
+        from jax._src import monitoring
+
+        watched_before = list(pauses._watchers)
+        engine = make_engine(metrics_label="obs-pauses")
+        seen = _process_counter("engine_other_compile_seconds_total")
+        await engine.start()
+        assert pauses._on_gc in gc.callbacks
+        assert pauses._on_event_duration in monitoring.get_event_duration_listeners()
+        plan_ragged, calls = engine._plan_ragged, []
+
+        def compile_a_helper(meta, prefilling):
+            if len(calls) == 1:  # inside the second iteration's `plan`
+                jax.jit(lambda x: x * 3 + len(calls))(jnp.ones((7,)))
+            calls.append(engine._phases.serial)
+            return plan_ragged(meta, prefilling)
+
+        engine._plan_ragged = compile_a_helper
+        params = SamplingParams(max_tokens=20, temperature=0.0, ignore_eos=True)
+        await collect(engine.generate([4, 5, 6], params))
+        await engine.stop()
+        rows = [_row(r) for r in engine.telemetry_snapshot()["dispatches"]["rows"]]
+        hit = [r for r in rows if r["other_compile"] > 0.0]
+        assert calls[1] in [r["serial"] for r in hit]
+        # the engine's own programs compiled in these iterations too
+        # (`compiled`), and are nobody's `other_compile`
+        assert any(r["compiled"] and not r["other_compile"] for r in rows)
+        assert (_process_counter("engine_other_compile_seconds_total") - seen
+                >= sum(r["other_compile"] for r in hit) > 0.0)
+        assert all(r["other_compile"] <= r["plan"] + r["launch"] for r in rows
+                   if r["serial"] == calls[1])
+        assert engine._warned_other_compile
+        assert pauses._ref(engine._paused) not in pauses._watchers
+        assert pauses._watchers == watched_before
+        if not watched_before:
+            assert pauses._on_gc not in gc.callbacks
+            assert (pauses._on_event_duration
+                    not in monitoring.get_event_duration_listeners())
+
+    def test_a_counted_program_s_miss_is_its_own_and_a_bare_jit_other(self):
+        import jax
+        import jax.numpy as jnp
+
+        from kserve_tpu.engine.compiled import _CompileCounting
+
+        told = []
+
+        def watcher(pause, seconds, what):
+            if pause != "gc":  # the collector runs when it likes
+                told.append((pause, what))
+
+        pauses.watch(watcher)
+        try:
+            def other():
+                return _process_counter("engine_other_compile_seconds_total")
+
+            x = jnp.ones((3,))  # made before the count: `ones` compiles too
+            told.clear()
+            before = other()
+            fn = _CompileCounting(
+                "obs-origin", jax.jit(lambda x: x * 5 + 2))
+            fn(x)
+            assert fn.compiles == 1 and other() == before and told == []
+
+            def obs_bare_helper(x):
+                return x * 7 + 3
+
+            jax.jit(obs_bare_helper)(x)
+            assert other() > before and fn.compiles == 1
+            assert told == [("other_compile", "jit(obs_bare_helper)")]
+        finally:
+            pauses.unwatch(watcher)
+        assert pauses._ref(watcher) not in pauses._watchers
+
+    def test_a_watcher_that_is_gone_is_not_held_nor_told(self):
+        """An engine that never reaches the end of its `stop()` is not
+        kept alive by the process's list, and the listener and the
+        collector's callback leave once nobody is left to tell."""
+        import gc
+
+        class Engine:
+            def __init__(self):
+                self.told = []
+
+            def paused(self, pause, seconds, what=""):
+                self.told.append(pause)
+
+        watched_before = list(pauses._watchers)
+        engine = Engine()
+        pauses.watch(engine.paused)
+        pauses.watch(engine.paused)  # once is enough
+        assert len(pauses._watchers) == len(watched_before) + 1
+        gc.collect()
+        assert "gc" in engine.told
+        del engine
+        gc.collect()
+        pauses._tell("gc", 0.0)  # nobody there, nothing raised
+
+        def late(pause, seconds, what):
+            pass
+
+        pauses.watch(late)
+        pauses.unwatch(late)
+        assert pauses._watchers == watched_before
+        if not watched_before:
+            assert pauses._on_gc not in gc.callbacks
+
+    @async_test
+    async def test_a_forced_collection_fills_the_row_s_gc(self):
+        import gc
+
+        engine = make_engine(metrics_label="obs-gc")
+        await engine.start()
+        plan_ragged, calls = engine._plan_ragged, []
+
+        def collect_garbage(meta, prefilling):
+            if len(calls) == 1:
+                gc.collect()  # generation 2, inside the second iteration
+            calls.append(engine._phases.serial)
+            return plan_ragged(meta, prefilling)
+
+        engine._plan_ragged = collect_garbage
+        before = _process_counter("engine_gc_pause_seconds_total", generation="2")
+        params = SamplingParams(max_tokens=20, temperature=0.0, ignore_eos=True)
+        await collect(engine.generate([4, 5, 6], params))
+        await engine.stop()
+        rows = [_row(r) for r in engine.telemetry_snapshot()["dispatches"]["rows"]]
+        (row,) = [r for r in rows if r["serial"] == calls[1]]
+        assert 0.0 < row["gc"] <= row["plan"]
+        assert _process_counter(
+            "engine_gc_pause_seconds_total", generation="2") >= before + row["gc"]
+
+    def test_a_collection_of_generation_0_is_not_timed(self):
+        import gc
+
+        told = []
+
+        def watcher(pause, seconds, what):
+            told.append(pause)
+
+        pauses.watch(watcher)
+        gc.disable()  # only the collections made up here
+        try:
+            for generation, so_far in ((0, []), (1, ["gc"]), (2, ["gc", "gc"])):
+                pauses._on_gc("start", {"generation": generation})
+                pauses._on_gc("stop", {"generation": generation})
+                assert told == so_far
+        finally:
+            gc.enable()
+            pauses.unwatch(watcher)
 
 
 # ------------------------------------------------------- trace propagation
