@@ -48,6 +48,7 @@ from ..metrics import (
     ENGINE_KV_DISK_BYTES,
     ENGINE_KV_OFFLOAD_BYTES,
     ENGINE_KV_CONTEXT_TOKENS,
+    ENGINE_KV_WRITE_CALLS,
     ENGINE_MOE_ASSIGNMENTS,
     ENGINE_MOE_EXPERT_HITS,
     ENGINE_MOE_PEAK_LOAD,
@@ -87,6 +88,7 @@ from ..observability import (
     DELIVERIES,
     CPU_COLUMNS,
     DISPATCH_COLUMNS,
+    KV_WRITE_PATHS,
     PARTS,
     PHASES,
     DispatchPhases,
@@ -389,6 +391,10 @@ class LLMEngine:
             model_name=metrics_label)
         self._kv_context_tokens = ENGINE_KV_CONTEXT_TOKENS.labels(
             model_name=metrics_label)
+        self._kv_write_calls = {
+            path: ENGINE_KV_WRITE_CALLS.labels(
+                model_name=metrics_label, write_path=path)
+            for path in KV_WRITE_PATHS}
         # engine_moe_*_total: pairs counted at launch, hits and peak load
         # summed in the program (the `mixed` program's last two rows)
         self._moe_assignments = ENGINE_MOE_ASSIGNMENTS.labels(
@@ -855,6 +861,11 @@ class LLMEngine:
                 model_config, engine_config, jax.default_backend()),
             "shapes": self._shapes.published(),
         }
+        # layers a pass writes by each K/V write path (_count_forward): the
+        # report's path of each kind of cache x the layers of that kind
+        self._kv_write_layers = dict.fromkeys(KV_WRITE_PATHS, 0)
+        for kind, path in self.dispatch_report["attention"]["kv_write"].items():
+            self._kv_write_layers[path] += len(getattr(layout, kind + "_layers"))
         self._set_state_gauges()
         # what the pool is made of does not change while the engine lives
         ENGINE_KV_PAGES_TOTAL.labels(model_name=metrics_label).set(
@@ -1959,7 +1970,7 @@ class LLMEngine:
         state = SamplingState.from_params(params_list)
         rng = jax.random.fold_in(self._base_rng, self._next_step())
         try:
-            self._count_forward(1)
+            self._count_forward(1, legacy_prefill=True)
             first, self.kv_pages = self._prefill_fn(
                 self.params,
                 jnp.asarray(tokens),
@@ -2500,7 +2511,8 @@ class LLMEngine:
         )
         lp_tuple = None
         prefill_t0 = self._clock.now()
-        self._count_forward(1)  # one legacy prefill forward, either program
+        # one legacy prefill forward, either program
+        self._count_forward(1, legacy_prefill=True)
         if use_fused_call:
             prefill_fn = self._prefill_lp_fn if want_lp else self._prefill_fn
             out = prefill_fn(
@@ -2655,10 +2667,14 @@ class LLMEngine:
         }
 
     def _count_forward(self, steps: int, pos=None, live=None, capacity=None,
-                       decode_steps: int = 0, packed_tokens: int = 0) -> None:
+                       decode_steps: int = 0, packed_tokens: int = 0,
+                       legacy_prefill: bool = False) -> None:
         """Count what a launch runs: `steps` forward steps (each all the
-        model's passes) and, over its `decode_steps` decode steps, the
-        cached tokens the live lanes attend to.  Lane b, live at position
+        model's passes, each pass one K/V write a writing layer: by the path
+        the program was built with, and by the row scatter in a
+        `legacy_prefill` program whatever the others take) and, over its
+        `decode_steps` decode steps, the cached tokens the live lanes attend
+        to.  Lane b, live at position
         pos[b], attends to pos[b] + s + 1 tokens at decode step s while it
         stays under its page capacity: the device's own rule
         (compiled._make_decode / _make_mixed), evaluated on the host.  The
@@ -2667,6 +2683,11 @@ class LLMEngine:
         experts in every expert layer."""
         mc = self.model_config
         self._layer_passes.inc(steps * mc.n_passes)
+        for path, layers in self._kv_write_layers.items():
+            if layers:
+                path = "row_scatter" if legacy_prefill else path
+                self._kv_write_calls[path].inc(steps * mc.n_passes * layers)
+                self._phases.wrote(path, steps * mc.n_passes * layers)
         tokens = packed_tokens
         if decode_steps and pos is not None:
             pos = np.asarray(pos, np.int64)
@@ -2820,7 +2841,7 @@ class LLMEngine:
                 tl = pf["req"].timeline
                 if tl is not None:
                     tl.mark_prefill_start(chunk_t0)
-                self._count_forward(1)
+                self._count_forward(1, legacy_prefill=True)
                 pf["logits"], self.kv_pages = self._prefill_chunk_fn(
                     self.params,
                     jnp.asarray(tokens),

@@ -31,6 +31,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..ops.attention import kv_write_path
+
 
 @dataclass
 class KVCacheConfig:
@@ -392,6 +394,23 @@ def _scatter_kv(kv_pages, k, v, pages_flat, slot_flat):
     )
 
 
+def slice_runs(q_start, q_len, kv_start):
+    """The packed buffer's slices as the page write's runs
+    (`write_ragged_kv`): one set, lane b's q_len[b] tokens from buffer
+    index q_start[b] to positions kv_start[b] .. of its own pages."""
+    return [(jnp.arange(q_start.shape[0], dtype=jnp.int32), q_start, q_len,
+             kv_start)]
+
+
+def _page_kernel(page_kernel, kv_pages, v) -> bool:
+    """Whether a write runs as the page kernel (ops/pallas_kv_write.py):
+    the caller's word, or ops/attention.kv_write_path's from what the trace
+    can see.  A caller whose cache is sharded over a mesh says False."""
+    if page_kernel is None:
+        return kv_write_path(kv_pages, v) == "page_kernel"
+    return page_kernel
+
+
 @jax.named_scope("kv_write")
 def write_ragged_kv(
     kv_pages,  # [num_pages, 2, n_kv, ps, d] or (int8 pages, scales)
@@ -401,12 +420,25 @@ def write_ragged_kv(
     token_seq: jnp.ndarray,  # [T] sequence index per packed token (-1 = pad)
     token_pos: jnp.ndarray,  # [T] absolute position per packed token
     page_size: int,
+    runs=None,  # the same tokens as sets of runs (row, src, n, pos), each
+    # [M]: run m is buffer rows src[m] .. src[m] + n[m] at positions pos[m]
+    # .. of page_table[row[m]].  No two runs of a set on one page; the sets
+    # are written one after the other
+    page_kernel: Optional[bool] = None,  # None: kv_write_path decides
 ):
-    """Ragged-batch scatter: each packed token lands at its sequence's
-    (page, slot) for its absolute position; padding tokens (seq -1) write
-    to the null page.  Decode steps (one token per sequence) and prompt
-    chunks (many) are the same scatter — the write half of the ragged
-    contract (docs/kernels.md)."""
+    """Ragged-batch write: each packed token lands at its sequence's
+    (page, slot) for its absolute position.  Decode steps (one token per
+    sequence) and prompt chunks (many) are the same write — the write half
+    of the ragged contract (docs/kernels.md).  Given the tokens as `runs`
+    it is a page write where the kernel runs (padding tokens then write
+    nothing); else a row scatter (padding tokens, seq -1, write to the null
+    page)."""
+    if runs is not None and _page_kernel(page_kernel, kv_pages, v):
+        from ..ops.pallas_kv_write import write_runs
+
+        for one in runs:
+            kv_pages = write_runs(kv_pages, k, v, page_table, *one)
+        return kv_pages
     valid = token_seq >= 0
     seq_ix = jnp.maximum(token_seq, 0)
     page = jnp.where(
@@ -423,10 +455,17 @@ def append_token_kv(
     v,  # [B, n_kv, d]; None: latent pages, k the rows [B, 1, row]
     page_table: jnp.ndarray,  # [B, max_pages_per_seq]
     pos: jnp.ndarray,  # [B] position being written
-    active: jnp.ndarray,  # [B] bool — inactive slots write to null page
+    active: jnp.ndarray,  # [B] bool
     page_size: int,
+    page_kernel: Optional[bool] = None,  # None: kv_write_path decides
 ) -> jnp.ndarray:
-    """Decode-step scatter: one new token per active sequence."""
+    """Decode-step write: one new token per active sequence.  An inactive
+    lane writes nothing where the page kernel runs, and to the null page
+    where the scatter does."""
+    if _page_kernel(page_kernel, kv_pages, v):
+        from ..ops.pallas_kv_write import append_rows
+
+        return append_rows(kv_pages, k, v, page_table, pos, active)
     B = k.shape[0]
     b = jnp.arange(B, dtype=jnp.int32)
     page = jnp.where(active, page_table[b, pos // page_size], 0)

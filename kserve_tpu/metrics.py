@@ -420,6 +420,14 @@ ENGINE_LAYER_PASSES = Counter(
     "(1 a step for a model that is not looped)",
     ["model_name"],
 )
+ENGINE_KV_WRITE_CALLS = Counter(
+    "engine_kv_write_calls_total",
+    "K/V writes of one layer in one forward step (a layer-step), by the "
+    "path the program was built with: page_kernel (the Pallas page write) | "
+    "row_scatter (XLA's scatter); from static shapes: forward steps x "
+    "passes x the layers that write a cache of that path",
+    ["model_name", "write_path"],
+)
 ENGINE_KV_CONTEXT_TOKENS = Counter(
     "engine_kv_context_tokens_total",
     "sum over a dispatch's decode steps of the cached tokens its live "

@@ -54,7 +54,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..engine.kvcache import append_token_kv, write_ragged_kv
+from ..engine.kvcache import append_token_kv, slice_runs, write_ragged_kv
 from ..ops import ssm
 from ..ops.attention import (
     latent_paged_attention,
@@ -447,6 +447,22 @@ def decode_step(params, config, tokens, pos, state, page_table, active,
 # ---------------- the packed buffer ----------------
 
 
+def _ring_runs(q_start, q_len, kv_start, R: int):
+    """A window layer's packed write as the page write's runs
+    (engine/kvcache.write_ragged_kv): of lane b's slice the newest R tokens
+    are kept; those up to the ring's end are one run, those that wrap to
+    its start another.  Two sets, written one after the other, because the
+    two runs of one lane may meet on a page."""
+    lanes = jnp.arange(q_start.shape[0], dtype=jnp.int32)
+    skipped = jnp.maximum(q_len - R, 0)
+    first = (kv_start + skipped) % R
+    n = q_len - skipped
+    to_end = jnp.minimum(n, R - first)
+    return [(lanes, q_start + skipped, to_end, first),
+            (lanes, q_start + skipped + to_end, n - to_end,
+             jnp.zeros_like(first))]
+
+
 def forward_ragged(params, config, tokens, token_seq, token_pos, q_start,
                    q_len, kv_start, state, page_table, page_size: int,
                    last_idx, use_pallas: Optional[bool] = None,
@@ -519,7 +535,8 @@ def forward_ragged(params, config, tokens, token_seq, token_pos, q_start,
                 kept = token_pos >= (kv_start + q_len)[lane] - R
                 state["window"][j] = write_ragged_kv(
                     ring, k, v, ring_table,
-                    jnp.where(kept, token_seq, -1), token_pos % R, ps)
+                    jnp.where(kept, token_seq, -1), token_pos % R, ps,
+                    runs=_ring_runs(q_start, q_len, kv_start, R))
                 mixed = _differential_out(layer, attn, config, i)
         elif spec.kind == "attention" and i == last_writer:
             # K/V of every token go to the pool; the attention's own output
@@ -529,7 +546,7 @@ def forward_ragged(params, config, tokens, token_seq, token_pos, q_start,
                 j = slots[i]
                 state["paged"][j] = write_ragged_kv(
                     state["paged"][j], k, v, page_table, token_seq, token_pos,
-                    page_size)
+                    page_size, runs=slice_runs(q_start, q_len, kv_start))
             x = x[last_idx]
             handed = {key: m[last_idx] for key, m in handed.items()}
             x = _rows_layer(layer, spec, i, x, pos_rows, has_slice, state,

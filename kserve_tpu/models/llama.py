@@ -30,6 +30,7 @@ import numpy as np
 
 from ..engine.kvcache import (
     append_token_kv,
+    slice_runs,
     write_chunk_kv_batch,
     write_prompt_kv_batch,
     write_ragged_kv,
@@ -1049,6 +1050,9 @@ def decode_step(
     x = _embed(params, tokens, config)[:, None, :]  # [B,1,h]
     positions = pos[:, None]
     seq_lens = jnp.where(active, pos + 1, 0)
+    # a sharded attention_fn means a cache sharded over the mesh: its write
+    # stays the scatter GSPMD partitions (ops/attention.kv_write_path)
+    page_kernel = None if attention_fn is None else False
 
     def stack(x, kv_pages, page_table):
         new_pages = []
@@ -1060,8 +1064,8 @@ def decode_step(
                 q = apply_rope(q, positions, config.rope_theta, config.rope_scaling)
                 k = apply_rope(k, positions, config.rope_theta, config.rope_scaling)
                 pages = append_token_kv(
-                    pages, k[:, 0], v[:, 0], page_table, pos, active, page_size
-                )
+                    pages, k[:, 0], v[:, 0], page_table, pos, active,
+                    page_size, page_kernel=page_kernel)
                 window = layer.get("attn_window")
                 if attention_fn is not None:
                     attn = attention_fn(q[:, 0], pages, page_table, seq_lens,
@@ -1152,6 +1156,8 @@ def forward_ragged(
     onehot = _adapter_onehot(params, token_adapters, T)
     x = _embed(params, tokens, config)[:, None, :]  # [T, 1, h]
     positions = token_pos[:, None]
+    runs = slice_runs(q_start, q_len, kv_start)
+    page_kernel = None if attention_fn is None else False  # as decode_step
 
     def stack(x, kv_pages, page_table):
         new_pages = []
@@ -1164,8 +1170,7 @@ def forward_ragged(
                 k = apply_rope(k, positions, config.rope_theta, config.rope_scaling)
                 pages = write_ragged_kv(
                     pages, k[:, 0], v[:, 0], page_table, token_seq, token_pos,
-                    page_size,
-                )
+                    page_size, runs=runs, page_kernel=page_kernel)
                 window = layer.get("attn_window")
                 if attention_fn is not None:
                     attn = attention_fn(
@@ -1255,7 +1260,8 @@ def _pp_decode_block(config: LlamaConfig, page_size: int):
             q = apply_rope(q, positions, config.rope_theta, config.rope_scaling)
             k = apply_rope(k, positions, config.rope_theta, config.rope_scaling)
             pages_l = append_token_kv(
-                pages_l, k[:, 0], v[:, 0], page_table, pos, live, page_size)
+                pages_l, k[:, 0], v[:, 0], page_table, pos, live, page_size,
+                page_kernel=False)  # a stage's cache may be sharded over tp
             seq_lens = jnp.where(live, pos + 1, 0)
             attn = paged_attention(
                 q[:, 0], pages_l, page_table, seq_lens,

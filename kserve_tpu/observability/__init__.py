@@ -24,6 +24,7 @@ from .timeline import (  # noqa: F401
     CPU_COLUMNS,
     DELIVERIES,
     DISPATCH_COLUMNS,
+    KV_WRITE_PATHS,
     PARTS,
     PAUSES,
     PHASES,

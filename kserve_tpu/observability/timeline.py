@@ -65,6 +65,9 @@ PAUSES = ("other_compile", "gc")
 #: launched after the one that produced it, while that one runs, or `inline`,
 #: between its own dispatch's fetch and the next launch
 DELIVERIES = ("overlapped", "inline")
+#: how a layer's K/V write runs (ops/attention.kv_write_path): a row's
+#: `kv_<path>` is the layer-steps its launches wrote that way
+KV_WRITE_PATHS = ("page_kernel", "row_scatter")
 #: the columns of a dispatch row: flat, so a snapshot of the whole ring
 #: serialises in about a millisecond
 #: (`tokens`, `width` are the (T, W) pair the dispatch ran in, `need_*` the
@@ -74,14 +77,14 @@ DELIVERIES = ("overlapped", "inline")
 #: those that had been deferred, inside whichever phase that was: the part
 #: of that name; the other PARTS follow, then `cpu_<phase>`, the CPU seconds
 #: of the loop's thread inside each of the iteration's PHASES, and the
-#: PAUSES)
+#: PAUSES; last the K/V writes, by KV_WRITE_PATHS)
 _PARTS_APPENDED = tuple(part for part in PARTS if part != "deliver")
 CPU_COLUMNS = tuple("cpu_" + phase for phase in PHASES)
 DISPATCH_COLUMNS = (
     "serial", "launched_at", "program", "tokens", "width", "need_tokens",
     "need_width", "prefill_tokens", "decode_tokens", *PHASES, "wait_lag",
     "compiled", "chained", "deliver", *DELIVERIES, *_PARTS_APPENDED,
-    *CPU_COLUMNS, *PAUSES)
+    *CPU_COLUMNS, *PAUSES, *("kv_" + path for path in KV_WRITE_PATHS))
 
 
 class RequestTimeline:
@@ -433,6 +436,7 @@ class DispatchPhases:
         self._pauses = dict.fromkeys(PAUSES, 0.0)
         self._wait_lag = 0.0
         self._handed = dict.fromkeys(DELIVERIES, 0)
+        self._kv_writes = dict.fromkeys(KV_WRITE_PATHS, 0)
 
     def _close_phase(self) -> Tuple[float, float]:
         """Book the clocks' readings to the phase under way."""
@@ -480,6 +484,11 @@ class DispatchPhases:
         DELIVERIES)."""
         self._handed[when] += tokens
 
+    def wrote(self, path: str, layer_steps: int) -> None:
+        """A launch's forward steps write `layer_steps` layers' K/V by
+        `path` (one of KV_WRITE_PATHS)."""
+        self._kv_writes[path] += layer_steps
+
     def paused(self, pause: str, seconds: float) -> None:
         """The process stood still for `seconds` (`pause`: one of PAUSES);
         outside an iteration that is nobody's row."""
@@ -501,7 +510,7 @@ class DispatchPhases:
         self.close()
         seconds, parts, cpus = self._seconds, self._parts, self._cpu
         wait_lag = min(self._wait_lag, seconds["wait"])
-        handed, pauses = self._handed, self._pauses
+        handed, pauses, kv_writes = self._handed, self._pauses, self._kv_writes
         self._reset(now, cpu)
         self._phase = "admit"
         if not self._launches:
@@ -511,7 +520,7 @@ class DispatchPhases:
                *(seconds[p] for p in PHASES), wait_lag, compiled, chained,
                parts["deliver"], *handed.values(),
                *(parts[p] for p in _PARTS_APPENDED),
-               *cpus.values(), *pauses.values()]
+               *cpus.values(), *pauses.values(), *kv_writes.values()]
         self.serial += 1
         return row
 

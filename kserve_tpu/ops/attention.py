@@ -282,6 +282,37 @@ def _should_use_pallas(d: int, quantized: bool, table_width: int, batch: int,
     return min_pages is not None and table_width >= min_pages
 
 
+def _should_use_page_write(d: int, quantized: bool, latent: bool,
+                           backend: str, sharded: bool = False) -> bool:
+    """Whether a K/V write runs as the page kernel
+    (ops/pallas_kv_write.py) or as XLA's row scatter
+    (engine/kvcache._scatter_kv): the ONE predicate, from what a trace can
+    see.  The kernel on a TPU, over a plain cache of K and V planes whose
+    rows are whole 128-lane tiles.  The scatter for: an int8 cache (the
+    (pages, scales) tuple: Mosaic refuses the scale page's DMA, as it does
+    the ragged kernel's); latent pages (one row a token and layer: the
+    scatter's cost is the count of rows, and that is already one); other
+    head sizes; every other backend; and a cache sharded over a mesh
+    (`sharded`: tp, sp or pp > 1, where GSPMD partitions the scatter along
+    the heads and has no rule for the kernel; no four-chip cell times it)."""
+    return (backend == "tpu" and not quantized and not latent
+            and d % 128 == 0 and not sharded)
+
+
+def _kv_write_name(page_kernel: bool) -> str:
+    return "page_kernel" if page_kernel else "row_scatter"
+
+
+def kv_write_path(kv_pages, v, backend: Optional[str] = None) -> str:
+    """`page_kernel` or `row_scatter` for a write of (k, `v`) into
+    `kv_pages`: `_should_use_page_write` read off the arrays.  A caller
+    whose cache is sharded does not ask (engine/kvcache `page_kernel`)."""
+    quantized = isinstance(kv_pages, tuple)
+    d = (kv_pages[0] if quantized else kv_pages).shape[-1]
+    return _kv_write_name(_should_use_page_write(
+        d, quantized, v is None, backend or jax.default_backend()))
+
+
 def make_sharded_paged_attention(
     mesh,
     logit_softcap: float = 0.0,
@@ -565,10 +596,20 @@ def describe_attention_dispatch(model_config, engine_config,
     the legacy decode programs a logprobs/penalty lane falls back to).
     Its kernel is gated per compiled shape by `pallas_min_pages`:
     `decode_pallas_min_pages` is the width it starts at, None where it
-    runs at every width (or, with `decode: xla_gather`, at none)."""
+    runs at every width (or, with `decode: xla_gather`, at none).
+    `kv_write` says how each kind of cache the model has is written
+    (`_should_use_page_write`)."""
     mc, cfg = model_config, engine_config
     quantized = cfg.kv_quant == "int8"
     min_pages = None
+    # the write half, per kind of cache the model's layers write
+    # (models/llama.LayerSpec.writes): the kernel or the scatter
+    written = {row.writes for row in mc.layer_table()}
+    kv_write = {
+        kind: _kv_write_name(_should_use_page_write(
+            mc.cache_head_dim, quantized, kind == "latent", backend,
+            sharded=cfg.tp > 1 or cfg.sp > 1 or cfg.pp > 1))
+        for kind in ("paged", "window", "latent") if kind + "_kv" in written}
     if mc.is_latent:
         # models/latent.py: both reads of the latent pages are the kernels
         # on a TPU and the XLA references on a K/V view elsewhere
@@ -579,6 +620,7 @@ def describe_attention_dispatch(model_config, engine_config,
             "decode": "pallas_latent_decode" if pallas else "xla_gather",
             "decode_pallas_min_pages": None,
             "shard_map": False,
+            "kv_write": kv_write,
         }
     if mc.is_hybrid:
         # models/hybrid.py: every read of a cache is one query per lane (the
@@ -601,6 +643,7 @@ def describe_attention_dispatch(model_config, engine_config,
             "decode": "pallas_decode" if decode else "xla_gather",
             "decode_pallas_min_pages": min_pages,
             "shard_map": False,
+            "kv_write": kv_write,
         }
     if cfg.use_pallas is None:
         ragged = _should_use_ragged_pallas(mc.head_dim, backend, quantized)
@@ -625,6 +668,7 @@ def describe_attention_dispatch(model_config, engine_config,
         "decode_pallas_min_pages": min_pages,
         # tp/sp>1: both run per shard inside shard_map over the model axis
         "shard_map": cfg.tp > 1 or cfg.sp > 1,
+        "kv_write": kv_write,
     }
 
 

@@ -648,7 +648,8 @@ class TestDispatchPhases:
             "route": 0.5, "yield": 4.0, "wait_lag": 2.5, "compiled": 1,
             "chained": 0, "deliver": 0.0, "overlapped": 0, "inline": 0,
             **dict.fromkeys(PARTS, 0.0), **dict.fromkeys(CPU_COLUMNS, 0.0),
-            **dict.fromkeys(PAUSES, 0.0)}
+            **dict.fromkeys(PAUSES, 0.0), "kv_page_kernel": 0,
+            "kv_row_scatter": 0}
         assert sum(row[p] for p in PHASES) == clock.now()
         assert phases.serial == 2
         # an iteration that launched nothing is dropped, and so is idle time
@@ -1070,6 +1071,21 @@ class TestDispatchParts:
                 first["call"], first["register"]) == (1, 6.0, 2.0, 4.0, 0.0)
         assert (second["serial"], second["chained"], second["launch"],
                 second["upload"], second["register"]) == (2, 1, 0.0, 0.0, 0.5)
+
+    def test_kv_writes_go_to_the_row_of_the_iteration_that_launched(self):
+        clock = FakeClock()
+        phases = DispatchPhases(clock)
+        phases.mark("launch")
+        phases.launched("mixed", 16, 8, 0, 2)
+        phases.wrote("page_kernel", 36 * 8)
+        phases.wrote("row_scatter", 8)
+        phases.wrote("page_kernel", 4)
+        row = _row(phases.commit())
+        assert (row["kv_page_kernel"], row["kv_row_scatter"]) == (292, 8)
+        phases.mark("launch")
+        phases.launched("mixed", 16, 8, 0, 2)
+        row = _row(phases.commit())
+        assert (row["kv_page_kernel"], row["kv_row_scatter"]) == (0, 0)
 
     def test_a_pause_outside_an_iteration_is_nobody_s(self):
         clock = FakeClock()

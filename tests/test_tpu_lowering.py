@@ -173,6 +173,118 @@ class TestCacheKeepsKernelLayout:
         assert compiled.memory_analysis().temp_size_in_bytes < cache_bytes // 4
 
 
+def _compile_mixed(mc, cfg, cache_shape, width, monkeypatch,
+                   page_write=True):
+    """The `mixed` program of `mc` under `cfg` COMPILED for the described
+    v5e (abstract arguments placed on its first device), with the K/V page
+    write or, `page_write` False, as the program was before it: the row
+    scatter."""
+    from kserve_tpu.engine.compiled import program_defs
+    from kserve_tpu.engine.sampling import SamplingState
+    from kserve_tpu.models import llama
+    from kserve_tpu.parallel import sharding as shd
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    if not page_write:
+        monkeypatch.setattr(
+            att, "_should_use_page_write", lambda *a, **kw: False)
+    lanes, tokens = cfg.max_batch_size, cfg.prefill_buckets[-1]
+    device = _tpu_sharding().mesh.devices.ravel()[0]
+    fn, donate = program_defs(
+        mc, cfg, shd.create_mesh(devices=[device]))["mixed"]
+
+    def placed(tree):
+        return jax.tree.map(lambda x: _abstract(x.shape, x.dtype), tree)
+
+    return jax.jit(fn, donate_argnums=donate).lower(
+        placed(jax.eval_shape(
+            lambda: llama.init_params(mc, jax.random.PRNGKey(1)))),
+        _i32(tokens), _i32(tokens), _i32(tokens),  # q_tokens, seq, pos
+        _i32(lanes), _i32(lanes), _i32(lanes), _i32(lanes),
+        [_abstract(cache_shape, jnp.bfloat16)] * mc.n_layers,
+        _i32(lanes, width), _abstract((lanes,), jnp.bool_),  # joins
+        _i32(lanes), _i32(lanes), _i32(lanes), _i32(lanes), _i32(lanes),
+        placed(jax.eval_shape(lambda: SamplingState.defaults(lanes))),
+        _abstract((2,), jnp.uint32), _i32(lanes),
+    ).compile()
+
+
+def _two_layer_cell(name):
+    """(model, engine config, one layer's cache shape, table width) of a
+    cell of the benchmark: its lanes, tokens, pages and head shapes; two
+    layers and a narrow MLP, which only have to compile."""
+    import dataclasses
+
+    from kserve_tpu.engine.types import EngineConfig
+    from kserve_tpu.models import llama
+
+    if name == "qwen3-4b.decode-sat":
+        lanes, tokens, pages, width, passes = 48, 512, 2300, 40, 1
+        mc = dataclasses.replace(
+            llama.LlamaConfig.qwen3_0_6b(), n_layers=2, n_heads=32,
+            hidden_size=256, intermediate_size=512, vocab_size=1024,
+            dtype="bfloat16")
+    else:
+        lanes, tokens, pages, width, passes = 12, 256, 300, 24, 4
+        mc = dataclasses.replace(
+            llama.LlamaConfig.from_hf_config({
+                "model_type": "ouro", "vocab_size": 1024, "hidden_size": 256,
+                "intermediate_size": 512, "num_hidden_layers": 2,
+                "num_attention_heads": 16, "num_key_value_heads": 16,
+                "head_dim": 128, "rms_norm_eps": 1e-6, "rope_theta": 1000000,
+                "total_ut_steps": passes, "early_exit_threshold": 1}),
+            dtype="bfloat16")
+    cfg = EngineConfig(
+        max_batch_size=lanes, page_size=16, num_pages=pages,
+        max_pages_per_seq=width, max_prefill_len=tokens,
+        prefill_buckets=(128, tokens), dtype="bfloat16")
+    return mc, cfg, (passes * pages, 2, mc.n_kv_heads, 16, mc.head_dim), width
+
+
+class TestMixedWritesPagesInPlace:
+    """`mixed` with the K/V page write (ops/pallas_kv_write.py), compiled
+    for the described v5e at two cells' shapes: the kernel's aliasing holds
+    through both layers, the packed step, the decode steps' scan and a
+    looped model's passes."""
+
+    @pytest.mark.parametrize("cell", ["qwen3-4b.decode-sat",
+                                      "ouro-2.6b.eval-sat"])
+    def test_no_scatter_no_copy_no_more_temporaries(self, cell, monkeypatch):
+        import re
+
+        if _tpu_sharding() is None:
+            pytest.skip("no compile-only TPU topology in this installation")
+        mc, cfg, shape, width = _two_layer_cell(cell)
+        with monkeypatch.context() as before:
+            scattered = _compile_mixed(
+                mc, cfg, shape, width, before, page_write=False)
+        compiled = _compile_mixed(mc, cfg, shape, width, monkeypatch)
+        cache = "bf16\\[" + ",".join(map(str, shape)) + "\\]"
+
+        def count(program, op):
+            return len(re.findall(cache + r"\S* " + op + r"\(",
+                                  program.as_text()))
+
+        # the check sees the scatter it is there to miss: one a layer in
+        # the packed step's fusion, one in the decode steps'
+        assert count(scattered, "scatter") == 2
+        assert count(compiled, "scatter") == 0
+        # one call a layer and form (a looped model's passes are a loop
+        # around them), each aliased onto its operand
+        calls = re.findall(
+            r"custom-call\(.*custom_call_target=\"tpu_custom_call\".*"
+            r"kv_page_write", compiled.as_text())
+        assert len(calls) == 4
+        # no layer's cache is copied, and the program holds no more beside
+        # the cache than it did (the new rows, padded: well under 1 MB)
+        assert count(compiled, "copy") == count(scattered, "copy") == 0
+        was = scattered.memory_analysis().temp_size_in_bytes
+        now = compiled.memory_analysis().temp_size_in_bytes
+        assert now < was + (1 << 20), (was, now)
+        cache_bytes = int(np.prod(shape)) * 2 * mc.n_layers
+        assert compiled.memory_analysis().alias_size_in_bytes >= cache_bytes
+
+
 class TestDispatchReport:
     def _report(self, model_config, backend="tpu", **cfg):
         from kserve_tpu.engine.types import EngineConfig
@@ -550,9 +662,11 @@ class TestLoopedMixedProgram:
         one = self._lowered(monkeypatch, 1).as_text()
         kernels = re.findall(r'kernel_name = "([a-z_]+)"', looped)
         # 2 layers: traced once under each loop, whatever the passes
+        # (each layer's write is the page kernel, once in each form)
         assert sorted(kernels) == sorted(
             re.findall(r'kernel_name = "([a-z_]+)"', one)) == [
-                "paged_attention_decode"] * 2 + ["ragged_paged_attention"] * 2
+                "kv_page_write"] * 4 + ["paged_attention_decode"] * 2 + [
+                "ragged_paged_attention"] * 2
         # beside the sampler's two searches at each of its two sites:
         searches = 4
         assert looped.count("stablehlo.while") == 3 + searches  # passes, steps, passes
