@@ -577,11 +577,14 @@ def program_defs(model_config, engine_config, mesh, spec_k=None) -> dict:
             else:
                 out = sampled0[None]
             if expert_stats:
-                # two more rows behind the tokens', the sums in column 0:
-                # fetched with the tokens, no sync of their own
+                # a row behind the tokens' for each sum (two; four where the
+                # program counts its pairs: LlamaConfig.counts_routed_pairs),
+                # the sum in column 0: fetched with the tokens, no sync of
+                # their own
+                sums = kv_pages["stats"][0]
                 out = jnp.concatenate([out, jnp.broadcast_to(
-                    kv_pages["stats"][0][:, None].astype(out.dtype),
-                    (2, out.shape[1]))], axis=0)
+                    sums[:, None].astype(out.dtype),
+                    (sums.shape[0], out.shape[1]))], axis=0)
             return out, _kv_pin(kv_pages)
 
         return fn

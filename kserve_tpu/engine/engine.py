@@ -51,12 +51,17 @@ from ..metrics import (
     ENGINE_KV_WRITE_CALLS,
     ENGINE_MOE_ASSIGNMENTS,
     ENGINE_MOE_EXPERT_HITS,
+    ENGINE_MOE_EXPERTS_HELD,
+    ENGINE_MOE_PAIRS_ELSEWHERE,
     ENGINE_MOE_PEAK_LOAD,
     ENGINE_KV_PAGES_FREE,
     ENGINE_KV_PAGES_TOTAL,
     ENGINE_KV_TOKEN_BYTES,
     ENGINE_LAYER_PASSES,
     ENGINE_PREEMPTIONS,
+    ENGINE_SSD_SCAN_TOKENS,
+    ENGINE_SSD_UPDATE_CALLS,
+    ENGINE_SSD_UPDATE_LANE_STEPS,
     ENGINE_STATE_BYTES,
     ENGINE_STATE_RESETS,
     ENGINE_STATE_SLOTS_IN_USE,
@@ -218,7 +223,8 @@ def _refuse_latent(model_config, engine_config, role: str) -> None:
 def resolve_hybrid_serving(model_config, engine_config,
                            role: str = "both") -> None:
     """THE place that says what a model with recurrent or ring state
-    (models/hybrid.py) cannot do yet.  A lane's pages are no longer its
+    (models/hybrid.py: the Mamba-1 family with window rings and the
+    Mamba-2 family alike) cannot do yet.  A lane's pages are no longer its
     whole state, and that state cannot be rewound, shared or shipped, so:
     what was asked for explicitly is refused here, at start-up, by name;
     what was left at its default is resolved to off, with a log line.
@@ -259,8 +265,8 @@ def resolve_hybrid_serving(model_config, engine_config,
         refused.append(f"role={role} (the P/D wire ships pages only)")
     if refused:
         raise NotImplementedError(
-            "not supported yet for a model with mamba / window / shared-cache "
-            "layers: " + "; ".join(refused))
+            "not supported yet for a model with Mamba-1 / Mamba-2 / window / "
+            "shared-cache layers: " + "; ".join(refused))
     if cfg.prefix_cache is None:
         cfg.prefix_cache = False
         logger.info(
@@ -402,7 +408,27 @@ class LLMEngine:
         self._moe_hits = ENGINE_MOE_EXPERT_HITS.labels(
             model_name=metrics_label)
         self._moe_peak = ENGINE_MOE_PEAK_LOAD.labels(model_name=metrics_label)
+        self._moe_elsewhere = ENGINE_MOE_PAIRS_ELSEWHERE.labels(
+            model_name=metrics_label)
+        if model_config.has_expert_sums:
+            ENGINE_MOE_EXPERTS_HELD.labels(
+                model_name=metrics_label, of=str(model_config.n_experts)).set(
+                model_config.n_experts_held or model_config.n_experts)
+        # engine_ssd_*_total: what the Mamba-2 mixers' two forms are asked
+        # to do, counted at launch
+        self._ssd_layers = sum(
+            kind == "mamba2" for kind in model_config.mixer_kinds or ())
+        self._ssd_scan_tokens = ENGINE_SSD_SCAN_TOKENS.labels(
+            model_name=metrics_label)
+        self._ssd_update_calls = ENGINE_SSD_UPDATE_CALLS.labels(
+            model_name=metrics_label)
+        self._ssd_update_lane_steps = ENGINE_SSD_UPDATE_LANE_STEPS.labels(
+            model_name=metrics_label)
         self._expert_stats = model_config.has_expert_sums
+        # pairs counted on the host at launch (tokens x experts a token x
+        # expert layers) unless the program counts its own
+        self._host_counts_pairs = (model_config.n_experts > 0
+                                   and not model_config.counts_routed_pairs)
         # when the fetch worker last had a result on the host
         self._fetch_ready_at: Optional[float] = None
         # checkpoints carry this as model_name; resume_generation rejects a
@@ -2680,7 +2706,9 @@ class LLMEngine:
         (compiled._make_decode / _make_mixed), evaluated on the host.  The
         tokens that pass the model (`packed_tokens` in the packed step, one
         a live lane and decode step) are each routed to `n_experts_per_tok`
-        experts in every expert layer."""
+        experts in every expert layer, where every expert layer sees them
+        all and every expert is held; else the program counts its pairs
+        (LlamaConfig.counts_routed_pairs)."""
         mc = self.model_config
         self._layer_passes.inc(steps * mc.n_passes)
         for path, layers in self._kv_write_layers.items():
@@ -2695,7 +2723,15 @@ class LLMEngine:
                          np.clip(np.asarray(capacity) - pos, 0, decode_steps), 0)
             self._kv_context_tokens.inc(int(np.sum(n * pos + n * (n + 1) // 2)))
             tokens += int(np.sum(n))
-        if mc.n_experts > 0 and tokens:
+        if self._ssd_layers:
+            self._ssd_scan_tokens.inc(packed_tokens * self._ssd_layers)
+            self._ssd_update_calls.inc(decode_steps * self._ssd_layers)
+            self._ssd_update_lane_steps.inc(
+                (tokens - packed_tokens) * self._ssd_layers)
+        if self._host_counts_pairs and tokens:
+            # every expert is held and every expert layer sees every token:
+            # what is routed is multiplied.  Else the counts are the
+            # program's own and come back with the dispatch's tokens
             self._moe_assignments.inc(
                 tokens * mc.n_experts_per_tok * mc.n_expert_layers)
 
@@ -3737,9 +3773,16 @@ class LLMEngine:
         chunk_np = await self._fetch_async(out, self._deliver_overlapped)
         phases.resumed(self._fetch_ready_at)
         if self._expert_stats:
-            chunk_np, (hits, peak) = chunk_np[:-2], chunk_np[-2:, 0]
-            self._moe_hits.inc(int(hits))
-            self._moe_peak.inc(int(peak))
+            n = self.state_layout.expert_sums
+            chunk_np, sums = chunk_np[:-n], chunk_np[-n:, 0]
+            self._moe_hits.inc(int(sums[0]))
+            self._moe_peak.inc(int(sums[1]))
+            if n > 2:
+                # the program counted the pairs it multiplied and the pairs
+                # its expert layers routed, over the rows each layer saw;
+                # the rest went to experts held elsewhere
+                self._moe_assignments.inc(int(sums[2]))
+                self._moe_elsewhere.inc(int(sums[3] - sums[2]))
         self._route_mixed(plan, chunk_np, dispatched_at)
         return True
 

@@ -106,7 +106,10 @@ class StateLayout:
       (lane b holds ring pages 1 + b * ring_width ..; page 0 is the null
       page), so it never grows;
     - `ssm` and `conv`: for every layer that writes `recurrent` one slot
-      per lane: the scan's float32 state and the convolution's tail;
+      per lane, sized by the mixer: the scan's float32 state (Mamba-1:
+      [d_inner, d_state]; Mamba-2: a matrix a head, [heads, head_dim,
+      d_state]) and the convolution's tail ([d_conv - 1, columns the
+      convolution runs over]: d_inner, or x, B and C together);
     - `latent`: for every layer that writes `latent_kv` (latent attention,
       models/latent.py) pages of the SAME pool and page table, holding ONE
       row a token and no K/V planes or heads: `latent_width` values (the
@@ -140,6 +143,16 @@ class StateLayout:
     latent_layers: tuple = ()
     latent_width: int = 0  # values a latent row holds
     expert_layers: int = 0
+    expert_sums: int = 2  # int32 sums an expert layer adds to `stats`
+    # a recurrent slot's shapes, by the mixer; () / 0 = Mamba-1's
+    ssm_shape: tuple = ()
+    conv_width: int = 0
+
+    def __post_init__(self):
+        if not self.ssm_shape:
+            object.__setattr__(self, "ssm_shape", (self.d_inner, self.d_state))
+        if not self.conv_width:
+            object.__setattr__(self, "conv_width", self.d_inner)
 
     @classmethod
     def of(cls, model_config, page_size: int, num_pages: int, lanes: int,
@@ -148,6 +161,8 @@ class StateLayout:
 
         def rows(writes):
             return tuple(i for i, r in enumerate(table) if r.writes == writes)
+
+        mamba2 = model_config.mamba_n_heads > 0
 
         return cls(
             paged_layers=rows("paged_kv"), window_layers=rows("window_kv"),
@@ -162,7 +177,12 @@ class StateLayout:
             n_passes=model_config.n_passes,
             latent_layers=rows("latent_kv"),
             latent_width=model_config.latent_width,
-            expert_layers=sum(r.ffn == "experts" for r in table))
+            expert_layers=sum(r.ffn == "experts" for r in table),
+            expert_sums=4 if model_config.counts_routed_pairs else 2,
+            # Mamba-2 where the model has its heads; else __post_init__'s
+            ssm_shape=(model_config.mamba_n_heads, model_config.mamba_head_dim,
+                       model_config.mamba_d_state) if mamba2 else (),
+            conv_width=model_config.mamba2_conv_dim if mamba2 else 0)
 
     @property
     def _itemsize(self) -> int:
@@ -214,8 +234,8 @@ class StateLayout:
         return {
             "window_kv": len(self.window_layers) * self.window * 2
             * self.kv_heads * self.head_dim * self._itemsize,
-            "ssm": n * self.d_inner * self.d_state * 4,
-            "conv": n * max(self.d_conv - 1, 0) * self.d_inner * self._itemsize,
+            "ssm": n * math.prod(self.ssm_shape) * 4,
+            "conv": n * max(self.d_conv - 1, 0) * self.conv_width * self._itemsize,
         }
 
     def bytes_in_use(self, lanes_seated: int, pages_held: int) -> dict:
@@ -244,14 +264,14 @@ class StateLayout:
                 (self.num_pages, 1, 1, self.page_size, self.latent_row),
                 dtype, len(self.latent_layers))
         if self.expert_layers:
-            extra["stats"] = fill((2,), jnp.int32, 1)
+            extra["stats"] = fill((self.expert_sums,), jnp.int32, 1)
         return {
             **extra,
             "paged": fill((self.num_pages,) + page, dtype, len(self.paged_layers)),
             "window": fill((1 + self.lanes * self.ring_width,) + ring, dtype,
                            len(self.window_layers)),
-            "ssm": fill((self.lanes, self.d_inner, self.d_state), jnp.float32, n),
-            "conv": fill((self.lanes, max(self.d_conv - 1, 0), self.d_inner),
+            "ssm": fill((self.lanes,) + self.ssm_shape, jnp.float32, n),
+            "conv": fill((self.lanes, max(self.d_conv - 1, 0), self.conv_width),
                          dtype, n),
         }
 

@@ -437,10 +437,46 @@ ENGINE_KV_CONTEXT_TOKENS = Counter(
 # Expert layers (models/moe.py).  Assignments are counted at launch from the
 # dispatch's tokens; hits and peak load are summed IN the program over its
 # forward steps and expert layers and come back with the dispatch's tokens.
+# Where the chip holds a share of the experts all three count what THIS
+# chip multiplied (the assignments then come back with the tokens too, as
+# do the pairs routed), and a fourth counts the pairs routed to experts
+# held elsewhere.
 ENGINE_MOE_ASSIGNMENTS = Counter(
     "engine_moe_assignments_total",
-    "(token, expert) pairs the routed experts multiplied: forward tokens x "
-    "experts a token x expert layers",
+    "(token, expert) pairs the routed experts held here multiplied: forward "
+    "tokens x experts a token x expert layers where every expert is held",
+    ["model_name"],
+)
+ENGINE_MOE_PAIRS_ELSEWHERE = Counter(
+    "engine_moe_pairs_elsewhere_total",
+    "(token, expert) pairs routed to experts this chip does not hold: the "
+    "pairs the program's expert layers routed over the rows each saw, less "
+    "those it multiplied; given to no group, multiplied by nobody here; 0 "
+    "where every expert is held",
+    ["model_name"],
+)
+ENGINE_MOE_EXPERTS_HELD = Gauge(
+    "engine_moe_experts_held",
+    "experts of an expert layer held on this chip, of the `scored` the "
+    "router chooses among",
+    ["model_name", "of"],
+)
+# Mamba-2 mixers (ops/ssm.ssd_*): what the two forms were asked to do,
+# counted at launch from the dispatch's plan.
+ENGINE_SSD_SCAN_TOKENS = Counter(
+    "engine_ssd_scan_tokens_total",
+    "tokens the packed step's chunked scan took (real tokens of the packed "
+    "buffer x Mamba-2 layers)",
+    ["model_name"],
+)
+ENGINE_SSD_UPDATE_CALLS = Counter(
+    "engine_ssd_update_calls_total",
+    "one-step state updates launched: decode steps x Mamba-2 layers",
+    ["model_name"],
+)
+ENGINE_SSD_UPDATE_LANE_STEPS = Counter(
+    "engine_ssd_update_lane_steps_total",
+    "live lanes summed over those updates: lane-steps whose state moved on",
     ["model_name"],
 )
 ENGINE_MOE_EXPERT_HITS = Counter(

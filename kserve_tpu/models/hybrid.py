@@ -37,6 +37,17 @@ the absorbed form by both programs) and a feed-forward that is `dense` or
 shared expert).  Its norms carry no bias and its head is untied: `_ln`,
 `_ffn` and `_logits` choose by `config.norm_type` and by the row.
 
+Third family: `model_type: nemotron_h`: ONE sublayer a layer, `h +=
+Mixer(RMSNorm(h))` with one norm and one residual, the mixer by the row:
+`mamba2` (a Mamba-2 mixer: one convolution over x, B and C, a matrix-valued
+state a head in `state["ssm"]`, a gated group-wise RMSNorm behind the scan;
+ops/ssm.ssd_*), `gqa_attention` (plain grouped-query attention with no
+positional encoding, over the pool's pages through the kernels the Llama
+path uses) or `ffn` (the row's mixer IS its
+feed-forward: routed experts of the ungated `relu2` form, of which this
+chip may hold a share, beside a shared one).  A row's `ffn` is `none`
+where its mixer is not the feed-forward; `_close` adds what the row has.
+
 Layers behind the last layer that writes state only feed the logits, so the
 packed forward runs them (and the last writer's own attention output) on
 the rows that are sampled, one per lane: exact, and it makes every read of
@@ -59,12 +70,14 @@ from ..ops import ssm
 from ..ops.attention import (
     latent_paged_attention,
     latent_ragged_attention,
+    paged_attention,
     paged_attention_scaled,
+    ragged_paged_attention,
     ring_window_attention_ragged,
 )
 from ..ops.norms import layer_norm, rms_norm
 from . import latent, llama
-from .moe import moe_config_of, moe_mlp, moe_param_shapes
+from .moe import moe_config_of, moe_mlp, moe_param_shapes, zero_stored_padding
 from .quant import dense, tied_head_matmul
 
 Params = Dict[str, Any]
@@ -77,27 +90,43 @@ def layer_param_shapes(config, spec) -> Dict[str, tuple]:
     """{name: (shape, init)} of one layer, from its row of the table.
     `init`: "normal" (N(0, scale)), "ones", "bias" (N(0, scale)),
     "lambda" (N(0, 0.1), arXiv:2410.05258), "A_log", "dt_bias", "D" (the
-    Mamba-1 defaults), "zeros" (a router's choice-only bias); float32 for
-    those four; "routed_out" (N(0, scale x ROUTED_OUT_GAIN): a routed
-    expert's down-projection)."""
+    Mamba-1 defaults), "A_log_heads" (Mamba-2: one A a head, spread over
+    [1, 16]), "zeros" (a router's choice-only bias); float32 for those;
+    "routed_out" (N(0, scale x ROUTED_OUT_GAIN): a routed expert's
+    down-projection).  A row has the norm of each sublayer it has."""
     h, f = config.hidden_size, config.intermediate_size
     nq, nkv, hd = config.n_heads, config.n_kv_heads, config.head_dim
     di, n, k, r = (config.mamba_d_inner, config.mamba_d_state,
                    config.mamba_d_conv, config.mamba_dt_rank)
-    shapes = {"attn_norm": ((h,), "ones"), "mlp_norm": ((h,), "ones")}
+    norms = (["attn_norm"] if spec.kind != "ffn" else []) + (
+        ["mlp_norm"] if spec.ffn != "none" else [])
+    shapes = {name: ((h,), "ones") for name in norms}
     if config.norm_type == "layernorm":
-        shapes.update({"attn_norm_b": ((h,), "bias"),
-                       "mlp_norm_b": ((h,), "bias")})
+        shapes.update({name + "_b": ((h,), "bias") for name in norms})
     if spec.ffn == "experts":
         inits = {"router_bias": "zeros", "w_down": "routed_out"}
         shapes.update({
             name: (shape, inits.get(name, "normal"))
             for name, shape in moe_param_shapes(moe_config_of(config)).items()})
-    else:
+    elif spec.ffn == "dense":
         shapes.update({"w_gate": ((h, f), "normal"), "w_up": ((h, f), "normal"),
                        "w_down": ((f, h), "normal")})
     if spec.kind == "latent_attention":
         shapes.update(latent.param_shapes(config))
+    elif spec.kind == "gqa_attention":
+        shapes.update({
+            "wq": ((h, nq * hd), "normal"), "wk": ((h, nkv * hd), "normal"),
+            "wv": ((h, nkv * hd), "normal"), "wo": ((nq * hd, h), "normal")})
+    elif spec.kind == "mamba2":
+        heads, conv = config.mamba_n_heads, config.mamba2_conv_dim
+        shapes.update({
+            "in_proj": ((h, di + conv + heads), "normal"),
+            "conv_w": ((k, conv), "normal"), "conv_b": ((conv,), "bias"),
+            "dt_bias": ((heads,), "dt_bias"),
+            "A_log": ((heads,), "A_log_heads"), "D": ((heads,), "D"),
+            "ssm_norm": ((di,), "ones"),
+            "out_proj": ((di, h), "normal"),
+        })
     elif spec.kind in ("attention", "window_attention", "cross_attention"):
         shapes.update({
             "wq": ((h, nq * hd), "normal"), "wo": ((nq * hd, h), "normal"),
@@ -172,6 +201,8 @@ def init_params(config, rng, scale: float = 0.02, weight_quant: str = "none",
         if init == "A_log":
             return jnp.log(jnp.broadcast_to(
                 jnp.arange(1, shape[1] + 1, dtype=jnp.float32), shape))
+        if init == "A_log_heads":
+            return jnp.log(jnp.linspace(1.0, 16.0, shape[0], dtype=jnp.float32))
         if init == "D":
             return jnp.ones(shape, jnp.float32)
         if init == "dt_bias":
@@ -184,8 +215,11 @@ def init_params(config, rng, scale: float = 0.02, weight_quant: str = "none",
     def make_layer(spec, key):
         shapes = layer_param_shapes(config, spec)
         ks = jax.random.split(key, len(shapes))
-        return {name: make(shape, init, k)
-                for (name, (shape, init)), k in zip(sorted(shapes.items()), ks)}
+        layer = {name: make(shape, init, k)
+                 for (name, (shape, init)), k in zip(sorted(shapes.items()), ks)}
+        if spec.ffn == "experts":
+            layer = zero_stored_padding(layer, moe_config_of(config))
+        return layer
 
     def make_top(key):
         k = jax.random.split(key, 2)
@@ -223,20 +257,33 @@ def _ln(x, layer, name, config):
 
 def _ffn(layer, spec, x, valid, state, config):
     """The row's feed-forward over x [N, h].  An `experts` row multiplies
-    only the pairs routed among the `valid` rows and adds two sums to
-    `state["stats"]`: experts that got at least one row, and the fullest
-    expert's rows (engine_moe_expert_hits_total / _peak_load_total)."""
+    only the pairs routed among the `valid` rows to the experts held here
+    and adds its sums to `state["stats"]`: experts that got at least one
+    row, the fullest expert's rows (engine_moe_expert_hits_total /
+    _peak_load_total) and, where the host cannot know them
+    (`config.counts_routed_pairs`), the pairs it multiplied and the pairs
+    this layer routed, over the rows it SAW (a layer behind the last
+    writer sees the sampled rows only): engine_moe_assignments_total and,
+    by their difference, engine_moe_pairs_elsewhere_total."""
     if spec.ffn != "experts":
         return llama._mlp(layer, x, config)
     out, rows = moe_mlp(layer, x, moe_config_of(config), valid, with_rows=True)
-    state["stats"][0] = state["stats"][0] + jnp.stack(
-        [jnp.sum(rows > 0, dtype=jnp.int32), jnp.max(rows)])
+    sums = [jnp.sum(rows > 0, dtype=jnp.int32), jnp.max(rows)]
+    if config.counts_routed_pairs:
+        sums += [jnp.sum(rows), jnp.sum(valid, dtype=jnp.int32)
+                 * config.n_experts_per_tok]
+    state["stats"][0] = state["stats"][0] + jnp.stack(sums)
     return out
 
 
 def _close(layer, spec, x, mixed, valid, state, config):
-    """Residual around the mixer's output, then the feed-forward's."""
-    x = x + mixed
+    """Residual around the mixer's output, then the feed-forward's: each
+    where the row has it (`mixed` None: the row's mixer is its
+    feed-forward)."""
+    if mixed is not None:
+        x = x + mixed
+    if spec.ffn == "none":
+        return x
     return x + _ffn(layer, spec, _ln(x, layer, "mlp_norm", config), valid,
                     state, config)
 
@@ -259,7 +306,8 @@ def _queries(layer, u, config):
 
 
 def _keys_values(layer, u, config):
-    """[N, h] -> K, V [N, pairs, 2 x head_dim]: a pair's heads side by side."""
+    """[N, h] -> K, V [N, K/V heads, head_dim]; differential attention:
+    [N, pairs, 2 x head_dim], a pair's heads side by side."""
     k, v = dense(u, layer["wk"]), dense(u, layer["wv"])
     if config.attention_bias:
         k, v = k + layer["bk"], v + layer["bv"]
@@ -285,6 +333,17 @@ def _differential_out(layer, attn, config, layer_index: int):
     if config.attention_out_bias:
         out = out + layer["bo"]
     return out
+
+
+def _gqa_queries(layer, u, config):
+    """A `gqa_attention` row's queries: [N, h] -> [N, heads, head_dim]."""
+    return dense(u, layer["wq"]).reshape(
+        u.shape[0], config.n_heads, config.head_dim)
+
+
+def _gqa_out(layer, attn):
+    """A `gqa_attention` row's output: attn [N, heads, head_dim] -> [N, h]."""
+    return dense(attn.reshape(attn.shape[0], -1), layer["wo"])
 
 
 def _scale(config) -> float:
@@ -314,6 +373,43 @@ def _mamba_out(layer, y, z):
     a gated memory unit reuses, y before the gate)."""
     gated = y * jax.nn.silu(z.astype(jnp.float32))
     return dense(gated.astype(z.dtype), layer["out_proj"]), y.astype(z.dtype)
+
+
+def _mamba2_project(layer, u, config):
+    """[N, h] -> (z [N, Di], xBC [N, Di + 2 G N], dt [N, H]) of `in_proj`."""
+    di, conv = config.mamba_d_inner, config.mamba2_conv_dim
+    zxbcdt = dense(u, layer["in_proj"])
+    return zxbcdt[:, :di], zxbcdt[:, di:di + conv], zxbcdt[:, di + conv:]
+
+
+def _mamba2_scan_inputs(layer, conv_out, dt, config):
+    """The convolution's output (float32, before its activation) and the
+    projected dt -> what the scan takes: x [N, H, P], dt [N, H] float32,
+    A [H], B, C [N, G, N_state]."""
+    f32 = jnp.float32
+    N = conv_out.shape[0]
+    H, P = config.mamba_n_heads, config.mamba_head_dim
+    G, n = config.mamba_n_groups, config.mamba_d_state
+    xbc = jax.nn.silu(conv_out)
+    x = xbc[:, :H * P].reshape(N, H, P)
+    Bm = xbc[:, H * P:H * P + G * n].reshape(N, G, n)
+    Cm = xbc[:, H * P + G * n:].reshape(N, G, n)
+    dt = jax.nn.softplus(dt.astype(f32) + layer["dt_bias"])
+    return x, dt, -jnp.exp(layer["A_log"].astype(f32)), Bm, Cm
+
+
+def _mamba2_out(layer, y, z, config):
+    """The scan's output y [N, H, P] float32 -> the mixer's output: the
+    gate first, then an RMSNorm over each of the G groups of columns by
+    itself, then `out_proj`."""
+    with jax.named_scope("ssd_gated_norm"):
+        N, G = y.shape[0], config.mamba_n_groups
+        gated = (y.reshape(N, -1) * jax.nn.silu(z.astype(jnp.float32))
+                 ).reshape(N, G, -1)
+        var = jnp.mean(gated * gated, axis=-1, keepdims=True)
+        normed = (gated * jax.lax.rsqrt(var + config.rms_norm_eps)
+                  ).reshape(N, -1) * layer["ssm_norm"].astype(jnp.float32)
+    return dense(normed.astype(z.dtype), layer["out_proj"])
 
 
 def _gmu(layer, u, m):
@@ -361,9 +457,37 @@ def _rows_layer(layer, spec, i, x, pos, live, state, slots, page_table,
     """One layer over one row per lane (x [B, h] at positions pos [B]).
     `write` False: the row's own K/V are already in the cache (the packed
     forward wrote them for every token)."""
+    if spec.kind == "ffn":
+        return _close(layer, spec, x, None, live, state, config)
     u = _ln(x, layer, "attn_norm", config)
     seq_lens = jnp.where(live, pos + 1, 0)
-    if spec.kind == "mamba":
+    if spec.kind == "mamba2":
+        with jax.named_scope("ssd"):
+            j = slots[i]
+            z, xbc, dt = _mamba2_project(layer, u, config)
+            with jax.named_scope("ssd_conv"):
+                conv_out, tail = ssm.causal_conv_step(
+                    xbc, state["conv"][j], layer["conv_w"], layer["conv_b"])
+            xs, dt, A, Bm, Cm = _mamba2_scan_inputs(layer, conv_out, dt, config)
+            with jax.named_scope("ssd_update"):
+                y, s = ssm.ssd_step(
+                    xs, dt, A, Bm, Cm, layer["D"], state["ssm"][j], live)
+            state["ssm"][j] = s
+            state["conv"][j] = jnp.where(
+                live[:, None, None], tail, state["conv"][j])
+            mixed = _mamba2_out(layer, y, z, config)
+    elif spec.kind == "gqa_attention":
+        with jax.named_scope("attention"):
+            j = slots[i]
+            if write:
+                k, v = _keys_values(layer, u, config)
+                state["paged"][j] = append_token_kv(
+                    state["paged"][j], k, v, page_table, pos, live, page_size)
+            attn = paged_attention(
+                _gqa_queries(layer, u, config), state["paged"][j], page_table,
+                seq_lens, use_pallas=use_pallas)
+            mixed = _gqa_out(layer, attn)
+    elif spec.kind == "mamba":
         with jax.named_scope("ssm"):
             j = slots[i]
             xin, z = _mamba_project(layer, u, config)
@@ -491,8 +615,42 @@ def forward_ragged(params, config, tokens, token_seq, token_pos, q_start,
                             slots, page_table, page_size, ring_table, handed,
                             config, use_pallas)
             continue
+        if spec.kind == "ffn":  # the row's mixer is its feed-forward
+            x = _close(layer, spec, x, None, token_seq >= 0, state, config)
+            continue
         u = _ln(x, layer, "attn_norm", config)
-        if spec.kind == "mamba":
+        if spec.kind == "mamba2":
+            with jax.named_scope("ssd"):
+                j = slots[i]
+                z, xbc, dt = _mamba2_project(layer, u, config)
+                with jax.named_scope("ssd_conv"):
+                    conv_out, tail = ssm.causal_conv_ragged(
+                        xbc, state["conv"][j], layer["conv_w"],
+                        layer["conv_b"], token_seq, token_off, q_start, q_len,
+                        fresh)
+                xs, dt, A, Bm, Cm = _mamba2_scan_inputs(
+                    layer, conv_out, dt, config)
+                with jax.named_scope("ssd_chunk_scan"):
+                    y, s = ssm.ssd_ragged(
+                        xs, dt, A, Bm, Cm, layer["D"], state["ssm"][j],
+                        token_seq, q_start, q_len, last_idx, fresh)
+                state["ssm"][j] = s
+                state["conv"][j] = jnp.where(
+                    has_slice[:, None, None], tail, state["conv"][j])
+                mixed = _mamba2_out(layer, y, z, config)
+        elif spec.kind == "gqa_attention" and i != last_writer:
+            with jax.named_scope("attention"):
+                j = slots[i]
+                k, v = _keys_values(layer, u, config)
+                state["paged"][j] = write_ragged_kv(
+                    state["paged"][j], k, v, page_table, token_seq, token_pos,
+                    page_size, runs=slice_runs(q_start, q_len, kv_start))
+                attn = ragged_paged_attention(
+                    _gqa_queries(layer, u, config), state["paged"][j],
+                    page_table, q_start, q_len, kv_start,
+                    use_pallas=use_pallas)
+                mixed = _gqa_out(layer, attn)
+        elif spec.kind == "mamba":
             with jax.named_scope("ssm"):
                 j = slots[i]
                 xin, z = _mamba_project(layer, u, config)
@@ -538,7 +696,7 @@ def forward_ragged(params, config, tokens, token_seq, token_pos, q_start,
                     jnp.where(kept, token_seq, -1), token_pos % R, ps,
                     runs=_ring_runs(q_start, q_len, kv_start, R))
                 mixed = _differential_out(layer, attn, config, i)
-        elif spec.kind == "attention" and i == last_writer:
+        elif spec.kind in ("attention", "gqa_attention") and i == last_writer:
             # K/V of every token go to the pool; the attention's own output
             # feeds only layers behind it, so it is taken at the sampled rows
             with jax.named_scope("shared_kv_attention"):

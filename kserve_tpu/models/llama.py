@@ -66,12 +66,17 @@ def _map_hidden_act(act) -> str:
     raise ValueError(f"unsupported hidden_act {act!r}")
 
 
-#: what a layer's mixer can be, and what state it writes
+#: what a layer's mixer can be, and what state it writes.  In a hybrid
+#: table (models/hybrid.py) `attention`, `window_attention` and
+#: `cross_attention` are the first hybrid family's differential attention
+#: (`LlamaConfig.diff_attention`: a pair of heads a cache row) and
+#: `gqa_attention` is plain grouped-query attention over the pool's pages
 MIXER_KINDS = ("attention", "window_attention", "cross_attention", "mamba",
-               "gmu", "latent_attention")
+               "gmu", "latent_attention", "mamba2", "ffn", "gqa_attention")
 _WRITES = {"attention": "paged_kv", "window_attention": "window_kv",
            "cross_attention": "none", "mamba": "recurrent", "gmu": "none",
-           "latent_attention": "latent_kv"}
+           "latent_attention": "latent_kv", "mamba2": "recurrent",
+           "ffn": "none", "gqa_attention": "paged_kv"}
 
 #: model_type values LlamaConfig's family knobs describe
 _LLAMA_MODEL_TYPES = (None, "llama", "mistral", "mixtral", "qwen2", "qwen3",
@@ -97,8 +102,11 @@ class LayerSpec:
     (models/hybrid.py), the cache manager (engine/kvcache.StateLayout),
     parallel/sharding.param_pspecs, the weight initialiser and the
     checkpoint loader all derive from these rows.  `ffn` is the layer's
-    feed-forward: `dense` (one gated MLP) or `experts` (a router over
-    routed experts, models/moe.py)."""
+    feed-forward: `dense` (one gated MLP), `experts` (a router over routed
+    experts, models/moe.py) or `none`.  A model of ONE sublayer a layer
+    (`LlamaConfig.one_sublayer`: `h += Mixer(norm(h))`, one norm, one
+    residual) has rows whose feed-forward is `none` and rows of kind `ffn`,
+    whose mixer IS the feed-forward."""
 
     kind: str
     writes: str
@@ -178,6 +186,13 @@ class LlamaConfig:
     mamba_d_state: int = 0
     mamba_d_conv: int = 0
     mamba_dt_rank: int = 0
+    # Mamba-2 sizes (kind "mamba2"; d_inner = heads x head_dim, d_state and
+    # d_conv as above): B and C are shared by groups of heads
+    mamba_n_heads: int = 0
+    mamba_head_dim: int = 0
+    mamba_n_groups: int = 0
+    # every layer is one sublayer (Nemotron-H): see LayerSpec
+    one_sublayer: bool = False
     # ---- latent attention (models/latent.py): queries through a rank
     # q_lora_rank bottleneck, keys and values through ONE compressed row of
     # kv_lora_rank values plus qk_rope_head_dim roped ones a token, which is
@@ -194,6 +209,12 @@ class LlamaConfig:
     moe_router: str = "softmax"  # "sigmoid": scores sigmoid, a bias chooses
     routed_scaling_factor: float = 1.0
     norm_topk_prob: bool = True
+    moe_form: str = "gated"  # "relu2": down(relu(up x)^2), no gate matrix
+    moe_shared_intermediate_size: int = 0  # 0 = an expert's width
+    # this chip's share of the n_experts the router scores: experts
+    # first_expert .. first_expert + n_experts_held - 1; 0 held = all
+    n_experts_held: int = 0
+    first_expert: int = 0
     # ---- looped models (arXiv:2510.25741): the stack runs n_passes times
     # a token over ONE set of weights, the final norm closes every pass and
     # every (pass, layer) keeps K/V rows of its own ----
@@ -221,6 +242,10 @@ class LlamaConfig:
                 raise ValueError(
                     f"mixer_kinds: {self.n_layers} entries of {MIXER_KINDS} "
                     f"expected, got {len(self.mixer_kinds)} with {unknown}")
+            if self.diff_attention and "gqa_attention" in self.mixer_kinds:
+                raise ValueError(
+                    "gqa_attention rows in a model whose cache rows hold "
+                    "differential pairs (diff_attention)")
 
     @property
     def is_hybrid(self) -> bool:
@@ -237,9 +262,17 @@ class LlamaConfig:
         return self.kv_lora_rank + self.qk_rope_head_dim
 
     def ffn_kind(self, i: int) -> str:
-        """Layer i's feed-forward: `dense` or `experts`."""
+        """Layer i's feed-forward: `dense`, `experts` or `none`."""
+        if self.one_sublayer and self.mixer_kinds[i] != "ffn":
+            return "none"
         return ("experts" if self.n_experts > 0 and i >= self.first_k_dense
                 else "dense")
+
+    @property
+    def mamba2_conv_dim(self) -> int:
+        """Columns a Mamba-2 mixer's convolution runs over: x, B and C."""
+        return (self.mamba_n_heads * self.mamba_head_dim
+                + 2 * self.mamba_n_groups * self.mamba_d_state)
 
     @property
     def n_expert_layers(self) -> int:
@@ -251,6 +284,25 @@ class LlamaConfig:
         (models/hybrid._ffn adds to `state["stats"]`; the `mixed` program
         returns them with its tokens)."""
         return self.is_hybrid and self.n_expert_layers > 0
+
+    @property
+    def counts_routed_pairs(self) -> bool:
+        """Whether the program itself counts the pairs its expert layers
+        routed and multiplied (two more sums).  It need not where every
+        expert is held and every expert layer sees every token, since the
+        host then knows both as tokens x experts a token x layers.  It must
+        where the chip holds a share of the experts (which pairs fall on it
+        is the router's doing), or where an expert layer lies behind the
+        last layer that writes state: the packed forward runs such a layer
+        on the sampled rows only (models/hybrid.forward_ragged)."""
+        if not self.has_expert_sums:
+            return False
+        table = self.layer_table()
+        last_writer = max(
+            (i for i, row in enumerate(table) if row.writes != "none"),
+            default=-1)
+        return self.n_experts_held > 0 or any(
+            row.ffn == "experts" for row in table[last_writer + 1:])
 
     def layer_table(self) -> Tuple[LayerSpec, ...]:
         """The per-layer table.  A Llama-family model is n_layers equal
@@ -405,6 +457,8 @@ class LlamaConfig:
             return _phi4flash_config(cfg)
         if model_type == "glm4_moe_lite":
             return _glm4_moe_lite_config(cfg)
+        if model_type == "nemotron_h":
+            return _nemotron_h_config(cfg)
         if model_type not in _LLAMA_MODEL_TYPES:
             foreign = [k for k in _FOREIGN_MIXER_KEYS if k in cfg
                        and not (k == "total_ut_steps" and int(cfg[k]) <= 1)]
@@ -576,6 +630,92 @@ def _glm4_moe_lite_config(cfg: dict) -> LlamaConfig:
         n_shared_experts=int(cfg.get("n_shared_experts", 0)),
         first_k_dense=int(cfg.get("first_k_dense_replace", 0)),
         moe_router="sigmoid",
+        routed_scaling_factor=float(cfg.get("routed_scaling_factor", 1.0)),
+        norm_topk_prob=bool(cfg.get("norm_topk_prob", True)),
+    )
+
+
+#: `hybrid_override_pattern` letter -> the row's mixer kind
+_NEMOTRON_H_LETTERS = {"M": "mamba2", "E": "ffn", "*": "gqa_attention"}
+
+
+def _nemotron_h_config(cfg: dict) -> LlamaConfig:
+    """config.json of `model_type: nemotron_h`: ONE sublayer a layer, by
+    the letter of `hybrid_override_pattern`: `M` a Mamba-2 mixer, `E`
+    routed experts (sigmoid router, a choice-only bias, ungated relu^2
+    experts and a shared one), `*` plain grouped-query attention with no
+    positional encoding.  What the published file leaves to the modeling
+    file is listed under `assumed` in benchmark/configs/nemotron3-nano.json.
+    A deployment that holds a chip's share of the experts says so with two
+    keys of its own: `n_routed_experts` counts the experts HELD here,
+    `router_n_experts` the experts the router scores (the published
+    count), `first_expert` the first one held."""
+    pattern = cfg["hybrid_override_pattern"]
+    n_layers = cfg["num_hidden_layers"]
+    refused = []
+    unknown = sorted(set(pattern) - set(_NEMOTRON_H_LETTERS))
+    if unknown:
+        refused.append(
+            f"hybrid_override_pattern letters {unknown} (built: M Mamba-2, "
+            "E experts, * attention; '-' is the family's dense MLP)")
+    if len(pattern) != n_layers:
+        refused.append(f"hybrid_override_pattern of {len(pattern)} letters "
+                       f"for num_hidden_layers={n_layers}")
+    if int(cfg.get("n_group", 1)) != 1 or int(cfg.get("topk_group", 1)) != 1:
+        refused.append(f"n_group={cfg.get('n_group')} / topk_group="
+                       f"{cfg.get('topk_group')} (group-limited routing)")
+    for key in ("mamba_proj_bias", "mlp_bias", "attention_bias", "use_bias"):
+        if cfg.get(key):
+            refused.append(f"{key} true")
+    if cfg.get("sliding_window"):
+        refused.append(f"sliding_window={cfg['sliding_window']}")
+    if cfg.get("mlp_hidden_act", "relu2") != "relu2":
+        refused.append(f"mlp_hidden_act={cfg['mlp_hidden_act']!r}")
+    if cfg.get("mamba_hidden_act", "silu") != "silu":
+        refused.append(f"mamba_hidden_act={cfg['mamba_hidden_act']!r}")
+    if int(cfg.get("n_shared_experts", 1)) > 1:
+        refused.append(f"n_shared_experts={cfg['n_shared_experts']}")
+    if cfg.get("residual_in_fp32"):
+        refused.append("residual_in_fp32")
+    heads, groups = cfg["mamba_num_heads"], cfg["n_groups"]
+    if heads % groups:
+        refused.append(f"mamba_num_heads={heads} not a multiple of "
+                       f"n_groups={groups}")
+    if refused:
+        raise ValueError("nemotron_h: not implemented: " + "; ".join(refused))
+    held = cfg["n_routed_experts"]
+    scored = int(cfg.get("router_n_experts", held))
+    return LlamaConfig(
+        vocab_size=cfg["vocab_size"],
+        hidden_size=cfg["hidden_size"],
+        intermediate_size=cfg["intermediate_size"],
+        n_layers=n_layers,
+        n_heads=cfg["num_attention_heads"],
+        n_kv_heads=cfg["num_key_value_heads"],
+        head_dim=cfg.get("head_dim"),
+        rms_norm_eps=cfg.get("layer_norm_epsilon", 1e-5),
+        max_position_embeddings=cfg.get("max_position_embeddings", 4096),
+        tie_word_embeddings=cfg.get("tie_word_embeddings", False),
+        mixer_kinds=tuple(_NEMOTRON_H_LETTERS[c] for c in pattern),
+        one_sublayer=True,
+        use_rope=False,
+        mamba_d_state=cfg["ssm_state_size"],
+        mamba_d_conv=cfg["conv_kernel"],
+        mamba_n_heads=heads,
+        mamba_head_dim=cfg["mamba_head_dim"],
+        mamba_n_groups=groups,
+        mamba_d_inner=heads * cfg["mamba_head_dim"],
+        n_experts=scored,
+        n_experts_held=0 if held == scored else held,
+        first_expert=int(cfg.get("first_expert", 0)),
+        n_experts_per_tok=cfg["num_experts_per_tok"],
+        moe_intermediate_size=cfg["moe_intermediate_size"],
+        moe_shared_intermediate_size=(
+            cfg.get("moe_shared_expert_intermediate_size", 0)
+            if int(cfg.get("n_shared_experts", 0)) else 0),
+        n_shared_experts=int(cfg.get("n_shared_experts", 0)),
+        moe_router="sigmoid",
+        moe_form="relu2",
         routed_scaling_factor=float(cfg.get("routed_scaling_factor", 1.0)),
         norm_topk_prob=bool(cfg.get("norm_topk_prob", True)),
     )

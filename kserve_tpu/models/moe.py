@@ -1,4 +1,5 @@
-"""Sparse mixture-of-experts feed-forward: a router over gated experts.
+"""Sparse mixture-of-experts feed-forward: a router over experts, of which
+this chip may hold a share.
 
 Two routers (`MoEConfig.router`):
 
@@ -9,8 +10,11 @@ Two routers (`MoEConfig.router`):
   in the weights, which are the chosen scores, renormalised to sum 1
   (`norm_topk`) and multiplied by `scale` (`routed_scaling_factor`).
 
-A `shared` expert, where the model has one, takes every token beside the
-routed ones.
+Two forms of an expert (`MoEConfig.form`): `gated`, `down(silu(gate x) *
+up x)`, three matrices; `relu2`, `down(relu(up x)^2)`, two (the
+Nemotron-H family).  A `shared` expert of the same form, where the model
+has one, takes every token beside the routed ones, at a width of its own
+where `shared_intermediate_size` says so.
 
 ONE compute path for every router, packed step and decode alike
 (`routed_experts`): the (token, expert) pairs that were routed are sorted
@@ -21,10 +25,20 @@ are not read), and the rows go back to their tokens weighted.  Nothing of
 size [tokens, experts, width] exists.  Rows of padding (`valid` False) are
 given to no expert.
 
-Experts live on stacked tensors [n_experts, ...].  `moe_param_pspecs`
-shards that axis over the `model` mesh axis, which GSPMD partitions where
-`ragged_dot` lowers to plain XLA (the CPU); a chip's share of the experts
-on the TPU (an expert layer told which experts it holds) is ROADMAP work.
+A chip's share of the experts (`first_expert`, `held`): the router keeps
+its published width (`n_experts`) and its experts a token, and the weights
+are normalised over all the experts chosen, held or not; the stacked
+tensors hold experts `first_expert .. first_expert + held - 1` only, a pair
+routed to another expert goes where padding goes, to no group, and adds
+nothing: what the absent experts would have added is the other chips' part
+of the sum.  On one chip the layer runs without the exchange that would
+add the parts up; nothing here stands in for it.  `rows` counts what THIS
+chip multiplied.
+
+Experts live on stacked tensors [held, ...].  `moe_param_pspecs` shards
+that axis over the `model` mesh axis, which GSPMD partitions where
+`ragged_dot` lowers to plain XLA (the CPU): Mixtral's tp path, which holds
+every expert on every mesh.
 
 Role parity: vLLM's fused MoE path (SURVEY.md §2.3 Expert parallel row).
 """
@@ -50,6 +64,29 @@ class MoEConfig:
     norm_topk: bool = True  # sigmoid router: weights renormalised to sum 1
     scale: float = 1.0  # sigmoid router: routed_scaling_factor
     shared: bool = False  # a shared expert beside the routed ones
+    form: str = "gated"  # or "relu2": down(relu(up x)^2), no gate matrix
+    shared_intermediate_size: int = 0  # the shared expert's width; 0 = an expert's
+    # this chip's share: experts first_expert .. first_expert + held - 1 of
+    # the n_experts the router scores; held 0 = all of them
+    first_expert: int = 0
+    held: int = 0
+
+    def __post_init__(self):
+        if self.form not in ("gated", "relu2"):
+            raise ValueError(f"unknown expert form {self.form!r}")
+        if not (0 <= self.first_expert
+                and self.first_expert + self.n_held <= self.n_experts):
+            raise ValueError(
+                f"experts {self.first_expert}..{self.first_expert + self.n_held - 1} "
+                f"held of {self.n_experts}")
+
+    @property
+    def n_held(self) -> int:
+        return self.held or self.n_experts
+
+    @property
+    def holds_all(self) -> bool:
+        return self.n_held == self.n_experts
 
 
 def moe_config_of(config) -> MoEConfig:
@@ -64,20 +101,67 @@ def moe_config_of(config) -> MoEConfig:
         norm_topk=config.norm_topk_prob,
         scale=config.routed_scaling_factor,
         shared=config.n_shared_experts > 0,
+        form=config.moe_form,
+        shared_intermediate_size=config.moe_shared_intermediate_size,
+        first_expert=config.first_expert,
+        held=config.n_experts_held,
     )
 
 
+#: columns the grouped matmul's widest tiles span (`stored_width`)
+STORED_WIDTH_TILE = 512
+
+
+def stored_width(width: int) -> int:
+    """Columns a routed expert's `width` is STORED in: the next multiple of
+    512, with zero columns (w_up, w_gate) and zero rows (w_down) behind the
+    model's width, which change nothing that is computed.  A width that is
+    no multiple of the TPU's 128 lanes (Nemotron's 1856) makes the device's
+    default layout of [experts, hidden, width] put `hidden` innermost, and
+    the grouped matmul, which wants the width there, then copies every
+    layer's tensor on every call: 609 MB a layer, past the chip's memory
+    (docs/kernels.md, "A chip's share of the experts").  In the layout the
+    kernel wants the tiles hold padding to 128 anyway; at the next multiple
+    of 512 (2048) the chip measured its tiles twice as fast again.  A width
+    under one such tile (a test's) is stored as it is."""
+    if width < STORED_WIDTH_TILE:
+        return width
+    return -(-width // STORED_WIDTH_TILE) * STORED_WIDTH_TILE
+
+
 def moe_param_shapes(config: MoEConfig) -> Dict[str, tuple]:
-    """{name: shape} of one expert layer's feed-forward."""
-    E, h, f = config.n_experts, config.hidden_size, config.intermediate_size
-    shapes = {"router": (h, E), "w_gate": (E, h, f), "w_up": (E, h, f),
-              "w_down": (E, f, h)}
+    """{name: shape} of one expert layer's feed-forward: the router over
+    every expert, the stacked tensors over those held here, a routed
+    expert's width as `stored_width` has it."""
+    E, h = config.n_experts, config.hidden_size
+    f = stored_width(config.intermediate_size)
+    held = config.n_held
+    shapes = {"router": (h, E), "w_up": (held, h, f), "w_down": (held, f, h)}
     if config.router == "sigmoid":
         shapes["router_bias"] = (E,)
     if config.shared:
-        shapes.update({"shared_gate": (h, f), "shared_up": (h, f),
-                       "shared_down": (f, h)})
+        fs = config.shared_intermediate_size or config.intermediate_size
+        shapes.update({"shared_up": (h, fs), "shared_down": (fs, h)})
+    if config.form == "gated":
+        shapes["w_gate"] = (held, h, f)
+        if config.shared:
+            shapes["shared_gate"] = shapes["shared_up"]
     return shapes
+
+
+def zero_stored_padding(params: Dict[str, Any], config: MoEConfig) -> Dict[str, Any]:
+    """An expert layer's tensors with what lies behind the routed experts'
+    width set to zero (`stored_width`); as they are where the width is
+    stored as it is."""
+    f = config.intermediate_size
+    if stored_width(f) == f:
+        return params
+    out = dict(params)
+    for name in ("w_up", "w_gate"):
+        if name in out:
+            out[name] = out[name].at[:, :, f:].set(0)
+    out["w_down"] = out["w_down"].at[:, f:, :].set(0)
+    return out
 
 
 def init_moe_params(config: MoEConfig, rng: jax.Array, scale: float = 0.02,
@@ -91,7 +175,7 @@ def init_moe_params(config: MoEConfig, rng: jax.Array, scale: float = 0.02,
         else:
             out[name] = (jax.random.normal(key, shape, jnp.float32)
                          * scale).astype(dtype)
-    return out
+    return zero_stored_padding(out, config)
 
 
 @jax.named_scope("router")
@@ -118,18 +202,29 @@ def route(params: Dict[str, Any], x: jnp.ndarray,
 def routed_experts(params: Dict[str, Any], x: jnp.ndarray,
                    weights: jnp.ndarray, selected: jnp.ndarray,
                    n_experts: int, valid: Optional[jnp.ndarray] = None,
-                   ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """The routed pairs through their experts, and nothing else.
+                   share: Optional[Tuple[int, int]] = None,
+                   form: str = "gated") -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """The routed pairs through the experts held here, and nothing else.
 
-    x [N, h], weights / selected [N, k] -> (out [N, h] float32, rows
-    [n_experts] int32: how many rows each expert multiplied).  Pairs are
-    ordered by expert; `valid` False rows come behind every expert and
-    belong to no group, so no expert multiplies them."""
+    x [N, h], weights / selected [N, k] over `n_experts` experts -> (out
+    [N, h] float32, rows [held] int32: how many rows each held expert
+    multiplied).  `share` (first expert, held) where the stacked tensors
+    hold a part of the experts; None = all.  Pairs are ordered by expert;
+    `valid` False rows and pairs routed to an expert that is not held come
+    behind every expert and belong to no group, so no expert multiplies
+    them and they add nothing."""
     N, k = selected.shape
-    E = n_experts
+    first_expert, E = share or (0, n_experts)
     flat = selected.reshape(-1).astype(jnp.int32)
+    here = None
+    if share is not None:
+        flat = flat - first_expert
+        here = (flat >= 0) & (flat < E)
     if valid is not None:
-        flat = jnp.where(jnp.repeat(valid, k), flat, E)
+        rows_valid = jnp.repeat(valid, k)
+        here = rows_valid if here is None else here & rows_valid
+    if here is not None:
+        flat = jnp.where(here, flat, E)
     # a counting sort, not a sort: pair i goes to row `dest[i]`, behind the
     # pairs of lower experts and the earlier pairs of its own
     onehot = (flat[:, None] == jnp.arange(E + 1, dtype=jnp.int32)[None, :]
@@ -142,51 +237,64 @@ def routed_experts(params: Dict[str, Any], x: jnp.ndarray,
     order = jnp.zeros_like(dest).at[dest].set(
         jnp.arange(N * k, dtype=jnp.int32), unique_indices=True)
     xs = x[order // k]  # [N k, h]: each pair's token, in its expert's run
-    gate = jax.lax.ragged_dot(xs, params["w_gate"], rows)
-    up = jax.lax.ragged_dot(xs, params["w_up"], rows)
+    if form == "gated":
+        gate = jax.lax.ragged_dot(xs, params["w_gate"], rows)
+        up = jax.lax.ragged_dot(xs, params["w_up"], rows)
+        act = jax.nn.silu(gate) * up
+    else:
+        act = jnp.square(jax.nn.relu(
+            jax.lax.ragged_dot(xs, params["w_up"], rows)))
     ys = jax.lax.ragged_dot(
-        (jax.nn.silu(gate) * up).astype(xs.dtype), params["w_down"], rows,
+        act.astype(xs.dtype), params["w_down"], rows,
         preferred_element_type=jnp.float32)  # [N k, h]
     # back to (token, choice) order; rows of no group hold nothing defined
     y = ys[dest].reshape(N, k, -1)
-    if valid is not None:
+    if share is not None:
+        y = jnp.where(here.reshape(N, k, 1), y, 0.0)
+    elif valid is not None:
         y = jnp.where(valid[:, None, None], y, 0.0)
     return jnp.einsum("nkh,nk->nh", y, weights.astype(jnp.float32)), rows
 
 
 @jax.named_scope("shared_expert")
-def shared_expert(params: Dict[str, Any], x: jnp.ndarray) -> jnp.ndarray:
-    gate = jax.nn.silu(dense(x, params["shared_gate"]))
-    return dense(gate * dense(x, params["shared_up"]), params["shared_down"])
+def shared_expert(params: Dict[str, Any], x: jnp.ndarray,
+                  form: str = "gated") -> jnp.ndarray:
+    if form == "gated":
+        gate = jax.nn.silu(dense(x, params["shared_gate"]))
+        act = gate * dense(x, params["shared_up"])
+    else:
+        act = jnp.square(jax.nn.relu(dense(x, params["shared_up"])))
+    return dense(act, params["shared_down"])
 
 
 def moe_mlp(params: Dict[str, Any], x: jnp.ndarray, config: MoEConfig,
             valid: Optional[jnp.ndarray] = None, with_rows: bool = False):
     """x [..., h] -> [..., h]; `valid` [...] marks the rows that are tokens.
-    `with_rows`: also the rows each expert multiplied ([n_experts] int32),
+    `with_rows`: also the rows each held expert multiplied ([held] int32),
     from which the engine's expert counters are summed."""
     lead, h = x.shape[:-1], x.shape[-1]
     flat = x.reshape(-1, h)
     mask = None if valid is None else valid.reshape(-1)
     weights, selected = route(params, flat, config)
     out, rows = routed_experts(
-        params, flat, weights, selected, config.n_experts, mask)
+        params, flat, weights, selected, config.n_experts, mask,
+        None if config.holds_all else (config.first_expert, config.n_held),
+        config.form)
     if config.shared:
-        out = out + shared_expert(params, flat).astype(jnp.float32)
+        out = out + shared_expert(params, flat, config.form).astype(jnp.float32)
     out = out.astype(x.dtype).reshape(lead + (h,))
     return (out, rows) if with_rows else out
 
 
-def moe_param_pspecs():
-    """Expert-parallel shardings: the expert axis over the `model` mesh axis
-    (EP == TP axis on a single slice), the router replicated."""
+def moe_param_pspecs(config: Optional[MoEConfig] = None):
+    """Expert-parallel shardings of `moe_param_shapes(config)`'s tensors
+    (a Mixtral layer's without a config): the expert axis over the `model`
+    mesh axis (EP == TP axis on a single slice), the router, its bias and
+    the shared expert replicated."""
     from jax.sharding import PartitionSpec as P
 
     from ..parallel.sharding import MODEL_AXIS
 
-    return {
-        "router": P(),
-        "w_gate": P(MODEL_AXIS, None, None),
-        "w_up": P(MODEL_AXIS, None, None),
-        "w_down": P(MODEL_AXIS, None, None),
-    }
+    names = moe_param_shapes(config or MoEConfig())
+    return {name: (P(MODEL_AXIS, None, None) if name.startswith("w_") else P())
+            for name in names}

@@ -622,8 +622,10 @@ def describe_attention_dispatch(model_config, engine_config,
             "shard_map": False,
             "kv_write": kv_write,
         }
-    if mc.is_hybrid:
-        # models/hybrid.py: every read of a cache is one query per lane (the
+    if mc.is_hybrid and "gqa_attention" not in mc.mixer_kinds:
+        # models/hybrid.py's differential rows (a table whose attention is
+        # `gqa_attention` rows takes the Llama path's kernels, below):
+        # every read of a cache is one query per lane (the
         # decode kernel or its gather, gated as below at the cache's row
         # width); a window layer's packed slice is the XLA ring attention
         if cfg.use_pallas is None:

@@ -55,9 +55,10 @@ def validate_tp(config: LlamaConfig, tp: int) -> None:
     if config.is_hybrid:
         if tp > 1:
             raise NotImplementedError(
-                "tp>1 over a hybrid model (mamba / window / shared-cache "
-                "layers): its parameters and per-lane state have no sharding "
-                "rules yet; run it with tp=1")
+                "tp>1 over a hybrid model (Mamba-1 / Mamba-2 / window / "
+                "shared-cache layers, a chip's share of the experts): its "
+                "parameters and per-lane state have no sharding rules yet; "
+                "run it with tp=1")
         return
     if config.n_heads % tp != 0:
         raise ValueError(f"n_heads={config.n_heads} not divisible by tp={tp}")

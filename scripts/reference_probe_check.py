@@ -154,6 +154,10 @@ def int8_child(config_path, family_name, probes_path, out_path) -> int:
               # latent attention's projections and the shared expert
               "wq_a", "wq_b", "wkv_a", "wkv_b",
               "shared_gate", "shared_up", "shared_down")
+    if family_name == "nemotron_h":
+        # a Mamba-2 mixer's two projections (the Mamba-1 family's readings
+        # of PR 29 were taken with its own left in bf16, and stay so)
+        linear += ("in_proj", "out_proj")
     for i, layer in enumerate(params["layers"]):
         # in place: a layer's bf16 tensors go as its float32 ones come
         params["layers"][i] = {k: rounded(v) if k in linear else v

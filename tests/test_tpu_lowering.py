@@ -743,3 +743,70 @@ class TestLatentPagesAndGroupedExperts:
         report = att.describe_attention_dispatch(mc, cfg, "tpu")
         assert (report["mixed"], report["decode"]) == (
             "pallas_latent_ragged", "pallas_latent_decode")
+
+
+class TestNemotronH:
+    """`model_type: nemotron_h` (PR 41): the mixed program groups its held
+    experts and reads its two attention layers through the kernels the
+    Llama path uses; the routed experts' width is stored where the grouped
+    matmul wants it."""
+
+    def test_mixed_groups_the_held_experts_and_calls_the_paged_kernels(
+            self, monkeypatch):
+        import dataclasses
+        import re
+
+        from kserve_tpu.engine.shapes import DispatchShapes
+        from kserve_tpu.engine.types import EngineConfig
+        from kserve_tpu.models import llama
+        from test_nemotron_model import CFG
+
+        mc = dataclasses.replace(
+            llama.LlamaConfig.from_hf_config(
+                dict(CFG, hidden_size=128, head_dim=128, num_attention_heads=4,
+                     n_routed_experts=8, router_n_experts=16,
+                     moe_intermediate_size=520)),
+            dtype="bfloat16")
+        cfg = EngineConfig(
+            max_batch_size=8, page_size=16, num_pages=64, max_pages_per_seq=16,
+            max_prefill_len=128, prefill_buckets=(128,), dtype="bfloat16")
+        layout = kvcache.StateLayout.of(mc, 16, cfg.num_pages, 8, "bfloat16")
+        text = _lower_mixed(
+            mc, cfg, jax.eval_shape(layout.init_state), 16,
+            monkeypatch).as_text()
+        kernels = re.findall(r'kernel_name = "([a-z_]+)"', text)
+        # ONE attention layer: the ragged kernel in the packed step, the
+        # page write in both steps (the decode gather under this width)
+        assert "ragged_paged_attention" in " ".join(kernels), kernels
+        # 2 expert layers x (packed step + decode steps) x up, down: no gate
+        assert len(re.findall(r"ragged_dot", text)) >= 8
+        # the router's 16 outputs, the 8 held experts' tensors of width 520
+        # at 1024 columns (models/moe.stored_width)
+        assert "tensor<8x128x1024xbf16>" in text
+        assert not re.findall(r"tensor<16x128x(?:520|1024)xbf16>", text)
+        # nothing over (tokens, heads, head_dim, state) and no state a block
+        assert not re.findall(r"tensor<128x8x8x16x", text)
+        assert not re.findall(r"tensor<16x8x8x16xf32>", text)  # 128 / 8 blocks
+        assert DispatchShapes.of(mc, cfg, "tpu").align == pk.RAGGED_BQ
+        report = att.describe_attention_dispatch(mc, cfg, "tpu")
+        assert report["mixed"] == "pallas_ragged"
+        assert report["kv_write"] == {"paged": "page_kernel"}
+
+    @pytest.mark.parametrize("width, copies", [(2048, 0), (1920, 0), (1856, 1)])
+    def test_the_stored_width_spares_the_grouped_matmul_a_copy(
+            self, width, copies):
+        """At the published sizes the device's default layout of [64, 2688,
+        1856] puts 2688 innermost (1856 is no multiple of 128 lanes) and
+        the grouped matmul copies the tensor, 609 MB a layer and call;
+        stored in a multiple of 128 columns it is taken as it lies (2048, where
+        the chip measured its tiles twice as fast as at 1920: docs/kernels.md)."""
+        if _tpu_sharding() is None:
+            pytest.skip("no compile-only TPU topology here")
+        compiled = jax.jit(jax.lax.ragged_dot).lower(
+            _abstract((288, 2688), jnp.bfloat16),
+            _abstract((64, 2688, width), jnp.bfloat16), _i32(64)).compile()
+        found = [line for line in compiled.as_text().splitlines()
+                 if " copy(" in line and "64,2688" in line]
+        assert len(found) == copies, found
+        temp = compiled.memory_analysis().temp_size_in_bytes
+        assert (temp > 600e6) == bool(copies)
