@@ -48,6 +48,7 @@ from ..metrics import (
     ENGINE_KV_DISK_BYTES,
     ENGINE_KV_OFFLOAD_BYTES,
     ENGINE_KV_CONTEXT_TOKENS,
+    ENGINE_KV_DECODE_PAGES,
     ENGINE_KV_WRITE_CALLS,
     ENGINE_MOE_ASSIGNMENTS,
     ENGINE_MOE_EXPERT_HITS,
@@ -73,6 +74,7 @@ from ..metrics import (
     ENGINE_STEP_DURATION,
     ENGINE_WEDGED,
     GENERATED_TOKENS,
+    KV_DECODE_REACHES,
     PROMPT_TOKENS,
     observe_request_timeline,
     observe_startup_phase,
@@ -159,6 +161,26 @@ _DELIVERED_COLUMNS = slice(DISPATCH_COLUMNS.index(DELIVERIES[0]),
 _PART_COLUMNS = [DISPATCH_COLUMNS.index(part) for part in PARTS]
 _CPU_COLUMNS = slice(DISPATCH_COLUMNS.index(CPU_COLUMNS[0]),
                      DISPATCH_COLUMNS.index(CPU_COLUMNS[-1]) + 1)
+
+
+def _decode_page_reach(pos, n, decode_steps: int,
+                       page_size: int) -> Dict[str, int]:
+    """Pages of context over a dispatch's decode steps, by
+    metrics.KV_DECODE_REACHES: lane b attends to pos[b] + s + 1 tokens at
+    decode step s < n[b] and to none after (`seq_lens` as
+    models/llama.decode_step hands them to the kernel).  `own` sums the
+    pages the lanes hold; `block` sums, over the decode kernel's blocks
+    (`_pick_sb(lanes)` lanes each, dealt in order of length:
+    ops/pallas_paged_attention.length_order), the lanes of a block x the
+    pages of its longest lane: the iterations of the kernel's loop x the
+    ring slots an iteration has."""
+    from ..ops.pallas_paged_attention import _pick_sb
+
+    step = np.arange(decode_steps)[:, None]
+    pages = np.where(step < n, pages_needed(pos + step + 1, page_size), 0)
+    sb = _pick_sb(pages.shape[1])
+    longest = np.sort(pages, axis=1).reshape(decode_steps, -1, sb).max(axis=2)
+    return {"own": int(pages.sum()), "block": int(longest.sum()) * sb}
 
 
 def _refuse_looped(model_config, engine_config) -> None:
@@ -397,6 +419,10 @@ class LLMEngine:
             model_name=metrics_label)
         self._kv_context_tokens = ENGINE_KV_CONTEXT_TOKENS.labels(
             model_name=metrics_label)
+        self._kv_decode_pages = {
+            reach: ENGINE_KV_DECODE_PAGES.labels(
+                model_name=metrics_label, reach=reach)
+            for reach in KV_DECODE_REACHES}
         self._kv_write_calls = {
             path: ENGINE_KV_WRITE_CALLS.labels(
                 model_name=metrics_label, write_path=path)
@@ -2703,7 +2729,10 @@ class LLMEngine:
         to.  Lane b, live at position
         pos[b], attends to pos[b] + s + 1 tokens at decode step s while it
         stays under its page capacity: the device's own rule
-        (compiled._make_decode / _make_mixed), evaluated on the host.  The
+        (compiled._make_decode / _make_mixed), evaluated on the host; the
+        pages of those tokens are counted beside them, as the lanes hold
+        them and as the decode kernel's blocks of lanes walk them
+        (_decode_page_reach).  The
         tokens that pass the model (`packed_tokens` in the packed step, one
         a live lane and decode step) are each routed to `n_experts_per_tok`
         experts in every expert layer, where every expert layer sees them
@@ -2722,6 +2751,9 @@ class LLMEngine:
             n = np.where(np.asarray(live),
                          np.clip(np.asarray(capacity) - pos, 0, decode_steps), 0)
             self._kv_context_tokens.inc(int(np.sum(n * pos + n * (n + 1) // 2)))
+            for reach, pages in _decode_page_reach(
+                    pos, n, decode_steps, self.config.page_size).items():
+                self._kv_decode_pages[reach].inc(pages)
             tokens += int(np.sum(n))
         if self._ssd_layers:
             self._ssd_scan_tokens.inc(packed_tokens * self._ssd_layers)

@@ -434,6 +434,19 @@ ENGINE_KV_CONTEXT_TOKENS = Counter(
     "lanes attend to: the work of decode attention, in tokens a cache row",
     ["model_name"],
 )
+#: what is summed of a decode step's pages of context: the pages the lanes
+#: `own`; the walk of the decode kernel's `block`s (each out to its longest
+#: lane) as it forms them, from lanes in order of length
+#: (ops/pallas_paged_attention.length_order)
+KV_DECODE_REACHES = ("own", "block")
+ENGINE_KV_DECODE_PAGES = Counter(
+    "engine_kv_decode_pages_total",
+    "sum over a dispatch's decode steps of pages of context, by reach: own = "
+    "the pages its live lanes hold; block = over the decode kernel's blocks "
+    "of lanes, lanes a block x the pages of the block's longest lane (what "
+    "it fetches and folds), the lanes dealt to blocks in order of length",
+    ["model_name", "reach"],
+)
 # Expert layers (models/moe.py).  Assignments are counted at launch from the
 # dispatch's tokens; hits and peak load are summed IN the program over its
 # forward steps and expert layers and come back with the dispatch's tokens.

@@ -11,6 +11,16 @@ the gather path — but the gathered KV only ever exists in VMEM, so HBM
 traffic is ONE read of the table width instead of gather's read + write +
 re-read.
 
+A block's loop runs to its LONGEST sequence, and every sequence of the
+block fetches and folds a page in every iteration: past its own length that
+is the null page (page 0, where its padded table entries point), masked out
+and paid for all the same, in DMA and in compute alike.  So the entry
+points hand the kernel its sequences SORTED BY LENGTH and put its rows back
+(`_by_length`): a block then holds sequences of like length and walks
+little past any of them.  Leaving the null-page DMAs out instead (a
+`pl.when` a sequence around its start and its wait) was measured and is
+slower than fetching them: docs/kernels.md "Kernel against gather".
+
 Why not one-sequence-per-grid-step (v1/v2): the grid is sequential on a
 TPU core, so per-sequence page loops serialize B small DMA bursts and the
 per-page compute ([group, ps] matmuls) is far below MXU granularity —
@@ -121,6 +131,48 @@ def _ring_wait_and_refill(start_iter, kv_hbm_ref, kv_bufs, sems, sb, i,
         start_iter(i + NBUF - 1, jax.lax.rem(i + NBUF - 1, NBUF))
 
     return slot
+
+
+def length_order(seq_lens):
+    """(order, rank) that put B sequences in order of length, or None where
+    the decode kernel's blocks leave nothing to sort (one block holds them
+    all, or each holds one): `order[r]` is the sequence at place r,
+    `rank[b]` the place of sequence b (ties by index), so `x[order]` sorts
+    rows and `y[rank]` puts sorted rows back.  One [B, B] comparison; no
+    `sort`, which the programs are kept free of
+    (tests/test_tpu_lowering.py)."""
+    B = seq_lens.shape[0]
+    if _pick_sb(B) in (1, B):
+        return None
+    lane = jnp.arange(B, dtype=jnp.int32)
+    shorter = (seq_lens[None, :] < seq_lens[:, None]) | (
+        (seq_lens[None, :] == seq_lens[:, None]) & (lane[None, :] < lane[:, None]))
+    rank = shorter.sum(axis=1, dtype=jnp.int32)
+    order = ((rank[None, :] == lane[:, None]) * lane[None, :]).sum(
+        axis=1, dtype=jnp.int32)
+    return order, rank
+
+
+def rows_at(x, index):
+    """x[index] along the first axis for an `index` that is a permutation:
+    nothing to clip, nothing met twice."""
+    return x.at[index].get(unique_indices=True, mode="promise_in_bounds")
+
+
+def _by_length(call, page_table, seq_lens, q, kv):
+    """`call(page_table, seq_lens, q, kv)` with the sequences in order of
+    length, its rows put back in the order they came in.  The grid takes
+    `sb` sequences a block in the order given and walks every one of them
+    out to the block's longest (`_block_pages`); sorted, a block's
+    sequences are of like length.  Each row is computed from its own
+    sequence alone, so the outputs are the unsorted call's bit for bit."""
+    by_length = length_order(seq_lens)
+    if by_length is None:
+        return call(page_table, seq_lens, q, kv)
+    order, rank = by_length
+    out = call(rows_at(page_table, order), rows_at(seq_lens, order),
+               rows_at(q, order), kv)
+    return rows_at(out, rank)
 
 
 def _per_row(ref, base, n):
@@ -353,11 +405,11 @@ def _paged_attention_pallas_packed(
         scale=scale,
         logit_softcap=logit_softcap,
     )
-    packed_out = _pallas_call(kernel, B, sb, nq, 128, kv_packed)(
+    packed_out = _by_length(_pallas_call(kernel, B, sb, nq, 128, kv_packed)(
         out_shape=jax.ShapeDtypeStruct((B, nq, 128), jnp.float32),
         interpret=interpret,
         name="paged_attention_decode_packed",
-    )(page_table, seq_lens, q2, kv_packed)
+    ), page_table, seq_lens, q2, kv_packed)
     # fold the parity halves (plain XLA; f32 before the final cast)
     out = packed_out.reshape(B, nq, 2, 64).sum(axis=2)
     return out.astype(q.dtype)
@@ -402,11 +454,11 @@ def paged_attention_pallas(
         scale=scale,
         logit_softcap=logit_softcap,
     )
-    return _pallas_call(kernel, B, sb, nq, d, kv_pages)(
+    return _by_length(_pallas_call(kernel, B, sb, nq, d, kv_pages)(
         out_shape=jax.ShapeDtypeStruct((B, nq, d), q.dtype),
         interpret=interpret,
         name=name,
-    )(page_table, seq_lens, q, kv_pages)
+    ), page_table, seq_lens, q, kv_pages)
 
 
 # ---------------- ragged paged attention (mixed prefill+decode) ----------------
@@ -878,11 +930,11 @@ def latent_attention_decode_pallas(
         _decode_kernel, sb=sb, page_size=pages.shape[3], num_kv_heads=1,
         head_dim=d, scale=float(scale), logit_softcap=0.0,
         value_dim=value_dim)
-    return _pallas_call(kernel, B, sb, nq, d, pages, out_lane=value_dim)(
+    return _by_length(_pallas_call(kernel, B, sb, nq, d, pages, out_lane=value_dim)(
         out_shape=jax.ShapeDtypeStruct((B, nq, value_dim), q.dtype),
         interpret=interpret,
         name="latent_attention_decode",
-    )(page_table, seq_lens, q, pages)
+    ), page_table, seq_lens, q, pages)
 
 
 def latent_attention_ragged_pallas(

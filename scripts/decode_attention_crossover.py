@@ -64,6 +64,8 @@ FAMILIES = {
     # published heads would lie, for the packed kernel
     "phi4-mini-flash": (48, 40, 10, 128),
     "phi4-mini-flash/heads-of-64": (48, 40, 20, 64),
+    # Ouro-2.6B: 12 lanes = two blocks of six, 128 KB pages
+    "ouro-2.6b": (12, 16, 16, 128),
 }
 N_LO, N_HI = 72, 216
 

@@ -119,7 +119,8 @@ def standin_counts_dispatch_shapes(monkeypatch):
     program has since PR 33: its made-up engine pads one mixed dispatch in
     ten, so that `dispatch.padded_share`'s reader finds something to read
     against it, like every other reader; likewise the deliveries (PR 36)
-    and the host's parts, CPU seconds and pauses (PR 39).  Kept here because
+    and the host's parts, CPU seconds and pauses (PR 39), and the pages of
+    decode attention by reach (PR 42).  Kept here because
     a second conftest.py would take this one's module name."""
     standin = sys.modules.get("standin")
     if standin is None:  # not a test of the benchmark
@@ -155,6 +156,11 @@ def standin_counts_dispatch_shapes(monkeypatch):
         lines += ["engine_other_compile_seconds_total 0.0"]
         lines += [f'engine_gc_pause_seconds_total{{generation="{g}"}} 0.0'
                   for g in (1, 2)]
+        # since PR 42 the pages of decode attention: the made-up lanes own
+        # three quarters of what their blocks walk
+        lines += ['engine_kv_decode_pages_total{model_name="bench",'
+                  f'reach="{reach}"}} {n * pages}'
+                  for reach, pages in (("own", 300), ("block", 400))]
         return "\n".join(lines) + "\n"
 
     monkeypatch.setattr(standin.StandIn, "_metrics", with_dispatch_shapes)

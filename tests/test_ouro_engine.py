@@ -16,9 +16,11 @@ from kserve_tpu.engine.sampling import SamplingParams
 from kserve_tpu.engine.tokenizer import ByteTokenizer
 from kserve_tpu.metrics import (
     ENGINE_KV_CONTEXT_TOKENS,
+    ENGINE_KV_DECODE_PAGES,
     ENGINE_KV_PAGES_TOTAL,
     ENGINE_KV_TOKEN_BYTES,
     ENGINE_LAYER_PASSES,
+    KV_DECODE_REACHES,
 )
 from test_ouro_model import CFG, CONFIG, PARAMS, _reference, config_of
 
@@ -153,10 +155,70 @@ def test_counters_gauges_and_the_cache_block():
     assert _value(ENGINE_LAYER_PASSES, label) == dispatches * 4 * 3
     assert _value(ENGINE_KV_CONTEXT_TOKENS, label) == (
         14 + 15 + 16 + 18 + 19 + 20 + 22 + 23 + 24)
+    # the same steps in pages of 4 tokens: the one live lane's own, and the
+    # walk of its block of two lanes (the other seat is empty)
+    own = 4 + 4 + 4 + 5 + 5 + 5 + 6 + 6 + 6
+    assert _decode_pages(label) == {"own": own, "block": 2 * own}
     # the device's cache: a row a pass in each layer's array
     assert len(engine.kv_pages) == 2
     assert engine.kv_pages[0].shape[0] == 3 * 64
     assert engine.cache_config.page_bytes() == 4 * token_bytes
+
+
+def _decode_pages(label):
+    return {reach: ENGINE_KV_DECODE_PAGES.labels(
+        model_name=label, reach=reach)._value.get()
+        for reach in KV_DECODE_REACHES}
+
+
+#: 3 decode steps over 16-token pages.  Lane 0 at 599 cached tokens
+#: (contexts 600-602: 38 pages a step), lanes 1-6 at 99 (100-102: 7 pages),
+#: lane 7 an empty seat; lane 8 meets its capacity of 48 after ONE step
+#: (context 48: 3 pages, then not live), lane 9 crosses a page (contexts 16,
+#: 17, 18: 1, 2, 2 pages), lane 10 is already AT its capacity (never live);
+#: the rest are empty seats
+_OWN = 3 * 38 + 6 * 3 * 7 + 3 + (1 + 2 + 2)
+
+
+@pytest.mark.parametrize("lanes, block", [
+    # two blocks of EIGHT, the lanes dealt in order of length: the eight
+    # longest of a step share a block (38, six of 7 and 3 | 2 | 2) and the
+    # other block holds lane 9's 1 page, then nothing (in lane order lanes
+    # 8-15 would walk to 3, 2, 2)
+    (16, 8 * 3 * 38 + 8 * (1 + 0 + 0)),
+    # two blocks of SIX: the six longest of a step are 38 and five of 7,
+    # the others walk to the sixth lane's 7
+    (12, 6 * 3 * 38 + 6 * 3 * 7),
+    # thirteen lanes have no divisor up to eight but one: a block a lane,
+    # which walks what its lane owns
+    (13, _OWN),
+], ids=["blocks-of-8", "blocks-of-6", "blocks-of-1"])
+def test_decode_pages_by_reach_of_a_hand_built_dispatch(lanes, block):
+    """`engine_kv_decode_pages_total{reach}` beside
+    `engine_kv_context_tokens_total`, from the same pos / live / capacity:
+    `_count_forward` on a dispatch nobody launched."""
+    label = f"pages-by-reach-{lanes}"
+
+    async def jobs(engine):
+        return None
+
+    _, engine = _run(engine_config(max_batch_size=lanes, page_size=16,
+                                   num_pages=256), jobs, label)
+    before = _decode_pages(label), _value(ENGINE_KV_CONTEXT_TOKENS, label)
+    pos = np.zeros(lanes, np.int64)
+    live = np.zeros(lanes, bool)
+    capacity = np.full(lanes, 640, np.int64)
+    pos[0], live[0] = 599, True
+    pos[1:7], live[1:7] = 99, True
+    pos[8], live[8], capacity[8] = 47, True, 48
+    pos[9], live[9] = 15, True
+    pos[10], live[10], capacity[10] = 48, True, 48
+    engine._count_forward(4, pos, live, capacity, decode_steps=3)
+    after = _decode_pages(label)
+    assert {r: after[r] - before[0][r] for r in after} == {
+        "own": _OWN, "block": block}
+    assert _value(ENGINE_KV_CONTEXT_TOKENS, label) - before[1] == (
+        600 + 601 + 602 + 6 * (100 + 101 + 102) + 48 + 16 + 17 + 18)
 
 
 def test_a_one_pass_model_counts_one_pass_a_step():

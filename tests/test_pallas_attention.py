@@ -11,8 +11,13 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from kserve_tpu.ops.attention import paged_attention_xla
-from kserve_tpu.ops.pallas_paged_attention import _pick_sb, paged_attention_pallas
+from kserve_tpu.ops import pallas_paged_attention as pk
+from kserve_tpu.ops.attention import _latent_as_kv, paged_attention_xla
+from kserve_tpu.ops.pallas_paged_attention import (
+    _pick_sb,
+    latent_attention_decode_pallas,
+    paged_attention_pallas,
+)
 
 
 def make_case(B=8, nq=8, nkv=4, d=64, ps=8, num_pages=80, max_pages=4, seed=0,
@@ -157,6 +162,173 @@ class TestPallasPagedAttention:
         assert _pick_sb(6) == 6
         assert _pick_sb(5) == 5
         assert _pick_sb(13) == 1  # prime > MAX_SB: no divisor <= 8 except 1
+
+
+@pytest.fixture
+def lane_order(monkeypatch):
+    """Inside `with lane_order():` the entry points hand the kernel its
+    sequences as they come, as they did before PR 42."""
+    import contextlib
+
+    @contextlib.contextmanager
+    def unsorted():
+        with monkeypatch.context() as m:
+            m.setattr(pk, "_by_length", lambda call, *args: call(*args))
+            yield
+
+    return unsorted
+
+
+#: form -> (head size or row, kernel call, XLA reference)
+_LATENT = dict(scale=0.125, value_dim=64)
+FORMS = {
+    # K and V planes, head size 128: _decode_kernel
+    "planes": (128, lambda q, kv, pt, lens: paged_attention_pallas(
+        q, kv, pt, lens, interpret=True), paged_attention_xla),
+    # one latent row a token, its first 64 columns the value: _decode_kernel
+    # with value_dim
+    "latent": (128, lambda q, kv, pt, lens: latent_attention_decode_pallas(
+        q, kv, pt, lens, interpret=True, **_LATENT),
+        lambda q, kv, pt, lens: paged_attention_xla(
+            q, _latent_as_kv(kv), pt, lens, scale=_LATENT["scale"]
+        )[..., :_LATENT["value_dim"]]),
+    # head size 64, two tokens a row: _packed_decode_kernel
+    "packed": (64, lambda q, kv, pt, lens: paged_attention_pallas(
+        q, kv, pt, lens, interpret=True), paged_attention_xla),
+}
+PS, WIDTH = 8, 12
+
+
+def _case(form, lens):
+    """Lanes of the given lengths over 8-token pages and a 12-page table no
+    lane fills; the table's entries past a lane's pages are the null page,
+    as the engine pads them.  -> q, cache, the cache with a NaN null page,
+    table, lengths, which lanes are live."""
+    d = FORMS[form][0]
+    planes = nkv = 1 if form == "latent" else 2
+    lanes = len(lens)
+    rng = np.random.RandomState(lanes)
+    own = -(-lens // PS)
+    assert own.max() < WIDTH
+    table = rng.permutation(np.arange(1, lanes * WIDTH + 1)).reshape(
+        lanes, WIDTH)
+    table[np.arange(WIDTH)[None, :] >= own[:, None]] = 0
+    q = jnp.asarray(rng.randn(lanes, 8, d), jnp.float32)
+    kv = jnp.asarray(
+        rng.randn(lanes * WIDTH + 1, planes, nkv, PS, d),
+        jnp.float32).at[0].set(0.0)
+    return (q, kv, kv.at[0].set(jnp.nan), jnp.asarray(table, jnp.int32),
+            jnp.asarray(lens, jnp.int32), lens > 0)
+
+
+class TestBlocksOfLikeLength:
+    """PR 42: the entry points hand the kernel its sequences sorted by
+    length, so that a block (which walks out to its longest sequence) holds
+    sequences of like length."""
+
+    @pytest.mark.parametrize("lanes", [12, 16], ids=["sb6", "sb8"])
+    @pytest.mark.parametrize("form", sorted(FORMS))
+    def test_every_lane_is_the_lane_order_kernel_s_bit_for_bit(
+            self, form, lanes, lane_order):
+        """Two blocks: lanes of 70 and 3 tokens side by side, empty seats,
+        a lane that ends exactly on a page, the longest lane in the second
+        block's seats."""
+        kernel, reference = FORMS[form][1:]
+        sb = _pick_sb(lanes)
+        assert lanes // sb == 2 and sb == {12: 6, 16: 8}[lanes]
+        lens = np.random.RandomState(lanes).randint(1, 5 * PS, size=lanes)
+        lens[:5] = [70, 3, 2 * PS, 0, 1]
+        lens[sb + 1], lens[sb + 2], lens[-1] = 9 * PS, 0, 5
+        q, kv, _, pt, seq, live = _case(form, lens)
+        got = np.asarray(kernel(q, kv, pt, seq))
+        want = np.asarray(reference(q, kv, pt, seq))
+        assert np.isfinite(got).all()  # an empty seat writes finite numbers
+        np.testing.assert_allclose(got[live], want[live], rtol=2e-5, atol=2e-5)
+        assert np.abs(want[live]).max() > 1e-3
+        with lane_order():
+            unsorted = np.asarray(kernel(q, kv, pt, seq))
+        # each row is computed from its own sequence alone: the same pages
+        # in the same order through the same accumulator
+        np.testing.assert_array_equal(got[live], unsorted[live])
+
+    @pytest.mark.parametrize("lanes", [12, 16], ids=["sb6", "sb8"])
+    @pytest.mark.parametrize("form", sorted(FORMS))
+    def test_a_block_of_equal_lanes_never_reads_the_null_page(
+            self, form, lanes, lane_order):
+        """Half the lanes hold two pages (9-16 tokens) and half hold nine,
+        seated alternately.  In lane order every block walks nine pages and
+        the short lanes fetch the null page seven times each: filled with
+        NaN it reaches their outputs (0 x NaN).  Sorted, one block walks
+        two pages and the other nine, and nobody fetches it."""
+        kernel, reference = FORMS[form][1:]
+        rng = np.random.RandomState(lanes + 1)
+        lens = np.where(np.arange(lanes) % 2 == 0,
+                        rng.randint(PS + 1, 2 * PS + 1, size=lanes),
+                        rng.randint(8 * PS + 1, 9 * PS + 1, size=lanes))
+        lens[2], lens[3] = 2 * PS, 9 * PS  # ending exactly on a page
+        q, kv, poisoned, pt, seq, _ = _case(form, lens)
+        got = np.asarray(kernel(q, poisoned, pt, seq))
+        assert np.isfinite(got).all()
+        np.testing.assert_allclose(
+            got, np.asarray(reference(q, kv, pt, seq)), rtol=2e-5, atol=2e-5)
+        with lane_order():
+            unsorted = np.asarray(kernel(q, poisoned, pt, seq))
+        assert np.isnan(unsorted[0::2]).all()
+        assert np.isfinite(unsorted[1::2]).all()
+
+    @pytest.mark.parametrize("lanes", [16, 12, 48])
+    def test_by_length_sorts_for_the_call_and_puts_the_rows_back(self, lanes):
+        rng = np.random.RandomState(lanes)
+        lens = jnp.asarray(rng.randint(0, 640, size=lanes), jnp.int32)
+        table = jnp.asarray(rng.randint(1, 99, size=(lanes, 5)), jnp.int32)
+        q = jnp.asarray(rng.randn(lanes, 4, 8), jnp.float32)
+        seen = {}
+
+        def call(table_, lens_, q_, kv_):
+            seen.update(table=table_, lens=lens_, q=q_, kv=kv_)
+            return q_ * 2.0 + lens_[:, None, None]
+
+        out = pk._by_length(call, table, lens, q, "the cache")
+        assert seen["kv"] == "the cache"
+        dealt = np.asarray(seen["lens"])
+        assert (np.diff(dealt) >= 0).all() and sorted(dealt) == sorted(
+            np.asarray(lens))
+        # a lane's table row and query travel with its length
+        order = np.argsort(np.asarray(lens), kind="stable")
+        np.testing.assert_array_equal(np.asarray(seen["table"]),
+                                      np.asarray(table)[order])
+        np.testing.assert_array_equal(np.asarray(seen["q"]),
+                                      np.asarray(q)[order])
+        np.testing.assert_array_equal(
+            np.asarray(out), np.asarray(q * 2.0 + lens[:, None, None]))
+
+    @pytest.mark.parametrize("lanes", [8, 13],
+                             ids=["one-block", "blocks-of-one"])
+    def test_nothing_is_gathered_where_nothing_is_to_sort(self, lanes):
+        """One block holds every sequence, or every block holds one (13
+        lanes: no divisor up to MAX_SB but 1) and walks its own pages."""
+        lens = jnp.asarray(np.arange(lanes)[::-1].copy(), jnp.int32)
+
+        def call(table, lens_, q, kv):
+            assert lens_ is lens
+            return q
+
+        q = jnp.ones((lanes, 2, 4))
+        assert pk.length_order(lens) is None
+        assert pk._by_length(call, jnp.zeros((lanes, 3), jnp.int32),
+                             lens, q, None) is q
+
+    def test_length_order_is_a_stable_sort_and_its_inverse(self):
+        lens = jnp.asarray([7, 0, 7, 3, 0, 9, 3, 3, 1, 0, 640, 2], jnp.int32)
+        order, rank = pk.length_order(lens)
+        np.testing.assert_array_equal(
+            np.asarray(order), np.argsort(np.asarray(lens), kind="stable"))
+        np.testing.assert_array_equal(np.asarray(order)[np.asarray(rank)],
+                                      np.arange(12))
+        rows = jnp.arange(24.0).reshape(12, 2)
+        np.testing.assert_array_equal(
+            np.asarray(pk.rows_at(pk.rows_at(rows, order), rank)),
+            np.asarray(rows))
 
 
 class TestShardedPagedAttention:

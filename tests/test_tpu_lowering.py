@@ -457,6 +457,22 @@ class TestMixedProgramTakesTheDecodeKernel:
             rf"tensor<48x{width}x[0-9x]*16x128xbf16>", text)
         assert not gathered, sorted(set(gathered))
 
+    def test_the_kernel_s_entry_sorts_its_lanes_around_each_call(
+            self, monkeypatch):
+        """The decode kernel walks a block of lanes to its longest, so its
+        entry point hands it the lanes in order of length and puts its rows
+        back (PR 42, `_by_length`): a layer's call gathers the page table,
+        the queries and the attention's rows by lane, and nothing else of
+        the step moves (the hidden state stays in lane order)."""
+        import re
+
+        text = _decode_sat_mixed(40, monkeypatch).as_text()
+        gathers = re.findall(r'"stablehlo.gather"\(.*?-> (tensor<[^>]+>)', text)
+        layers = 2
+        assert gathers.count("tensor<48x40xi32>") == layers, gathers
+        assert gathers.count("tensor<48x32x128xbf16>") == 2 * layers, gathers
+        assert "tensor<48x1x256xbf16>" not in gathers, gathers
+
 
 def _sorts_met_without_a_branch(hlo: str):
     """(sorts in all, computations that sort and are reached from the entry
