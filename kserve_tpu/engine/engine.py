@@ -63,6 +63,8 @@ from ..metrics import (
     ENGINE_SSD_SCAN_TOKENS,
     ENGINE_SSD_UPDATE_CALLS,
     ENGINE_SSD_UPDATE_LANE_STEPS,
+    ENGINE_WINDOW_LANE_STEPS,
+    ENGINE_WINDOW_RAGGED_WORK,
     ENGINE_STATE_BYTES,
     ENGINE_STATE_RESETS,
     ENGINE_STATE_SLOTS_IN_USE,
@@ -245,8 +247,9 @@ def _refuse_latent(model_config, engine_config, role: str) -> None:
 def resolve_hybrid_serving(model_config, engine_config,
                            role: str = "both") -> None:
     """THE place that says what a model with recurrent or ring state
-    (models/hybrid.py: the Mamba-1 family with window rings and the
-    Mamba-2 family alike) cannot do yet.  A lane's pages are no longer its
+    (models/hybrid.py: the Mamba-1 family with window rings, the Mamba-2
+    family and the Cohere family's roped window rings alike) cannot do
+    yet.  A lane's pages are no longer its
     whole state, and that state cannot be rewound, shared or shipped, so:
     what was asked for explicitly is refused here, at start-up, by name;
     what was left at its default is resolved to off, with a log line.
@@ -280,7 +283,7 @@ def resolve_hybrid_serving(model_config, engine_config,
                        "persistent prefix store move pages only)")
     if cfg.prefix_cache:
         refused.append("prefix_cache (a prefix's pages do not hold the "
-                       "recurrent state at its boundary)")
+                       "recurrent state or the rings at its boundary)")
     if cfg.use_ragged is False:
         refused.append("use_ragged=False (the legacy programs)")
     if role != "both":
@@ -293,7 +296,8 @@ def resolve_hybrid_serving(model_config, engine_config,
         cfg.prefix_cache = False
         logger.info(
             "hybrid model: prefix cache adoption resolved to OFF (snapshots "
-            "of recurrent state are not implemented); speculative decoding, "
+            "of recurrent state and of rings are not implemented); "
+            "speculative decoding, "
             "tier offload, the P/D wire of pages, logprobs and penalties "
             "lanes are refused by name")
 
@@ -450,6 +454,20 @@ class LLMEngine:
             model_name=metrics_label)
         self._ssd_update_lane_steps = ENGINE_SSD_UPDATE_LANE_STEPS.labels(
             model_name=metrics_label)
+        # engine_window_*_total: layers that keep a ring a lane, and their
+        # window (0 where there is none: nothing is counted)
+        self._ring_layers = sum(
+            r.writes == "window_kv" for r in model_config.layer_table())
+        self._ring_window = (
+            model_config.sliding_window if self._ring_layers else 0)
+        self._window_lane_steps = {
+            bound: ENGINE_WINDOW_LANE_STEPS.labels(
+                model_name=metrics_label, bound=bound)
+            for bound in ("yes", "no")}
+        self._window_ragged_work = {
+            unit: ENGINE_WINDOW_RAGGED_WORK.labels(
+                model_name=metrics_label, unit=unit)
+            for unit in ("queries", "pairs", "keys")}
         self._expert_stats = model_config.has_expert_sums
         # pairs counted on the host at launch (tokens x experts a token x
         # expert layers) unless the program counts its own
@@ -2755,6 +2773,12 @@ class LLMEngine:
                     pos, n, decode_steps, self.config.page_size).items():
                 self._kv_decode_pages[reach].inc(pages)
             tokens += int(np.sum(n))
+            if self._ring_window:
+                # step s of a lane at pos attends to pos + s + 1 tokens: past
+                # the window from s = window - pos on
+                free = np.clip(self._ring_window - pos, 0, n)
+                self._window_lane_steps["no"].inc(int(np.sum(free)))
+                self._window_lane_steps["yes"].inc(int(np.sum(n - free)))
         if self._ssd_layers:
             self._ssd_scan_tokens.inc(packed_tokens * self._ssd_layers)
             self._ssd_update_calls.inc(decode_steps * self._ssd_layers)
@@ -2766,6 +2790,21 @@ class LLMEngine:
             # program's own and come back with the dispatch's tokens
             self._moe_assignments.inc(
                 tokens * mc.n_experts_per_tok * mc.n_expert_layers)
+
+    def _count_window_ragged(self, q_len, kv_start) -> None:
+        """engine_window_ragged_work_total for one packed step: the query at
+        offset j of a slice that starts at `kv_start` sees min(kv_start + j
+        + 1, window) keys; the slice must read the ring tokens its first
+        query sees and its own."""
+        R, layers = self._ring_window, self._ring_layers
+        n, s = np.asarray(q_len, np.int64), np.asarray(kv_start, np.int64)
+        under = np.clip(R - s, 0, n)  # queries whose context is <= window
+        pairs = under * s + under * (under + 1) // 2 + (n - under) * R
+        keys = np.where(n > 0, np.minimum(s, R - 1) + n, 0)
+        work = self._window_ragged_work
+        work["queries"].inc(int(n.sum()) * layers)
+        work["pairs"].inc(int(pairs.sum()) * layers)
+        work["keys"].inc(int(keys.sum()) * layers)
 
     def _set_state_gauges(self) -> None:
         occupancy = self._state_occupancy()
@@ -3796,6 +3835,8 @@ class LLMEngine:
                 self._shapes.steps, plan["scan_pos0"], plan["joins"],
                 plan["capacity"], decode_steps=self._shapes.steps - 1,
                 packed_tokens=plan["prefill_tokens"] + plan["decode_tokens"])
+            if self._ring_window:
+                self._count_window_ragged(plan["q_len"], plan["kv_start"])
         phases.mark("wait")
         # the fetch is handed to its worker first, so that the result is
         # stamped when the device has it and a delivery that outlasts the

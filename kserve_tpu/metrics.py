@@ -492,6 +492,26 @@ ENGINE_SSD_UPDATE_LANE_STEPS = Counter(
     "live lanes summed over those updates: lane-steps whose state moved on",
     ["model_name"],
 )
+# Window layers that keep a ring a lane (models/hybrid.py): whether the
+# window BOUND a decode step's attention, counted at launch from the plan.
+ENGINE_WINDOW_LANE_STEPS = Counter(
+    "engine_window_lane_steps_total",
+    "decode lane-steps of a model with window rings, by whether the lane's "
+    "context at that step was past the window (bound=yes: the ring is full "
+    "and the step reads `sliding_window` tokens whatever the context) or "
+    "not (bound=no: it reads the context); the rule of "
+    "engine_kv_context_tokens_total, evaluated on the host",
+    ["model_name", "bound"],
+)
+ENGINE_WINDOW_RAGGED_WORK = Counter(
+    "engine_window_ragged_work_total",
+    "what the packed step's window attention is asked to do, summed over "
+    "lanes' slices and window layers at launch: unit=queries (tokens of "
+    "the slices), unit=pairs ((query, key) pairs inside the window: its "
+    "operations), unit=keys (ring tokens a slice's first query sees plus "
+    "the slice's own: K/V rows it must read at least once)",
+    ["model_name", "unit"],
+)
 ENGINE_MOE_EXPERT_HITS = Counter(
     "engine_moe_expert_hits_total",
     "sum over forward steps and expert layers of the experts that got at "

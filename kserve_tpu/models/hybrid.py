@@ -48,6 +48,17 @@ feed-forward: routed experts of the ungated `relu2` form, of which this
 chip may hold a share, beside a shared one).  A row's `ffn` is `none`
 where its mixer is not the feed-forward; `_close` adds what the row has.
 
+Fourth family: `model_type: cohere2_moe` (Command A+): a PARALLEL block, `u
+= LN(h); h += Mixer(u) + FFN(u)`: one bias-free LayerNorm, one residual
+(`config.parallel_block`; `_close` takes the norm's output along).  The
+mixer is plain grouped-query attention, `gqa_window_attention` over the last
+`sliding_window` tokens with interleaved rotary (keys are turned BEFORE they
+enter the lane's ring, so the ring's slot order stays free for the softmax
+and the mask needs the slots' positions only) or `gqa_attention` over the
+pool's pages with no positions (`config.layer_ropes`); the feed-forward is
+routed experts, of which this chip may hold a share, beside several shared
+ones fused into one MLP (models/moe.py).
+
 Layers behind the last layer that writes state only feed the logits, so the
 packed forward runs them (and the last writer's own attention output) on
 the rows that are sampled, one per lane: exact, and it makes every read of
@@ -73,9 +84,10 @@ from ..ops.attention import (
     paged_attention,
     paged_attention_scaled,
     ragged_paged_attention,
-    ring_window_attention_ragged,
+    window_attention_ragged,
 )
 from ..ops.norms import layer_norm, rms_norm
+from ..ops.rotary import apply_rope, apply_rope_interleaved
 from . import latent, llama
 from .moe import moe_config_of, moe_mlp, moe_param_shapes, zero_stored_padding
 from .quant import dense, tied_head_matmul
@@ -99,9 +111,10 @@ def layer_param_shapes(config, spec) -> Dict[str, tuple]:
     di, n, k, r = (config.mamba_d_inner, config.mamba_d_state,
                    config.mamba_d_conv, config.mamba_dt_rank)
     norms = (["attn_norm"] if spec.kind != "ffn" else []) + (
-        ["mlp_norm"] if spec.ffn != "none" else [])
+        ["mlp_norm"] if spec.ffn != "none" and not config.parallel_block
+        else [])
     shapes = {name: ((h,), "ones") for name in norms}
-    if config.norm_type == "layernorm":
+    if config.norm_type == "layernorm" and config.norm_bias:
         shapes.update({name + "_b": ((h,), "bias") for name in norms})
     if spec.ffn == "experts":
         inits = {"router_bias": "zeros", "w_down": "routed_out"}
@@ -113,7 +126,7 @@ def layer_param_shapes(config, spec) -> Dict[str, tuple]:
                        "w_down": ((f, h), "normal")})
     if spec.kind == "latent_attention":
         shapes.update(latent.param_shapes(config))
-    elif spec.kind == "gqa_attention":
+    elif spec.kind in ("gqa_attention", "gqa_window_attention"):
         shapes.update({
             "wq": ((h, nq * hd), "normal"), "wk": ((h, nkv * hd), "normal"),
             "wv": ((h, nkv * hd), "normal"), "wo": ((nq * hd, h), "normal")})
@@ -226,7 +239,7 @@ def init_params(config, rng, scale: float = 0.02, weight_quant: str = "none",
         h = config.hidden_size
         top = {"embed": make((config.vocab_size, h), "normal", k[0]),
                "final_norm": jnp.ones((h,), dtype)}
-        if config.norm_type == "layernorm":
+        if config.norm_type == "layernorm" and config.norm_bias:
             top["final_norm_b"] = make((h,), "bias", k[1])
         if not config.tie_word_embeddings:
             top["lm_head"] = make((h, config.vocab_size), "normal", k[1])
@@ -250,7 +263,7 @@ def init_params(config, rng, scale: float = 0.02, weight_quant: str = "none",
 
 def _ln(x, layer, name, config):
     if config.norm_type == "layernorm":
-        return layer_norm(x, layer[name], layer[name + "_b"],
+        return layer_norm(x, layer[name], layer.get(name + "_b"),
                           config.rms_norm_eps)
     return rms_norm(x, layer[name], config.rms_norm_eps)
 
@@ -276,10 +289,13 @@ def _ffn(layer, spec, x, valid, state, config):
     return out
 
 
-def _close(layer, spec, x, mixed, valid, state, config):
+def _close(layer, spec, x, mixed, valid, state, config, u=None):
     """Residual around the mixer's output, then the feed-forward's: each
     where the row has it (`mixed` None: the row's mixer is its
-    feed-forward)."""
+    feed-forward).  A parallel block's feed-forward reads `u`, the norm the
+    mixer read, and both meet in the one residual."""
+    if config.parallel_block:
+        return x + mixed + _ffn(layer, spec, u, valid, state, config)
     if mixed is not None:
         x = x + mixed
     if spec.ffn == "none":
@@ -335,10 +351,29 @@ def _differential_out(layer, attn, config, layer_index: int):
     return out
 
 
-def _gqa_queries(layer, u, config):
-    """A `gqa_attention` row's queries: [N, h] -> [N, heads, head_dim]."""
-    return dense(u, layer["wq"]).reshape(
+def _rope(x, pos, i, config):
+    """x [N, heads, head_dim] of row i at positions pos [N], turned where
+    the row carries positions (`config.layer_ropes`)."""
+    if not config.layer_ropes(i):
+        return x
+    if config.rope_interleaved:
+        return apply_rope_interleaved(x, pos, config.rope_theta)
+    return apply_rope(x[None], pos[None], config.rope_theta,
+                      config.rope_scaling)[0]
+
+
+def _gqa_queries(layer, u, config, pos, i):
+    """A plain grouped-query row's queries: [N, h] -> [N, heads, head_dim],
+    turned by position where row i is."""
+    q = dense(u, layer["wq"]).reshape(
         u.shape[0], config.n_heads, config.head_dim)
+    return _rope(q, pos, i, config)
+
+
+def _gqa_keys_values(layer, u, config, pos, i):
+    """A plain grouped-query row's K (turned as its queries are) and V."""
+    k, v = _keys_values(layer, u, config)
+    return _rope(k, pos, i, config), v
 
 
 def _gqa_out(layer, attn):
@@ -423,7 +458,7 @@ def _logits(params, x, config):
     if config.norm_type != "layernorm":
         return llama._logits(params, x, config)
     with jax.named_scope("lm_head"):
-        x = layer_norm(x, params["final_norm"], params["final_norm_b"],
+        x = layer_norm(x, params["final_norm"], params.get("final_norm_b"),
                        config.rms_norm_eps)
         return tied_head_matmul(x, params["embed"]).astype(jnp.float32)
 
@@ -477,15 +512,29 @@ def _rows_layer(layer, spec, i, x, pos, live, state, slots, page_table,
                 live[:, None, None], tail, state["conv"][j])
             mixed = _mamba2_out(layer, y, z, config)
     elif spec.kind == "gqa_attention":
-        with jax.named_scope("attention"):
+        with jax.named_scope("gqa_attention"):
             j = slots[i]
             if write:
-                k, v = _keys_values(layer, u, config)
+                k, v = _gqa_keys_values(layer, u, config, pos, i)
                 state["paged"][j] = append_token_kv(
                     state["paged"][j], k, v, page_table, pos, live, page_size)
             attn = paged_attention(
-                _gqa_queries(layer, u, config), state["paged"][j], page_table,
-                seq_lens, use_pallas=use_pallas)
+                _gqa_queries(layer, u, config, pos, i), state["paged"][j],
+                page_table, seq_lens, use_pallas=use_pallas)
+            mixed = _gqa_out(layer, attn)
+    elif spec.kind == "gqa_window_attention":
+        with jax.named_scope("window_attention"):
+            j = slots[i]
+            ring = state["window"][j]
+            R = ring_table.shape[1] * ring.shape[3]
+            k, v = _gqa_keys_values(layer, u, config, pos, i)
+            ring = append_token_kv(
+                ring, k, v, ring_table, pos % R, live, ring.shape[3])
+            state["window"][j] = ring
+            attn = paged_attention_scaled(
+                _gqa_queries(layer, u, config, pos, i), ring, ring_table,
+                jnp.minimum(seq_lens, R), _scale(config),
+                "window_attention_decode", use_pallas)
             mixed = _gqa_out(layer, attn)
     elif spec.kind == "mamba":
         with jax.named_scope("ssm"):
@@ -542,7 +591,7 @@ def _rows_layer(layer, spec, i, x, pos, live, state, slots, page_table,
                 seq_lens, _scale(config), "shared_kv_attention_decode",
                 use_pallas)
             mixed = _differential_out(layer, attn, config, i)
-    return _close(layer, spec, x, mixed, live, state, config)
+    return _close(layer, spec, x, mixed, live, state, config, u)
 
 
 def _copy_state(state) -> dict:
@@ -585,6 +634,12 @@ def _ring_runs(q_start, q_len, kv_start, R: int):
     return [(lanes, q_start + skipped, to_end, first),
             (lanes, q_start + skipped + to_end, n - to_end,
              jnp.zeros_like(first))]
+
+
+def _ring_kept(token_pos, lane, q_len, kv_start, R: int):
+    """[T]: of a slice longer than the ring only the newest R tokens are
+    kept (the others would collide with them)."""
+    return token_pos >= (kv_start + q_len)[lane] - R
 
 
 def forward_ragged(params, config, tokens, token_seq, token_pos, q_start,
@@ -639,16 +694,34 @@ def forward_ragged(params, config, tokens, token_seq, token_pos, q_start,
                     has_slice[:, None, None], tail, state["conv"][j])
                 mixed = _mamba2_out(layer, y, z, config)
         elif spec.kind == "gqa_attention" and i != last_writer:
-            with jax.named_scope("attention"):
+            with jax.named_scope("gqa_attention"):
                 j = slots[i]
-                k, v = _keys_values(layer, u, config)
+                k, v = _gqa_keys_values(layer, u, config, token_pos, i)
                 state["paged"][j] = write_ragged_kv(
                     state["paged"][j], k, v, page_table, token_seq, token_pos,
                     page_size, runs=slice_runs(q_start, q_len, kv_start))
                 attn = ragged_paged_attention(
-                    _gqa_queries(layer, u, config), state["paged"][j],
-                    page_table, q_start, q_len, kv_start,
+                    _gqa_queries(layer, u, config, token_pos, i),
+                    state["paged"][j], page_table, q_start, q_len, kv_start,
                     use_pallas=use_pallas)
+                mixed = _gqa_out(layer, attn)
+        elif spec.kind == "gqa_window_attention":
+            with jax.named_scope("window_attention"):
+                j = slots[i]
+                ring = state["window"][j]
+                ps = ring.shape[3]
+                R = ring_table.shape[1] * ps
+                k, v = _gqa_keys_values(layer, u, config, token_pos, i)
+                attn = window_attention_ragged(
+                    _gqa_queries(layer, u, config, token_pos, i), k, v, ring,
+                    ring_table, token_seq, token_pos, q_start, q_len,
+                    kv_start, _scale(config), block, use_pallas)
+                state["window"][j] = write_ragged_kv(
+                    ring, k, v, ring_table,
+                    jnp.where(_ring_kept(token_pos, lane, q_len, kv_start, R),
+                              token_seq, -1),
+                    token_pos % R, ps,
+                    runs=_ring_runs(q_start, q_len, kv_start, R))
                 mixed = _gqa_out(layer, attn)
         elif spec.kind == "mamba":
             with jax.named_scope("ssm"):
@@ -685,22 +758,25 @@ def forward_ragged(params, config, tokens, token_seq, token_pos, q_start,
                 ps = ring.shape[3]
                 R = ring_table.shape[1] * ps
                 k, v = _keys_values(layer, u, config)
-                attn = ring_window_attention_ragged(
+                attn = window_attention_ragged(
                     _queries(layer, u, config), k, v, ring, ring_table,
-                    token_seq, token_pos, kv_start, _scale(config), block)
-                # of a slice longer than the ring only the newest R tokens
-                # are kept (the others would collide with them)
-                kept = token_pos >= (kv_start + q_len)[lane] - R
+                    token_seq, token_pos, q_start, q_len, kv_start,
+                    _scale(config), block, use_pallas)
                 state["window"][j] = write_ragged_kv(
                     ring, k, v, ring_table,
-                    jnp.where(kept, token_seq, -1), token_pos % R, ps,
+                    jnp.where(_ring_kept(token_pos, lane, q_len, kv_start, R),
+                              token_seq, -1),
+                    token_pos % R, ps,
                     runs=_ring_runs(q_start, q_len, kv_start, R))
                 mixed = _differential_out(layer, attn, config, i)
         elif spec.kind in ("attention", "gqa_attention") and i == last_writer:
             # K/V of every token go to the pool; the attention's own output
             # feeds only layers behind it, so it is taken at the sampled rows
             with jax.named_scope("shared_kv_attention"):
-                k, v = _keys_values(layer, u, config)
+                if spec.kind == "gqa_attention":
+                    k, v = _gqa_keys_values(layer, u, config, token_pos, i)
+                else:
+                    k, v = _keys_values(layer, u, config)
                 j = slots[i]
                 state["paged"][j] = write_ragged_kv(
                     state["paged"][j], k, v, page_table, token_seq, token_pos,
@@ -715,7 +791,7 @@ def forward_ragged(params, config, tokens, token_seq, token_pos, q_start,
             raise NotImplementedError(
                 f"layer {i} ({spec.kind}) in the packed forward before the "
                 "last layer that writes state")
-        x = _close(layer, spec, x, mixed, token_seq >= 0, state, config)
+        x = _close(layer, spec, x, mixed, token_seq >= 0, state, config, u)
         if i == last_writer:
             x = x[last_idx]
             handed = {key: m[last_idx] for key, m in handed.items()}

@@ -70,13 +70,17 @@ def _map_hidden_act(act) -> str:
 #: table (models/hybrid.py) `attention`, `window_attention` and
 #: `cross_attention` are the first hybrid family's differential attention
 #: (`LlamaConfig.diff_attention`: a pair of heads a cache row) and
-#: `gqa_attention` is plain grouped-query attention over the pool's pages
+#: `gqa_attention` is plain grouped-query attention over the pool's pages,
+#: `gqa_window_attention` the same over the last `sliding_window` tokens,
+#: kept in a ring
 MIXER_KINDS = ("attention", "window_attention", "cross_attention", "mamba",
-               "gmu", "latent_attention", "mamba2", "ffn", "gqa_attention")
+               "gmu", "latent_attention", "mamba2", "ffn", "gqa_attention",
+               "gqa_window_attention")
 _WRITES = {"attention": "paged_kv", "window_attention": "window_kv",
            "cross_attention": "none", "mamba": "recurrent", "gmu": "none",
            "latent_attention": "latent_kv", "mamba2": "recurrent",
-           "ffn": "none", "gqa_attention": "paged_kv"}
+           "ffn": "none", "gqa_attention": "paged_kv",
+           "gqa_window_attention": "window_kv"}
 
 #: model_type values LlamaConfig's family knobs describe
 _LLAMA_MODEL_TYPES = (None, "llama", "mistral", "mixtral", "qwen2", "qwen3",
@@ -177,7 +181,16 @@ class LlamaConfig:
     # mixer kind per layer (MIXER_KINDS); None = every layer "attention"
     mixer_kinds: Optional[Tuple[str, ...]] = None
     norm_type: str = "rmsnorm"  # "layernorm": weight and bias, eps = rms_norm_eps
+    norm_bias: bool = True  # False: a layernorm of a weight alone (Cohere)
     use_rope: bool = True
+    # a hybrid table's plain grouped-query rows (models/hybrid.py): rotary
+    # turns columns (2j, 2j+1) (`rope_gptj`), not (j, j + d/2); only the
+    # window rows carry positions, the full rows none
+    rope_interleaved: bool = False
+    rope_window_rows_only: bool = False
+    # the mixer and the feed-forward read ONE norm of h and meet in ONE
+    # residual: h' = h + Mixer(u) + FFN(u), u = norm(h)
+    parallel_block: bool = False
     attention_out_bias: bool = False
     # differential attention (arXiv:2410.05258): heads pair up (2j, 2j+1)
     diff_attention: bool = False
@@ -211,6 +224,9 @@ class LlamaConfig:
     norm_topk_prob: bool = True
     moe_form: str = "gated"  # "relu2": down(relu(up x)^2), no gate matrix
     moe_shared_intermediate_size: int = 0  # 0 = an expert's width
+    # several shared experts: their outputs averaged (True) or summed
+    moe_shared_average: bool = False
+    moe_router_bias: bool = True  # sigmoid router: a choice-only bias
     # this chip's share of the n_experts the router scores: experts
     # first_expert .. first_expert + n_experts_held - 1; 0 held = all
     n_experts_held: int = 0
@@ -242,7 +258,8 @@ class LlamaConfig:
                 raise ValueError(
                     f"mixer_kinds: {self.n_layers} entries of {MIXER_KINDS} "
                     f"expected, got {len(self.mixer_kinds)} with {unknown}")
-            if self.diff_attention and "gqa_attention" in self.mixer_kinds:
+            if self.diff_attention and {
+                    "gqa_attention", "gqa_window_attention"} & set(self.mixer_kinds):
                 raise ValueError(
                     "gqa_attention rows in a model whose cache rows hold "
                     "differential pairs (diff_attention)")
@@ -335,6 +352,14 @@ class LlamaConfig:
     @property
     def cache_head_dim(self) -> int:
         return 2 * self.head_dim if self.diff_attention else self.head_dim
+
+    def layer_ropes(self, i: int) -> bool:
+        """Whether a hybrid table's plain grouped-query row i turns its
+        queries and keys by position."""
+        if not self.use_rope:
+            return False
+        return (not self.rope_window_rows_only
+                or self.mixer_kinds[i] == "gqa_window_attention")
 
     def layer_window(self, i: int) -> int:
         """Sliding-window width for layer i (0 = full attention)."""
@@ -459,6 +484,8 @@ class LlamaConfig:
             return _glm4_moe_lite_config(cfg)
         if model_type == "nemotron_h":
             return _nemotron_h_config(cfg)
+        if model_type == "cohere2_moe":
+            return _cohere2_moe_config(cfg)
         if model_type not in _LLAMA_MODEL_TYPES:
             foreign = [k for k in _FOREIGN_MIXER_KEYS if k in cfg
                        and not (k == "total_ut_steps" and int(cfg[k]) <= 1)]
@@ -600,7 +627,12 @@ def _glm4_moe_lite_config(cfg: dict) -> LlamaConfig:
     if not cfg.get("q_lora_rank"):
         refused.append("q_lora_rank null (queries without the bottleneck)")
     if int(cfg.get("n_shared_experts", 1)) > 1:
-        refused.append(f"n_shared_experts={cfg['n_shared_experts']}")
+        # models/moe.py runs several shared experts as one fused MLP and a
+        # factor (the Cohere family's four, averaged); this family's would
+        # be their sum, which its reference does not compute yet
+        refused.append(f"n_shared_experts={cfg['n_shared_experts']} (held "
+                       "to no reference for this family: benchmark/"
+                       "reference/glm4_moe_lite.py computes one)")
     if refused:
         raise ValueError(
             "glm4_moe_lite: not implemented: " + "; ".join(refused))
@@ -674,7 +706,12 @@ def _nemotron_h_config(cfg: dict) -> LlamaConfig:
     if cfg.get("mamba_hidden_act", "silu") != "silu":
         refused.append(f"mamba_hidden_act={cfg['mamba_hidden_act']!r}")
     if int(cfg.get("n_shared_experts", 1)) > 1:
-        refused.append(f"n_shared_experts={cfg['n_shared_experts']}")
+        # as above: the family states ONE shared width
+        # (moe_shared_expert_intermediate_size); how several would split or
+        # combine it is not published
+        refused.append(f"n_shared_experts={cfg['n_shared_experts']} (the "
+                       "family states one shared width; how several combine "
+                       "is not published)")
     if cfg.get("residual_in_fp32"):
         refused.append("residual_in_fp32")
     heads, groups = cfg["mamba_num_heads"], cfg["n_groups"]
@@ -717,6 +754,97 @@ def _nemotron_h_config(cfg: dict) -> LlamaConfig:
         moe_router="sigmoid",
         moe_form="relu2",
         routed_scaling_factor=float(cfg.get("routed_scaling_factor", 1.0)),
+        norm_topk_prob=bool(cfg.get("norm_topk_prob", True)),
+    )
+
+
+def _cohere2_moe_config(cfg: dict) -> LlamaConfig:
+    """config.json of `model_type: cohere2_moe` (Command A+): every layer ONE
+    bias-free LayerNorm read by the attention and by the experts alike, one
+    residual (`use_parallel_block`); `layer_types` says which rows attend
+    over the last `sliding_window` tokens with interleaved rotary
+    (`rope_gptj`) and which over the whole context with no positions; the
+    feed-forward is routed experts (sigmoid scores, no choice bias, weights
+    normalised over the chosen) beside `num_shared_experts` shared ones
+    whose outputs are averaged.  `intermediate_size` is one expert's width,
+    routed and shared.  What the published file leaves to the modeling file
+    is listed under `assumed` in benchmark/configs/command-a-plus.json.  A
+    deployment that holds a chip's share of the experts says so as the
+    Nemotron family does: `num_experts` counts the experts HELD here,
+    `router_n_experts` the experts the router scores (the published count),
+    `first_expert` the first one held."""
+    n_layers = cfg["num_hidden_layers"]
+    kinds = cfg.get("layer_types") or ()
+    refused = []
+    if len(kinds) != n_layers or set(kinds) - {
+            "sliding_attention", "full_attention"}:
+        refused.append(f"layer_types of {len(kinds)} entries "
+                       f"{sorted(set(kinds))} for num_hidden_layers={n_layers}")
+    if not cfg.get("use_parallel_block", True):
+        refused.append("use_parallel_block false")
+    if int(cfg.get("first_k_dense_replace", 0)):
+        refused.append(f"first_k_dense_replace={cfg['first_k_dense_replace']} "
+                       "(the prefix_dense_* layers)")
+    if cfg.get("position_embedding_type", "rope_gptj") != "rope_gptj":
+        refused.append(
+            f"position_embedding_type={cfg['position_embedding_type']!r}")
+    if float(cfg.get("rotary_pct", 1)) != 1:
+        refused.append(f"rotary_pct={cfg['rotary_pct']}")
+    rope = cfg.get("rope_parameters") or {}
+    if rope.get("rope_type", "default") != "default" or cfg.get("rope_scaling"):
+        refused.append("rope scaling")
+    if cfg.get("expert_selection_fn", "sigmoid") != "sigmoid":
+        refused.append(f"expert_selection_fn={cfg['expert_selection_fn']!r}")
+    if cfg.get("shared_expert_combination_strategy", "average") not in (
+            "average", "sum"):
+        refused.append("shared_expert_combination_strategy="
+                       f"{cfg['shared_expert_combination_strategy']!r}")
+    if not cfg.get("use_gated_activation", True):
+        refused.append("use_gated_activation false")
+    for key in ("attention_bias", "use_qk_norm", "use_parallel_embedding"):
+        if cfg.get(key):
+            refused.append(f"{key} true")
+    if float(cfg.get("logit_scale", 1)) != 1:
+        refused.append(f"logit_scale={cfg['logit_scale']}")
+    if not cfg.get("tie_word_embeddings", True):
+        refused.append("tie_word_embeddings false")
+    if not cfg.get("sliding_window"):
+        refused.append("sliding_window absent")
+    if refused:
+        raise ValueError("cohere2_moe: not implemented: " + "; ".join(refused))
+    held = cfg["num_experts"]
+    scored = int(cfg.get("router_n_experts", held))
+    return LlamaConfig(
+        vocab_size=cfg["vocab_size"],
+        hidden_size=cfg["hidden_size"],
+        intermediate_size=cfg["intermediate_size"],
+        n_layers=n_layers,
+        n_heads=cfg["num_attention_heads"],
+        n_kv_heads=cfg["num_key_value_heads"],
+        head_dim=cfg.get("head_dim"),
+        rope_theta=float(rope.get("rope_theta", cfg.get("rope_theta", 10000.0))),
+        rms_norm_eps=cfg.get("layer_norm_eps", 1e-5),
+        max_position_embeddings=cfg.get("max_position_embeddings", 4096),
+        tie_word_embeddings=True,
+        hidden_act=_map_hidden_act(cfg.get("hidden_act")),
+        sliding_window=cfg["sliding_window"],
+        mixer_kinds=tuple(
+            "gqa_window_attention" if kind == "sliding_attention"
+            else "gqa_attention" for kind in kinds),
+        norm_type="layernorm",
+        norm_bias=False,
+        rope_interleaved=True,
+        rope_window_rows_only=True,
+        parallel_block=True,
+        n_experts=scored,
+        n_experts_held=0 if held == scored else held,
+        first_expert=int(cfg.get("first_expert", 0)),
+        n_experts_per_tok=cfg["num_experts_per_tok"],
+        n_shared_experts=int(cfg.get("num_shared_experts", 0)),
+        moe_shared_average=cfg.get(
+            "shared_expert_combination_strategy", "average") == "average",
+        moe_router="sigmoid",
+        moe_router_bias=False,
         norm_topk_prob=bool(cfg.get("norm_topk_prob", True)),
     )
 

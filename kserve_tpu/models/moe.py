@@ -14,7 +14,11 @@ Two forms of an expert (`MoEConfig.form`): `gated`, `down(silu(gate x) *
 up x)`, three matrices; `relu2`, `down(relu(up x)^2)`, two (the
 Nemotron-H family).  A `shared` expert of the same form, where the model
 has one, takes every token beside the routed ones, at a width of its own
-where `shared_intermediate_size` says so.
+where `shared_intermediate_size` says so.  SEVERAL shared experts
+(`n_shared`) are ONE fused MLP and a factor: expert s is columns [s w,
+(s + 1) w) of `shared_gate` / `shared_up` and the same rows of
+`shared_down`, so the one matmul's result is their sum, and their average
+(`shared_average`, the Cohere family) is that sum over `n_shared`.
 
 ONE compute path for every router, packed step and decode alike
 (`routed_experts`): the (token, expert) pairs that were routed are sorted
@@ -64,6 +68,9 @@ class MoEConfig:
     norm_topk: bool = True  # sigmoid router: weights renormalised to sum 1
     scale: float = 1.0  # sigmoid router: routed_scaling_factor
     shared: bool = False  # a shared expert beside the routed ones
+    n_shared: int = 1  # how many: one fused MLP of n_shared x the width
+    shared_average: bool = False  # their outputs averaged, not summed
+    router_bias: bool = True  # sigmoid router: a choice-only bias
     form: str = "gated"  # or "relu2": down(relu(up x)^2), no gate matrix
     shared_intermediate_size: int = 0  # the shared expert's width; 0 = an expert's
     # this chip's share: experts first_expert .. first_expert + held - 1 of
@@ -101,6 +108,9 @@ def moe_config_of(config) -> MoEConfig:
         norm_topk=config.norm_topk_prob,
         scale=config.routed_scaling_factor,
         shared=config.n_shared_experts > 0,
+        n_shared=max(1, config.n_shared_experts),
+        shared_average=config.moe_shared_average,
+        router_bias=config.moe_router_bias,
         form=config.moe_form,
         shared_intermediate_size=config.moe_shared_intermediate_size,
         first_expert=config.first_expert,
@@ -137,10 +147,11 @@ def moe_param_shapes(config: MoEConfig) -> Dict[str, tuple]:
     f = stored_width(config.intermediate_size)
     held = config.n_held
     shapes = {"router": (h, E), "w_up": (held, h, f), "w_down": (held, f, h)}
-    if config.router == "sigmoid":
+    if config.router == "sigmoid" and config.router_bias:
         shapes["router_bias"] = (E,)
     if config.shared:
-        fs = config.shared_intermediate_size or config.intermediate_size
+        fs = config.n_shared * (
+            config.shared_intermediate_size or config.intermediate_size)
         shapes.update({"shared_up": (h, fs), "shared_down": (fs, h)})
     if config.form == "gated":
         shapes["w_gate"] = (held, h, f)
@@ -190,8 +201,10 @@ def route(params: Dict[str, Any], x: jnp.ndarray,
     if config.router != "sigmoid":
         raise ValueError(f"unknown router {config.router!r}")
     scores = jax.nn.sigmoid(logits)
-    _, selected = jax.lax.top_k(
-        scores + params["router_bias"].astype(jnp.float32), config.top_k)
+    chooser = scores
+    if config.router_bias:
+        chooser = scores + params["router_bias"].astype(jnp.float32)
+    _, selected = jax.lax.top_k(chooser, config.top_k)
     weights = jnp.take_along_axis(scores, selected, axis=-1)
     if config.norm_topk:
         weights = weights / (weights.sum(axis=-1, keepdims=True) + 1e-20)
@@ -256,9 +269,11 @@ def routed_experts(params: Dict[str, Any], x: jnp.ndarray,
     return jnp.einsum("nkh,nk->nh", y, weights.astype(jnp.float32)), rows
 
 
-@jax.named_scope("shared_expert")
+@jax.named_scope("shared_experts")
 def shared_expert(params: Dict[str, Any], x: jnp.ndarray,
                   form: str = "gated") -> jnp.ndarray:
+    """The shared experts' SUM over x [N, h]: one MLP over their fused
+    columns (`moe_param_shapes`)."""
     if form == "gated":
         gate = jax.nn.silu(dense(x, params["shared_gate"]))
         act = gate * dense(x, params["shared_up"])
@@ -281,7 +296,10 @@ def moe_mlp(params: Dict[str, Any], x: jnp.ndarray, config: MoEConfig,
         None if config.holds_all else (config.first_expert, config.n_held),
         config.form)
     if config.shared:
-        out = out + shared_expert(params, flat, config.form).astype(jnp.float32)
+        shared = shared_expert(params, flat, config.form).astype(jnp.float32)
+        if config.shared_average:
+            shared = shared / config.n_shared
+        out = out + shared
     out = out.astype(x.dtype).reshape(lead + (h,))
     return (out, rows) if with_rows else out
 

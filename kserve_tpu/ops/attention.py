@@ -583,6 +583,21 @@ def make_sharded_ragged_attention(
     )
 
 
+def _window_mixed_name(model_config, engine_config, backend: str) -> str:
+    """How the packed step's window attention of a model with rings runs:
+    `_should_use_window_pallas` at the model's sizes."""
+    from .pallas_paged_attention import RAGGED_BQ
+
+    mc, cfg = model_config, engine_config
+    if cfg.use_pallas is None:
+        kernel = _should_use_window_pallas(
+            mc.cache_head_dim, mc.n_heads, mc.cache_kv_heads,
+            mc.sliding_window, RAGGED_BQ, backend)
+    else:
+        kernel = bool(cfg.use_pallas)
+    return "pallas_window_ragged" if kernel else "xla_ring_window"
+
+
 def describe_attention_dispatch(model_config, engine_config,
                                 backend: str) -> dict:
     """Which implementation each program's attention is built with, from
@@ -628,6 +643,7 @@ def describe_attention_dispatch(model_config, engine_config,
         # every read of a cache is one query per lane (the
         # decode kernel or its gather, gated as below at the cache's row
         # width); a window layer's packed slice is the XLA ring attention
+        # or the window kernel, by `_should_use_window_pallas`
         if cfg.use_pallas is None:
             decode = _should_use_pallas(
                 mc.cache_head_dim, False, cfg.max_pages_per_seq,
@@ -640,7 +656,7 @@ def describe_attention_dispatch(model_config, engine_config,
             decode = bool(cfg.use_pallas)
         return {
             "backend": backend,
-            "mixed": "xla_ring_window+" + (
+            "mixed": _window_mixed_name(mc, cfg, backend) + "+" + (
                 "pallas_decode" if decode else "xla_gather"),
             "decode": "pallas_decode" if decode else "xla_gather",
             "decode_pallas_min_pages": min_pages,
@@ -651,8 +667,9 @@ def describe_attention_dispatch(model_config, engine_config,
         ragged = _should_use_ragged_pallas(mc.head_dim, backend, quantized)
         kv_heads = mc.n_kv_heads // cfg.tp  # one device's
         # the widest table this replica compiles: is the kernel built at all
+        # a hybrid table's window rows keep rings: its full rows have none
         decode = (
-            mc.sliding_window <= 0 and mc.attn_scale is None
+            (mc.is_hybrid or mc.sliding_window <= 0) and mc.attn_scale is None
             and _should_use_pallas(
                 mc.head_dim, quantized, cfg.max_pages_per_seq,
                 cfg.max_batch_size, backend, cfg.page_size, kv_heads,
@@ -663,9 +680,12 @@ def describe_attention_dispatch(model_config, engine_config,
                 cfg.max_batch_size, cfg.num_pages) or None
     else:
         ragged = decode = bool(cfg.use_pallas)
+    mixed = "pallas_ragged" if ragged else "xla_ragged_gather"
+    if "window_kv" in written:  # plain window rows beside the paged ones
+        mixed = _window_mixed_name(mc, cfg, backend) + "+" + mixed
     return {
         "backend": backend,
-        "mixed": "pallas_ragged" if ragged else "xla_ragged_gather",
+        "mixed": mixed,
         "decode": "pallas_decode" if decode else "xla_gather",
         "decode_pallas_min_pages": min_pages,
         # tp/sp>1: both run per shard inside shard_map over the model axis
@@ -830,6 +850,67 @@ def ring_window_attention_ragged(
     out = out + jnp.einsum("nkgbs,skd->nbkgd", weights[..., R:], v_new,
                            preferred_element_type=f32)
     return out.reshape(T, nq, d).astype(q.dtype)
+
+
+#: what the XLA window attention builds for ONE block of queries, in bytes
+#: (the lane's gathered ring and the block's float32 scores over it), from
+#: which the packed step's window attention runs as the kernel
+WINDOW_XLA_MAX_BLOCK_BYTES = 8 << 20
+
+
+def _should_use_window_pallas(d: int, nq: int, nkv: int, ring_tokens: int,
+                              block: int, backend: str,
+                              itemsize: int = 2) -> bool:
+    """Whether the packed step's window attention runs as the kernel
+    (ops/pallas_paged_attention.window_attention_ragged_pallas) or as
+    `ring_window_attention_ragged`: the ONE predicate, from sizes.  The XLA
+    form gathers a lane's whole ring once for every block of queries and
+    builds the block's scores over it in HBM: [blocks, ring] arrays, small
+    at a window of 512 (3.3 MB a block at 40 query heads over 10 rows of
+    128: the Phi-4-flash cell, measured on this path and kept on it) and
+    past the chip's memory at 4096 x 128 heads (33.5 MB a block, 8.6 GB at
+    2048 tokens).  The kernel needs rows of whole 128-lane tiles, blocks of
+    whole sublane tiles and a TPU; where the two meet has not been
+    measured, so the bound sits between the two shapes that exist."""
+    if backend != "tpu" or d % 128 or block % 8:
+        return False
+    block_bytes = ring_tokens * (2 * nkv * d * itemsize + nq * block * 4)
+    return block_bytes > WINDOW_XLA_MAX_BLOCK_BYTES
+
+
+def window_attention_ragged(
+    q: jnp.ndarray,  # [T, nq, d] packed queries
+    k_new: jnp.ndarray,  # [T, nkv, d] the buffer's own keys (not yet in the ring)
+    v_new: jnp.ndarray,  # [T, nkv, d]
+    ring_pages: jnp.ndarray,  # [pages, 2, nkv, ps, d] as it was BEFORE this buffer
+    ring_table: jnp.ndarray,  # [B, Wr]
+    token_seq: jnp.ndarray,  # [T]
+    token_pos: jnp.ndarray,  # [T]
+    q_start: jnp.ndarray,  # [B]
+    q_len: jnp.ndarray,  # [B]
+    kv_start: jnp.ndarray,  # [B]
+    scale: float,
+    block: int,
+    use_pallas: Optional[bool] = None,
+) -> jnp.ndarray:
+    """The packed step's window attention over (ring, the buffer's own
+    slice): the kernel where `_should_use_window_pallas` says so (or
+    `use_pallas` forces it), else the XLA form, which is also its oracle."""
+    if use_pallas is None:
+        _, nq, d = q.shape
+        use_pallas = _should_use_window_pallas(
+            d, nq, int(k_new.shape[1]),
+            int(ring_table.shape[1]) * int(ring_pages.shape[3]), block,
+            jax.default_backend(), ring_pages.dtype.itemsize)
+    if use_pallas:
+        from .pallas_paged_attention import window_attention_ragged_pallas
+
+        return window_attention_ragged_pallas(
+            q, k_new, v_new, ring_pages, ring_table, q_start, q_len,
+            kv_start, scale, block)
+    return ring_window_attention_ragged(
+        q, k_new, v_new, ring_pages, ring_table, token_seq, token_pos,
+        kv_start, scale, block)
 
 
 # ---------------- latent pages (models/latent.py) ----------------

@@ -24,10 +24,14 @@ def rms_norm_plus_one(x: jnp.ndarray, weight: jnp.ndarray,
     return (normed * (1.0 + weight.astype(jnp.float32))).astype(dtype)
 
 
-def layer_norm(x: jnp.ndarray, weight: jnp.ndarray, bias: jnp.ndarray, eps: float = 1e-12) -> jnp.ndarray:
+def layer_norm(x: jnp.ndarray, weight: jnp.ndarray, bias, eps: float = 1e-12) -> jnp.ndarray:
+    """`bias` None: a LayerNorm of a weight alone (the Cohere family's)."""
     dtype = x.dtype
     x32 = x.astype(jnp.float32)
     mean = x32.mean(axis=-1, keepdims=True)
     var = jnp.var(x32, axis=-1, keepdims=True)
     normed = (x32 - mean) * jnp.reciprocal(jnp.sqrt(var + eps))
-    return (normed * weight.astype(jnp.float32) + bias.astype(jnp.float32)).astype(dtype)
+    out = normed * weight.astype(jnp.float32)
+    if bias is not None:
+        out = out + bias.astype(jnp.float32)
+    return out.astype(dtype)

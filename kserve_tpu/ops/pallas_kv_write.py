@@ -40,6 +40,8 @@ BEHIND = 4  # page writes still in flight behind it
 NBUF = AHEAD + BEHIND  # VMEM ring: a slot is refilled once its write is done
 
 _HBM = pltpu.MemorySpace.HBM
+VMEM_DEFAULT_BYTES = 16 << 20  # the compiler's scoped limit for one call
+VMEM_SLACK_BYTES = 4 << 20  # its own temporaries beside what the call names
 
 
 def _kv_page_write_kernel(
@@ -151,6 +153,14 @@ def kv_page_write(
     start = src - lo + page_size
     # k/v and head as one dim of planes (a bitcast: the tiled dims stay)
     planes = kv_pages.reshape(num_pages, 2 * n_kv, page_size, d)
+    # the new rows lie in VMEM whole: past the compiler's default scoped
+    # limit (16 MB) for a long buffer of many heads (4096 tokens x 8 K/V
+    # heads x 128: 35 MB of the chip's 128), where the call states its need
+    need = rows.size * 4 + NBUF * planes[0].size * planes.dtype.itemsize
+    params = {}
+    if need > VMEM_DEFAULT_BYTES - VMEM_SLACK_BYTES:
+        params["compiler_params"] = pltpu.CompilerParams(
+            vmem_limit_bytes=need + VMEM_SLACK_BYTES)
     return pl.pallas_call(
         _kv_page_write_kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -172,6 +182,7 @@ def kv_page_write(
         input_output_aliases={5: 0},
         interpret=interpret,
         name="kv_page_write",
+        **params,
     )(page, lo, hi, start, rows, planes).reshape(kv_pages.shape)
 
 

@@ -981,3 +981,256 @@ def latent_attention_ragged_pallas(
         name="latent_attention_ragged",
     )(block_seq, block_qoff, page_table, kv_start, q_len,
       jnp.zeros((1,), jnp.int32), q, pages)
+
+
+# ---------------- window rings (models/hybrid.py, plain grouped-query rows) ----------------
+#
+# The packed step's attention of a window layer that keeps a RING a lane
+# (engine/kvcache.StateLayout, `window_kv`).  The ring holds what the lane
+# had BEFORE this buffer; the buffer's own keys are not in it yet (a slice
+# overwrites slots its own first queries still need), so a block of queries
+# reads two sources through one DMA ring: its lane's ring pages, then the
+# pages of the buffer itself that cover the lane's slice.  Keys are turned
+# by position before they are stored, so the softmax needs no order among
+# them: the mask alone needs each slot's position.
+#
+# Slot s of a ring of R = Wr x ps slots holds the newest position below
+# kv_start that is congruent to s modulo R, so position p lies on ring page
+# (p // ps) % Wr: a block walks the pages of positions [lo, kv_start) with
+# lo the oldest position its FIRST query still sees, and no others.  Work
+# and bytes a query are those of its window, whatever the context.
+
+WINDOW_BQ = 32  # query tokens a grid step: shares a lane's pages among them
+#: the step's accumulators, scores and page ring (~12 MB at 128 query heads
+#: of 128) pass the compiler's default scoped limit of 16 MB with its own
+#: temporaries; the chip has 128 MB
+WINDOW_VMEM_BYTES = 64 << 20
+
+
+def _window_ragged_kernel(
+    # scalar prefetch (SMEM)
+    block_seq_ref,  # [G * nsub] int32: the lane of each `bq`-token block (-1 = pad)
+    block_qoff_ref,  # [G * nsub] int32: the block's first query's offset in its slice
+    ring_table_ref,  # [B, Wr] int32
+    q_start_ref,  # [B] int32
+    q_len_ref,  # [B] int32
+    kv_start_ref,  # [B] int32
+    # inputs
+    q_ref,  # [nkv, nsub * bq * group, d] VMEM: row (t, j) is token t, head j of the group
+    ring_hbm_ref,  # [pages, 2, nkv, ps, d] in HBM
+    buf_hbm_ref,  # [T / ps, 2, nkv, ps, d] in HBM: the buffer's own K/V as pages
+    # output
+    out_ref,  # [nkv, nsub * bq * group, d] VMEM
+    # scratch
+    kv_bufs,  # [NBUF, 2, nkv, 2 ps, d] VMEM ring: two pages a slot
+    sems,  # DMA semaphores [NBUF, 2]
+    *,
+    nsub: int,
+    bq: int,
+    group: int,
+    page_size: int,
+    ring_width: int,
+    scale: float,
+):
+    g = pl.program_id(0)
+    ps, Wr = page_size, ring_width
+    R = Wr * ps
+    nkv = q_ref.shape[0]
+    d = q_ref.shape[2]
+
+    def attend(q, s_raw, qoff, ntok: int):
+        """`ntok` tokens of ONE lane (q [nkv, ntok * group, d]) over the
+        lane's ring and its slice of the buffer -> [nkv, ntok * group, d]."""
+        rows = ntok * group
+        s = jnp.maximum(s_raw, 0)
+        kv0, qn, qs = kv_start_ref[s], q_len_ref[s], q_start_ref[s]
+        live = (s_raw >= 0) & (qoff < qn)
+        # ring pages: positions [lo, kv0), lo the oldest the first query sees
+        lo = jnp.maximum(kv0 + qoff + 1 - R, 0)
+        u0 = lo // ps
+        n_ring = jnp.where(live & (kv0 > lo), (kv0 - 1) // ps - u0 + 1, 0)
+        # buffer pages: indices [b_lo, t1] of the packed buffer
+        t0 = qs + qoff
+        t1 = t0 + jnp.minimum(qn - qoff, ntok) - 1
+        v0 = jnp.maximum(qs, t0 + 1 - R) // ps
+        n_buf = jnp.where(live, t1 // ps - v0 + 1, 0)
+        n_pages = n_ring + n_buf
+
+        # TWO pages an iteration, side by side in one slot: 128 keys fill the
+        # lanes of the scores' tiles and the MXU's columns, which 64 leave
+        # half empty
+        n_iters = (n_pages + 1) // 2
+
+        def start_iter(i, slot):
+            for half in range(2):
+                vp = 2 * i + half
+                into = kv_bufs.at[slot, :, :, pl.ds(half * ps, ps), :]
+                sem = sems.at[slot, half]
+
+                @pl.when(vp < n_ring)
+                def _(vp=vp, into=into, sem=sem):
+                    page = ring_table_ref[s, jax.lax.rem(u0 + vp, Wr)]
+                    pltpu.make_async_copy(
+                        ring_hbm_ref.at[page], into, sem).start()
+
+                @pl.when((vp >= n_ring) & (vp < n_pages))
+                def _(vp=vp, into=into, sem=sem):
+                    pltpu.make_async_copy(
+                        buf_hbm_ref.at[v0 + vp - n_ring], into, sem).start()
+
+                # an odd count's last half: the null page, masked out, so
+                # that no byte the kernel multiplies is one nobody wrote
+                @pl.when(vp >= n_pages)
+                def _(into=into, sem=sem):
+                    pltpu.make_async_copy(
+                        ring_hbm_ref.at[0], into, sem).start()
+
+        for j in range(NBUF - 1):
+            @pl.when(j < n_iters)
+            def _(j=j):
+                start_iter(j, j)
+
+        rowq = jax.lax.broadcasted_iota(jnp.int32, (1, rows, 1), 1) // group
+        qpos = kv0 + qoff + rowq  # absolute position per query row
+        qvalid = ((qoff + rowq) < qn) & (s_raw >= 0)
+        col = jax.lax.broadcasted_iota(jnp.int32, (1, 1, 2 * ps), 2)
+        half_of, within = col // ps, jax.lax.rem(col, ps)
+
+        def body(i, carry):
+            m, l, acc = carry
+            slot = jax.lax.rem(i, NBUF)
+            for half in range(2):
+                pltpu.make_async_copy(
+                    ring_hbm_ref.at[0],
+                    kv_bufs.at[slot, :, :, pl.ds(half * ps, ps), :],
+                    sems.at[slot, half]).wait()
+
+            @pl.when(i + NBUF - 1 < n_iters)
+            def _():
+                start_iter(i + NBUF - 1, jax.lax.rem(i + NBUF - 1, NBUF))
+
+            k = kv_bufs[slot, 0]  # [nkv, 2 ps, d], the cache's dtype
+            v = kv_bufs[slot, 1]
+            s_ = _heads_dot(q, k, 2) * scale  # [nkv, rows, 2 ps] float32
+            vp = 2 * i + half_of  # each column's page of the walk
+            in_ring = vp < n_ring
+            tk = (v0 + vp - n_ring) * ps + within  # its index in the buffer
+            kpos = jnp.where(in_ring, (u0 + vp) * ps + within, kv0 + tk - qs)
+            # a ring page's slots hold positions below kv0 (those at and
+            # above it are stale), a buffer page's rows positions from kv0
+            # on (those below it are another lane's): bounds as numbers,
+            # since Mosaic selects no vector of booleans
+            below = jnp.where(in_ring, kv0, jnp.int32(2 ** 30))
+            from_ = jnp.where(in_ring, 0, kv0)
+            mask = ((kpos < below) & (kpos >= from_) & (vp < n_pages)
+                    & (kpos <= qpos) & (kpos > qpos - R) & qvalid)
+            s_ = jnp.where(mask, s_, -1e30)
+            m_new = jnp.maximum(m, s_.max(axis=-1, keepdims=True))
+            alpha = jnp.exp(m - m_new)
+            p = jnp.exp(s_ - m_new)
+            l_new = l * alpha + p.sum(axis=-1, keepdims=True)
+            pv = _heads_dot(p.astype(v.dtype), v, 1)  # [nkv, rows, d]
+            return m_new, l_new, acc * alpha + pv
+
+        m0 = jnp.full((nkv, rows, 1), -1e30, jnp.float32)
+        l0 = jnp.zeros((nkv, rows, 1), jnp.float32)
+        acc0 = jnp.zeros((nkv, rows, d), jnp.float32)
+        m, l, acc = jax.lax.fori_loop(0, n_iters, body, (m0, l0, acc0))
+        # rows past their slice never see a key: exact zero, not a mean of V
+        return jnp.where(qvalid, acc / jnp.maximum(l, 1e-30), 0.0
+                         ).astype(out_ref.dtype)
+
+    first = block_seq_ref[g * nsub]
+    if nsub == 1:
+        out_ref[...] = attend(q_ref[...], first, block_qoff_ref[g], bq)
+        return
+    # one lane holds the whole step (blocks behind its slice's end are
+    # padding): its pages are fetched once for all nsub blocks
+    uniform = first >= 0
+    for j in range(1, nsub):
+        other = block_seq_ref[g * nsub + j]
+        uniform = uniform & ((other == first) | (other < 0))
+
+    @pl.when(uniform)
+    def _():
+        out_ref[...] = attend(
+            q_ref[...], first, block_qoff_ref[g * nsub], nsub * bq)
+
+    @pl.when(jnp.logical_not(uniform))
+    def _():
+        def one(j, _):
+            rows = pl.ds(pl.multiple_of(j * bq * group, bq * group), bq * group)
+            out_ref[:, rows, :] = attend(
+                q_ref[:, rows, :], block_seq_ref[g * nsub + j],
+                block_qoff_ref[g * nsub + j], bq)
+            return 0
+
+        jax.lax.fori_loop(0, nsub, one, 0)
+
+
+def window_attention_ragged_pallas(
+    q: jnp.ndarray,  # [T, nq, d] packed queries, slices at multiples of `block`
+    k_new: jnp.ndarray,  # [T, nkv, d] the buffer's own keys (not yet in the ring)
+    v_new: jnp.ndarray,  # [T, nkv, d]
+    ring_pages: jnp.ndarray,  # [pages, 2, nkv, ps, d] as it was BEFORE this buffer
+    ring_table: jnp.ndarray,  # [B, Wr] int32; Wr * ps = window
+    q_start: jnp.ndarray,  # [B] int32
+    q_len: jnp.ndarray,  # [B] int32 (0 = no slice)
+    kv_start: jnp.ndarray,  # [B] int32
+    scale: float,
+    block: int = RAGGED_BQ,
+    interpret: bool = False,
+) -> jnp.ndarray:
+    """The contract of ops/attention.ring_window_attention_ragged (its
+    oracle), as one kernel: nothing over [T, T] or [blocks, window] exists
+    outside VMEM.  The queries go in, and the result comes out, by K/V head
+    ([nkv, T x group, d]: two XLA transposes of the buffer), so that the
+    kernel's matmuls take whole tiles whatever the group's size."""
+    T, nq, d = q.shape
+    nkv, ps = ring_pages.shape[2], ring_pages.shape[3]
+    group = nq // nkv
+    if T % block:
+        raise ValueError(f"buffer of {T} tokens not a multiple of {block}")
+    if d % 128 != 0 and not interpret:
+        raise ValueError(
+            f"window pallas kernel requires head_dim % 128 == 0, got {d}")
+    nsub = max(1, WINDOW_BQ // block)
+    step = nsub * block
+    Tq = -(-T // step) * step
+    block_seq, block_qoff = _ragged_block_metadata(
+        q_start, q_len, Tq // block, block)
+    qg = jnp.pad(q, ((0, Tq - T), (0, 0), (0, 0))).reshape(
+        Tq, nkv, group, d).transpose(1, 0, 2, 3).reshape(nkv, Tq * group, d)
+    Tk = -(-T // ps) * ps
+    buf = jnp.pad(jnp.stack([k_new, v_new], axis=1).astype(ring_pages.dtype),
+                  ((0, Tk - T), (0, 0), (0, 0), (0, 0)))
+    buf = buf.reshape(Tk // ps, ps, 2, nkv, d).transpose(0, 2, 3, 1, 4)
+    kernel = functools.partial(
+        _window_ragged_kernel, nsub=nsub, bq=block, group=group,
+        page_size=ps, ring_width=ring_table.shape[1], scale=float(scale))
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=6,
+            grid=(Tq // step,),
+            in_specs=[
+                pl.BlockSpec((nkv, step * group, d), lambda g, *_: (0, g, 0)),
+                pl.BlockSpec(memory_space=_HBM),
+                pl.BlockSpec(memory_space=_HBM),
+            ],
+            out_specs=pl.BlockSpec(
+                (nkv, step * group, d), lambda g, *_: (0, g, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((NBUF, 2, nkv, 2 * ps, d), ring_pages.dtype),
+                pltpu.SemaphoreType.DMA((NBUF, 2)),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((nkv, Tq * group, d), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=WINDOW_VMEM_BYTES),
+        interpret=interpret,
+        name="window_attention_ragged",
+    )(block_seq, block_qoff, ring_table, q_start, q_len, kv_start,
+      qg, ring_pages, buf)
+    return out.reshape(nkv, Tq, group, d).transpose(1, 0, 2, 3).reshape(
+        Tq, nq, d)[:T]

@@ -1,4 +1,5 @@
-"""Rotary position embeddings (half-rotation layout, Llama/NeoX style).
+"""Rotary position embeddings: the half-rotation layout (Llama/NeoX style,
+`apply_rope`) and the interleaved one (GPT-J style, `apply_rope_interleaved`).
 
 Computed on the fly from positions (no host-side cache tables) so the same
 function serves prefill ([B,T]) and decode ([B,1]) under one jit.
@@ -15,6 +16,7 @@ from __future__ import annotations
 import math
 from typing import Optional
 
+import jax
 import jax.numpy as jnp
 
 
@@ -68,3 +70,32 @@ def apply_rope(
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return out.astype(x.dtype)
+
+
+def apply_rope_interleaved(
+    x: jnp.ndarray,  # [N, H, D]
+    positions: jnp.ndarray,  # [N] int32
+    theta: float = 10000.0,
+) -> jnp.ndarray:
+    """The interleaved pairing (`rope_gptj`): columns (2j, 2j+1) turn by
+    `pos * theta^(-2j/D)`.  Written without splitting the lanes: every
+    column keeps its place, `x * cos + partner(x) * sin` with the partner of
+    column 2j being -x[2j+1] and of 2j+1 being x[2j].  The partner is a
+    product with a fixed [D, D] matrix of 0 and +-1 (exact in any dtype: one
+    term a column): on the TPU a shift along the lanes is slices and a
+    concatenation over the whole array, three passes and 5 % of a step's
+    device time at 128 heads (PERF.md section 6, PR 43), and this is one
+    small matmul."""
+    head_dim = x.shape[-1]
+    inv_freq = jnp.repeat(rope_frequencies(head_dim, theta), 2)  # [D]
+    angles = positions.astype(jnp.float32)[:, None] * inv_freq  # [N, D]
+    cos, sin = jnp.cos(angles)[:, None, :], jnp.sin(angles)[:, None, :]
+    col = jnp.arange(head_dim)
+    # swap[d, e]: what column d of x adds to column e of the partner
+    swap = (jnp.where((col[:, None] == col[None, :] + 1) & (col[None, :] % 2 == 0), -1.0, 0.0)
+            + jnp.where((col[:, None] + 1 == col[None, :]) & (col[:, None] % 2 == 0), 1.0, 0.0))
+    partner = jnp.einsum(
+        "nhd,de->nhe", x, swap.astype(x.dtype),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32)
+    return (x.astype(jnp.float32) * cos + partner * sin).astype(x.dtype)

@@ -826,3 +826,79 @@ class TestNemotronH:
         assert len(found) == copies, found
         temp = compiled.memory_analysis().temp_size_in_bytes
         assert (temp > 600e6) == bool(copies)
+
+
+class TestCommandAPlus:
+    """`model_type: cohere2_moe` (PR 43): the packed step's window attention
+    as ONE kernel over (ring pages, the buffer's own slice) at the published
+    sizes, and the `mixed` program that calls it."""
+
+    def test_the_window_kernel_compiles_at_the_published_sizes(self):
+        """128 query / 8 K/V heads of 128, a ring of 64 pages of 64 tokens,
+        32 lanes, a 1024-token buffer: no [T, T] or [blocks, ring] array
+        outside the kernel (its temporaries are the two transposes of the
+        buffer's queries), and the K/V page write of a 4096-token buffer
+        states the VMEM it needs."""
+        if _tpu_sharding() is None:
+            pytest.skip("no compile-only TPU topology here")
+        from kserve_tpu.ops import pallas_kv_write
+
+        T, lanes, nq, nkv, d, ps, ring = 1024, 32, 128, 8, 128, 64, 64
+        bf16 = jnp.bfloat16
+        rings = _abstract((1 + lanes * ring, 2, nkv, ps, d), bf16)
+        compiled = jax.jit(
+            lambda q, k, v, pages, table, start, n, kv0:
+            pk.window_attention_ragged_pallas(
+                q, k, v, pages, table, start, n, kv0, d ** -0.5)).lower(
+            _abstract((T, nq, d), bf16), _abstract((T, nkv, d), bf16),
+            _abstract((T, nkv, d), bf16), rings, _i32(lanes, ring),
+            _i32(lanes), _i32(lanes), _i32(lanes)).compile()
+        assert "window_attention_ragged" in compiled.as_text()
+        # q by K/V head and back, the buffer's K/V as pages: a few copies
+        # of the buffer, nothing of the size of a gathered ring a block
+        # (128 blocks x 16.8 MB = 2.1 GB) or of the scores
+        temp = compiled.memory_analysis().temp_size_in_bytes
+        assert temp < 4 * T * nq * d * 2, temp
+        pool = _abstract((4352, 2, nkv, ps, d), bf16)
+        jax.jit(pallas_kv_write.write_runs).lower(
+            pool, _abstract((4096, nkv, d), bf16), _abstract((4096, nkv, d), bf16),
+            _i32(lanes, 128), _i32(lanes), _i32(lanes), _i32(lanes),
+            _i32(lanes)).compile()
+
+    def test_mixed_calls_the_window_kernel_and_the_decode_kernel_over_rings(
+            self, monkeypatch):
+        import dataclasses
+        import re
+
+        from kserve_tpu.engine.shapes import DispatchShapes
+        from kserve_tpu.engine.types import EngineConfig
+        from kserve_tpu.models import llama
+        from test_command_a_model import CFG
+
+        # rows of 128 and a window wide enough for the rule to choose the
+        # kernel (36 MB of gathered ring and scores a block of 8 queries)
+        mc = dataclasses.replace(
+            llama.LlamaConfig.from_hf_config(
+                dict(CFG, hidden_size=128, head_dim=128, num_attention_heads=32,
+                     num_key_value_heads=2, sliding_window=16384,
+                     intermediate_size=128)),
+            dtype="bfloat16")
+        cfg = EngineConfig(
+            max_batch_size=8, page_size=64, num_pages=64, max_pages_per_seq=8,
+            max_prefill_len=128, prefill_buckets=(128,), dtype="bfloat16")
+        assert att._should_use_window_pallas(128, 32, 2, 16384, 8, "tpu")
+        layout = kvcache.StateLayout.of(mc, 64, cfg.num_pages, 8, "bfloat16")
+        text = _lower_mixed(
+            mc, cfg, jax.eval_shape(layout.init_state), 8,
+            monkeypatch).as_text()
+        kernels = set(re.findall(r'kernel_name = "([a-z_]+)"', text))
+        assert {"window_attention_ragged", "window_attention_decode",
+                "kv_page_write"} <= kernels, kernels
+        # no gathered ring a block of queries: [16 blocks, 256 ring pages, ..]
+        assert not re.findall(r"tensor<16x256x2x2x64x128xbf16>", text)
+        # 4 layers x (packed step + decode steps) x gate, up, down
+        assert len(re.findall(r"ragged_dot", text)) >= 24
+        assert DispatchShapes.of(mc, cfg, "tpu").align == pk.RAGGED_BQ
+        report = att.describe_attention_dispatch(mc, cfg, "tpu")
+        assert report["mixed"] == "pallas_window_ragged+pallas_ragged"
+        assert report["kv_write"] == {"paged": "page_kernel", "window": "page_kernel"}
