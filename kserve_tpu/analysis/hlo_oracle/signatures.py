@@ -19,11 +19,17 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ...engine.compiled import program_defs
 from ...engine.kvcache import KVCacheConfig, init_kv_pages, init_kv_scales
 from ...engine.sampling import SamplingState
-from ...engine.shapes import DispatchShapes
+from ...engine.shapes import (
+    PLAN_ROWS,
+    SAMPLER_COLUMNS,
+    DispatchShapes,
+    MixedLayout,
+)
 from ...engine.types import EngineConfig
 from ...models import llama
 from ...parallel import sharding as shd
@@ -204,26 +210,28 @@ def args_for(ps: ProgramSet, name: str,
         )
         return args, {"pages": nb, "steps": 1}
     if name == "mixed":
+        # the three packed buffers of _step_mixed, filled through the
+        # program's own layout: no lane mid-resume, every lane joining the
+        # scan at full capacity, the sampler's defaults, step 0
+        defaults = SamplingState.defaults(B)
+        columns = {name: np.zeros((B,), np.int32) for name in PLAN_ROWS}
+        columns.update(
+            {name: np.asarray(getattr(defaults, name))
+             for name in SAMPLER_COLUMNS},
+            q_tokens=[], token_seq=[], token_pos=[],
+            joins=np.ones((B,), bool),
+            scan_tok0=np.full((B,), -1, np.int32),
+            capacity=np.full(
+                (B,), cfg.max_pages_per_seq * cfg.page_size, np.int32),
+            adapters=np.full((B,), -1, np.int32))
+        tokens_buf, lanes_buf = MixedLayout(T, B, width).pack(columns, 0)
         args = (
             ps.params,
-            i32(T),              # q_tokens
-            i32(T, fill=-1),     # token_seq
-            i32(T),              # token_pos
-            i32(B),              # q_start
-            i32(B),              # q_len
-            i32(B),              # kv_start
-            i32(B),              # last_idx
+            jnp.asarray(tokens_buf),
+            jnp.asarray(lanes_buf),
             ps.kv_pages,
             i32(B, width),       # page_table
-            jnp.ones((B,), bool),  # joins
-            i32(B, fill=-1),     # scan_tok0
-            i32(B),              # scan_pos0
-            i32(B),              # step0_emits
-            i32(B, fill=cfg.max_pages_per_seq * cfg.page_size),  # capacity
-            i32(B),              # counters
-            SamplingState.defaults(B),
-            rng,
-            i32(B, fill=-1),     # adapters
+            rng,                 # the base key: the program folds the step in
         )
         return args, {"batch": B, "tokens": T + (steps - 1) * B,
                       "steps": steps}

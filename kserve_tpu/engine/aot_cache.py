@@ -118,7 +118,9 @@ def _mesh_devices(mesh) -> list:
 #: where the device programs are written: a change to any of these files
 #: may change the compiled artifact, so their bytes are part of the key
 _PROGRAM_SOURCES = ("models", "ops", "parallel", "engine/compiled.py",
-                    "engine/sampling.py", "engine/kvcache.py")
+                    "engine/sampling.py", "engine/kvcache.py",
+                    # `mixed` cuts its arguments apart by shapes.MixedLayout
+                    "engine/shapes.py")
 
 
 @functools.lru_cache(maxsize=1)

@@ -27,13 +27,15 @@ from ..observability.pauses import PROGRAM_COMPILE
 from ..parallel import sharding as shd
 from .kvcache import pages_of_passes
 from .sampling import (
+    COLUMNS as SAMPLER_COLUMNS,
+    SamplingState,
     apply_penalties,
     compute_logprobs,
     sample_tokens,
     sampler_top_k,
     sampler_truncates,
 )
-from .shapes import DispatchShapes
+from .shapes import PLAN_ROWS, TOKEN_ROWS, DispatchShapes, MixedLayout
 
 _log = logging.getLogger(__name__)
 
@@ -522,12 +524,20 @@ def program_defs(model_config, engine_config, mesh, spec_k=None) -> dict:
         ragged sample at a re-prefill boundary is discarded.
 
         Emits [steps, B] tokens like the legacy decode program; the host
-        consumes per-lane windows (engine._route_mixed)."""
+        consumes per-lane windows (engine._route_mixed).
+
+        What the host built for the dispatch arrives in three int32 arrays
+        (shapes.MixedLayout: the tokens' buffer, the lanes' buffer, the page
+        table), not in one argument a column: `packed` is the program, and
+        cuts them into the arguments of `fn`, its body (kept as the
+        program's `body` for the test that holds the two to the same
+        tokens).  The dispatch's key is folded here from the base key, which
+        stays on the device, and the step's number in the lanes' buffer."""
 
         def fn(params, q_tokens, token_seq, token_pos, q_start, q_len,
                kv_start, last_idx, kv_pages, page_table, joins, scan_tok0,
                scan_pos0, step0_emits, capacity, counters, state, rng,
-               adapter_ids):
+               adapters):
             steps = cfg.steps_per_sync
             rngs = jax.random.split(rng, steps)
             truncates = sampler_truncates(state)  # once, not once a step
@@ -540,7 +550,7 @@ def program_defs(model_config, engine_config, mesh, spec_k=None) -> dict:
                 params, mc, q_tokens, token_seq, token_pos,
                 q_start, q_len, kv_start, kv_pages, page_table,
                 cfg.page_size, last_idx,
-                adapter_ids=adapter_ids,
+                adapter_ids=adapters,
                 attention_fn=ragged_attention_fn,
                 use_pallas=cfg.use_pallas,
                 ragged_block=ragged_block,
@@ -556,7 +566,7 @@ def program_defs(model_config, engine_config, mesh, spec_k=None) -> dict:
                 logits, kv_pages = llama.decode_step(
                     params, mc, tokens, pos, kv_pages, page_table, live,
                     cfg.page_size, use_pallas=cfg.use_pallas,
-                    adapter_ids=adapter_ids,
+                    adapter_ids=adapters,
                     attention_fn=decode_attention_fn,
                 )
                 nxt = sample_tokens(
@@ -587,7 +597,20 @@ def program_defs(model_config, engine_config, mesh, spec_k=None) -> dict:
                     (sums.shape[0], out.shape[1]))], axis=0)
             return out, _kv_pin(kv_pages)
 
-        return fn
+        def packed(params, tokens_buf, lanes_buf, kv_pages, page_table,
+                   base_rng):
+            layout = MixedLayout(
+                tokens_buf.shape[1], lanes_buf.shape[1], page_table.shape[1])
+            cols = layout.unpack(tokens_buf, lanes_buf)
+            state = SamplingState(
+                **{name: cols[name] for name in SAMPLER_COLUMNS})
+            rng = jax.random.fold_in(base_rng, cols["step"])
+            return fn(params, kv_pages=kv_pages, page_table=page_table,
+                      state=state, rng=rng,
+                      **{name: cols[name] for name in TOKEN_ROWS + PLAN_ROWS})
+
+        packed.body = fn
+        return packed
 
     def _make_mixed_decode(k_drafts: int):
         """Dense decode packing + self-drafting speculative verify
@@ -791,7 +814,7 @@ def program_defs(model_config, engine_config, mesh, spec_k=None) -> dict:
     if cfg.pp == 1:
         # the mixed program runs the flat per-layer forward; pp>1 engines
         # keep the staged legacy programs (use_ragged forces off there)
-        defs["mixed"] = (_make_mixed(), (8,))
+        defs["mixed"] = (_make_mixed(), (3,))
         if spec_k is not None:
             # kv_pages (3) and the draft table (8) are the device-resident
             # carries the engine threads dispatch to dispatch — both

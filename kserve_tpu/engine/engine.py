@@ -42,6 +42,7 @@ from ..metrics import (
     ENGINE_DISPATCH_PART_SECONDS,
     ENGINE_DISPATCH_PHASE_CPU_SECONDS,
     ENGINE_DISPATCH_PHASE_SECONDS,
+    ENGINE_DISPATCH_UPLOADS,
     ENGINE_DISPATCHES,
     ENGINE_DISPATCH_DELIVERIES,
     ENGINE_FIRST_TOKEN_DISPATCHES,
@@ -125,8 +126,13 @@ from .kvcache import (
     pages_needed,
     pages_of_passes,
 )
-from .sampling import SAMPLER_PATHS, SamplingParams, SamplingState
-from .shapes import FITS, DispatchShapes, LoadedPairs
+from .sampling import SAMPLER_PATHS, SamplingParams, SamplingState, unpacked
+from .shapes import (
+    FITS,
+    DispatchShapes,
+    LoadedPairs,
+    MixedLayout,
+)
 from .tokenizer import BaseTokenizer, IncrementalDetokenizer
 
 
@@ -154,13 +160,15 @@ def _device_row(device) -> dict:
 
 #: where a dispatch row (observability.DISPATCH_COLUMNS) holds what the
 #: counters are fed from: the program, the six phases with `wait_lag`, the
-#: tokens the iteration handed to its streams, and the parts of its phases
+#: tokens the iteration handed to its streams, the parts of its phases and
+#: the transfers of its launches' inputs
 _PROGRAM_COLUMN = DISPATCH_COLUMNS.index("program")
 _PHASE_COLUMNS = slice(DISPATCH_COLUMNS.index(PHASES[0]),
                        DISPATCH_COLUMNS.index("wait_lag") + 1)
 _DELIVERED_COLUMNS = slice(DISPATCH_COLUMNS.index(DELIVERIES[0]),
                            DISPATCH_COLUMNS.index(DELIVERIES[-1]) + 1)
 _PART_COLUMNS = [DISPATCH_COLUMNS.index(part) for part in PARTS]
+_UPLOADS_COLUMN = DISPATCH_COLUMNS.index("uploads")
 _CPU_COLUMNS = slice(DISPATCH_COLUMNS.index(CPU_COLUMNS[0]),
                      DISPATCH_COLUMNS.index(CPU_COLUMNS[-1]) + 1)
 
@@ -394,6 +402,8 @@ class LLMEngine:
             ENGINE_DISPATCH_PART_SECONDS.labels(
                 model_name=metrics_label, part=part)
             for part in PARTS]
+        self._uploads = ENGINE_DISPATCH_UPLOADS.labels(
+            model_name=metrics_label)
         # a compile of something that is none of the engine's programs,
         # after warm-up, is logged once (_paused)
         self._warned_other_compile = False
@@ -1024,13 +1034,13 @@ class LLMEngine:
         self._mixed_fn = getattr(p, "mixed", None)
         # the (T, W) pairs `mixed` is loaded in, which its planner fits a
         # dispatch to (shapes.LoadedPairs): what the AOT cache preloaded
-        # above, then every pair a launch runs in.  Arguments 1 and 9 of
-        # `mixed` are the packed tokens [T] and the page table [B, W]
-        # (_step_mixed's call)
+        # above, then every pair a launch runs in.  Arguments 1 and 4 of
+        # `mixed` are the tokens' buffer [3, T] and the page table [B, W]
+        # (shapes.MixedLayout, _step_mixed's call)
         preloaded = getattr(self._mixed_fn, "loaded_shapes", None)
         self._loaded = LoadedPairs(
-            (tokens[0], table[1])
-            for tokens, table in (preloaded(1, 9) if preloaded else ()))
+            (tokens[1], table[1])
+            for tokens, table in (preloaded(1, 4) if preloaded else ()))
         # dense/speculative decode-only program (docs/kernels.md); present
         # only when spec_decode_k is configured (stubs included)
         self._mixed_decode_fn = getattr(p, "mixed_decode", None)
@@ -2431,6 +2441,7 @@ class LLMEngine:
             counter.inc(seconds)
         for counter, column in zip(self._part_seconds, _PART_COLUMNS):
             counter.inc(row[column])
+        self._uploads.inc(row[_UPLOADS_COLUMN])
         for counter, seconds in zip(self._phase_cpu_seconds,
                                     row[_CPU_COLUMNS]):
             counter.inc(seconds)
@@ -3425,8 +3436,6 @@ class LLMEngine:
             and slot.params.logprobs is not None
             for i, slot in enumerate(self._slots)
         )
-        with self._phases.span("sampling"):
-            state, sampler_path = SamplingState.planned(params_list)
         return {
             "tokens": tokens,
             "pos": pos,
@@ -3435,8 +3444,10 @@ class LLMEngine:
             "page_table": page_table,
             "counters": counters,
             "adapters": adapters,
-            "state": state,
-            "sampler_path": sampler_path,
+            # the lanes' sampling rows: whichever launch takes this chunk
+            # builds its state from them (_sampling_state; `mixed` packs its
+            # own lanes' in _plan_ragged)
+            "params_list": params_list,
             "penalized": penalized,
             "want_logprobs": want_logprobs,
         }
@@ -3557,33 +3568,51 @@ class LLMEngine:
         must share one jit signature."""
         return shd.named(self.mesh, shd.draft_table_pspec())
 
+    def _upload(self, array: np.ndarray) -> jax.Array:
+        """One host-to-device transfer of a launch's input, counted on the
+        row of the dispatch under way (engine_dispatch_uploads_total): the
+        one way such an input reaches the device.  Uncommitted, as
+        `jnp.asarray` leaves it: the program's input shardings place it."""
+        self._phases.uploaded()
+        return jnp.asarray(array)
+
+    def _sampling_state(self, params_list) -> Tuple[SamplingState, str]:
+        """The lanes' sampling state on the device, in ONE transfer, and the
+        path the sampler takes for it: for the launches whose program takes
+        the state as an argument (the legacy decode, the dense path)."""
+        with self._phases.span("sampling"):
+            cols, sampler_path = SamplingState.planned(params_list)
+            packed = SamplingState.packed(cols)
+        return unpacked(self._upload(packed)), sampler_path
+
     def _dispatch_chunk(self, meta: dict, tokens_dev=None):
         """Launch one decode chunk (async); tokens_dev chains the previous
         chunk's device-resident last tokens, skipping a host round-trip."""
         phases = self._phases
         meta["_dispatched_at"] = phases.mark("launch")
+        with phases.span("upload"):
+            state, sampler_path = self._sampling_state(meta["params_list"])
+            tokens = (tokens_dev if tokens_dev is not None
+                      else self._upload(meta["tokens"]))
+            pos = self._upload(meta["pos"])
+            page_table = self._upload(meta["page_table"])
+            active = self._upload(meta["active"])
+            capacity = self._upload(meta["capacity"])
+            counters = self._upload(meta["counters"])
+            adapters = self._upload(meta["adapters"])
         with phases.span("account"):
             n_active = int(np.count_nonzero(meta["active"]))
             phases.launched(
                 "decode", n_active, meta["page_table"].shape[1], 0, n_active,
                 chained=tokens_dev is not None)
-            self._sampler_dispatches[meta["sampler_path"]].inc()
+            self._sampler_dispatches[sampler_path].inc()
             self._count_forward(
                 self._shapes.steps, meta["pos"], meta["active"],
                 meta["capacity"], decode_steps=self._shapes.steps)
-        with phases.span("upload"):
-            tokens = (tokens_dev if tokens_dev is not None
-                      else jnp.asarray(meta["tokens"]))
-            pos = jnp.asarray(meta["pos"])
-            page_table = jnp.asarray(meta["page_table"])
-            active = jnp.asarray(meta["active"])
-            capacity = jnp.asarray(meta["capacity"])
-            counters = jnp.asarray(meta["counters"])
-            adapters = jnp.asarray(meta["adapters"])
         with phases.span("call"):
             return self._call_chunk(meta, (
                 self.params, tokens, pos, self.kv_pages, page_table, active,
-                capacity, counters, meta["state"],
+                capacity, counters, state,
                 jax.random.fold_in(self._base_rng, self._next_step()),
                 adapters))
 
@@ -3796,32 +3825,20 @@ class LLMEngine:
         with phases.span("pack"):
             plan = self._plan_ragged(meta, prefilling)
         dispatched_at = phases.mark("launch")
+        # everything the host built reaches the device in three transfers
+        # (shapes.MixedLayout); the dispatch's key is folded in the program
+        # from the base key, which stays on the device
         with phases.span("upload"):
-            q_tokens = jnp.asarray(plan["q_tokens"])
-            token_seq = jnp.asarray(plan["token_seq"])
-            token_pos = jnp.asarray(plan["token_pos"])
-            q_start = jnp.asarray(plan["q_start"])
-            q_len = jnp.asarray(plan["q_len"])
-            kv_start = jnp.asarray(plan["kv_start"])
-            last_idx = jnp.asarray(plan["last_idx"])
-            page_table = jnp.asarray(plan["page_table"])
-            joins = jnp.asarray(plan["joins"])
-            scan_tok0 = jnp.asarray(plan["scan_tok0"])
-            scan_pos0 = jnp.asarray(plan["scan_pos0"])
-            step0_emits = jnp.asarray(plan["step0_emits"])
-            capacity = jnp.asarray(plan["capacity"])
-            counters = jnp.asarray(plan["counters"])
-            adapters = jnp.asarray(plan["adapters"])
+            tokens_buf = self._upload(plan["tokens_buf"])
+            lanes_buf = self._upload(plan["lanes_buf"])
+            page_table = self._upload(plan["page_table"])
         with phases.span("call"):
-            rng = jax.random.fold_in(self._base_rng, self._next_step())
             compiles = getattr(self._mixed_fn, "compiles", 0)
             out, self.kv_pages = self._mixed_fn(
-                self.params, q_tokens, token_seq, token_pos, q_start, q_len,
-                kv_start, last_idx, self.kv_pages, page_table, joins,
-                scan_tok0, scan_pos0, step0_emits, capacity, counters,
-                plan["state"], rng, adapters)
+                self.params, tokens_buf, lanes_buf, self.kv_pages,
+                page_table, self._base_rng)
         with phases.span("account"):
-            ran = (len(plan["q_tokens"]), plan["page_table"].shape[1])
+            ran = (plan["tokens_buf"].shape[1], plan["page_table"].shape[1])
             phases.launched(
                 "mixed", *ran, plan["prefill_tokens"], plan["decode_tokens"],
                 compiled=getattr(self._mixed_fn, "compiles", 0) != compiles,
@@ -3970,30 +3987,28 @@ class LLMEngine:
         rows = self._page_rows(np.ones((B,), bool))
         need = (self._shapes.tokens(max(offset, 1)), self._width_of(rows))
         (tokens, width), fit = self._loaded.fit(*need)
-        pad = tokens - offset
-        tok_list.extend([0] * pad)
-        seq_list.extend([-1] * pad)
-        pos_list.extend([0] * pad)
         page_table = self._page_table(rows, width)
         with self._phases.span("sampling"):
-            state, sampler_path = SamplingState.planned(params_list)
+            sampler, sampler_path = SamplingState.planned(params_list)
+        # what the program takes, in the two buffers it takes it in; the
+        # buffer pads the packed slices to T with rows of no lane
+        tokens_buf, lanes_buf = MixedLayout(tokens, B, width).pack(dict(
+            sampler,
+            q_tokens=tok_list, token_seq=seq_list, token_pos=pos_list,
+            q_start=q_start, q_len=q_len, kv_start=kv_start,
+            last_idx=last_idx, joins=joins, scan_tok0=scan_tok0,
+            scan_pos0=scan_pos0, step0_emits=step0_emits, capacity=capacity,
+            counters=counters, adapters=adapters), self._next_step())
         return {
-            "q_tokens": np.asarray(tok_list, np.int32),
-            "token_seq": np.asarray(seq_list, np.int32),
-            "token_pos": np.asarray(pos_list, np.int32),
-            "q_start": q_start,
+            "tokens_buf": tokens_buf,
+            "lanes_buf": lanes_buf,
+            "page_table": page_table,
+            # the columns the launch's accounting and the tests read
             "q_len": q_len,
             "kv_start": kv_start,
-            "last_idx": last_idx,
-            "page_table": page_table,
             "joins": joins,
-            "scan_tok0": scan_tok0,
             "scan_pos0": scan_pos0,
-            "step0_emits": step0_emits,
             "capacity": capacity,
-            "counters": counters,
-            "adapters": adapters,
-            "state": state,
             "sampler_path": sampler_path,
             "need": need,
             "fit": fit,
@@ -4095,8 +4110,7 @@ class LLMEngine:
             "counters": meta["counters"],
             "adapters": meta["adapters"],
             "page_table": meta["page_table"],
-            "state": meta["state"],
-            "sampler_path": meta["sampler_path"],
+            "params_list": meta["params_list"],
         }
 
     def _plan_dense_chained(self, prev: dict) -> Optional[dict]:
@@ -4143,6 +4157,8 @@ class LLMEngine:
             "counters": prev["counters"],
             "adapters": prev["adapters"],
             "page_table": page_table,
+            # the lanes are the in-flight dispatch's: so is their sampling
+            # state, already on the device
             "state": prev["state"],
             "sampler_path": prev["sampler_path"],
         }
@@ -4154,6 +4170,10 @@ class LLMEngine:
         round-trip between them."""
         phases = self._phases
         plan["_dispatched_at"] = phases.mark("launch")
+        if "state" not in plan:
+            with phases.span("upload"):
+                plan["state"], plan["sampler_path"] = self._sampling_state(
+                    plan["params_list"])
         with phases.span("account"):
             n_tokens = (int(np.count_nonzero(plan["live"]))
                         * ((self._spec_k or 0) + 1))
@@ -4170,13 +4190,13 @@ class LLMEngine:
                 # its carry outputs to: chained and unchained dispatches must
                 # share ONE jit signature (see _refresh_draft_table)
                 rep = self._replicated_sharding
-                tok = jax.device_put(jnp.asarray(plan["tokens"]), rep)
-                pos = jax.device_put(jnp.asarray(plan["pos"]), rep)
-                cnt = jax.device_put(jnp.asarray(plan["counters"]), rep)
-            page_table = jnp.asarray(plan["page_table"])
-            live = jnp.asarray(plan["live"])
-            capacity = jnp.asarray(plan["capacity"])
-            adapters = jnp.asarray(plan["adapters"])
+                tok = jax.device_put(self._upload(plan["tokens"]), rep)
+                pos = jax.device_put(self._upload(plan["pos"]), rep)
+                cnt = jax.device_put(self._upload(plan["counters"]), rep)
+            page_table = self._upload(plan["page_table"])
+            live = self._upload(plan["live"])
+            capacity = self._upload(plan["capacity"])
+            adapters = self._upload(plan["adapters"])
         with phases.span("call"):
             rng = jax.random.fold_in(self._base_rng, self._next_step())
             out = self._mixed_decode_fn(

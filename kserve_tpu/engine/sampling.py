@@ -16,7 +16,7 @@ Role parity: vLLM's Sampler (the reference delegates sampling to vLLM).
 from __future__ import annotations
 
 from dataclasses import dataclass
-from typing import List, Optional, Tuple
+from typing import Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -56,6 +56,28 @@ class SamplingParams:
         )
 
 
+#: SamplingState's columns in the order a launch packs them (`packed`), the
+#: int32 ones first; the float32 ones travel as their bits
+INT_COLUMNS = ("top_k", "seed")
+FLOAT_COLUMNS = ("temperature", "top_p", "min_p", "repetition_penalty",
+                 "frequency_penalty", "presence_penalty")
+COLUMNS = INT_COLUMNS + FLOAT_COLUMNS
+
+
+def as_bits(column: np.ndarray) -> np.ndarray:
+    """A host column as it travels in an int32 buffer: a float32 one as its
+    bits, so nothing is rounded on the way."""
+    return column.view(np.int32) if column.dtype == np.float32 else column
+
+
+def as_float32(bits):
+    """The float32 whose bits an int32 array holds: numpy on the host, a
+    bitcast in a program.  Bit for bit either way."""
+    if isinstance(bits, np.ndarray):
+        return bits.view(np.float32)
+    return jax.lax.bitcast_convert_type(bits, jnp.float32)
+
+
 @jax.tree_util.register_dataclass
 @dataclass
 class SamplingState:
@@ -72,32 +94,48 @@ class SamplingState:
     presence_penalty: jnp.ndarray  # [B] f32 (0.0 => off)
 
     @staticmethod
-    def planned(params_list: List[SamplingParams]) -> Tuple["SamplingState", str]:
-        """The rows' state for the device and, from the same float32 /
-        int32 columns, the path `sample_tokens` will take for them
-        (SAMPLER_PATHS: engine_sampler_dispatches_total's label)."""
+    def planned(
+        params_list: List[SamplingParams],
+    ) -> Tuple[Dict[str, np.ndarray], str]:
+        """The rows' columns on the HOST (numpy, float32 / int32 by the
+        fields above) and, from the same columns, the path `sample_tokens`
+        will take for them (SAMPLER_PATHS: engine_sampler_dispatches_total's
+        label).  Nothing is uploaded: a launch packs the columns into what
+        it hands the device (`packed`; `mixed` into its lanes' buffer:
+        shapes.MixedLayout)."""
         def column(name, dtype):
             return np.asarray([getattr(p, name) for p in params_list], dtype)
 
-        cols = dict(
-            temperature=column("temperature", np.float32),
-            top_p=column("top_p", np.float32),
-            top_k=column("top_k", np.int32),
-            min_p=column("min_p", np.float32),
-            seed=np.asarray(
-                [p.seed if p.seed is not None else -1 for p in params_list],
-                np.int32),
-            repetition_penalty=column("repetition_penalty", np.float32),
-            frequency_penalty=column("frequency_penalty", np.float32),
-            presence_penalty=column("presence_penalty", np.float32),
-        )
+        cols = {name: column(name, np.float32) for name in FLOAT_COLUMNS}
+        cols["top_k"] = column("top_k", np.int32)
+        cols["seed"] = np.asarray(
+            [p.seed if p.seed is not None else -1 for p in params_list],
+            np.int32)
         path = SAMPLER_PATHS[int(_truncates(
             cols["temperature"], cols["top_k"], cols["top_p"], cols["min_p"]))]
-        return SamplingState(**{k: jnp.asarray(v) for k, v in cols.items()}), path
+        return cols, path
+
+    @staticmethod
+    def packed(cols: Dict[str, np.ndarray]) -> np.ndarray:
+        """`planned`'s columns as ONE int32 [8, B] array, in COLUMNS' order:
+        one transfer instead of eight."""
+        return np.stack([as_bits(cols[name]) for name in COLUMNS])
+
+    @staticmethod
+    def of_rows(rows) -> "SamplingState":
+        """The state from `packed`'s rows, in COLUMNS' order: numpy rows on
+        the host, static slices of an argument inside a program."""
+        return SamplingState(**{
+            name: row if name in INT_COLUMNS else as_float32(row)
+            for name, row in zip(COLUMNS, rows)})
 
     @staticmethod
     def from_params(params_list: List[SamplingParams]) -> "SamplingState":
-        return SamplingState.planned(params_list)[0]
+        """The state on the device, for the programs that take it as an
+        argument: ONE transfer, then one small program that cuts the rows
+        apart."""
+        cols, _ = SamplingState.planned(params_list)
+        return unpacked(jnp.asarray(SamplingState.packed(cols)))
 
     @staticmethod
     def defaults(batch: int) -> "SamplingState":
@@ -111,6 +149,12 @@ class SamplingState:
             frequency_penalty=jnp.zeros((batch,), jnp.float32),
             presence_penalty=jnp.zeros((batch,), jnp.float32),
         )
+
+
+@jax.jit
+def unpacked(packed: jnp.ndarray) -> SamplingState:
+    """`SamplingState.packed`'s array, on the device, as the state."""
+    return SamplingState.of_rows(tuple(packed))
 
 
 #: the work a batch asks of the sampler: `truncate` where a sampled row

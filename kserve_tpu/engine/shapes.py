@@ -16,6 +16,11 @@ which executables the engine holds (`LoadedPairs.fit`): a dispatch compiles
 only when no loaded pair holds it, since a compile stalls every request in
 flight and a longer buffer or a wider table only pads.
 
+How a mixed dispatch's host-built inputs reach the program is here too
+(`MixedLayout`): three int32 buffers whose shapes spell T, B and W, filled
+by the engine's planner and cut apart by the program, the sim's stub and
+the HLO oracle through the one description.
+
 A change of the policy (coarser buckets, a step count from queue depth) is
 a change to this file.
 """
@@ -24,6 +29,12 @@ from __future__ import annotations
 
 from dataclasses import dataclass
 from typing import Dict, Iterable, List, Set, Tuple
+
+import numpy as np
+
+from .sampling import COLUMNS as SAMPLER_COLUMNS
+from .sampling import INT_COLUMNS as SAMPLER_INT_COLUMNS
+from .sampling import as_bits, as_float32
 
 #: the first rung of the width ladder, in pages
 MIN_WIDTH = 8
@@ -199,3 +210,80 @@ class DispatchShapes:
             "width_buckets": list(self.width_buckets),
             "min_width": self.width_buckets[0],
         }
+
+
+#: the rows of `mixed`'s token buffer, [3, T] int32: the packed slices'
+#: tokens, the lane each row belongs to (-1: padding) and its position
+TOKEN_ROWS = ("q_tokens", "token_seq", "token_pos")
+#: what the planner sets a lane: a row each of the lanes' buffer, in order
+PLAN_ROWS = ("q_start", "q_len", "kv_start", "last_idx", "joins",
+             "scan_tok0", "scan_pos0", "step0_emits", "capacity", "counters",
+             "adapters")
+#: the rows of `mixed`'s lanes' buffer, [20, B] int32: the plan's, the
+#: sampler's columns (sampling.COLUMNS: a float32 one as its bits, so a seed
+#: or a top_p arrives as it left) and `step`, the dispatch's number, which the
+#: program folds into its base key (read at lane 0)
+LANE_ROWS = PLAN_ROWS + SAMPLER_COLUMNS + ("step",)
+
+
+@dataclass(frozen=True)
+class MixedLayout:
+    """Where the host-built inputs of ONE `mixed` dispatch lie, by its
+    static sizes: T tokens in the packed buffer, B lanes, a page table W
+    wide.  Three int32 arrays, three transfers: the tokens' buffer
+    (TOKEN_ROWS), the lanes' buffer (LANE_ROWS) and the page table [B, W].
+    Their shapes are the program's signature and spell T, B and W apart, so
+    no two pairs of a grid share an executable (one flat buffer would:
+    3 T + B W collides)."""
+
+    tokens: int
+    lanes: int
+    width: int
+
+    @property
+    def shapes(self) -> Tuple[Tuple[int, int], ...]:
+        """Of the tokens' buffer, the lanes' buffer and the page table."""
+        return ((len(TOKEN_ROWS), self.tokens), (len(LANE_ROWS), self.lanes),
+                (self.lanes, self.width))
+
+    def pack(self, columns: Dict[str, np.ndarray],
+             step: int) -> Tuple[np.ndarray, np.ndarray]:
+        """The tokens' and the lanes' buffer from the planner's numpy
+        columns by name (TOKEN_ROWS, as long as the packed slices reach:
+        what lies behind them belongs to no lane; PLAN_ROWS and the
+        sampler's columns, a value a lane) and the dispatch's number."""
+        tokens_shape, lanes_shape, _ = self.shapes
+        tokens_buf = np.zeros(tokens_shape, np.int32)
+        lanes_buf = np.zeros(lanes_shape, np.int32)
+        rows = self.rows(tokens_buf, lanes_buf)
+        rows["token_seq"][:] = -1
+        for name in TOKEN_ROWS:
+            rows[name][:len(columns[name])] = columns[name]
+        for name in PLAN_ROWS + SAMPLER_COLUMNS:
+            rows[name][:] = as_bits(columns[name])
+        rows["step"][:] = step & 0x7FFFFFFF  # an int32, however long it ran
+        return tokens_buf, lanes_buf
+
+    def rows(self, tokens_buf, lanes_buf) -> Dict[str, object]:
+        """Every input by name as it lies: an int32 row of its buffer (a
+        view of a numpy buffer, a static slice of a program's argument)."""
+        if (tuple(tokens_buf.shape), tuple(lanes_buf.shape)) != self.shapes[:2]:
+            raise ValueError(
+                f"buffers of {tokens_buf.shape} and {lanes_buf.shape} are not "
+                f"a mixed dispatch of {self}")
+        return {**dict(zip(TOKEN_ROWS, tokens_buf)),
+                **dict(zip(LANE_ROWS, lanes_buf))}
+
+    def unpack(self, tokens_buf, lanes_buf) -> Dict[str, object]:
+        """Every input by name as the program's body takes it: `joins` a
+        boolean, the sampler's float32 columns from their bits, `step` a
+        scalar, the rest int32 rows.  numpy in, numpy out (the sim's stub,
+        the tests); inside a program, slices and bitcasts that cost the
+        device nothing."""
+        cols = self.rows(tokens_buf, lanes_buf)
+        cols["joins"] = cols["joins"] != 0
+        cols["step"] = cols["step"][0]
+        for name in SAMPLER_COLUMNS:
+            if name not in SAMPLER_INT_COLUMNS:
+                cols[name] = as_float32(cols[name])
+        return cols

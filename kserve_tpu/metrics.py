@@ -355,6 +355,19 @@ ENGINE_DISPATCH_PART_SECONDS = Counter(
     "dispatch rows, as the phases are",
     ["model_name", "part"],
 )
+# Host-to-device transfers of the launches' inputs: every one goes through
+# the engine's one helper (LLMEngine._upload), which counts it on the row of
+# the dispatch under way.  A transfer costs ~0.25 ms of the loop whatever it
+# carries, so `mixed` hands its inputs over in three (shapes.MixedLayout);
+# over engine_dispatches_total this reads 3 there
+ENGINE_DISPATCH_UPLOADS = Counter(
+    "engine_dispatch_uploads_total",
+    "host-to-device transfers of the inputs the engine's loop built for its "
+    "launches (a `mixed` dispatch: the tokens' buffer, the lanes' buffer, "
+    "the page table); fed from the committed dispatch rows, as the phases "
+    "are",
+    ["model_name"],
+)
 # CPU seconds of the loop's THREAD (time.thread_time), booked to the phase
 # each stamp closes: in `wait` they are the work the device's step hides
 # (delivery, the SSE writes, the handlers); over the phases' wall seconds

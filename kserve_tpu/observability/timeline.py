@@ -77,14 +77,17 @@ KV_WRITE_PATHS = ("page_kernel", "row_scatter")
 #: those that had been deferred, inside whichever phase that was: the part
 #: of that name; the other PARTS follow, then `cpu_<phase>`, the CPU seconds
 #: of the loop's thread inside each of the iteration's PHASES, and the
-#: PAUSES; last the K/V writes, by KV_WRITE_PATHS)
+#: PAUSES; then the K/V writes, by KV_WRITE_PATHS; last `uploads`, the
+#: host-to-device transfers the iteration's launches made of their inputs:
+#: three for a `mixed` dispatch)
 _PARTS_APPENDED = tuple(part for part in PARTS if part != "deliver")
 CPU_COLUMNS = tuple("cpu_" + phase for phase in PHASES)
 DISPATCH_COLUMNS = (
     "serial", "launched_at", "program", "tokens", "width", "need_tokens",
     "need_width", "prefill_tokens", "decode_tokens", *PHASES, "wait_lag",
     "compiled", "chained", "deliver", *DELIVERIES, *_PARTS_APPENDED,
-    *CPU_COLUMNS, *PAUSES, *("kv_" + path for path in KV_WRITE_PATHS))
+    *CPU_COLUMNS, *PAUSES, *("kv_" + path for path in KV_WRITE_PATHS),
+    "uploads")
 
 
 class RequestTimeline:
@@ -433,6 +436,7 @@ class DispatchPhases:
         self._seconds = dict.fromkeys(PHASES, 0.0)
         self._cpu = dict.fromkeys(PHASES, 0.0)
         self._parts = dict.fromkeys(PARTS, 0.0)
+        self._uploads = 0
         self._pauses = dict.fromkeys(PAUSES, 0.0)
         self._wait_lag = 0.0
         self._handed = dict.fromkeys(DELIVERIES, 0)
@@ -479,6 +483,10 @@ class DispatchPhases:
         also a host span `engine.<part>` nested in the phase's."""
         return _Part(self, part)
 
+    def uploaded(self) -> None:
+        """A launch's input went to the device: one transfer."""
+        self._uploads += 1
+
     def delivered(self, when: str, tokens: int) -> None:
         """`tokens` were handed to their streams, `when` (one of
         DELIVERIES)."""
@@ -511,6 +519,7 @@ class DispatchPhases:
         seconds, parts, cpus = self._seconds, self._parts, self._cpu
         wait_lag = min(self._wait_lag, seconds["wait"])
         handed, pauses, kv_writes = self._handed, self._pauses, self._kv_writes
+        uploads = self._uploads
         self._reset(now, cpu)
         self._phase = "admit"
         if not self._launches:
@@ -520,7 +529,7 @@ class DispatchPhases:
                *(seconds[p] for p in PHASES), wait_lag, compiled, chained,
                parts["deliver"], *handed.values(),
                *(parts[p] for p in _PARTS_APPENDED),
-               *cpus.values(), *pauses.values(), *kv_writes.values()]
+               *cpus.values(), *pauses.values(), *kv_writes.values(), uploads]
         self.serial += 1
         return row
 

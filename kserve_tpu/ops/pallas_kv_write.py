@@ -26,9 +26,18 @@ for the engine's own page tables.
 
 The copy is exact (bf16 -> f32 -> bf16 is the identity): the cache holds
 bit for bit what the scatter would have put there.
+
+The entry points (`append_rows`, `write_runs`) are jitted functions whose
+Python-level choices are static: a model's layers are unrolled in Python,
+and a plain function would be traced, and its `pallas_call` lowered, once a
+LAYER; a jitted one is traced and lowered once a PROGRAM, and every layer
+after the first calls that one function (docs/kernels.md "A kernel's entry
+point is a jitted function").
 """
 
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -215,6 +224,7 @@ def max_work_items(tokens: int, runs: int, page_size: int) -> int:
     return tokens // page_size + 2 * runs
 
 
+@functools.partial(jax.jit, static_argnames=("interpret",))
 def append_rows(kv_pages, k, v, page_table, pos, active, interpret=False):
     """The decode step: lane b's row (k[b], v[b]) to position pos[b] of
     its sequence; a lane that is not `active` writes nothing."""
@@ -227,6 +237,7 @@ def append_rows(kv_pages, k, v, page_table, pos, active, interpret=False):
         lo + active.astype(jnp.int32), b, interpret=interpret)
 
 
+@functools.partial(jax.jit, static_argnames=("interpret",))
 def write_runs(kv_pages, k, v, page_table, row, src, n, pos,
                interpret=False):
     """Runs (row, src, n, pos) [M] of the buffer (k, v) [T]: see the

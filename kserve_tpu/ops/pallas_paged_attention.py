@@ -38,6 +38,13 @@ engine's legacy-fallback flag are documented in docs/kernels.md.
 Numerics match ops/attention.paged_attention_xla and
 ops/attention.ragged_paged_attention_xla respectively (tests compare the
 paths in interpret mode; bench exercises the compiled kernels on hardware).
+
+Every entry point (`*_pallas`) is a jitted function (`_entry`) whose
+Python-level choices are static: a model's layers are unrolled in Python,
+and a plain function would be traced, and its `pallas_call` lowered, once a
+LAYER; a jitted one is traced and lowered once a PROGRAM for each set of
+static choices and shapes, and every layer after the first calls that one
+function (docs/kernels.md "A kernel's entry point is a jitted function").
 """
 
 from __future__ import annotations
@@ -54,6 +61,13 @@ NBUF = 4  # VMEM ring depth (iterations in flight); NBUF-1 ahead
 MAX_SB = 8  # sequences per grid step (VMEM budget: NBUF*SB pages resident)
 
 _HBM = pltpu.MemorySpace.HBM  # stay in device memory, no VMEM block
+
+
+def _entry(*static: str):
+    """Decorator of a kernel's entry point: `jax.jit` with the arguments
+    `static` (what is a Python value: a mode, a label, a scale, a block
+    size) held static, everything else an array or a pytree of arrays."""
+    return functools.partial(jax.jit, static_argnames=static)
 
 
 def _pick_sb(B: int) -> int:
@@ -415,6 +429,7 @@ def _paged_attention_pallas_packed(
     return out.astype(q.dtype)
 
 
+@_entry("logit_softcap", "interpret", "scale", "name")
 def paged_attention_pallas(
     q: jnp.ndarray,  # [B, nq, d]
     kv_pages: jnp.ndarray,  # [num_pages, 2, nkv, ps, d]
@@ -806,6 +821,7 @@ def _dense_ragged_call(q, pages, scales, page_table, q_len, kv_start, win,
     )(page_table, kv_start, q_len, win, *operands)
 
 
+@_entry("logit_softcap", "scale", "interpret", "dense_stride")
 def ragged_paged_attention_pallas(
     q: jnp.ndarray,  # [T, nq, d] — packed at RAGGED_BQ-aligned offsets
     kv_pages,  # [num_pages, 2, nkv, ps, d] or (int8 pages, scales)
@@ -914,6 +930,7 @@ def ragged_paged_attention_pallas(
 # `value_dim` set: a page is fetched once and serves scores and values.
 
 
+@_entry("scale", "value_dim", "interpret")
 def latent_attention_decode_pallas(
     q: jnp.ndarray,  # [B, nq, row]: absorbed queries, zero where the row pads
     pages: jnp.ndarray,  # [num_pages, 1, 1, ps, row]
@@ -937,6 +954,7 @@ def latent_attention_decode_pallas(
     ), page_table, seq_lens, q, pages)
 
 
+@_entry("scale", "value_dim", "interpret")
 def latent_attention_ragged_pallas(
     q: jnp.ndarray,  # [T, nq, row] packed at RAGGED_BQ-aligned offsets
     pages: jnp.ndarray,  # [num_pages, 1, 1, ps, row]
@@ -1168,6 +1186,7 @@ def _window_ragged_kernel(
         jax.lax.fori_loop(0, nsub, one, 0)
 
 
+@_entry("scale", "block", "interpret")
 def window_attention_ragged_pallas(
     q: jnp.ndarray,  # [T, nq, d] packed queries, slices at multiples of `block`
     k_new: jnp.ndarray,  # [T, nkv, d] the buffer's own keys (not yet in the ring)

@@ -32,6 +32,8 @@ from typing import List
 
 import numpy as np
 
+from ..engine.shapes import MixedLayout
+
 # token ids the stub emits: a printable-ASCII band, clear of BOS/EOS/PAD
 # (ByteTokenizer reserves 256..258) so streams never hit an accidental
 # EOS and detokenize to readable text
@@ -413,14 +415,14 @@ class StubPrograms:
 
     # ---------------- unified ragged (mixed) program ----------------
 
-    def _mixed(self, params, q_tokens, token_seq, token_pos, q_start,
-               q_len, kv_start, last_idx, kv_pages, page_table, joins,
-               scan_tok0, scan_pos0, step0_emits, capacity, counters,
-               state, rng, adapters):
+    def _mixed(self, params, tokens_buf, lanes_buf, kv_pages, page_table,
+               base_rng):
         """Host-math twin of engine/compiled.py's mixed program, emitting
         the SAME deterministic token chain as the legacy stub paths so
         checkpoint/resume stays token-exact across both program sets and
-        `expected_stream()` remains the oracle.
+        `expected_stream()` remains the oracle.  It takes the program's
+        three packed buffers and cuts them apart by the program's own
+        layout (engine/shapes.MixedLayout), in numpy.
 
         Step-0 discrimination mirrors the engine's packing contract: a
         lane sampling its FIRST token has counters==0 (stub_first_token of
@@ -429,16 +431,20 @@ class StubPrograms:
         (step0_emits==0 with scan_tok0>=0) re-enters the chain at its
         checkpointed token."""
         steps = self._cfg.steps_per_sync
-        toks = np.asarray(q_tokens)
-        qs = np.asarray(q_start)
-        ql = np.asarray(q_len)
-        ks = np.asarray(kv_start)
-        jn = np.asarray(joins)
-        st0 = np.asarray(scan_tok0)
-        sp0 = np.asarray(scan_pos0)
-        emits0 = np.asarray(step0_emits)
-        cap = np.asarray(capacity)
-        cnt = np.asarray(counters)
+        tokens_buf, lanes_buf = np.asarray(tokens_buf), np.asarray(lanes_buf)
+        cols = MixedLayout(
+            tokens_buf.shape[1], lanes_buf.shape[1],
+            np.shape(page_table)[1]).unpack(tokens_buf, lanes_buf)
+        toks = cols["q_tokens"]
+        qs = cols["q_start"]
+        ql = cols["q_len"]
+        ks = cols["kv_start"]
+        jn = cols["joins"]
+        st0 = cols["scan_tok0"]
+        sp0 = cols["scan_pos0"]
+        emits0 = cols["step0_emits"]
+        cap = cols["capacity"]
+        cnt = cols["counters"]
         B = qs.shape[0]
         # cost: the ragged step pays prefill for every packed prompt
         # token (non-decode lanes) + the scan pays the decode chunk

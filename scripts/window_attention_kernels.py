@@ -101,6 +101,8 @@ def main() -> int:
     rows = []
     for bq in args.bq:
         pk.WINDOW_BQ = bq
+        # the entry point is jitted and read WINDOW_BQ when it was traced
+        pk.window_attention_ragged_pallas.clear_cache()
         for name, (slices, T) in cases.items():
             T = -(-T // 32) * 32
             arrays, least, work = case(slices, T, lanes, sizes)

@@ -119,8 +119,9 @@ def standin_counts_dispatch_shapes(monkeypatch):
     program has since PR 33: its made-up engine pads one mixed dispatch in
     ten, so that `dispatch.padded_share`'s reader finds something to read
     against it, like every other reader; likewise the deliveries (PR 36)
-    and the host's parts, CPU seconds and pauses (PR 39), and the pages of
-    decode attention by reach (PR 42).  Kept here because
+    and the host's parts, CPU seconds and pauses (PR 39), the pages of
+    decode attention by reach (PR 42) and the transfers of a dispatch's
+    inputs (PR 45).  Kept here because
     a second conftest.py would take this one's module name."""
     standin = sys.modules.get("standin")
     if standin is None:  # not a test of the benchmark
@@ -161,6 +162,8 @@ def standin_counts_dispatch_shapes(monkeypatch):
         lines += ['engine_kv_decode_pages_total{model_name="bench",'
                   f'reach="{reach}"}} {n * pages}'
                   for reach, pages in (("own", 300), ("block", 400))]
+        # since PR 45 the transfers of a dispatch's inputs: three each
+        lines += [f'engine_dispatch_uploads_total{{model_name="bench"}} {3 * n}']
         return "\n".join(lines) + "\n"
 
     monkeypatch.setattr(standin.StandIn, "_metrics", with_dispatch_shapes)

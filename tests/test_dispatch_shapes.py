@@ -182,13 +182,15 @@ def _compile_counts():
 
 def _mixed_shapes_compiled():
     """(T, W) of every `mixed` compile recorded since the last reset, read
-    from the argument spellings (q_tokens int32[T] ..., page_table
-    int32[B,W])."""
+    from the argument spellings (the tokens' buffer int32[3,T], the lanes'
+    buffer, the cache, the page table int32[B,W], the base key:
+    shapes.MixedLayout)."""
     out = []
     for event in compile_fingerprints("mixed"):
-        t = re.search(r"int32\[(\d+)\], int32\[\1\], int32\[\1\]",
+        t = re.search(r"int32\[3,(\d+)\], int32\[\d+,(\d+)\], \(",
                       event["signature"])
-        w = re.search(r"\), int32\[\d+,(\d+)\], bool\[", event["signature"])
+        w = re.search(r"\), int32\[%s,(\d+)\], uint32\[2\]" % t.group(2),
+                      event["signature"])
         out.append((int(t.group(1)), int(w.group(1))))
     return out
 

@@ -649,7 +649,7 @@ class TestDispatchPhases:
             "chained": 0, "deliver": 0.0, "overlapped": 0, "inline": 0,
             **dict.fromkeys(PARTS, 0.0), **dict.fromkeys(CPU_COLUMNS, 0.0),
             **dict.fromkeys(PAUSES, 0.0), "kv_page_kernel": 0,
-            "kv_row_scatter": 0}
+            "kv_row_scatter": 0, "uploads": 0}
         assert sum(row[p] for p in PHASES) == clock.now()
         assert phases.serial == 2
         # an iteration that launched nothing is dropped, and so is idle time
@@ -1130,10 +1130,10 @@ class TestDispatchParts:
             assert r["deliver"] <= r["wait"] + r["route"]
             assert r["register"] <= r["route"]
             assert all(r["cpu_" + p] == 0.5 * r[p] for p in PHASES)
-        # a lane that is decoding has its sampling state rebuilt twice an
-        # iteration (`_prepare_chunk`, `_plan_ragged`), a first dispatch of
-        # prompts alone once
-        assert rows[0]["sampling"] == 1.0 and rows[-1]["sampling"] == 2.0
+        # the sampling state's columns are built once an iteration, by
+        # `_plan_ragged` (PR 45: `_prepare_chunk` builds none), and reach
+        # the device inside the lanes' buffer: three transfers a dispatch
+        assert all(r["sampling"] == 1.0 and r["uploads"] == 3 for r in rows)
         assert rows[-1]["register"] > 0.0  # the finishes give their pages back
         counter = _counter(label)
         for part in PARTS:

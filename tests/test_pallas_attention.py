@@ -167,14 +167,23 @@ class TestPallasPagedAttention:
 @pytest.fixture
 def lane_order(monkeypatch):
     """Inside `with lane_order():` the entry points hand the kernel its
-    sequences as they come, as they did before PR 42."""
+    sequences as they come, as they did before PR 42.  The entry points are
+    jitted (PR 45) and remember what they traced, so what they remember goes
+    on the way in, and on the way out."""
     import contextlib
+
+    def forget():
+        for entry in vars(pk).values():
+            if hasattr(entry, "clear_cache"):
+                entry.clear_cache()
 
     @contextlib.contextmanager
     def unsorted():
         with monkeypatch.context() as m:
             m.setattr(pk, "_by_length", lambda call, *args: call(*args))
+            forget()
             yield
+        forget()
 
     return unsorted
 

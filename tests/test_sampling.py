@@ -298,16 +298,17 @@ def test_host_label_is_the_device_s_branch(mix):
     """engine_sampler_dispatches_total's label, computed at plan time from
     the params, names the branch the program takes for the same rows."""
     rows, expected = MIXES[mix]
-    state, label = SamplingState.planned(rows)
-    on_device = int(jax.jit(sampler_truncates)(state))
+    columns, label = SamplingState.planned(rows)
+    on_device = int(jax.jit(sampler_truncates)(SamplingState(**columns)))
     assert SAMPLER_PATHS[on_device] == label == expected
 
 
 def test_host_label_compares_what_the_device_holds():
     """A top_p that only float64 tells from 1 is 1.0 on the device: the
     host's predicate reads float32 too."""
-    state, label = SamplingState.planned([P(top_p=1.0 - 1e-12)])
-    assert label == "plain" and not bool(sampler_truncates(state))
+    columns, label = SamplingState.planned([P(top_p=1.0 - 1e-12)])
+    assert label == "plain"
+    assert not bool(sampler_truncates(SamplingState(**columns)))
     assert SamplingState.planned([P(temperature=-1.0, top_k=4)])[1] == "plain"
 
 
@@ -568,8 +569,9 @@ def planned():
     real = SamplingState.planned
 
     def spy(params_list):
-        caller = next(f.function for f in inspect.stack()[1:]
-                      if f.function != "from_params")
+        caller = next(
+            f.function for f in inspect.stack()[1:]
+            if f.function not in ("from_params", "_sampling_state"))
         seen.setdefault(caller, []).append(list(params_list))
         return real(params_list)
 
@@ -598,7 +600,7 @@ def planned():
 
 
 @pytest.mark.parametrize("planner", [
-    "_prefill_detached_batch", "_admit_batch", "_prepare_chunk", "_plan_ragged"])
+    "_prefill_detached_batch", "_admit_batch", "_dispatch_chunk", "_plan_ragged"])
 def test_a_planner_s_empty_lanes_ask_for_no_sort(planned, planner):
     """An unseated lane's token is discarded: what a planner seats there
     must not put its batch on the truncating path."""

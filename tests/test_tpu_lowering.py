@@ -27,6 +27,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from kserve_tpu.engine import kvcache
 from kserve_tpu.ops import attention as att
+from kserve_tpu.ops import pallas_kv_write as kw
 from kserve_tpu.ops import pallas_paged_attention as pk
 
 # the suite's persistent compile cache stores these TPU executables too, and
@@ -180,7 +181,7 @@ def _compile_mixed(mc, cfg, cache_shape, width, monkeypatch,
     write or, `page_write` False, as the program was before it: the row
     scatter."""
     from kserve_tpu.engine.compiled import program_defs
-    from kserve_tpu.engine.sampling import SamplingState
+    from kserve_tpu.engine.shapes import MixedLayout
     from kserve_tpu.models import llama
     from kserve_tpu.parallel import sharding as shd
 
@@ -196,16 +197,15 @@ def _compile_mixed(mc, cfg, cache_shape, width, monkeypatch,
     def placed(tree):
         return jax.tree.map(lambda x: _abstract(x.shape, x.dtype), tree)
 
+    # the three packed buffers of a dispatch (shapes.MixedLayout)
+    tokens_buf, lanes_buf, page_table = (
+        _i32(*shape) for shape in MixedLayout(tokens, lanes, width).shapes)
     return jax.jit(fn, donate_argnums=donate).lower(
         placed(jax.eval_shape(
             lambda: llama.init_params(mc, jax.random.PRNGKey(1)))),
-        _i32(tokens), _i32(tokens), _i32(tokens),  # q_tokens, seq, pos
-        _i32(lanes), _i32(lanes), _i32(lanes), _i32(lanes),
+        tokens_buf, lanes_buf,
         [_abstract(cache_shape, jnp.bfloat16)] * mc.n_layers,
-        _i32(lanes, width), _abstract((lanes,), jnp.bool_),  # joins
-        _i32(lanes), _i32(lanes), _i32(lanes), _i32(lanes), _i32(lanes),
-        placed(jax.eval_shape(lambda: SamplingState.defaults(lanes))),
-        _abstract((2,), jnp.uint32), _i32(lanes),
+        page_table, _abstract((2,), jnp.uint32),  # the base key
     ).compile()
 
 
@@ -392,7 +392,7 @@ def _lower_mixed(mc, cfg, cache, width, monkeypatch):
     """The `mixed` program of `mc` under `cfg`, lowered for TPU from
     abstract arguments (`cache`: the program's K/V argument)."""
     from kserve_tpu.engine.compiled import program_defs
-    from kserve_tpu.engine.sampling import SamplingState
+    from kserve_tpu.engine.shapes import MixedLayout
     from kserve_tpu.models import llama
     from kserve_tpu.parallel import sharding as shd
 
@@ -401,19 +401,15 @@ def _lower_mixed(mc, cfg, cache, width, monkeypatch):
     lanes, tokens = cfg.max_batch_size, cfg.prefill_buckets[-1]
     fn, donate = program_defs(mc, cfg, shd.create_mesh())["mixed"]
 
-    def i32(*shape):
-        return jax.ShapeDtypeStruct(shape, jnp.int32)
-
+    # the three packed buffers of a dispatch (shapes.MixedLayout)
+    tokens_buf, lanes_buf, page_table = (
+        jax.ShapeDtypeStruct(shape, jnp.int32)
+        for shape in MixedLayout(tokens, lanes, width).shapes)
     return jax.jit(fn, donate_argnums=donate).trace(
         jax.eval_shape(
             lambda: llama.init_params(mc, jax.random.PRNGKey(1))),
-        i32(tokens), i32(tokens), i32(tokens),  # q_tokens, seq, pos
-        i32(lanes), i32(lanes), i32(lanes), i32(lanes),
-        cache, i32(lanes, width),
-        jax.ShapeDtypeStruct((lanes,), jnp.bool_),  # joins
-        i32(lanes), i32(lanes), i32(lanes), i32(lanes), i32(lanes),
-        jax.eval_shape(lambda: SamplingState.defaults(lanes)),
-        jax.ShapeDtypeStruct((2,), jnp.uint32), i32(lanes),
+        tokens_buf, lanes_buf, cache, page_table,
+        jax.ShapeDtypeStruct((2,), jnp.uint32),  # the base key
     ).lower(lowering_platforms=("tpu",))
 
 
@@ -438,6 +434,23 @@ def _decode_sat_mixed(width, monkeypatch):
     cache = jax.ShapeDtypeStruct(
         (cfg.num_pages, 2, mc.n_kv_heads, ps, mc.head_dim), jnp.bfloat16)
     return _lower_mixed(mc, cfg, [cache] * mc.n_layers, width, monkeypatch)
+
+
+def _entry_calls(text: str) -> dict:
+    """Call sites in a lowered program, by the kernels' entry points
+    (ops/pallas_*.py: jitted functions, so a program holds each once and
+    its layers call it)."""
+    import collections
+    import re
+
+    entries = [name for module in (pk, kw) for name, fn in vars(module).items()
+               if hasattr(fn, "clear_cache")]
+    calls = collections.Counter(re.findall(
+        r"call @(%s)(?:_\d+)?\(" % "|".join(entries), text))
+    defined = collections.Counter(re.findall(
+        r"func\.func private @(%s)(?:_\d+)?\(" % "|".join(entries), text))
+    assert all(defined[name] == 1 for name in calls), defined
+    return dict(calls)
 
 
 class TestMixedProgramTakesTheDecodeKernel:
@@ -468,10 +481,14 @@ class TestMixedProgramTakesTheDecodeKernel:
 
         text = _decode_sat_mixed(40, monkeypatch).as_text()
         gathers = re.findall(r'"stablehlo.gather"\(.*?-> (tensor<[^>]+>)', text)
-        layers = 2
-        assert gathers.count("tensor<48x40xi32>") == layers, gathers
-        assert gathers.count("tensor<48x32x128xbf16>") == 2 * layers, gathers
+        # once in the program's text: the entry point is a jitted function
+        # (PR 45), lowered once and called by both layers
+        assert gathers.count("tensor<48x40xi32>") == 1, gathers
+        assert gathers.count("tensor<48x32x128xbf16>") == 2, gathers
         assert "tensor<48x1x256xbf16>" not in gathers, gathers
+        assert _entry_calls(text) == {
+            "paged_attention_pallas": 2, "ragged_paged_attention_pallas": 2,
+            "append_rows": 2, "write_runs": 2}
 
 
 def _sorts_met_without_a_branch(hlo: str):
@@ -677,12 +694,16 @@ class TestLoopedMixedProgram:
         looped = self._lowered(monkeypatch, 4).as_text()
         one = self._lowered(monkeypatch, 1).as_text()
         kernels = re.findall(r'kernel_name = "([a-z_]+)"', looped)
-        # 2 layers: traced once under each loop, whatever the passes
-        # (each layer's write is the page kernel, once in each form)
+        # 2 layers, whatever the passes: every kernel's entry point is
+        # lowered ONCE a program (PR 45: the page write once in each form)
+        # and called by each layer under each loop
         assert sorted(kernels) == sorted(
             re.findall(r'kernel_name = "([a-z_]+)"', one)) == [
-                "kv_page_write"] * 4 + ["paged_attention_decode"] * 2 + [
-                "ragged_paged_attention"] * 2
+                "kv_page_write"] * 2 + ["paged_attention_decode"] + [
+                "ragged_paged_attention"]
+        assert _entry_calls(looped) == _entry_calls(one) == {
+            "paged_attention_pallas": 2, "ragged_paged_attention_pallas": 2,
+            "append_rows": 2, "write_runs": 2}
         # beside the sampler's two searches at each of its two sites:
         searches = 4
         assert looped.count("stablehlo.while") == 3 + searches  # passes, steps, passes
@@ -746,9 +767,13 @@ class TestLatentPagesAndGroupedExperts:
             mc, cfg, jax.eval_shape(layout.init_state), 16,
             monkeypatch).as_text()
         kernels = re.findall(r'kernel_name = "([a-z_]+)"', text)
-        # 3 layers: the packed step's kernel and the decode steps' once each
-        assert sorted(kernels) == ["latent_attention_decode"] * 3 + [
-            "latent_attention_ragged"] * 3
+        # 3 layers call the packed step's kernel and the decode steps', each
+        # lowered once (PR 45)
+        assert sorted(kernels) == [
+            "latent_attention_decode", "latent_attention_ragged"]
+        assert _entry_calls(text) == {
+            "latent_attention_decode_pallas": 3,
+            "latent_attention_ragged_pallas": 3}
         # 2 expert layers x (packed step + decode steps) x gate, up, down
         assert len(re.findall(r"ragged_dot", text)) >= 12
         # nothing over (tokens or pairs, experts, an expert's width)
