@@ -50,6 +50,7 @@ from ..metrics import (
     ENGINE_KV_OFFLOAD_BYTES,
     ENGINE_KV_CONTEXT_TOKENS,
     ENGINE_KV_DECODE_PAGES,
+    ENGINE_PACKED_LANES,
     ENGINE_KV_WRITE_CALLS,
     ENGINE_MOE_ASSIGNMENTS,
     ENGINE_MOE_EXPERT_HITS,
@@ -78,6 +79,7 @@ from ..metrics import (
     ENGINE_WEDGED,
     GENERATED_TOKENS,
     KV_DECODE_REACHES,
+    PACKED_LANE_PATHS,
     PROMPT_TOKENS,
     observe_request_timeline,
     observe_startup_phase,
@@ -184,12 +186,18 @@ def _decode_page_reach(pos, n, decode_steps: int,
     ops/pallas_paged_attention.length_order), the lanes of a block x the
     pages of its longest lane: the iterations of the kernel's loop x the
     ring slots an iteration has."""
+    step = np.arange(decode_steps)[:, None]
+    return _page_reach(
+        np.where(step < n, pages_needed(pos + step + 1, page_size), 0))
+
+
+def _page_reach(pages) -> Dict[str, int]:
+    """`pages` [calls, lanes], the pages a lane holds of its context at each
+    call of the decode kernel, summed by metrics.KV_DECODE_REACHES."""
     from ..ops.pallas_paged_attention import _pick_sb
 
-    step = np.arange(decode_steps)[:, None]
-    pages = np.where(step < n, pages_needed(pos + step + 1, page_size), 0)
     sb = _pick_sb(pages.shape[1])
-    longest = np.sort(pages, axis=1).reshape(decode_steps, -1, sb).max(axis=2)
+    longest = np.sort(pages, axis=1).reshape(len(pages), -1, sb).max(axis=2)
     return {"own": int(pages.sum()), "block": int(longest.sum()) * sb}
 
 
@@ -437,6 +445,10 @@ class LLMEngine:
             reach: ENGINE_KV_DECODE_PAGES.labels(
                 model_name=metrics_label, reach=reach)
             for reach in KV_DECODE_REACHES}
+        self._packed_lanes = {
+            path: ENGINE_PACKED_LANES.labels(
+                model_name=metrics_label, attention_path=path)
+            for path in PACKED_LANE_PATHS}
         self._kv_write_calls = {
             path: ENGINE_KV_WRITE_CALLS.labels(
                 model_name=metrics_label, write_path=path)
@@ -2802,6 +2814,31 @@ class LLMEngine:
             self._moe_assignments.inc(
                 tokens * mc.n_experts_per_tok * mc.n_expert_layers)
 
+    def _count_packed_lanes(self, q_len, kv_start, width: int) -> None:
+        """engine_packed_lanes_total for one packed step run at a table of
+        `width` pages and, where the program hands its single-token lanes
+        to the decode kernel from that width on (the report's
+        `packed_single_token_min_pages`), that call's work on the decode
+        attention's counters: one more step for those lanes, each at
+        `kv_start + 1` tokens of context, every other lane at 0 (the
+        kernel's `seq_lens` as
+        ops/pallas_paged_attention.ragged_single_token_split_pallas hands
+        them over)."""
+        q_len = np.asarray(q_len)
+        single = q_len == 1
+        min_pages = self.dispatch_report["attention"][
+            "packed_single_token_min_pages"]
+        split = min_pages is not None and width >= min_pages
+        self._packed_lanes["decode_kernel" if split else "ragged"].inc(
+            int(single.sum()))
+        self._packed_lanes["ragged"].inc(int((q_len > 1).sum()))
+        if split:
+            context = np.where(single, np.asarray(kv_start, np.int64) + 1, 0)
+            self._kv_context_tokens.inc(int(context.sum()))
+            for reach, pages in _page_reach(pages_needed(
+                    context, self.config.page_size)[None, :]).items():
+                self._kv_decode_pages[reach].inc(pages)
+
     def _count_window_ragged(self, q_len, kv_start) -> None:
         """engine_window_ragged_work_total for one packed step: the query at
         offset j of a slice that starts at `kv_start` sees min(kv_start + j
@@ -3852,6 +3889,7 @@ class LLMEngine:
                 self._shapes.steps, plan["scan_pos0"], plan["joins"],
                 plan["capacity"], decode_steps=self._shapes.steps - 1,
                 packed_tokens=plan["prefill_tokens"] + plan["decode_tokens"])
+            self._count_packed_lanes(plan["q_len"], plan["kv_start"], ran[1])
             if self._ring_window:
                 self._count_window_ragged(plan["q_len"], plan["kv_start"])
         phases.mark("wait")

@@ -444,7 +444,10 @@ ENGINE_KV_WRITE_CALLS = Counter(
 ENGINE_KV_CONTEXT_TOKENS = Counter(
     "engine_kv_context_tokens_total",
     "sum over a dispatch's decode steps of the cached tokens its live "
-    "lanes attend to: the work of decode attention, in tokens a cache row",
+    "lanes attend to: the work of decode attention, in tokens a cache row; "
+    "where the packed step hands its single-token lanes to the decode "
+    "kernel (engine_packed_lanes_total{attention_path=\"decode_kernel\"}) those "
+    "lanes' tokens too, as one more step",
     ["model_name"],
 )
 #: what is summed of a decode step's pages of context: the pages the lanes
@@ -457,8 +460,25 @@ ENGINE_KV_DECODE_PAGES = Counter(
     "sum over a dispatch's decode steps of pages of context, by reach: own = "
     "the pages its live lanes hold; block = over the decode kernel's blocks "
     "of lanes, lanes a block x the pages of the block's longest lane (what "
-    "it fetches and folds), the lanes dealt to blocks in order of length",
+    "it fetches and folds), the lanes dealt to blocks in order of length; "
+    "the packed step's call of the kernel over its single-token lanes "
+    "counts as one more step, where the program makes it",
     ["model_name", "reach"],
+)
+#: which kernel attends for a lane of the packed step (`attention_path`):
+#: the decode kernel in one call over the lanes that bring ONE token, where
+#: the program was built with that split
+#: (ops/attention.ragged_attention_path); the ragged kernel, or its XLA
+#: reference, for every other slice
+PACKED_LANE_PATHS = ("decode_kernel", "ragged")
+ENGINE_PACKED_LANES = Counter(
+    "engine_packed_lanes_total",
+    "lanes with a slice in a `mixed` dispatch's packed step, by the kernel "
+    "that attends for them over the pool's pages: decode_kernel (a slice of "
+    "one token, in a program whose packed step hands such lanes to the "
+    "decode kernel) | ragged (every other slice); counted at planning from "
+    "the plan's slice lengths and how the program was built",
+    ["model_name", "attention_path"],
 )
 # Expert layers (models/moe.py).  Assignments are counted at launch from the
 # dispatch's tokens; hits and peak load are summed IN the program over its

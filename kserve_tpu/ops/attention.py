@@ -20,6 +20,9 @@ same online-softmax program:
   numerics ground truth; also the production path off-TPU).
 - `ragged_paged_attention_pallas` (ops/pallas_paged_attention.py): the
   fused kernel, verified against the reference in interpret mode.
+- `ragged_single_token_split_pallas` (same file): the lanes that bring ONE
+  token through the decode kernel in one call, the longer slices through
+  the ragged kernel; `ragged_attention_path` says where it is traced.
 
 Role parity: replaces vLLM's CUDA PagedAttention, which the reference uses
 through the vLLM engine (SURVEY.md §2.3 "Sequence/context parallel" row).
@@ -282,6 +285,18 @@ def _should_use_pallas(d: int, quantized: bool, table_width: int, batch: int,
     return min_pages is not None and table_width >= min_pages
 
 
+def _decode_auto(lanes: int, d: int, kv_pages, page_table,
+                 backend: Optional[str] = None) -> bool:
+    """`_should_use_pallas` read off the arrays of a call: `lanes` queries
+    of head size `d`, one a lane, over `kv_pages` through `page_table`."""
+    quantized = isinstance(kv_pages, tuple)
+    pages = kv_pages[0] if quantized else kv_pages
+    return _should_use_pallas(
+        d, quantized, int(page_table.shape[1]), lanes,
+        backend or jax.default_backend(), int(pages.shape[3]),
+        int(pages.shape[2]), int(pages.shape[0]))
+
+
 def _should_use_page_write(d: int, quantized: bool, latent: bool,
                            backend: str, sharded: bool = False) -> bool:
     """Whether a K/V write runs as the page kernel
@@ -488,6 +503,46 @@ def dense_stride_for(width: int, align: int) -> int:
     return sp
 
 
+def ragged_attention_path(
+    q, kv_pages, page_table, use_pallas: Optional[bool] = None,
+    scale: Optional[float] = None, window=None,
+    dense_stride: Optional[int] = None, backend: Optional[str] = None,
+) -> str:
+    """Which form of the ragged contract `ragged_paged_attention` traces for
+    these arguments (arrays or their shapes), from what a trace can see:
+
+    - `xla_gather`: the reference, off the TPU, over int8 pages and for
+      heads the kernel cannot tile (`_should_use_ragged_pallas`);
+    - `pallas_ragged`: the ragged kernel over every slice;
+    - `pallas_ragged+decode`: the ragged kernel over the slices longer than
+      one token and the DECODE kernel, in one call over the lanes, for the
+      lanes that bring one token (a decode lane of length `kv_start + 1`;
+      ops/pallas_paged_attention.ragged_single_token_split_pallas).  Taken
+      exactly where the decode steps of the same program take that kernel
+      for the same lanes over the same pages, by `paged_attention`'s own
+      rule: full attention (`window is None`), no `scale` override, and
+      `_should_use_pallas` on (head size, page shape, table width, lanes),
+      or `use_pallas=True`.  A shape whose decode steps gather (pages of
+      1-2 K/V heads under the measured width) keeps the ragged kernel for
+      its single-token lanes too, and the lanes of the dense packing
+      (`dense_stride`, the speculative program) share blocks already."""
+    d = q.shape[-1]
+    quantized = isinstance(kv_pages, tuple)
+    backend = backend or jax.default_backend()
+    ragged = use_pallas
+    if ragged is None:
+        ragged = _should_use_ragged_pallas(d, backend, quantized)
+    if not ragged:
+        return "xla_gather"
+    decode = use_pallas
+    if decode is None:
+        decode = _decode_auto(
+            int(page_table.shape[0]), d, kv_pages, page_table, backend)
+    if decode and window is None and scale is None and dense_stride is None:
+        return "pallas_ragged+decode"
+    return "pallas_ragged"
+
+
 def ragged_paged_attention(
     q: jnp.ndarray,  # [T, nq, d]
     kv_pages,
@@ -503,32 +558,36 @@ def ragged_paged_attention(
     # decode/spec-verify packing (< RAGGED_BQ shares blocks between lanes;
     # ignored by the XLA reference, which is per-token already)
 ) -> jnp.ndarray:
-    """Dispatch the ragged contract between the fused Pallas kernel and the
-    XLA gather reference.  The ragged kernel (unlike the decode kernel)
-    masks sliding windows and applies scale overrides natively, so the
-    dispatch is head-alignment + page dtype + backend; use_pallas=True
-    forces the kernel (raising on an unsupported head_dim or int8 pages),
-    False forces the reference."""
-    d = q.shape[-1]
-    quantized = isinstance(kv_pages, tuple)
-    if use_pallas is None:
-        use_pallas = _should_use_ragged_pallas(
-            d, jax.default_backend(), quantized)
-    if use_pallas:
-        if quantized:
-            raise ValueError(
-                "the ragged pallas kernel does not compile over the int8 "
-                "KV cache (docs/kernels.md)")
-        from .pallas_paged_attention import ragged_paged_attention_pallas
-
-        return ragged_paged_attention_pallas(
+    """Dispatch the ragged contract between the fused Pallas kernels and
+    the XLA gather reference (`ragged_attention_path`).  The ragged kernel
+    (unlike the decode kernel) masks sliding windows and applies scale
+    overrides natively, so the dispatch is head-alignment + page dtype +
+    backend; use_pallas=True forces the kernel (raising on an unsupported
+    head_dim or int8 pages), False forces the reference."""
+    path = ragged_attention_path(
+        q, kv_pages, page_table, use_pallas, scale, window, dense_stride)
+    if path == "xla_gather":
+        return ragged_paged_attention_xla(
             q, kv_pages, page_table, q_start, q_len, kv_start,
-            window=window, logit_softcap=logit_softcap, scale=scale,
-            dense_stride=dense_stride,
+            logit_softcap=logit_softcap, scale=scale, window=window,
         )
-    return ragged_paged_attention_xla(
+    if isinstance(kv_pages, tuple):
+        raise ValueError(
+            "the ragged pallas kernel does not compile over the int8 "
+            "KV cache (docs/kernels.md)")
+    from .pallas_paged_attention import (
+        ragged_paged_attention_pallas,
+        ragged_single_token_split_pallas,
+    )
+
+    if path == "pallas_ragged+decode":
+        return ragged_single_token_split_pallas(
+            q, kv_pages, page_table, q_start, q_len, kv_start,
+            logit_softcap=logit_softcap)
+    return ragged_paged_attention_pallas(
         q, kv_pages, page_table, q_start, q_len, kv_start,
-        logit_softcap=logit_softcap, scale=scale, window=window,
+        window=window, logit_softcap=logit_softcap, scale=scale,
+        dense_stride=dense_stride,
     )
 
 
@@ -598,6 +657,24 @@ def _window_mixed_name(model_config, engine_config, backend: str) -> str:
     return "pallas_window_ragged" if kernel else "xla_ring_window"
 
 
+def _packed_ragged_layers(model_config) -> int:
+    """Layers whose packed step attends over the pool's pages through
+    `ragged_paged_attention` with neither a window nor a scale override:
+    every layer of a Llama-family model without sliding windows
+    (models/llama.forward_ragged: with them every layer carries a traced
+    `attn_window`, 0 on its full rows); of a hybrid table its
+    `gqa_attention` rows but the last layer that writes state, whose
+    output is taken at the sampled rows (models/hybrid.forward_ragged)."""
+    mc = model_config
+    if not mc.is_hybrid:
+        plain = mc.sliding_window <= 0 and mc.attn_scale is None
+        return mc.n_layers if plain else 0
+    table = mc.layer_table()
+    last_writer = max(i for i, row in enumerate(table) if row.writes != "none")
+    return sum(row.kind == "gqa_attention" and i != last_writer
+               for i, row in enumerate(table))
+
+
 def describe_attention_dispatch(model_config, engine_config,
                                 backend: str) -> dict:
     """Which implementation each program's attention is built with, from
@@ -613,7 +690,12 @@ def describe_attention_dispatch(model_config, engine_config,
     `decode_pallas_min_pages` is the width it starts at, None where it
     runs at every width (or, with `decode: xla_gather`, at none).
     `kv_write` says how each kind of cache the model has is written
-    (`_should_use_page_write`)."""
+    (`_should_use_page_write`).  `packed_single_token_min_pages` is the
+    table width from which `mixed`'s packed step hands the lanes that
+    bring one token to the decode kernel (`ragged_attention_path`'s
+    `pallas_ragged+decode`: wherever the decode steps take that kernel and
+    a layer of the packed step reads the pool's pages through
+    `ragged_paged_attention` with no window), None where it never does."""
     mc, cfg = model_config, engine_config
     quantized = cfg.kv_quant == "int8"
     min_pages = None
@@ -634,6 +716,7 @@ def describe_attention_dispatch(model_config, engine_config,
             "mixed": "pallas_latent_ragged" if pallas else "xla_ragged_gather",
             "decode": "pallas_latent_decode" if pallas else "xla_gather",
             "decode_pallas_min_pages": None,
+            "packed_single_token_min_pages": None,
             "shard_map": False,
             "kv_write": kv_write,
         }
@@ -660,6 +743,9 @@ def describe_attention_dispatch(model_config, engine_config,
                 "pallas_decode" if decode else "xla_gather"),
             "decode": "pallas_decode" if decode else "xla_gather",
             "decode_pallas_min_pages": min_pages,
+            # the packed step's slices go through the ring form, and the
+            # full layer is taken at the sampled rows, one query a lane
+            "packed_single_token_min_pages": None,
             "shard_map": False,
             "kv_write": kv_write,
         }
@@ -683,11 +769,17 @@ def describe_attention_dispatch(model_config, engine_config,
     mixed = "pallas_ragged" if ragged else "xla_ragged_gather"
     if "window_kv" in written:  # plain window rows beside the paged ones
         mixed = _window_mixed_name(mc, cfg, backend) + "+" + mixed
+    # `ragged_attention_path` at the model's sizes: both kernels, one device
+    # (tp / sp > 1 thread a window through shard_map), and a layer whose
+    # packed step calls `ragged_paged_attention` with no window
+    splits = (ragged and decode and cfg.tp == 1 and cfg.sp == 1
+              and _packed_ragged_layers(mc) > 0)
     return {
         "backend": backend,
         "mixed": mixed,
         "decode": "pallas_decode" if decode else "xla_gather",
         "decode_pallas_min_pages": min_pages,
+        "packed_single_token_min_pages": (min_pages or 0) if splits else None,
         # tp/sp>1: both run per shard inside shard_map over the model axis
         "shard_map": cfg.tp > 1 or cfg.sp > 1,
         "kv_write": kv_write,
@@ -731,12 +823,7 @@ def paged_attention(
         # sliding window): auto-dispatch falls back rather than raising
         use_pallas = False
     if use_pallas is None:
-        pages = kv_pages[0] if quantized else kv_pages
-        use_pallas = _should_use_pallas(
-            d, quantized, int(page_table.shape[1]), int(q.shape[0]),
-            jax.default_backend(), int(pages.shape[3]), int(pages.shape[2]),
-            int(pages.shape[0]),
-        )
+        use_pallas = _decode_auto(int(q.shape[0]), d, kv_pages, page_table)
     if use_pallas:
         if quantized:
             raise ValueError(
@@ -749,9 +836,12 @@ def paged_attention(
         if scale is not None:
             raise ValueError(
                 "pallas paged attention does not take a scale override")
+        # the arguments, given and left out, of the packed step's call
+        # (ragged_single_token_split_pallas): a jitted entry point keys its
+        # traces on them, and a program holds the kernel once for both
         return paged_attention_pallas(
-            q, kv_pages, page_table, seq_lens, logit_softcap=logit_softcap
-        )
+            q, kv_pages, page_table, seq_lens, logit_softcap=logit_softcap,
+            interpret=False)
     return paged_attention_xla(
         q, kv_pages, page_table, seq_lens, logit_softcap,
         scale=scale, window=window,

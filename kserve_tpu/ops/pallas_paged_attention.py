@@ -919,6 +919,56 @@ def ragged_paged_attention_pallas(
       *operands)
 
 
+@_entry("logit_softcap", "interpret")
+def ragged_single_token_split_pallas(
+    q: jnp.ndarray,  # [T, nq, d] — packed at RAGGED_BQ-aligned offsets
+    kv_pages: jnp.ndarray,  # [num_pages, 2, nkv, ps, d]
+    page_table: jnp.ndarray,  # [B, W] int32
+    q_start: jnp.ndarray,  # [B] int32
+    q_len: jnp.ndarray,  # [B] int32
+    kv_start: jnp.ndarray,  # [B] int32
+    logit_softcap: float = 0.0,
+    interpret: bool = False,
+) -> jnp.ndarray:
+    """The ragged contract with the work split by slice length: the lanes
+    whose slice is ONE token go through the decode kernel in one call over
+    the lanes, every longer slice through the ragged kernel.
+
+    The ragged kernel gives each RAGGED_BQ-token block to one sequence and
+    walks that sequence's pages one page an iteration, alone: a batch of
+    single decode tokens is a block a lane in series, seven of each block's
+    eight query rows masked.  The decode kernel takes the same lanes
+    `MAX_SB` to a block, sorted by length, a page DMA a lane in flight
+    every iteration (48 lanes of ~350 tokens: 98 us against 447,
+    docs/kernels.md "The packed step's single-token lanes").  The slice's
+    K/V is in the pages before attention runs (kvcache.write_ragged_kv), so
+    a one-token slice at `kv_start` IS a decode lane of length `kv_start +
+    1`: the same keys, the same float32 products and online softmax.
+
+    Which lanes are single-token is read from `q_len` here, in the
+    program: the decode call sees length 0 for every other lane (an empty
+    lane costs a block nothing: its loop runs to its longest lane, and
+    `_by_length` puts the empty ones together), the ragged call sees
+    `q_len` 0 for the single-token ones (`_ragged_block_metadata` then
+    owns their blocks to nobody and the kernel's loop runs 0 pages there;
+    rows outside every slice come back exact zero).  The decode rows are
+    put back at `q_start` by one scatter of B rows."""
+    T = q.shape[0]
+    single = q_len == 1
+    rows = q.at[jnp.where(single, q_start, 0)].get(
+        mode="promise_in_bounds")  # [B, nq, d]
+    decoded = paged_attention_pallas(
+        rows, kv_pages, page_table, jnp.where(single, kv_start + 1, 0),
+        logit_softcap=logit_softcap, interpret=interpret)
+    chunks = ragged_paged_attention_pallas(
+        q, kv_pages, page_table, q_start, jnp.where(single, 0, q_len),
+        kv_start, logit_softcap=logit_softcap, interpret=interpret)
+    # a lane that is not single-token aims past the buffer and is dropped
+    lane = jnp.arange(q_len.shape[0], dtype=jnp.int32)
+    return chunks.at[jnp.where(single, q_start, T + lane)].set(
+        decoded, mode="drop", unique_indices=True)
+
+
 # ---------------- latent pages (models/latent.py) ----------------
 #
 # A latent-attention layer keeps ONE row a token: [compressed K/V | the

@@ -120,9 +120,9 @@ def standin_counts_dispatch_shapes(monkeypatch):
     ten, so that `dispatch.padded_share`'s reader finds something to read
     against it, like every other reader; likewise the deliveries (PR 36)
     and the host's parts, CPU seconds and pauses (PR 39), the pages of
-    decode attention by reach (PR 42) and the transfers of a dispatch's
-    inputs (PR 45).  Kept here because
-    a second conftest.py would take this one's module name."""
+    decode attention by reach (PR 42), the transfers of a dispatch's
+    inputs (PR 45) and the packed step's lanes by kernel (PR 46).  Kept here
+    because a second conftest.py would take this one's module name."""
     standin = sys.modules.get("standin")
     if standin is None:  # not a test of the benchmark
         return
@@ -164,6 +164,11 @@ def standin_counts_dispatch_shapes(monkeypatch):
                   for reach, pages in (("own", 300), ("block", 400))]
         # since PR 45 the transfers of a dispatch's inputs: three each
         lines += [f'engine_dispatch_uploads_total{{model_name="bench"}} {3 * n}']
+        # since PR 46 the packed step's lanes by the kernel that attends for
+        # them: 48 decode lanes beside one prompt chunk
+        lines += ['engine_packed_lanes_total{model_name="bench",'
+                  f'attention_path="{path}"}} {n * lanes}'
+                  for path, lanes in (("decode_kernel", 48), ("ragged", 1))]
         return "\n".join(lines) + "\n"
 
     monkeypatch.setattr(standin.StandIn, "_metrics", with_dispatch_shapes)

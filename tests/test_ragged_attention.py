@@ -18,6 +18,7 @@ from kserve_tpu.engine.kvcache import (
     write_ragged_kv,
 )
 from kserve_tpu.ops.attention import (
+    ragged_attention_path,
     ragged_paged_attention,
     ragged_paged_attention_xla,
     ragged_token_metadata,
@@ -25,6 +26,7 @@ from kserve_tpu.ops.attention import (
 from kserve_tpu.ops.pallas_paged_attention import (
     RAGGED_BQ,
     ragged_paged_attention_pallas,
+    ragged_single_token_split_pallas,
 )
 
 PS = 8  # page size
@@ -46,8 +48,8 @@ class RaggedCase:
     """
 
     def __init__(self, lanes, seed=0, quantized=False, window=0,
-                 scale=None, softcap=0.0, d=D):
-        # lanes: list of (kv_start, q_len)
+                 scale=None, softcap=0.0, d=D, W=8):
+        # lanes: list of (kv_start, q_len); W: the page table's width
         rng = np.random.RandomState(seed)
         self.lanes = lanes
         self.window = window
@@ -55,7 +57,6 @@ class RaggedCase:
         self.softcap = softcap
         self.d = d
         B = len(lanes)
-        W = 8  # page-table width
         num_pages = 1 + B * W
         self.q_start = np.zeros((B,), np.int32)
         self.q_len = np.array([q for _, q in lanes], np.int32)
@@ -281,6 +282,120 @@ class TestRaggedDispatch:
             pytest.skip("CPU-only guard")
         with pytest.raises(ValueError, match="head_dim"):
             ragged_paged_attention(*case.args(), use_pallas=True)
+
+
+#: (kv_start, q_len) a lane: what a packed step holds beside its decode
+#: lanes (lanes of ONE token, which the split hands to the decode kernel)
+SPLIT_CASES = {
+    "decode_lanes_beside_a_chunk": [(10, 1), (8, 5), (3, 1), (0, 7), (17, 1)],
+    "chunk_of_2": [(10, 1), (6, 2), (31, 1)],
+    "chunk_of_8": [(5, 1), (16, 8), (9, 1), (0, 8)],
+    "chunk_of_9": [(12, 1), (7, 9), (40, 1)],
+    "chunk_of_128": [(20, 1), (64, 128), (3, 1), (0, 128)],
+    "empty_lanes": [(0, 0), (10, 1), (0, 0), (8, 5), (0, 0), (2, 1)],
+    "one_token_prompt_at_0": [(0, 1), (23, 1), (0, 3)],
+    "no_single_token_lane": [(8, 5), (0, 7), (0, 0), (16, 2)],
+    "decode_only": [(10, 1), (3, 1), (17, 1), (1, 1), (63, 1), (8, 1)],
+    "nine_lanes_two_blocks": [(h, 1) for h in (30, 2, 17, 0, 9, 44, 5, 61, 12)]
+    + [(0, 0)] * 7,
+}
+
+
+class TestSingleTokenSplit:
+    """The packed step's single-token lanes through the decode kernel
+    (`ragged_single_token_split_pallas`, interpret mode): the ragged
+    contract's answers, whatever the batch is made of."""
+
+    @pytest.mark.parametrize("name", sorted(SPLIT_CASES))
+    def test_split_is_the_xla_reference(self, name):
+        lanes = SPLIT_CASES[name]
+        case = RaggedCase(lanes, seed=len(name), W=32)
+        got = np.asarray(ragged_single_token_split_pallas(
+            *case.args(), interpret=True))
+        _assert_close(got, _xla(case), case)
+        # and the one kernel's, row for row
+        _assert_close(got, _pallas(case), case, atol=1e-5)
+
+    def test_softcap(self):
+        case = RaggedCase(SPLIT_CASES["decode_lanes_beside_a_chunk"], seed=3,
+                          softcap=6.0)
+        got = np.asarray(ragged_single_token_split_pallas(
+            *case.args(), logit_softcap=6.0, interpret=True))
+        _assert_close(got, _xla(case), case)
+
+    def test_the_ragged_call_walks_no_single_token_block(self):
+        """What the ragged kernel is handed: `q_len` 0 where the slice is
+        one token, so those blocks belong to nobody (-1: 0 pages)."""
+        from kserve_tpu.ops.pallas_paged_attention import (
+            _ragged_block_metadata)
+
+        case = RaggedCase(SPLIT_CASES["chunk_of_9"], seed=5, W=32)
+        q_len = jnp.asarray(case.q_len)
+        block_seq, _ = _ragged_block_metadata(
+            jnp.asarray(case.q_start), jnp.where(q_len == 1, 0, q_len),
+            case.T // RAGGED_BQ, RAGGED_BQ)
+        assert np.asarray(block_seq).tolist() == [-1, 1, 1, -1]
+
+
+def _shapes(lanes=48, width=40, kv_heads=8, d=128, quantized=False):
+    q = jax.ShapeDtypeStruct((512, 32, d), jnp.bfloat16)
+    pages = jax.ShapeDtypeStruct((2300, 2, kv_heads, 16, d), jnp.bfloat16)
+    if quantized:
+        pages = (jax.ShapeDtypeStruct(pages.shape, jnp.int8),
+                 jax.ShapeDtypeStruct(pages.shape[:-1], jnp.float32))
+    return q, pages, jax.ShapeDtypeStruct((lanes, width), jnp.int32)
+
+
+class TestSplitIsDerived:
+    """`ragged_attention_path`: the split is traced where the same
+    program's decode steps take the decode kernel, and nowhere else."""
+
+    def test_decode_sat_s_shape_splits_on_a_tpu(self):
+        assert ragged_attention_path(
+            *_shapes(), backend="tpu") == "pallas_ragged+decode"
+
+    @pytest.mark.parametrize("why,shapes,kwargs,path", [
+        ("a window", {}, {"window": 4096}, "pallas_ragged"),
+        ("a scale override", {}, {"scale": 0.1}, "pallas_ragged"),
+        ("the dense packing", {}, {"dense_stride": 2}, "pallas_ragged"),
+        ("int8 pages", {"quantized": True}, {}, "xla_gather"),
+        ("pages of one K/V head: the decode steps gather",
+         {"kv_heads": 1}, {}, "pallas_ragged"),
+        ("pages of two K/V heads under 64 pages: the decode steps gather",
+         {"kv_heads": 2, "width": 40}, {}, "pallas_ragged"),
+        ("lanes no block divides: the decode steps gather",
+         {"lanes": 1}, {}, "pallas_ragged"),
+        ("heads the kernels cannot tile", {"d": 64}, {}, "xla_gather"),
+        ("the reference asked for", {}, {"use_pallas": False}, "xla_gather"),
+    ])
+    def test_today_s_call_stays_for(self, why, shapes, kwargs, path):
+        assert ragged_attention_path(
+            *_shapes(**shapes), backend="tpu", **kwargs) == path, why
+
+    def test_two_kv_heads_split_from_the_width_their_decode_steps_do(self):
+        assert ragged_attention_path(
+            *_shapes(kv_heads=2, width=64), backend="tpu"
+        ) == "pallas_ragged+decode"
+
+    @pytest.mark.parametrize("backend", ["cpu", "gpu"])
+    def test_off_the_tpu_nothing_changes(self, backend):
+        assert ragged_attention_path(
+            *_shapes(), backend=backend) == "xla_gather"
+
+    @pytest.mark.parametrize("lanes,width,kv_heads", [
+        (48, 8, 8), (48, 40, 8), (12, 24, 16), (16, 80, 2), (8, 16, 1),
+        (7, 40, 8)])
+    def test_the_split_is_the_decode_steps_own_rule(
+            self, lanes, width, kv_heads):
+        """One predicate: wherever `paged_attention` auto-selects the
+        kernel for the lanes, the packed step splits; and only there."""
+        from kserve_tpu.ops.attention import _should_use_pallas
+
+        decode = _should_use_pallas(
+            128, False, width, lanes, "tpu", 16, kv_heads, 2300)
+        path = ragged_attention_path(
+            *_shapes(lanes, width, kv_heads), backend="tpu")
+        assert path == ("pallas_ragged+decode" if decode else "pallas_ragged")
 
 
 class TestDenseBlockPacking:

@@ -413,10 +413,11 @@ def _lower_mixed(mc, cfg, cache, width, monkeypatch):
     ).lower(lowering_platforms=("tpu",))
 
 
-def _decode_sat_mixed(width, monkeypatch):
-    """`mixed` at the decode-sat cell's shape (48 lanes, T = 512, Qwen3-4B's
-    32/8 x 128 heads, 16-token pages).  Two layers and a narrow MLP: the
-    attention shapes are the cell's, the rest only has to lower."""
+def _decode_sat_cell():
+    """(model, engine config) at the decode-sat cell's shape (48 lanes, T =
+    512, Qwen3-4B's 32/8 x 128 heads, 16-token pages).  Two layers and a
+    narrow MLP: the attention shapes are the cell's, the rest only has to
+    lower."""
     import dataclasses
 
     from kserve_tpu.engine.types import EngineConfig
@@ -431,8 +432,15 @@ def _decode_sat_mixed(width, monkeypatch):
         max_batch_size=lanes, page_size=ps, num_pages=2300,
         max_pages_per_seq=40, max_prefill_len=tokens,
         prefill_buckets=(128, tokens), dtype="bfloat16")
+    return mc, cfg
+
+
+def _decode_sat_mixed(width, monkeypatch):
+    """`mixed` of `_decode_sat_cell`, lowered for TPU."""
+    mc, cfg = _decode_sat_cell()
     cache = jax.ShapeDtypeStruct(
-        (cfg.num_pages, 2, mc.n_kv_heads, ps, mc.head_dim), jnp.bfloat16)
+        (cfg.num_pages, 2, mc.n_kv_heads, cfg.page_size, mc.head_dim),
+        jnp.bfloat16)
     return _lower_mixed(mc, cfg, [cache] * mc.n_layers, width, monkeypatch)
 
 
@@ -451,6 +459,15 @@ def _entry_calls(text: str) -> dict:
         r"func\.func private @(%s)(?:_\d+)?\(" % "|".join(entries), text))
     assert all(defined[name] == 1 for name in calls), defined
     return dict(calls)
+
+
+#: call sites of the kernels' entry points in a two-layer `mixed` program
+#: whose packed step splits (PR 46): each layer's packed step calls the
+#: split, whose one lowering calls the ragged kernel once and the decode
+#: kernel once; the decode steps' two layers call that same decode kernel
+PACKED_SPLIT_CALLS = {
+    "ragged_single_token_split_pallas": 2, "ragged_paged_attention_pallas": 1,
+    "paged_attention_pallas": 3, "append_rows": 2, "write_runs": 2}
 
 
 class TestMixedProgramTakesTheDecodeKernel:
@@ -484,11 +501,36 @@ class TestMixedProgramTakesTheDecodeKernel:
         # once in the program's text: the entry point is a jitted function
         # (PR 45), lowered once and called by both layers
         assert gathers.count("tensor<48x40xi32>") == 1, gathers
-        assert gathers.count("tensor<48x32x128xbf16>") == 2, gathers
+        # the queries in, the rows back; and the packed step's one query a
+        # lane out of its buffer (PR 46)
+        assert gathers.count("tensor<48x32x128xbf16>") == 3, gathers
         assert "tensor<48x1x256xbf16>" not in gathers, gathers
-        assert _entry_calls(text) == {
-            "paged_attention_pallas": 2, "ragged_paged_attention_pallas": 2,
-            "append_rows": 2, "write_runs": 2}
+        assert _entry_calls(text) == PACKED_SPLIT_CALLS
+
+    @pytest.mark.parametrize("width", DECODE_SAT_WIDTHS)
+    def test_the_packed_step_hands_its_single_token_lanes_to_that_kernel(
+            self, width, monkeypatch):
+        """PR 46: at every width the cell compiles, each layer's packed
+        step calls the split, which calls the ragged kernel and the SAME
+        lowered decode kernel the scan's layers call (the program holds it
+        once: no custom call more than before), and puts the lanes' rows
+        back with one scatter of 48 rows: no sort, no gather of pages."""
+        import re
+
+        text = _decode_sat_mixed(width, monkeypatch).as_text()
+        assert _entry_calls(text) == PACKED_SPLIT_CALLS
+        kernels = re.findall(r'kernel_name = "([a-z_]+)"', text)
+        assert kernels.count("paged_attention_decode") == 1
+        assert kernels.count("ragged_paged_attention") == 1
+        split = text[text.index(
+            "func.func private @ragged_single_token_split_pallas"):]
+        split = split[:split.index("\n  }\n") + 1]
+        assert split.count('"stablehlo.scatter"(') == 1
+        assert re.search(
+            r"tensor<48x32x128xbf16>\) -> tensor<512x32x128xbf16>", split)
+        assert "stablehlo.sort" not in split
+        assert att.describe_attention_dispatch(
+            *_decode_sat_cell(), "tpu")["packed_single_token_min_pages"] == 0
 
 
 def _sorts_met_without_a_branch(hlo: str):
@@ -701,9 +743,7 @@ class TestLoopedMixedProgram:
             re.findall(r'kernel_name = "([a-z_]+)"', one)) == [
                 "kv_page_write"] * 2 + ["paged_attention_decode"] + [
                 "ragged_paged_attention"]
-        assert _entry_calls(looped) == _entry_calls(one) == {
-            "paged_attention_pallas": 2, "ragged_paged_attention_pallas": 2,
-            "append_rows": 2, "write_runs": 2}
+        assert _entry_calls(looped) == _entry_calls(one) == PACKED_SPLIT_CALLS
         # beside the sampler's two searches at each of its two sites:
         searches = 4
         assert looped.count("stablehlo.while") == 3 + searches  # passes, steps, passes
