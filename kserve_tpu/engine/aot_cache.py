@@ -3,7 +3,7 @@
 A replica restart today re-traces and re-compiles every engine program
 (`mixed`, the legacy prefill/decode set, the inject scatters) even though
 the programs are 100% identical across replicas of the same deployment —
-restart cost is dominated by redundant work (ROADMAP item 3; SLINFER
+restart cost is dominated by redundant work (SLINFER
 arXiv:2507.00507 and DeepServe arXiv:2501.14417 both put cold start on
 the critical path of scale-to-zero).  This module makes compiled
 executables a *persistent artifact*:
@@ -118,7 +118,9 @@ def _mesh_devices(mesh) -> list:
 #: where the device programs are written: a change to any of these files
 #: may change the compiled artifact, so their bytes are part of the key
 _PROGRAM_SOURCES = ("models", "ops", "parallel", "engine/compiled.py",
-                    "engine/sampling.py", "engine/kvcache.py",
+                    "engine/sampling.py",
+                    # StateLayout shapes a program's state, `inject` its pages
+                    "engine/kvcache.py",
                     # `mixed` cuts its arguments apart by shapes.MixedLayout
                     "engine/shapes.py")
 

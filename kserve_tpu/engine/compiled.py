@@ -113,16 +113,17 @@ class _CompileCounting:
     """Wrap a jitted program and count its jit-cache misses (compiles AND
     retraces) into the engine_xla_compiles_total counter, labeled by the
     program's fixed name.  A growing count at steady state is the recompile
-    alarm ROADMAP item 2's perf oracle needs (shape-bucket drift, weak-type
-    wobble, donation mismatch all show up here before they show up as tail
-    latency).  Each counted miss also records the dispatch's argument
-    signature via record_compile_fingerprint, so the retrace-budget test
-    can diff the spellings of compile N and N+1.  The signature is built
-    from avals (which survive donation) AFTER the dispatch — cost is one
-    tree-flatten per compile event, and one clock read per steady-state
-    call: a call that missed is timed whole (trace, compile, first run)
-    into engine_xla_compile_seconds_total, which is how long it kept the
-    engine's loop from serving anything, /metrics included."""
+    alarm beside the HLO perf oracle (analysis/hlo_oracle): shape-bucket
+    drift, weak-type wobble and donation mismatch all show up here before
+    they show up as tail latency.  Each counted miss also records the
+    dispatch's argument signature via record_compile_fingerprint, so the
+    retrace-budget test can diff the spellings of compile N and N+1.  The
+    signature is built from avals (which survive donation) AFTER the
+    dispatch — cost is one tree-flatten per compile event, and one clock
+    read per steady-state call: a call that missed is timed whole (trace,
+    compile, first run) into engine_xla_compile_seconds_total, which is how
+    long it kept the engine's loop from serving anything, /metrics
+    included."""
 
     __slots__ = ("_name", "_fn", "_seen")
 

@@ -15,13 +15,9 @@ TPU-first:
 
 Host<->device traffic per step is one [B] token fetch + tiny control arrays.
 
-Module layout (the r4 review asked for the scheduler and the device-step
-code to live apart):
-- engine/types.py     EngineConfig + runtime dataclasses + deadline fetcher
-- engine/compiled.py  every jitted device program (prefill/decode/inject)
-- engine/prefix_cache.py  shared-prefix page cache
-- this file           admission, slots, chunked prefill, preemption,
-                      offload, P/D, the run loop — the host-side scheduler
+This file is the host-side scheduler: admission, slots, chunked prefill,
+preemption, offload, P/D, the run loop.  README.md maps the modules beside
+it (types, shapes, limits, work, compiled, kvcache, prefix_cache).
 """
 
 from __future__ import annotations
@@ -48,25 +44,10 @@ from ..metrics import (
     ENGINE_FIRST_TOKEN_DISPATCHES,
     ENGINE_KV_DISK_BYTES,
     ENGINE_KV_OFFLOAD_BYTES,
-    ENGINE_KV_CONTEXT_TOKENS,
-    ENGINE_KV_DECODE_PAGES,
-    ENGINE_PACKED_LANES,
-    ENGINE_KV_WRITE_CALLS,
-    ENGINE_MOE_ASSIGNMENTS,
-    ENGINE_MOE_EXPERT_HITS,
-    ENGINE_MOE_EXPERTS_HELD,
-    ENGINE_MOE_PAIRS_ELSEWHERE,
-    ENGINE_MOE_PEAK_LOAD,
     ENGINE_KV_PAGES_FREE,
     ENGINE_KV_PAGES_TOTAL,
     ENGINE_KV_TOKEN_BYTES,
-    ENGINE_LAYER_PASSES,
     ENGINE_PREEMPTIONS,
-    ENGINE_SSD_SCAN_TOKENS,
-    ENGINE_SSD_UPDATE_CALLS,
-    ENGINE_SSD_UPDATE_LANE_STEPS,
-    ENGINE_WINDOW_LANE_STEPS,
-    ENGINE_WINDOW_RAGGED_WORK,
     ENGINE_STATE_BYTES,
     ENGINE_STATE_RESETS,
     ENGINE_STATE_SLOTS_IN_USE,
@@ -78,13 +59,7 @@ from ..metrics import (
     ENGINE_STEP_DURATION,
     ENGINE_WEDGED,
     GENERATED_TOKENS,
-    KV_DECODE_REACHES,
-    PACKED_LANE_PATHS,
     PROMPT_TOKENS,
-    observe_request_timeline,
-    observe_startup_phase,
-)
-from ..metrics import (
     DEADLINE_REJECTED,
     GENERATION_CHECKPOINTS,
     GENERATION_RESUMES,
@@ -92,6 +67,8 @@ from ..metrics import (
     KV_PREFIX_HIT_TOKENS,
     SPEC_TOKENS,
     TOKENS_SALVAGED,
+    observe_request_timeline,
+    observe_startup_phase,
 )
 from ..lifecycle.checkpoint import GenerationCheckpoint, GenerationPreempted
 from ..lifecycle.state import ReplicaDrainingError
@@ -100,7 +77,6 @@ from ..observability import (
     DELIVERIES,
     CPU_COLUMNS,
     DISPATCH_COLUMNS,
-    KV_WRITE_PATHS,
     PARTS,
     PHASES,
     DispatchPhases,
@@ -128,6 +104,7 @@ from .kvcache import (
     pages_needed,
     pages_of_passes,
 )
+from .limits import check_request, resolve_serving
 from .sampling import SAMPLER_PATHS, SamplingParams, SamplingState, unpacked
 from .shapes import (
     FITS,
@@ -136,6 +113,7 @@ from .shapes import (
     MixedLayout,
 )
 from .tokenizer import BaseTokenizer, IncrementalDetokenizer
+from .work import DispatchWork
 
 
 from .types import (  # noqa: F401 — re-exported: the public engine surface
@@ -173,149 +151,6 @@ _PART_COLUMNS = [DISPATCH_COLUMNS.index(part) for part in PARTS]
 _UPLOADS_COLUMN = DISPATCH_COLUMNS.index("uploads")
 _CPU_COLUMNS = slice(DISPATCH_COLUMNS.index(CPU_COLUMNS[0]),
                      DISPATCH_COLUMNS.index(CPU_COLUMNS[-1]) + 1)
-
-
-def _decode_page_reach(pos, n, decode_steps: int,
-                       page_size: int) -> Dict[str, int]:
-    """Pages of context over a dispatch's decode steps, by
-    metrics.KV_DECODE_REACHES: lane b attends to pos[b] + s + 1 tokens at
-    decode step s < n[b] and to none after (`seq_lens` as
-    models/llama.decode_step hands them to the kernel).  `own` sums the
-    pages the lanes hold; `block` sums, over the decode kernel's blocks
-    (`_pick_sb(lanes)` lanes each, dealt in order of length:
-    ops/pallas_paged_attention.length_order), the lanes of a block x the
-    pages of its longest lane: the iterations of the kernel's loop x the
-    ring slots an iteration has."""
-    step = np.arange(decode_steps)[:, None]
-    return _page_reach(
-        np.where(step < n, pages_needed(pos + step + 1, page_size), 0))
-
-
-def _page_reach(pages) -> Dict[str, int]:
-    """`pages` [calls, lanes], the pages a lane holds of its context at each
-    call of the decode kernel, summed by metrics.KV_DECODE_REACHES."""
-    from ..ops.pallas_paged_attention import _pick_sb
-
-    sb = _pick_sb(pages.shape[1])
-    longest = np.sort(pages, axis=1).reshape(len(pages), -1, sb).max(axis=2)
-    return {"own": int(pages.sum()), "block": int(longest.sum()) * sb}
-
-
-def _refuse_looped(model_config, engine_config) -> None:
-    """What a model whose stack runs several times a token cannot do yet,
-    by name (ROADMAP.md Queue R names the mechanisms)."""
-    if not model_config.is_looped:
-        return
-    refused = []
-    if model_config.early_exit_threshold < 1.0:
-        refused.append(
-            f"early_exit_threshold={model_config.early_exit_threshold} < 1 "
-            "(per-token early exit: the lanes of one dispatch would run "
-            "different numbers of passes)")
-    if engine_config.pp > 1:
-        refused.append("pp>1 (a stage boundary inside the loop over passes)")
-    if engine_config.sp > 1:
-        refused.append("sp>1 (ring-attention prefill under the loop over "
-                       "passes is untested)")
-    if refused:
-        raise NotImplementedError(
-            f"not supported yet for a looped model ({model_config.n_passes} "
-            "passes over shared weights): " + "; ".join(refused))
-
-
-def _refuse_latent(model_config, engine_config, role: str) -> None:
-    """What a model of latent-attention layers and routed experts
-    (models/latent.py, models/moe.py) cannot do yet, by name (ROADMAP.md
-    Queue R names the mechanisms).  Its pages ARE a lane's whole state (a
-    latent row carries absolute positions in its roped key, as K does), so
-    the prefix cache stays as configured: on by default."""
-    cfg = engine_config
-    refused = []
-    if cfg.tp > 1:
-        refused.append("tp>1 (latent attention across chips: heads shard, "
-                       "the latent page would be replicated; a chip's share "
-                       "of the experts)")
-    if cfg.pp > 1:
-        refused.append("pp>1 (staged layers assume one kind of layer)")
-    if cfg.sp > 1:
-        refused.append("sp>1 (ring-attention prefill over K and V per head)")
-    if cfg.kv_quant != "none":
-        refused.append(f"kv_quant={cfg.kv_quant} (a latent row has no scales)")
-    if cfg.weight_quant != "none":
-        refused.append(f"weight_quant={cfg.weight_quant} (int8 over experts "
-                       "and the latent projections)")
-    if cfg.spec_decode_k is not None:
-        refused.append("spec_decode_k (the dense verify program has no "
-                       "attention over latent pages)")
-    if cfg.kv_offload != "none" or cfg.kv_persist_dir:
-        refused.append("kv_offload / kv_persist_dir (spill and page-in move "
-                       "K/V pages; the latent row is not on their wire)")
-    if cfg.use_ragged is False:
-        refused.append("use_ragged=False (the legacy programs)")
-    if role != "both":
-        refused.append(f"role={role} (the P/D wire ships K/V pages)")
-    if refused:
-        raise NotImplementedError(
-            "not supported yet for a model with latent-attention layers and "
-            "routed experts: " + "; ".join(refused))
-
-
-def resolve_hybrid_serving(model_config, engine_config,
-                           role: str = "both") -> None:
-    """THE place that says what a model with recurrent or ring state
-    (models/hybrid.py: the Mamba-1 family with window rings, the Mamba-2
-    family and the Cohere family's roped window rings alike) cannot do
-    yet.  A lane's pages are no longer its
-    whole state, and that state cannot be rewound, shared or shipped, so:
-    what was asked for explicitly is refused here, at start-up, by name;
-    what was left at its default is resolved to off, with a log line.
-    Request-time features that run the legacy programs are refused at
-    submit (`LLMEngine._check_hybrid_request`).
-
-    It also says what a LOOPED model (LlamaConfig.n_passes > 1) cannot do
-    yet: every program the engine can pick for it runs all the passes
-    (models/llama._run_passes is under each forward), or is refused here."""
-    _refuse_looped(model_config, engine_config)
-    if not model_config.is_hybrid:
-        return
-    if model_config.is_latent:
-        _refuse_latent(model_config, engine_config, role)
-        return
-    cfg = engine_config
-    refused = []
-    if cfg.pp > 1:
-        refused.append("pp>1 (staged layers assume one kind of layer)")
-    if cfg.sp > 1:
-        refused.append("sp>1 (ring-attention prefill)")
-    if cfg.kv_quant != "none":
-        refused.append(f"kv_quant={cfg.kv_quant}")
-    if cfg.weight_quant != "none":
-        refused.append(f"weight_quant={cfg.weight_quant}")
-    if cfg.spec_decode_k is not None:
-        refused.append("spec_decode_k (a rejected draft rewinds kv_len; "
-                       "recurrent state has no rewind)")
-    if cfg.kv_offload != "none" or cfg.kv_persist_dir:
-        refused.append("kv_offload / kv_persist_dir (tier offload and the "
-                       "persistent prefix store move pages only)")
-    if cfg.prefix_cache:
-        refused.append("prefix_cache (a prefix's pages do not hold the "
-                       "recurrent state or the rings at its boundary)")
-    if cfg.use_ragged is False:
-        refused.append("use_ragged=False (the legacy programs)")
-    if role != "both":
-        refused.append(f"role={role} (the P/D wire ships pages only)")
-    if refused:
-        raise NotImplementedError(
-            "not supported yet for a model with Mamba-1 / Mamba-2 / window / "
-            "shared-cache layers: " + "; ".join(refused))
-    if cfg.prefix_cache is None:
-        cfg.prefix_cache = False
-        logger.info(
-            "hybrid model: prefix cache adoption resolved to OFF (snapshots "
-            "of recurrent state and of rings are not implemented); "
-            "speculative decoding, "
-            "tier offload, the P/D wire of pages, logprobs and penalties "
-            "lanes are refused by name")
 
 
 class LLMEngine:
@@ -435,66 +270,6 @@ class LLMEngine:
         self._dispatch_fits = {
             fit: ENGINE_DISPATCH_SHAPE.labels(model_name=metrics_label, fit=fit)
             for fit in FITS}
-        # engine_layer_passes_total / engine_kv_context_tokens_total: what
-        # a launch's forward steps run (_count_forward)
-        self._layer_passes = ENGINE_LAYER_PASSES.labels(
-            model_name=metrics_label)
-        self._kv_context_tokens = ENGINE_KV_CONTEXT_TOKENS.labels(
-            model_name=metrics_label)
-        self._kv_decode_pages = {
-            reach: ENGINE_KV_DECODE_PAGES.labels(
-                model_name=metrics_label, reach=reach)
-            for reach in KV_DECODE_REACHES}
-        self._packed_lanes = {
-            path: ENGINE_PACKED_LANES.labels(
-                model_name=metrics_label, attention_path=path)
-            for path in PACKED_LANE_PATHS}
-        self._kv_write_calls = {
-            path: ENGINE_KV_WRITE_CALLS.labels(
-                model_name=metrics_label, write_path=path)
-            for path in KV_WRITE_PATHS}
-        # engine_moe_*_total: pairs counted at launch, hits and peak load
-        # summed in the program (the `mixed` program's last two rows)
-        self._moe_assignments = ENGINE_MOE_ASSIGNMENTS.labels(
-            model_name=metrics_label)
-        self._moe_hits = ENGINE_MOE_EXPERT_HITS.labels(
-            model_name=metrics_label)
-        self._moe_peak = ENGINE_MOE_PEAK_LOAD.labels(model_name=metrics_label)
-        self._moe_elsewhere = ENGINE_MOE_PAIRS_ELSEWHERE.labels(
-            model_name=metrics_label)
-        if model_config.has_expert_sums:
-            ENGINE_MOE_EXPERTS_HELD.labels(
-                model_name=metrics_label, of=str(model_config.n_experts)).set(
-                model_config.n_experts_held or model_config.n_experts)
-        # engine_ssd_*_total: what the Mamba-2 mixers' two forms are asked
-        # to do, counted at launch
-        self._ssd_layers = sum(
-            kind == "mamba2" for kind in model_config.mixer_kinds or ())
-        self._ssd_scan_tokens = ENGINE_SSD_SCAN_TOKENS.labels(
-            model_name=metrics_label)
-        self._ssd_update_calls = ENGINE_SSD_UPDATE_CALLS.labels(
-            model_name=metrics_label)
-        self._ssd_update_lane_steps = ENGINE_SSD_UPDATE_LANE_STEPS.labels(
-            model_name=metrics_label)
-        # engine_window_*_total: layers that keep a ring a lane, and their
-        # window (0 where there is none: nothing is counted)
-        self._ring_layers = sum(
-            r.writes == "window_kv" for r in model_config.layer_table())
-        self._ring_window = (
-            model_config.sliding_window if self._ring_layers else 0)
-        self._window_lane_steps = {
-            bound: ENGINE_WINDOW_LANE_STEPS.labels(
-                model_name=metrics_label, bound=bound)
-            for bound in ("yes", "no")}
-        self._window_ragged_work = {
-            unit: ENGINE_WINDOW_RAGGED_WORK.labels(
-                model_name=metrics_label, unit=unit)
-            for unit in ("queries", "pairs", "keys")}
-        self._expert_stats = model_config.has_expert_sums
-        # pairs counted on the host at launch (tokens x experts a token x
-        # expert layers) unless the program counts its own
-        self._host_counts_pairs = (model_config.n_experts > 0
-                                   and not model_config.counts_routed_pairs)
         # when the fetch worker last had a result on the host
         self._fetch_ready_at: Optional[float] = None
         # checkpoints carry this as model_name; resume_generation rejects a
@@ -502,34 +277,19 @@ class LLMEngine:
         # (engine-dp0, engine-dp1, ...) share one weights identity and a
         # checkpoint from any of them resumes on any other
         self._ckpt_label = checkpoint_label or metrics_label
-        shd.validate_tp(model_config, engine_config.tp)
-        resolve_hybrid_serving(model_config, engine_config)
-        if model_config.is_hybrid and (lora_adapters or lora_stacked):
-            raise NotImplementedError("LoRA adapters over a hybrid model")
-        if engine_config.sp > 1 and (
-                model_config.sliding_window > 0
-                or model_config.query_pre_attn_scalar is not None):
-            raise NotImplementedError(
-                "sp>1 (ring-attention prefill) does not support sliding "
-                "windows or attention-scale overrides yet")
         # which (T, W) program a dispatch runs in: engine/shapes.py decides,
         # every planner below asks it
         self._shapes = DispatchShapes.of(
             model_config, engine_config, jax.default_backend())
-        if engine_config.pp > 1:
-            # supported composition today: pp x tp (x dp via disjoint
-            # replica meshes).  Everything else raises loudly here rather
-            # than inside a jitted trace.
-            bad = []
-            if engine_config.sp > 1:
-                bad.append("sp")
-            if bad:
-                raise NotImplementedError(
-                    f"pp>1 does not compose with {bad} yet")
-            if model_config.n_layers % engine_config.pp != 0:
-                raise ValueError(
-                    f"n_layers={model_config.n_layers} not divisible by "
-                    f"pp={engine_config.pp}")
+        # what this model cannot be served with is refused here, by name; the
+        # engine steps through `mixed` where topology and sizes admit it
+        resolve_serving(
+            model_config, engine_config, shapes=self._shapes,
+            lora=bool(lora_adapters or lora_stacked))
+        self._use_mixed = (
+            self._shapes.admits_mixed(engine_config)
+            if engine_config.use_ragged is None
+            else bool(engine_config.use_ragged))
         if engine_config.prefix_cache is None:
             engine_config.prefix_cache = True
         self.mesh = shd.create_mesh(
@@ -560,8 +320,6 @@ class LLMEngine:
         self.adapter_ids: Dict[str, int] = {}
         lora_layer_stacks = None
         if lora_adapters or lora_stacked:
-            if model_config.n_experts > 0:
-                raise NotImplementedError("LoRA over MoE layers is not supported yet")
             from ..models import lora as lora_mod
 
             if lora_stacked is not None:
@@ -835,59 +593,11 @@ class LLMEngine:
         # preemption choice (and therefore its whole report) would hinge on
         # a tie-break
         self._admission_seq = 0.0
-        # unified ragged program (docs/kernels.md): resolve the use_ragged
-        # knob against what the topology supports
-        align = self._shapes.align
-        mixed_ok = (
-            engine_config.pp == 1
-            and engine_config.sp == 1
-            and self._shapes.fits_pure_decode
-        )
-        if engine_config.use_ragged and not mixed_ok:
-            raise NotImplementedError(
-                "use_ragged=True requires pp==1, sp==1 and max_batch_size "
-                "(x the kernel's block alignment) <= the largest prefill "
-                "bucket; set use_ragged=None/False for this topology"
-            )
-        self._use_mixed = (
-            mixed_ok if engine_config.use_ragged is None
-            else bool(engine_config.use_ragged)
-        )
         # speculative decoding + dense decode packing (docs/kernels.md):
         # spec_decode_k=None keeps today's mixed-only behavior; an int K
         # adds the decode-only `mixed_decode` program — dense (K+1)-token
         # slices, on-device draft/verify/accept, depth-2 chaining
-        spec_k = engine_config.spec_decode_k
-        if spec_k is not None:
-            if spec_k < 0:
-                raise ValueError(
-                    f"spec_decode_k must be >= 0, got {spec_k}")
-            if not self._use_mixed:
-                raise NotImplementedError(
-                    "spec_decode_k requires the unified ragged (mixed) "
-                    "path; it does not compose with use_ragged=False, "
-                    "pp>1 or sp>1")
-            from ..ops.attention import dense_stride_for
-
-            stride = dense_stride_for(spec_k + 1, align)
-            if align > 1 and (engine_config.max_batch_size * stride) % align:
-                raise ValueError(
-                    "spec_decode_k on the Pallas kernel path needs "
-                    "max_batch_size * padded-slice stride "
-                    f"({engine_config.max_batch_size}*{stride}) to be a "
-                    f"multiple of the {align}-token block")
-            # the [B, V] draft table shards lane rows over the model axis
-            # (sharding.draft_table_pspec) — an indivisible batch would
-            # only surface as a JAX sharding error at the first dense
-            # dispatch, mid-serving
-            tp_size = self.mesh.shape[shd.MODEL_AXIS]
-            if engine_config.max_batch_size % tp_size:
-                raise ValueError(
-                    "spec_decode_k needs max_batch_size "
-                    f"({engine_config.max_batch_size}) divisible by the "
-                    f"tensor-parallel mesh axis ({tp_size}): the draft "
-                    "table shards lane rows over it")
-        self._spec_k = spec_k
+        self._spec_k = spec_k = engine_config.spec_decode_k
         # worst-case per-lane advance of one dispatch: every round accepts
         # all K drafts plus the bonus token.  Page growth and the
         # predictable-finish chain gate both plan against it.
@@ -923,24 +633,11 @@ class LLMEngine:
                 "mixed_decode; dense/speculative stepping disabled",
                 self._spec_k)
         if self._mixed_fn is None and self._use_mixed:
-            if engine_config.use_ragged:
-                # an EXPLICIT opt-in must not silently serve the legacy
-                # dispatch behavior (different compile-count budget and
-                # batching) — same contract as the topology gate above
-                raise NotImplementedError(
-                    "use_ragged=True but the compiled program set has no "
-                    "`mixed` program (pre-ragged stub or pp build)"
-                )
-            logger.info(
-                "ragged mixed program unavailable in this program set; "
-                "falling back to the legacy dispatch paths")
-            self._use_mixed = False
-        if model_config.is_hybrid and not self._use_mixed:
+            # build_compiled builds `mixed` wherever engine/limits.py admits
+            # the regime: only an injected program set can lack it
             raise NotImplementedError(
-                "a hybrid model runs the mixed program only (the legacy "
-                "programs assume one kind of layer): max_batch_size x the "
-                f"{align}-token slice alignment must fit the "
-                "largest prefill bucket")
+                "the compiled program set has no `mixed` program "
+                "(a pre-ragged stub)")
         # what this replica was BUILT with — dispatch regime and, per
         # program family, the attention implementation — logged at start
         # and served on /v1/internal/scheduler/state, so a TPU replica
@@ -953,11 +650,10 @@ class LLMEngine:
                 model_config, engine_config, jax.default_backend()),
             "shapes": self._shapes.published(),
         }
-        # layers a pass writes by each K/V write path (_count_forward): the
-        # report's path of each kind of cache x the layers of that kind
-        self._kv_write_layers = dict.fromkeys(KV_WRITE_PATHS, 0)
-        for kind, path in self.dispatch_report["attention"]["kv_write"].items():
-            self._kv_write_layers[path] += len(getattr(layout, kind + "_layers"))
+        # what a launch runs, counted for the roofline readers
+        self._work = DispatchWork(
+            model_config, layout, self.dispatch_report["attention"],
+            metrics_label, wrote=self._phases.wrote)
         self._set_state_gauges()
         # what the pool is made of does not change while the engine lives
         ENGINE_KV_PAGES_TOTAL.labels(model_name=metrics_label).set(
@@ -1041,8 +737,9 @@ class LLMEngine:
         self._decode_penalized_lp_fn = p.decode_penalized_lp
         self._inject_fn = p.inject
         self._inject_q_fn = p.inject_q
-        # the unified ragged program; absent on program sets that predate
-        # it (or pp>1 builds), which forces the legacy dispatch paths
+        # the unified ragged program: absent on pp / sp builds, which step
+        # through the legacy programs; an injected program set that lacks it
+        # where the engine steps through it is refused (__init__)
         self._mixed_fn = getattr(p, "mixed", None)
         # the (T, W) pairs `mixed` is loaded in, which its planner fits a
         # dispatch to (shapes.LoadedPairs): what the AOT cache preloaded
@@ -1808,7 +1505,7 @@ class LLMEngine:
             raise ValueError(
                 f"prompt+max_tokens exceeds max_model_len {self.config.max_model_len}"
             )
-        self._check_hybrid_request(params)
+        check_request(self.model_config, params)
         self._check_accepting()
         deadline = self._admission_deadline()
         queue: asyncio.Queue = asyncio.Queue()
@@ -1820,24 +1517,6 @@ class LLMEngine:
             timeline=self._new_timeline(rid, len(prompt_ids)),
         )
         return self._submit_and_stream(req)
-
-    def _check_hybrid_request(self, params: Optional[SamplingParams],
-                              kv_wire: bool = False) -> None:
-        """The request-time half of resolve_hybrid_serving: logprobs and
-        penalties lanes run the legacy programs, and the P/D wire ships
-        pages, which are not a hybrid model's whole state."""
-        if not self.model_config.is_hybrid:
-            return
-        if kv_wire:
-            raise ValueError(
-                "the P/D wire of KV pages is not supported for a model with "
-                "recurrent state or latent pages")
-        if params is not None and (
-                params.has_penalties or params.logprobs is not None):
-            raise ValueError(
-                "logprobs and sampling penalties run the legacy programs, "
-                "which a model with recurrent state or latent pages does "
-                "not have")
 
     def _new_timeline(self, rid: str, n_prompt: int) -> RequestTimeline:
         """Stamp `received` NOW (the sync part of submit) and capture the
@@ -1910,7 +1589,7 @@ class LLMEngine:
             raise NotImplementedError(
                 "KV injection over a quantized cache is not supported yet"
             )
-        self._check_hybrid_request(params, kv_wire=True)
+        check_request(self.model_config, params, kv_wire=True)
         # validation runs HERE (sync), not at first __anext__: a shape
         # mismatch inside _run_loop would kill the engine for all traffic,
         # not just this request (version-skewed prefill peer)
@@ -1977,7 +1656,7 @@ class LLMEngine:
         Parity: the KV-connector role of the reference's disaggregated
         serving (workload_kvcache.go, llm_inference_service_types.go:105-110)
         with the transfer payload produced TPU-side in one gather."""
-        self._check_hybrid_request(params, kv_wire=True)
+        check_request(self.model_config, params, kv_wire=True)
         if self.config.kv_quant != "none":
             raise NotImplementedError(
                 "detached prefill (P/D transfer) over a quantized KV cache "
@@ -2062,7 +1741,7 @@ class LLMEngine:
         state = SamplingState.from_params(params_list)
         rng = jax.random.fold_in(self._base_rng, self._next_step())
         try:
-            self._count_forward(1, legacy_prefill=True)
+            self._work.forward(1, legacy_prefill=True)
             first, self.kv_pages = self._prefill_fn(
                 self.params,
                 jnp.asarray(tokens),
@@ -2605,7 +2284,7 @@ class LLMEngine:
         lp_tuple = None
         prefill_t0 = self._clock.now()
         # one legacy prefill forward, either program
-        self._count_forward(1, legacy_prefill=True)
+        self._work.forward(1, legacy_prefill=True)
         if use_fused_call:
             prefill_fn = self._prefill_lp_fn if want_lp else self._prefill_fn
             out = prefill_fn(
@@ -2759,101 +2438,6 @@ class LLMEngine:
             "token_bytes": layout.token_bytes(),
         }
 
-    def _count_forward(self, steps: int, pos=None, live=None, capacity=None,
-                       decode_steps: int = 0, packed_tokens: int = 0,
-                       legacy_prefill: bool = False) -> None:
-        """Count what a launch runs: `steps` forward steps (each all the
-        model's passes, each pass one K/V write a writing layer: by the path
-        the program was built with, and by the row scatter in a
-        `legacy_prefill` program whatever the others take) and, over its
-        `decode_steps` decode steps, the cached tokens the live lanes attend
-        to.  Lane b, live at position
-        pos[b], attends to pos[b] + s + 1 tokens at decode step s while it
-        stays under its page capacity: the device's own rule
-        (compiled._make_decode / _make_mixed), evaluated on the host; the
-        pages of those tokens are counted beside them, as the lanes hold
-        them and as the decode kernel's blocks of lanes walk them
-        (_decode_page_reach).  The
-        tokens that pass the model (`packed_tokens` in the packed step, one
-        a live lane and decode step) are each routed to `n_experts_per_tok`
-        experts in every expert layer, where every expert layer sees them
-        all and every expert is held; else the program counts its pairs
-        (LlamaConfig.counts_routed_pairs)."""
-        mc = self.model_config
-        self._layer_passes.inc(steps * mc.n_passes)
-        for path, layers in self._kv_write_layers.items():
-            if layers:
-                path = "row_scatter" if legacy_prefill else path
-                self._kv_write_calls[path].inc(steps * mc.n_passes * layers)
-                self._phases.wrote(path, steps * mc.n_passes * layers)
-        tokens = packed_tokens
-        if decode_steps and pos is not None:
-            pos = np.asarray(pos, np.int64)
-            n = np.where(np.asarray(live),
-                         np.clip(np.asarray(capacity) - pos, 0, decode_steps), 0)
-            self._kv_context_tokens.inc(int(np.sum(n * pos + n * (n + 1) // 2)))
-            for reach, pages in _decode_page_reach(
-                    pos, n, decode_steps, self.config.page_size).items():
-                self._kv_decode_pages[reach].inc(pages)
-            tokens += int(np.sum(n))
-            if self._ring_window:
-                # step s of a lane at pos attends to pos + s + 1 tokens: past
-                # the window from s = window - pos on
-                free = np.clip(self._ring_window - pos, 0, n)
-                self._window_lane_steps["no"].inc(int(np.sum(free)))
-                self._window_lane_steps["yes"].inc(int(np.sum(n - free)))
-        if self._ssd_layers:
-            self._ssd_scan_tokens.inc(packed_tokens * self._ssd_layers)
-            self._ssd_update_calls.inc(decode_steps * self._ssd_layers)
-            self._ssd_update_lane_steps.inc(
-                (tokens - packed_tokens) * self._ssd_layers)
-        if self._host_counts_pairs and tokens:
-            # every expert is held and every expert layer sees every token:
-            # what is routed is multiplied.  Else the counts are the
-            # program's own and come back with the dispatch's tokens
-            self._moe_assignments.inc(
-                tokens * mc.n_experts_per_tok * mc.n_expert_layers)
-
-    def _count_packed_lanes(self, q_len, kv_start, width: int) -> None:
-        """engine_packed_lanes_total for one packed step run at a table of
-        `width` pages and, where the program hands its single-token lanes
-        to the decode kernel from that width on (the report's
-        `packed_single_token_min_pages`), that call's work on the decode
-        attention's counters: one more step for those lanes, each at
-        `kv_start + 1` tokens of context, every other lane at 0 (the
-        kernel's `seq_lens` as
-        ops/pallas_paged_attention.ragged_single_token_split_pallas hands
-        them over)."""
-        q_len = np.asarray(q_len)
-        single = q_len == 1
-        min_pages = self.dispatch_report["attention"][
-            "packed_single_token_min_pages"]
-        split = min_pages is not None and width >= min_pages
-        self._packed_lanes["decode_kernel" if split else "ragged"].inc(
-            int(single.sum()))
-        self._packed_lanes["ragged"].inc(int((q_len > 1).sum()))
-        if split:
-            context = np.where(single, np.asarray(kv_start, np.int64) + 1, 0)
-            self._kv_context_tokens.inc(int(context.sum()))
-            for reach, pages in _page_reach(pages_needed(
-                    context, self.config.page_size)[None, :]).items():
-                self._kv_decode_pages[reach].inc(pages)
-
-    def _count_window_ragged(self, q_len, kv_start) -> None:
-        """engine_window_ragged_work_total for one packed step: the query at
-        offset j of a slice that starts at `kv_start` sees min(kv_start + j
-        + 1, window) keys; the slice must read the ring tokens its first
-        query sees and its own."""
-        R, layers = self._ring_window, self._ring_layers
-        n, s = np.asarray(q_len, np.int64), np.asarray(kv_start, np.int64)
-        under = np.clip(R - s, 0, n)  # queries whose context is <= window
-        pairs = under * s + under * (under + 1) // 2 + (n - under) * R
-        keys = np.where(n > 0, np.minimum(s, R - 1) + n, 0)
-        work = self._window_ragged_work
-        work["queries"].inc(int(n.sum()) * layers)
-        work["pairs"].inc(int(pairs.sum()) * layers)
-        work["keys"].inc(int(keys.sum()) * layers)
-
     def _set_state_gauges(self) -> None:
         occupancy = self._state_occupancy()
         ENGINE_STATE_SLOTS_IN_USE.labels(model_name=self._mlabel).set(
@@ -2996,7 +2580,7 @@ class LLMEngine:
                 tl = pf["req"].timeline
                 if tl is not None:
                     tl.mark_prefill_start(chunk_t0)
-                self._count_forward(1, legacy_prefill=True)
+                self._work.forward(1, legacy_prefill=True)
                 pf["logits"], self.kv_pages = self._prefill_chunk_fn(
                     self.params,
                     jnp.asarray(tokens),
@@ -3643,7 +3227,7 @@ class LLMEngine:
                 "decode", n_active, meta["page_table"].shape[1], 0, n_active,
                 chained=tokens_dev is not None)
             self._sampler_dispatches[sampler_path].inc()
-            self._count_forward(
+            self._work.forward(
                 self._shapes.steps, meta["pos"], meta["active"],
                 meta["capacity"], decode_steps=self._shapes.steps)
         with phases.span("call"):
@@ -3883,15 +3467,7 @@ class LLMEngine:
             self._loaded.ran(ran)
             self._dispatch_fits[plan["fit"]].inc()
             self._sampler_dispatches[plan["sampler_path"]].inc()
-            # the packed step, then steps - 1 decode steps over the joining
-            # lanes
-            self._count_forward(
-                self._shapes.steps, plan["scan_pos0"], plan["joins"],
-                plan["capacity"], decode_steps=self._shapes.steps - 1,
-                packed_tokens=plan["prefill_tokens"] + plan["decode_tokens"])
-            self._count_packed_lanes(plan["q_len"], plan["kv_start"], ran[1])
-            if self._ring_window:
-                self._count_window_ragged(plan["q_len"], plan["kv_start"])
+            self._work.packed(plan, ran[1], self._shapes.steps)
         phases.mark("wait")
         # the fetch is handed to its worker first, so that the result is
         # stamped when the device has it and a delivery that outlasts the
@@ -3900,18 +3476,7 @@ class LLMEngine:
         # happen in the turns of the event loop that the await leaves
         chunk_np = await self._fetch_async(out, self._deliver_overlapped)
         phases.resumed(self._fetch_ready_at)
-        if self._expert_stats:
-            n = self.state_layout.expert_sums
-            chunk_np, sums = chunk_np[:-n], chunk_np[-n:, 0]
-            self._moe_hits.inc(int(sums[0]))
-            self._moe_peak.inc(int(sums[1]))
-            if n > 2:
-                # the program counted the pairs it multiplied and the pairs
-                # its expert layers routed, over the rows each layer saw;
-                # the rest went to experts held elsewhere
-                self._moe_assignments.inc(int(sums[2]))
-                self._moe_elsewhere.inc(int(sums[3] - sums[2]))
-        self._route_mixed(plan, chunk_np, dispatched_at)
+        self._route_mixed(plan, self._work.fetched(chunk_np), dispatched_at)
         return True
 
     def _plan_ragged(self, meta: Optional[dict], prefilling) -> dict:
@@ -4219,7 +3784,7 @@ class LLMEngine:
                 "mixed_decode", n_tokens, plan["page_table"].shape[1], 0,
                 n_tokens, chained=chain is not None)
             self._sampler_dispatches[plan["sampler_path"]].inc()
-            self._count_forward(self._shapes.steps)  # rounds of the packed step
+            self._work.forward(self._shapes.steps)  # rounds of the packed step
         with phases.span("upload"):
             if chain is not None:
                 tok, pos, cnt = chain["carry"]

@@ -178,6 +178,12 @@ class DispatchShapes:
         per lane, so the largest bucket must cover the batch."""
         return self.lanes * self.align <= self.token_budget
 
+    def admits_mixed(self, engine_config) -> bool:
+        """Whether the engine can step through `mixed`: the program is not
+        built staged or sequence-sharded."""
+        return (engine_config.pp == 1 and engine_config.sp == 1
+                and self.fits_pure_decode)
+
     def aligned(self, n: int) -> int:
         return -(-n // self.align) * self.align
 

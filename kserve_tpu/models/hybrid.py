@@ -76,7 +76,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..engine.kvcache import append_token_kv, slice_runs, write_ragged_kv
+from ..ops.kv_write import append_token_kv, slice_runs, write_ragged_kv
 from ..ops import ssm
 from ..ops.attention import (
     latent_paged_attention,
@@ -622,7 +622,7 @@ def decode_step(params, config, tokens, pos, state, page_table, active,
 
 def _ring_runs(q_start, q_len, kv_start, R: int):
     """A window layer's packed write as the page write's runs
-    (engine/kvcache.write_ragged_kv): of lane b's slice the newest R tokens
+    (ops/kv_write.write_ragged_kv): of lane b's slice the newest R tokens
     are kept; those up to the ring's end are one run, those that wrap to
     its start another.  Two sets, written one after the other, because the
     two runs of one lane may meet on a page."""

@@ -28,7 +28,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..engine.kvcache import (
+from ..ops.kv_write import (
     append_token_kv,
     slice_runs,
     write_chunk_kv_batch,
@@ -1099,7 +1099,7 @@ def _run_passes(params: Params, config: LlamaConfig, x, kv_pages, table,
     logits on the TPU than on the CPU and than the same passes unrolled
     (PERF.md section 6, PR 35).  The exit gate is not evaluated: the
     engine refuses early_exit_threshold < 1
-    (engine.resolve_hybrid_serving)."""
+    (engine/limits.resolve_serving)."""
     if not config.is_looped:
         return stack(x, kv_pages, table)
     first = kv_pages[0]

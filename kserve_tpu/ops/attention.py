@@ -12,7 +12,7 @@ The RAGGED contract (docs/kernels.md) generalizes both: every sequence in
 the batch contributes an arbitrary-length query slice — a full prompt, a
 prompt chunk, or a single decode token — packed into one [T, nq, d] token
 buffer with per-sequence (q_start, q_len, kv_start) metadata.  The caller
-writes the slice's K/V into the paged cache FIRST (kvcache.write_ragged_kv),
+writes the slice's K/V into the paged cache FIRST (ops/kv_write.write_ragged_kv),
 then attention reads everything from pages with a causal mask anchored at
 each sequence's kv offset, so prompt chunks and decode steps fold into the
 same online-softmax program:
@@ -96,7 +96,7 @@ def _gather_history(kv_pages, page_table):
         nkv, ps, d = pages.shape[2], pages.shape[3], pages.shape[4]
         g = pages[page_table]  # [B, W, 2, nkv, ps, d] int8
         s = scales[page_table]  # [B, W, 2, nkv, ps]
-        from ..engine.kvcache import dequantize_rows
+        from .kv_write import dequantize_rows
 
         # dequantize to bf16: the attention math upcasts to f32 internally,
         # and a f32 intermediate would double the bandwidth the int8 cache
@@ -202,53 +202,26 @@ def paged_attention_xla(
     return out[:, 0].astype(q.dtype)
 
 
-#: the packed head-64 kernel copies one layer's whole cache on every call:
-#: beyond this many bytes of cache the copy alone outlasts the gather
-PACKED_KERNEL_MAX_CACHE_BYTES = 256 << 20
-
-
-def pallas_min_pages(d: int, kv_heads: int, page_size: int, batch: int,
-                     cache_pages: Optional[int] = None) -> Optional[int]:
+def pallas_min_pages(d: int, kv_heads: int,
+                     page_size: int) -> Optional[int]:
     """The narrowest page table (in pages) from which the decode kernel
     beats the gather for one compiled shape: 0 = at every width, None = at
     no width.  Set from per-call times measured on a v5e at widths 8-128
     (the table in docs/kernels.md "Kernel against gather", re-measured by
     scripts/decode_attention_crossover.py), not carried.
 
-    Head size 128: the size of one page DMA decides.  The kernel spends
-    ~0.45 us per (8-lane block, page) iteration whatever the page holds
-    and streams at ~630 GB/s once a page covers that; the gather moves the
-    same pages three times but in a few large operations.  K+V pages of
-    32 KB and more (4+ KV heads x 16 tokens): the kernel wins at every
+    The size of one page DMA decides.  The kernel spends ~0.45 us per
+    (8-lane block, page) iteration whatever the page holds and streams at
+    ~630 GB/s once a page covers that; the gather moves the same pages
+    three times but in a few large operations.  K+V pages of 32 KB and more
+    (4+ KV heads x 16 tokens of head size 128): the kernel wins at every
     width, 1.2-1.6x at 8 pages, 6-8x from 40 up, at 8, 16 and 48 lanes.
     16 KB (2 KV heads, a tp=4 shard of 8): the gather is 1-10 % ahead up
     to 56 pages, the kernel 1.2-1.9x from 64.  8 KB (1 KV head): the
     gather is 2-3x ahead at every width.
 
-    Head size 64 (the packed kernel): its [.., ps, 64] -> [.., ps/2, 128]
-    view of the cache is NOT free on the chip, XLA re-lays one layer's
-    whole cache out on every call (0.13 ms at 2300 pages of 8 KV heads,
-    0.9 ms at 9200), so where it wins depends on the cache's size, which
-    a width cannot express.  64 is where it has stopped losing at every
-    cache size measured, 128 for pages of 2 KV heads; 8 lanes never pay
-    the copy back.  Where the caller knows the cache's size (`cache_pages`)
-    and one layer's cache passes PACKED_KERNEL_MAX_CACHE_BYTES, never: at
-    30000 pages of 20 KV heads (2.46 GB) the copy is 7.8 ms a call and the
-    kernel loses at every width to 64 (0.04-0.71x; PR 29's rows), at 9200
-    pages of 8 (301 MB) it loses at 40 pages.  Removing the copy, not
-    these numbers, is the repair: models/hybrid.py stores head-64 pairs
-    side by side in rows of 128 and takes the main kernel.
-
     `kv_heads` is what ONE device holds (the local shard under shard_map),
     so a model's answer changes with its tp, from the shape alone."""
-    if d == 64:
-        if batch < 16:
-            return None
-        if cache_pages is not None and (
-                cache_pages * 2 * kv_heads * page_size * d * 2
-                > PACKED_KERNEL_MAX_CACHE_BYTES):
-            return None
-        return 64 if kv_heads >= 4 else 128
     page_bytes = 2 * kv_heads * page_size * d * 2  # K and V, bf16
     if page_bytes >= 32 * 1024:
         return 0
@@ -258,20 +231,15 @@ def pallas_min_pages(d: int, kv_heads: int, page_size: int, batch: int,
 
 
 def _should_use_pallas(d: int, quantized: bool, table_width: int, batch: int,
-                       backend: str, page_size, kv_heads: int,
-                       cache_pages: Optional[int] = None) -> bool:
+                       backend: str, page_size, kv_heads: int) -> bool:
     """The use_pallas=None auto-dispatch predicate (factored out so tests
     assert the production decision, not a re-inlined copy)."""
     from .pallas_paged_attention import _pick_sb
 
-    supported_head = (
-        d % 128 == 0
-        # d=64 runs the packed two-tokens-per-row kernel, which needs an
-        # even page_size; auto must fall back to the gather, not raise
-        or (d == 64 and page_size is not None and page_size % 2 == 0)
-    )
     if not (
-        supported_head
+        # a narrower head's rows are not whole 128-lane tiles: the gather,
+        # or rows stored side by side in rows of 128 (models/hybrid.py)
+        d % 128 == 0
         and not quantized  # kernel reads bf16 pages only (today)
         # a batch with no divisor <= MAX_SB would run the serialized
         # sb=1 kernel shape, which loses to the gather
@@ -281,7 +249,7 @@ def _should_use_pallas(d: int, quantized: bool, table_width: int, batch: int,
         and backend == "tpu"
     ):
         return False
-    min_pages = pallas_min_pages(d, kv_heads, page_size, batch, cache_pages)
+    min_pages = pallas_min_pages(d, kv_heads, page_size)
     return min_pages is not None and table_width >= min_pages
 
 
@@ -294,14 +262,14 @@ def _decode_auto(lanes: int, d: int, kv_pages, page_table,
     return _should_use_pallas(
         d, quantized, int(page_table.shape[1]), lanes,
         backend or jax.default_backend(), int(pages.shape[3]),
-        int(pages.shape[2]), int(pages.shape[0]))
+        int(pages.shape[2]))
 
 
 def _should_use_page_write(d: int, quantized: bool, latent: bool,
                            backend: str, sharded: bool = False) -> bool:
     """Whether a K/V write runs as the page kernel
     (ops/pallas_kv_write.py) or as XLA's row scatter
-    (engine/kvcache._scatter_kv): the ONE predicate, from what a trace can
+    (ops/kv_write._scatter_kv): the ONE predicate, from what a trace can
     see.  The kernel on a TPU, over a plain cache of K and V planes whose
     rows are whole 128-lane tiles.  The scatter for: an int8 cache (the
     (pages, scales) tuple: Mosaic refuses the scale page's DMA, as it does
@@ -321,7 +289,7 @@ def _kv_write_name(page_kernel: bool) -> str:
 def kv_write_path(kv_pages, v, backend: Optional[str] = None) -> str:
     """`page_kernel` or `row_scatter` for a write of (k, `v`) into
     `kv_pages`: `_should_use_page_write` read off the arrays.  A caller
-    whose cache is sharded does not ask (engine/kvcache `page_kernel`)."""
+    whose cache is sharded does not ask (ops/kv_write `page_kernel`)."""
     quantized = isinstance(kv_pages, tuple)
     d = (kv_pages[0] if quantized else kv_pages).shape[-1]
     return _kv_write_name(_should_use_page_write(
@@ -429,7 +397,7 @@ def ragged_paged_attention_xla(
     """XLA gather reference for the ragged contract (docs/kernels.md).
 
     The caller has already written the slice's K/V into the pages
-    (kvcache.write_ragged_kv), so attention reads ONLY the paged cache:
+    (ops/kv_write.write_ragged_kv), so attention reads ONLY the paged cache:
     query token j of sequence i sits at absolute position kv_start[i]+j and
     attends causally to positions 0..kv_start[i]+j.  Padded table entries
     point at the null page, whose positions lie beyond every query's causal
@@ -733,8 +701,8 @@ def describe_attention_dispatch(model_config, engine_config,
                 cfg.max_batch_size, backend, cfg.page_size, mc.cache_kv_heads)
             if decode:
                 min_pages = pallas_min_pages(
-                    mc.cache_head_dim, mc.cache_kv_heads, cfg.page_size,
-                    cfg.max_batch_size) or None
+                    mc.cache_head_dim, mc.cache_kv_heads,
+                    cfg.page_size) or None
         else:
             decode = bool(cfg.use_pallas)
         return {
@@ -758,12 +726,10 @@ def describe_attention_dispatch(model_config, engine_config,
             (mc.is_hybrid or mc.sliding_window <= 0) and mc.attn_scale is None
             and _should_use_pallas(
                 mc.head_dim, quantized, cfg.max_pages_per_seq,
-                cfg.max_batch_size, backend, cfg.page_size, kv_heads,
-                cfg.num_pages))
+                cfg.max_batch_size, backend, cfg.page_size, kv_heads))
         if decode:
             min_pages = pallas_min_pages(
-                mc.head_dim, kv_heads, cfg.page_size,
-                cfg.max_batch_size, cfg.num_pages) or None
+                mc.head_dim, kv_heads, cfg.page_size) or None
     else:
         ragged = decode = bool(cfg.use_pallas)
     mixed = "pallas_ragged" if ragged else "xla_ragged_gather"

@@ -1,6 +1,6 @@
 """The K/V row write as a PAGE write (Pallas/TPU).
 
-XLA's scatter in engine/kvcache._scatter_kv addresses every [head_dim] row
+XLA's scatter in ops/kv_write._scatter_kv addresses every [head_dim] row
 by (page, k/v, head, slot) and writes them one after another: 0.07-0.11 us
 a row of 256 bytes on a v5e, padding rows included, whatever the cache's
 size (docs/kernels.md "K/V page write").  This kernel moves the same bytes a page at a time: for

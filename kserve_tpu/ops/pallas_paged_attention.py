@@ -295,140 +295,6 @@ def _decode_kernel(
     out_ref[...] = out.reshape(sb, nq, vd).astype(out_ref.dtype)
 
 
-def _packed_decode_kernel(
-    # scalar prefetch
-    page_table_ref,  # [B, W] int32 (SMEM)
-    seq_lens_ref,  # [B] int32 (SMEM)
-    # inputs
-    q_ref,  # [SB, nq, 128] VMEM — q duplicated into both lane halves
-    kv_hbm_ref,  # [num_pages, 2, nkv, ps/2, 128] in HBM (packed view)
-    # output
-    out_ref,  # [SB, nq, 128] VMEM — even-token pv in lanes 0-63, odd in 64-127
-    # scratch
-    kv_bufs,  # [NBUF, SB, 2, nkv, ps/2, 128] VMEM ring
-    sems,  # DMA semaphores [NBUF, SB]
-    *,
-    sb: int,
-    page_size: int,  # TOKENS per page (rows per page = page_size // 2)
-    num_kv_heads: int,
-    scale: float,
-    logit_softcap: float,
-):
-    """head_dim=64 variant: two tokens share one 128-lane row.
-
-    The natural [ps, 64] layout would pad the lane dim to 128 (half of
-    VMEM wasted) and Mosaic rejects both trailing-dim DMA slices and the
-    in-kernel shape-cast that would unpack a packed row.  Instead the
-    CALLER reshapes the cache to [.., ps/2, 128] (a copy of the array on
-    the chip, not a free view) and everything inside stays 128-lane aligned:
-    - q arrives duplicated: q2 = [q | q], so one dot against a half-masked
-      K row contracts exactly one token's 64 dims
-    - scores for even/odd tokens are two dots against lane-masked K; each
-      feeds the shared online-softmax accumulator
-    - pv accumulates PACKED: lanes 0-63 carry the even tokens' 64-dim
-      contribution, lanes 64-127 the odd tokens'; the caller folds the two
-      halves with one XLA add — no lane slicing anywhere in the kernel.
-    """
-    g = pl.program_id(0)
-    nq = q_ref.shape[1]
-    group = nq // num_kv_heads
-    rows = page_size // 2  # packed rows per page
-
-    num_pages = _block_pages(seq_lens_ref, g, sb, page_size)
-    start_iter = _make_start_iter(
-        page_table_ref, kv_hbm_ref, kv_bufs, sems, g, sb)
-    _ring_prologue(start_iter, num_pages)
-
-    q2 = q_ref[...].astype(jnp.float32).reshape(
-        sb, num_kv_heads, group, 128
-    )
-    lens = _per_row(seq_lens_ref, g * sb, sb)
-    lane = jax.lax.broadcasted_iota(jnp.int32, (1, 1, 1, 128), 3)
-    mask_lo = (lane < 64).astype(jnp.float32)
-    mask_hi = (lane >= 64).astype(jnp.float32)
-
-    def body(i, carry):
-        m, l, acc = carry
-        slot = _ring_wait_and_refill(
-            start_iter, kv_hbm_ref, kv_bufs, sems, sb, i, num_pages)
-
-        k = kv_bufs[slot, :, 0].astype(jnp.float32)  # [SB, nkv, ps/2, 128]
-        v = kv_bufs[slot, :, 1].astype(jnp.float32)
-        row = jax.lax.broadcasted_iota(jnp.int32, (1, 1, 1, rows), 3)
-
-        def scores(kmask, parity):
-            s_ = _heads_dot(q2, k * kmask, 2) * scale  # [SB, nkv, group, ps/2]
-            if logit_softcap > 0.0:
-                s_ = jnp.tanh(s_ / logit_softcap) * logit_softcap
-            pos = i * page_size + 2 * row + parity
-            return jnp.where(pos < lens, s_, -1e30)
-
-        s_even = scores(mask_lo, 0)
-        s_odd = scores(mask_hi, 1)
-        m_new = jnp.maximum(
-            m,
-            jnp.maximum(
-                s_even.max(axis=-1, keepdims=True),
-                s_odd.max(axis=-1, keepdims=True),
-            ),
-        )
-        alpha = jnp.exp(m - m_new)
-        p_even = jnp.exp(s_even - m_new)
-        p_odd = jnp.exp(s_odd - m_new)
-        l_new = (
-            l * alpha
-            + p_even.sum(axis=-1, keepdims=True)
-            + p_odd.sum(axis=-1, keepdims=True)
-        )
-        pv = (
-            _heads_dot(p_even, v * mask_lo, 1)
-            + _heads_dot(p_odd, v * mask_hi, 1)
-        )  # [SB, nkv, group, 128] — halves carry their parity's pv
-        return m_new, l_new, acc * alpha + pv
-
-    m0 = jnp.full((sb, num_kv_heads, group, 1), -1e30, jnp.float32)
-    l0 = jnp.zeros((sb, num_kv_heads, group, 1), jnp.float32)
-    acc0 = jnp.zeros((sb, num_kv_heads, group, 128), jnp.float32)
-    m, l, acc = jax.lax.fori_loop(0, num_pages, body, (m0, l0, acc0))
-    out = acc / jnp.maximum(l, 1e-30)
-    out_ref[...] = out.reshape(sb, nq, 128).astype(out_ref.dtype)
-
-
-def _paged_attention_pallas_packed(
-    q, kv_pages, page_table, seq_lens, logit_softcap, interpret
-):
-    """head_dim=64 entry: pack the cache view, duplicate q, fold halves."""
-    B, nq, d = q.shape
-    num_pages_total, _, nkv, ps, _ = kv_pages.shape
-    if ps % 2 != 0:
-        raise ValueError(f"packed kernel requires even page_size, got {ps}")
-    sb = _pick_sb(B)
-    scale = float(1.0 / (d ** 0.5))
-    # [.., ps, 64] -> [.., ps/2, 128] (two tokens per lane row): the same
-    # bytes in row-major order, but NOT a bitcast under the TPU's tiled
-    # layout — XLA copies the whole array on every call (measured:
-    # docs/kernels.md "Kernel against gather"), which is why auto-dispatch
-    # gates this kernel harder than the 128-lane one
-    kv_packed = kv_pages.reshape(num_pages_total, 2, nkv, ps // 2, 128)
-    q2 = jnp.concatenate([q, q], axis=-1)  # [B, nq, 128]
-    kernel = functools.partial(
-        _packed_decode_kernel,
-        sb=sb,
-        page_size=ps,
-        num_kv_heads=nkv,
-        scale=scale,
-        logit_softcap=logit_softcap,
-    )
-    packed_out = _by_length(_pallas_call(kernel, B, sb, nq, 128, kv_packed)(
-        out_shape=jax.ShapeDtypeStruct((B, nq, 128), jnp.float32),
-        interpret=interpret,
-        name="paged_attention_decode_packed",
-    ), page_table, seq_lens, q2, kv_packed)
-    # fold the parity halves (plain XLA; f32 before the final cast)
-    out = packed_out.reshape(B, nq, 2, 64).sum(axis=2)
-    return out.astype(q.dtype)
-
-
 @_entry("logit_softcap", "interpret", "scale", "name")
 def paged_attention_pallas(
     q: jnp.ndarray,  # [B, nq, d]
@@ -437,26 +303,18 @@ def paged_attention_pallas(
     seq_lens: jnp.ndarray,  # [B] int32
     logit_softcap: float = 0.0,
     interpret: bool = False,
-    scale: Optional[float] = None,  # None = 1/sqrt(d); head size 64 has none
+    scale: Optional[float] = None,  # None = 1/sqrt(d)
     name: str = "paged_attention_decode",  # the trace label of this call
 ) -> jnp.ndarray:
     B, nq, d = q.shape
     num_pages_total, _, nkv, ps, _ = kv_pages.shape
-    if d == 64:
-        # real Llama-3.2-1B / Qwen-class checkpoints (VERDICT r4 #4): two
-        # tokens packed per 128-lane row, see _packed_decode_kernel
-        if scale is not None:
-            raise ValueError("the packed head-64 kernel takes no scale override")
-        return _paged_attention_pallas_packed(
-            q, kv_pages, page_table, seq_lens, logit_softcap, interpret
-        )
     if d % 128 != 0 and not interpret:
         # Lane tiling pads head_dim to 128 and Mosaic rejects both DMA
         # slices of the padded trailing dim and the shape-cast that would
-        # unpack a token-packed row (d=64 has the dedicated packed kernel
-        # above; other sub-128 head dims fall back to the XLA path).
+        # unpack a token-packed row: a narrower head takes the XLA path, or
+        # stores its rows side by side in rows of 128 (models/hybrid.py).
         raise ValueError(
-            f"pallas paged attention requires head_dim % 128 == 0 or 64, got {d}"
+            f"pallas paged attention requires head_dim % 128 == 0, got {d}"
         )
     sb = _pick_sb(B)
     scale = float(1.0 / (d ** 0.5)) if scale is None else float(scale)
@@ -941,7 +799,7 @@ def ragged_single_token_split_pallas(
     `MAX_SB` to a block, sorted by length, a page DMA a lane in flight
     every iteration (48 lanes of ~350 tokens: 98 us against 447,
     docs/kernels.md "The packed step's single-token lanes").  The slice's
-    K/V is in the pages before attention runs (kvcache.write_ragged_kv), so
+    K/V is in the pages before attention runs (ops/kv_write.write_ragged_kv), so
     a one-token slice at `kv_start` IS a decode lane of length `kv_start +
     1`: the same keys, the same float32 products and online softmax.
 

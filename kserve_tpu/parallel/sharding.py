@@ -52,14 +52,8 @@ def create_mesh(
 
 
 def validate_tp(config: LlamaConfig, tp: int) -> None:
-    if config.is_hybrid:
-        if tp > 1:
-            raise NotImplementedError(
-                "tp>1 over a hybrid model (Mamba-1 / Mamba-2 / window / "
-                "shared-cache layers, a chip's share of the experts): its "
-                "parameters and per-lane state have no sharding rules yet; "
-                "run it with tp=1")
-        return
+    """The sizes tp divides (a hybrid model is refused tp>1 before it comes
+    here: engine/limits.py)."""
     if config.n_heads % tp != 0:
         raise ValueError(f"n_heads={config.n_heads} not divisible by tp={tp}")
     if config.n_kv_heads % tp != 0:
@@ -80,7 +74,7 @@ def validate_tp(config: LlamaConfig, tp: int) -> None:
 def param_pspecs(config: LlamaConfig) -> Dict[str, Any]:
     """PartitionSpec pytree matching models.llama param pytree."""
     if config.is_hybrid:
-        # tp=1 only (validate_tp): every leaf of every row replicated
+        # tp=1 only (engine/limits.py): every leaf of every row replicated
         from ..models import hybrid
 
         specs = {

@@ -26,7 +26,8 @@ from ..engine.aot_cache import aot_cache_dir_from_env
 from ..engine.types import spec_decode_k_from_env
 from ..engine.watchdog import watchdog_enabled_from_env
 from ..kvstore.persist import kv_persist_dir_from_env
-from ..engine.engine import EngineConfig, LLMEngine, resolve_hybrid_serving
+from ..engine.engine import EngineConfig, LLMEngine
+from ..engine.limits import resolve_serving
 from ..engine.sampling import SamplingParams
 from ..engine.tokenizer import load_tokenizer
 from ..errors import InvalidInput
@@ -112,11 +113,11 @@ class JAXGenerativeModel(OpenAIGenerativeModel):
                     f"no config.json under {self.model_dir}; pass model_config"
                 )
             self._model_config = llama.LlamaConfig.from_hf_config(cfg_path)
-        # refused before any weight is read: what a model with recurrent
-        # state cannot do yet (the engine checks again with its own copy)
-        resolve_hybrid_serving(
+        # refused before any weight is read: what this model cannot be
+        # served with (the engine checks again with its own copy and sizes)
+        resolve_serving(
             self._model_config, dataclasses.replace(self.engine_config),
-            role=self.role)
+            role=self.role, lora=bool(self.lora_adapters))
         self.tokenizer = load_tokenizer(self.model_dir, self._model_config.vocab_size)
         if self.random_weights or not self.model_dir:
             self._params = None  # engine random-initializes

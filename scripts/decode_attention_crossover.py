@@ -53,17 +53,9 @@ FAMILIES = {
     "mistral-7b/tp4": (48, 8, 2, 128),  # one shard of four
     "mistral-7b/tp4/b8": (8, 8, 2, 128),
     "llama3-8b/tp8": (48, 4, 1, 128),  # one shard of eight
-    "llama3.2-1b": (48, 32, 8, 64),  # the packed kernel
-    "llama3.2-1b/b8": (8, 32, 8, 64),
-    "llama3.2-1b/b16": (16, 32, 8, 64),
-    "llama3.2-1b/b32": (32, 32, 8, 64),
-    "llama3.2-1b/tp2": (48, 16, 4, 64),
-    "llama3.2-1b/tp4": (48, 8, 2, 64),
     # Phi-4-mini-flash: 20 K/V heads of 64 stored as 10 rows of 128 (a
-    # differential pair's heads side by side), and the same bytes as the
-    # published heads would lie, for the packed kernel
+    # differential pair's heads side by side)
     "phi4-mini-flash": (48, 40, 10, 128),
-    "phi4-mini-flash/heads-of-64": (48, 40, 20, 64),
     # Ouro-2.6B: 12 lanes = two blocks of six, 128 KB pages
     "ouro-2.6b": (12, 16, 16, 128),
 }
@@ -118,8 +110,7 @@ def measure(family: str, width: int, budget_s: float,
         .reshape(lanes, width), jnp.int32)
     row = {"family": family, "lanes": lanes, "nq": nq, "nkv": nkv, "d": d,
            "width": width, "cache_pages": num_pages, "auto": _should_use_pallas(
-               d, False, width, lanes, jax.default_backend(), PAGE, nkv,
-               num_pages)}
+               d, False, width, lanes, jax.default_backend(), PAGE, nkv)}
     fns = {"kernel": _looped(True, num_pages),
            "gather": _looped(False, num_pages)}
     for kind in ("aged", "full"):

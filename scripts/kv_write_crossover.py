@@ -2,7 +2,7 @@
 """The K/V write, page kernel against row scatter, per call on the device.
 
 The measurement behind docs/kernels.md "K/V page write" and the rows in
-docs/data/kv_write_crossover.v5e.json: `engine/kvcache.append_token_kv` (a
+docs/data/kv_write_crossover.v5e.json: `ops/kv_write.append_token_kv` (a
 decode step's write) and `write_ragged_kv` (a packed step's) with
 `page_kernel=True` and `page_kernel=False`, bf16 pages of 16 tokens, at the
 head shapes, lanes and packed lengths the benchmark's cells compile.  Run
@@ -42,7 +42,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from kserve_tpu.engine.kvcache import append_token_kv, write_ragged_kv
+from kserve_tpu.ops.kv_write import append_token_kv, write_ragged_kv
 
 PAGE = 16
 ALIGN = 8  # ops/pallas_paged_attention.RAGGED_BQ: a lane's slice offset

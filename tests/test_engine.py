@@ -713,7 +713,7 @@ class TestInt8KVCache:
         import jax.numpy as jnp
         import numpy as np
 
-        from kserve_tpu.engine.kvcache import dequantize_rows, quantize_rows
+        from kserve_tpu.ops.kv_write import dequantize_rows, quantize_rows
 
         rng = np.random.RandomState(0)
         x = jnp.asarray(rng.randn(4, 2, 64) * 0.3, jnp.float32)
@@ -726,7 +726,7 @@ class TestInt8KVCache:
         import jax.numpy as jnp
         import numpy as np
 
-        from kserve_tpu.engine.kvcache import quantize_rows
+        from kserve_tpu.ops.kv_write import quantize_rows
         from kserve_tpu.ops.attention import paged_attention_xla
 
         B, nq, nkv, d, ps, NP, W = 3, 8, 4, 32, 8, 32, 4

@@ -8,7 +8,8 @@ import asyncio
 import numpy as np
 import pytest
 
-from kserve_tpu.engine.engine import EngineConfig, LLMEngine, resolve_hybrid_serving
+from kserve_tpu.engine.engine import EngineConfig, LLMEngine
+from kserve_tpu.engine.limits import resolve_serving
 from kserve_tpu.engine.sampling import SamplingParams
 from kserve_tpu.engine.tokenizer import ByteTokenizer
 from kserve_tpu.metrics import (
@@ -18,7 +19,6 @@ from kserve_tpu.metrics import (
     ENGINE_MOE_PEAK_LOAD,
     ENGINE_STATE_BYTES,
 )
-from kserve_tpu.parallel import sharding as shd
 from test_glm_model import CFG, CONFIG, PARAMS, _reference
 
 #: a served token's reference logit against the reference's maximum at its
@@ -146,17 +146,17 @@ def test_expert_counters_cache_gauges_and_scheduler_state():
 def test_what_the_family_cannot_do_yet_is_refused_by_name(over, named):
     role = over.pop("role", "both")
     with pytest.raises(NotImplementedError) as info:
-        resolve_hybrid_serving(CONFIG, engine_config(**over), role=role)
+        resolve_serving(CONFIG, engine_config(**over), role=role)
     assert named in str(info.value) and "latent-attention" in str(info.value)
 
 
 def test_the_prefix_cache_stays_as_configured_and_requests_are_refused_by_name():
     config = engine_config()
-    resolve_hybrid_serving(CONFIG, config)
+    resolve_serving(CONFIG, config)
     assert config.prefix_cache is None  # the engine's default: ON
-    resolve_hybrid_serving(CONFIG, engine_config(prefix_cache=True))
+    resolve_serving(CONFIG, engine_config(prefix_cache=True))
     with pytest.raises(NotImplementedError, match="tp>1 over a hybrid model"):
-        shd.validate_tp(CONFIG, 2)
+        resolve_serving(CONFIG, engine_config(tp=2))
     engine = LLMEngine(CONFIG, engine_config(), ByteTokenizer(320))
     assert engine.config.prefix_cache is True
     assert engine.dispatch_report["regime"] == "mixed"

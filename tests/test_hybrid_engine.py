@@ -10,7 +10,8 @@ import jax
 import numpy as np
 import pytest
 
-from kserve_tpu.engine.engine import EngineConfig, LLMEngine, resolve_hybrid_serving
+from kserve_tpu.engine.engine import EngineConfig, LLMEngine
+from kserve_tpu.engine.limits import resolve_serving
 from kserve_tpu.engine.sampling import SamplingParams
 from kserve_tpu.engine.tokenizer import ByteTokenizer
 from kserve_tpu.metrics import (
@@ -19,7 +20,6 @@ from kserve_tpu.metrics import (
     ENGINE_STATE_SLOTS_IN_USE,
 )
 from kserve_tpu.models import llama
-from kserve_tpu.parallel import sharding as shd
 from test_hybrid_model import CFG, _reference
 
 CONFIG = dataclasses.replace(
@@ -149,24 +149,24 @@ def test_state_gauges_and_scheduler_state():
 def test_what_the_family_cannot_do_yet_is_refused_by_name(over, named):
     role = over.pop("role", "both")
     with pytest.raises(NotImplementedError) as info:
-        resolve_hybrid_serving(CONFIG, engine_config(**over), role=role)
+        resolve_serving(CONFIG, engine_config(**over), role=role)
     assert named in str(info.value)
 
 
 def test_defaults_resolve_to_off_and_a_llama_is_left_alone():
     config = engine_config()
     assert config.prefix_cache is None
-    resolve_hybrid_serving(CONFIG, config)
+    resolve_serving(CONFIG, config)
     assert config.prefix_cache is False
     plain = engine_config(prefix_cache=True, spec_decode_k=2, kv_quant="int8")
-    resolve_hybrid_serving(llama.LlamaConfig.tiny(), plain)  # not its business
+    resolve_serving(llama.LlamaConfig.tiny(), plain)  # not its business
     assert plain.prefix_cache is True
 
 
 def test_tensor_parallelism_and_request_time_features_are_refused_by_name():
     with pytest.raises(NotImplementedError, match="tp>1 over a hybrid model"):
-        shd.validate_tp(CONFIG, 2)
-    shd.validate_tp(CONFIG, 1)
+        resolve_serving(CONFIG, engine_config(tp=2))
+    resolve_serving(CONFIG, engine_config(tp=1))
     with pytest.raises(NotImplementedError, match="LoRA adapters over a hybrid"):
         LLMEngine(CONFIG, engine_config(), ByteTokenizer(320),
                   lora_adapters={"a": "/nowhere"})

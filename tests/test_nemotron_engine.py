@@ -11,7 +11,8 @@ import jax
 import numpy as np
 import pytest
 
-from kserve_tpu.engine.engine import EngineConfig, LLMEngine, resolve_hybrid_serving
+from kserve_tpu.engine.engine import EngineConfig, LLMEngine
+from kserve_tpu.engine.limits import resolve_serving
 from kserve_tpu.engine.sampling import SamplingParams
 from kserve_tpu.engine.tokenizer import ByteTokenizer
 from kserve_tpu.metrics import (
@@ -198,16 +199,16 @@ def test_a_closing_expert_layer_counts_the_rows_it_saw():
 def test_what_the_family_cannot_do_yet_is_refused_by_name(over, named):
     role = over.pop("role", "both")
     with pytest.raises(NotImplementedError) as info:
-        resolve_hybrid_serving(CONFIG, engine_config(**over), role=role)
+        resolve_serving(CONFIG, engine_config(**over), role=role)
     assert named in str(info.value) and "Mamba-2" in str(info.value)
 
 
 def test_the_prefix_cache_resolves_to_off_and_across_chips_stays_refused():
     config = engine_config()
-    resolve_hybrid_serving(CONFIG, config)
+    resolve_serving(CONFIG, config)
     assert config.prefix_cache is False
     with pytest.raises(NotImplementedError, match="share of the experts"):
-        shd.validate_tp(CONFIG, 2)
+        resolve_serving(CONFIG, engine_config(tp=2))
     # tp = 1: every new tensor has a spec, each replicated
     specs = shd.param_pspecs(CONFIG)
     for layer, spec in zip(PARAMS["layers"], specs["layers"]):

@@ -11,7 +11,8 @@ import dataclasses
 import numpy as np
 import pytest
 
-from kserve_tpu.engine.engine import EngineConfig, LLMEngine, resolve_hybrid_serving
+from kserve_tpu.engine.engine import EngineConfig, LLMEngine
+from kserve_tpu.engine.limits import resolve_serving
 from kserve_tpu.engine.sampling import SamplingParams
 from kserve_tpu.engine.tokenizer import ByteTokenizer
 from kserve_tpu.metrics import (
@@ -196,7 +197,7 @@ _OWN = 3 * 38 + 6 * 3 * 7 + 3 + (1 + 2 + 2)
 def test_decode_pages_by_reach_of_a_hand_built_dispatch(lanes, block):
     """`engine_kv_decode_pages_total{reach}` beside
     `engine_kv_context_tokens_total`, from the same pos / live / capacity:
-    `_count_forward` on a dispatch nobody launched."""
+    `DispatchWork.forward` on a dispatch nobody launched."""
     label = f"pages-by-reach-{lanes}"
 
     async def jobs(engine):
@@ -213,7 +214,7 @@ def test_decode_pages_by_reach_of_a_hand_built_dispatch(lanes, block):
     pos[8], live[8], capacity[8] = 47, True, 48
     pos[9], live[9] = 15, True
     pos[10], live[10], capacity[10] = 48, True, 48
-    engine._count_forward(4, pos, live, capacity, decode_steps=3)
+    engine._work.forward(4, pos, live, capacity, decode_steps=3)
     after = _decode_pages(label)
     assert {r: after[r] - before[0][r] for r in after} == {
         "own": _OWN, "block": block}
@@ -248,7 +249,7 @@ def test_what_a_looped_model_cannot_do_yet_is_refused_by_name(
         model_over, engine_over, named):
     model = config_of(**model_over)
     with pytest.raises(NotImplementedError) as info:
-        resolve_hybrid_serving(model, engine_config(**engine_over))
+        resolve_serving(model, engine_config(**engine_over))
     assert named in str(info.value) and "looped model" in str(info.value)
     with pytest.raises(NotImplementedError, match="looped model"):
         LLMEngine(model, engine_config(**engine_over), ByteTokenizer(320))
@@ -256,9 +257,9 @@ def test_what_a_looped_model_cannot_do_yet_is_refused_by_name(
 
 def test_what_runs_every_pass_is_left_alone():
     config = engine_config(prefix_cache=True, spec_decode_k=2)
-    resolve_hybrid_serving(CONFIG, config)
+    resolve_serving(CONFIG, config)
     assert config.prefix_cache is True
-    resolve_hybrid_serving(config_of(total_ut_steps=1),
+    resolve_serving(config_of(total_ut_steps=1),
                            engine_config(pp=2))  # not looped: not its business
 
 

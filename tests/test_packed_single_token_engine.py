@@ -169,7 +169,7 @@ def test_the_kernel_s_counters_follow_the_kernel(
         min_pages, width, lanes, pages, tokens):
     """`engine_packed_lanes_total{attention_path}`, and the packed step's call on
     `engine_kv_decode_pages_total{reach}` / `engine_kv_context_tokens_total`
-    where the program makes it: `_count_packed_lanes` on a plan nobody
+    where the program makes it: `DispatchWork.packed_lanes` on a plan nobody
     launched."""
     label = f"packed-lanes-{min_pages}-{width}"
 
@@ -182,7 +182,7 @@ def test_the_kernel_s_counters_follow_the_kernel(
     assert report["packed_single_token_min_pages"] is None  # on the CPU
     report["packed_single_token_min_pages"] = min_pages
     before = _counted(label)
-    engine._count_packed_lanes(
+    engine._work.packed_lanes(
         np.asarray(_Q_LEN), np.asarray(_KV_START), width)
     after = _counted(label)
     assert {p: after[0][p] - before[0][p] for p in after[0]} == lanes

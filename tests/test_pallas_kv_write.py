@@ -1,5 +1,5 @@
 """The K/V page write (ops/pallas_kv_write.py) against the row scatter it
-replaces (engine/kvcache._scatter_kv), bit for bit, in interpret mode on
+replaces (ops/kv_write._scatter_kv), bit for bit, in interpret mode on
 the CPU; the predicate that chooses between them; and the invariant the
 page write rests on: no two lanes of one dispatch write the same page.
 
@@ -15,9 +15,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from kserve_tpu.engine import kvcache
 from kserve_tpu.models import hybrid
 from kserve_tpu.ops import attention as att
+from kserve_tpu.ops import kv_write
 from kserve_tpu.ops import pallas_kv_write as pw
 
 #: (n_kv, page_size, head_dim) of a layer's cache, as ONE device holds it
@@ -56,7 +56,7 @@ def _jitted(fn):
 
 
 def _interpreted(monkeypatch):
-    """Calls that reach the kernel through engine/kvcache run it in
+    """Calls that reach the kernel through ops/kv_write run it in
     interpret mode."""
     kernel = pw.kv_page_write
     monkeypatch.setattr(
@@ -80,7 +80,7 @@ def test_decode_write_matches_scatter(name):
     k, v = rows[LANES]
     pos = jnp.asarray([0, ps - 1, ps, 2 * ps + 1, CONTEXT - 1, 5], jnp.int32)
     active = jnp.asarray([1, 1, 0, 1, 1, 1], bool)
-    want = kvcache.append_token_kv(
+    want = kv_write.append_token_kv(
         cache, k, v, table, pos, active, ps, page_kernel=False)
     got = _jitted(pw.append_rows)(cache, k, v, table, pos, active)
     _assert_same(got, want, cache)
@@ -122,7 +122,7 @@ def test_packed_write_matches_scatter(name, case):
     k, v = rows[T]
     q_start, q_len, kv_start = (np.asarray(a, np.int32) for a in RUNS[case])
     seq, pos = _tokens_of(q_start, q_len, kv_start)
-    want = kvcache.write_ragged_kv(
+    want = kv_write.write_ragged_kv(
         cache, k, v, table, seq, pos, ps, page_kernel=False)
     got = _jitted(pw.write_runs)(
         cache, k, v, table, jnp.arange(LANES, dtype=jnp.int32),
@@ -157,10 +157,10 @@ def test_ring_write_matches_scatter(case, monkeypatch):
     lane = jnp.maximum(seq, 0)
     kept = pos >= jnp.asarray(kv_start + q_len)[lane] - R
     ring_seq = jnp.where(kept, seq, -1)
-    want = kvcache.write_ragged_kv(
+    want = kv_write.write_ragged_kv(
         cache, k, v, ring_table, ring_seq, pos % R, ps, page_kernel=False)
     _interpreted(monkeypatch)
-    got = kvcache.write_ragged_kv(
+    got = kv_write.write_ragged_kv(
         cache, k, v, ring_table, ring_seq, pos % R, ps,
         runs=hybrid._ring_runs(jnp.asarray(q_start), jnp.asarray(q_len),
                                jnp.asarray(kv_start), R),

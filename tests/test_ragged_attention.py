@@ -14,9 +14,8 @@ from kserve_tpu.engine.kvcache import (
     KVCacheConfig,
     init_kv_pages,
     init_kv_scales,
-    quantize_rows,
-    write_ragged_kv,
 )
+from kserve_tpu.ops.kv_write import quantize_rows, write_ragged_kv
 from kserve_tpu.ops.attention import (
     ragged_attention_path,
     ragged_paged_attention,
@@ -119,7 +118,7 @@ class RaggedCase:
                 jnp.asarray(self.page_table), jnp.asarray(token_seq),
                 jnp.asarray(self.token_pos), PS)
             # the oracle must see the QUANTIZED values (int8 is lossy)
-            from kserve_tpu.engine.kvcache import dequantize_rows
+            from kserve_tpu.ops.kv_write import dequantize_rows
 
             deq = dequantize_rows(
                 self.kv_pages[0].transpose(0, 1, 3, 2, 4),
@@ -392,7 +391,7 @@ class TestSplitIsDerived:
         from kserve_tpu.ops.attention import _should_use_pallas
 
         decode = _should_use_pallas(
-            128, False, width, lanes, "tpu", 16, kv_heads, 2300)
+            128, False, width, lanes, "tpu", 16, kv_heads)
         path = ragged_attention_path(
             *_shapes(lanes, width, kv_heads), backend="tpu")
         assert path == ("pallas_ragged+decode" if decode else "pallas_ragged")

@@ -27,6 +27,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from kserve_tpu.engine import kvcache
 from kserve_tpu.ops import attention as att
+from kserve_tpu.ops import kv_write
 from kserve_tpu.ops import pallas_kv_write as kw
 from kserve_tpu.ops import pallas_paged_attention as pk
 
@@ -40,7 +41,6 @@ HEAD_SHAPES = {
     "qwen3-0.6b": (16, 8, 128),
     "llama3-8b": (32, 8, 128),
     "llama3-8b/tp4": (8, 2, 128),  # one tp=4 shard
-    "llama3.2-1b": (32, 8, 64),  # packed decode kernel only (d % 128 != 0)
     "gemma2-2b": (8, 4, 256),
 }
 PAGE_SIZES = (8, 16)
@@ -92,7 +92,7 @@ def _i32(*shape):
 @pytest.mark.parametrize("model", sorted(HEAD_SHAPES))
 class TestKernelsLowerAndCompileForTpu:
     def test_decode_kernel(self, model, ps):
-        """_decode_kernel, and _packed_decode_kernel at head_dim 64."""
+        """_decode_kernel."""
         nq, nkv, d = HEAD_SHAPES[model]
         _check(pk.paged_attention_pallas,
                _abstract((B, nq, d), jnp.bfloat16), _cache(nkv, ps, d),
@@ -150,7 +150,7 @@ class TestCacheKeepsKernelLayout:
         [2, nkv, d] window made XLA re-lay the WHOLE cache out around
         every layer's write (12 GiB of temporaries for `mixed` at
         4096 pages x 28 layers — it did not fit the chip); the row scatter
-        in kvcache._scatter_kv leaves the layout alone."""
+        in kv_write._scatter_kv leaves the layout alone."""
         if _tpu_sharding() is None:
             pytest.skip("no compile-only TPU topology in this installation")
         nq, nkv, d = HEAD_SHAPES["qwen3-0.6b"]
@@ -160,7 +160,7 @@ class TestCacheKeepsKernelLayout:
         cache = _abstract((4096, 2, nkv, ps, d), jnp.bfloat16)
 
         def write_then_attend(pages, q, k, v, table, seq, pos, start, n):
-            pages = kvcache.write_ragged_kv(
+            pages = kv_write.write_ragged_kv(
                 pages, k, v, table, seq, pos, ps)
             return pages, pk.ragged_paged_attention_pallas(
                 q, pages, table, start, n, start)
@@ -305,12 +305,10 @@ class TestDispatchReport:
         on_cpu = self._report(qwen, backend="cpu")
         assert (on_cpu["mixed"], on_cpu["decode"]) == (
             "xla_ragged_gather", "xla_gather")
-        # head_dim 64: the ragged kernel is out; the packed decode kernel
-        # is in from 64 pages for 16+ lanes (8 never pay its cache copy back)
+        # head_dim 64: rows narrower than a 128-lane tile take the gather
         d64 = self._report(LlamaConfig.llama3_1b(), max_batch_size=48)
         assert (d64["mixed"], d64["decode"], d64["decode_pallas_min_pages"]
-                ) == ("xla_ragged_gather", "pallas_decode", 64)
-        assert self._report(LlamaConfig.llama3_1b())["decode"] == "xla_gather"
+                ) == ("xla_ragged_gather", "xla_gather", None)
         # int8 pages, windows and scale overrides keep decode on the gather
         assert self._report(qwen, kv_quant="int8")["mixed"] == (
             "xla_ragged_gather")
@@ -330,15 +328,15 @@ class TestDispatchReport:
         assert self._report(qwen, use_pallas=True)[
             "decode_pallas_min_pages"] is None
 
-    @pytest.mark.parametrize("kv_heads, page_size, batch, expected", [
-        (8, 16, 48, 0), (8, 16, 8, 0), (4, 16, 48, 0), (8, 8, 48, 0),
-        (2, 16, 48, 64), (4, 8, 48, 64), (1, 16, 48, None),
+    @pytest.mark.parametrize("kv_heads, page_size, expected", [
+        (8, 16, 0), (16, 16, 0), (4, 16, 0), (8, 8, 0),
+        (2, 16, 64), (4, 8, 64), (1, 16, None),
     ])
-    def test_gate_follows_the_page_dma_size(self, kv_heads, page_size, batch,
+    def test_gate_follows_the_page_dma_size(self, kv_heads, page_size,
                                             expected):
-        """`pallas_min_pages` for the 128-lane kernel is a function of the
-        bytes of one K+V page (the rows of docs/kernels.md's table)."""
-        assert att.pallas_min_pages(128, kv_heads, page_size, batch) == expected
+        """`pallas_min_pages` is a function of the bytes of one K+V page
+        (the rows of docs/kernels.md's table)."""
+        assert att.pallas_min_pages(128, kv_heads, page_size) == expected
 
 
 def _crossover_rows():
@@ -368,7 +366,7 @@ class TestGateEqualsTheMeasuredTable:
                   for k in ("aged", "full")]
         auto = att._should_use_pallas(
             row["d"], False, row["width"], row["lanes"], "tpu", 16,
-            row["nkv"], row["cache_pages"])
+            row["nkv"])
         return auto, min(ratios) > 1.03, max(ratios) < 0.97
 
     def test_never_takes_the_measured_loser(self, family):
@@ -378,9 +376,8 @@ class TestGateEqualsTheMeasuredTable:
             # the two paths' bf16 outputs, compared on the chip
             assert max(row["aged.max_abs_diff"],
                        row["full.max_abs_diff"]) < 2e-2, row
-            if row["d"] == 128 and row["aged.gather_spread"] < 0.05:
-                # the main kernel's wins are all taken (the packed
-                # kernel's depend on the cache's size: docs/kernels.md)
+            if row["aged.gather_spread"] < 0.05:
+                # the kernel's wins are all taken
                 assert auto or not kernel_wins, row
 
 
@@ -685,17 +682,16 @@ class TestHybridDecodeKernelCalls:
     def test_gate_takes_the_kernel_at_every_width_of_the_cell(self):
         """80 KB pages (10 rows x 16 tokens x 128, K and V): the kernel at
         every width, the ring's 32 pages included."""
-        assert att.pallas_min_pages(128, 10, 16, 48) == 0
+        assert att.pallas_min_pages(128, 10, 16) == 0
         for width in (8, 16, 32, 64):
             assert att._should_use_pallas(128, False, width, 48, "tpu", 16, 10)
 
-    def test_the_packed_head_64_kernel_takes_no_scale(self):
-        with pytest.raises(ValueError, match="no scale override"):
+    def test_a_head_narrower_than_128_lanes_is_refused_by_the_kernel(self):
+        with pytest.raises(ValueError, match="head_dim % 128 == 0"):
             pk.paged_attention_pallas(
                 jnp.zeros((8, 4, 64), jnp.bfloat16),
                 jnp.zeros((8, 2, 2, 16, 64), jnp.bfloat16),
-                jnp.zeros((8, 4), jnp.int32), jnp.zeros((8,), jnp.int32),
-                scale=0.1)
+                jnp.zeros((8, 4), jnp.int32), jnp.zeros((8,), jnp.int32))
 
 
 class TestLoopedMixedProgram:
@@ -754,7 +750,7 @@ class TestLoopedMixedProgram:
 
     def test_gate_takes_the_kernel_at_every_width_of_the_cell(self):
         """128 KB pages (16 rows x 16 tokens x 128, K and V) at 12 lanes."""
-        assert att.pallas_min_pages(128, 16, 16, 12) == 0
+        assert att.pallas_min_pages(128, 16, 16) == 0
         for width in (8, 16, 24):
             assert att._should_use_pallas(128, False, width, 12, "tpu", 16, 16)
 
