@@ -106,8 +106,9 @@ class StateLayout:
     - `ssm` and `conv`: for every layer that writes `recurrent` one slot
       per lane, sized by the mixer: the scan's float32 state (Mamba-1:
       [d_inner, d_state]; Mamba-2: a matrix a head, [heads, head_dim,
-      d_state]) and the convolution's tail ([d_conv - 1, columns the
-      convolution runs over]: d_inner, or x, B and C together);
+      d_state]; a Kimi-delta mixer: [heads, head_dim, head_dim]) and the
+      convolution's tail ([d_conv - 1, columns the convolution runs over]:
+      d_inner, x, B and C together, or q, k and v together);
     - `latent`: for every layer that writes `latent_kv` (latent attention,
       models/latent.py) pages of the SAME pool and page table, holding ONE
       row a token and no K/V planes or heads: `latent_width` values (the
@@ -160,7 +161,8 @@ class StateLayout:
         def rows(writes):
             return tuple(i for i, r in enumerate(table) if r.writes == writes)
 
-        mamba2 = model_config.mamba_n_heads > 0
+        # the recurrent slot is the mixer's to size
+        ssm_shape, conv_width, d_conv = model_config.recurrent_slot()
 
         return cls(
             paged_layers=rows("paged_kv"), window_layers=rows("window_kv"),
@@ -171,16 +173,13 @@ class StateLayout:
             window=model_config.sliding_window if rows("window_kv") else 0,
             d_inner=model_config.mamba_d_inner,
             d_state=model_config.mamba_d_state,
-            d_conv=model_config.mamba_d_conv, dtype=dtype,
+            d_conv=d_conv, dtype=dtype,
             n_passes=model_config.n_passes,
             latent_layers=rows("latent_kv"),
             latent_width=model_config.latent_width,
             expert_layers=sum(r.ffn == "experts" for r in table),
             expert_sums=4 if model_config.counts_routed_pairs else 2,
-            # Mamba-2 where the model has its heads; else __post_init__'s
-            ssm_shape=(model_config.mamba_n_heads, model_config.mamba_head_dim,
-                       model_config.mamba_d_state) if mamba2 else (),
-            conv_width=model_config.mamba2_conv_dim if mamba2 else 0)
+            ssm_shape=ssm_shape, conv_width=conv_width)
 
     @property
     def _itemsize(self) -> int:

@@ -13,6 +13,8 @@ from typing import Callable, Dict
 import numpy as np
 
 from ..metrics import (
+    ENGINE_KDA_CHUNK_TOKENS,
+    ENGINE_KDA_UPDATE_LANE_STEPS,
     ENGINE_KV_CONTEXT_TOKENS,
     ENGINE_KV_DECODE_PAGES,
     ENGINE_KV_WRITE_CALLS,
@@ -33,6 +35,15 @@ from ..metrics import (
 )
 from ..observability import KV_WRITE_PATHS
 from .kvcache import StateLayout, pages_needed
+
+
+#: a recurrent mixer's two forms, by its kind: the counter of the packed
+#: tokens its chunked form is handed and the counter of the live lanes x
+#: decode steps its one-step form is (each x the layers of that kind)
+_FORM_COUNTERS = {
+    "mamba2": (ENGINE_SSD_SCAN_TOKENS, ENGINE_SSD_UPDATE_LANE_STEPS),
+    "kda": (ENGINE_KDA_CHUNK_TOKENS, ENGINE_KDA_UPDATE_LANE_STEPS),
+}
 
 
 def _page_reach(pages) -> Dict[str, int]:
@@ -98,11 +109,13 @@ class DispatchWork:
         if model_config.has_expert_sums:
             child(ENGINE_MOE_EXPERTS_HELD, of=str(model_config.n_experts)).set(
                 model_config.n_experts_held or model_config.n_experts)
-        # engine_ssd_*_total: what the Mamba-2 mixers' two forms are asked
+        # engine_ssd_*_total, engine_kda_*_total: what the recurrent mixers'
+        # two forms are asked (layers of the kind, its two counters)
+        self._forms = [
+            (sum(row.kind == kind for row in table), child(chunked), child(stepped))
+            for kind, (chunked, stepped) in _FORM_COUNTERS.items()]
         self._ssd_layers = sum(row.kind == "mamba2" for row in table)
-        self._ssd_scan_tokens = child(ENGINE_SSD_SCAN_TOKENS)
         self._ssd_update_calls = child(ENGINE_SSD_UPDATE_CALLS)
-        self._ssd_update_lane_steps = child(ENGINE_SSD_UPDATE_LANE_STEPS)
         # engine_window_*_total: layers that keep a ring a lane, and their
         # window (0 where there is none: nothing is counted)
         self._ring_layers = len(layout.window_layers)
@@ -168,11 +181,11 @@ class DispatchWork:
                 free = np.clip(self._ring_window - pos, 0, n)
                 self._window_lane_steps["no"].inc(int(np.sum(free)))
                 self._window_lane_steps["yes"].inc(int(np.sum(n - free)))
-        if self._ssd_layers:
-            self._ssd_scan_tokens.inc(packed_tokens * self._ssd_layers)
-            self._ssd_update_calls.inc(decode_steps * self._ssd_layers)
-            self._ssd_update_lane_steps.inc(
-                (tokens - packed_tokens) * self._ssd_layers)
+        for layers, chunked, stepped in self._forms:
+            if layers:
+                chunked.inc(packed_tokens * layers)
+                stepped.inc((tokens - packed_tokens) * layers)
+        self._ssd_update_calls.inc(decode_steps * self._ssd_layers)
         if self._host_pairs and tokens:
             # every expert is held and every expert layer sees every token:
             # what is routed is multiplied.  Else the counts are the

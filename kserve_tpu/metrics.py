@@ -525,6 +525,19 @@ ENGINE_SSD_UPDATE_LANE_STEPS = Counter(
     "live lanes summed over those updates: lane-steps whose state moved on",
     ["model_name"],
 )
+# Kimi-delta mixers (ops/delta.kda_*): likewise.
+ENGINE_KDA_CHUNK_TOKENS = Counter(
+    "engine_kda_chunk_tokens_total",
+    "tokens the packed step's chunked delta rule took (real tokens of the "
+    "packed buffer x Kimi-delta layers)",
+    ["model_name"],
+)
+ENGINE_KDA_UPDATE_LANE_STEPS = Counter(
+    "engine_kda_update_lane_steps_total",
+    "live lanes summed over the decode steps' one-step delta-rule updates "
+    "and Kimi-delta layers: lane-steps whose state moved on",
+    ["model_name"],
+)
 # Window layers that keep a ring a lane (models/hybrid.py): whether the
 # window BOUND a decode step's attention, counted at launch from the plan.
 ENGINE_WINDOW_LANE_STEPS = Counter(

@@ -59,6 +59,15 @@ pool's pages with no positions (`config.layer_ropes`); the feed-forward is
 routed experts, of which this chip may hold a share, beside several shared
 ones fused into one MLP (models/moe.py).
 
+Fifth family: `model_type: solar_open2` (Solar-Open2): the second family's
+layer of two residuals with the mixer by the row: `kda`, a Kimi-delta
+linear-attention mixer (one convolution over q, k and v together, a matrix a
+head in `state["ssm"]` updated by the gated delta rule with a decay per
+channel, a gated RMSNorm a head behind it; ops/delta.kda_*), or
+`gqa_attention` with no positions whose output a sigmoid gate multiplies
+(`config.attention_gate`); every row's feed-forward is routed experts, of
+which this chip may hold a share, beside a shared one.
+
 Layers behind the last layer that writes state only feed the logits, so the
 packed forward runs them (and the last writer's own attention output) on
 the rows that are sampled, one per lane: exact, and it makes every read of
@@ -77,7 +86,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..ops.kv_write import append_token_kv, slice_runs, write_ragged_kv
-from ..ops import ssm
+from ..ops import delta, ssm
 from ..ops.attention import (
     latent_paged_attention,
     latent_ragged_attention,
@@ -103,7 +112,8 @@ def layer_param_shapes(config, spec) -> Dict[str, tuple]:
     `init`: "normal" (N(0, scale)), "ones", "bias" (N(0, scale)),
     "lambda" (N(0, 0.1), arXiv:2410.05258), "A_log", "dt_bias", "D" (the
     Mamba-1 defaults), "A_log_heads" (Mamba-2: one A a head, spread over
-    [1, 16]), "zeros" (a router's choice-only bias); float32 for those;
+    [1, 16]), "A_log_kda" / "dt_bias_kda" (a Kimi-delta mixer's decays:
+    `make`), "zeros" (a router's choice-only bias); float32 for those;
     "routed_out" (N(0, scale x ROUTED_OUT_GAIN): a routed expert's
     down-projection).  A row has the norm of each sublayer it has."""
     h, f = config.hidden_size, config.intermediate_size
@@ -130,6 +140,20 @@ def layer_param_shapes(config, spec) -> Dict[str, tuple]:
         shapes.update({
             "wq": ((h, nq * hd), "normal"), "wk": ((h, nkv * hd), "normal"),
             "wv": ((h, nkv * hd), "normal"), "wo": ((nq * hd, h), "normal")})
+        if config.attention_gate and spec.kind == "gqa_attention":
+            shapes["wg"] = ((h, nq * hd), "normal")
+    elif spec.kind == "kda":
+        heads, d, rank = config.kda_n_heads, config.kda_head_dim, config.kda_rank
+        shapes.update({
+            "wqkv": ((h, config.kda_conv_dim), "normal"),
+            "conv_w": ((config.kda_d_conv, config.kda_conv_dim), "normal"),
+            "wf_a": ((h, rank), "normal"), "wf_b": ((rank, heads * d), "normal"),
+            "A_log": ((heads,), "A_log_kda"),
+            "dt_bias": ((heads * d,), "dt_bias_kda"),
+            "w_beta": ((h, heads), "normal"),
+            "wg_a": ((h, rank), "normal"), "wg_b": ((rank, heads * d), "normal"),
+            "o_norm": ((d,), "ones"), "wo": ((heads * d, h), "normal"),
+        })
     elif spec.kind == "mamba2":
         heads, conv = config.mamba_n_heads, config.mamba2_conv_dim
         shapes.update({
@@ -218,11 +242,20 @@ def init_params(config, rng, scale: float = 0.02, weight_quant: str = "none",
             return jnp.log(jnp.linspace(1.0, 16.0, shape[0], dtype=jnp.float32))
         if init == "D":
             return jnp.ones(shape, jnp.float32)
-        if init == "dt_bias":
-            # softplus(dt_bias) log-uniform in [1e-3, 1e-1]
+        if init in ("dt_bias", "dt_bias_kda"):
+            # softplus(dt_bias) log-uniform in [1e-3, 1e-1]; a Kimi-delta
+            # mixer's in [1e-3, 5e-2]: with A below in [0.5, 1.5] and the
+            # projection's own term of deviation ~0.3 a token keeps 0.9 to
+            # 0.999 of a channel, so that what a lane carries over hundreds
+            # of tokens shows in its logits (a state that forgets in a few
+            # tokens would hide an error in what is carried)
+            top = 0.1 if init == "dt_bias" else 0.05
             dt = jnp.exp(jax.random.uniform(key, shape, jnp.float32)
-                         * (math.log(0.1) - math.log(0.001)) + math.log(0.001))
+                         * (math.log(top) - math.log(0.001)) + math.log(0.001))
             return dt + jnp.log(-jnp.expm1(-dt))
+        if init == "A_log_kda":
+            return jnp.log(jax.random.uniform(
+                key, shape, jnp.float32, 0.5, 1.5))
         return (jax.random.normal(key, shape, jnp.float32) * scale).astype(dtype)
 
     def make_layer(spec, key):
@@ -376,9 +409,16 @@ def _gqa_keys_values(layer, u, config, pos, i):
     return _rope(k, pos, i, config), v
 
 
-def _gqa_out(layer, attn):
-    """A `gqa_attention` row's output: attn [N, heads, head_dim] -> [N, h]."""
-    return dense(attn.reshape(attn.shape[0], -1), layer["wo"])
+def _gqa_out(layer, attn, u=None):
+    """A `gqa_attention` row's output: attn [N, heads, head_dim] -> [N, h];
+    where the row has a gate (`config.attention_gate`: "wg"), times
+    sigmoid(u W_g) first, u [N, h] what the row's projections read."""
+    attn = attn.reshape(attn.shape[0], -1)
+    if "wg" in layer:
+        with jax.named_scope("attention_gate"):
+            gate = jax.nn.sigmoid(dense(u, layer["wg"]).astype(jnp.float32))
+            attn = (attn.astype(jnp.float32) * gate).astype(attn.dtype)
+    return dense(attn, layer["wo"])
 
 
 def _scale(config) -> float:
@@ -447,6 +487,45 @@ def _mamba2_out(layer, y, z, config):
     return dense(normed.astype(z.dtype), layer["out_proj"])
 
 
+def _kda_project(layer, u, config):
+    """[N, h] -> (qkv [N, 3 H d] before the convolution, g [N, H, d] float32:
+    the log decay a head and channel, <= 0, beta [N, H] float32, the output
+    gate's argument [N, H d])."""
+    N, H, d = u.shape[0], config.kda_n_heads, config.kda_head_dim
+    f32 = jnp.float32
+    f = dense(dense(u, layer["wf_a"]), layer["wf_b"]).astype(f32)
+    g = -jnp.exp(layer["A_log"].astype(f32))[None, :, None] * jax.nn.softplus(
+        (f + layer["dt_bias"].astype(f32)).reshape(N, H, d))
+    beta = jax.nn.sigmoid(dense(u, layer["w_beta"]).astype(f32))
+    if config.kda_neg_eigval:
+        beta = 2.0 * beta
+    return (dense(u, layer["wqkv"]), g, beta,
+            dense(dense(u, layer["wg_a"]), layer["wg_b"]))
+
+
+def _kda_scan_inputs(conv_out, config):
+    """The convolution's output (float32, before its activation) -> q, k, v
+    [N, H, d] float32 as the recurrence takes them: q and k of unit length a
+    head, q scaled by d^-1/2."""
+    N, H, d = conv_out.shape[0], config.kda_n_heads, config.kda_head_dim
+    q, k, v = (x.reshape(N, H, d)
+               for x in jnp.split(jax.nn.silu(conv_out), 3, axis=-1))
+    q = q * jax.lax.rsqrt(jnp.sum(q * q, axis=-1, keepdims=True) + 1e-6)
+    k = k * jax.lax.rsqrt(jnp.sum(k * k, axis=-1, keepdims=True) + 1e-6)
+    return q * float(d) ** -0.5, k, v
+
+
+def _kda_out(layer, o, gate, config):
+    """The recurrence's output o [N, H, d] float32 -> the mixer's output:
+    an RMSNorm over each head by itself, times sigmoid(gate), then `wo`."""
+    with jax.named_scope("kda_gated_norm"):
+        var = jnp.mean(o * o, axis=-1, keepdims=True)
+        normed = (o * jax.lax.rsqrt(var + config.rms_norm_eps)
+                  * layer["o_norm"].astype(jnp.float32)).reshape(o.shape[0], -1)
+        gated = normed * jax.nn.sigmoid(gate.astype(jnp.float32))
+    return dense(gated.astype(gate.dtype), layer["wo"])
+
+
 def _gmu(layer, u, m):
     with jax.named_scope("gmu"):
         gate = jax.nn.silu(dense(u, layer["gmu_in"]).astype(jnp.float32))
@@ -511,6 +590,21 @@ def _rows_layer(layer, spec, i, x, pos, live, state, slots, page_table,
             state["conv"][j] = jnp.where(
                 live[:, None, None], tail, state["conv"][j])
             mixed = _mamba2_out(layer, y, z, config)
+    elif spec.kind == "kda":
+        with jax.named_scope("kda"):
+            j = slots[i]
+            qkv, g, beta, gate = _kda_project(layer, u, config)
+            with jax.named_scope("kda_conv"):
+                conv_out, tail = ssm.causal_conv_step(
+                    qkv, state["conv"][j], layer["conv_w"],
+                    jnp.zeros((), jnp.float32))
+            q, k, v = _kda_scan_inputs(conv_out, config)
+            with jax.named_scope("kda_update"):
+                o, s = delta.kda_step(q, k, v, g, beta, state["ssm"][j], live)
+            state["ssm"][j] = s
+            state["conv"][j] = jnp.where(
+                live[:, None, None], tail, state["conv"][j])
+            mixed = _kda_out(layer, o, gate, config)
     elif spec.kind == "gqa_attention":
         with jax.named_scope("gqa_attention"):
             j = slots[i]
@@ -521,7 +615,7 @@ def _rows_layer(layer, spec, i, x, pos, live, state, slots, page_table,
             attn = paged_attention(
                 _gqa_queries(layer, u, config, pos, i), state["paged"][j],
                 page_table, seq_lens, use_pallas=use_pallas)
-            mixed = _gqa_out(layer, attn)
+            mixed = _gqa_out(layer, attn, u)
     elif spec.kind == "gqa_window_attention":
         with jax.named_scope("window_attention"):
             j = slots[i]
@@ -693,6 +787,24 @@ def forward_ragged(params, config, tokens, token_seq, token_pos, q_start,
                 state["conv"][j] = jnp.where(
                     has_slice[:, None, None], tail, state["conv"][j])
                 mixed = _mamba2_out(layer, y, z, config)
+        elif spec.kind == "kda":
+            with jax.named_scope("kda"):
+                j = slots[i]
+                qkv, g, beta, gate = _kda_project(layer, u, config)
+                with jax.named_scope("kda_conv"):
+                    conv_out, tail = ssm.causal_conv_ragged(
+                        qkv, state["conv"][j], layer["conv_w"],
+                        jnp.zeros((), jnp.float32), token_seq, token_off,
+                        q_start, q_len, fresh)
+                q, k, v = _kda_scan_inputs(conv_out, config)
+                with jax.named_scope("kda_chunk_scan"):
+                    o, s = delta.kda_ragged(
+                        q, k, v, g, beta, state["ssm"][j], q_start, q_len,
+                        fresh)
+                state["ssm"][j] = s
+                state["conv"][j] = jnp.where(
+                    has_slice[:, None, None], tail, state["conv"][j])
+                mixed = _kda_out(layer, o, gate, config)
         elif spec.kind == "gqa_attention" and i != last_writer:
             with jax.named_scope("gqa_attention"):
                 j = slots[i]
@@ -704,7 +816,7 @@ def forward_ragged(params, config, tokens, token_seq, token_pos, q_start,
                     _gqa_queries(layer, u, config, token_pos, i),
                     state["paged"][j], page_table, q_start, q_len, kv_start,
                     use_pallas=use_pallas)
-                mixed = _gqa_out(layer, attn)
+                mixed = _gqa_out(layer, attn, u)
         elif spec.kind == "gqa_window_attention":
             with jax.named_scope("window_attention"):
                 j = slots[i]

@@ -72,15 +72,27 @@ def _map_hidden_act(act) -> str:
 #: (`LlamaConfig.diff_attention`: a pair of heads a cache row) and
 #: `gqa_attention` is plain grouped-query attention over the pool's pages,
 #: `gqa_window_attention` the same over the last `sliding_window` tokens,
-#: kept in a ring
+#: kept in a ring; `kda` is a Kimi-delta linear-attention mixer (the gated
+#: delta rule with a decay per channel, ops/delta.py)
 MIXER_KINDS = ("attention", "window_attention", "cross_attention", "mamba",
                "gmu", "latent_attention", "mamba2", "ffn", "gqa_attention",
-               "gqa_window_attention")
+               "gqa_window_attention", "kda")
 _WRITES = {"attention": "paged_kv", "window_attention": "window_kv",
            "cross_attention": "none", "mamba": "recurrent", "gmu": "none",
            "latent_attention": "latent_kv", "mamba2": "recurrent",
            "ffn": "none", "gqa_attention": "paged_kv",
-           "gqa_window_attention": "window_kv"}
+           "gqa_window_attention": "window_kv", "kda": "recurrent"}
+#: what a layer that writes `recurrent` keeps a lane, by its kind: (the
+#: float32 state's shape, the columns its convolution runs over, the
+#: convolution's taps).  engine/kvcache.StateLayout sizes its slots by it
+_RECURRENT_SLOT = {
+    "mamba": lambda c: ((c.mamba_d_inner, c.mamba_d_state),
+                        c.mamba_d_inner, c.mamba_d_conv),
+    "mamba2": lambda c: ((c.mamba_n_heads, c.mamba_head_dim, c.mamba_d_state),
+                         c.mamba2_conv_dim, c.mamba_d_conv),
+    "kda": lambda c: ((c.kda_n_heads, c.kda_head_dim, c.kda_head_dim),
+                      c.kda_conv_dim, c.kda_d_conv),
+}
 
 #: model_type values LlamaConfig's family knobs describe
 _LLAMA_MODEL_TYPES = (None, "llama", "mistral", "mixtral", "qwen2", "qwen3",
@@ -206,6 +218,18 @@ class LlamaConfig:
     mamba_n_groups: int = 0
     # every layer is one sublayer (Nemotron-H): see LayerSpec
     one_sublayer: bool = False
+    # Kimi-delta mixers (kind "kda", ops/delta.py): heads x head_dim columns
+    # of q, k and v each, a convolution of kda_d_conv taps over all three, a
+    # float32 state [heads, head_dim, head_dim] a lane; the decay's and the
+    # output gate's projections pass through kda_rank columns
+    kda_n_heads: int = 0
+    kda_head_dim: int = 0
+    kda_d_conv: int = 0
+    kda_rank: int = 0
+    kda_neg_eigval: bool = False  # beta in (0, 2): eigenvalues in (-1, 1)
+    # `gqa_attention` rows multiply their attention's output by
+    # sigmoid(u W_g) before the output projection (arXiv:2505.06708)
+    attention_gate: bool = False
     # ---- latent attention (models/latent.py): queries through a rank
     # q_lora_rank bottleneck, keys and values through ONE compressed row of
     # kv_lora_rank values plus qk_rope_head_dim roped ones a token, which is
@@ -290,6 +314,23 @@ class LlamaConfig:
         """Columns a Mamba-2 mixer's convolution runs over: x, B and C."""
         return (self.mamba_n_heads * self.mamba_head_dim
                 + 2 * self.mamba_n_groups * self.mamba_d_state)
+
+    @property
+    def kda_conv_dim(self) -> int:
+        """Columns a Kimi-delta mixer's convolution runs over: q, k and v."""
+        return 3 * self.kda_n_heads * self.kda_head_dim
+
+    def recurrent_slot(self) -> Tuple[Tuple[int, ...], int, int]:
+        """A lane's slot in a layer that writes `recurrent`, by the mixer
+        (_RECURRENT_SLOT); a model without such a layer gets Mamba-1's at
+        its sizes of 0.  The slots of one model have one shape."""
+        kinds = {kind for kind in self.mixer_kinds or ()
+                 if _WRITES[kind] == "recurrent"}
+        if len(kinds) > 1:
+            raise ValueError(
+                f"recurrent mixers of {len(kinds)} kinds ({sorted(kinds)}) in "
+                "one model: a lane's slots have one shape")
+        return _RECURRENT_SLOT[kinds.pop() if kinds else "mamba"](self)
 
     @property
     def n_expert_layers(self) -> int:
@@ -486,6 +527,8 @@ class LlamaConfig:
             return _nemotron_h_config(cfg)
         if model_type == "cohere2_moe":
             return _cohere2_moe_config(cfg)
+        if model_type == "solar_open2":
+            return _solar_open2_config(cfg)
         if model_type not in _LLAMA_MODEL_TYPES:
             foreign = [k for k in _FOREIGN_MIXER_KEYS if k in cfg
                        and not (k == "total_ut_steps" and int(cfg[k]) <= 1)]
@@ -845,6 +888,90 @@ def _cohere2_moe_config(cfg: dict) -> LlamaConfig:
             "shared_expert_combination_strategy", "average") == "average",
         moe_router="sigmoid",
         moe_router_bias=False,
+        norm_topk_prob=bool(cfg.get("norm_topk_prob", True)),
+    )
+
+
+def _solar_open2_config(cfg: dict) -> LlamaConfig:
+    """config.json of `model_type: solar_open2` (Solar-Open2): pre-norm
+    layers of two residuals, `h += Mixer(RMSNorm(h)); h += Experts(
+    RMSNorm(h))`, with no positional encoding anywhere.  The mixer is a
+    Kimi-delta linear-attention mixer (`kda`, the `kda_*` keys and
+    `linear_attn_config`: arXiv:2510.26692) but in `gqa_layers`, every
+    `gqa_interval + 1`-th layer from 0, where it is plain grouped-query
+    attention whose output an elementwise sigmoid gate multiplies
+    (`use_gqa_gate`).  Every layer's feed-forward is routed experts (sigmoid
+    scores, a choice-only bias, weights normalised over the chosen) beside
+    one shared expert.  What the published file leaves to the modeling file
+    is listed under `assumed` in benchmark/configs/solar-open2.json.  A
+    deployment that holds a chip's share of the experts says so as the
+    Nemotron family does: `n_routed_experts` counts the experts HELD here,
+    `router_n_experts` the experts the router scores (the published count),
+    `first_expert` the first one held."""
+    n_layers = cfg["num_hidden_layers"]
+    linear = cfg.get("linear_attn_config") or {}
+    period = int(cfg.get("gqa_interval", 3)) + 1
+    gqa = list(cfg.get("gqa_layers") or ())
+    refused = []
+    if int(cfg.get("first_k_dense_replace", 0)):
+        refused.append(f"first_k_dense_replace={cfg['first_k_dense_replace']} "
+                       "(leading dense feed-forwards)")
+    if cfg.get("kda_use_full_proj"):
+        refused.append("kda_use_full_proj true (the decay's and the gate's "
+                       "projections at full rank)")
+    if cfg.get("use_rope"):
+        refused.append("use_rope true (the attention rows carry no positions)")
+    if int(cfg.get("n_group", 1)) != 1 or int(cfg.get("topk_group", 1)) != 1:
+        refused.append(f"n_group={cfg.get('n_group')} / topk_group="
+                       f"{cfg.get('topk_group')} (group-limited routing)")
+    if linear.get("num_kv_heads") is not None:
+        refused.append(f"linear_attn_config.num_kv_heads="
+                       f"{linear['num_kv_heads']} (keys shared by groups of "
+                       "heads)")
+    if gqa != list(range(0, n_layers, period)):
+        refused.append(f"gqa_layers={gqa} (built: every {period}th layer "
+                       f"from 0 of num_hidden_layers={n_layers})")
+    if int(cfg.get("n_shared_experts", 1)) > 1:
+        refused.append(f"n_shared_experts={cfg['n_shared_experts']} (held to "
+                       "no reference for this family)")
+    if cfg.get("tie_word_embeddings"):
+        refused.append("tie_word_embeddings true")
+    if cfg.get("attention_bias"):
+        refused.append("attention_bias true")
+    if refused:
+        raise ValueError("solar_open2: not implemented: " + "; ".join(refused))
+    held = cfg["n_routed_experts"]
+    scored = int(cfg.get("router_n_experts", held))
+    return LlamaConfig(
+        vocab_size=cfg["vocab_size"],
+        hidden_size=cfg["hidden_size"],
+        intermediate_size=cfg["intermediate_size"],
+        n_layers=n_layers,
+        n_heads=cfg["num_attention_heads"],
+        n_kv_heads=cfg["num_key_value_heads"],
+        head_dim=cfg.get("head_dim"),
+        rope_theta=cfg.get("rope_theta", 10000.0),
+        rms_norm_eps=cfg.get("rms_norm_eps", 1e-5),
+        max_position_embeddings=cfg.get("max_position_embeddings", 4096),
+        tie_word_embeddings=False,
+        mixer_kinds=tuple("gqa_attention" if i % period == 0 else "kda"
+                          for i in range(n_layers)),
+        use_rope=False,
+        attention_gate=bool(cfg.get("use_gqa_gate", False)),
+        kda_n_heads=linear["num_heads"],
+        kda_head_dim=linear["head_dim"],
+        kda_d_conv=linear["short_conv_kernel_size"],
+        # the family's low-rank projections pass through head_dim columns
+        kda_rank=linear["head_dim"],
+        kda_neg_eigval=bool(cfg.get("kda_allow_neg_eigval", False)),
+        n_experts=scored,
+        n_experts_held=0 if held == scored else held,
+        first_expert=int(cfg.get("first_expert", 0)),
+        n_experts_per_tok=cfg["num_experts_per_tok"],
+        moe_intermediate_size=cfg["moe_intermediate_size"],
+        n_shared_experts=int(cfg.get("n_shared_experts", 0)),
+        moe_router="sigmoid",
+        routed_scaling_factor=float(cfg.get("routed_scaling_factor", 1.0)),
         norm_topk_prob=bool(cfg.get("norm_topk_prob", True)),
     )
 

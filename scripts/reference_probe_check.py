@@ -20,7 +20,9 @@ int8` computes the reference a second time with its projection weights
 rounded to int8 per output channel (the nearest precision below bf16
 weights that the program has: models/quant.py's rule) and reports that
 reading beside the first: a configuration's `logit_tolerance` lies between
-the two.
+the two as the harness's own probes read them (`--seed 24 --prompts
+48,48,48,48 --served 8` sends exactly those); `--tolerance` holds longer
+probes to a limit of their own.
 
 Prints one JSON line: per probe its largest gap, the largest of all, the
 share of served tokens that are the reference's argmax, the tolerance of
@@ -55,6 +57,11 @@ def main(argv=None) -> int:
                     help="send the probes at once: lanes share dispatches")
     ap.add_argument("--weights", choices=("bf16", "int8"), default="bf16",
                     help="int8: also read the reference with int8 weights")
+    ap.add_argument("--tolerance", type=float, default=None,
+                    help="hold the reading to this limit and not to the "
+                    "configuration's logit_tolerance, which is set at the "
+                    "harness's own 48 + 8 probes: thousands of tokens of "
+                    "near-flat random logits have a scale of their own")
     ap.add_argument("--experts", action="store_true",
                     help="also count, on the host CPU, the (position, expert "
                     "layer) decisions at which the program's router and the "
@@ -95,7 +102,8 @@ def main(argv=None) -> int:
          "--probes", probes_path, "--out", out_path], env=env, check=True)
     with open(out_path) as f:
         result = json.load(f)
-    tolerance = plan.cell.deployment["logit_tolerance"]
+    tolerance = (plan.cell.deployment["logit_tolerance"]
+                 if args.tolerance is None else args.tolerance)
     line = {
         "workload": args.workload, "platform": platform,
         "prompt_lens": args.prompts, "served": args.served,
@@ -158,6 +166,9 @@ def int8_child(config_path, family_name, probes_path, out_path) -> int:
         # a Mamba-2 mixer's two projections (the Mamba-1 family's readings
         # of PR 29 were taken with its own left in bf16, and stay so)
         linear += ("in_proj", "out_proj")
+    if family_name == "solar_open2":
+        # a Kimi-delta mixer's projections and the attention row's gate
+        linear += ("wqkv", "wf_a", "wf_b", "w_beta", "wg_a", "wg_b", "wg")
     for i, layer in enumerate(params["layers"]):
         # in place: a layer's bf16 tensors go as its float32 ones come
         params["layers"][i] = {k: rounded(v) if k in linear else v

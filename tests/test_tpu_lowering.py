@@ -963,3 +963,41 @@ class TestCommandAPlus:
         report = att.describe_attention_dispatch(mc, cfg, "tpu")
         assert report["mixed"] == "pallas_window_ragged+pallas_ragged"
         assert report["kv_write"] == {"paged": "page_kernel", "window": "page_kernel"}
+
+
+class TestSolarOpen2:
+    """`model_type: solar_open2` (PR 49): the delta rule's two forms compile
+    for the described v5e at the published sizes, and the packed form holds
+    one piece's arrays at a time."""
+
+    def test_both_forms_compile_at_the_published_sizes(self):
+        """64 heads of 128, 48 lanes, a 4096-token buffer: the pieces run
+        inside ONE loop whose body inverts one triangular system by matrix
+        products (no library call: ops/delta._unit_lower_inverse); nothing
+        over [tokens, heads, d, d] (137 GB) and no padded copy of the
+        buffer's five inputs (680 MB): the temporaries are the loop's copy
+        of the lanes' states (201 MB), the output and one piece's arrays."""
+        if _tpu_sharding() is None:
+            pytest.skip("no compile-only TPU topology here")
+        import re
+
+        from kserve_tpu.ops import delta
+
+        T, lanes, H, d = 4096, 48, 64, 128
+        f32 = jnp.float32
+        buf, state = _abstract((T, H, d), f32), _abstract((lanes, H, d, d), f32)
+        compiled = jax.jit(delta.kda_ragged).lower(
+            buf, buf, buf, buf, _abstract((T, H), f32), state, _i32(lanes),
+            _i32(lanes), _abstract((lanes,), jnp.bool_)).compile()
+        text = compiled.as_text()
+        assert "InvertDiagBlocksLowerTriangular" not in text  # no library solve
+        assert len(re.findall(r"\bconditional\(", text)) == 1  # a dead piece
+        assert not re.findall(r"f32\[\d+,64,128,128,128\]", text)
+        temp = compiled.memory_analysis().temp_size_in_bytes
+        assert temp < 450e6, temp
+        lane = _abstract((lanes, H, d), f32)
+        step = jax.jit(delta.kda_step).lower(
+            lane, lane, lane, lane, _abstract((lanes, H), f32), state,
+            _abstract((lanes,), jnp.bool_)).compile()
+        # the state read and written once: no copy of it beside the result
+        assert step.memory_analysis().temp_size_in_bytes < lanes * H * d * d * 4
