@@ -20,7 +20,7 @@ import subprocess
 import sys
 
 from .manifest import BENCH_DIR
-from .server import MODEL_NAME
+from .server import MODEL_NAME, die_with_parent
 
 #: the probes are the same for every --seed: the reference's result is
 #: kept in the checkout's cache by (configuration, probes, served tokens),
@@ -110,7 +110,7 @@ class ReferenceCheck:
                  "--family", family, "--probes", probes_path,
                  "--out", self.out_path],
                 env=env, stdout=self._log, stderr=subprocess.STDOUT,
-                start_new_session=True)
+                start_new_session=True, preexec_fn=die_with_parent())
 
     def result(self, timeout_s: float = 900.0) -> dict:
         if self.proc is not None:

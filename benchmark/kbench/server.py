@@ -6,12 +6,14 @@ benchmark takes only the served endpoints, `/metrics`, `/admin/telemetry`,
 `/v1/internal/scheduler/state` and `/admin/profile`.
 """
 
+import ctypes
 import json
 import os
 import signal
 import socket
 import subprocess
 import sys
+import threading
 import time
 import urllib.error
 import urllib.request
@@ -94,9 +96,34 @@ def child_env(platform: str, cache: str, n_cpu_devices: int = 0) -> dict:
     return env
 
 
+PR_SET_PDEATHSIG = 1  # <linux/prctl.h>
+
+
+def die_with_parent():
+    """A `preexec_fn`: the kernel sends the child SIGKILL when the thread
+    that started it ends, however it ends.  A `run.py` killed by SIGKILL (a
+    run at its limit) runs no handler and no `finally`, and a child in a
+    session of its own would go on holding the chip (the ledger's
+    `process_left_running`, PR 44)."""
+    libc = ctypes.CDLL(None)  # loaded here, by the parent, not after the fork
+    parent = os.getpid()
+
+    def in_child():
+        libc.prctl(PR_SET_PDEATHSIG, signal.SIGKILL)
+        if os.getppid() != parent:  # it ended between the fork and the call
+            os._exit(1)
+
+    return in_child
+
+
 class Server:
     """The server child, in its own process group so that nothing it
-    spawns outlives the run."""
+    spawns outlives the run, and not the run either: SIGTERM to this
+    process kills the group before the process exits (code 143, through
+    the callers' `finally`), SIGKILL leaves it to `die_with_parent`."""
+
+    #: what python runs as the server; a test puts a sleeping stand-in here
+    ENTRY = ("-m", "kserve_tpu.runtimes.generative_server")
 
     def __init__(self, flags: dict, platform: str, cache: str, log_name: str,
                  n_cpu_devices: int = 0):
@@ -105,12 +132,29 @@ class Server:
         os.makedirs(os.path.join(cache, "logs"), exist_ok=True)
         self.log_path = os.path.join(cache, "logs", log_name + ".server.log")
         self._log = open(self.log_path, "wb")
-        argv = [sys.executable, "-m", "kserve_tpu.runtimes.generative_server",
+        argv = [sys.executable, *self.ENTRY,
                 f"--http_port={self.port}", f"--model_name={MODEL_NAME}",
                 "--enable_grpc=false", *flags_to_argv(flags)]
         self.proc = subprocess.Popen(
             argv, cwd=ROOT, env=child_env(platform, cache, n_cpu_devices),
-            stdout=self._log, stderr=subprocess.STDOUT, start_new_session=True)
+            stdout=self._log, stderr=subprocess.STDOUT, start_new_session=True,
+            preexec_fn=die_with_parent())
+        # signal handlers are the main thread's to set; run.py starts its
+        # server there
+        self._sigterm_was = None
+        if threading.current_thread() is threading.main_thread():
+            self._sigterm_was = signal.signal(signal.SIGTERM, self._on_sigterm)
+
+    def _on_sigterm(self, signum, frame):
+        self._kill_group()
+        raise SystemExit(128 + signum)
+
+    def _kill_group(self) -> None:
+        try:
+            os.killpg(self.proc.pid, signal.SIGKILL)
+        except (ProcessLookupError, PermissionError):
+            pass
+        self.proc.wait()
 
     # ---- HTTP, from the parent's side ----
 
@@ -162,12 +206,11 @@ class Server:
                 code = self.proc.wait(STOP_TIMEOUT_S)
             except subprocess.TimeoutExpired:
                 code = None
-        try:
-            os.killpg(self.proc.pid, signal.SIGKILL)
-        except (ProcessLookupError, PermissionError):
-            pass
-        self.proc.wait()
+        self._kill_group()
         self._log.close()
+        if self._sigterm_was is not None:
+            signal.signal(signal.SIGTERM, self._sigterm_was)
+            self._sigterm_was = None
         return self.proc.returncode if code is None else code
 
     def log_tail(self, lines: int = 40) -> str:

@@ -1,6 +1,6 @@
-"""End-to-end utilisation: 2 x matmul parameters x (prompt + output tokens per second) over chips x the chip's bf16 peak.
+"""The model's FLOPs utilisation over the whole step: served tokens (prompt + output) per second x 2 x the parameters a token multiplies, over chips x the chip's bf16 peak.
 
-Not a roofline share and not an mfu of a kernel: it says how much of the chips' arithmetic the served tokens needed."""
+The share of the chips' arithmetic that the served tokens needed, whatever kernels did the work: it still reads where a kernel is taken off the path and that kernel's own roofline falls silent.  Not a kernel's roofline share."""
 
 from kbench.model_math import forward_flops_per_token
 from kbench.server import metric_delta

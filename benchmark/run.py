@@ -63,6 +63,20 @@ TRACE_TAIL_S = TRACE_SECONDS + 1.0
 TRACED_MAX_S = 200.0
 MAX_OPEN_LATE_S = 2.0
 MAX_RAMPS = 3
+#: A run is ended from outside this long after its launch (PERF.md section
+#: 7, "`process_left_running`": PR 44's cold chat run ran out at it).
+RUN_LIMIT_S = 1200.0
+#: Kept back for what a traced run still does once its capture is written:
+#: the state, the server's stop, the trace's reduction, the line.  Measured
+#: in `ouro-2.6b.eval-sat`, the largest capture there is: 29.4-30.8 s from
+#: the write's end to the process's exit in five runs, of which the stop
+#: 5.1-5.9 s and the reduction 23.8-24.8 s (my chip runs, PR 51; PERF.md
+#: section 6).  Four times that: the stop may wait STOP_TIMEOUT_S, 60 s, for
+#: a server that does not go, and the reduction twice as long on a busy host.
+STOP_RESERVE_S = 120.0
+#: what the two waits for a capture had before they followed the run's
+#: clock (PR 51); neither is ever shorter
+CAPTURE_WAIT_S = {1: 120.0, 2: 200.0}
 
 
 def log(msg: str) -> None:
@@ -160,11 +174,11 @@ def traced_phase(server: Server, plan: Plan, seed: int, profile_dir: str) -> dic
     which so falls into no number); at the ramp's end TRACE_SECONDS are
     captured (`{"seconds": N}`: the server stops the capture itself), and
     the traffic is called off as the capture ends.  Writing the trace then
-    takes the server 37-79 s more, in a thread of its own: `wait_capture`
-    waits that out after the probes.  Returns what the capture cost so
-    far: the first start and stop, how long the server took to answer
-    /admin/telemetry under the capture, the engine's dispatch period
-    under it."""
+    takes the server 37-79 s more (190-200+ s in `ouro-2.6b.eval-sat`), in
+    a thread of its own: `wait_capture` waits that out after the probes.
+    Returns what the capture cost so far: the first start and stop, how
+    long the server took to answer /admin/telemetry under the capture, the
+    engine's dispatch period under it, and when the capture ended."""
     import aiohttp
 
     mix = plan.mix
@@ -232,21 +246,41 @@ def traced_phase(server: Server, plan: Plan, seed: int, profile_dir: str) -> dic
     return out
 
 
-def wait_capture(server: Server, cost: dict, timeout_s: float = 200.0) -> None:
-    """Until the traced stretch's capture is written; adds to `cost` how
-    long that took from the capture's end (`stop_s`) and the longest the
-    server took to answer meanwhile."""
-    ended_at = cost.pop("capture_ended_at")
-    while time.monotonic() - ended_at < timeout_s:
+def wait_capture(server: Server, ended_at: float, at_least_s: float) -> dict:
+    """Until the profiler has written its capture, which ended at
+    `ended_at` (`time.monotonic`).  How long that may take is set by what
+    the run has left: to RUN_LIMIT_S after the launch less STOP_RESERVE_S,
+    and `at_least_s` however late the run is.  Returns how long it took
+    from the capture's end (`stop_s`), the limit it was held to
+    (`stop_limit_s`) and the longest the server took to answer meanwhile."""
+    limit_s = max(at_least_s,
+                  _T_LAUNCH + RUN_LIMIT_S - STOP_RESERVE_S - ended_at)
+    answered_s = 0.0
+    while True:
         t = time.monotonic()
-        active = server.get_json("/admin/telemetry")["profiler"]["active"]
-        cost["telemetry_answered_s"] = max(
-            cost["telemetry_answered_s"], time.monotonic() - t)
+        try:
+            # a `stop_trace` that blocks the loop that would answer (the
+            # program's before PR 26) is waited out
+            active = server.get_json(
+                "/admin/telemetry", timeout=at_least_s)["profiler"]["active"]
+        except OSError as e:
+            raise ServerFailure(
+                f"the server stopped answering {t - ended_at:.0f} s after "
+                f"the capture's end, {t - _T_LAUNCH:.0f} s into the run, "
+                f"with the capture being written: {e!r}") from None
+        now = time.monotonic()
+        answered_s = max(answered_s, now - t)
         if not active:
-            cost["stop_s"] = time.monotonic() - ended_at
-            return
+            return {"stop_s": now - ended_at, "stop_limit_s": limit_s,
+                    "telemetry_answered_s": answered_s}
+        if now - ended_at >= limit_s:
+            raise ServerFailure(
+                f"the profiler capture did not end: {now - ended_at:.0f} s "
+                f"after the capture's end (the limit was {limit_s:.0f} s) and "
+                f"{now - _T_LAUNCH:.0f} s into a run of {RUN_LIMIT_S:.0f} s "
+                "the server still answers and reports the capture `active`: "
+                "a slow write of the trace, not a server that died")
         time.sleep(0.5)
-    raise ServerFailure("the profiler capture did not end")
 
 
 def drive(server: Server, plan: Plan, seed: int, seconds: float, trace: bool,
@@ -295,17 +329,6 @@ def wait_idle(server: Server, timeout_s: float = IDLE_TIMEOUT_S) -> float:
             return time.monotonic() - t0
         time.sleep(0.25)
     raise ServerFailure(f"server not idle {timeout_s:.0f} s after the window")
-
-
-def wait_profiler(server: Server, timeout_s: float = 120.0) -> None:
-    t0 = time.monotonic()
-    while time.monotonic() - t0 < timeout_s:
-        # `stop_trace` blocks the loop that would answer: wait it out
-        if not server.get_json(
-                "/admin/telemetry", timeout=timeout_s)["profiler"]["active"]:
-            return
-        time.sleep(0.5)
-    raise ServerFailure("the profiler capture did not end")
 
 
 def reduce_trace(profile_dir: str, out_path: str) -> dict:
@@ -481,22 +504,34 @@ def measure(args, plan: Plan, platform: str) -> int:
                 "had warmed: no window was measured")
         log(f"window closed; set-up was {side['t_open_launch_s']:.1f} s")
         if args.trace == 1:
-            wait_profiler(server)
+            # the capture started as the window closed; the traffic's tail
+            # has outlasted it
+            timings["capture_wait"] = wait_capture(
+                server, time.monotonic(), CAPTURE_WAIT_S[1])
         elif args.trace == 2:
             timings["traced_phase"] = traced_phase(
                 server, plan, args.seed + 1, profile_dir)
         timings["idle_wait_s"] = wait_idle(server)
         served_after = correctness.run_probes(server, prompts)
         if args.trace == 2:
-            wait_capture(server, timings["traced_phase"])
+            cost = timings["traced_phase"]
+            waited = wait_capture(
+                server, cost.pop("capture_ended_at"), CAPTURE_WAIT_S[2])
+            waited["telemetry_answered_s"] = max(
+                waited["telemetry_answered_s"], cost["telemetry_answered_s"])
+            cost.update(waited)
         state = server.state()
         device = device_report(state)
+        t0 = time.monotonic()
         code = server.stop()
+        timings["server_stop_s"] = time.monotonic() - t0
         log(f"server stopped with code {code}")
         trace = None
         if args.trace:
+            t0 = time.monotonic()
             trace = reduce_trace(
                 profile_dir, os.path.join(profile_dir, "reduced.json"))
+            timings["reduce_s"] = time.monotonic() - t0
             if args.trace == 2:  # reduced: the trace itself is not kept
                 shutil.rmtree(profile_dir, ignore_errors=True)
         run = build_run(plan, side, args.seconds, state, trace, timings,

@@ -280,7 +280,7 @@ EXPECTED = {
     "dispatch.step_ms": 430.0,  # 21.5 s / 50
     "dispatch.compiles_in_window": 0.0,
     # 2 x 4.02e9 x 11000 tokens / 30 s over 197e12
-    "model.flops_util": 100.0 * 2 * 4.0225e9 * 11000 / 30.0 / 197e12,
+    "model.mfu": 100.0 * 2 * 4.0225e9 * 11000 / 30.0 / 197e12,
     "sampler.sort_share": 37.5,
     "kernel.attention_share": 12.5,
     "attention.xla_gather_share": 25.0,  # (0.4 + 0.3 + 0.1) / 3.2

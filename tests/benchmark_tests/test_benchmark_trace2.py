@@ -8,7 +8,7 @@ import os
 
 import pytest
 from bench_paths import BENCH  # noqa: F401
-from standin import StandIn, patch_harness, small_plan
+from standin import StandIn, StandInServer, patch_harness, small_plan
 
 import run as bench_run
 from kbench import loadgen, manifest, server
@@ -54,6 +54,9 @@ def test_trace_2_prints_both_kinds_of_metric_and_none_missing(
     cost = line["detail"]["timings"]["traced_phase"]
     assert cost["first_start_stop_s"] < cost["ramp_s"]  # inside the ramp
     assert cost["telemetry_answered_s"] < 1.0 and cost["stop_s"] < 1.0
+    # the margin every traced line on record shows: the wait's limit
+    assert cost["stop_limit_s"] > bench_run.CAPTURE_WAIT_S[2]
+    assert "capture_ended_at" not in cost
     assert cost["period_under_capture_ms"] == pytest.approx(50.0, rel=0.02)
 
 
@@ -65,6 +68,8 @@ def test_trace_0_and_1_print_what_they_printed(monkeypatch, tmp_path, capsys, ce
     assert "breakdown" not in line and standin.profile_bodies == []
     line, standin = measure(monkeypatch, tmp_path, capsys, cell, 1)
     assert set(line["metrics"]) == {m["name"] for m in resolved.per_layer}
+    waited = line["detail"]["timings"]["capture_wait"]
+    assert waited["stop_s"] < 1.0 < bench_run.CAPTURE_WAIT_S[1] < waited["stop_limit_s"]
     assert standin.profile_bodies == [{
         "seconds": 0.3,
         "dir": os.path.join(str(tmp_path), "profiles", cell + ".run")}]
@@ -157,3 +162,90 @@ def test_trace_2_drives_the_trace_0_schedule_and_then_another(
     assert window == plain
     assert {r[4] for r in traced[0]}.isdisjoint(r[4] for r in plain[0])
     assert sorted({r[2] for r in traced[0]})[0] >= 1  # the same mix's lengths
+
+
+class Clock:
+    """In the place of `time` in run.py: a sleep moves it, nothing waits."""
+
+    def __init__(self, now: float = 5000.0):
+        self.now = now
+
+    def monotonic(self) -> float:
+        return self.now
+
+    def sleep(self, seconds: float) -> None:
+        self.now += seconds
+
+
+class WritingStandIn(StandIn):
+    """A stand-in that reports its capture `active` until the injected
+    clock reaches `written_at`."""
+
+    def __init__(self, clock: Clock, written_at: float):
+        self.clock, self.written_at = clock, written_at
+        super().__init__()
+
+    capturing = property(lambda self: self.clock.now < self.written_at,
+                         lambda self, value: None)
+
+
+NEVER = float("inf")
+#: (--trace, s from the launch to the capture's end, s the write takes)
+CAPTURE_WAITS = {
+    # eval-sat's write on a slow day; the 200 s this wait had gave up on it
+    "trace_2_written_after_260_s": (2, 300.0, 260.0),
+    "trace_2_never_written": (2, 300.0, NEVER),
+    # a run that is already late keeps the wait it had before PR 51
+    "trace_2_late_run_written_after_199_s": (2, 900.0, 199.0),
+    "trace_2_very_late_run_never_written": (2, 1150.0, NEVER),
+    "trace_1_written_after_260_s": (1, 300.0, 260.0),
+    "trace_1_never_written": (1, 300.0, NEVER),
+    "trace_1_very_late_run_written_after_119_s": (1, 1150.0, 119.0),
+}
+
+
+@pytest.mark.parametrize("case", CAPTURE_WAITS)
+def test_the_wait_for_a_capture_follows_the_runs_clock(monkeypatch, case):
+    """`wait_capture` against the stand-in server and an injected clock: it
+    waits for as long as the run has left, less the reserve, and never less
+    than the wait of its mode had; what it gives up with says how long it
+    waited, how far into the run, and that the capture was still active."""
+    trace, launched_ago, write_s = CAPTURE_WAITS[case]
+    clock = Clock()
+    ended_at = clock.now
+    monkeypatch.setattr(bench_run, "time", clock)
+    monkeypatch.setattr(bench_run, "_T_LAUNCH", ended_at - launched_ago)
+    floor_s = bench_run.CAPTURE_WAIT_S[trace]
+    left_s = bench_run.RUN_LIMIT_S - bench_run.STOP_RESERVE_S - launched_ago
+    limit_s = max(floor_s, left_s)
+    with WritingStandIn(clock, ended_at + write_s) as standin:
+        server_ = StandInServer(standin)
+        if write_s < limit_s:
+            waited = bench_run.wait_capture(server_, ended_at, floor_s)
+            assert write_s <= waited["stop_s"] < write_s + 1.0
+            assert waited["stop_limit_s"] == pytest.approx(limit_s)
+            assert 0.0 <= waited["telemetry_answered_s"] < 1.0
+            return
+        with pytest.raises(server.ServerFailure) as failure:
+            bench_run.wait_capture(server_, ended_at, floor_s)
+    gave_up_after = clock.now - ended_at
+    assert limit_s <= gave_up_after < limit_s + 1.0
+    if left_s > floor_s:  # inside the run's limit, the reserve kept
+        assert launched_ago + gave_up_after < (
+            bench_run.RUN_LIMIT_S - bench_run.STOP_RESERVE_S + 1.0)
+    message = str(failure.value)
+    assert f"{gave_up_after:.0f} s after the capture's end" in message
+    assert f"{launched_ago + gave_up_after:.0f} s into a run" in message
+    assert "`active`" in message and f"limit was {limit_s:.0f} s" in message
+
+
+def test_a_server_that_died_under_the_write_is_told_from_a_slow_write(monkeypatch):
+    clock = Clock()
+    monkeypatch.setattr(bench_run, "time", clock)
+    monkeypatch.setattr(bench_run, "_T_LAUNCH", clock.now - 300.0)
+    with StandIn() as standin:
+        gone = StandInServer(standin)
+    with pytest.raises(server.ServerFailure) as failure:
+        bench_run.wait_capture(gone, clock.now, 200.0)
+    assert "stopped answering" in str(failure.value)
+    assert "`active`" not in str(failure.value)
