@@ -52,31 +52,63 @@ def causal_conv_step(x, tail, w, b):
     return y, window[:, 1:]
 
 
+def _conv_rows(window, w32, b32):
+    """window [..., n + K - 1, D] -> float32 [..., n, D]: row i is the
+    convolution's output for the window's row K - 1 + i, the taps as static
+    slices, newest first, the bias last."""
+    K = w32.shape[0]
+    n = window.shape[-2] - (K - 1)
+    y = window[..., K - 1:, :].astype(jnp.float32) * w32[K - 1]
+    for k in range(1, K):
+        y = y + (window[..., K - 1 - k:K - 1 - k + n, :].astype(jnp.float32)
+                 * w32[K - 1 - k])
+    return y + b32
+
+
 def causal_conv_ragged(x, tail, w, b, token_seq, token_off, q_start, q_len,
                        fresh):
     """The same convolution over the packed buffer.  x [T, D]; tail
     [B, K-1, D]; token_seq [T] lane per token (-1 = padding); token_off [T]
     the token's offset inside its lane's slice; q_start, q_len [B]; fresh
     [B] bool: the slice starts at position 0, so its tail is zero.
-    Returns (y [T, D] float32, new tail [B, K-1, D])."""
-    T = x.shape[0]
+    Returns (y [T, D] float32, new tail [B, K-1, D]).
+
+    A slice is contiguous, so tap k of row t is row t - k of the buffer
+    wherever `token_off[t] >= k`: every row is computed from STATIC shifts
+    of the buffer (K - 1 zero rows in front of it and K slices: one
+    elementwise pass that reads x once and writes y once, no gather along
+    the token axis).  That is wrong only where a tap reaches before its
+    slice, in a slice's first K - 1 rows, and those are computed again per
+    LANE, from the stored tail followed by the slice's first K - 1 rows,
+    and put over what the shifts gave there: lanes x (K - 1) rows, not T.
+    Rows 0 .. k - 1 of the buffer need no guard: a slice that holds row t
+    starts at or before it, so `token_off[t] <= t < k` and the row is one of
+    those; the same holds for what a shift brings in across a slice's
+    start.  Both passes add the same terms in the same order, so a row's
+    value does not depend on which of them made it.  `token_seq` and
+    `token_off` say nothing that `q_start` and `q_len` do not."""
+    del token_seq, token_off
+    T, D = x.shape
     K = w.shape[0]
-    lane = jnp.maximum(token_seq, 0)
+    B = q_start.shape[0]
     tail = jnp.where(fresh[:, None, None], jnp.zeros_like(tail), tail)
-    x32 = x.astype(jnp.float32)
     w32 = w.astype(jnp.float32)
-    t = jnp.arange(T, dtype=jnp.int32)
-    y = x32 * w32[K - 1]
-    for k in range(1, K):
-        in_buffer = x32[jnp.maximum(t - k, 0)]
-        # offset o < k: the tap lies before the slice, in the stored tail
-        from_tail = tail[lane, jnp.clip(K - 1 + token_off - k, 0, K - 2)]
-        tap = jnp.where((token_off >= k)[:, None], in_buffer,
-                        from_tail.astype(jnp.float32))
-        y = y + tap * w32[K - 1 - k]
-    y = y + b.astype(jnp.float32)
+    b32 = b.astype(jnp.float32)
+    y = _conv_rows(jnp.pad(x, ((K - 1, 0), (0, 0))), w32, b32)
+    # a slice's first K-1 rows, from (stored tail ++ those rows) per lane;
+    # rows past the slice's end go nowhere (indices past T, each its own)
+    j = jnp.arange(K - 1, dtype=jnp.int32)[None, :]
+    head = x[jnp.clip(q_start[:, None] + j, 0, T - 1)]  # [B, K-1, D]
+    opening = _conv_rows(
+        jnp.concatenate([tail.astype(jnp.float32),
+                         head.astype(jnp.float32)], axis=1), w32, b32)
+    rows = jnp.where(
+        j < q_len[:, None], q_start[:, None] + j,
+        T + jnp.arange(B * (K - 1), dtype=jnp.int32).reshape(B, K - 1))
+    y = y.at[rows.reshape(-1)].set(
+        opening.reshape(-1, D), mode="drop", unique_indices=True)
     # new tail: entries q_len .. q_len+K-2 of (old tail ++ the slice)
-    m = q_len[:, None] + jnp.arange(K - 1, dtype=jnp.int32)[None, :]  # [B, K-1]
+    m = q_len[:, None] + j  # [B, K-1]
     from_old = jnp.take_along_axis(
         tail, jnp.clip(m, 0, K - 2)[:, :, None], axis=1)
     from_new = x[jnp.clip(q_start[:, None] + m - (K - 1), 0, T - 1)]

@@ -18,6 +18,12 @@ token), 48 lanes:
   sub-block: us a call against the larger of its bytes and its operations
   at the chip's peaks, and its result against the one-step form run token
   by token over the chunk's first 96 tokens;
+- `causal_conv_ragged`: one layer's packed convolution at `[4096, 24576]`
+  (47 decode lanes and one 3712-token chunk: this model's) and at
+  `[2048, 6144]` (Nemotron-3-Nano's: 47 lanes and a 1664-token chunk): us a
+  call against the bytes it must move (the buffer read once in bf16, the
+  result written once in float32, the tails read and written once).  To
+  put a parent beside a change, run `--only conv` in both trees in one call;
 - `routed_experts`: the counting sort, three grouped matmuls and the way
   back at 48 tokens (a decode step: 384 pairs, an eighth of them on held
   experts) and at 4096 (a packed step), the width stored in 1280 and in
@@ -27,7 +33,11 @@ Run it on the chip (it refuses any other backend unless --cpu, which only
 rehearses the control flow at a small size).  A call runs `n` times inside
 ONE jitted loop, its result feeding the next call, and the time a call is
 the slope between two `n` (scripts/decode_attention_crossover.py has the
-reasoning).  Results go to stdout and to chiprun_out/kda_kernels.json.
+reasoning); the convolution's calls are launched one by one instead, each
+taking the tail the last one left (inside one loop the compiler would fold
+whatever reads the result into the pass that makes it, and the result
+would never be written).  Results go to stdout and to
+chiprun_out/kda_kernels.json.
 """
 
 from __future__ import annotations
@@ -46,7 +56,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from kserve_tpu.models.moe import MoEConfig, route, routed_experts
-from kserve_tpu.ops import delta
+from kserve_tpu.ops import delta, ssm
 
 HBM, PEAK = 819e9, 197e12
 N_LO, N_HI = 2, 6
@@ -159,6 +169,50 @@ def delta_rows(args) -> list:
     return rows
 
 
+def conv_rows(args) -> list:
+    if args.cpu:
+        lanes, K, cases = 4, 4, ((64, 96, 40),)
+    else:
+        lanes, K, cases = 48, 4, ((4096, 24576, 3712), (2048, 6144, 1664))
+    conv = jax.jit(ssm.causal_conv_ragged)
+    rows = []
+    for T, D, chunk_tokens in cases:
+        x, tail, w = (
+            jax.random.normal(key, shape, jnp.float32).astype(jnp.bfloat16)
+            for key, shape in zip(
+                jax.random.split(jax.random.PRNGKey(T), 3),
+                ((T, D), (lanes, K - 1, D), (K, D))))
+        q_start, q_len, fresh = slices(T, lanes, chunk_tokens)
+        token_seq = np.full(T, -1, np.int32)
+        token_off = np.zeros(T, np.int32)
+        for lane, at in enumerate(np.asarray(q_start)):
+            n = int(q_len[lane])
+            token_seq[at:at + n], token_off[at:at + n] = lane, np.arange(n)
+        rest = (w, jnp.zeros((), jnp.float32), jnp.asarray(token_seq),
+                jnp.asarray(token_off), q_start, q_len, fresh)
+
+        def timed(n):
+            out = []
+            for _ in range(3):
+                t = tail
+                t0 = time.perf_counter()
+                for _ in range(n):
+                    y, t = conv(x, t, *rest)
+                jax.block_until_ready((y, t))
+                out.append(time.perf_counter() - t0)
+            return statistics.median(out)
+
+        timed(2)
+        s = (timed(12) - timed(4)) / 8
+        bytes_ = T * D * (2 + 4) + 2 * lanes * (K - 1) * D * 2
+        rows.append({"form": "causal_conv_ragged", "T": T, "D": D,
+                     "chunk_tokens": chunk_tokens, "us_per_call": 1e6 * s,
+                     "least_us": 1e6 * bytes_ / HBM,
+                     "share_pct": 100 * bytes_ / HBM / s})
+        print(json.dumps(rows[-1]), flush=True)
+    return rows
+
+
 def expert_rows(args) -> list:
     if args.cpu:
         hidden, width, stored, scored, held, k = 64, 48, (48, 128), 16, 2, 2
@@ -220,15 +274,17 @@ def expert_rows(args) -> list:
 def main() -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--cpu", action="store_true")
-    ap.add_argument("--only", choices=("delta", "experts"))
+    ap.add_argument("--only", choices=("delta", "conv", "experts"))
     args = ap.parse_args()
     if jax.default_backend() != "tpu" and not args.cpu:
         print("this measures the chip", file=sys.stderr)
         return 1
     rows = []
-    if args.only != "experts":
+    if args.only in (None, "delta"):
         rows += delta_rows(args)
-    if args.only != "delta":
+    if args.only in (None, "conv"):
+        rows += conv_rows(args)
+    if args.only in (None, "experts"):
         rows += expert_rows(args)
     os.makedirs("chiprun_out", exist_ok=True)
     with open("chiprun_out/kda_kernels.json", "w") as f:

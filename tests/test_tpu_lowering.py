@@ -1001,3 +1001,32 @@ class TestSolarOpen2:
             _abstract((lanes,), jnp.bool_)).compile()
         # the state read and written once: no copy of it beside the result
         assert step.memory_analysis().temp_size_in_bytes < lanes * H * d * d * 4
+
+    def test_the_packed_convolution_is_one_pass_over_the_buffer(self):
+        """`[4096, 24576]` bf16, 4 taps, 48 lanes (PR 52): the taps are
+        static shifts inside ONE fusion that reads the bf16 buffer and
+        writes the float32 result, the slices' first rows are put into that
+        result in place, and nothing else has the buffer's rows: no float32
+        copy of the buffer (403 MB) and no gathered tap (403 MB each; the
+        parent held six) among the temporaries."""
+        if _tpu_sharding() is None:
+            pytest.skip("no compile-only TPU topology here")
+        import re
+
+        from kserve_tpu.ops import ssm
+
+        T, D, lanes, K = 4096, 24576, 48, 4
+        bf16 = jnp.bfloat16
+        compiled = jax.jit(ssm.causal_conv_ragged).lower(
+            _abstract((T, D), bf16), _abstract((lanes, K - 1, D), bf16),
+            _abstract((K, D), bf16), _abstract((), jnp.float32), _i32(T),
+            _i32(T), _i32(lanes), _i32(lanes),
+            _abstract((lanes,), jnp.bool_)).compile()
+        entry = compiled.as_text().split("ENTRY")[1]
+        passes = [line for line in entry.splitlines()
+                  if re.search(rf"= \w+\[{T},{D}\]\S* (fusion|convert|copy|"
+                               r"gather|scatter|pad|slice)\(", line)]
+        # the shifted sum, and the scatter that aliases its result
+        assert len(passes) == 2, passes
+        assert "kind=kLoop" in passes[0] and "scatter" in passes[1], passes
+        assert compiled.memory_analysis().temp_size_in_bytes < 32e6
