@@ -16,12 +16,11 @@ Two forms, the same mathematics (as ops/ssm.py's `ssd_*`):
   two contractions over the key channel;
 - `kda_ragged`: the packed `[T]` buffer.  A lane's slice is cut into PIECES
   of `KDA_CHUNK` tokens from the slice's own start, wherever it lies in the
-  buffer; the pieces of all lanes are run one after the other, each reading
-  its lane's state and leaving it the state after its last token, so a
-  slice's second piece starts from its first's, a slice that continues a
-  request from the state the lane stored, and nothing of shape
-  `[T, heads, d, d]` exists.  Inside a piece the WY / UT form of the delta
-  rule: with `G` the running sum of `g` inside the piece,
+  buffer.  A piece reads its lane's state and leaves it the state after its
+  last token, so a slice's second piece starts from its first's, a slice
+  that continues a request from the state the lane stored, and nothing of
+  shape `[T, heads, d, d]` exists.  Inside a piece the WY / UT form of the
+  delta rule: with `G` the running sum of `g` inside the piece,
 
       A[i, j] = beta_i sum_c k_i[c] k_j[c] exp(G_i[c] - G_j[c])     (j < i)
       (I + A) [U | W] = [beta v | beta exp(G) k]      (one unit lower system,
@@ -29,6 +28,17 @@ Two forms, the same mathematics (as ops/ssm.py's `ssd_*`):
       u = U - W S_in                                  (the pieces' "new values")
       o = (exp(G) q) S_in + B u,    B[i, j] = sum_c q_i k_j exp(G_i - G_j)  (j <= i)
       S_out = Diag(exp(G_n)) S_in + (k exp(G_n - G))^T u
+
+  Only the last three lines read the state: the pieces go one after the
+  other in one scan, live pieces first, and a dead one is skipped.  A piece
+  cuts its window out of each buffer by one gather of rows (`first +
+  arange`, clamped to the buffer; rows past its count are masked) and
+  writes its rows to the output by one scatter that drops the others; a
+  lane without a slice is written by nothing.  Grouping the pieces to run
+  what does not read the state for several at once LOSES on the v5e at 64
+  heads of 128: a piece's arrays (2 MB each, 33.5 MB of differences) stay
+  in the chip's fast memory and a group's do not (docs/kernels.md, "Delta
+  rule": PR 53's rows).
 
   A lane that adds ONE token (a decode lane of the `mixed` program's packed
   step) takes `kda_step` instead of a piece of its own.
@@ -104,31 +114,44 @@ def _decayed_scores(x, k, G, sub: int):
 def _unit_lower_inverse(A):
     """`(I + A)^-1` of strictly lower triangular A [H, Q, Q], by halves:
     `[[L11, 0], [L21, L22]]^-1 = [[L11^-1, 0], [-L22^-1 L21 L11^-1,
-    L22^-1]]`, from blocks of one row up, every level two batched matrix
-    products over all its blocks.  No power of A is formed (a Neumann
-    series' terms pass float32 where keys repeat and beta nears 2), and no
-    library call: `lax.linalg.triangular_solve` is a LAPACK custom call on
-    the CPU, and a `mixed` program that holds one does not survive the AOT
-    cache there (it crashes when loaded again)."""
+    L22^-1]]`, from blocks of one row up, every level two products of all
+    its blocks.  No power of A is formed (a Neumann series' terms pass
+    float32 where keys repeat and beta nears 2), and no library call:
+    `lax.linalg.triangular_solve` is a LAPACK custom call on the CPU, and a
+    `mixed` program that holds one does not survive the AOT cache there (it
+    crashes when loaded again).
+
+    The blocks' rows and columns stand IN FRONT of the heads and the
+    products are sums of elementwise products over them, not matrix
+    products: a level's blocks are `[s, s]` with s from 1 up, and as the
+    last two axes of an array the chip pads each to a tile of 8 x 128 (as
+    batched matrix products the levels took 5.7 of a 4096-token call's 19.2
+    ms, so about 1: PR 53's rows in docs/kernels.md); with the heads last
+    every lane multiplies."""
     H, Q, _ = A.shape
     P = 1 << max(Q - 1, 0).bit_length()  # rows of padding solve to themselves
-    A = jnp.pad(A, ((0, 0), (0, P - Q), (0, P - Q)))
-    inv = jnp.ones((H, P, 1, 1), A.dtype)
+    low = jnp.pad(A, ((0, 0), (0, P - Q), (0, P - Q))).transpose(1, 2, 0)
+
+    def times(x, y):  # [nb, s, s, H] each
+        return (x[:, :, :, None] * y[:, None]).sum(axis=2)
+
+    inv = jnp.ones((P, 1, 1, H), A.dtype)
     s = 1
     while s < P:
         nb = P // (2 * s)
-        at = jnp.arange(nb)
         # the blocks under the diagonal of this level's [2 s, 2 s] blocks
-        below = A.reshape(H, nb, 2 * s, nb, 2 * s)[:, at, s:, at, :s]  # [nb, H, s, s]
-        first, second = inv[:, 0::2], inv[:, 1::2]
-        off = -jnp.einsum("bhij,hbjk->hbik",
-                          jnp.einsum("hbij,bhjk->bhik", second, below,
-                                     precision=_HP), first, precision=_HP)
+        same = jnp.eye(nb, dtype=bool).reshape(nb, 1, nb, 1, 1)
+        below = jnp.where(
+            same, low.reshape(nb, 2, s, nb, 2, s, H)[:, 1, :, :, 0],
+            0.0).sum(axis=2)  # [nb, s, s, H]
+        halves = inv.reshape(nb, 2, s, s, H)
+        first, second = halves[:, 0], halves[:, 1]
+        off = -times(times(second, below), first)
         inv = jnp.concatenate([
-            jnp.concatenate([first, jnp.zeros_like(first)], axis=-1),
-            jnp.concatenate([off, second], axis=-1)], axis=-2)
+            jnp.concatenate([first, jnp.zeros_like(first)], axis=2),
+            jnp.concatenate([off, second], axis=2)], axis=1)
         s *= 2
-    return inv[:, 0, :Q, :Q]
+    return inv[0, :Q, :Q].transpose(2, 0, 1)
 
 
 def _piece(q, k, v, g, beta, n, S, sub: int):
@@ -168,13 +191,14 @@ def kda_ragged(q, k, v, g, beta, state, q_start, q_len, fresh,
     B = state.shape[0]
     Q = min(chunk, T)
     sub = math.gcd(Q, sub)
-    states = jnp.where(fresh[:, None, None, None], 0.0, state)
+    # a lane without a slice is never written below: it keeps its state
+    states = jnp.where((fresh & (q_len > 0))[:, None, None, None], 0.0, state)
     # lanes that add one token: the one-step form, all of them at once
     single = q_len == 1
     at = jnp.clip(q_start, 0, T - 1)
     o_single, states = kda_step(
         q[at], k[at], v[at], g[at], beta[at], states, single)
-    # the pieces of the longer slices, in lane order
+    # the pieces of the longer slices, in lane order: the live ones first
     pieces = jnp.where(q_len > 1, -(-q_len // Q), 0)
     ends = jnp.cumsum(pieces)
     n_pieces = T // Q + min(B, T // 2)  # full pieces + one partial a lane
@@ -191,28 +215,16 @@ def kda_ragged(q, k, v, g, beta, state, q_start, q_len, fresh,
 
         def one(carry):
             states, out = carry
-            # the window holds the piece's rows from `shift` on: it is not
-            # let run past the buffer's end
-            start = jnp.minimum(t0, T - Q)
-            shift = t0 - start
-
-            def window(x):
-                rows = jax.lax.dynamic_slice_in_dim(x, start, Q, axis=0)
-                return jnp.roll(rows, -shift, axis=0)
-
-            o, s = _piece(window(q), window(k), window(v), window(g),
-                          window(beta), n, states[b], sub)
-            # rows around the piece's n tokens belong to others: kept
-            row = jnp.arange(Q) - shift
-            o = jnp.where(((row >= 0) & (row < n))[:, None, None],
-                          jnp.roll(o, shift, axis=0),
-                          jax.lax.dynamic_slice_in_dim(out, start, Q, axis=0))
-            return (states.at[b].set(s),
-                    jax.lax.dynamic_update_slice_in_dim(out, o, start, axis=0))
+            rows = t0 + jnp.arange(Q)
+            window = jnp.minimum(rows, T - 1)  # rows past `n` are masked
+            o, s = _piece(q[window], k[window], v[window], g[window],
+                          beta[window], n, states[b], sub)
+            to = jnp.where(jnp.arange(Q) < n, rows, T)  # the others: dropped
+            return states.at[b].set(s), out.at[to].set(o, mode="drop")
 
         return jax.lax.cond(n > 0, one, lambda c: c, carry), None
 
     (states, out), _ = jax.lax.scan(
         run, (states, jnp.zeros((T, H, d), jnp.float32)), (lane, first, count))
     out = out.at[jnp.where(single, q_start, T)].set(o_single, mode="drop")
-    return out, jnp.where((q_len > 0)[:, None, None, None], states, state)
+    return out, states

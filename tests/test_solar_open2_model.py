@@ -383,6 +383,23 @@ DELTA_CASES = {
         32, 3, [(2, 1, 5), (0, 6, 7), (1, 13, 19)], (), 4, 4),
     "padding at both ends, a slice that ends at the buffer's end": (
         32, 3, [(1, 8, 9), (0, 29, 3)], (), 8, 2),
+    # what gathered windows, scattered rows and the piece table can get wrong
+    "seven live pieces of two lanes, the last one partial, a lane between": (
+        64, 4, [(0, 2, 30), (2, 34, 20)], (2,), 8, 4),
+    "three lanes' pieces back to back, each slice's last piece partial": (
+        64, 4, [(1, 0, 20), (3, 21, 24), (0, 50, 9)], (0,), 8, 4),
+    "every piece full, the buffer full, as many dead pieces as live ones": (
+        32, 3, [(0, 0, 16), (2, 16, 16)], (0,), 8, 8),
+    "short slices of several lanes, new and continued, and a one-token lane": (
+        128, 6, [(0, 0, 2), (4, 2, 63), (1, 70, 5), (5, 80, 17), (3, 100, 1)],
+        (4, 5), 64, 16),
+    "unaligned slices, lanes out of buffer order, one a new request": (
+        32, 3, [(2, 1, 5), (0, 6, 7), (1, 13, 19)], (1,), 8, 4),
+    "a last piece whose window would pass the buffer's last row": (
+        64, 3, [(0, 3, 2), (1, 41, 23)], (0,), 16, 4),
+    "single-token lanes and padding only: no piece at all": (
+        32, 4, [(0, 0, 1), (2, 9, 1), (3, 31, 1)], (2,), 8, 4),
+    "an empty buffer: every lane keeps its state": (32, 3, [], (), 8, 4),
 }
 
 
@@ -392,7 +409,7 @@ def test_the_packed_form_is_the_one_step_form_token_by_token(case):
     state a piece) against `kda_step` run token by token: slices of unequal
     length in one buffer, slices that open a request (zero state) or
     continue one (the lane's stored state), padding between and around; a
-    lane without a slice keeps its state."""
+    lane without a slice keeps its state and a row of no slice reads zero."""
     T, B, slices, fresh, chunk, sub = DELTA_CASES[case]
     a, q_start, q_len, is_fresh = _delta_case(T, B, slices, fresh)
     o, new = jax.jit(delta.kda_ragged, static_argnames=("chunk", "sub"))(
@@ -402,6 +419,7 @@ def test_the_packed_form_is_the_one_step_form_token_by_token(case):
     want, want_state = _token_by_token(a, slices, is_fresh)
     for t, row in want.items():
         np.testing.assert_allclose(np.asarray(o[t]), row, rtol=1e-4, atol=1e-5)
+    assert not np.asarray(o)[sorted(set(range(T)) - set(want))].any()
     np.testing.assert_allclose(np.asarray(new), want_state, rtol=1e-4, atol=1e-5)
     untouched = sorted(set(range(B)) - {lane for lane, _, _ in slices})
     assert np.array_equal(np.asarray(new)[untouched],
@@ -435,6 +453,37 @@ def test_a_channel_that_decays_by_e5_a_token_stays_finite_and_exact(sub):
     # the factored form this guards against: exp(-G) alone overflows
     with np.errstate(over="ignore"):
         assert np.isinf(np.exp(-np.cumsum(g[:64], axis=0), dtype=np.float32)).any()
+
+
+@pytest.mark.parametrize("seed", [0, 1])
+def test_the_packed_form_is_as_near_a_float64_recurrence_as_the_one_step_form(seed):
+    """At the published 64 heads of 128, a 200-token slice continued from a
+    stored state (four pieces, the state carried between them) and a new
+    40-token one: the outputs and the states the lanes keep against the
+    recurrence in float64 on the same float32 inputs.  On the CPU the
+    one-step form in float32 reads 5.8e-8 and 4.5e-7 there and the packed
+    form 1.3e-7 and 4.6e-7; with its inverse as matrix products (before PR
+    53) it read 1.3e-7 and 4.6e-7 to 6.1e-7: sums in another order, not a
+    loss.  The limits are three times the readings."""
+    H, d, slices = 64, 128, [(1, 3, 200), (0, 210, 40)]
+    a, q_start, q_len, is_fresh = _delta_case(
+        256, 2, slices, fresh=(0,), seed=seed, H=H, d=d, fastest=0.1)
+    o, new = jax.jit(delta.kda_ragged)(
+        a["q"], a["k"], a["v"], a["g"], a["beta"], a["state"],
+        jnp.asarray(q_start), jnp.asarray(q_len), jnp.asarray(is_fresh))
+    o, new = np.asarray(o), np.asarray(new)
+    q, k, v, g, beta, state = (
+        np.asarray(a[name], np.float64)
+        for name in ("q", "k", "v", "g", "beta", "state"))
+    for lane, start, n in slices:
+        S = state[lane] * (0.0 if is_fresh[lane] else 1.0)
+        for t in range(start, start + n):
+            S = np.exp(g[t])[..., None] * S
+            u = beta[t][:, None] * (v[t] - np.einsum("hc,hce->he", k[t], S))
+            S = S + k[t][..., None] * u[:, None, :]
+            want = np.einsum("hc,hce->he", q[t], S)
+            assert np.abs(o[t] - want).max() < 4e-7, t
+        assert np.abs(new[lane] - S).max() < 1.5e-6, lane
 
 
 def test_a_lane_that_is_not_live_keeps_its_state():

@@ -971,14 +971,21 @@ class TestSolarOpen2:
     one piece's arrays at a time."""
 
     def test_both_forms_compile_at_the_published_sizes(self):
-        """64 heads of 128, 48 lanes, a 4096-token buffer: the pieces run
-        inside ONE loop whose body inverts one triangular system by matrix
-        products (no library call: ops/delta._unit_lower_inverse); nothing
-        over [tokens, heads, d, d] (137 GB) and no padded copy of the
-        buffer's five inputs (680 MB): the temporaries are the loop's copy
-        of the lanes' states (201 MB), the output and one piece's arrays."""
+        """64 heads of 128, 48 lanes, a 4096-token buffer: the 112 piece slots
+        run inside ONE loop, and ONE conditional skips a dead one.  The
+        triangular system is inverted by products (no library call:
+        ops/delta._unit_lower_inverse); nothing over [tokens, heads, d, d]
+        (137 GB), no float32 array with the slots or a 58-piece chunk as an
+        axis (index arrays only), none larger than the lanes' states (the
+        piece's block of differences times its two row operands is a
+        product inside a fusion), and no padded copy of the buffer's five
+        inputs (680 MB).  The loop's carry IS the result (the states 201 MB,
+        the output 134 MB: no lane is selected back afterwards, so neither
+        is copied), and the temporaries are one piece's arrays, 5 MB of them
+        outside the chip's fast memory."""
         if _tpu_sharding() is None:
             pytest.skip("no compile-only TPU topology here")
+        import math
         import re
 
         from kserve_tpu.ops import delta
@@ -993,8 +1000,14 @@ class TestSolarOpen2:
         assert "InvertDiagBlocksLowerTriangular" not in text  # no library solve
         assert len(re.findall(r"\bconditional\(", text)) == 1  # a dead piece
         assert not re.findall(r"f32\[\d+,64,128,128,128\]", text)
+        slots = T // delta.KDA_CHUNK + min(lanes, T // 2)
+        shapes = {tuple(int(n) for n in dims.split(","))
+                  for dims in re.findall(r"f32\[([\d,]+)\]", text)}
+        for shape in shapes:
+            assert not (len(shape) > 2 and shape[0] in (slots, 58)), shape
+            assert math.prod(shape) <= lanes * H * d * d, shape
         temp = compiled.memory_analysis().temp_size_in_bytes
-        assert temp < 450e6, temp
+        assert temp < 48e6, temp
         lane = _abstract((lanes, H, d), f32)
         step = jax.jit(delta.kda_step).lower(
             lane, lane, lane, lane, _abstract((lanes, H), f32), state,
