@@ -106,9 +106,11 @@ class StateLayout:
     - `ssm` and `conv`: for every layer that writes `recurrent` one slot
       per lane, sized by the mixer: the scan's float32 state (Mamba-1:
       [d_inner, d_state]; Mamba-2: a matrix a head, [heads, head_dim,
-      d_state]; a Kimi-delta mixer: [heads, head_dim, head_dim]) and the
-      convolution's tail ([d_conv - 1, columns the convolution runs over]:
-      d_inner, x, B and C together, or q, k and v together);
+      d_state]; a Kimi-delta mixer: [heads, head_dim, head_dim]; a gated
+      short convolution: none, `ssm` holds no array and costs 0 bytes) and
+      the convolution's tail ([d_conv - 1, columns the convolution runs
+      over]: d_inner, x, B and C together, q, k and v together, or the
+      hidden columns);
     - `latent`: for every layer that writes `latent_kv` (latent attention,
       models/latent.py) pages of the SAME pool and page table, holding ONE
       row a token and no K/V planes or heads: `latent_width` values (the
@@ -143,7 +145,8 @@ class StateLayout:
     latent_width: int = 0  # values a latent row holds
     expert_layers: int = 0
     expert_sums: int = 2  # int32 sums an expert layer adds to `stats`
-    # a recurrent slot's shapes, by the mixer; () / 0 = Mamba-1's
+    # a recurrent slot's shapes, by the mixer; () / 0 = Mamba-1's; a state
+    # of no elements (models/llama.NO_SCAN_STATE) = the tail is all a lane keeps
     ssm_shape: tuple = ()
     conv_width: int = 0
 
@@ -267,7 +270,8 @@ class StateLayout:
             "paged": fill((self.num_pages,) + page, dtype, len(self.paged_layers)),
             "window": fill((1 + self.lanes * self.ring_width,) + ring, dtype,
                            len(self.window_layers)),
-            "ssm": fill((self.lanes,) + self.ssm_shape, jnp.float32, n),
+            "ssm": fill((self.lanes,) + self.ssm_shape, jnp.float32,
+                        n if math.prod(self.ssm_shape) else 0),
             "conv": fill((self.lanes, max(self.d_conv - 1, 0), self.conv_width),
                          dtype, n),
         }

@@ -29,8 +29,8 @@ def model_kinds(model_config) -> Dict[str, str]:
     writes = {row.writes for row in table}
     family = ("a model with latent-attention layers and routed experts"
               if "latent_kv" in writes else
-              "a model with Mamba-1 / Mamba-2 / delta-rule / window / "
-              "shared-cache layers")
+              "a model with Mamba-1 / Mamba-2 / delta-rule / "
+              "short-convolution / window / shared-cache layers")
     kinds = {
         "looped": model_config.n_passes > 1 and (
             f"a looped model ({model_config.n_passes} passes over shared "

@@ -13,6 +13,8 @@ from typing import Callable, Dict
 import numpy as np
 
 from ..metrics import (
+    ENGINE_CONV_PACKED_TOKENS,
+    ENGINE_CONV_UPDATE_LANE_STEPS,
     ENGINE_KDA_CHUNK_TOKENS,
     ENGINE_KDA_UPDATE_LANE_STEPS,
     ENGINE_KV_CONTEXT_TOKENS,
@@ -43,6 +45,7 @@ from .kvcache import StateLayout, pages_needed
 _FORM_COUNTERS = {
     "mamba2": (ENGINE_SSD_SCAN_TOKENS, ENGINE_SSD_UPDATE_LANE_STEPS),
     "kda": (ENGINE_KDA_CHUNK_TOKENS, ENGINE_KDA_UPDATE_LANE_STEPS),
+    "short_conv": (ENGINE_CONV_PACKED_TOKENS, ENGINE_CONV_UPDATE_LANE_STEPS),
 }
 
 
@@ -109,8 +112,9 @@ class DispatchWork:
         if model_config.has_expert_sums:
             child(ENGINE_MOE_EXPERTS_HELD, of=str(model_config.n_experts)).set(
                 model_config.n_experts_held or model_config.n_experts)
-        # engine_ssd_*_total, engine_kda_*_total: what the recurrent mixers'
-        # two forms are asked (layers of the kind, its two counters)
+        # engine_ssd_*_total, engine_kda_*_total, engine_conv_*_total: what
+        # the recurrent mixers' two forms are asked (layers of the kind, its
+        # two counters)
         self._forms = [
             (sum(row.kind == kind for row in table), child(chunked), child(stepped))
             for kind, (chunked, stepped) in _FORM_COUNTERS.items()]

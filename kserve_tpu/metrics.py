@@ -538,6 +538,19 @@ ENGINE_KDA_UPDATE_LANE_STEPS = Counter(
     "and Kimi-delta layers: lane-steps whose state moved on",
     ["model_name"],
 )
+# Gated short convolutions (models/hybrid.py `short_conv`): likewise.
+ENGINE_CONV_PACKED_TOKENS = Counter(
+    "engine_conv_packed_tokens_total",
+    "tokens the packed step's gated short convolutions took (real tokens "
+    "of the packed buffer x short-convolution layers)",
+    ["model_name"],
+)
+ENGINE_CONV_UPDATE_LANE_STEPS = Counter(
+    "engine_conv_update_lane_steps_total",
+    "live lanes summed over the decode steps' one-token convolutions and "
+    "short-convolution layers: lane-steps whose tail moved on",
+    ["model_name"],
+)
 # Window layers that keep a ring a lane (models/hybrid.py): whether the
 # window BOUND a decode step's attention, counted at launch from the plan.
 ENGINE_WINDOW_LANE_STEPS = Counter(

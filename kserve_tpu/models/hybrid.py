@@ -27,7 +27,12 @@ padded to `[q, 0]` and 2j+1 to `[0, q]`, so plain grouped-query attention
 over rows of twice the width gives `softmax(q_1 k_1^T) [v_1, v_2]` and
 `softmax(q_2 k_2^T) [v_1, v_2]`: the pair's two terms, exactly.  The cache
 holds the same bytes per token, at the width the decode kernel streams
-without a re-layout.
+without a re-layout.  The same rule serves any plain grouped-query row whose
+heads are 64 wide (`LlamaConfig.pairs_kv_heads`: the sixth family's): K/V
+heads 2r and 2r+1 share cache row r, a query head goes into the half where
+ITS K/V head lies (`_pair_queries`) and keeps that half of the output
+(`_own_half`): twice the score and value products, and both kernels and the
+page write where 64-wide rows would take the gather.
 
 Second family: `model_type: glm4_moe_lite`: every layer `h += Mixer(
 RMSNorm(h)); h += FFN(RMSNorm(h))` with the `latent_attention` mixer
@@ -67,6 +72,16 @@ channel, a gated RMSNorm a head behind it; ops/delta.kda_*), or
 `gqa_attention` with no positions whose output a sigmoid gate multiplies
 (`config.attention_gate`); every row's feed-forward is routed experts, of
 which this chip may hold a share, beside a shared one.
+
+Sixth family: `model_type: lfm2_moe` (LFM2-24B-A2B): the second family's
+layer of two residuals with the mixer by the row: `short_conv`, a gated
+short convolution (`[B | C | x] = u W_in`; a depthwise causal convolution
+of a few taps over `B * x` with no bias and no activation behind it; `(C *
+conv) W_out`) whose only state is the convolution's tail in `state["conv"]`
+(`state["ssm"]` holds nothing), or `gqa_attention` with an RMSNorm a head on
+q and k before the rotary, heads of 64 on cache rows of 128 as above; the
+first `first_k_dense` feed-forwards dense, the others routed experts with no
+shared one; the head is the embedding, transposed.
 
 Layers behind the last layer that writes state only feed the logits, so the
 packed forward runs them (and the last writer's own attention output) on
@@ -114,6 +129,7 @@ def layer_param_shapes(config, spec) -> Dict[str, tuple]:
     Mamba-1 defaults), "A_log_heads" (Mamba-2: one A a head, spread over
     [1, 16]), "A_log_kda" / "dt_bias_kda" (a Kimi-delta mixer's decays:
     `make`), "zeros" (a router's choice-only bias); float32 for those;
+    "taps" (a short convolution's: 1 / taps + N(0, scale): `make`);
     "routed_out" (N(0, scale x ROUTED_OUT_GAIN): a routed expert's
     down-projection).  A row has the norm of each sublayer it has."""
     h, f = config.hidden_size, config.intermediate_size
@@ -142,6 +158,13 @@ def layer_param_shapes(config, spec) -> Dict[str, tuple]:
             "wv": ((h, nkv * hd), "normal"), "wo": ((nq * hd, h), "normal")})
         if config.attention_gate and spec.kind == "gqa_attention":
             shapes["wg"] = ((h, nq * hd), "normal")
+        if config.qk_norm:
+            shapes.update({"q_norm": ((hd,), "ones"), "k_norm": ((hd,), "ones")})
+    elif spec.kind == "short_conv":
+        shapes.update({
+            "in_proj": ((h, 3 * h), "normal"),
+            "conv_w": ((config.conv_taps, h), "taps"),
+            "out_proj": ((h, h), "normal")})
     elif spec.kind == "kda":
         heads, d, rank = config.kda_n_heads, config.kda_head_dim, config.kda_rank
         shapes.update({
@@ -256,6 +279,12 @@ def init_params(config, rng, scale: float = 0.02, weight_quant: str = "none",
         if init == "A_log_kda":
             return jnp.log(jax.random.uniform(
                 key, shape, jnp.float32, 0.5, 1.5))
+        if init == "taps":
+            # a short convolution's taps around 1 / taps each: at N(0, scale)
+            # alone the rows a lane carries (the tail) would reach the
+            # logits at a size bf16 rounding hides, and a wrong tail with it
+            return (1.0 / shape[0] + jax.random.normal(key, shape, jnp.float32)
+                    * scale).astype(dtype)
         return (jax.random.normal(key, shape, jnp.float32) * scale).astype(dtype)
 
     def make_layer(spec, key):
@@ -395,24 +424,69 @@ def _rope(x, pos, i, config):
                       config.rope_scaling)[0]
 
 
+def _head_norm(x, layer, name, config):
+    """x [N, heads, head_dim] under the row's RMSNorm a head (`config.qk_norm`:
+    "q_norm" / "k_norm"), where it has one."""
+    if name not in layer:
+        return x
+    with jax.named_scope("qk_norm"):
+        return rms_norm(x, layer[name], config.rms_norm_eps)
+
+
+def _pair_queries(q, config):
+    """q [N, heads, head_dim] of a plain grouped-query row whose K/V heads lie
+    two a cache row (`config.pairs_kv_heads`) -> [N, heads, 2 x head_dim]:
+    `[q, 0]` where the head's K/V head is the row's first, `[0, q]` where it
+    is the second.  The kernels scale scores by the ROW's width^-1/2, so the
+    queries carry the sqrt(2) that is short of head_dim^-1/2, multiplied in
+    float32 and rounded with the cast."""
+    N, group = q.shape[0], config.n_heads // config.n_kv_heads
+    half = jnp.eye(2, dtype=jnp.float32) * math.sqrt(2.0)
+    padded = q.astype(jnp.float32).reshape(
+        N, config.cache_kv_heads, 2, group, 1, config.head_dim
+    ) * half[:, None, :, None]
+    return padded.astype(q.dtype).reshape(
+        N, config.n_heads, config.cache_head_dim)
+
+
+def _own_half(attn, config):
+    """attn [N, heads, 2 x head_dim] over paired cache rows -> [N, heads,
+    head_dim]: of `softmax(q k^T) [v_even, v_odd]` the half that is the
+    head's own V head."""
+    N, group = attn.shape[0], config.n_heads // config.n_kv_heads
+    pair = attn.reshape(N, config.cache_kv_heads, 2, group, 2, config.head_dim)
+    return jnp.stack([pair[:, :, 0, :, 0], pair[:, :, 1, :, 1]], axis=2
+                     ).reshape(N, config.n_heads, config.head_dim)
+
+
 def _gqa_queries(layer, u, config, pos, i):
-    """A plain grouped-query row's queries: [N, h] -> [N, heads, head_dim],
-    turned by position where row i is."""
+    """A plain grouped-query row's queries: [N, h] -> [N, heads, the cache
+    row's width], normed a head where the row is, turned by position where
+    row i is."""
     q = dense(u, layer["wq"]).reshape(
         u.shape[0], config.n_heads, config.head_dim)
-    return _rope(q, pos, i, config)
+    q = _rope(_head_norm(q, layer, "q_norm", config), pos, i, config)
+    return _pair_queries(q, config) if config.pairs_kv_heads else q
 
 
 def _gqa_keys_values(layer, u, config, pos, i):
-    """A plain grouped-query row's K (turned as its queries are) and V."""
-    k, v = _keys_values(layer, u, config)
-    return _rope(k, pos, i, config), v
+    """A plain grouped-query row's K (normed and turned as its queries are)
+    and V, as the cache stores them."""
+    heads = (u.shape[0], config.n_kv_heads, config.head_dim)
+    rows = (u.shape[0], config.cache_kv_heads, config.cache_head_dim)
+    k, v = dense(u, layer["wk"]), dense(u, layer["wv"])
+    k, v = k.reshape(heads), v.reshape(rows)
+    k = _rope(_head_norm(k, layer, "k_norm", config), pos, i, config)
+    return k.reshape(rows), v
 
 
-def _gqa_out(layer, attn, u=None):
-    """A `gqa_attention` row's output: attn [N, heads, head_dim] -> [N, h];
-    where the row has a gate (`config.attention_gate`: "wg"), times
-    sigmoid(u W_g) first, u [N, h] what the row's projections read."""
+def _gqa_out(layer, attn, config, u=None):
+    """A `gqa_attention` row's output: attn [N, heads, the cache row's
+    width] -> [N, h]; where the row has a gate (`config.attention_gate`:
+    "wg"), times sigmoid(u W_g) first, u [N, h] what the row's projections
+    read."""
+    if config.pairs_kv_heads:
+        attn = _own_half(attn, config)
     attn = attn.reshape(attn.shape[0], -1)
     if "wg" in layer:
         with jax.named_scope("attention_gate"):
@@ -526,6 +600,24 @@ def _kda_out(layer, o, gate, config):
     return dense(gated.astype(gate.dtype), layer["wo"])
 
 
+def _short_conv(layer, u, tail, conv, *packing):
+    """A gated short convolution over u [N, h] from the lanes' `tail`, around
+    `conv`, the form's convolution (ops/ssm.causal_conv_step, or
+    causal_conv_ragged with the buffer's `packing`): -> (the mixer's output,
+    the new tail).  The products in float32; what the convolution reads,
+    and with it the tail, in the model's dtype."""
+    f32 = jnp.float32
+    with jax.named_scope("short_conv_in"):
+        b, c, x = jnp.split(dense(u, layer["in_proj"]), 3, axis=-1)
+    with jax.named_scope("short_conv_taps"):
+        z = (b.astype(f32) * x.astype(f32)).astype(u.dtype)
+        conv_out, tail = conv(
+            z, tail, layer["conv_w"], jnp.zeros((), f32), *packing)
+        y = (c.astype(f32) * conv_out).astype(u.dtype)
+    with jax.named_scope("short_conv_out"):
+        return dense(y, layer["out_proj"]), tail
+
+
 def _gmu(layer, u, m):
     with jax.named_scope("gmu"):
         gate = jax.nn.silu(dense(u, layer["gmu_in"]).astype(jnp.float32))
@@ -605,6 +697,13 @@ def _rows_layer(layer, spec, i, x, pos, live, state, slots, page_table,
             state["conv"][j] = jnp.where(
                 live[:, None, None], tail, state["conv"][j])
             mixed = _kda_out(layer, o, gate, config)
+    elif spec.kind == "short_conv":
+        with jax.named_scope("short_conv"):
+            j = slots[i]
+            mixed, tail = _short_conv(
+                layer, u, state["conv"][j], ssm.causal_conv_step)
+            state["conv"][j] = jnp.where(
+                live[:, None, None], tail, state["conv"][j])
     elif spec.kind == "gqa_attention":
         with jax.named_scope("gqa_attention"):
             j = slots[i]
@@ -615,7 +714,7 @@ def _rows_layer(layer, spec, i, x, pos, live, state, slots, page_table,
             attn = paged_attention(
                 _gqa_queries(layer, u, config, pos, i), state["paged"][j],
                 page_table, seq_lens, use_pallas=use_pallas)
-            mixed = _gqa_out(layer, attn, u)
+            mixed = _gqa_out(layer, attn, config, u)
     elif spec.kind == "gqa_window_attention":
         with jax.named_scope("window_attention"):
             j = slots[i]
@@ -629,7 +728,7 @@ def _rows_layer(layer, spec, i, x, pos, live, state, slots, page_table,
                 _gqa_queries(layer, u, config, pos, i), ring, ring_table,
                 jnp.minimum(seq_lens, R), _scale(config),
                 "window_attention_decode", use_pallas)
-            mixed = _gqa_out(layer, attn)
+            mixed = _gqa_out(layer, attn, config)
     elif spec.kind == "mamba":
         with jax.named_scope("ssm"):
             j = slots[i]
@@ -805,6 +904,14 @@ def forward_ragged(params, config, tokens, token_seq, token_pos, q_start,
                 state["conv"][j] = jnp.where(
                     has_slice[:, None, None], tail, state["conv"][j])
                 mixed = _kda_out(layer, o, gate, config)
+        elif spec.kind == "short_conv":
+            with jax.named_scope("short_conv"):
+                j = slots[i]
+                mixed, tail = _short_conv(
+                    layer, u, state["conv"][j], ssm.causal_conv_ragged,
+                    token_seq, token_off, q_start, q_len, fresh)
+                state["conv"][j] = jnp.where(
+                    has_slice[:, None, None], tail, state["conv"][j])
         elif spec.kind == "gqa_attention" and i != last_writer:
             with jax.named_scope("gqa_attention"):
                 j = slots[i]
@@ -816,7 +923,7 @@ def forward_ragged(params, config, tokens, token_seq, token_pos, q_start,
                     _gqa_queries(layer, u, config, token_pos, i),
                     state["paged"][j], page_table, q_start, q_len, kv_start,
                     use_pallas=use_pallas)
-                mixed = _gqa_out(layer, attn, u)
+                mixed = _gqa_out(layer, attn, config, u)
         elif spec.kind == "gqa_window_attention":
             with jax.named_scope("window_attention"):
                 j = slots[i]
@@ -834,7 +941,7 @@ def forward_ragged(params, config, tokens, token_seq, token_pos, q_start,
                               token_seq, -1),
                     token_pos % R, ps,
                     runs=_ring_runs(q_start, q_len, kv_start, R))
-                mixed = _gqa_out(layer, attn)
+                mixed = _gqa_out(layer, attn, config)
         elif spec.kind == "mamba":
             with jax.named_scope("ssm"):
                 j = slots[i]

@@ -73,18 +73,24 @@ def _map_hidden_act(act) -> str:
 #: `gqa_attention` is plain grouped-query attention over the pool's pages,
 #: `gqa_window_attention` the same over the last `sliding_window` tokens,
 #: kept in a ring; `kda` is a Kimi-delta linear-attention mixer (the gated
-#: delta rule with a decay per channel, ops/delta.py)
+#: delta rule with a decay per channel, ops/delta.py); `short_conv` is a
+#: gated short convolution (the LFM2 family's: a product, a few taps over
+#: the hidden columns, a product) whose only state is the convolution's tail
 MIXER_KINDS = ("attention", "window_attention", "cross_attention", "mamba",
                "gmu", "latent_attention", "mamba2", "ffn", "gqa_attention",
-               "gqa_window_attention", "kda")
+               "gqa_window_attention", "kda", "short_conv")
 _WRITES = {"attention": "paged_kv", "window_attention": "window_kv",
            "cross_attention": "none", "mamba": "recurrent", "gmu": "none",
            "latent_attention": "latent_kv", "mamba2": "recurrent",
            "ffn": "none", "gqa_attention": "paged_kv",
-           "gqa_window_attention": "window_kv", "kda": "recurrent"}
+           "gqa_window_attention": "window_kv", "kda": "recurrent",
+           "short_conv": "recurrent"}
+#: a recurrent slot that is a convolution's tail alone: no float32 state
+NO_SCAN_STATE = (0,)
 #: what a layer that writes `recurrent` keeps a lane, by its kind: (the
-#: float32 state's shape, the columns its convolution runs over, the
-#: convolution's taps).  engine/kvcache.StateLayout sizes its slots by it
+#: float32 state's shape, NO_SCAN_STATE where the mixer has none, the
+#: columns its convolution runs over, the convolution's taps).
+#: engine/kvcache.StateLayout sizes its slots by it
 _RECURRENT_SLOT = {
     "mamba": lambda c: ((c.mamba_d_inner, c.mamba_d_state),
                         c.mamba_d_inner, c.mamba_d_conv),
@@ -92,6 +98,7 @@ _RECURRENT_SLOT = {
                          c.mamba2_conv_dim, c.mamba_d_conv),
     "kda": lambda c: ((c.kda_n_heads, c.kda_head_dim, c.kda_head_dim),
                       c.kda_conv_dim, c.kda_d_conv),
+    "short_conv": lambda c: (NO_SCAN_STATE, c.hidden_size, c.conv_taps),
 }
 
 #: model_type values LlamaConfig's family knobs describe
@@ -168,7 +175,8 @@ class LlamaConfig:
     max_position_embeddings: int = 4096
     tie_word_embeddings: bool = False
     attention_bias: bool = False
-    # per-head RMSNorm on q/k before rope (Qwen3-family)
+    # per-head RMSNorm on q/k before rope (Qwen3-family; a hybrid table's
+    # `gqa_attention` rows likewise: the LFM2 family)
     qk_norm: bool = False
     # ---- Gemma-2 family knobs (all default to Llama behavior) ----
     hidden_act: str = "silu"  # or "gelu_tanh" (GeGLU)
@@ -230,6 +238,10 @@ class LlamaConfig:
     # `gqa_attention` rows multiply their attention's output by
     # sigmoid(u W_g) before the output projection (arXiv:2505.06708)
     attention_gate: bool = False
+    # gated short convolutions (kind "short_conv"): taps of the depthwise
+    # causal convolution over the hidden columns; the lane keeps its last
+    # conv_taps - 1 rows and nothing else
+    conv_taps: int = 0
     # ---- latent attention (models/latent.py): queries through a rank
     # q_lora_rank bottleneck, keys and values through ONE compressed row of
     # kv_lora_rank values plus qk_rope_head_dim roped ones a token, which is
@@ -385,14 +397,28 @@ class LlamaConfig:
         return self.n_passes > 1
 
     @property
+    def pairs_kv_heads(self) -> bool:
+        """Whether a cache row holds TWO adjacent K/V heads side by side, in
+        one row of twice the head's width (models/hybrid.py's head note): a
+        differential pair's, and those of a hybrid table's `gqa_attention`
+        rows whose heads are 64 wide, half the 128 lanes the attention
+        kernels and the page write tile by.  The same bytes a token either
+        way.  A table with window rows of such heads (none exists) and the
+        Llama path keep their 64-wide rows, and with them the gather."""
+        kinds = set(self.mixer_kinds or ())
+        return self.diff_attention or (
+            self.head_dim == 64 and self.n_kv_heads % 2 == 0
+            and "gqa_attention" in kinds
+            and "gqa_window_attention" not in kinds)
+
+    @property
     def cache_kv_heads(self) -> int:
-        """K/V heads as the cache stores them: a differential pair's two
-        heads lie side by side in one row of twice the width."""
-        return self.n_kv_heads // 2 if self.diff_attention else self.n_kv_heads
+        """K/V heads as the cache stores them (`pairs_kv_heads`)."""
+        return self.n_kv_heads // 2 if self.pairs_kv_heads else self.n_kv_heads
 
     @property
     def cache_head_dim(self) -> int:
-        return 2 * self.head_dim if self.diff_attention else self.head_dim
+        return 2 * self.head_dim if self.pairs_kv_heads else self.head_dim
 
     def layer_ropes(self, i: int) -> bool:
         """Whether a hybrid table's plain grouped-query row i turns its
@@ -529,6 +555,8 @@ class LlamaConfig:
             return _cohere2_moe_config(cfg)
         if model_type == "solar_open2":
             return _solar_open2_config(cfg)
+        if model_type == "lfm2_moe":
+            return _lfm2_moe_config(cfg)
         if model_type not in _LLAMA_MODEL_TYPES:
             foreign = [k for k in _FOREIGN_MIXER_KEYS if k in cfg
                        and not (k == "total_ut_steps" and int(cfg[k]) <= 1)]
@@ -971,6 +999,73 @@ def _solar_open2_config(cfg: dict) -> LlamaConfig:
         moe_intermediate_size=cfg["moe_intermediate_size"],
         n_shared_experts=int(cfg.get("n_shared_experts", 0)),
         moe_router="sigmoid",
+        routed_scaling_factor=float(cfg.get("routed_scaling_factor", 1.0)),
+        norm_topk_prob=bool(cfg.get("norm_topk_prob", True)),
+    )
+
+
+#: `layer_types` entry of `model_type: lfm2_moe` -> the row's mixer kind
+_LFM2_LAYER_TYPES = {"conv": "short_conv", "full_attention": "gqa_attention"}
+
+
+def _lfm2_moe_config(cfg: dict) -> LlamaConfig:
+    """config.json of `model_type: lfm2_moe` (LFM2-24B-A2B): pre-norm layers
+    of two residuals, `h += Mixer(RMSNorm(h)); h += FFN(RMSNorm(h))`, the
+    mixer by `layer_types`: `conv` a gated short convolution (`short_conv`:
+    `[B | C | x] = u W_in`, a depthwise causal convolution of `conv_L_cache`
+    taps over `B * x` with no bias and no activation, `(C * conv) W_out`),
+    `full_attention` grouped-query attention with an RMSNorm a head on q and
+    k before a half-split rotary.  The first `num_dense_layers` feed-forwards
+    are one gated MLP of `intermediate_size`, the others `num_experts`
+    routed experts of `moe_intermediate_size` (sigmoid scores, a choice-only
+    bias where `use_expert_bias`, weights normalised over the chosen), no
+    shared one.  The head is the embedding, transposed.  What the published
+    file leaves to the modeling file is listed under `assumed` in
+    benchmark/configs/lfm2-24b-a2b.json."""
+    n_layers = cfg["num_hidden_layers"]
+    kinds = list(cfg.get("layer_types") or ())
+    rope = cfg.get("rope_parameters") or {}
+    refused = []
+    if cfg.get("conv_bias"):
+        refused.append("conv_bias true")
+    if int(cfg.get("conv_L_cache", 3)) < 2:
+        refused.append(f"conv_L_cache={cfg['conv_L_cache']} (a convolution "
+                       "of one tap keeps no tail)")
+    unknown = sorted(set(kinds) - set(_LFM2_LAYER_TYPES))
+    if unknown or len(kinds) != n_layers:
+        refused.append(
+            f"layer_types of {len(kinds)} entries for num_hidden_layers="
+            f"{n_layers} with {unknown} (built: conv, full_attention)")
+    if int(cfg.get("num_dense_layers", 0)) > n_layers:
+        refused.append(f"num_dense_layers={cfg['num_dense_layers']} of "
+                       f"num_hidden_layers={n_layers}")
+    if rope.get("rope_type", "default") != "default" or cfg.get("rope_scaling"):
+        refused.append(f"rope_type={rope.get('rope_type')!r} (built: default)")
+    if not cfg.get("tie_word_embeddings", True):
+        refused.append("tie_word_embeddings false")
+    if refused:
+        raise ValueError("lfm2_moe: not implemented: " + "; ".join(refused))
+    return LlamaConfig(
+        vocab_size=cfg["vocab_size"],
+        hidden_size=cfg["hidden_size"],
+        intermediate_size=cfg["intermediate_size"],
+        n_layers=n_layers,
+        n_heads=cfg["num_attention_heads"],
+        n_kv_heads=cfg["num_key_value_heads"],
+        head_dim=cfg.get("head_dim"),
+        rope_theta=float(rope.get("rope_theta", cfg.get("rope_theta", 10000.0))),
+        rms_norm_eps=cfg.get("norm_eps", 1e-5),
+        max_position_embeddings=cfg.get("max_position_embeddings", 4096),
+        tie_word_embeddings=True,
+        qk_norm=True,
+        mixer_kinds=tuple(_LFM2_LAYER_TYPES[kind] for kind in kinds),
+        conv_taps=int(cfg.get("conv_L_cache", 3)),
+        n_experts=cfg["num_experts"],
+        n_experts_per_tok=cfg["num_experts_per_tok"],
+        moe_intermediate_size=cfg["moe_intermediate_size"],
+        first_k_dense=int(cfg.get("num_dense_layers", 0)),
+        moe_router="sigmoid",
+        moe_router_bias=bool(cfg.get("use_expert_bias", True)),
         routed_scaling_factor=float(cfg.get("routed_scaling_factor", 1.0)),
         norm_topk_prob=bool(cfg.get("norm_topk_prob", True)),
     )

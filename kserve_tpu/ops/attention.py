@@ -718,18 +718,20 @@ def describe_attention_dispatch(model_config, engine_config,
             "kv_write": kv_write,
         }
     if cfg.use_pallas is None:
-        ragged = _should_use_ragged_pallas(mc.head_dim, backend, quantized)
-        kv_heads = mc.n_kv_heads // cfg.tp  # one device's
+        # the cache's rows: a table's 64-wide heads lie two a row
+        # (LlamaConfig.pairs_kv_heads); every other model's are its heads
+        d = mc.cache_head_dim
+        ragged = _should_use_ragged_pallas(d, backend, quantized)
+        kv_heads = mc.cache_kv_heads // cfg.tp  # one device's
         # the widest table this replica compiles: is the kernel built at all
         # a hybrid table's window rows keep rings: its full rows have none
         decode = (
             (mc.is_hybrid or mc.sliding_window <= 0) and mc.attn_scale is None
             and _should_use_pallas(
-                mc.head_dim, quantized, cfg.max_pages_per_seq,
+                d, quantized, cfg.max_pages_per_seq,
                 cfg.max_batch_size, backend, cfg.page_size, kv_heads))
         if decode:
-            min_pages = pallas_min_pages(
-                mc.head_dim, kv_heads, cfg.page_size) or None
+            min_pages = pallas_min_pages(d, kv_heads, cfg.page_size) or None
     else:
         ragged = decode = bool(cfg.use_pallas)
     mixed = "pallas_ragged" if ragged else "xla_ragged_gather"

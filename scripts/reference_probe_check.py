@@ -169,12 +169,20 @@ def int8_child(config_path, family_name, probes_path, out_path) -> int:
     if family_name == "solar_open2":
         # a Kimi-delta mixer's projections and the attention row's gate
         linear += ("wqkv", "wf_a", "wf_b", "w_beta", "wg_a", "wg_b", "wg")
+    if family_name == "lfm2_moe":
+        # a gated short convolution's two projections
+        linear += ("in_proj", "out_proj")
     for i, layer in enumerate(params["layers"]):
         # in place: a layer's bf16 tensors go as its float32 ones come
         params["layers"][i] = {k: rounded(v) if k in linear else v
                                for k, v in layer.items()}
     if "lm_head" in params:
         params["lm_head"] = rounded(params["lm_head"])
+    elif family_name == "lfm2_moe":
+        # the tied head: a scale a vocabulary row, as the program's int8
+        # embedding carries (the earlier tied families' readings were taken
+        # with theirs in bf16, and stay so)
+        params["embed"] = rounded(np.asarray(params["embed"], np.float32).T).T
     gaps, matches, total = [], 0, 0
     for probe in probes:
         prompt, served = probe["prompt"], probe["served"]

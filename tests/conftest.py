@@ -203,29 +203,34 @@ def harness_test_finds_a_dotted_configuration(monkeypatch):
 
 
 #: tests of the accepted benchmark that hold their metric to be the LAST
-#: entry of `per_layer`, which it was when their PR added it
-LAST_ENTRY_TESTS = ("test_benchmark_null_fetch_share",
-                    "test_benchmark_packed_single_token_share")
+#: entry of `per_layer`, which it was when their PR added it: the module's
+#: `NAME`, or the entry named here
+LAST_ENTRY_TESTS = {"test_benchmark_null_fetch_share": None,
+                    "test_benchmark_packed_single_token_share": None,
+                    "test_benchmark_reference_solar": "kda.chunk_roofline"}
 
 
 @pytest.fixture(autouse=True)
 def last_entry_tests_see_the_manifest_as_they_left_it(request, monkeypatch):
-    """tests/benchmark_tests/test_benchmark_null_fetch_share.py (PR 42) and
-    test_benchmark_packed_single_token_share.py (PR 46), the accepted
-    benchmark's and so left as they are, hold their metric to be the LAST
-    entry of `per_layer`, which it was when they were added; new entries go
-    to the end of that list (PR 43's four, PR 49's three).  Those files'
-    tests are shown the list up to and including their own entry; every
-    other test, and the harness, read the manifest whole."""
-    if request.module.__name__ not in LAST_ENTRY_TESTS:
+    """tests/benchmark_tests/test_benchmark_null_fetch_share.py (PR 42),
+    test_benchmark_packed_single_token_share.py (PR 46) and
+    test_benchmark_reference_solar.py (PR 49), the accepted benchmark's and
+    so left as they are, hold their metrics to be the LAST entries of
+    `per_layer`, which they were when they were added; new entries go to the
+    end of that list (PR 43's four, PR 49's three, PR 54's two).  Those
+    files' tests are shown the list up to and including their own entry;
+    every other test, and the harness, read the manifest whole."""
+    name = request.module.__name__
+    if name not in LAST_ENTRY_TESTS:
         return
     manifest = request.module.manifest
     load = manifest.load_manifest
+    own = LAST_ENTRY_TESTS[name] or request.module.NAME
 
     def as_its_pr_left_it(*args, **kwargs):
         whole = load(*args, **kwargs)
         names = [m["name"] for m in whole["per_layer"]]
-        last = names.index(request.module.NAME) + 1
+        last = names.index(own) + 1
         return dict(whole, per_layer=whole["per_layer"][:last])
 
     monkeypatch.setattr(manifest, "load_manifest", as_its_pr_left_it)

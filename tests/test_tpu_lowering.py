@@ -1043,3 +1043,76 @@ class TestSolarOpen2:
         assert len(passes) == 2, passes
         assert "kind=kLoop" in passes[0] and "scatter" in passes[1], passes
         assert compiled.memory_analysis().temp_size_in_bytes < 32e6
+
+
+class TestLfm2:
+    """`model_type: lfm2_moe` (PR 54): heads of 64 lie two a 128-wide cache
+    row, so the attention rows take the kernels the other tables' rows take;
+    the gated short convolution is plain XLA around `in_proj` / `out_proj`."""
+
+    def test_mixed_calls_the_paged_kernels_over_paired_rows(self, monkeypatch):
+        """The `mixed` program's two steps (the packed step and the decode
+        steps), lowered for the TPU: the ragged kernel and the decode kernel
+        (the packed step's one-token lanes on it too), the page write, the
+        grouped experts; no gathered history and no tail but [lanes, 2,
+        hidden]."""
+        import dataclasses
+        import re
+
+        from kserve_tpu.engine.shapes import DispatchShapes
+        from kserve_tpu.engine.types import EngineConfig
+        from kserve_tpu.models import llama
+        from test_lfm2_model import CFG
+
+        mc = dataclasses.replace(
+            llama.LlamaConfig.from_hf_config(
+                dict(CFG, hidden_size=256, num_attention_heads=8,
+                     num_key_value_heads=4, intermediate_size=256,
+                     moe_intermediate_size=128)),
+            dtype="bfloat16")
+        assert mc.pairs_kv_heads and (mc.cache_kv_heads, mc.cache_head_dim) == (2, 128)
+        cfg = EngineConfig(
+            max_batch_size=8, page_size=64, num_pages=64, max_pages_per_seq=8,
+            max_prefill_len=128, prefill_buckets=(128,), dtype="bfloat16")
+        layout = kvcache.StateLayout.of(mc, 64, cfg.num_pages, 8, "bfloat16")
+        state = jax.eval_shape(layout.init_state)
+        assert state["ssm"] == [] and len(state["conv"]) == 6
+        text = _lower_mixed(mc, cfg, state, 8, monkeypatch).as_text()
+        kernels = set(re.findall(r'kernel_name = "([a-z_]+)"', text))
+        assert {"ragged_paged_attention", "paged_attention_decode",
+                "kv_page_write"} <= kernels, kernels
+        # the cache's rows as the kernels see them, and no history gathered
+        # a lane ([lanes, 8 pages x 64 tokens, ..]) beside them
+        assert "tensor<64x2x2x64x128xbf16>" in text
+        assert not re.findall(r"tensor<8x512x\d+x(?:64|128)x", text)
+        # 6 expert layers x (packed step + decode steps) x gate, up, down
+        assert len(re.findall(r"ragged_dot", text)) >= 36
+        assert "tensor<8x2x256xbf16>" in text  # the tails
+        assert DispatchShapes.of(mc, cfg, "tpu").align == pk.RAGGED_BQ
+        report = att.describe_attention_dispatch(mc, cfg, "tpu")
+        assert report["mixed"] == "pallas_ragged"
+        assert report["decode"] == "pallas_decode"
+        assert report["packed_single_token_min_pages"] == 0
+        assert report["kv_write"] == {"paged": "page_kernel"}
+
+    def test_the_kernels_compile_at_the_published_widths(self):
+        """32 query heads over 4 cache rows of 128 (8 K/V heads of 64), 48
+        lanes, a 4096-token buffer, a 128-page table of 64-token pages: the
+        ragged kernel, the decode kernel, the split of the packed step's
+        one-token lanes and the page write compile for the described v5e."""
+        if _tpu_sharding() is None:
+            pytest.skip("no compile-only TPU topology here")
+        T, lanes, nq, rows, d, ps, W = 4096, 48, 32, 4, 128, 64, 128
+        bf16 = jnp.bfloat16
+        pool = _abstract((6528, 2, rows, ps, d), bf16)
+        q, lane = _abstract((T, nq, d), bf16), _i32(lanes)
+        for entry in (pk.ragged_paged_attention_pallas,
+                      pk.ragged_single_token_split_pallas):
+            compiled = jax.jit(entry).lower(
+                q, pool, _i32(lanes, W), lane, lane, lane).compile()
+            assert "ragged_paged_attention" in compiled.as_text()
+        jax.jit(pk.paged_attention_pallas).lower(
+            _abstract((lanes, nq, d), bf16), pool, _i32(lanes, W), lane).compile()
+        jax.jit(kw.write_runs).lower(
+            pool, _abstract((T, rows, d), bf16), _abstract((T, rows, d), bf16),
+            _i32(lanes, W), lane, lane, lane, lane).compile()
