@@ -73,6 +73,7 @@ from ..metrics import (
 from ..lifecycle.checkpoint import GenerationCheckpoint, GenerationPreempted
 from ..lifecycle.state import ReplicaDrainingError
 from ..models import llama
+from ..models.moe import device_layout
 from ..observability import (
     DELIVERIES,
     CPU_COLUMNS,
@@ -382,6 +383,14 @@ class LLMEngine:
             )
         else:
             self.params = shd.shard_params(params, model_config, self.mesh)
+            # the held experts' tensors as the grouped matmul wants them to
+            # lie (models/moe.device_layout: by their shape; most layers come
+            # back as they are), one layer at a time, the tensors it replaces
+            # let go before the next layer's are made
+            params = None
+            layers = self.params["layers"]
+            for i in range(len(layers)):
+                layers[i] = jax.block_until_ready(device_layout(layers[i]))
 
         # multi-adapter LoRA (pp==1 path): stacked [n_adapters, ...]
         # tensors attached per layer; a per-slot id selects at runtime

@@ -44,6 +44,14 @@ that axis over the `model` mesh axis, which GSPMD partitions where
 `ragged_dot` lowers to plain XLA (the CPU): Mixtral's tp path, which holds
 every expert on every mesh.
 
+Two dimensions of those tensors meet the grouped matmul's tiles, and both
+are laid out by shape alone: the routed width is STORED in whole 512-tiles
+(`stored_width`, zeros behind it, in the parameters themselves), and a
+`hidden` that is no multiple of 512 is SPLIT on the device into a body of
+whole tiles and what is left over (`device_layout`, made by the engine from
+the parameters as they were initialised or loaded; `routed_experts` takes
+either).
+
 Role parity: vLLM's fused MoE path (SURVEY.md §2.3 Expert parallel row).
 """
 
@@ -139,6 +147,51 @@ def stored_width(width: int) -> int:
     return -(-width // STORED_WIDTH_TILE) * STORED_WIDTH_TILE
 
 
+def hidden_body(hidden: int) -> int:
+    """Rows of `hidden` that `device_layout` keeps in the BODY: the largest
+    multiple of 512 in it; all of it where it is such a multiple, or lies
+    under one tile (a test's).  The grouped matmul takes ONE tile size for
+    a whole dimension, the largest of 512 / 256 / 128 that divides it: at
+    Nemotron's 2688 = 21 x 128 every call ran one of k and n on 128-wide
+    tiles (`ragged_dot_tiling` 32,128,512 and 32,512,128), where 2560
+    compiles to 512 x 512 as every other configuration's tensors do
+    (docs/kernels.md, "A chip's share of the experts"; pinned in
+    tests/test_tpu_lowering.py)."""
+    if hidden < STORED_WIDTH_TILE:
+        return hidden
+    return hidden // STORED_WIDTH_TILE * STORED_WIDTH_TILE
+
+
+def device_layout(layer: Dict[str, Any]) -> Dict[str, Any]:
+    """An expert layer's tensors as they lie on the device: where `hidden`
+    is no whole number of 512-tiles, each routed tensor is a pair (body,
+    rest) split along `hidden` at `hidden_body`: `w_up` (and `w_gate`)
+    [held, h, f] -> ([held, body, f], [held, h - body, f]), `w_down` [held,
+    f, h] -> ([held, f, body], [held, f, h - body]).  The same values,
+    slice for slice; not a byte more is stored or read, nothing is padded.
+    Everywhere else (`hidden` a multiple of 512 or under one tile, a layer
+    without routed experts) the layer comes back AS IT IS, the same
+    object, and traces the program it traced.
+
+    Chosen by the tensors' shape alone.  tp shards the expert axis
+    (`moe_param_pspecs`), never `hidden`, so each chip's tensors keep the
+    whole `hidden` and the split is taken on it as on one chip; a sharding
+    of `hidden` itself would have to take the rule on each chip's own
+    share of the rows, and none exists."""
+    w_up = layer.get("w_up")
+    if getattr(w_up, "ndim", 0) != 3:  # a dense feed-forward, or none
+        return layer
+    body = hidden_body(w_up.shape[1])
+    if body == w_up.shape[1]:
+        return layer
+    out = dict(layer)
+    for name in ("w_up", "w_gate"):
+        if name in out:
+            out[name] = (out[name][:, :body], out[name][:, body:])
+    out["w_down"] = (out["w_down"][:, :, :body], out["w_down"][:, :, body:])
+    return out
+
+
 def moe_param_shapes(config: MoEConfig) -> Dict[str, tuple]:
     """{name: shape} of one expert layer's feed-forward: the router over
     every expert, the stacked tensors over those held here, a routed
@@ -211,6 +264,34 @@ def route(params: Dict[str, Any], x: jnp.ndarray,
     return weights * config.scale, selected
 
 
+def _grouped_in(xs: jnp.ndarray, w: Any, rows: jnp.ndarray) -> jnp.ndarray:
+    """xs [M, h] through each row's expert of w [held, h, f] -> [M, f] in
+    xs's dtype: float32 accumulation, rounded ONCE.  Over a `device_layout`
+    pair the contraction split at a column is the sum of two, each asked in
+    float32, summed in float32 and rounded where the whole call rounds."""
+    if not isinstance(w, tuple):
+        return jax.lax.ragged_dot(xs, w, rows)
+    body, rest = w
+    cut = body.shape[1]
+    return (
+        jax.lax.ragged_dot(xs[:, :cut], body, rows,
+                           preferred_element_type=jnp.float32)
+        + jax.lax.ragged_dot(xs[:, cut:], rest, rows,
+                             preferred_element_type=jnp.float32)
+    ).astype(xs.dtype)
+
+
+def _grouped_out(act: jnp.ndarray, w: Any, rows: jnp.ndarray) -> jnp.ndarray:
+    """act [M, f] through each row's expert of w [held, f, h] -> [M, h]
+    float32; over a `device_layout` pair the output split at a column is
+    the two calls' columns side by side."""
+    parts = w if isinstance(w, tuple) else (w,)
+    ys = [jax.lax.ragged_dot(act, part, rows,
+                             preferred_element_type=jnp.float32)
+          for part in parts]
+    return ys[0] if len(ys) == 1 else jnp.concatenate(ys, axis=-1)
+
+
 @jax.named_scope("experts")
 def routed_experts(params: Dict[str, Any], x: jnp.ndarray,
                    weights: jnp.ndarray, selected: jnp.ndarray,
@@ -225,7 +306,12 @@ def routed_experts(params: Dict[str, Any], x: jnp.ndarray,
     hold a part of the experts; None = all.  Pairs are ordered by expert;
     `valid` False rows and pairs routed to an expert that is not held come
     behind every expert and belong to no group, so no expert multiplies
-    them and they add nothing."""
+    them and they add nothing.
+
+    The routed tensors as `moe_param_shapes` names them or as
+    `device_layout` lays them out: the same sums either way, `up` (and
+    `gate`) accumulated in float32 and rounded to x's dtype once, BEFORE
+    the activation, in both."""
     N, k = selected.shape
     first_expert, E = share or (0, n_experts)
     flat = selected.reshape(-1).astype(jnp.int32)
@@ -251,15 +337,11 @@ def routed_experts(params: Dict[str, Any], x: jnp.ndarray,
         jnp.arange(N * k, dtype=jnp.int32), unique_indices=True)
     xs = x[order // k]  # [N k, h]: each pair's token, in its expert's run
     if form == "gated":
-        gate = jax.lax.ragged_dot(xs, params["w_gate"], rows)
-        up = jax.lax.ragged_dot(xs, params["w_up"], rows)
-        act = jax.nn.silu(gate) * up
+        gate = _grouped_in(xs, params["w_gate"], rows)
+        act = jax.nn.silu(gate) * _grouped_in(xs, params["w_up"], rows)
     else:
-        act = jnp.square(jax.nn.relu(
-            jax.lax.ragged_dot(xs, params["w_up"], rows)))
-    ys = jax.lax.ragged_dot(
-        act.astype(xs.dtype), params["w_down"], rows,
-        preferred_element_type=jnp.float32)  # [N k, h]
+        act = jnp.square(jax.nn.relu(_grouped_in(xs, params["w_up"], rows)))
+    ys = _grouped_out(act.astype(xs.dtype), params["w_down"], rows)  # [N k, h]
     # back to (token, choice) order; rows of no group hold nothing defined
     y = ys[dest].reshape(N, k, -1)
     if share is not None:

@@ -14,6 +14,15 @@ and its operations at the chip's peaks; and the same 48 tokens through
 every held expert with the weights masked (`dense_masked`: one batched
 matmul, no grouping), which is what the grouped matmul has to beat there.
 
+At the width the program stores (the last of `stored`) the same call three
+ways along `hidden` (PR 55): the tensors whole (`routed_experts`: 2688 = 21
+x 128, one of the grouped matmul's k and n on 128-wide tiles in every
+call), as `models/moe.device_layout` lays them out (`body_rest`: 2560 of
+whole 512-tiles and the 128 left over, the same bytes), and with `hidden`
+padded to the next multiple of 512 with zeros, the tokens' columns too
+(`hidden_padded`: 3072, 14.3 % more bytes).  `gap_to_whole` is the largest
+difference of a form's result from `routed_experts`' on the same tokens.
+
 Run it on the chip (it refuses any other backend unless --cpu, which only
 rehearses the control flow at a small size).  Results go to stdout and to
 chiprun_out/expert_share_kernels.json.
@@ -34,7 +43,13 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from kserve_tpu.models.moe import MoEConfig, route, routed_experts
+from kserve_tpu.models.moe import (
+    STORED_WIDTH_TILE,
+    MoEConfig,
+    device_layout,
+    route,
+    routed_experts,
+)
 
 HBM, PEAK = 819e9, 197e12
 N_LO, N_HI = 8, 24
@@ -86,6 +101,16 @@ def stored_in(columns: int, cfg: MoEConfig, dtype) -> dict:
     }
 
 
+def hidden_padded(params: dict, hidden: int) -> dict:
+    """The routed tensors with `hidden` padded to the next multiple of 512,
+    zero rows (w_up) and zero columns (w_down) behind it."""
+    pad = -hidden % STORED_WIDTH_TILE
+    return dict(
+        params,
+        w_up=jnp.pad(params["w_up"], ((0, 0), (0, pad), (0, 0))),
+        w_down=jnp.pad(params["w_down"], ((0, 0), (0, 0), (0, pad))))
+
+
 def main() -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--cpu", action="store_true")
@@ -94,7 +119,7 @@ def main() -> int:
         print("this measures the chip", file=sys.stderr)
         return 1
     if args.cpu:
-        hidden, width, stored, scored, held, k = 64, 48, (128,), 8, 4, 2
+        hidden, width, stored, scored, held, k = 640, 48, (128,), 8, 4, 2
         token_counts = (8, 32)
         dtype = jnp.float32  # the CPU has no bf16 x bf16 = f32 product
     else:
@@ -114,21 +139,34 @@ def main() -> int:
             _, counts = routed_experts(
                 params, x, w, sel, cfg.n_experts, None, (0, held), "relu2")
             counts = np.asarray(counts)
-            forms = {"routed_experts": lambda x: routed_experts(
-                params, x, w, sel, cfg.n_experts, None, (0, held), "relu2")[0]}
+            def whole(params, x):
+                return routed_experts(
+                    params, x, w, sel, cfg.n_experts, None, (0, held), "relu2")[0]
+
+            # name -> (the call, the tensors it is given as ARGUMENTS: closed
+            # over, each program would carry its 1.4 GB as constants)
+            forms = {"routed_experts": (whole, params)}
             if tokens <= 64:
-                forms["dense_masked"] = lambda x: dense_masked(
-                    params, x, w, sel, cfg)
-            for name, fn in forms.items():
+                forms["dense_masked"] = (
+                    lambda params, x: dense_masked(params, x, w, sel, cfg),
+                    params)
+            if columns == stored[-1]:
+                forms["body_rest"] = (whole, device_layout(params))
+                forms["hidden_padded"] = (
+                    lambda wide, x: whole(wide, jnp.pad(x, (
+                        (0, 0), (0, wide["w_up"].shape[1] - hidden))))[:, :hidden],
+                    hidden_padded(params, hidden))
+            expected = jax.jit(whole)(params, x)
+            for name, (fn, tensors) in forms.items():
                 @jax.jit
-                def loop(n, x, fn=fn):
+                def loop(n, x, tensors, fn=fn):
                     def body(_, carry):
                         x, acc = carry
-                        y = fn(x)
+                        y = fn(tensors, x)
                         return x + (y * 1e-6).astype(x.dtype), acc + y.sum()
                     return jax.lax.fori_loop(0, n, body, (x, jnp.float32(0)))
 
-                s = per_call(loop, (x,))
+                s = per_call(loop, (x, tensors))
                 hit = int((counts > 0).sum())
                 bytes_ = hit * 2 * hidden * width * 2
                 flops = int(counts.sum()) * 4 * hidden * width
@@ -138,6 +176,8 @@ def main() -> int:
                        "least_us": 1e6 * max(bytes_ / HBM, flops / PEAK),
                        "bound": "bytes" if bytes_ / HBM > flops / PEAK else "flops"}
                 row["share_pct"] = 100.0 * row["least_us"] / row["us_per_call"]
+                row["gap_to_whole"] = float(
+                    jnp.max(jnp.abs(jax.jit(fn)(tensors, x) - expected)))
                 rows.append(row)
                 print(json.dumps(row), flush=True)
     os.makedirs("chiprun_out", exist_ok=True)

@@ -10,6 +10,8 @@ import pytest
 
 from kserve_tpu.models.moe import (
     MoEConfig,
+    device_layout,
+    hidden_body,
     init_moe_params,
     moe_mlp,
     route,
@@ -137,3 +139,91 @@ def test_no_array_over_tokens_experts_and_width(family):
                if e.primitive.name.startswith("ragged_dot")]
     assert len(grouped) == 3
     assert all(e.invars[0].aval.shape[0] == 16 * config.top_k for e in grouped)
+
+
+# ---- the device's layout along `hidden` (models/moe.device_layout) ----
+
+
+def _wide(form, hidden, held=0):
+    """Experts of a `hidden` around the grouped matmul's 512-tile, their
+    width one such tile."""
+    return MoEConfig(n_experts=8, top_k=3, hidden_size=hidden,
+                     intermediate_size=512, router="sigmoid", scale=2.5,
+                     form=form, held=held)
+
+
+#: which pairs a call multiplies: every one, a chip's share of the experts,
+#: the rows that are tokens, both
+LAYOUT_CALLS = {
+    "all": (None, False), "share": ((2, 4), False), "valid": (None, True),
+    "share_valid": ((2, 4), True)}
+
+
+@pytest.mark.parametrize("call", sorted(LAYOUT_CALLS))
+@pytest.mark.parametrize("form", ["relu2", "gated"])
+def test_the_device_layout_computes_what_the_canonical_tensors_compute(
+        form, call):
+    """hidden 640 = 512 + 128: body and rest give the sums the whole
+    tensors give, to float32 rounding (a contraction split at a column is
+    a sum of two, an output split at a column two outputs side by side)."""
+    share, masked = LAYOUT_CALLS[call]
+    config = _wide(form, 640, held=share[1] if share else 0)
+    params = _params(config)
+    laid = device_layout(params)
+    assert laid is not params and sorted(laid) == sorted(params)
+    x = jax.random.normal(jax.random.PRNGKey(4), (20, 640), jnp.float32)
+    valid = jnp.arange(20) % 5 != 0 if masked else None
+    weights, selected = route(params, x, config)
+    args = (x, weights, selected, config.n_experts, valid, share, form)
+    out, rows = routed_experts(params, *args)
+    split, split_rows = jax.jit(routed_experts, static_argnums=(4, 6, 7))(
+        laid, *args)
+    np.testing.assert_array_equal(np.asarray(split_rows), np.asarray(rows))
+    assert int(rows.sum()) < 20 * config.top_k or call == "all"
+    size = float(jnp.abs(out).max())
+    assert size > 0.1
+    np.testing.assert_allclose(
+        np.asarray(split), np.asarray(out), rtol=2e-5, atol=1e-6 * size)
+
+
+@pytest.mark.parametrize("form", ["relu2", "gated"])
+@pytest.mark.parametrize("hidden", [32, 512, 1024])
+def test_the_device_layout_leaves_whole_tiles_and_small_layers_alone(
+        hidden, form):
+    """A `hidden` of whole 512-tiles (every accepted expert configuration
+    but one: 2048, 4096) or under one tile (a test's): the SAME dict comes
+    back, so the layer traces the program it traced.  So does a layer whose
+    feed-forward is dense, or that has none."""
+    assert hidden_body(hidden) == hidden
+    config = _wide(form, hidden)
+    params = jax.eval_shape(
+        lambda: init_moe_params(config, jax.random.PRNGKey(0)))
+    assert device_layout(params) is params
+    dense = {"w_up": params["router"], "w_down": params["router"]}
+    assert device_layout(dense) is dense
+    mixer_only = {"in_proj": params["router"]}
+    assert device_layout(mixer_only) is mixer_only
+
+
+@pytest.mark.parametrize("form", ["relu2", "gated"])
+@pytest.mark.parametrize("hidden, body", [(640, 512), (1152, 1024), (2688, 2560)])
+def test_the_device_layout_holds_the_canonical_values_slice_for_slice(
+        hidden, body, form):
+    assert hidden_body(hidden) == body
+    config = MoEConfig(n_experts=4, top_k=2, hidden_size=hidden,
+                       intermediate_size=8, form=form)
+    params = init_moe_params(config, jax.random.PRNGKey(2))
+    laid = device_layout(params)
+    routed = ("w_up", "w_down") + (("w_gate",) if form == "gated" else ())
+    for name in sorted(params):
+        if name not in routed:
+            assert laid[name] is params[name]
+            continue
+        axis = 2 if name == "w_down" else 1
+        parts = laid[name]
+        assert isinstance(parts, tuple) and len(parts) == 2
+        assert parts[0].shape[axis] == body
+        assert parts[1].shape[axis] == hidden - body
+        np.testing.assert_array_equal(
+            np.asarray(jnp.concatenate(parts, axis=axis)),
+            np.asarray(params[name]))

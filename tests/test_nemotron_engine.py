@@ -185,6 +185,44 @@ def test_a_closing_expert_layer_counts_the_rows_it_saw():
     assert 0.2 < here / (here + away) < 0.8
 
 
+def test_a_hidden_of_no_whole_tiles_is_served_from_body_and_rest():
+    """hidden 640 = 512 + 128: the engine lays the held experts' tensors
+    out as a body of whole tiles and the rest (models/moe.device_layout)
+    and leaves the parameters it was given as they are, names, shapes and
+    values; what it serves is held to the reference over THOSE tensors."""
+    label = "nemotron-640"
+    cfg = dict(CFG, hidden_size=640, num_hidden_layers=4,
+               hybrid_override_pattern="ME*E")
+    config = dataclasses.replace(
+        LlamaConfig.from_hf_config(cfg), dtype="float32")
+    params = init_params(config, jax.random.PRNGKey(4), scale=0.1)
+    given = jax.tree.map(np.asarray, params)
+    prompt = PROMPTS[0]
+
+    async def jobs(engine):
+        return await _generate(engine, prompt, 8)
+
+    served, engine = _run(engine_config(), jobs, label, (config, params))
+    for i, (mine, canonical) in enumerate(
+            zip(engine.params["layers"], params["layers"])):
+        assert sorted(mine) == sorted(canonical)
+        if i in (1, 3):
+            for name, axis in (("w_up", 1), ("w_down", 2)):
+                body, rest = mine[name]
+                assert (body.shape[axis], rest.shape[axis]) == (512, 128)
+                np.testing.assert_array_equal(
+                    np.concatenate([body, rest], axis=axis), given["layers"][i][name])
+        else:
+            assert not any(isinstance(v, tuple) for v in mine.values())
+    jax.tree.map(np.testing.assert_array_equal,
+                 jax.tree.map(np.asarray, params), given)
+    logits = np.asarray(_reference().forward(params, cfg, prompt + served[:-1]))
+    rows = logits[len(prompt) - 1:]
+    assert max(float(r.max() - r[t]) for r, t in zip(rows, served)) < GAP
+    assert float(np.ptp(rows, axis=-1).min()) > 100 * GAP and len(set(served)) > 2
+    assert _value(ENGINE_MOE_ASSIGNMENTS, label) > 0
+
+
 @pytest.mark.parametrize("over, named", [
     (dict(spec_decode_k=2), "spec_decode_k"),
     (dict(kv_quant="int8"), "kv_quant=int8"),

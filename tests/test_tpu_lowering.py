@@ -889,6 +889,75 @@ class TestNemotronH:
         assert (temp > 600e6) == bool(copies)
 
 
+#: the routed experts of the benchmark's five expert configurations on one
+#: chip: (experts held, hidden, stored width, experts a token, lanes of a
+#: decode step, tokens of the longest packed step)
+HELD_EXPERTS = {
+    "glm47-flash": (64, 2048, 1536, 4, 48, 2048),
+    "nemotron3-nano": (64, 2688, 2048, 6, 48, 2048),
+    "command-a-plus": (16, 4096, 4096, 8, 32, 4096),
+    "solar-open2": (40, 4096, 1536, 8, 48, 4096),
+    "lfm2-24b-a2b": (64, 2048, 1536, 4, 48, 4096),
+}
+
+
+def _grouped_tiling(rows, stacked):
+    """(m, k, n) tiles the TPU's grouped matmul takes for [rows, k] x
+    `stacked` [held, k, n], as the compiled program states them."""
+    import re
+
+    held, k, n = stacked.shape
+    text = jax.jit(functools.partial(
+        jax.lax.ragged_dot, preferred_element_type=jnp.float32)).lower(
+        _abstract((rows, k), jnp.bfloat16), _abstract((held, k, n), jnp.bfloat16),
+        _i32(held)).compile().as_text()
+    (found,) = set(re.findall(r"ragged_dot_tiling[^0-9]*(\d+,\d+,\d+)", text))
+    return tuple(int(t) for t in found.split(","))
+
+
+class TestHeldExpertsMeetWholeTiles:
+    """The grouped matmul takes ONE tile size for each of k and n, the
+    largest of 512 / 256 / 128 that divides it, and on 128 it read its
+    weights at a third of the bandwidth (docs/kernels.md, "A chip's share
+    of the experts"; PR 55).  Every routed tensor of every expert
+    configuration, as `models/moe.device_layout` lays it out, compiles to
+    512 x 512, in a decode step and in the packed step; what is left over
+    of a `hidden` of no whole tiles keeps its 128 on that side alone."""
+
+    @pytest.mark.parametrize("step", ["decode", "packed"])
+    @pytest.mark.parametrize("name", sorted(HELD_EXPERTS))
+    def test_the_device_layout_compiles_to_512_tiles(self, name, step):
+        from kserve_tpu.models.moe import device_layout, hidden_body
+
+        if _tpu_sharding() is None:
+            pytest.skip("no compile-only TPU topology here")
+        held, hidden, width, k, lanes, tokens = HELD_EXPERTS[name]
+        rows = k * (lanes if step == "decode" else tokens)
+        layer = {"w_up": jax.ShapeDtypeStruct((held, hidden, width), jnp.bfloat16),
+                 "w_down": jax.ShapeDtypeStruct((held, width, hidden), jnp.bfloat16)}
+        laid = jax.eval_shape(device_layout, layer)
+        split = hidden_body(hidden) != hidden
+        assert split == (name == "nemotron3-nano")
+        for tensor in (laid["w_up"], laid["w_down"]):
+            assert isinstance(tensor, tuple) == split
+            body, *rest = tensor if split else (tensor,)
+            assert _grouped_tiling(rows, body)[1:] == (512, 512), body
+            for part in rest:  # 128 rows of w_up, 128 columns of w_down
+                tiles = _grouped_tiling(rows, part)[1:]
+                assert sorted(tiles) == [128, 512], part
+                assert tiles[part.shape[1:].index(128)] == 128
+
+    @pytest.mark.parametrize("rows", [288, 12288])
+    def test_a_hidden_of_21_x_128_whole_ran_one_side_on_128_tiles(self, rows):
+        """What PR 41 to PR 54 ran: the pin that would have caught it."""
+        if _tpu_sharding() is None:
+            pytest.skip("no compile-only TPU topology here")
+        up = jax.ShapeDtypeStruct((64, 2688, 2048), jnp.bfloat16)
+        down = jax.ShapeDtypeStruct((64, 2048, 2688), jnp.bfloat16)
+        assert _grouped_tiling(rows, up)[1:] == (128, 512)
+        assert _grouped_tiling(rows, down)[1:] == (512, 128)
+
+
 class TestCommandAPlus:
     """`model_type: cohere2_moe` (PR 43): the packed step's window attention
     as ONE kernel over (ring pages, the buffer's own slice) at the published
